@@ -44,6 +44,7 @@ import heapq
 import itertools
 import os
 import selectors
+import signal
 import subprocess
 import sys
 import threading
@@ -214,7 +215,11 @@ class WorkerEntry:
     # jax binds devices at first import, so once a worker has run a TPU task
     # its chips are pinned for the worker's lifetime; the scheduler only
     # reuses it for tasks wanting the same chip count (chip affinity).
+    # The process holds them while it idles in the pool and gives them
+    # back by exiting (max_calls, ray_tpu.kill, a removed SLICE group).
     pinned_chips: Optional[Tuple[int, ...]] = None
+    # executions per function, counted only for tasks with max_calls
+    fn_calls: Dict[str, int] = field(default_factory=dict)
     # tracing: monotonic spawn-request/HELLO stamps; the first traced
     # task dispatched onto a freshly spawned worker attributes the
     # spawn window to its trace as a "spawn" stage span (once)
@@ -470,6 +475,11 @@ class Hub:
         # scan and heterogeneous classes never block each other.
         self.runnable: Dict[tuple, deque] = {}
         self.workers: Dict[str, WorkerEntry] = {}
+        # every local worker process not yet reaped: a worker leaves
+        # `workers` the moment it is killed or its socket closes, which
+        # is before its process has gone (one that holds a chip takes
+        # seconds to die). shutdown() must outlast them all.
+        self._procs: List[subprocess.Popen] = []
         self.conn_to_worker: Dict[Any, str] = {}
         # driver/client conns in HELLO order (value = (arrival seq,
         # monotonic HELLO stamp)): deterministic victim ordering for
@@ -3346,7 +3356,8 @@ class Hub:
         actor), no TPU (chip assignment is per-dispatch), no streaming
         (backpressure credits assume one producer per worker), no
         execute deadline (the timer would count worker-queue wait), no
-        placement group (bundle accounting is head-only). Only BULK
+        max_calls (the worker may exit after the head), no placement
+        group (bundle accounting is head-only). Only BULK
         submissions (RemoteFunction.map) opt in at all — the caller
         declared a throughput-oriented fan-out; individually submitted
         tasks keep strict one-task-per-worker work-stealing."""
@@ -3358,6 +3369,7 @@ class Hub:
             and not spec.resources.get("TPU", 0)
             and not o.get("streaming")
             and not o.get("timeout_s")
+            and not o.get("max_calls")
             and not o.get("placement_group")
             and not self.config.task_timeout_default_s
         )
@@ -3493,6 +3505,8 @@ class Hub:
             else:
                 spawnable = len(node.free_tpu_chips) >= n_chips
             if n_chips == 0 or spawnable:
+                if n_chips and not spec.is_actor_create:
+                    self._make_room_for_fresh_worker(node)
                 self._spawn_wants.setdefault(node.node_id, []).append(
                     (spec.options.get("runtime_env"),
                      spec.options.get("runtime_env_hash", ""),
@@ -3506,8 +3520,12 @@ class Hub:
                           node: NodeEntry, chip_pool: Optional[tuple] = None):
         """Pick an idle worker ON THIS NODE; TPU tasks require chip
         affinity (a worker pinned to exactly n chips, or a fresh worker +
-        n free chips on the node). With chip_pool (a SLICE bundle's
-        reserved chips) the task must land on exactly those chips."""
+        n free chips on the node). Fresh means it has run nothing: a
+        worker without chips is held to the CPU backend from its first
+        instruction (worker_process.main), and a process whose jax is
+        initialised cannot change devices. With chip_pool (a SLICE
+        bundle's reserved chips) the task must land on exactly those
+        chips."""
         need_env = spec.options.get("runtime_env_hash", "")
         if n_chips > 0:
             fresh = None
@@ -3520,7 +3538,7 @@ class Hub:
                     if pool_set is not None and not set(w.pinned_chips) <= pool_set:
                         continue  # pinned outside this bundle's slice
                     return w, w.pinned_chips
-                if w.pinned_chips is None and fresh is None:
+                if w.pinned_chips is None and not w.seen_fns and fresh is None:
                     fresh = w
             if pool_set is not None:
                 # reserved chips are free iff no live worker pins them
@@ -3549,6 +3567,20 @@ class Hub:
             if best is None or (best.pinned_chips is not None and w.pinned_chips is None):
                 best = w
         return best, ()
+
+    def _make_room_for_fresh_worker(self, node: NodeEntry) -> None:
+        """A task that needs chips needs a worker that has run nothing,
+        and a pool at its cap spawns none: retire one idle worker that
+        holds no chips, so that the spawn this pass asks for fits."""
+        if (node.spawning
+                or self._node_worker_count(node.node_id) < node.max_workers):
+            return  # a fresh worker is on its way, or fits as things are
+        for w in self.workers.values():
+            if (w.state == "idle" and w.node_id == node.node_id
+                    and w.actor_id is None and w.pinned_chips is None):
+                self._kill_worker(w)
+                self._worker_died(w)
+                return
 
     def _send_exec(self, worker: WorkerEntry, spec: TaskSpec,
                    chips: Tuple[int, ...], pipelined: bool = False):
@@ -3606,6 +3638,7 @@ class Hub:
             fn_blob = self.functions.get(spec.fn_id)
             worker.seen_fns.add(spec.fn_id)
         msg = P.EXEC_ACTOR_CREATE if spec.is_actor_create else P.EXEC_TASK
+        node = self.nodes.get(worker.node_id)
         exec_payload = {
                 "task_id": spec.task_id,
                 "fn_id": spec.fn_id,
@@ -3614,6 +3647,9 @@ class Hub:
                 "args_payload": spec.args_payload,
                 "return_ids": spec.return_ids,
                 "tpu_chips": chips,
+                # a worker given fewer chips than its host has must
+                # also tell libtpu the extent of its share
+                "node_tpu_chips": int(node.total.get("TPU", 0)) if node else 0,
                 "actor_id": spec.actor_id,
                 "ready_id": spec.ready_id,
                 "options": {
@@ -3759,6 +3795,7 @@ class Hub:
             env=env,
             cwd=os.getcwd(),
         )
+        self._procs.append(proc)
         self.workers[wid] = WorkerEntry(
             worker_id=wid, proc=proc, state="starting", node_id=node.node_id,
             runtime_env_hash=renv_hash, spawned_for_actor=for_actor,
@@ -3790,6 +3827,8 @@ class Hub:
             self.workers.pop(w.worker_id, None)
         if dead:
             self._dispatch()
+        # poll() reaps: an exited worker must not stay a zombie
+        self._procs = [p for p in self._procs if p.poll() is None]
         self._add_timer(self.config.worker_reap_period_s, self._reap_workers)
 
     _worker_rss = staticmethod(proc_rss_bytes)
@@ -3938,6 +3977,16 @@ class Hub:
                 "hub.complete", "complete", tr, t_done0, time.monotonic(),
                 task_id=p["task_id"].hex(),
             )
+        max_calls = spec.options.get("max_calls") if spec is not None else None
+        if max_calls and worker is not None and worker.state == "idle":
+            # reference: @ray.remote(max_calls=N) — the process exits
+            # after its Nth run of the function and gives back what it
+            # held; for a task that was given chips, the chips
+            calls = worker.fn_calls.get(spec.fn_id, 0) + 1
+            worker.fn_calls[spec.fn_id] = calls
+            if calls >= max_calls:
+                self._kill_worker(worker)
+                self._worker_died(worker)
         self._dispatch()
 
     def _maybe_retry_app_error(self, spec, returns) -> bool:
@@ -4737,8 +4786,6 @@ class Hub:
                 if force:
                     self._kill_worker(w)
                 elif w.proc is not None:
-                    import signal
-
                     try:
                         w.proc.send_signal(signal.SIGINT)
                     except Exception:
@@ -5533,15 +5580,31 @@ class Hub:
         except Exception:
             pass
         self._shutdown_evt.wait(timeout)
-        for w in self.workers.values():
-            if w.proc is not None:
-                try:
-                    w.proc.terminate()
-                    w.proc.wait(timeout=1)
-                except Exception:
-                    try:
-                        w.proc.kill()
-                    except Exception:
-                        pass
+        self._stop_worker_processes()
         if self._kv_store is not None:
             self._kv_store.close()
+
+    def _stop_worker_processes(self, term_grace_s: float = 10.0,
+                               kill_grace_s: float = 10.0):
+        """No process this hub started outlives shutdown(), as a zombie
+        or otherwise: SIGTERM them all at once, wait for them together,
+        SIGKILL whatever is left, and reap. The grace is for chip
+        holders: libtpu's SIGTERM handler takes about three seconds."""
+        procs = [p for p in self._procs if p.poll() is None]
+        for sig, grace in ((signal.SIGTERM, term_grace_s),
+                           (signal.SIGKILL, kill_grace_s)):
+            for p in procs:
+                try:
+                    p.send_signal(sig)
+                except OSError:
+                    pass  # gone since the poll
+            deadline = time.monotonic() + grace
+            for p in procs:
+                try:
+                    p.wait(timeout=max(0.0, deadline - time.monotonic()))
+                except subprocess.TimeoutExpired:
+                    pass
+            procs = [p for p in procs if p.poll() is None]
+            if not procs:
+                break
+        self._procs = procs

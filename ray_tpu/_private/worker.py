@@ -47,19 +47,6 @@ def get_client() -> CoreClient:
     return _client
 
 
-def _detect_num_tpus() -> int:
-    env = os.environ.get("RAY_TPU_NUM_TPUS")
-    if env is not None:
-        return int(env)
-    from .jax_utils import probe_accelerator, tpu_env_markers
-
-    # When the env advertises a TPU, probe even if jax was never
-    # imported here (worth the subprocess); otherwise only an already-
-    # imported jax is consulted — a CPU-only init() stays instant.
-    # RAY_TPU_NUM_TPUS is the explicit override for marker-less hosts.
-    return probe_accelerator(force=tpu_env_markers())[1]
-
-
 def init(
     address: Optional[str] = None,
     *,
@@ -114,15 +101,16 @@ def init(
         # keeps control-plane latency low under CPU-bound driver code.
         sys.setswitchinterval(0.001)
         ncpu = num_cpus if num_cpus is not None else (os.cpu_count() or 1)
-        ntpu = num_tpus if num_tpus is not None else _detect_num_tpus()
         res: Dict[str, float] = {"CPU": float(ncpu)}
         # accelerator-manager detection (reference: node resources built
-        # from AcceleratorManager plugins) — explicit args still win
+        # from AcceleratorManager plugins) — explicit args still win.
+        # Chips are counted from device files, never through jax: the
+        # driver must not open what its workers are about to be given.
         from .accelerators import detect_resources
 
         detected = detect_resources()
-        if num_tpus is not None:
-            detected.pop("TPU", None)
+        found = detected.pop("TPU", 0)
+        ntpu = num_tpus if num_tpus is not None else found
         res.update(detected)
         if ntpu:
             res["TPU"] = float(ntpu)
@@ -131,8 +119,11 @@ def init(
         res["memory"] = float(kwargs.get("_memory", 64 * 1024**3))
         if resources:
             res.update(resources)
+        from .jax_utils import ensure_compilation_cache_dir
         from .session import new_session_dir
 
+        # before the hub exists, so that every worker inherits it
+        ensure_compilation_cache_dir()
         _session_dir = new_session_dir()
         os.makedirs(_session_dir, exist_ok=True)
         from .accelerators.tpu import get_chip_topology

@@ -15,10 +15,12 @@ Concurrency model per the reference's scheduling queues
     many calls in flight (the reference runs async actors on an asyncio
     loop owned by the core worker).
 
-TPU chip visibility: the hub assigns chip ids at dispatch; we export
-TPU_VISIBLE_CHIPS before user code first imports jax (the reference's
+TPU chip visibility: a worker starts held to the CPU backend; the hub
+assigns chip ids at dispatch, to a worker that has run nothing yet, and
+the worker then claims exactly those before user code first imports jax
+(accelerators/tpu.py deny_chips / claim_chips; the reference's
 TPUAcceleratorManager.set_current_process_visible_accelerators —
-python/ray/_private/accelerators/tpu.py:193 — does the same).
+python/ray/_private/accelerators/tpu.py:193 — sets the same variable).
 """
 
 from __future__ import annotations
@@ -34,6 +36,7 @@ from typing import Any, Dict, Optional
 
 from . import profiling as _prof
 from . import protocol as P
+from .accelerators.tpu import claim_chips, deny_chips
 from .client import CoreClient
 from .serialization import dumps_inline, loads_function, loads_inline
 from ..util import tracing as _t
@@ -271,8 +274,6 @@ class WorkerRuntime:
     def exec_task(self, p: dict):
         self._adopt_job_identity(p)
         self._chaos_stall()
-        if p.get("tpu_chips"):
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in p["tpu_chips"])
         from ..runtime_context import _current_pg
 
         pg = (p.get("options") or {}).get("placement_group")
@@ -281,6 +282,8 @@ class WorkerRuntime:
         tr = p.get("trace")
         et = _ExecTrace(self.client, tr) if tr is not None else None
         try:
+            if p.get("tpu_chips"):
+                claim_chips(p["tpu_chips"], p.get("node_tpu_chips", 0))
             fn = self._get_fn(p["fn_id"], p.get("fn_blob"))
             fn_name = getattr(fn, "__name__", fn_name)
             if et is not None:
@@ -347,8 +350,6 @@ class WorkerRuntime:
 
     def exec_actor_create(self, p: dict):
         self._adopt_job_identity(p)
-        if p.get("tpu_chips"):
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in p["tpu_chips"])
         # the hub marks respawned incarnations so user __init__ can
         # branch on was_current_actor_reconstructed; always assigned so
         # a later actor on a reused worker never inherits the flag
@@ -359,6 +360,8 @@ class WorkerRuntime:
         self.actor_pg = tuple(pg) if pg else None
         _current_pg.set(self.actor_pg)
         try:
+            if p.get("tpu_chips"):
+                claim_chips(p["tpu_chips"], p.get("node_tpu_chips", 0))
             cls = self._get_fn(p["fn_id"], p.get("fn_blob"))
             args, kwargs = self._decode_args(p["args_kind"], p["args_payload"])
             self.actor_instance = cls(*args, **kwargs)
@@ -859,6 +862,7 @@ class _LogTee:
 
 def main():
     sys.setswitchinterval(0.001)
+    deny_chips()
     hub_addr = os.environ["RAY_TPU_HUB_ADDR"]
     session_dir = os.environ["RAY_TPU_SESSION_DIR"]
     worker_id = os.environ["RAY_TPU_WORKER_ID"]

@@ -96,6 +96,9 @@ class LlamaEngine:
         self._jnp = jnp
         self._llama = llama
         self.shards: List[_Shard] = [self._new_shard()]
+        # most slots one decode call has advanced so far: whether
+        # requests were ever batched, not merely queued
+        self.peak_active = 0
 
         # prefill-chunk buckets: powers of two up to prefill_chunk
         self.buckets = []
@@ -280,6 +283,7 @@ class LlamaEngine:
                 self._pump_prefill(shard, out)
                 if not shard.active:
                     continue
+                self.peak_active = max(self.peak_active, len(shard.active))
                 last = np.zeros(self.max_batch, np.int32)
                 temps = np.zeros(self.max_batch, np.float32)
                 # inactive lanes (free or mid-prefill) still ride the

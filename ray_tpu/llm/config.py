@@ -54,14 +54,19 @@ class LLMConfig:
 
     def load_params(self):
         """Materialize model params: from checkpoint_path if given
-        (orbax dir or .npz), else fresh initialization."""
+        (orbax dir or .npz), else fresh initialization — one jitted
+        program that writes every leaf in the configuration's
+        param_dtype, not an eager float32 op per leaf."""
+        from functools import partial
+
         import jax
 
         from ray_tpu.models import llama
 
         cfg = self.model_config or llama.LLAMA_TINY
+        init = jax.jit(partial(llama.init_params, config=cfg))
         if not self.checkpoint_path:
-            return llama.init_params(jax.random.PRNGKey(0), cfg)
+            return init(jax.random.PRNGKey(0))
         import os
 
         if self.checkpoint_path.endswith(".npz"):
@@ -73,7 +78,7 @@ class LLMConfig:
         # train/_checkpoint.py)
         import orbax.checkpoint as ocp
 
-        target = llama.init_params(jax.random.PRNGKey(0), cfg)
+        target = init(jax.random.PRNGKey(0))
         ckptr = ocp.StandardCheckpointer()
         return ckptr.restore(os.path.abspath(self.checkpoint_path), target)
 

@@ -330,13 +330,17 @@ class LLMServer:
         return model
 
     def engine_stats(self) -> Dict[str, Any]:
+        from ray_tpu._private.jax_utils import device_report
+
         return {
             "active": self.engine.num_active(),
+            "peak_active": self.engine.peak_active,
             "free_slots": sum(
                 len(s.free_slots) for s in self.engine.shards
             ),
             "max_batch": self.engine.max_batch,
             "shards": len(self.engine.shards),
+            **device_report(),
         }
 
 

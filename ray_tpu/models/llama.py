@@ -50,7 +50,7 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    attention_impl: str = "xla"  # "xla" | "flash" (pallas/blockwise)
+    attention_impl: str = "xla"  # "xla" | "flash" (pallas kernel) | "ring"
     ce_impl: str = "xla"  # "xla" | "fused" (pallas lm-head CE; needs
     # B*S % 128 == 0, vocab % 128 == 0, no logit softcap)
     # logits softcap (Gemma-style) kept for generality; 0 disables.

@@ -3,9 +3,8 @@
 Hand-tiled MXU implementation of the online-softmax attention in
 ``ray_tpu.ops.attention`` — same semantics (causal, GQA), O(S) memory,
 logits never materialized in HBM. ``ops.attention.flash_attention``
-substitutes this kernel on TPU backends; the XLA blockwise formulation
-remains the fallback (and the numerical reference in
-tests/test_pallas_attention.py).
+dispatches to this kernel; the XLA blockwise formulation there is the
+numerical reference in tests/test_pallas_attention.py.
 
 Reference parity note: the reference (Ray) has no attention kernels at
 all (SURVEY.md §5.7 — delegated to vLLM/torch); this is TPU-native
@@ -44,7 +43,9 @@ _NEG_INF = float("-inf")
 
 def _interpret() -> bool:
     # CPU has no Mosaic; interpret mode keeps the kernel testable on the
-    # virtual device mesh.
+    # virtual device mesh. A worker that holds a chip runs with
+    # JAX_PLATFORMS=tpu (_private/accelerators/tpu.py), so it can never
+    # find itself here on "cpu".
     return jax.default_backend() == "cpu"
 
 
@@ -55,20 +56,21 @@ def _pick_block(size: int, preferred: int) -> int:
     raise NotImplementedError(f"sequence length {size} not a multiple of 128")
 
 
-def _check_shapes(q, k, v):
+def untileable(q, k, v):
+    """Why the kernel cannot take these shapes, or None when it can."""
     B, S, H, hd = q.shape
+    if k.shape != v.shape:
+        return "k/v shape mismatch"
     Bk, T, KVH, hdk = k.shape
-    if (B, T, KVH, hdk) != k.shape or k.shape != v.shape:
-        raise NotImplementedError("k/v shape mismatch")
     if Bk != B or hdk != hd:
-        raise NotImplementedError("q/k shape mismatch")
+        return "q/k shape mismatch"
     if H % KVH != 0:
-        raise NotImplementedError(f"H={H} not divisible by KVH={KVH}")
+        return f"H={H} not divisible by KVH={KVH}"
     if hd % _LANES != 0:
-        raise NotImplementedError(
-            f"head_dim={hd} not a multiple of {_LANES} (MXU lane width)"
-        )
-    return B, S, H, hd, T, KVH
+        return f"head_dim={hd} not a multiple of {_LANES} (MXU lane width)"
+    if S % _LANES != 0 or T % _LANES != 0:
+        return f"sequence lengths {S}/{T} not multiples of {_LANES}"
+    return None
 
 
 # ----------------------------------------------------------------------
@@ -428,10 +430,10 @@ def pallas_flash_attention(
 ) -> jax.Array:
     """Flash attention on TPU via Pallas. q (B,S,H,hd), k/v (B,T,KVH,hd)
     -> (B,S,H,hd). Raises NotImplementedError for shapes the kernel does
-    not tile (caller falls back to the XLA blockwise path)."""
-    B, S, H, hd, T, KVH = _check_shapes(q, k, v)
-    _pick_block(S, block_q)
-    _pick_block(T, block_kv)
+    not tile (see ``untileable``)."""
+    reason = untileable(q, k, v)
+    if reason is not None:
+        raise NotImplementedError(reason)
     qt = q.transpose(0, 2, 1, 3)          # (B, H, S, hd)
     kt = k.transpose(0, 2, 1, 3)          # (B, KVH, T, hd)
     vt = v.transpose(0, 2, 1, 3)

@@ -29,6 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ._partition import ambient_partition
 
 _LANES = 128
 _NEG_INF = float("-inf")
@@ -87,9 +90,11 @@ def _fwd_kernel(x_ref, w_ref, t_ref, lse_ref, tgt_ref, m_ref, l_ref, g_ref,
 
     @pl.when(vi == nv - 1)
     def _finish():
-        lse = m_ref[:, :1] + jnp.log(l_ref[:, :1])
-        lse_ref[:] = lse[:, 0]
-        tgt_ref[:] = g_ref[:, 0]
+        # per-row scalars leave lane-broadcast as (block_n, LANES):
+        # Mosaic tiles a 1-D f32 operand by 128 where XLA lays it out
+        # by 1024, and refuses the mismatch
+        lse_ref[:] = m_ref[:] + jnp.log(l_ref[:])
+        tgt_ref[:] = g_ref[:]
 
 
 def _fwd_call(x, w, targets, block_n, block_v):
@@ -115,12 +120,12 @@ def _fwd_call(x, w, targets, block_n, block_v):
             pl.BlockSpec((block_n, 1), lambda ni, vi: (ni, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda ni, vi: (ni,)),
-            pl.BlockSpec((block_n,), lambda ni, vi: (ni,)),
+            pl.BlockSpec((block_n, _LANES), lambda ni, vi: (ni, 0)),
+            pl.BlockSpec((block_n, _LANES), lambda ni, vi: (ni, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.float32),
-            jax.ShapeDtypeStruct((N,), jnp.float32),
+            jax.ShapeDtypeStruct((N, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((N, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_n, _LANES), jnp.float32),  # running max
@@ -131,8 +136,9 @@ def _fwd_call(x, w, targets, block_n, block_v):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="fused_ce_fwd",
     )(x, w, targets[:, None].astype(jnp.int32))
-    return lse, tgt
+    return lse[:, 0], tgt[:, 0]
 
 
 # ----------------------------------------------------------------------
@@ -153,11 +159,11 @@ def _dx_kernel(x_ref, w_ref, t_ref, lse_ref, gin_ref, dx_ref, acc_ref,
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    p = jnp.exp(s - lse_ref[:][:, None])           # softmax tile
+    p = jnp.exp(s - lse_ref[:, :1])                # softmax tile
     t = t_ref[:]
     cols = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, (block_n, block_v), 1)
-    dlog = (p - jnp.where(cols == t, 1.0, 0.0)) * gin_ref[:][:, None]
+    dlog = (p - jnp.where(cols == t, 1.0, 0.0)) * gin_ref[:, :1]
     acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
         dlog.astype(w.dtype), w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -182,12 +188,12 @@ def _dw_kernel(x_ref, w_ref, t_ref, lse_ref, gin_ref, dw_ref, acc_ref,
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    p = jnp.exp(s - lse_ref[:][:, None])
+    p = jnp.exp(s - lse_ref[:, :1])
     t = t_ref[:]
     vi = pl.program_id(0)
     cols = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, (x.shape[0], s.shape[1]), 1)
-    dlog = (p - jnp.where(cols == t, 1.0, 0.0)) * gin_ref[:][:, None]
+    dlog = (p - jnp.where(cols == t, 1.0, 0.0)) * gin_ref[:, :1]
     acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
         x, dlog.astype(x.dtype), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -204,6 +210,9 @@ def _bwd_call(x, w, targets, lse, g, block_n, block_v):
     nv = V // block_v
     nn = N // block_n
     t2 = targets[:, None].astype(jnp.int32)
+    # lane-broadcast the per-row inputs (see _fwd_kernel._finish)
+    lse = jnp.broadcast_to(lse[:, None], (N, _LANES))
+    g = jnp.broadcast_to(g[:, None], (N, _LANES))
 
     dx = pl.pallas_call(
         functools.partial(
@@ -214,8 +223,8 @@ def _bwd_call(x, w, targets, lse, g, block_n, block_v):
             pl.BlockSpec((block_n, D), lambda ni, vi: (ni, 0)),
             pl.BlockSpec((D, block_v), lambda ni, vi: (0, vi)),
             pl.BlockSpec((block_n, 1), lambda ni, vi: (ni, 0)),
-            pl.BlockSpec((block_n,), lambda ni, vi: (ni,)),
-            pl.BlockSpec((block_n,), lambda ni, vi: (ni,)),
+            pl.BlockSpec((block_n, _LANES), lambda ni, vi: (ni, 0)),
+            pl.BlockSpec((block_n, _LANES), lambda ni, vi: (ni, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, D), lambda ni, vi: (ni, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
@@ -224,6 +233,7 @@ def _bwd_call(x, w, targets, lse, g, block_n, block_v):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="fused_ce_bwd_dx",
     )(x, w, t2, lse, g)
 
     dw = pl.pallas_call(
@@ -235,8 +245,8 @@ def _bwd_call(x, w, targets, lse, g, block_n, block_v):
             pl.BlockSpec((block_n, D), lambda vi, ni: (ni, 0)),
             pl.BlockSpec((D, block_v), lambda vi, ni: (0, vi)),
             pl.BlockSpec((block_n, 1), lambda vi, ni: (ni, 0)),
-            pl.BlockSpec((block_n,), lambda vi, ni: (ni,)),
-            pl.BlockSpec((block_n,), lambda vi, ni: (ni,)),
+            pl.BlockSpec((block_n, _LANES), lambda vi, ni: (ni, 0)),
+            pl.BlockSpec((block_n, _LANES), lambda vi, ni: (ni, 0)),
         ],
         out_specs=pl.BlockSpec((D, block_v), lambda vi, ni: (0, vi)),
         out_shape=jax.ShapeDtypeStruct((D, V), w.dtype),
@@ -245,6 +255,7 @@ def _bwd_call(x, w, targets, lse, g, block_n, block_v):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="fused_ce_bwd_dw",
     )(x, w, t2, lse, g)
     return dx, dw
 
@@ -254,6 +265,26 @@ def _bwd_call(x, w, targets, lse, g, block_n, block_v):
 # ----------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _fused(x, w, targets, block_n, block_v):
+    lse, tgt = _fwd_call(x, w, targets, block_n, block_v)
+    return lse - tgt
+
+
+def _vjp_fwd(x, w, targets, block_n, block_v):
+    lse, tgt = _fwd_call(x, w, targets, block_n, block_v)
+    return lse - tgt, (x, w, targets, lse)
+
+
+def _vjp_bwd(block_n, block_v, res, g):
+    x, w, targets, lse = res
+    dx, dw = _bwd_call(x, w, targets, lse, g.astype(jnp.float32),
+                       block_n, block_v)
+    return dx, dw, None
+
+
+_fused.defvjp(_vjp_fwd, _vjp_bwd)
+
+
 def fused_cross_entropy(x, w, targets, block_n: int = 128,
                         block_v: int = 512):
     """Per-token losses (N,) f32 for logits = x @ w against targets.
@@ -261,28 +292,23 @@ def fused_cross_entropy(x, w, targets, block_n: int = 128,
     Out-of-range targets are clamped into [0, V) to match the XLA
     path's gather semantics (jnp.take_along_axis clamps under jit);
     without the clamp the kernel's one-hot match would silently miss
-    and return lse instead of a real loss."""
+    and return lse instead of a real loss.
+
+    Under an ambient mesh the rows are split over (data, fsdp) and
+    ``w`` is gathered whole onto every shard (ops/_partition.py); the
+    transpose of that gather sums dW over the shards."""
     targets = jnp.clip(targets, 0, w.shape[1] - 1)
-    lse, tgt = _fwd_call(x, w, targets, block_n, _pick_block(w.shape[1], block_v))
-    return lse - tgt
-
-
-def _vjp_fwd(x, w, targets, block_n, block_v):
-    targets = jnp.clip(targets, 0, w.shape[1] - 1)  # match XLA gather clamp
-    bv = _pick_block(w.shape[1], block_v)
-    lse, tgt = _fwd_call(x, w, targets, block_n, bv)
-    return lse - tgt, (x, w, targets, lse)
-
-
-def _vjp_bwd(block_n, block_v, res, g):
-    x, w, targets, lse = res
-    bv = _pick_block(w.shape[1], block_v)
-    dx, dw = _bwd_call(x, w, targets, lse, g.astype(jnp.float32),
-                       block_n, bv)
-    return dx, dw, None
-
-
-fused_cross_entropy.defvjp(_vjp_fwd, _vjp_bwd)
+    kernel = functools.partial(
+        _fused, block_n=block_n, block_v=_pick_block(w.shape[1], block_v)
+    )
+    part = ambient_partition()
+    if part is None:
+        return kernel(x, w, targets)
+    rows = P(part.batch)
+    return jax.shard_map(
+        kernel, in_specs=(P(part.batch, None), P(None, None), rows),
+        out_specs=rows, axis_names=part.axes, check_vma=False,
+    )(x, w, targets)
 
 
 def xla_cross_entropy(x, w, targets):

@@ -130,12 +130,16 @@ def make_train_step(
     """Build the donated, sharded train step.
 
     loss_fn(params, batch) -> scalar. Batch arrays are sharded on dim0
-    over the (data, fsdp) axes.
+    over the (data, fsdp) axes. The step is traced with ``mesh`` as the
+    ambient mesh, so ops that must map themselves over it by hand (the
+    Pallas kernels, ring attention) find it whether or not the caller
+    entered ``jax.sharding.set_mesh``.
     """
     bspec = NamedSharding(mesh, batch_spec(batch_ndim_extra))
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
         updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         gnorm = optax.global_norm(grads)
@@ -160,7 +164,8 @@ def make_eval_step(
     bspec = NamedSharding(mesh, batch_spec(batch_ndim_extra))
 
     def step(state: TrainState, batch):
-        return {"loss": loss_fn(state.params, batch)}
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return {"loss": loss_fn(state.params, batch)}
 
     return jax.jit(step, in_shardings=(state_sh, bspec),
                    out_shardings=NamedSharding(mesh, P()))

@@ -145,6 +145,8 @@ def scheduling_options(opts: Dict[str, Any]) -> Dict[str, Any]:
             out["strategy"] = strategy
     if opts.get("max_retries") is not None:
         out["max_retries"] = opts["max_retries"]
+    if opts.get("max_calls"):
+        out["max_calls"] = int(opts["max_calls"])
     if opts.get("timeout_s"):
         # execute deadline: past it the hub SIGKILLs the (possibly
         # hung) worker and retries the task against its crash budget,

@@ -118,9 +118,8 @@ def make_sharded_update(config, spec: MLPSpec, group):
     ``psum`` over the group's mesh axis — one cached compiled program
     per (hyperparams, spec, world), exactly the XlaGroup contract.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from ...util.collective.collective_group.xla_group import shard_map
 
     key = (
         config.loss, config.lr, config.gamma, config.vtrace_clip_rho,

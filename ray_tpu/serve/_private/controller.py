@@ -64,6 +64,11 @@ class ServeController:
         self._replicas: Dict[str, List[Any]] = {}  # name -> actor handles
         self._replica_versions: Dict[str, List[int]] = {}
         self._ping_misses: Dict[bytes, int] = {}  # consecutive health misses
+        # replicas that have answered a ping: until its constructor
+        # returns a replica answers nothing, and a model that takes its
+        # chip, initialises its weights and allocates its cache there
+        # takes longer than three missed pings
+        self._answered: set = set()
         # deployment -> {replica id -> loaded multiplexed model ids};
         # refreshed from the same batched ping (multiplex routing info)
         self._model_ids: Dict[str, Dict[bytes, List[str]]] = {}
@@ -238,8 +243,12 @@ class ServeController:
                         queued_sum += int(stats.get("queued", 0))
                         healthy = True
                         self._ping_misses.pop(rid, None)
+                        self._answered.add(rid)
                     except Exception:
                         healthy = False
+                elif rid not in self._answered:
+                    healthy = True  # still constructing (a failed
+                    # constructor answers the ping with its error)
                 else:
                     misses = self._ping_misses.get(rid, 0) + 1
                     self._ping_misses[rid] = misses
@@ -296,6 +305,7 @@ class ServeController:
         for rid in list(self._ping_misses):
             if rid not in live_rids:
                 del self._ping_misses[rid]
+        self._answered &= live_rids
 
     def _start_replica(self, info: DeploymentInfo):
         import ray_tpu

@@ -22,28 +22,9 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5: top-level export, replication check named check_vma
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-except ImportError:  # jax 0.4.x: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-spanning shard_map with the replication check off (the
-    eager collective bodies intentionally return per-rank values that
-    the checker would reject as unreplicated)."""
-    kwargs.pop("check_vma", None)
-    kwargs.pop("check_rep", None)
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **_SHARD_MAP_NO_CHECK, **kwargs,
-    )
 
 from ..types import (
     AllGatherOptions,

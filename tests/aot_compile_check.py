@@ -1,0 +1,150 @@
+"""Compile-only check of the device programs for a TPU v5e, without one.
+
+    python tests/aot_compile_check.py
+
+The installed libtpu compiles ahead of time for a topology it is only
+told about: ``get_topology_desc("tpu", "v5e:2x2")`` gives four abstract
+``TPU v5 lite`` devices, and lowering against ShapeDtypeStructs sharded
+on them runs Mosaic and the TPU compiler for real. That costs no chip
+time, so run it before every chip call. It catches what a CPU run
+cannot: a kernel Mosaic refuses, a Pallas call GSPMD cannot partition,
+a program that does not fit the chip's memory. It cannot say that a
+program runs, is right or is fast.
+
+Prints one line per program and exits non-zero if any failed to
+compile; exits 77 when this libtpu cannot describe the topology.
+tests/test_chip_path.py runs it in a subprocess with ``--quick``: the
+same programs at the same widths, one layer and a shorter, smaller
+batch, because each compile costs about a minute of CPU time.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import sys
+import time
+from functools import partial
+
+os.environ["JAX_PLATFORMS"] = "cpu"  # the process itself computes nowhere
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+
+def main() -> int:
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever this libtpu raises when it cannot
+        print(f"no TPU topology from this installation: {type(e).__name__}: {e}")
+        return 77
+
+    from ray_tpu import parallel
+    from ray_tpu.models import llama
+    from ray_tpu.ops import pallas_attention, pallas_ce
+    from ray_tpu.parallel.train_step import state_shardings
+
+    # this process's backend is the CPU, where the kernels would choose
+    # to be interpreted; the programs below are for the TPU
+    pallas_attention._interpret = lambda: False
+    pallas_ce._interpret = lambda: False
+
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    failures = 0
+
+    def check(name, lower, expect=()):
+        nonlocal failures
+        t0 = time.perf_counter()
+        try:
+            compiled = lower().compile()
+        except Exception as e:
+            failures += 1
+            print(f"FAIL {name}: {type(e).__name__}: {str(e)[:600]}")
+            return
+        text = compiled.as_text()
+        missing = [n for n in expect if n not in text]
+        mem = compiled.memory_analysis()
+        if missing:
+            failures += 1
+        print(
+            f"{'FAIL' if missing else 'ok  '} {name}: "
+            f"{time.perf_counter() - t0:.1f}s, arguments "
+            f"{mem.argument_size_in_bytes / 1e9:.2f} GB + temporaries "
+            f"{mem.temp_size_in_bytes / 1e9:.2f} GB per device"
+            + (f", kernels missing from the program: {missing}" if missing else "")
+        )
+
+    quick = "--quick" in sys.argv[1:]
+    cfg = dataclasses.replace(
+        llama.LLAMA_BENCH, n_layers=1 if quick else 2,
+        param_dtype=jnp.bfloat16, remat=True, attention_impl="flash",
+    )
+    B, S = (1, 512) if quick else (8, 2048)  # sequences per device
+    flash_names = ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                   "flash_attention_bwd_dq")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def flash(q, k, v, do):
+        o, vjp = jax.vjp(
+            lambda *a: pallas_attention.pallas_flash_attention(*a, causal=True),
+            q, k, v,
+        )
+        return (o, *vjp(do))
+
+    q = sds((B, S, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+    kv = sds((B, S, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    check("flash attention fwd+bwd, one device",
+          lambda: jax.jit(flash).lower(q, kv, kv, q), flash_names)
+
+    def fused(x, w, t):
+        loss, vjp = jax.vjp(lambda a, b: pallas_ce.fused_cross_entropy(a, b, t), x, w)
+        return (loss, *vjp(jnp.ones_like(loss)))
+
+    check(
+        "fused cross entropy fwd+bwd, one device",
+        lambda: jax.jit(fused).lower(
+            sds((B * S, cfg.dim), jnp.bfloat16),
+            sds((cfg.dim, cfg.vocab_size), jnp.bfloat16),
+            sds((B * S,), jnp.int32),
+        ),
+        ("fused_ce_fwd", "fused_ce_bwd_dx", "fused_ce_bwd_dw"),
+    )
+
+    def train_step(n_devices):
+        mesh = parallel.make_mesh(devices=topo.devices[:n_devices])
+        opt = parallel.default_optimizer(1e-4)
+
+        def init():
+            params = llama.init_params(jax.random.PRNGKey(0), cfg)
+            return parallel.TrainState(
+                jnp.zeros((), jnp.int32), params, opt.init(params)
+            )
+
+        shardings, shapes = state_shardings(mesh, llama.param_specs(cfg), init)
+        state = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, shardings,
+        )
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (B * n_devices, S + 1), jnp.int32,
+            sharding=parallel.batch_sharding(mesh),
+        )}
+        step = parallel.make_train_step(
+            partial(llama.loss_fn, config=cfg), opt, mesh, shardings
+        )
+        return step.lower(state, batch)
+
+    for n in (1, 4):
+        check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "
+              f"{B}x{S} per device, {n} device(s)",
+              partial(train_step, n), flash_names)
+    return 1 if failures else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -130,7 +130,7 @@ def test_unknown_attention_impl_raises():
 def test_ring_attention_impl_matches_xla():
     """attention_impl='ring' without a seq mesh falls back to flash and
     matches the xla einsum path; with a seq mesh it runs the ring (the
-    multi-axis case is exercised by __graft_entry__.dryrun_multichip)."""
+    multi-axis case is tests/test_chip_path.py)."""
     import dataclasses
 
     cfg = dataclasses.replace(llama.LLAMA_TINY, dtype=jnp.float32)

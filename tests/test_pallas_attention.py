@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import blockwise_attention
 from ray_tpu.ops.pallas_attention import pallas_flash_attention
 
 
@@ -42,8 +42,8 @@ def test_forward_matches_reference(causal, kvh):
     out = pallas_flash_attention(q, k, v, causal, block_q=128, block_kv=128)
     ref = _naive(q, k, v, causal)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-    blockwise = flash_attention(q, k, v, causal=causal,
-                                block_q=128, block_kv=128)
+    blockwise = blockwise_attention(q, k, v, causal=causal,
+                                    block_q=128, block_kv=128)
     np.testing.assert_allclose(out, blockwise, atol=2e-5, rtol=2e-5)
 
 

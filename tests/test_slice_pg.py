@@ -36,7 +36,7 @@ def _pg_entry(pg):
 
 
 def _coords_1x8(chip):
-    return (0, chip)
+    return (0, chip)  # x has one value, so y counts the chips
 
 
 def _is_connected(chips, coords):
@@ -86,7 +86,7 @@ def test_slice_2d_mesh(monkeypatch):
         assert len(chips) == 4
 
         def coords(c):
-            return (c // 4, c % 4)  # row-major 2x4
+            return (c % 2, c // 2)  # 2x4, x fastest (libtpu's numbering)
 
         assert _is_connected(chips, coords)
         remove_placement_group(pg)

@@ -179,7 +179,7 @@ def test_jax_trainer_mesh_training(ray_start_4_cpus, storage):
             l, g = jax.value_and_grad(loss)(w)
             return w - 0.1 * g, l
 
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+        with jax.sharding.set_mesh(mesh):
             for i in range(10):
                 w, l = step(w, xs, ys)
         train.report({"loss": float(l)})
