@@ -1,0 +1,273 @@
+"""A serving cell: ``serve.run(build_llm_app(cfg, server_cls=...))`` and
+a streaming handle, loaded from this one process.
+
+Open loop: a pacer thread sends each request at its due time whatever
+the state of earlier ones; every time is taken from when the request
+was DUE, so a stall is charged to the requests behind it, and how late
+the pacer ran is reported. Closed loop: ``clients`` threads, each
+sending its next request when the last answer is complete.
+"""
+
+from __future__ import annotations
+
+import statistics
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+from . import spec, traffic
+
+CALL_TIMEOUT_S = 900
+
+
+class _Record:
+    __slots__ = ("request", "due", "sent", "token_times", "tokens", "error")
+
+    def __init__(self, request):
+        self.request = request
+        self.due = self.sent = 0.0
+        self.token_times = []
+        self.tokens = []
+        self.error = None
+
+
+def _send(stream, record: _Record, prompt) -> None:
+    """One request: tokens as they arrive at the client."""
+    record.sent = time.perf_counter()
+    try:
+        for token in stream.generate_stream.remote(
+                prompt, max_tokens=record.request.max_tokens,
+                temperature=0.0):
+            record.token_times.append(time.perf_counter())
+            record.tokens.append(int(token))
+    except Exception as e:  # a failed request is a result, not a crash
+        record.error = f"{type(e).__name__}: {e}"[:300]
+
+
+def _open_loop(stream, requests, prompts, seconds, max_in_flight, on_window):
+    """Returns the records, the window's start and end on perf_counter,
+    and the pool and futures still to be waited for."""
+    records = [_Record(r) for r in requests]
+    ramp_s = -min([r.due_s for r in requests] + [0.0])
+    pool = ThreadPoolExecutor(max_workers=max_in_flight)
+    origin = time.perf_counter() + ramp_s + 0.05  # the window's start
+    window_open = False
+    futures = []
+    for record, prompt in zip(records, prompts):
+        record.due = origin + record.request.due_s
+        if record.request.due_s >= 0 and not window_open:
+            _sleep_until(origin)
+            on_window()
+            window_open = True
+        _sleep_until(record.due)
+        futures.append(pool.submit(_send, stream, record, prompt))
+    _sleep_until(origin + seconds)
+    return records, origin, origin + seconds, pool, futures
+
+
+def _sleep_until(t: float) -> None:
+    """Sleeps in short naps, and yields without sleeping over the last
+    two milliseconds, so that a send is not late by a nap's overshoot."""
+    while (left := t - time.perf_counter()) > 0:
+        time.sleep(min(left, 0.05) if left > 0.002 else 0)
+
+
+def _closed_loop(stream, requests, prompts, seconds, clients, on_window):
+    """The pool in its seeded order, round again if it runs out."""
+    records = []
+    lock = threading.Lock()
+    state = {"stop": False}
+
+    def client():
+        while True:
+            with lock:
+                if state["stop"]:
+                    return
+                i = len(records) % len(requests)
+                record = _Record(requests[i])
+                records.append(record)
+            record.due = time.perf_counter()
+            _send(stream, record, prompts[i])
+
+    pool = ThreadPoolExecutor(max_workers=clients)
+    on_window()
+    origin = time.perf_counter()
+    futures = [pool.submit(client) for _ in range(clients)]
+    _sleep_until(origin + seconds)
+    with lock:
+        state["stop"] = True
+    return records, origin, origin + seconds, pool, futures
+
+
+def run(cell: dict, args, per_layer: dict) -> dict:
+    from ray_tpu import serve
+    from ray_tpu.llm import build_llm_app
+
+    from .server import BenchLLMServer, SeededLLMConfig
+
+    hp, tr, sv = cell["hp"], cell["traffic"], cell["serve"]
+    phases = {"runner_ready": time.time()}  # where set-up goes, wall clock
+    llm_config = SeededLLMConfig(
+        model_config=spec.llama_config(hp),
+        max_batch_size=sv["max_batch_size"], max_seq_len=sv["max_seq_len"],
+        accelerator_type="" if args.rehearse else "TPU",
+        engine_kwargs=sv.get("engine_kwargs", {}),
+        seed=args.seed, rehearse=args.rehearse)
+    app = build_llm_app(llm_config, server_cls=BenchLLMServer)
+    # more request threads than slots plus the deepest queue, so that a
+    # health ping never waits behind the generations
+    app = app.deployment.options(
+        num_replicas=1, max_ongoing_requests=sv["max_ongoing_requests"],
+    ).bind(*app.args)
+    handle = serve.run(app).options(request_timeout_s=CALL_TIMEOUT_S)
+    stream = handle.options(stream=True)
+    phases["replica_up"] = time.time()  # chip open, weights, engine
+
+    def call(method, *a):
+        return getattr(handle, method).remote(*a).result(
+            timeout_s=CALL_TIMEOUT_S)
+
+    notes = {}
+    try:
+        # -- set-up: the traffic, the programs, the checks -------------
+        open_loop = tr["loop"] == "open"
+        requests = (traffic.open_loop(tr, args.seed, args.seconds)
+                    if open_loop else traffic.closed_loop(tr, args.seed))
+        prompts = [traffic.prompt_tokens(args.seed, r, hp["vocab_size"])
+                   for r in requests]
+        notes["offered"] = traffic.offered(requests)
+        warm = call("warm_up", sv["warm_up_prompt_lens"])
+        phases["warmed_up"] = time.time()
+        check = sv["reference_check"]
+        ref = call("reference_check", args.seed, hp, check["length"],
+                   check["decode_steps"])
+        notes["reference"] = ref
+        phases["reference_done"] = time.time()
+        probe = traffic.prompt_tokens(
+            args.seed, traffic.Request(10**6, 0.0, tr["probe_prompt_len"], 8),
+            hp["vocab_size"])
+        probes = [list(stream.generate_stream.remote(
+            probe, max_tokens=8, temperature=0.0)) for _ in range(2)]
+
+        phases["probes_done"] = time.time()
+
+        # -- the window -------------------------------------------------
+        window = {}
+
+        def on_window():
+            call("begin_window")
+            window["wall"] = phases["window"] = time.time()
+
+        tracing = threading.Event()
+        if args.trace:
+            def trace_part():
+                # the last seconds of the window, still under load
+                lead = min(sv["trace_seconds"] + 1.0, args.seconds * 0.6)
+                while "wall" not in window:
+                    time.sleep(0.01)
+                time.sleep(max(args.seconds - lead, 0.0))
+                call("trace_start")
+                time.sleep(min(sv["trace_seconds"], lead * 0.8))
+                window["traced_s"] = call("trace_stop")
+                tracing.set()
+
+            threading.Thread(target=trace_part, daemon=True).start()
+
+        if open_loop:
+            records, t0, t1, pool, futures = _open_loop(
+                stream, requests, prompts, args.seconds,
+                sv["max_ongoing_requests"], on_window)
+        else:
+            records, t0, t1, pool, futures = _closed_loop(
+                stream, requests, prompts, args.seconds, tr["clients"],
+                on_window)
+        at_end = call("end_window")
+        # every request that was sent is waited for: a tail is the tail
+        # of all requests of the window
+        pool.shutdown(wait=True)
+        for f in futures:
+            f.result()
+        if args.trace:
+            tracing.wait(timeout=120)
+    except BaseException:
+        serve.shutdown()
+        raise
+
+    # -- samples, as the client saw them --------------------------------
+    in_window = [r for r in records if r.due >= t0 - 1e-9]
+    ttft, itl, late = [], [], []
+    done_tokens = done = failed = 0
+    lengths_ok = vocab_ok = True
+    for r in in_window:
+        late.append(1e3 * (r.sent - r.due))
+        if r.error or not r.token_times:
+            failed += 1
+            continue
+        lengths_ok &= len(r.tokens) == r.request.max_tokens
+        vocab_ok &= all(0 <= t < hp["vocab_size"] for t in r.tokens)
+        ttft.append(1e3 * (r.token_times[0] - r.due))
+        itl.extend(1e3 * (b - a) for a, b in
+                   zip(r.token_times, r.token_times[1:]))
+        if r.token_times[-1] <= t1:
+            done += 1
+            done_tokens += r.request.prompt_len + len(r.tokens)
+    half = (t0 + t1) / 2
+    samples = {
+        **at_end["samples"],
+        "window_s": t1 - t0,
+        "tokens_in_window": done_tokens,
+        "ttft_ms": ttft, "itl_ms": itl,
+    }
+    result = {
+        "attempted": len(in_window), "failed": failed, "samples": samples,
+        "window_start_wall": window["wall"], "device": at_end["device"],
+        "per_layer": {}, "breakdown": None,
+    }
+    if args.trace:
+        slim = {k: v for k, v in samples.items()
+                if k not in ("ttft_ms", "itl_ms")}
+        slim["traced_s"] = window.get("traced_s")
+        report = call("trace_report", per_layer, cell, slim)
+        result["device"] = {**result["device"], **report["device"]}
+        result["per_layer"] = report["per_layer"]
+        result["breakdown"] = report["breakdown"]
+    serve.shutdown()
+
+    tol = sv["reference_check"]
+    result["checks"] = {
+        "every_request_answered": failed == 0 and len(in_window) > 0,
+        "every_answer_full_length": lengths_ok,
+        "tokens_in_vocabulary": vocab_ok,
+        "equal_prompts_equal_tokens":
+            probes[0] == probes[1] and len(probes[0]) == 8,
+        "nothing_compiled_in_window": at_end["compiled_in_window"] == 0,
+        "engine_matches_reference": ref["finite"]
+            and ref["prefill_rel_rms"] <= tol["rel_rms_tol"]
+            and ref["after_decode_rel_rms"] <= tol["rel_rms_tol"]
+            and ref["decode_choice_gap"] <= tol["choice_gap_tol"],
+        "on_one_chip": warm["count"] == cell["chips"],
+    }
+
+    def med(xs):
+        return statistics.median(xs) if xs else None
+
+    notes.update({
+        "requests_in_window": len(in_window), "completed_in_window": done,
+        "arrived_second_half": sum(r.due >= half for r in in_window),
+        "completed_second_half": sum(
+            bool(r.token_times) and half <= r.token_times[-1] <= t1
+            for r in records),
+        "ttft_ms_median": med(ttft), "ttft_samples": len(ttft),
+        "itl_ms_median": med(itl), "itl_samples": len(itl),
+        "generator_late_ms_median": med(late),
+        "generator_late_ms_max": max(late) if late else None,
+        "engine_step_ms_median": med(at_end["samples"]["engine_step_ms"]),
+        "engine_steps": at_end["samples"]["engine_steps"],
+        "shards": at_end["shards"], "peak_active": at_end["peak_active"],
+        "compiled_in_window": at_end["compiled_in_window"],
+    })
+    # seconds from the runner's start to the end of each phase
+    notes["setup_phases_s"] = {k: round(v - args.process_start, 3)
+                               for k, v in phases.items()}
+    result["notes"] = notes
+    return result
