@@ -459,6 +459,11 @@ def serve_phase(shapes: Shapes, n_chips: int) -> dict:
         "compile_s": round(warm_s, 1),
         "run_s": round(run_s, 3),
         "peak_active_per_replica": [s["peak_active"] for s in stats],
+        # EngineStats, per replica: what the steps did, the share of
+        # decode lanes that held a request, host milliseconds a step
+        # outside the two waits on the device, and the slowest request's
+        # four phases
+        "engine_per_replica": [_engine_summary(s["engine"]) for s in stats],
     }
     report["checks"] = {
         "every_request_full_length": all(
@@ -480,6 +485,37 @@ def serve_phase(shapes: Shapes, n_chips: int) -> dict:
     }
     serve.shutdown()
     return report
+
+
+def _engine_summary(engine: dict) -> dict:
+    """The fields of ``engine_stats()["engine"]`` an operator reads
+    first, from one snapshot (cumulative since the replica started)."""
+    from ray_tpu.llm._internal.engine import REQUEST_PHASES
+
+    seconds = {n: p["seconds"] for n, p in engine["phases"].items()}
+    steps = max(engine["steps"], 1)
+    host_s = (seconds.get("llm.step", 0.0)
+              - seconds.get("llm.first_token_sync", 0.0)
+              - seconds.get("llm.decode_sync", 0.0))
+    slowest = max(engine["requests"], key=lambda r: sum(r[1:5]),
+                  default=None)
+    return {
+        "steps": engine["steps"],
+        "tokens_emitted": engine["tokens_emitted"],
+        "prefill_chunks": engine["prefill_chunks"],
+        "prefill_tokens": engine["prefill_tokens"],
+        "decode_calls": engine["decode_calls"],
+        "decode_occupancy_pct": round(
+            100.0 * engine["decode_lanes_active"]
+            / max(engine["decode_lanes_total"], 1), 1),
+        "shards_grown": engine["shards_grown"],
+        "requests_finished": engine["requests_finished"],
+        "requests_refused": engine["requests_refused"],
+        "host_ms_per_step": round(1e3 * host_s / steps, 3),
+        "slowest_request_phases_ms": slowest and {
+            name: round(1e3 * s, 1)
+            for name, s in zip(REQUEST_PHASES, slowest[1:5])},
+    }
 
 
 # ----------------------------------------------------------------- driver

@@ -241,7 +241,18 @@ class Dataset:
             ),
             drop_last=drop_last,
             device_put=device_put,
+            stats=self.iter_stats,
         )
+
+    @property
+    def iter_stats(self):
+        """Cumulative seconds and counts of this dataset's
+        ``iter_batches``: ``data.stage_batch`` (making one batch and
+        putting it on the device, in the prefetch thread) and
+        ``data.next_batch`` (the consumer's wait for it)."""
+        from ray_tpu.util.tracing import PhaseStats
+
+        return self.__dict__.setdefault("_iter_stats", PhaseStats())
 
     def iter_torch_batches(self, **kwargs) -> Iterator[Any]:
         import torch

@@ -5,15 +5,24 @@ conversion, prefetching). TPU-native: ``device_put`` stages the next
 batch into device memory while the current one is being consumed
 (double buffering over the host->HBM DMA), which is how a training loop
 hides input latency behind compute.
+
+Two ``tracing.phase`` spans say where a batch's time goes:
+``data.stage_batch`` in the prefetch thread (get -> concat -> slice ->
+contiguous -> ``device_put`` of one batch) and ``data.next_batch`` on
+the consumer's side (its wait on the queue). Their seconds and counts
+add up in ``stats`` (``Dataset.iter_stats``), one count a batch.
 """
 
 from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Any, Iterator, List, Optional
 
 import numpy as np
+
+from ray_tpu.util.tracing import PhaseStats, phase
 
 from .block import Block, BlockAccessor
 
@@ -59,19 +68,38 @@ def iter_batches(
     prefetch_batches: int,
     drop_last: bool,
     device_put: Any = None,
+    stats: Optional[PhaseStats] = None,
 ) -> Iterator[Any]:
-    def produce() -> Iterator[Any]:
-        for block in _rebatch(block_refs, batch_size, drop_last):
-            batch = BlockAccessor.for_block(block).to_batch(batch_format)
-            if device_put is not None:
-                import jax
+    def timed(name: str, fn):
+        """fn() under the span ``name``; counted unless it returns the
+        sentinel (the probe that finds the data exhausted is no batch)."""
+        t0 = time.perf_counter()
+        with phase(name):
+            item = fn()
+        if stats is not None and item is not _SENTINEL:
+            stats.add(name, time.perf_counter() - t0)
+        return item
 
-                batch = jax.tree.map(
-                    lambda v: jax.device_put(np.ascontiguousarray(v), device_put)
-                    if isinstance(v, np.ndarray) and v.dtype != object
-                    else v,
-                    batch,
-                )
+    blocks = _rebatch(block_refs, batch_size, drop_last)
+
+    def stage():
+        block = next(blocks, _SENTINEL)
+        if block is _SENTINEL:
+            return _SENTINEL
+        batch = BlockAccessor.for_block(block).to_batch(batch_format)
+        if device_put is not None:
+            import jax
+
+            batch = jax.tree.map(
+                lambda v: jax.device_put(np.ascontiguousarray(v), device_put)
+                if isinstance(v, np.ndarray) and v.dtype != object
+                else v,
+                batch,
+            )
+        return batch
+
+    def produce() -> Iterator[Any]:
+        while (batch := timed("data.stage_batch", stage)) is not _SENTINEL:
             yield batch
 
     if prefetch_batches <= 0:
@@ -109,10 +137,7 @@ def iter_batches(
     t = threading.Thread(target=worker, daemon=True, name="data-prefetch")
     t.start()
     try:
-        while True:
-            item = q.get()
-            if item is _SENTINEL:
-                break
+        while (item := timed("data.next_batch", q.get)) is not _SENTINEL:
             yield item
         if err:
             raise err[0]

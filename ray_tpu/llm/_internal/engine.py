@@ -18,17 +18,29 @@ re-designed for XLA instead of wrapped:
 - KV cache is preallocated per shard (L, B, max_seq, KVH, hd);
   per-slot lengths mask attention (models/llama.py forward_with_cache).
 - Sampling (greedy / temperature) is jitted with the decode step.
+- Observation: every request carries five monotonic stamps (its four
+  phases: queue_wait, prefill_wait, prefill, decode), ``EngineStats``
+  counts what the steps did, and each part of ``step()`` is a
+  ``tracing.phase`` span (``ray_tpu.llm.*`` in a profiler trace, on the
+  device's clock). The jitted programs carry ``kv_slice`` / ``kv_merge``
+  / ``sample`` scopes next to the model's own.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from ray_tpu.util.tracing import PhaseStats, phase
+
+# a request's phases, each the gap between two of its stamps
+REQUEST_PHASES = ("queue_wait", "prefill_wait", "prefill", "decode")
 
 
 @dataclass
@@ -45,6 +57,24 @@ class GenRequest:
     prefill_pos: int = 0  # prompt tokens already written to cache
     generated: List[int] = field(default_factory=list)
     done: bool = False
+    # time.monotonic() stamps, 0.0 until reached: put on the server's
+    # pending queue, given a slot, first chunk dispatched, first token
+    # emitted, finished
+    submitted: float = 0.0
+    admitted: float = 0.0
+    prefill_started: float = 0.0
+    first_token: float = 0.0
+    finished: float = 0.0
+    prefill_chunks: int = 0
+
+    def phases(self) -> Dict[str, float]:
+        """Seconds in queue_wait (no slot yet), prefill_wait (a slot,
+        behind the other prompts of its shard), prefill and decode; they
+        sum to finished - submitted."""
+        stamps = (self.submitted, self.admitted, self.prefill_started,
+                  self.first_token, self.finished)
+        return {name: b - a for name, a, b in
+                zip(REQUEST_PHASES, stamps, stamps[1:])}
 
 
 @dataclass
@@ -54,8 +84,37 @@ class _Shard:
     cache: Any
     lengths: np.ndarray
     free_slots: List[int]
+    index: int = 0
     active: Dict[int, GenRequest] = field(default_factory=dict)
     prefilling: "deque[GenRequest]" = field(default_factory=deque)
+
+
+class EngineStats:
+    """What the engine has done since it was built. Written under the
+    engine's lock; every number only ever rises, so a reader takes two
+    snapshots and subtracts. ``phases`` holds the seconds and counts of
+    the parts of ``step()``; ``requests`` the last few thousand finished
+    requests as (submitted stamp, queue_wait, prefill_wait, prefill,
+    decode seconds, prefill chunks)."""
+
+    COUNTERS = (
+        "steps", "tokens_emitted",
+        "prefill_chunks", "prefill_tokens",  # real tokens, not the bucket
+        "decode_calls", "decode_lanes_active", "decode_lanes_total",
+        "shards_grown", "requests_finished", "requests_refused",
+    )
+
+    def __init__(self, ring: int = 4096):
+        for name in self.COUNTERS:
+            setattr(self, name, 0)
+        self.phases = PhaseStats()
+        self.requests: "deque[tuple]" = deque(maxlen=ring)
+
+    def snapshot(self) -> Dict[str, Any]:
+        out: Dict[str, Any] = {n: getattr(self, n) for n in self.COUNTERS}
+        out["phases"] = self.phases.snapshot()
+        out["requests"] = list(self.requests)
+        return out
 
 
 class LlamaEngine:
@@ -95,7 +154,9 @@ class LlamaEngine:
         self._jax = jax
         self._jnp = jnp
         self._llama = llama
-        self.shards: List[_Shard] = [self._new_shard()]
+        self.stats = EngineStats()
+        self.shards: List[_Shard] = []
+        self.shards.append(self._new_shard())
         # most slots one decode call has advanced so far: whether
         # requests were ever batched, not merely queued
         self.peak_active = 0
@@ -108,7 +169,6 @@ class LlamaEngine:
             b *= 2
         self.buckets.append(self.prefill_chunk)
 
-        @partial(jax.jit, static_argnames=("bucket",))
         def prefill(params, cache, tokens, slot_onehot, start, length, bucket):
             # tokens (1, bucket) padded; writes into the slot's rows at
             # offset `start` and returns logits at the chunk's last real
@@ -123,41 +183,46 @@ class LlamaEngine:
 
         def cache_slice(cache, slot_onehot):
             # gather the single slot (1, S, KVH, hd) per layer
-            idx = jnp.argmax(slot_onehot)
-            return {
-                "k": jax.lax.dynamic_slice_in_dim(cache["k"], idx, 1, axis=1),
-                "v": jax.lax.dynamic_slice_in_dim(cache["v"], idx, 1, axis=1),
-            }
+            with jax.named_scope("kv_slice"):
+                idx = jnp.argmax(slot_onehot)
+                return {
+                    "k": jax.lax.dynamic_slice_in_dim(cache["k"], idx, 1, axis=1),
+                    "v": jax.lax.dynamic_slice_in_dim(cache["v"], idx, 1, axis=1),
+                }
 
         def cache_merge(cache, updated, slot_onehot):
-            idx = jnp.argmax(slot_onehot)
-            return {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], updated["k"], idx, axis=1
-                ),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], updated["v"], idx, axis=1
-                ),
-            }
+            with jax.named_scope("kv_merge"):
+                idx = jnp.argmax(slot_onehot)
+                return {
+                    "k": jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], updated["k"], idx, axis=1
+                    ),
+                    "v": jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], updated["v"], idx, axis=1
+                    ),
+                }
 
-        @jax.jit
         def decode(params, cache, last_tokens, lengths, temps, rng):
             # one token for every slot: tokens (B,), lengths (B,) = count
             # already in cache; inactive slots just waste a lane
             logits, new_cache = llama.forward_with_cache(
                 params, last_tokens[:, None], cache, lengths, config
             )
-            logits = logits[:, 0]  # (B, V)
-            greedy = jnp.argmax(logits, axis=-1)
-            keys = jax.random.split(rng, logits.shape[0] + 1)
-            sampled = jax.vmap(
-                lambda k, lg, t: jax.random.categorical(k, lg / jnp.maximum(t, 1e-4))
-            )(keys[1:], logits, temps)
-            toks = jnp.where(temps > 0, sampled, greedy)
-            return toks.astype(jnp.int32), new_cache, keys[0]
+            with jax.named_scope("sample"):
+                logits = logits[:, 0]  # (B, V)
+                greedy = jnp.argmax(logits, axis=-1)
+                keys = jax.random.split(rng, logits.shape[0] + 1)
+                sampled = jax.vmap(
+                    lambda k, lg, t: jax.random.categorical(
+                        k, lg / jnp.maximum(t, 1e-4))
+                )(keys[1:], logits, temps)
+                toks = jnp.where(temps > 0, sampled, greedy)
+                return toks.astype(jnp.int32), new_cache, keys[0]
 
-        self._prefill = prefill
-        self._decode = decode
+        self._prefill = jax.jit(prefill, static_argnames=("bucket",))
+        self._decode = jax.jit(decode)
+        self._program_fns = (prefill, decode)  # for compiled_programs()
+        self._buckets_run: set = set()
         self._lock = threading.Lock()
 
     def _new_shard(self) -> _Shard:
@@ -167,6 +232,7 @@ class LlamaEngine:
             ),
             lengths=np.zeros(self.max_batch, dtype=np.int32),
             free_slots=list(range(self.max_batch)),
+            index=len(self.shards),
         )
 
     # ------------------------------------------------------------------
@@ -194,12 +260,9 @@ class LlamaEngine:
             dropped = self.in_flight_requests()
             for s in self.shards:
                 for slot in list(s.active):
-                    self._finish(s, slot)
+                    self._release(s, s.active.pop(slot))
                 while s.prefilling:
-                    req = s.prefilling.popleft()
-                    req.done = True
-                    s.lengths[req.slot] = 0
-                    s.free_slots.append(req.slot)
+                    self._release(s, s.prefilling.popleft())
             return dropped
 
     def add_request(self, req: GenRequest) -> bool:
@@ -216,21 +279,33 @@ class LlamaEngine:
             )
             if si is None:
                 if len(self.shards) * self.max_batch >= self.max_slots:
+                    self.stats.requests_refused += 1
                     return False
                 self.shards.append(self._new_shard())  # slot growth
+                self.stats.shards_grown += 1
                 si = len(self.shards) - 1
             shard = self.shards[si]
             req.slot = shard.free_slots.pop()
             req.shard = si
             req.prefill_pos = 0
+            req.admitted = time.monotonic()
+            if not req.submitted:  # handed to the engine directly
+                req.submitted = req.admitted
             shard.prefilling.append(req)
             return True
 
+    def _release(self, shard: _Shard, req: GenRequest):
+        req.done = True
+        shard.lengths[req.slot] = 0
+        shard.free_slots.append(req.slot)
+
     def _finish(self, shard: _Shard, slot: int):
         req = shard.active.pop(slot)
-        req.done = True
-        shard.lengths[slot] = 0
-        shard.free_slots.append(slot)
+        self._release(shard, req)
+        req.finished = time.monotonic()
+        self.stats.requests_finished += 1
+        self.stats.requests.append(
+            (req.submitted, *req.phases().values(), req.prefill_chunks))
 
     def _pump_prefill(self, shard: _Shard, out: List[Tuple[GenRequest, int]]):
         """Write ONE chunk of the oldest pending prompt into the cache;
@@ -239,30 +314,41 @@ class LlamaEngine:
         if not shard.prefilling:
             return
         req = shard.prefilling[0]
-        n = len(req.prompt_ids)
-        pos = req.prefill_pos
-        chunk = min(self.prefill_chunk, n - pos)
-        bucket = next(b for b in self.buckets if b >= chunk)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :chunk] = req.prompt_ids[pos:pos + chunk]
-        onehot = np.zeros(self.max_batch, np.float32)
-        onehot[req.slot] = 1.0
-        last_logits, shard.cache = self._prefill(
-            self.params, shard.cache, tokens, onehot,
-            np.asarray([pos], np.int32), chunk, bucket=bucket,
-        )
+        stats = self.stats
+        ids = {"request_id": req.request_id, "shard": shard.index}
+        with phase("llm.prefill_dispatch", stats.phases, **ids):
+            n = len(req.prompt_ids)
+            pos = req.prefill_pos
+            chunk = min(self.prefill_chunk, n - pos)
+            bucket = next(b for b in self.buckets if b >= chunk)
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :chunk] = req.prompt_ids[pos:pos + chunk]
+            onehot = np.zeros(self.max_batch, np.float32)
+            onehot[req.slot] = 1.0
+            if not req.prefill_started:
+                req.prefill_started = time.monotonic()
+            last_logits, shard.cache = self._prefill(
+                self.params, shard.cache, tokens, onehot,
+                np.asarray([pos], np.int32), chunk, bucket=bucket,
+            )
+        self._buckets_run.add(bucket)
         req.prefill_pos = pos + chunk
+        req.prefill_chunks += 1
+        stats.prefill_chunks += 1
+        stats.prefill_tokens += chunk
         if req.prefill_pos < n:
             return
         # prompt complete: first generated token from the last logits
         shard.prefilling.popleft()
-        lg = np.asarray(last_logits)
-        if req.temperature > 0:
-            self._rng, sub = self._jax.random.split(self._rng)
-            tok = int(self._jax.random.categorical(
-                sub, self._jnp.asarray(lg) / max(req.temperature, 1e-4)))
-        else:
-            tok = int(lg.argmax())
+        with phase("llm.first_token_sync", stats.phases, **ids):
+            lg = np.asarray(last_logits)
+            if req.temperature > 0:
+                self._rng, sub = self._jax.random.split(self._rng)
+                tok = int(self._jax.random.categorical(
+                    sub, self._jnp.asarray(lg) / max(req.temperature, 1e-4)))
+            else:
+                tok = int(lg.argmax())
+        req.first_token = time.monotonic()
         req.generated.append(tok)
         shard.lengths[req.slot] = n
         shard.active[req.slot] = req
@@ -277,45 +363,87 @@ class LlamaEngine:
         pending) then one decode for every active slot. Returns
         (request, new_token) pairs emitted this step — the FIRST token
         of a request (sampled off its prefill) arrives here too."""
-        with self._lock:
+        stats = self.stats
+        with self._lock, phase("llm.step", stats.phases):
             out: List[Tuple[GenRequest, int]] = []
             for shard in self.shards:
                 self._pump_prefill(shard, out)
                 if not shard.active:
                     continue
                 self.peak_active = max(self.peak_active, len(shard.active))
-                last = np.zeros(self.max_batch, np.int32)
-                temps = np.zeros(self.max_batch, np.float32)
-                # inactive lanes (free or mid-prefill) still ride the
-                # batched decode; point their cache write at the scratch
-                # row (max_seq-1, provably never attended: sequences
-                # finish before reaching it) so they cannot corrupt a
-                # half-prefilled prompt's rows
-                lens = np.full(self.max_batch, self.max_seq - 1, np.int32)
-                for slot, req in shard.active.items():
-                    last[slot] = req.generated[-1]
-                    temps[slot] = req.temperature
-                    lens[slot] = shard.lengths[slot]
-                toks, shard.cache, self._rng = self._decode(
-                    self.params, shard.cache, last,
-                    lens, temps, self._rng,
-                )
-                toks = np.asarray(toks)
-                for slot in list(shard.active.keys()):
-                    req = shard.active[slot]
-                    # the decode consumed the previous token: account it
-                    shard.lengths[slot] += 1
-                    tok = int(toks[slot])
-                    req.generated.append(tok)
-                    out.append((req, tok))
-                    total_len = shard.lengths[slot] + 1
-                    if (
-                        (req.eos_id is not None and tok == req.eos_id)
-                        or len(req.generated) >= req.max_tokens
-                        or total_len >= self.max_seq - 1
-                    ):
-                        self._finish(shard, slot)
+                with phase("llm.decode_prepare", stats.phases,
+                           shard=shard.index):
+                    last = np.zeros(self.max_batch, np.int32)
+                    temps = np.zeros(self.max_batch, np.float32)
+                    # inactive lanes (free or mid-prefill) still ride the
+                    # batched decode; point their cache write at the
+                    # scratch row (max_seq-1, provably never attended:
+                    # sequences finish before reaching it) so they cannot
+                    # corrupt a half-prefilled prompt's rows
+                    lens = np.full(self.max_batch, self.max_seq - 1, np.int32)
+                    for slot, req in shard.active.items():
+                        last[slot] = req.generated[-1]
+                        temps[slot] = req.temperature
+                        lens[slot] = shard.lengths[slot]
+                with phase("llm.decode_dispatch", stats.phases,
+                           shard=shard.index):
+                    toks, shard.cache, self._rng = self._decode(
+                        self.params, shard.cache, last,
+                        lens, temps, self._rng,
+                    )
+                stats.decode_calls += 1
+                stats.decode_lanes_active += len(shard.active)
+                stats.decode_lanes_total += self.max_batch
+                with phase("llm.decode_sync", stats.phases,
+                           shard=shard.index):
+                    toks = np.asarray(toks)
+                with phase("llm.decode_bookkeep", stats.phases,
+                           shard=shard.index):
+                    for slot in list(shard.active.keys()):
+                        req = shard.active[slot]
+                        # the decode consumed the previous token: account it
+                        shard.lengths[slot] += 1
+                        tok = int(toks[slot])
+                        req.generated.append(tok)
+                        out.append((req, tok))
+                        total_len = shard.lengths[slot] + 1
+                        if (
+                            (req.eos_id is not None and tok == req.eos_id)
+                            or len(req.generated) >= req.max_tokens
+                            or total_len >= self.max_seq - 1
+                        ):
+                            self._finish(shard, slot)
+            stats.steps += 1
+            stats.tokens_emitted += len(out)
             return out
+
+    def compiled_programs(self) -> Dict[str, Any]:
+        """The engine's programs as compiled executables: ``decode`` and
+        ``prefill_<bucket>`` for each chunk bucket run so far. For
+        reading their text (``jax_utils.scope_map``) next to a device
+        trace. Each is jitted afresh and compiled again (or loaded from
+        the persistent cache; ``compile_with_scopes`` says why), so call
+        this outside anything timed."""
+        from ray_tpu._private.jax_utils import compile_with_scopes
+
+        # new function objects under the old names: new traces, new modules
+        prefill_fn, decode_fn = self._program_fns
+        prefill = self._jax.jit(wraps(prefill_fn)(partial(prefill_fn)),
+                                static_argnames=("bucket",))
+        decode = self._jax.jit(wraps(decode_fn)(partial(decode_fn)))
+        cache = self.shards[0].cache
+        i32, f32 = np.int32, np.float32
+        decode_args = (
+            np.zeros(self.max_batch, i32), np.zeros(self.max_batch, i32),
+            np.zeros(self.max_batch, f32), self._rng)
+        out = {"decode": compile_with_scopes(decode.lower(
+            self.params, cache, *decode_args))}
+        for bucket in sorted(self._buckets_run):
+            out[f"prefill_{bucket}"] = compile_with_scopes(prefill.lower(
+                self.params, cache, np.zeros((1, bucket), i32),
+                np.zeros(self.max_batch, f32), np.zeros(1, i32), 1,
+                bucket=bucket))
+        return out
 
     # ------------------------------------------------------------------
     def generate(self, prompt_ids: List[int], *, max_tokens: int = 64,

@@ -19,6 +19,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.util import tracing
+
 from .config import LLMConfig
 
 
@@ -62,6 +64,9 @@ class LLMServer:
         self._id_counter = itertools.count()
         self._token_queues: Dict[str, "queue.Queue"] = {}
         self._lock = threading.Lock()
+        # seconds and counts of the batching loop's own parts (llm.admit,
+        # llm.emit, llm.idle_sleep); only the loop's thread adds to them
+        self._loop_phases = tracing.PhaseStats()
         self._running = True
         self._loop_thread = threading.Thread(
             target=self._batching_loop, daemon=True, name="llm-batching"
@@ -180,9 +185,41 @@ class LLMServer:
     # -- continuous batching loop -------------------------------------
     def _batching_loop(self):
         while self._running:
-            # admit as many pending requests as their engines have slots
-            admitted = False
-            requeue = []
+            admitted = self._admit_pending()
+            stepped = False
+            with self._engines_lock:
+                live_engines = list(self._engines.values())
+            for eng in live_engines:
+                if not eng.num_active():
+                    continue
+                stepped = True
+                try:
+                    emitted = eng.step()
+                except Exception as e:
+                    # engine fault: fail every in-flight request, keep serving
+                    for req in eng.abort_all():
+                        q = self._token_queues.get(req.request_id)
+                        if q is not None:
+                            q.put(("error", e))
+                    continue
+                with tracing.phase("llm.emit", self._loop_phases):
+                    for req, tok in emitted:
+                        q = self._token_queues.get(req.request_id)
+                        if q is not None:
+                            q.put(("token", tok))
+                            if req.done:
+                                q.put(("done", req))
+            if not stepped and not admitted:
+                with tracing.phase("llm.idle_sleep", self._loop_phases):
+                    time.sleep(0.005)
+
+    def _admit_pending(self) -> bool:
+        """Admit as many pending requests as their engines have slots."""
+        if self._pending.empty():
+            return False
+        admitted = False
+        requeue = []
+        with tracing.phase("llm.admit", self._loop_phases):
             while True:
                 try:
                     req = self._pending.get_nowait()
@@ -217,30 +254,7 @@ class LLMServer:
                 # prefill completes — nothing to emit at admission
             for req in requeue:
                 self._pending.put(req)
-            stepped = False
-            with self._engines_lock:
-                live_engines = list(self._engines.values())
-            for eng in live_engines:
-                if not eng.num_active():
-                    continue
-                stepped = True
-                try:
-                    emitted = eng.step()
-                except Exception as e:
-                    # engine fault: fail every in-flight request, keep serving
-                    for req in eng.abort_all():
-                        q = self._token_queues.get(req.request_id)
-                        if q is not None:
-                            q.put(("error", e))
-                    continue
-                for req, tok in emitted:
-                    q = self._token_queues.get(req.request_id)
-                    if q is not None:
-                        q.put(("token", tok))
-                        if req.done:
-                            q.put(("done", None))
-            if not stepped and not admitted:
-                time.sleep(0.005)
+        return admitted
 
     # -- request entrypoints ------------------------------------------
     def generate_stream(
@@ -268,6 +282,8 @@ class LLMServer:
         q: "queue.Queue" = queue.Queue()
         with self._lock:
             self._token_queues[rid] = q
+        # set by serve.execute for a sampled request; None otherwise
+        trace_ctx = tracing.current_context()
         self._pending.put(
             GenRequest(
                 request_id=rid,
@@ -276,12 +292,15 @@ class LLMServer:
                 temperature=temperature,
                 eos_id=eos_id,
                 adapter_id=adapter_id or "",
+                submitted=time.monotonic(),
             )
         )
         try:
             while True:
                 kind, tok = q.get(timeout=120)
                 if kind == "done":
+                    if trace_ctx is not None:
+                        self._emit_request_span(trace_ctx, tok)
                     return
                 if kind == "error":
                     raise tok
@@ -289,6 +308,20 @@ class LLMServer:
         finally:
             with self._lock:
                 self._token_queues.pop(rid, None)
+
+    @staticmethod
+    def _emit_request_span(trace_ctx, req) -> None:
+        """One ``llm.request`` span record for a finished request that
+        arrived under a sampled trace: submission to its last token,
+        with its four phases and chunk count as attributes."""
+        tracing._emit(tracing.make_runtime_record(
+            "llm.request", "llm.request", trace_ctx[0], trace_ctx[1],
+            req.submitted, req.finished,
+            attrs={"request_id": req.request_id,
+                   "prefill_chunks": req.prefill_chunks,
+                   **{f"{k}_s": round(v, 6)
+                      for k, v in req.phases().items()}},
+        ))
 
     def generate(self, prompt_ids, max_tokens=64, temperature=0.0,
                  eos_id=None, adapter_id=None) -> List[int]:
@@ -340,6 +373,11 @@ class LLMServer:
             ),
             "max_batch": self.engine.max_batch,
             "shards": len(self.engine.shards),
+            # cumulative counters, seconds per part of step(), and the
+            # finished requests' phases (EngineStats.snapshot); a reader
+            # takes two of these and subtracts
+            "engine": self.engine.stats.snapshot(),
+            "loop_phases": self._loop_phases.snapshot(),
             **device_report(),
         }
 

@@ -278,29 +278,36 @@ def attention_sublayer(config: LlamaConfig, x: jax.Array,
     """Pre-norm GQA attention + residual (shared by the dense and MoE
     model families — fix attention once, both models follow)."""
     c = config
-    h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    attn = _attention(q, k, v, c)
-    return x + jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+    with jax.named_scope("attn"):
+        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        attn = _attention(q, k, v, c)
+        return x + jnp.einsum(
+            "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+
+
+def mlp_sublayer(config: LlamaConfig, x: jax.Array,
+                 layer: Dict[str, jax.Array]) -> jax.Array:
+    """Pre-norm SwiGLU MLP + residual."""
+    c = config
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
+        gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(c.dtype))
+        up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(c.dtype))
+        return x + jnp.einsum(
+            "bsf,fd->bsd", jax.nn.silu(gate) * up,
+            layer["w_down"].astype(c.dtype))
 
 
 def block_fn(config: LlamaConfig, x: jax.Array, layer: Dict[str, jax.Array],
              cos: jax.Array, sin: jax.Array) -> jax.Array:
     """One transformer block. x: (B, S, D) in config.dtype."""
-    c = config
-    x = attention_sublayer(c, x, layer, cos, sin)
-
-    h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-    gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(c.dtype))
-    x = x + jnp.einsum(
-        "bsf,fd->bsd", jax.nn.silu(gate) * up, layer["w_down"].astype(c.dtype)
-    )
-    return x
+    x = attention_sublayer(config, x, layer, cos, sin)
+    return mlp_sublayer(config, x, layer)
 
 
 def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
@@ -309,7 +316,8 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     config.dtype (everything except the lm-head projection)."""
     c = config
     B, S = tokens.shape
-    x = params["embed"].astype(c.dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(c.dtype)[tokens]
     cos, sin = rope_table(c, S)
 
     blk = partial(block_fn, c)
@@ -321,8 +329,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     def scan_body(carry, layer):
         return blk(carry, layer, cos, sin), None
 
-    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-    return rms_norm(x, params["final_norm"], c.norm_eps)
+    with jax.named_scope("layers"):  # alone: the scan's own slicing
+        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+    with jax.named_scope("head"):
+        return rms_norm(x, params["final_norm"], c.norm_eps)
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array,
@@ -334,11 +344,13 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
     """
     c = config
     x = forward_hidden(params, tokens, c)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
-    logits = logits.astype(jnp.float32)
-    if c.logit_softcap:
-        logits = jnp.tanh(logits / c.logit_softcap) * c.logit_softcap
-    return logits
+    with jax.named_scope("head"):
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
+        logits = logits.astype(jnp.float32)
+        if c.logit_softcap:
+            logits = jnp.tanh(logits / c.logit_softcap) * c.logit_softcap
+        return logits
 
 
 def unpack_batch(batch: Dict[str, jax.Array]):
@@ -362,9 +374,10 @@ def masked_mean(nll: jax.Array, mask) -> jax.Array:
 
 
 def masked_ce(logits: jax.Array, targets: jax.Array, mask) -> jax.Array:
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return masked_mean(nll, mask)
+    with jax.named_scope("ce"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return masked_mean(nll, mask)
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
@@ -396,12 +409,13 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
         from ray_tpu.ops.pallas_ce import fused_cross_entropy
 
         x = forward_hidden(params, inputs, c)
-        nll = fused_cross_entropy(
-            x.reshape(B * S, c.dim),
-            params["lm_head"].astype(c.dtype),
-            targets.reshape(B * S),
-        ).reshape(B, S)
-        return masked_mean(nll, mask)
+        with jax.named_scope("ce"):
+            nll = fused_cross_entropy(
+                x.reshape(B * S, c.dim),
+                params["lm_head"].astype(c.dtype),
+                targets.reshape(B * S),
+            ).reshape(B, S)
+            return masked_mean(nll, mask)
     logits = forward(params, inputs, c)
     return masked_ce(logits, targets, mask)
 
@@ -458,45 +472,48 @@ def forward_with_cache(
     c = config
     B, T = tokens.shape
     max_seq = cache["k"].shape[2]
-    x = params["embed"].astype(c.dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(c.dtype)[tokens]
     cos_full, sin_full = rope_table(c, max_seq)
     pos = start_pos[:, None] + jnp.arange(T)[None, :]          # (B, T)
     cos = cos_full[pos]                                         # (B, T, hd/2)
     sin = sin_full[pos]
 
+    # scatter the T new k/v rows into each sequence's slot range
+    def write(cache_b, new_b, start_b):
+        return jax.lax.dynamic_update_slice(
+            cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0)
+        )
+
     def body(x, layer_and_cache):
         layer, k_c, v_c = layer_and_cache
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
+            k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
+            v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            with jax.named_scope("kv_write"):
+                k_c = jax.vmap(write)(k_c, k, start_pos)
+                v_c = jax.vmap(write)(v_c, v, start_pos)
+            with jax.named_scope("attn_cached"):
+                attn = _attention_cached(q, k_c, v_c, pos, c)
+            x = x + jnp.einsum(
+                "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+        return mlp_sublayer(c, x, layer), (k_c, v_c)
 
-        # scatter the T new k/v rows into each sequence's slot range
-        def write(cache_b, new_b, start_b):
-            return jax.lax.dynamic_update_slice(
-                cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0)
-            )
-
-        k_c = jax.vmap(write)(k_c, k, start_pos)
-        v_c = jax.vmap(write)(v_c, v, start_pos)
-        attn = _attention_cached(q, k_c, v_c, pos, c)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
-        h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-        gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(c.dtype))
-        up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(c.dtype))
-        x = x + jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up, layer["w_down"].astype(c.dtype)
+    # ops scoped "layers" and nothing deeper are the scan's own: a layer's
+    # weights and cache sliced out of the stacks, its cache written back
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            body, x, (params["blocks"], cache["k"], cache["v"])
         )
-        return x, (k_c, v_c)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
+        return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
 
 
 def flops_per_token(config: LlamaConfig, seq_len: int) -> float:

@@ -138,11 +138,15 @@ def make_train_step(
     bspec = NamedSharding(mesh, batch_spec(batch_ndim_extra))
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
-        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        # scopes name the ops in a device trace (jax_utils.scope_map)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh), \
+                jax.named_scope("loss_and_grad"):
             loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         metrics = {"loss": loss, "grad_norm": gnorm, "step": state.step + 1}
         return TrainState(state.step + 1, new_params, new_opt), metrics
 

@@ -29,6 +29,17 @@ Traces are queryable via ``list_state("traces")`` /
 dominant stage. The default sample rate is 0: no context rides the
 wire and no runtime span is ever built.
 
+**Device-path phases** (:func:`phase`): the engine, the LLM server's
+batching loop and the data iterator wrap each part of their hot loops in
+``tracing.phase("llm.decode_sync", stats, shard=0)``. That opens a
+``jax.profiler.TraceAnnotation("ray_tpu.llm.decode_sync", shard=0)``, so
+whenever a profiler session is on (``jax.profiler.start_trace``) the span
+lands in the ``.xplane.pb`` host plane on the same clock as the device's
+ops, and adds its duration and a count to ``stats`` (a
+:class:`PhaseStats`) for the operator's counters. There is no switch:
+with no session the annotation is a flag check, and nothing goes to the
+hub.
+
 Clock discipline (graftlint GL008, which covers this file): span
 start/end are positioned in wall time for cross-process stitching, but
 every DURATION comes from ``time.monotonic()`` — each process anchors
@@ -213,6 +224,66 @@ def span(name: str, **attrs: Any):
         _emit(record)
 
 
+class PhaseStats:
+    """Cumulative seconds and counts per phase name. They only ever
+    rise: a reader takes two snapshots and subtracts. Not locked; the
+    owner adds from one thread (or under its own lock) and a reader
+    copies whole dicts."""
+
+    __slots__ = ("seconds", "counts")
+
+    def __init__(self):
+        self.seconds: Dict[str, float] = {}
+        self.counts: Dict[str, int] = {}
+
+    def add(self, name: str, seconds: float) -> None:
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+        self.counts[name] = self.counts.get(name, 0) + 1
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        seconds, counts = dict(self.seconds), dict(self.counts)
+        return {n: {"seconds": s, "count": counts.get(n, 0)}
+                for n, s in seconds.items()}
+
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, on first use
+
+
+class phase:
+    """``with tracing.phase("llm.decode_sync", stats, shard=0): ...``
+
+    A span of the device path. It is a ``TraceAnnotation`` named
+    ``ray_tpu.<name>`` carrying ``ids`` as its stats (a request's
+    ``request_id``, a chunk's or a decode's ``shard``), and when
+    ``stats`` is given its ``time.perf_counter()`` duration and a count
+    are added to ``stats`` under ``name``."""
+
+    __slots__ = ("_name", "_stats", "_annotation", "_t0")
+
+    def __init__(self, name: str, stats: Optional[PhaseStats] = None,
+                 **ids: Any):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _TraceAnnotation = TraceAnnotation
+        self._name = name
+        self._stats = stats
+        self._annotation = _TraceAnnotation("ray_tpu." + name, **ids)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self._stats is not None:
+            self._stats.add(self._name, seconds)
+        return False
+
+
 def traced(name: Optional[str] = None):
     """Decorator form: ``@tracing.traced()`` wraps calls in a span."""
 
@@ -308,6 +379,13 @@ STAGE_PRECEDENCE: Dict[str, int] = {
     "podracer.learner_update": 71,
     "podracer.traj_handoff": 74,
     "podracer.param_sync": 74,
+    # ---- LLM engine (llm/serve.py generate_stream). One span per
+    # sampled request, submission to the last token, with its four
+    # phases (queue_wait, prefill_wait, prefill, decode) as attributes.
+    # It lies inside the replica's handler, so it sits just above
+    # serve.execute: the slice is named for the engine, and batch-wait
+    # or a payload fetch inside the same handler still keep their names.
+    "llm.request": 71,
 }
 
 
@@ -413,6 +491,8 @@ __all__ = [
     "disable",
     "is_enabled",
     "span",
+    "phase",
+    "PhaseStats",
     "traced",
     "current_context",
     "context",

@@ -1,0 +1,423 @@
+"""Observation of the device path: ``tracing.phase`` spans and
+``PhaseStats``, the engine's request stamps and ``EngineStats``, the
+``ray_tpu.llm.*`` events of a profiler trace, the named scopes inside
+the jitted programs and ``jax_utils.scope_map``, and the data
+iterator's counters. All on the CPU: what is checked is what is
+recorded, never how long it took."""
+
+import dataclasses
+import glob
+import os
+import re
+from functools import partial
+
+import numpy as np
+import pytest
+
+from ray_tpu._private.jax_utils import compile_with_scopes, scope_map
+from ray_tpu.llm import GenRequest, LLMConfig, LlamaEngine
+from ray_tpu.llm._internal.engine import REQUEST_PHASES, EngineStats
+from ray_tpu.models import llama
+from ray_tpu.util import tracing
+
+
+def tiny_cfg(**over):
+    return dataclasses.replace(llama.LLAMA_TINY, **{"remat": False, **over})
+
+
+@pytest.fixture(scope="module")
+def tiny_params():
+    import jax
+
+    return llama.init_params(jax.random.PRNGKey(0), tiny_cfg())
+
+
+def make_engine(params, **kw):
+    kw = {"max_batch": 2, "max_seq": 128, "prefill_chunk": 16,
+          "max_slots": 4, **kw}
+    return LlamaEngine(tiny_cfg(), params, **kw)
+
+
+def run_dry(eng):
+    while eng.num_active():
+        eng.step()
+
+
+def scopes_in(path: str) -> set:
+    """The words of a scope path: ``jit(step)/loss_and_grad/jvp(head)/mul``
+    holds loss_and_grad and head (JAX wraps a scope in its transforms)."""
+    return set(re.findall(r"[A-Za-z_][A-Za-z_0-9]*", path))
+
+
+# ------------------------------------------------------------ the primitive
+def test_phase_adds_seconds_and_a_count_and_nests():
+    stats = tracing.PhaseStats()
+    with tracing.phase("outer", stats, shard=1):
+        with tracing.phase("inner", stats, request_id="r0"):
+            pass
+        with tracing.phase("inner", stats):
+            pass
+    with tracing.phase("unrecorded"):
+        pass
+    snap = stats.snapshot()
+    assert {n: v["count"] for n, v in snap.items()} == {"outer": 1, "inner": 2}
+    assert snap["outer"]["seconds"] >= snap["inner"]["seconds"] >= 0.0
+    # a snapshot is a copy: a later phase does not move it
+    with tracing.phase("inner", stats):
+        pass
+    assert snap["inner"]["count"] == 2
+    assert stats.snapshot()["inner"]["count"] == 3
+
+
+def test_llm_request_has_a_stage_precedence():
+    prec = tracing.STAGE_PRECEDENCE
+    assert prec["serve.execute"] < prec["llm.request"] < prec["serve.batch_wait"]
+
+
+# ------------------------------------------------------ request phases
+def test_request_phases_in_order_and_sum_for_a_request_behind_another(
+        tiny_params):
+    """Two prompts land in one shard: the second has its slot at once
+    and waits behind the first one's chunks (prefill_wait)."""
+    import time
+
+    eng = make_engine(tiny_params, max_batch=2)
+    first = GenRequest("first", list(range(1, 41)), max_tokens=3)
+    behind = GenRequest("behind", list(range(1, 20)), max_tokens=3,
+                        submitted=time.monotonic())
+    assert eng.add_request(first) and eng.add_request(behind)
+    assert behind.shard == first.shard
+    run_dry(eng)
+    for req in (first, behind):
+        stamps = [req.submitted, req.admitted, req.prefill_started,
+                  req.first_token, req.finished]
+        assert all(s > 0 for s in stamps)
+        assert stamps == sorted(stamps)
+        phases = req.phases()
+        assert tuple(phases) == REQUEST_PHASES
+        assert sum(phases.values()) == pytest.approx(
+            req.finished - req.submitted, abs=1e-9)
+    # handed to the engine directly: submitted is its admission
+    assert first.phases()["queue_wait"] == 0.0
+    assert behind.phases()["queue_wait"] > 0.0
+    # the first prompt's three chunks were dispatched before its first
+    assert behind.prefill_started >= first.first_token
+    assert behind.phases()["prefill_wait"] > first.phases()["prefill_wait"]
+    assert (first.prefill_chunks, behind.prefill_chunks) == (3, 2)
+    ring = {r[0]: r for r in eng.stats.requests}
+    assert ring[behind.submitted][1:] == (*behind.phases().values(), 2)
+
+
+# --------------------------------------------------------- EngineStats
+def test_engine_stats_equal_the_hand_count(tiny_params):
+    """max_batch 2, at most 4 slots, chunks of 16. Five prompts of 39
+    tokens asking for 4 tokens each: four are admitted (the third grows
+    a shard), the fifth is refused."""
+    eng = make_engine(tiny_params)
+    reqs = [GenRequest(f"r{i}", list(range(1, 40)), max_tokens=4)
+            for i in range(5)]
+    assert [eng.add_request(r) for r in reqs] == [True] * 4 + [False]
+    steps = 0
+    while eng.num_active():
+        eng.step()
+        steps += 1
+    s = eng.stats.snapshot()
+    assert s["shards_grown"] == 1 and s["requests_refused"] == 1
+    assert s["requests_finished"] == 4 and len(s["requests"]) == 4
+    assert s["steps"] == steps
+    # 39 tokens = chunks of 16, 16, 7: real tokens, not the bucket of 16
+    assert s["prefill_chunks"] == 4 * 3
+    assert s["prefill_tokens"] == 4 * 39
+    # every request: 1 token off its prefill, 3 from decodes
+    assert s["tokens_emitted"] == 4 * 4
+    assert s["decode_lanes_active"] == 4 * 3
+    assert s["decode_lanes_total"] == s["decode_calls"] * eng.max_batch
+    # per shard: prompt A decodes alone while B prefills (3 calls), then
+    # B decodes alone (3 calls)
+    assert s["decode_calls"] == 12
+    counts = {n: v["count"] for n, v in s["phases"].items()}
+    assert counts == {
+        "llm.step": steps, "llm.prefill_dispatch": 12,
+        "llm.first_token_sync": 4, "llm.decode_prepare": 12,
+        "llm.decode_dispatch": 12, "llm.decode_sync": 12,
+        "llm.decode_bookkeep": 12}
+    inner = sum(v["seconds"] for n, v in s["phases"].items()
+                if n != "llm.step")
+    assert inner <= s["phases"]["llm.step"]["seconds"]
+    assert set(EngineStats.COUNTERS) <= set(s)
+
+
+def test_engine_programs_are_exposed_for_their_text(tiny_params):
+    eng = make_engine(tiny_params)
+    eng.generate(list(range(1, 20)), max_tokens=2)   # buckets 16 only
+    programs = eng.compiled_programs()
+    assert sorted(programs) == ["decode", "prefill_16"]
+    assert all(scope_map(c) for c in programs.values())
+
+
+def test_engine_programs_carry_scopes_over_a_stale_compile_cache(
+        tiny_params, monkeypatch):
+    """The persistent compile cache keys a program without its metadata.
+    An engine built with every scope switched off fills it; a normal
+    engine of the same shapes then runs those executables, whose text
+    names nothing. compiled_programs() must still carry the scopes, and
+    the instructions of the program that runs."""
+    import contextlib
+
+    import jax
+
+    shapes = {"max_batch": 3, "max_seq": 96}   # no other test's programs
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        make_engine(tiny_params, **shapes).generate(
+            list(range(1, 20)), max_tokens=2)
+    eng = make_engine(tiny_params, **shapes)
+    eng.generate(list(range(1, 20)), max_tokens=2)
+    programs = eng.compiled_programs()
+    words = {k: set().union(*(scopes_in(p) for p in scope_map(c).values()))
+             for k, c in programs.items()}
+    assert {"mlp", "attn", "kv_write", "sample"} <= words["decode"]
+    assert {"mlp", "kv_slice", "kv_merge"} <= words["prefill_16"]
+    # the same instructions as the program the jitted function runs
+    args = (np.zeros(3, np.int32), np.zeros(3, np.int32),
+            np.zeros(3, np.float32), eng._rng)
+    running = eng._decode.lower(
+        eng.params, eng.shards[0].cache, *args).compile().as_text()
+    names = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ", re.M)
+    assert names.findall(running) == names.findall(
+        programs["decode"].as_text())
+
+
+# ------------------------------------------------- the profiler's trace
+def test_profiler_trace_holds_the_engine_spans_nested(tiny_params, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = make_engine(tiny_params)
+    eng.generate([1, 2, 3], max_tokens=2)            # compile outside
+    for i in range(2):
+        assert eng.add_request(
+            GenRequest(f"r{i}", list(range(1, 20)), max_tokens=4))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    for _ in range(3):
+        eng.step()
+    jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = sorted(
+        ((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, dict(ev.stats))
+         for plane in ProfileData.from_file(path).planes
+         for line in plane.lines for ev in line.events
+         if ev.name.startswith("ray_tpu.")), key=lambda e: e[0])
+    names = [e[2] for e in events]
+    steps = [e for e in events if e[2] == "ray_tpu.llm.step"]
+    assert len(steps) == 3
+    # r0: chunks in steps 1 and 2, first token in 2; r1's first chunk and
+    # r0's second decode in step 3
+    assert names.count("ray_tpu.llm.prefill_dispatch") == 3
+    assert names.count("ray_tpu.llm.first_token_sync") == 1
+    for part in ("decode_prepare", "decode_dispatch", "decode_sync",
+                 "decode_bookkeep"):
+        assert names.count(f"ray_tpu.llm.{part}") == 2
+    for start, end, name, ids in events:
+        if name == "ray_tpu.llm.step":
+            continue
+        # every part lies inside one step, and says whose it is
+        assert any(s[0] <= start and end <= s[1] for s in steps), name
+        assert ids["shard"] == 0
+        if "prefill" in name or "first_token" in name:
+            assert ids["request_id"] in ("r0", "r1")
+    in_last = [e[2].rsplit(".", 1)[1] for e in events
+               if steps[2][0] <= e[0] and e[1] <= steps[2][1]]
+    assert in_last == ["step", "prefill_dispatch", "decode_prepare",
+                       "decode_dispatch", "decode_sync", "decode_bookkeep"]
+
+
+# --------------------------------------------------------- named scopes
+def _train_step_text(remat: bool) -> str:
+    import jax
+
+    from ray_tpu import parallel
+
+    cfg = tiny_cfg(remat=remat)
+    mesh = parallel.make_mesh(devices=jax.devices()[:1])
+    opt = parallel.default_optimizer(1e-3)
+    state, state_sh = parallel.create_train_state(
+        mesh, jax.random.PRNGKey(0), partial(llama.init_params, config=cfg),
+        opt, llama.param_specs(cfg))
+    step = parallel.make_train_step(
+        partial(llama.loss_fn, config=cfg), opt, mesh, state_sh)
+    batch = {"tokens": np.zeros((2, 33), np.int32)}
+    return compile_with_scopes(step.lower(state, batch)).as_text()
+
+
+def _engine_text(program: str) -> str:
+    import jax
+
+    eng = make_engine(llama.init_params(jax.random.PRNGKey(0), tiny_cfg()))
+    eng.generate(list(range(1, 20)), max_tokens=2)
+    return eng.compiled_programs()[program].as_text()
+
+
+MODEL_SCOPES = {"embed", "layers", "attn", "mlp", "head"}
+PROGRAM_SCOPES = {
+    "train": (partial(_train_step_text, False),
+              MODEL_SCOPES | {"ce", "loss_and_grad", "optimizer",
+                              "transpose", "jvp"}),
+    "train_remat": (partial(_train_step_text, True),
+                    MODEL_SCOPES | {"ce", "loss_and_grad", "optimizer",
+                                    "rematted_computation"}),
+    "prefill": (partial(_engine_text, "prefill_16"),
+                MODEL_SCOPES | {"kv_slice", "kv_merge", "kv_write",
+                                "attn_cached"}),
+    "decode": (partial(_engine_text, "decode"),
+               MODEL_SCOPES | {"kv_write", "attn_cached", "sample"}),
+}
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAM_SCOPES))
+def test_compiled_programs_carry_their_scopes(program):
+    make_text, want = PROGRAM_SCOPES[program]
+    found = set()
+    for path in scope_map(make_text()).values():
+        found |= scopes_in(path)
+    assert want <= found, sorted(want - found)
+    if program == "train":
+        assert "rematted_computation" not in found
+
+
+FUSED_TEXT = '''HloModule jit_f, is_scheduled=true
+
+%fused_computation.1 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  %multiply.3 = f32[8]{0} multiply(%param_0.1, %param_0.1), metadata={op_name="jit(f)/mlp/mul" stack_frame_id=3}
+  ROOT %add.4 = f32[8]{0} add(%multiply.3, %param_0.1), metadata={op_name="jit(f)/head/add" stack_frame_id=4}
+}
+
+%fused_computation.2 (param_0.2: f32[8]) -> f32[8] {
+  %param_0.2 = f32[8]{0} parameter(0)
+  %negate.1 = f32[8]{0} negate(%param_0.2), metadata={op_name="jit(f)/attn/neg"}
+  ROOT %copy.9 = f32[8]{0} copy(%negate.1)
+}
+
+ENTRY %main.7 (Arg_0.1: f32[8]) -> f32[8] {
+  %Arg_0.1 = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %fusion.1 = f32[8]{0} fusion(%Arg_0.1), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2
+  %copy.2 = f32[8]{0} copy(%fusion.2)
+  ROOT %fusion.3 = f32[8]{0} fusion(%copy.2), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/ce/own"}
+}
+'''
+
+
+def test_scope_map_gives_a_fusion_its_roots_scope():
+    scopes = scope_map(FUSED_TEXT)
+    assert scopes["fusion.1"] == "jit(f)/head/add"      # its root's
+    assert scopes["fusion.2"] == "jit(f)/attn/neg"      # root has none
+    assert scopes["fusion.3"] == "jit(f)/ce/own"        # its own wins
+    assert scopes["multiply.3"] == "jit(f)/mlp/mul"     # inside a fusion
+    assert "copy.2" not in scopes and "param_0.1" not in scopes
+
+
+def test_scope_map_reads_a_compiled_program():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def f(x, w):
+        with jax.named_scope("mlp"):
+            h = jnp.tanh(x @ w)
+        with jax.named_scope("head"):
+            return (h * 2.0).sum()
+
+    compiled = f.lower(jnp.ones((8, 8)), jnp.ones((8, 8))).compile()
+    scopes = scope_map(compiled)
+    assert scopes == scope_map(compiled.as_text())
+    words = set()
+    for path in scopes.values():
+        words |= scopes_in(path)
+    assert {"mlp", "head"} <= words
+
+
+# ------------------------------------------------------ the data iterator
+@pytest.mark.parametrize("prefetch", [2, 0])
+def test_iterator_counters_rise_by_one_a_batch(ray_start_regular, prefetch):
+    import ray_tpu.data
+
+    ds = ray_tpu.data.from_numpy(
+        [np.arange(40).reshape(10, 4) for _ in range(5)], column="tokens")
+    assert ds.iter_stats.snapshot() == {}
+    batches = ds.iter_batches(batch_size=8, prefetch_batches=prefetch)
+    next(batches)
+    first = ds.iter_stats.snapshot()
+    assert first["data.stage_batch"]["count"] >= 1
+    n = 1 + sum(1 for _ in batches)
+    assert n == 7                                   # 50 rows: 6 x 8 + 2
+    snap = ds.iter_stats.snapshot()
+    # the probe that finds the blocks exhausted is not a batch
+    assert snap["data.stage_batch"]["count"] == n
+    if prefetch:
+        assert snap["data.next_batch"]["count"] == n
+        assert first["data.next_batch"]["count"] == 1
+    else:
+        assert "data.next_batch" not in snap
+    assert snap["data.stage_batch"]["seconds"] > 0.0
+
+
+# ----------------------------------------------------------- the server
+@pytest.fixture
+def llm_server():
+    from ray_tpu.llm.serve import LLMServer
+
+    server = LLMServer(LLMConfig(
+        model_config=tiny_cfg(), max_batch_size=2, max_seq_len=64))
+    yield server
+    server.shutdown()
+
+
+@pytest.mark.parametrize("sampled", [False, True])
+def test_generate_sends_a_span_only_under_a_sampled_trace(
+        llm_server, monkeypatch, sampled):
+    """No profiler session, tracing disabled: generate() sends nothing
+    to the hub. Under a trace context (serve.execute sets one for a
+    sampled request) it sends one llm.request record."""
+    sent = []
+    monkeypatch.setattr(tracing, "_emit", sent.append)
+    assert not tracing.is_enabled()
+    if sampled:
+        with tracing.context(("trace0", "parent0")):
+            tokens = llm_server.generate(list(range(1, 20)), max_tokens=3)
+    else:
+        tokens = llm_server.generate(list(range(1, 20)), max_tokens=3)
+    assert len(tokens) == 3
+    if not sampled:
+        assert sent == []
+        return
+    [record] = sent
+    assert record["name"] == "llm.request"
+    assert (record["trace_id"], record["parent_id"]) == ("trace0", "parent0")
+    attrs = record["attrs"]
+    assert attrs["stage"] == "llm.request" and attrs["prefill_chunks"] == "1"
+    phases = [float(attrs[f"{p}_s"]) for p in REQUEST_PHASES]
+    assert all(p >= 0.0 for p in phases)
+    assert sum(phases) == pytest.approx(
+        record["end"] - record["start"], abs=1e-4)
+
+
+def test_engine_stats_call_returns_the_snapshot(llm_server):
+    before = llm_server.engine_stats()
+    assert before["engine"]["requests_finished"] == 0
+    llm_server.generate(list(range(1, 40)), max_tokens=4)
+    after = llm_server.engine_stats()
+    # what it returned before this PR is still there
+    assert {"active", "peak_active", "free_slots", "max_batch", "shards",
+            "platform", "pid"} <= set(after)
+    eng = after["engine"]
+    assert eng["requests_finished"] == 1 and eng["tokens_emitted"] == 4
+    assert eng["prefill_tokens"] == 39
+    [(submitted, *phases, chunks)] = eng["requests"]
+    assert submitted > 0 and len(phases) == 4 and chunks == 1
+    assert after["loop_phases"]["llm.admit"]["count"] == 1
+    assert after["loop_phases"]["llm.emit"]["count"] >= 3
