@@ -15,15 +15,22 @@ re-designed for XLA instead of wrapped:
   the decode of already-running sequences (vLLM's chunked-prefill
   scheduler, reference llm/_internal/batch/stages/vllm_engine_stage.py
   wraps the same idea). Chunk buckets bound compilations.
-- KV cache is preallocated per shard (L, B, max_seq, KVH, hd);
-  per-slot lengths mask attention (models/llama.py forward_with_cache).
+- KV cache is preallocated per shard (L, B, KVH, max_seq, hd) and
+  UPDATED IN PLACE: both programs are jitted with the cache donated,
+  the cached forward carries it through its layer scan and writes only
+  the new rows (a prefill chunk straight into its slot), so a call
+  moves no more of the cache than attention reads. The buffer passed
+  in is gone once a call is dispatched: every caller rebinds
+  ``shard.cache`` from the result, and ``abort_all()`` re-allocates the
+  cache of a shard whose failed call took it along. Per-slot lengths
+  mask attention (models/llama.py forward_with_cache).
 - Sampling (greedy / temperature) is jitted with the decode step.
 - Observation: every request carries five monotonic stamps (its four
   phases: queue_wait, prefill_wait, prefill, decode), ``EngineStats``
   counts what the steps did, and each part of ``step()`` is a
   ``tracing.phase`` span (``ray_tpu.llm.*`` in a profiler trace, on the
-  device's clock). The jitted programs carry ``kv_slice`` / ``kv_merge``
-  / ``sample`` scopes next to the model's own.
+  device's clock). The jitted programs carry a ``sample`` scope next to
+  the model's own (``kv_write``, ``kv_slice``, ``attn_cached``, ...).
 """
 
 from __future__ import annotations
@@ -175,32 +182,10 @@ class LlamaEngine:
             # token (used only when the chunk completes the prompt)
             del bucket
             logits, new_cache = llama.forward_with_cache(
-                params, tokens, cache_slice(cache, slot_onehot), start, config
+                params, tokens, cache, start, config,
+                slot=jnp.argmax(slot_onehot),
             )
-            new_cache = cache_merge(cache, new_cache, slot_onehot)
-            last = logits[0, length - 1]
-            return last, new_cache
-
-        def cache_slice(cache, slot_onehot):
-            # gather the single slot (1, S, KVH, hd) per layer
-            with jax.named_scope("kv_slice"):
-                idx = jnp.argmax(slot_onehot)
-                return {
-                    "k": jax.lax.dynamic_slice_in_dim(cache["k"], idx, 1, axis=1),
-                    "v": jax.lax.dynamic_slice_in_dim(cache["v"], idx, 1, axis=1),
-                }
-
-        def cache_merge(cache, updated, slot_onehot):
-            with jax.named_scope("kv_merge"):
-                idx = jnp.argmax(slot_onehot)
-                return {
-                    "k": jax.lax.dynamic_update_slice_in_dim(
-                        cache["k"], updated["k"], idx, axis=1
-                    ),
-                    "v": jax.lax.dynamic_update_slice_in_dim(
-                        cache["v"], updated["v"], idx, axis=1
-                    ),
-                }
+            return logits[0, length - 1], new_cache
 
         def decode(params, cache, last_tokens, lengths, temps, rng):
             # one token for every slot: tokens (B,), lengths (B,) = count
@@ -219,17 +204,26 @@ class LlamaEngine:
                 toks = jnp.where(temps > 0, sampled, greedy)
                 return toks.astype(jnp.int32), new_cache, keys[0]
 
-        self._prefill = jax.jit(prefill, static_argnames=("bucket",))
-        self._decode = jax.jit(decode)
+        self._prefill, self._decode = self._jit_programs(prefill, decode)
         self._program_fns = (prefill, decode)  # for compiled_programs()
         self._buckets_run: set = set()
         self._lock = threading.Lock()
 
+    def _jit_programs(self, prefill, decode):
+        """The cache (argument 1) is donated: with the model's carried
+        scan the programs update it in place, and the buffer a caller
+        passed in is gone once the call is dispatched."""
+        jit = self._jax.jit
+        return (jit(prefill, static_argnames=("bucket",), donate_argnums=(1,)),
+                jit(decode, donate_argnums=(1,)))
+
+    def _new_cache(self):
+        return self._llama.init_kv_cache(
+            self.config, self.max_batch, self.max_seq)
+
     def _new_shard(self) -> _Shard:
         return _Shard(
-            cache=self._llama.init_kv_cache(
-                self.config, self.max_batch, self.max_seq
-            ),
+            cache=self._new_cache(),
             lengths=np.zeros(self.max_batch, dtype=np.int32),
             free_slots=list(range(self.max_batch)),
             index=len(self.shards),
@@ -255,7 +249,9 @@ class LlamaEngine:
 
     def abort_all(self) -> List[GenRequest]:
         """Drop every in-flight request (engine fault path); returns
-        them so the caller can fail their waiters."""
+        them so the caller can fail their waiters. A call that failed
+        after it was dispatched has taken the shard's donated cache with
+        it: such a shard gets a new one, so the engine can go on."""
         with self._lock:
             dropped = self.in_flight_requests()
             for s in self.shards:
@@ -263,6 +259,8 @@ class LlamaEngine:
                     self._release(s, s.active.pop(slot))
                 while s.prefilling:
                     self._release(s, s.prefilling.popleft())
+                if any(leaf.is_deleted() for leaf in s.cache.values()):
+                    s.cache = self._new_cache()
             return dropped
 
     def add_request(self, req: GenRequest) -> bool:
@@ -427,10 +425,8 @@ class LlamaEngine:
         from ray_tpu._private.jax_utils import compile_with_scopes
 
         # new function objects under the old names: new traces, new modules
-        prefill_fn, decode_fn = self._program_fns
-        prefill = self._jax.jit(wraps(prefill_fn)(partial(prefill_fn)),
-                                static_argnames=("bucket",))
-        decode = self._jax.jit(wraps(decode_fn)(partial(decode_fn)))
+        prefill, decode = self._jit_programs(
+            *(wraps(fn)(partial(fn)) for fn in self._program_fns))
         cache = self.shards[0].cache
         i32, f32 = np.int32, np.float32
         decode_args = (

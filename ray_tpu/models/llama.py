@@ -29,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -428,9 +428,9 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
 # ---------------------------------------------------------------------
 
 def init_kv_cache(config: LlamaConfig, batch: int, max_seq: int):
-    """Preallocated cache: k/v (L, B, max_seq, KVH, hd) in config.dtype."""
+    """Preallocated cache: k/v (L, B, KVH, max_seq, hd) in config.dtype."""
     c = config
-    shape = (c.n_layers, batch, max_seq, c.n_kv_heads, c.head_dim)
+    shape = (c.n_layers, batch, c.n_kv_heads, max_seq, c.head_dim)
     return {
         "k": jnp.zeros(shape, c.dtype),
         "v": jnp.zeros(shape, c.dtype),
@@ -439,22 +439,21 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_seq: int):
 
 def _attention_cached(q, k_cache, v_cache, pos, config: LlamaConfig):
     """q (B, T, H, hd) new queries at absolute positions ``pos`` (B, T);
-    k/v_cache (B, S, KVH, hd) hold all tokens written so far (including
+    k/v_cache (B, KVH, S, hd) hold all tokens written so far (including
     the new ones). Rows attend to cache slots <= their position."""
     B, T, H, hd = q.shape
-    S = k_cache.shape[1]
-    KVH = k_cache.shape[2]
+    KVH, S = k_cache.shape[1:3]
     G = H // KVH
     qg = q.reshape(B, T, KVH, G, hd)
     scale = 1.0 / math.sqrt(hd)
     logits = jnp.einsum(
-        "btkgh,bskh->bkgts", qg, k_cache,
+        "btkgh,bksh->bkgts", qg, k_cache,
         preferred_element_type=jnp.float32,
     ) * scale
     mask = jnp.arange(S)[None, None, :] <= pos[:, :, None]  # (B, T, S)
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bkgts,bskh->btkgh", probs, v_cache)
+    out = jnp.einsum("bkgts,bksh->btkgh", probs, v_cache)
     return out.reshape(B, T, H, hd)
 
 
@@ -464,29 +463,49 @@ def forward_with_cache(
     cache: Dict[str, jax.Array],
     start_pos: jax.Array,
     config: LlamaConfig,
+    *,
+    slot: Optional[jax.Array] = None,
 ):
     """Incremental forward: tokens (B, T) appended at per-sequence
     offsets ``start_pos`` (B,). Returns (logits (B, T, V) fp32, updated
     cache). T is static (bucketed by the engine); start_pos is traced.
+
+    Row ``b`` of ``tokens`` belongs to row ``b`` of the cache, or to row
+    ``slot + b`` where ``slot`` (a traced scalar) is given: the engine
+    prefills one sequence, tokens (1, T), into its slot of a shard.
+
+    The cache is updated in place: the stacked k/v ride in the layer
+    scan's carry, a layer writes its T new rows into them and reads its
+    own rows for attention out of them. Under a jit that donates the
+    cache nothing else of it moves.
     """
     c = config
     B, T = tokens.shape
-    max_seq = cache["k"].shape[2]
+    _, _, KVH, max_seq, hd = cache["k"].shape
     with jax.named_scope("embed"):
         x = params["embed"].astype(c.dtype)[tokens]
     cos_full, sin_full = rope_table(c, max_seq)
     pos = start_pos[:, None] + jnp.arange(T)[None, :]          # (B, T)
     cos = cos_full[pos]                                         # (B, T, hd/2)
     sin = sin_full[pos]
+    first = 0 if slot is None else slot  # the cache row of tokens' row 0
 
-    # scatter the T new k/v rows into each sequence's slot range
-    def write(cache_b, new_b, start_b):
-        return jax.lax.dynamic_update_slice(
-            cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0)
-        )
+    def write(stack, new, layer):
+        # the T new rows of every sequence, and nothing else
+        new = new.astype(stack.dtype).transpose(0, 2, 1, 3)  # (B, KVH, T, hd)
+        for b in range(B):
+            stack = jax.lax.dynamic_update_slice(
+                stack, new[None, b:b + 1],
+                (layer, first + b, 0, start_pos[b], 0))
+        return stack
 
-    def body(x, layer_and_cache):
-        layer, k_c, v_c = layer_and_cache
+    def read(stack, layer):
+        # this layer's rows of the B sequences (B, KVH, max_seq, hd)
+        return jax.lax.dynamic_slice(
+            stack, (layer, first, 0, 0, 0), (1, B, KVH, max_seq, hd))[0]
+
+    def body(carry, layer):
+        x, k_all, v_all, i = carry
         with jax.named_scope("attn"):
             h = rms_norm(x, layer["attn_norm"], c.norm_eps)
             q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
@@ -495,19 +514,21 @@ def forward_with_cache(
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
             with jax.named_scope("kv_write"):
-                k_c = jax.vmap(write)(k_c, k, start_pos)
-                v_c = jax.vmap(write)(v_c, v, start_pos)
+                k_all = write(k_all, k, i)
+                v_all = write(v_all, v, i)
+            with jax.named_scope("kv_slice"):
+                k_c, v_c = read(k_all, i), read(v_all, i)
             with jax.named_scope("attn_cached"):
                 attn = _attention_cached(q, k_c, v_c, pos, c)
             x = x + jnp.einsum(
                 "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
-        return mlp_sublayer(c, x, layer), (k_c, v_c)
+        return (mlp_sublayer(c, x, layer), k_all, v_all, i + 1), None
 
     # ops scoped "layers" and nothing deeper are the scan's own: a layer's
-    # weights and cache sliced out of the stacks, its cache written back
+    # weights sliced out of the stack
     with jax.named_scope("layers"):
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["blocks"], cache["k"], cache["v"])
+        (x, new_k, new_v, _), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"], jnp.int32(0)), params["blocks"]
         )
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)

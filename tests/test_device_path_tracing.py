@@ -177,7 +177,7 @@ def test_engine_programs_carry_scopes_over_a_stale_compile_cache(
     words = {k: set().union(*(scopes_in(p) for p in scope_map(c).values()))
              for k, c in programs.items()}
     assert {"mlp", "attn", "kv_write", "sample"} <= words["decode"]
-    assert {"mlp", "kv_slice", "kv_merge"} <= words["prefill_16"]
+    assert {"mlp", "kv_slice", "kv_write"} <= words["prefill_16"]
     # the same instructions as the program the jitted function runs
     args = (np.zeros(3, np.int32), np.zeros(3, np.int32),
             np.zeros(3, np.float32), eng._rng)
@@ -270,8 +270,7 @@ PROGRAM_SCOPES = {
                     MODEL_SCOPES | {"ce", "loss_and_grad", "optimizer",
                                     "rematted_computation"}),
     "prefill": (partial(_engine_text, "prefill_16"),
-                MODEL_SCOPES | {"kv_slice", "kv_merge", "kv_write",
-                                "attn_cached"}),
+                MODEL_SCOPES | {"kv_slice", "kv_write", "attn_cached"}),
     "decode": (partial(_engine_text, "decode"),
                MODEL_SCOPES | {"kv_write", "attn_cached", "sample"}),
 }

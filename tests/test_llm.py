@@ -132,6 +132,152 @@ def test_slot_growth_beyond_max_batch(engine_setup):
         assert r.generated == solo.generate([i + 1], max_tokens=3)
 
 
+def _bits(cache):
+    """The cache on the host, as integers: bit-for-bit comparison."""
+    return {n: np.asarray(a).view(np.uint16) for n, a in cache.items()}
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("slot", [0, 3])
+def test_prefill_writes_only_its_slot_among_decoding_sequences(
+        engine_setup, slot, chunk):
+    """A prompt prefilled into one slot of a shard whose other slots are
+    decoding: a prefill call changes nothing but its chunk's rows of its
+    own slot, a decode call nothing of the half-prefilled slot but the
+    scratch row (where the inactive lanes write), and every sequence
+    gets the tokens it gets alone."""
+    cfg, params = engine_setup
+    kw = dict(max_batch=4, max_seq=256, prefill_chunk=chunk, max_slots=4)
+    eng = LlamaEngine(cfg, params, **kw)
+    shard = eng.shards[0]
+    # slots are handed out from the end of the list: `slot` goes last
+    shard.free_slots = [slot] + [s for s in range(4) if s != slot]
+    live = [GenRequest(f"live{i}", [7 + i, 3, 11 + 2 * i][:2 + i % 2],
+                       max_tokens=24) for i in range(3)]
+    late = GenRequest("late", [1 + (5 * j) % 500 for j in range(5 * chunk // 2)],
+                      max_tokens=6)
+    for r in live:
+        assert eng.add_request(r)
+    while len(shard.active) < 3:
+        eng.step()
+    assert eng.add_request(late) and late.slot == slot
+    others = [s for s in range(4) if s != slot]
+    seen = {"prefill": 0, "decode_between_chunks": 0}
+    prefill, decode = eng._prefill, eng._decode
+
+    def checked_prefill(params, cache, tokens, onehot, start, length, bucket):
+        before = _bits(cache)
+        logits, cache = prefill(
+            params, cache, tokens, onehot, start, length, bucket=bucket)
+        seen["prefill"] += 1
+        lo = int(start[0])
+        for name, after in _bits(cache).items():
+            was = before[name]            # (L, B, KVH, S, hd)
+            np.testing.assert_array_equal(after[:, others], was[:, others])
+            own, own_was = after[:, slot], was[:, slot]
+            np.testing.assert_array_equal(own[:, :, :lo], own_was[:, :, :lo])
+            np.testing.assert_array_equal(
+                own[:, :, lo + bucket:], own_was[:, :, lo + bucket:])
+            assert (own[:, :, lo:lo + length] != 0).any()
+        return logits, cache
+
+    def checked_decode(params, cache, last, lens, temps, rng):
+        before = _bits(cache)
+        toks, cache, rng = decode(params, cache, last, lens, temps, rng)
+        if 0 < late.prefill_pos < len(late.prompt_ids):
+            seen["decode_between_chunks"] += 1
+            assert lens[slot] == eng.max_seq - 1
+            for name, after in _bits(cache).items():
+                np.testing.assert_array_equal(
+                    after[:, slot, :, :-1], before[name][:, slot, :, :-1])
+        return toks, cache, rng
+
+    eng._prefill, eng._decode = checked_prefill, checked_decode
+    while not all(r.done for r in live + [late]):
+        eng.step()
+    assert seen["prefill"] == 3 and seen["decode_between_chunks"] == 2
+    alone = LlamaEngine(cfg, params, **kw)
+    for r in live + [late]:
+        assert r.generated == alone.generate(
+            r.prompt_ids, max_tokens=r.max_tokens), r.request_id
+
+
+def test_engine_programs_update_the_cache_in_place():
+    """Both cache leaves are donated to decode and to prefill and come
+    back as outputs under the same buffers; no instruction copies a
+    whole shard, a layer of it or a slot of it. A float32 cache, because
+    the CPU compiler widens a bfloat16 update to float32 and back over
+    the whole array, which the TPU's does not."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg = dataclasses.replace(tiny_cfg(), dtype=jnp.float32)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    eng = LlamaEngine(cfg, params, max_batch=4, max_seq=128, prefill_chunk=16)
+    eng.generate(list(range(1, 20)), max_tokens=2)     # runs bucket 16
+    old = eng.shards[0].cache
+    assert eng.add_request(GenRequest("r", [1, 2, 3], max_tokens=3))
+    eng.step()                                         # prefill, then decode
+    assert all(leaf.is_deleted() for leaf in old.values())
+    assert not any(
+        leaf.is_deleted() for leaf in eng.shards[0].cache.values())
+
+    first = len(jax.tree.leaves(params))   # k and v follow the parameters
+    shape = old["k"].shape
+    moved = {",".join(map(str, dims))
+             for dims in (shape, shape[1:], (1, *shape[2:]))}
+    instruction = re.compile(
+        r"^\s+(?:ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
+    programs = eng.compiled_programs()
+    assert sorted(programs) == ["decode", "prefill_16"]
+    for name, compiled in programs.items():
+        text = compiled.as_text()
+        header = text.split("\n", 1)[0]   # input_output_alias={ {1}: (12, ...
+        # output 0 is the tokens or the logits, 1 and 2 the cache
+        assert {int(o): int(p) for o, p in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}", header)} == {
+                1: first, 2: first + 1}, (name, header[:300])
+        copies = [m.group(0).strip() for m in instruction.finditer(text)
+                  if m.group(2) == "copy" and m.group(1) in moved]
+        assert not copies, (name, copies)
+
+
+@pytest.mark.parametrize("fault", ["before_dispatch", "after_dispatch"])
+def test_engine_serves_on_after_a_fault_and_abort_all(engine_setup, fault):
+    """LLMServer's loop catches an engine fault, calls abort_all() and
+    keeps serving. A call that failed after it was dispatched has taken
+    the donated cache with it."""
+    cfg, params = engine_setup
+    kw = dict(max_batch=2, max_seq=64, prefill_chunk=16)
+    eng = LlamaEngine(cfg, params, **kw)
+    victim = GenRequest("victim", [4, 5, 6], max_tokens=8)
+    assert eng.add_request(victim)
+    decode = eng._decode
+
+    def failing(params, cache, *rest):
+        if fault == "after_dispatch":
+            decode(params, cache, *rest)
+        raise RuntimeError("injected")
+
+    eng._decode = failing
+    with pytest.raises(RuntimeError, match="injected"):
+        while True:
+            eng.step()
+    eng._decode = decode
+    lost = fault == "after_dispatch"
+    assert all(leaf.is_deleted() == lost
+               for leaf in eng.shards[0].cache.values())
+    assert eng.abort_all() == [victim] and victim.done
+    assert not eng.num_active()
+    assert not any(leaf.is_deleted() for leaf in eng.shards[0].cache.values())
+    prompt = list(range(1, 30))
+    fresh = LlamaEngine(cfg, params, **kw)
+    assert eng.generate(prompt, max_tokens=6) == fresh.generate(
+        prompt, max_tokens=6)
+
+
 def test_generation_from_checkpoint(engine_setup, tmp_path):
     cfg, params = engine_setup
     path = str(tmp_path / "model.npz")
