@@ -54,12 +54,18 @@ def loss_fn(params, batch, config):
     return llama.loss_fn(params, batch, config)
 
 
+def logits(params, tokens, config):
+    """tokens (B, S) -> float32 logits (B, S, V) through the model code
+    ``loss_fn`` runs: what a train cell's ``correct`` compares per token
+    with ``reference_logits``."""
+    from ray_tpu.models import llama
+
+    return llama.forward(params, tokens, config)
+
+
 # -- the plain float32 reference (imports nothing of ray_tpu) ----------
-def reference_loss(params, tokens, hp: dict):
-    return reference.loss(params, tokens, theta=hp["rope_theta"],
-                          eps=hp["rms_norm_eps"])
-
-
+# the loss is the mean cross entropy and nothing more, so the harness
+# takes it from these logits and the family gives no reference_loss
 def reference_logits(params, tokens, hp: dict, last: int = 0):
     return reference.logits(params, tokens, theta=hp["rope_theta"],
                             eps=hp["rms_norm_eps"], last=last)

@@ -2,7 +2,15 @@
 found by name (the toy family of ``tests/tree``), a declared per-layer
 metric that may read nothing and says so, and a reduced trace that keeps
 what program-level readers need. Every number a new metric reads from
-the recorded fixtures is made a second time by hand."""
+the recorded fixtures is made a second time by hand.
+
+No test here knows which cells train and which serve: that is each
+cell's ``kind``. A cell is tried on its family's own recording for that
+kind, ``trace_<family>.<kind>.xplane.pb.gz`` with its sidecar
+``.json.gz`` (here or in ``tests/tree``; tree/record_toy_moe_fixture.py
+says what the sidecar holds), and where the family brings none, on the
+llama recordings of PR 23 and PR 24. So a PR that adds a family and a
+cell adds its recording, and no test file changes."""
 
 import ast
 import glob
@@ -26,7 +34,13 @@ ROOT = os.path.dirname(BENCH)
 CHAT, DOCS, OVER = ("internlm2-1.8b.serve-chat",
                     "mistral-7b-v0.3.serve-docbatch",
                     "internlm2-1.8b.serve-chat-over")
-TRAIN = ("internlm2-1.8b.train-2k", "mistral-7b-v0.3.train-fsdp4")
+# the llama family's train recording of PR 23 (record_fixture.py, four
+# chips), as a family's own sidecar would describe it; its toy step was
+# not compiled with scopes: an empty map is a program none of whose ops
+# is scoped
+LLAMA_TRAIN = {"trace": "trace_4chip.xplane.pb", "chips": 4, "seq": 256,
+               "seqs_per_step": 8, "traced_steps": 2,
+               "scopes": {"step": {}}, "reads_nothing": {}}
 # what PR 24 recorded and this PR declares: they read what the parent of
 # PR 24 lacks (engine_stats(), compiled_programs(), iter_stats)
 FROM_STATS = {"queue_wait_p95_ms", "prefill_wait_p95_ms", "prefill_p95_ms",
@@ -41,10 +55,34 @@ def harness_files():
 
 
 def unzipped(name, tmp_path):
-    out = tmp_path / name
+    """``name`` beside this file (or a whole path), without its .gz."""
+    out = tmp_path / os.path.basename(name)
     with gzip.open(os.path.join(HERE, name + ".gz"), "rb") as f:
         out.write_bytes(f.read())
     return str(out)
+
+
+def cells():
+    """BENCHMARK.json's cells, then the fixture tree's."""
+    tree = sorted(os.path.basename(p)[:-len(".json")] for p in glob.glob(
+        os.path.join(spec.FIXTURE_TREE, "workloads", "*.json")))
+    return [w["name"] for w in spec.benchmark_json()["workloads"]] + tree
+
+
+def cells_of_kind(kind):
+    return [c for c in cells() if spec.load_cell(c, True)["kind"] == kind]
+
+
+def recording_of(cell_name):
+    """The stem of the recording this cell's family brings for this kind
+    of cell, or None: the cell is then tried on the llama recordings."""
+    cell = spec.load_cell(cell_name, True)
+    family = cell["hp"].get("family", "llama")
+    for where in (HERE, spec.FIXTURE_TREE):
+        stem = os.path.join(where, f"trace_{family}.{cell['kind']}")
+        if os.path.exists(stem + ".xplane.pb.gz"):
+            return stem
+    return None
 
 
 @pytest.fixture(scope="module")
@@ -71,42 +109,50 @@ def serving_ctx(cell_name, serving, with_program_stats=True):
     return {"cell": spec.load_cell(cell_name, True), "chips": 1,
             "samples": samples, "trace": trace, "peak": {},
             "scopes": found["scopes"] if with_program_stats else None,
-            "cache_shapes": found["cache_shapes"]}
+            "cache_shapes": found["cache_shapes"], "reads_nothing": {}}
 
 
 def train_ctx(cell_name, tmp_path, with_program_stats=True):
-    trace = tr.load(unzipped("trace_4chip.xplane.pb", tmp_path))
-    samples = {"window_s": 2.0, "steps": 4, "tokens_in_window": 4 * 2 * 256,
-               "seq": 256, "seqs_per_step": 8, "step_ms": [500.0] * 4,
-               "input_wait_ms": [0.1] * 4, "traced_steps": 2}
+    stem, rec = recording_of(cell_name), LLAMA_TRAIN
+    if stem:
+        with gzip.open(stem + ".json.gz", "rt") as f:
+            rec = {"trace": stem + ".xplane.pb", **json.load(f)}
+    trace = tr.load(unzipped(rec["trace"], tmp_path))
+    samples = {"window_s": 2.0, "steps": 4,
+               "tokens_in_window": 4 * rec["seqs_per_step"] * rec["seq"],
+               "seq": rec["seq"], "seqs_per_step": rec["seqs_per_step"],
+               "step_ms": [500.0] * 4, "input_wait_ms": [0.1] * 4,
+               "traced_steps": rec["traced_steps"],
+               # what train_step.py's step reports besides its loss
+               "step.grad_norm": [4.0, 1.0, 3.0, 2.0],
+               "step.step": [3.0, 4.0, 5.0, 6.0]}
     if with_program_stats:
         samples.update(holder.phase_deltas(
             {"data.stage_batch": {"seconds": 1.0, "count": 10}},
             {"data.stage_batch": {"seconds": 1.5, "count": 60},
              "data.next_batch": {"seconds": 0.01, "count": 50}}))
-    return {"cell": spec.load_cell(cell_name, True), "chips": 4,
+    return {"cell": spec.load_cell(cell_name, True), "chips": rec["chips"],
             "samples": samples, "trace": trace,
             "peak": spec.load_json("peaks.json")["TPU v5 lite"],
-            # the toy step of the PR 23 recording was not compiled with
-            # scopes: an empty map is a program none of whose ops is scoped
-            "scopes": {"step": {}} if with_program_stats else None}
+            "scopes": rec["scopes"] if with_program_stats else None,
+            # not for the readers: what this recording cannot hold
+            "reads_nothing": rec["reads_nothing"]}
 
 
 def ctx_for(cell_name, serving, tmp_path, **kw):
-    if cell_name in TRAIN:
-        return train_ctx(cell_name, tmp_path, **kw)
-    return serving_ctx(cell_name, serving, **kw)
-
-
-def cells():
-    return [w["name"] for w in spec.benchmark_json()["workloads"]]
+    """By the cell's kind; a kind with a runner of its own
+    (``benchmarks/<kind>.py``) brings its family's recording and is
+    read as a train cell's is: samples, trace, scopes."""
+    if spec.load_cell(cell_name, True)["kind"] == "serve":
+        return serving_ctx(cell_name, serving, **kw)
+    return train_ctx(cell_name, tmp_path, **kw)
 
 
 # ------------------------------------------------- door 1: a family by name
 @pytest.mark.parametrize("cell,read,not_read", [
     ("toy-moe.train",
      {"train_step_ms", "train_mfu_pct", "optimizer_ms", "input_stage_ms",
-      "scope_unattributed_pct.train"},
+      "scope_unattributed_pct.train", "grad_norm_p50.toy"},
      {"flash_attention_roofline"}),
     ("toy-moe.serve",
      {"engine_tokens_per_step.docs", "engine_host_ms.docs",
@@ -215,25 +261,31 @@ def test_evaluate_leaves_out_what_was_not_read_with_the_reason():
 def test_every_declared_per_layer_metric_reads_a_number(cell, serving,
                                                         tmp_path):
     """No reader may go silent on the head of the tree: from the
-    recorded traces, scope maps and snapshots, each per-layer metric a
-    cell declares reads a finite number."""
+    recorded traces, scope maps and snapshots (the family's own where it
+    brings them), each per-layer metric a cell declares reads a finite
+    number, but for what the recording's sidecar names as not in it."""
     declared = spec.cell_metrics(cell, traced=True)
     assert declared
-    out, not_read = spec.evaluate(declared, ctx_for(cell, serving, tmp_path))
-    assert not_read == {}
-    assert set(out) == set(declared)
+    ctx = ctx_for(cell, serving, tmp_path)
+    out, not_read = spec.evaluate(declared, ctx)
+    assert set(not_read) == set(ctx["reads_nothing"])
+    assert set(out) == set(declared) - set(not_read)
     assert all(math.isfinite(m["value"]) for m in out.values())
     for name, m in out.items():
         if m["unit"] == "%" and "mfu" not in name:
             assert 0.0 <= m["value"] <= 100.0, name
 
 
-@pytest.mark.parametrize("cell", cells())
+@pytest.mark.parametrize(
+    "cell", [c for c in cells() if recording_of(c) is None])
 def test_the_parent_of_pr_24_ends_in_a_valid_line(cell, serving, tmp_path):
     """An engine without engine_stats() and compiled_programs(), a
     Dataset without iter_stats, a program without scope_map: the metrics
     that read them are named as not read, every other reads as before,
-    and the traced line passes the contract."""
+    and the traced line passes the contract. For the cells whose program
+    existed then: those tried on the llama recordings, which PR 23 and
+    PR 24 made. A family that brings a recording of its own came later,
+    and what its program lacked at that parent is everything."""
     declared = spec.cell_metrics(cell, traced=True)
     out, not_read = spec.evaluate(
         declared, ctx_for(cell, serving, tmp_path, with_program_stats=False))
@@ -306,9 +358,44 @@ def test_metrics_from_the_engines_stats_equal_the_hand_count(serving):
 
 
 def test_input_stage_ms_is_the_windows_seconds_a_batch(tmp_path):
-    out, _ = spec.evaluate(spec.cell_metrics(TRAIN[0], True),
-                           train_ctx(TRAIN[0], tmp_path))
+    cell = cells_of_kind("train")[0]
+    out, _ = spec.evaluate(spec.cell_metrics(cell, True),
+                           train_ctx(cell, tmp_path))
     assert out["input_stage_ms"]["value"] == pytest.approx(1e3 * 0.5 / 50)
+
+
+def test_a_train_cell_of_a_second_family_is_tried_on_its_own_recording(
+        serving, tmp_path):
+    """``toy-moe.train``, its metric ``grad_norm_p50.toy`` and its
+    recording exist under tests/tree alone. The helpers above find the
+    cell by its kind and the recording by its family; its own metric
+    reads the step's ``step.grad_norm`` samples; what the llama
+    recording could never give it (a scope map of its own program) is
+    read from its own."""
+    toy = [c for c in cells_of_kind("train") if recording_of(c)]
+    assert toy == ["toy-moe.train"]
+    assert recording_of(toy[0]).startswith(spec.FIXTURE_TREE)
+    assert "toy-moe.train" not in [
+        w["name"] for w in spec.benchmark_json()["workloads"]]
+    declared = spec.cell_metrics(toy[0], traced=True)
+    own = [e for e in spec.declared("per_layer")
+           if e["name"] == "grad_norm_p50.toy"]
+    assert len(own) == 1 and own[0]["workloads"] == toy
+    assert own[0] not in spec.benchmark_json()["per_layer"]
+    ctx = ctx_for(toy[0], serving, tmp_path)
+    assert ctx["chips"] == 1 and ctx["scopes"]["step"]
+    out, not_read = spec.evaluate(declared, ctx)
+    assert set(not_read) == {"flash_attention_roofline"} == set(
+        ctx["reads_nothing"])
+    # the median of [4, 1, 3, 2], by hand
+    assert out["grad_norm_p50.toy"]["value"] == 2.5
+    assert out["optimizer_ms"]["value"] > 0
+    llama_cell = next(c for c in cells_of_kind("train")
+                      if not recording_of(c))
+    on_llama, _ = spec.evaluate(
+        {"optimizer_ms": declared["optimizer_ms"]},
+        train_ctx(llama_cell, tmp_path))
+    assert on_llama["optimizer_ms"]["value"] == 0.0     # an empty scope map
 
 
 def test_metrics_from_the_trace_by_program_equal_the_hand_count(serving):

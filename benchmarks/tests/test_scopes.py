@@ -20,7 +20,14 @@ from benchmarks.readers import (trace_scope_ms_per_step,
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
-TRAIN_CELLS = ("internlm2-1.8b.train-2k", "mistral-7b-v0.3.train-fsdp4")
+CELLS = {w["name"]: spec.load_cell(w["name"], True)
+         for w in spec.benchmark_json()["workloads"]}
+# by each cell's kind and its configuration's family, never by name
+TRAIN_CELLS = [c for c, cell in CELLS.items() if cell["kind"] == "train"]
+LLAMA_TRAIN_CELLS = [c for c in TRAIN_CELLS
+                     if CELLS[c]["hp"].get("family", "llama") == "llama"]
+# the scopes of models/llama.py and train_step.py: a train cell of
+# another family declares those of them its program has
 NEW_TRAIN_METRICS = ("remat_recompute_ms", "head_ce_ms", "mlp_ms",
                      "optimizer_ms", "scope_unattributed_pct.train")
 
@@ -73,7 +80,7 @@ def toy_step_trace():
     from ray_tpu import parallel
     from ray_tpu._private.jax_utils import compile_with_scopes, scope_map
 
-    cell = spec.load_cell(TRAIN_CELLS[0], rehearse=True)
+    cell = spec.load_cell(LLAMA_TRAIN_CELLS[0], rehearse=True)
     hp, tf, opts = cell["hp"], cell["traffic"], cell["train"]
     llama = spec.family_of(hp)
     cfg = llama.model_config(hp, opts)
@@ -152,15 +159,18 @@ def test_new_metrics_are_declared_for_the_train_cells_only():
     by_name = {e["name"]: e for e in bench["per_layer"]}
     for name in NEW_TRAIN_METRICS:
         entry = by_name[name]
-        assert tuple(entry["workloads"]) == TRAIN_CELLS
+        assert set(LLAMA_TRAIN_CELLS) <= set(entry["workloads"]) <= set(
+            TRAIN_CELLS)
         assert entry["source"] == "device_trace"
         assert entry["moves"] == "train_tokens_per_s_per_chip"
         metric = spec.load_json("metrics", f"{name}.json")
         assert metric["layer"] == entry["layer"] == "step program"
-    for cell in TRAIN_CELLS:
+    for cell in LLAMA_TRAIN_CELLS:
         assert set(NEW_TRAIN_METRICS) <= set(spec.cell_metrics(cell, True))
-    assert not set(NEW_TRAIN_METRICS) & set(
-        spec.cell_metrics("internlm2-1.8b.serve-chat", True))
+    assert LLAMA_TRAIN_CELLS and set(CELLS) - set(TRAIN_CELLS)
+    for cell in set(CELLS) - set(TRAIN_CELLS):
+        assert not set(NEW_TRAIN_METRICS) & set(
+            spec.cell_metrics(cell, True))
 
 
 # What trace_reduce.py read on the two recorded v5e traces when PR 23
@@ -196,10 +206,7 @@ def test_recorded_traces_read_what_they_read_before(name, tmp_path):
     assert tr.idle_gaps(trace, 1)[0] == want["gap"]
 
 
-@pytest.mark.parametrize("cell", [
-    "internlm2-1.8b.train-2k", "internlm2-1.8b.serve-chat",
-    "internlm2-1.8b.serve-chat-over",
-    "mistral-7b-v0.3.serve-docbatch", "mistral-7b-v0.3.train-fsdp4"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_reaches_a_valid_last_line(cell):
     """run.py checks the line against contract.py before printing it:
     exit 0 means every declared metric a rehearsal can read is there."""
@@ -211,7 +218,7 @@ def test_rehearsal_reaches_a_valid_last_line(cell):
     assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["correct"] is False and last["device"]["platform"] == "cpu"
-    if cell in TRAIN_CELLS:
+    if cell in LLAMA_TRAIN_CELLS:
         assert set(NEW_TRAIN_METRICS) <= set(last["metrics"])
         assert last["metrics"]["mlp_ms"]["value"] > 0
         assert last["metrics"]["scope_unattributed_pct.train"]["value"] < 50

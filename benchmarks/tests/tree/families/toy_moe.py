@@ -25,13 +25,15 @@ def model_config(hp, options=None):
     if options is None:     # serving: the engine's decoder
         return LlamaConfig(**sizes)
     experts, top_k = hp["num_local_experts"], hp["num_experts_per_tok"]
+    if "capacity_factor" in MoELlamaConfig.__dataclass_fields__:
+        # a program that still drops tokens over a capacity: room for
+        # every token at one expert, so nothing is dropped, as in the
+        # reference
+        sizes["capacity_factor"] = experts / top_k
     return MoELlamaConfig(
         **sizes, remat=options["remat"],
         attention_impl=options["attention_impl"], n_experts=experts,
-        experts_per_token=top_k, router_aux_coeff=hp["router_aux_loss_coef"],
-        # room for every token at one expert: nothing is dropped, as in
-        # the reference
-        capacity_factor=experts / top_k)
+        experts_per_token=top_k, router_aux_coeff=hp["router_aux_loss_coef"])
 
 
 def _model(cfg):
@@ -52,6 +54,14 @@ def loss_fn(params, batch, config):
     return _model(config).loss_fn(params, batch, config)
 
 
+def logits(params, tokens, config):
+    out = _model(config).forward(params, tokens, config)
+    return out[0] if isinstance(out, tuple) else out    # (logits, aux, ...)
+
+
+# the loss carries the router's load-balance term besides the mean cross
+# entropy, so the family gives its own reference_loss for the first-loss
+# check; the per-token check covers the cross-entropy part
 reference_loss = reference.loss
 reference_logits = reference.logits
 

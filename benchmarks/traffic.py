@@ -6,7 +6,11 @@ run of a cell offers the same multiset of prompt and output lengths
 (hence the same tokens). Open-loop arrivals are Poisson in the same
 way: the n = round(rate x seconds) gaps between arrivals sit at the
 quantiles of the exponential distribution, scaled to fill the window
-exactly. Gaps and lengths are put into ONE order, fixed by the cell's
+exactly. A cell whose clients come in clumps gives ``arrivals``:
+``{"dist": "gamma", "cv": c}`` takes the gaps at the same quantiles of a
+gamma distribution of shape 1 / c^2 (c = 1 is the exponential), scaled
+to the same rate: most gaps a few milliseconds, a few of seconds.
+Gaps and lengths are put into ONE order, fixed by the cell's
 ``schedule_seed``, and read as a cycle; ``--seed`` decides where in the
 cycle the window starts (the ramp is the stretch of the cycle before
 it), besides the weights and the prompts' tokens. Every run therefore
@@ -54,6 +58,21 @@ def quantile_lengths(spec: dict, n: int) -> List[int]:
     return [int(min(max(round(v), lo), hi)) for v in vals]
 
 
+def quantile_gaps(arrivals: dict, n: int) -> np.ndarray:
+    """n arrival gaps at the quantiles (i + 0.5) / n of the cell's
+    ``arrivals`` distribution (absent: exponential), before scaling to
+    the rate."""
+    qs = (np.arange(n) + 0.5) / n
+    dist = (arrivals or {}).get("dist", "exponential")
+    if dist == "exponential":
+        return -np.log1p(-qs)
+    if dist == "gamma":
+        from scipy.special import gammaincinv  # jax itself needs scipy
+
+        return gammaincinv(1.0 / float(arrivals["cv"]) ** 2, qs)
+    raise ValueError(f"unknown arrival distribution {dist!r}")
+
+
 def _rng(seed: int, stream: int) -> np.random.Generator:
     # seeds run past 2**31; SeedSequence takes any non-negative integer
     return np.random.default_rng(np.random.SeedSequence([int(seed), stream]))
@@ -75,7 +94,7 @@ def open_loop(traffic: dict, seed: int, seconds: float) -> List[Request]:
     n_ramp = round(rate * traffic.get("ramp_s", 0))
     cycle = int(traffic.get("cycle", n))
     order = _rng(traffic.get("schedule_seed", 0), 1)
-    gaps = -np.log1p(-(np.arange(cycle) + 0.5) / cycle)  # exponential quantiles
+    gaps = quantile_gaps(traffic.get("arrivals"), cycle)
     # the window's n requests fill it exactly; a given cycle keeps the rate
     gaps *= (cycle / rate if "cycle" in traffic else seconds) / gaps.sum()
     order.shuffle(gaps)

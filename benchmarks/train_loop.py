@@ -16,6 +16,83 @@ import time
 
 from . import holder, spec, traffic
 
+# how many of the probe's last positions leave the first-forward program
+# as logits (server.py compares logits too); the per-token NLL leaves it
+# for every position
+LAST_LOGITS = 256
+
+
+def token_nll(logits, targets):
+    """(S, V) float32 logits, (S,) targets -> (S,) next-token NLL."""
+    import jax
+
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jax.numpy.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
+
+
+def first_forward(family, cfg, mesh, hp, params, probe, tokens) -> dict:
+    """The program's first forward against the float32 reference, per
+    token: ``tokens`` (B, S + 1) is the sequence ``probe`` repeated, as
+    the step is given it (so it divides over the mesh and a kernel runs
+    in its shard_map as in the step). One reference pass over the one
+    sequence; one jitted program through ``family.logits``, which
+    reduces to row 0's NLL (S,) and the logits of its last positions,
+    so nothing of size B x S x V leaves it. The mean of a few thousand
+    NLLs hides a sublayer in fp8 at any limit (PR 27's refusal); their
+    root mean square difference does not."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    logits_fn = getattr(family, "logits", None)
+    if logits_fn is None:
+        raise ValueError(
+            f"{family.__name__} gives no logits(params, tokens, config): a "
+            "train cell's `correct` compares the program's first forward per "
+            "token with reference_logits (README.md, \"A family\")")
+    probe = jnp.asarray(probe)
+    last = min(LAST_LOGITS, probe.shape[0] - 1)
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    # three programs, not a dozen small ones compiled anew in every run:
+    # the reference's reduction, the program's forward, the comparison
+    @partial(jax.jit, out_shardings=replicated)
+    def reduced(logits, targets):
+        return token_nll(logits, targets), logits[-last:]
+
+    @partial(jax.jit, out_shardings=replicated)
+    def forward(params, tokens):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            row = logits_fn(params, tokens[:, :-1], cfg)[0]
+        return reduced(row, tokens[0, 1:])
+
+    @jax.jit
+    def compared(got_nll, got_last, want_nll, want_last):
+        def rms(x):
+            return jnp.sqrt(jnp.mean(jnp.square(x)))
+
+        return {
+            "nll_rms": rms(got_nll - want_nll),
+            "nll_mean": got_nll.mean(),
+            "reference_nll_mean": want_nll.mean(),
+            "last_logits_rel_rms": rms(got_last - want_last) / rms(want_last),
+            "finite": jnp.isfinite(got_nll).all(),
+        }
+
+    # the reference's (S, V) logits are gone before the forward runs
+    want = reduced(family.reference_logits(params, probe[:-1], hp), probe[1:])
+    out = jax.device_get(compared(*forward(params, tokens), *want))
+    return {k: bool(v) if k == "finite" else float(v) for k, v in out.items()}
+
+
+def step_scalars(metrics: dict) -> dict:
+    """What a step reports besides its loss, as it came (device values:
+    read after the window, never inside it)."""
+    return {k: v for k, v in metrics.items()
+            if k != "loss" and getattr(v, "ndim", None) == 0}
+
 
 # ------------------------------------------------------------ the worker
 def train_loop(config: dict) -> None:
@@ -73,19 +150,26 @@ def train_loop(config: dict) -> None:
 
     feed = batches()
 
-    # -- correct, part 1: the first loss against the float32 reference,
-    # on one seeded sequence (the probe batch is that sequence repeated,
-    # so the step's mean loss is the sequence's loss). Before any step:
-    # the step donates the state it is given.
+    # -- correct, part 1: the first forward against the float32
+    # reference, on one seeded sequence: per token (first_forward), and
+    # the step's first loss against the reference's (the probe batch is
+    # that sequence repeated, so the step's mean loss is the sequence's
+    # loss; a family whose loss carries more than cross entropy gives
+    # its own reference_loss). Before any step: the step donates the
+    # state it is given.
     t0 = time.perf_counter()
     probe = traffic.probe_sequence(config["seed"], seq + 1, hp["vocab_size"])
-    reference_loss = float(family.reference_loss(
-        state.params, jax.numpy.asarray(probe), hp))
-    reference_s = time.perf_counter() - t0
-    phases["reference_done"] = time.time()
     probe_batch = {"tokens": jax.device_put(
         np.ascontiguousarray(np.broadcast_to(probe, (batch_size, seq + 1))),
         parallel.batch_sharding(mesh))}
+    first = first_forward(
+        family, cfg, mesh, hp, state.params, probe, probe_batch["tokens"])
+    reference_loss = (
+        float(family.reference_loss(
+            state.params, jax.numpy.asarray(probe), hp))
+        if hasattr(family, "reference_loss") else first["reference_nll_mean"])
+    reference_s = time.perf_counter() - t0
+    phases["reference_done"] = time.time()
     # compiled so that its text is sure to carry this build's scopes
     # (compile_with_scopes says why): the traced part joins the trace
     # with the text of the very program it ran. A program from before
@@ -133,7 +217,7 @@ def train_loop(config: dict) -> None:
     iter_stats = getattr(shard, "iter_stats", None)
     stats_before = iter_stats.snapshot() if iter_stats is not None else None
     compiles_before = compiles.count
-    step_ms, wait_ms = [], []
+    step_ms, wait_ms, kept = [], [], []
     window_start_wall = time.time()
     start = time.perf_counter()
     elapsed = 0.0
@@ -144,11 +228,13 @@ def train_loop(config: dict) -> None:
         state, metrics = compiled(state, batch)
         losses.append(float(metrics["loss"]))  # waits for the step
         t2 = time.perf_counter()
+        kept.append(step_scalars(metrics))
         wait_ms.append(1e3 * (t1 - t0))
         step_ms.append(1e3 * (t2 - t1))
         elapsed = t2 - start
     compiled_in_window = compiles.count - compiles_before
     stats_after = iter_stats.snapshot() if iter_stats is not None else None
+    host_kept = jax.device_get(kept)
     samples = {
         **holder.phase_deltas(stats_before, stats_after),
         "window_s": elapsed,
@@ -156,6 +242,9 @@ def train_loop(config: dict) -> None:
         "tokens_in_window": len(step_ms) * tokens_per_step,
         "seq": seq, "seqs_per_step": batch_size,
         "step_ms": step_ms, "input_wait_ms": wait_ms,
+        # step.<name>: every other scalar the step reports, per step
+        **{f"step.{k}": [float(m[k]) for m in host_kept]
+           for k in (host_kept[0] if host_kept else {})},
     }
 
     # -------------------------------------------- the traced part, after
@@ -185,6 +274,7 @@ def train_loop(config: dict) -> None:
         "window_start_wall": window_start_wall, "phases": phases,
         "samples": samples, "losses": losses,
         "reference_loss": reference_loss, "reference_s": reference_s,
+        "first_forward": first,
         "kernels_in_program": kernels_in_program,
         "compiled_in_window": compiled_in_window,
         "params_sharded": sharded, "params_not_sharded": whole,
@@ -228,7 +318,7 @@ def run(cell: dict, args, per_layer: dict) -> dict:
     r = dict(result.metrics)
     phases.update(r["phases"], window=r["window_start_wall"])
 
-    losses = r["losses"]
+    losses, first = r["losses"], r["first_forward"]
     uniform = math.log(hp["vocab_size"])
     last_quarter = losses[-max(len(losses) // 4, 1):]
     checks = {
@@ -240,6 +330,8 @@ def run(cell: dict, args, per_layer: dict) -> dict:
             <= losses[0] - opts["loss_drop_min"],
         "first_loss_matches_reference":
             abs(losses[0] - r["reference_loss"]) <= opts["reference_loss_tol"],
+        "first_nll_matches_reference": first["finite"]
+            and first["nll_rms"] <= opts["reference_nll_rms_tol"],
         "kernels_in_program": bool(r["kernels_in_program"]),
         "nothing_compiled_in_window": r["compiled_in_window"] == 0,
         "every_sharded_param_split": r["params_not_sharded"] == 0
@@ -265,7 +357,19 @@ def run(cell: dict, args, per_layer: dict) -> dict:
             "loss_last_quarter_median": statistics.median(last_quarter),
             "reference_loss": r["reference_loss"],
             "reference_s": r["reference_s"],
+            "first_forward": first,
+            # each number compared, beside its limit
+            "compared": {
+                "first_loss_minus_reference": [
+                    abs(losses[0] - r["reference_loss"]),
+                    opts["reference_loss_tol"]],
+                "first_nll_rms": [
+                    first["nll_rms"], opts["reference_nll_rms_tol"]]},
             "params_sharded": r["params_sharded"],
+            # step.<name>: how many, the first and the last of each
+            "step_samples": {k: [len(v), v[0], v[-1]]
+                             for k, v in r["samples"].items()
+                             if k.startswith("step.") and v},
             "step_ms_median": statistics.median(r["samples"]["step_ms"]),
             "input_wait_ms_median":
                 statistics.median(r["samples"]["input_wait_ms"]),
