@@ -62,6 +62,29 @@ class TPUAcceleratorManager(AcceleratorManager):
         os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in ids)
 
 
+# Peak dense bf16 FLOP/s and HBM bytes/s of one chip, by the
+# ``device_kind`` jax reports on it (Google Cloud's "TPU v4", "TPU v5e",
+# "TPU v5p" and "TPU v6e" pages; v5p answers to either spelling).
+# Published figures: only the v5e's row has been run against (PERF.md).
+CHIP_PEAKS = {
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+}
+
+
+def flops_per_hbm_byte(device_kind: str) -> float:
+    """The ridge of a chip's roofline: how many FLOPs it can do in the
+    time it reads one byte of HBM. Work that does fewer per byte it
+    reads waits for memory. A kind the table lacks (the CPU of a test, a
+    newer chip) takes the smallest ratio in it: whoever sizes work by
+    the ridge then errs towards less of it."""
+    ratios = {kind: f / b for kind, (f, b) in CHIP_PEAKS.items()}
+    return ratios.get(device_kind, min(ratios.values()))
+
+
 # One process per chip. libtpu gives a chip to the first process that
 # opens it and holds it until that process exits; what a second process
 # gets is an error at best. So every worker is told, before it runs any

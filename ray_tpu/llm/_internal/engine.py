@@ -14,7 +14,23 @@ re-designed for XLA instead of wrapped:
   engine step, interleaved with decode — a long prompt cannot stall
   the decode of already-running sequences (vLLM's chunked-prefill
   scheduler, reference llm/_internal/batch/stages/vllm_engine_stage.py
-  wraps the same idea). Chunk buckets bound compilations.
+  wraps the same idea). The chunk is sized from the chip, not set.
+  Every call reads all the weights once, so a chunk wants rows enough
+  to pay for that read. The rule taken is the roofline's ridge: the
+  rows at which a call's matmuls take as long as the read (the chip's
+  FLOPs per HBM byte, times the weights' bytes per parameter over 2:
+  240 rows of bf16 on a v5e), to the nearest power of two
+  (``derived_prefill_chunk``; 256 on a v5e), whatever the model. It
+  is a rule of thumb, not a knee that was measured: on a v5e a call's
+  time grew nearly in line with its rows from 128 on (attention over
+  the whole cache and the activations grow with them), and 512 rows
+  served every benchmark cell better than 256 (PERF.md section 6,
+  PR 29). What bounds a chunk from above is how long the decode step
+  behind it may wait, which no cell judges yet. Three chunk buckets
+  (C/4, C/2, C) bound compilations. The head runs on the one row a
+  chunk returns logits for. ``warm_up()`` runs every program once;
+  ``LLMServer`` calls it before it takes a request, a bare engine
+  compiles on first use.
 - KV cache is preallocated per shard (L, B, KVH, max_seq, hd) and
   UPDATED IN PLACE: both programs are jitted with the cache donated,
   the cached forward carries it through its layer scan and writes only
@@ -35,6 +51,7 @@ re-designed for XLA instead of wrapped:
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import deque
@@ -48,6 +65,30 @@ from ray_tpu.util.tracing import PhaseStats, phase
 
 # a request's phases, each the gap between two of its stamps
 REQUEST_PHASES = ("queue_wait", "prefill_wait", "prefill", "decode")
+
+
+def derived_prefill_chunk(device_kind: str, bytes_per_param: float,
+                          max_seq: int) -> int:
+    """The prefill chunk for weights of ``bytes_per_param`` on a chip of
+    ``device_kind``: the power of two nearest the rows at which a call's
+    matmuls (2 FLOPs a row a parameter) take as long as reading its
+    weights once, fitted to ``max_seq``. Measured on a v5e only (256
+    rows there); the other kinds' sizes follow from the table alone."""
+    from ray_tpu._private.accelerators.tpu import flops_per_hbm_byte
+
+    rows = flops_per_hbm_byte(device_kind) * bytes_per_param / 2
+    return _fit_chunk(2 ** round(math.log2(rows)), max_seq)
+
+
+def _fit_chunk(chunk: int, max_seq: int) -> int:
+    """chunk must divide max_seq: chunk starts are then always aligned
+    and a padded chunk bucket can never run past the cache end
+    (dynamic_update_slice would CLAMP the start backward and overwrite
+    earlier valid rows)."""
+    chunk = min(chunk, max_seq)
+    while max_seq % chunk:
+        chunk //= 2
+    return chunk
 
 
 @dataclass
@@ -106,7 +147,8 @@ class EngineStats:
 
     COUNTERS = (
         "steps", "tokens_emitted",
-        "prefill_chunks", "prefill_tokens",  # real tokens, not the bucket
+        "prefill_chunks",
+        "prefill_tokens", "prefill_rows",  # real tokens; the buckets' rows
         "decode_calls", "decode_lanes_active", "decode_lanes_total",
         "shards_grown", "requests_finished", "requests_refused",
     )
@@ -133,7 +175,7 @@ class LlamaEngine:
         max_batch: int = 8,
         max_seq: int = 512,
         seed: int = 0,
-        prefill_chunk: int = 64,
+        prefill_chunk: Optional[int] = None,
         max_slots: Optional[int] = None,
     ):
         import jax
@@ -145,14 +187,14 @@ class LlamaEngine:
         self.params = params
         self.max_batch = max_batch
         self.max_seq = max_seq
-        # chunk must divide max_seq: chunk starts are then always
-        # aligned and a padded chunk bucket can never run past the
-        # cache end (dynamic_update_slice would CLAMP the start
-        # backward and overwrite earlier valid rows)
-        chunk = min(prefill_chunk, max_seq)
-        while max_seq % chunk:
-            chunk //= 2
-        self.prefill_chunk = max(chunk, 1)
+        if prefill_chunk is None:
+            leaves = jax.tree_util.tree_leaves(params)
+            self.prefill_chunk = derived_prefill_chunk(
+                jax.devices()[0].device_kind,
+                sum(a.nbytes for a in leaves) / sum(a.size for a in leaves),
+                max_seq)
+        else:
+            self.prefill_chunk = _fit_chunk(prefill_chunk, max_seq)
         # growth is whole-shard; round the cap to shard granularity so
         # the KV-memory bound it expresses actually holds
         want_slots = max_slots or 4 * max_batch
@@ -168,13 +210,10 @@ class LlamaEngine:
         # requests were ever batched, not merely queued
         self.peak_active = 0
 
-        # prefill-chunk buckets: powers of two up to prefill_chunk
-        self.buckets = []
-        b = 16
-        while b < self.prefill_chunk:
-            self.buckets.append(b)
-            b *= 2
-        self.buckets.append(self.prefill_chunk)
+        # prefill-chunk buckets: a call reads all the weights whatever
+        # its rows, so a bucket far under the chunk saves little
+        c = self.prefill_chunk
+        self.buckets = [b for b in (c // 4, c // 2) if b >= 16] + [c]
 
         def prefill(params, cache, tokens, slot_onehot, start, length, bucket):
             # tokens (1, bucket) padded; writes into the slot's rows at
@@ -184,8 +223,9 @@ class LlamaEngine:
             logits, new_cache = llama.forward_with_cache(
                 params, tokens, cache, start, config,
                 slot=jnp.argmax(slot_onehot),
+                logits_at=jnp.reshape(length - 1, (1,)),
             )
-            return logits[0, length - 1], new_cache
+            return logits[0, 0], new_cache
 
         def decode(params, cache, last_tokens, lengths, temps, rng):
             # one token for every slot: tokens (B,), lengths (B,) = count
@@ -228,6 +268,30 @@ class LlamaEngine:
             free_slots=list(range(self.max_batch)),
             index=len(self.shards),
         )
+
+    def warm_up(self) -> None:
+        """Run every program once (each chunk bucket into slot 0 of the
+        first shard, then decode on its scratch row) and wait for them,
+        so that no request pays a compile. Counts nothing in ``stats``
+        and leaves the sampling key as it was; the rows it writes are
+        overwritten by the slot's next prompt before anything attends to
+        them. The engine must be idle."""
+        with self._lock:
+            if self.num_active():
+                raise RuntimeError("warm_up needs an idle engine")
+            shard = self.shards[0]
+            onehot = np.zeros(self.max_batch, np.float32)
+            onehot[0] = 1.0
+            for bucket in self.buckets:
+                _, shard.cache = self._prefill(
+                    self.params, shard.cache, np.zeros((1, bucket), np.int32),
+                    onehot, np.zeros(1, np.int32), 1, bucket=bucket)
+            toks, shard.cache, _ = self._decode(
+                self.params, shard.cache, np.zeros(self.max_batch, np.int32),
+                np.full(self.max_batch, self.max_seq - 1, np.int32),
+                np.zeros(self.max_batch, np.float32), self._rng)
+            self._jax.block_until_ready((toks, shard.cache))
+        self._buckets_run.update(self.buckets)
 
     # ------------------------------------------------------------------
     def has_capacity(self) -> bool:
@@ -334,6 +398,7 @@ class LlamaEngine:
         req.prefill_chunks += 1
         stats.prefill_chunks += 1
         stats.prefill_tokens += chunk
+        stats.prefill_rows += bucket
         if req.prefill_pos < n:
             return
         # prompt complete: first generated token from the last logits

@@ -27,7 +27,12 @@ class LLMConfig:
     max_batch_size: int = 8
     max_seq_len: int = 512
     accelerator_type: str = "TPU"
-    # engine extras (temperature defaults etc.)
+    # passed to LlamaEngine (max_slots=...). The prefill chunk is not
+    # among what a deployment sets: the engine takes the roofline's
+    # ridge of its chip (FLOPs per HBM byte, times the weights' bytes
+    # per parameter over 2) as a rule of thumb, 256 rows of bf16 on a
+    # v5e, the one chip it was measured on. The server runs every engine
+    # program once (LlamaEngine.warm_up) before it takes a request
     engine_kwargs: Dict[str, Any] = field(default_factory=dict)
     # LoRA multiplexing (reference: ray.llm LoraConfig):
     #   {"dynamic_lora_loading_path": dir with <adapter_id>.npz,

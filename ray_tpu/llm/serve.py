@@ -44,6 +44,7 @@ class LLMServer:
             max_seq=llm_config.max_seq_len,
             **llm_config.engine_kwargs,
         )
+        self.engine.warm_up()  # no request pays a compile
         # LoRA multiplexing: adapter id -> folded-weights engine, LRU-
         # capped (never evicting active engines — which is why this is
         # a hand-rolled cache rather than @serve.multiplexed); loaded
@@ -96,9 +97,10 @@ class LLMServer:
         (LRU-capped per lora_config.max_adapters_per_replica).
 
         Callers invoke this at SUBMISSION time (their own thread) so a
-        cold load — disk read + fold + KV-cache alloc + first XLA
-        compile — never stalls the batching loop's token emission for
-        other requests; the loop only re-resolves on the rare
+        cold load — disk read + fold + KV-cache alloc + the engine's
+        ``warm_up()`` (every program compiled or loaded, and run once) —
+        never stalls the batching loop's token emission for other
+        requests; the loop only re-resolves on the rare
         submitted-then-evicted race."""
         with self._engines_lock:
             eng = self._engines.get(adapter_id)
@@ -163,6 +165,7 @@ class LLMServer:
                 max_seq=self.config.max_seq_len,
                 **self.config.engine_kwargs,
             )
+            eng.warm_up()
         finally:
             with self._engines_lock:
                 self._loading.discard(adapter_id)

@@ -465,10 +465,16 @@ def forward_with_cache(
     config: LlamaConfig,
     *,
     slot: Optional[jax.Array] = None,
+    logits_at: Optional[jax.Array] = None,
 ):
     """Incremental forward: tokens (B, T) appended at per-sequence
     offsets ``start_pos`` (B,). Returns (logits (B, T, V) fp32, updated
     cache). T is static (bucketed by the engine); start_pos is traced.
+
+    With ``logits_at`` (B,), the row of each sequence's T whose logits
+    the caller keeps, the final norm and the head run on those rows
+    alone and the logits are (B, 1, V): a prefill chunk samples from
+    its last real token only.
 
     Row ``b`` of ``tokens`` belongs to row ``b`` of the cache, or to row
     ``slot + b`` where ``slot`` (a traced scalar) is given: the engine
@@ -518,6 +524,17 @@ def forward_with_cache(
                 v_all = write(v_all, v, i)
             with jax.named_scope("kv_slice"):
                 k_c, v_c = read(k_all, i), read(v_all, i)
+                if T >= 128:
+                    # From a chunk of 128 rows (a register's lanes) the
+                    # TPU compiler gives QK^T its K with the rows minor.
+                    # Read straight off the carry, that layout goes to
+                    # the whole stack, and the scan is bracketed by two
+                    # transposing copies of the shard. Behind the
+                    # barrier only these sequences' rows of this layer
+                    # are laid out anew. Under 128 rows there are no
+                    # such copies and the barrier would only add one.
+                    # (tests/aot_compile_check.py compiles both sides.)
+                    k_c = jax.lax.optimization_barrier(k_c)
             with jax.named_scope("attn_cached"):
                 attn = _attention_cached(q, k_c, v_c, pos, c)
             x = x + jnp.einsum(
@@ -531,6 +548,8 @@ def forward_with_cache(
             body, (x, cache["k"], cache["v"], jnp.int32(0)), params["blocks"]
         )
     with jax.named_scope("head"):
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = jnp.einsum(
             "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import sys
 import time
 from functools import partial
@@ -55,7 +56,7 @@ def main() -> int:
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     failures = 0
 
-    def check(name, lower, expect=()):
+    def check(name, lower, expect=(), forbid=None):
         nonlocal failures
         t0 = time.perf_counter()
         try:
@@ -66,15 +67,18 @@ def main() -> int:
             return
         text = compiled.as_text()
         missing = [n for n in expect if n not in text]
+        unwanted = re.findall(forbid, text) if forbid else []
         mem = compiled.memory_analysis()
-        if missing:
+        if missing or unwanted:
             failures += 1
         print(
-            f"{'FAIL' if missing else 'ok  '} {name}: "
+            f"{'FAIL' if missing or unwanted else 'ok  '} {name}: "
             f"{time.perf_counter() - t0:.1f}s, arguments "
             f"{mem.argument_size_in_bytes / 1e9:.2f} GB + temporaries "
             f"{mem.temp_size_in_bytes / 1e9:.2f} GB per device"
             + (f", kernels missing from the program: {missing}" if missing else "")
+            + (f", instructions that must not be there: {unwanted}"
+               if unwanted else "")
         )
 
     quick = "--quick" in sys.argv[1:]
@@ -138,6 +142,38 @@ def main() -> int:
             partial(llama.loss_fn, config=cfg), opt, mesh, shardings
         )
         return step.lower(state, batch)
+
+    # two layers: one makes no scan, and nothing to bracket
+    serve_cfg = dataclasses.replace(
+        cfg, n_layers=2, remat=False, attention_impl="xla")
+    abstract = partial(jax.tree.map, lambda a: sds(a.shape, a.dtype))
+    cache = abstract(jax.eval_shape(
+        partial(llama.init_kv_cache, serve_cfg, 8, 2048)))
+
+    def prefill_chunk(rows):
+        """What the engine's prefill program does with a chunk of one
+        sequence: its rows into one slot of a donated cache shard."""
+        def chunk(params, cache, tokens, start, slot, at):
+            return llama.forward_with_cache(
+                params, tokens, cache, start, serve_cfg, slot=slot,
+                logits_at=at)
+
+        params = abstract(jax.eval_shape(
+            partial(llama.init_params, config=serve_cfg), jax.random.PRNGKey(0)))
+        return jax.jit(chunk, donate_argnums=(1,)).lower(
+            params, cache, sds((1, rows), jnp.int32), sds((1,), jnp.int32),
+            sds((), jnp.int32), sds((1,), jnp.int32))
+
+    # the cache is updated in place: no instruction copies a whole leaf
+    # of the shard (layout assignment once bracketed the layer scan with
+    # two, for chunks of 128 rows and more). The three buckets of a v5e's
+    # derived chunk: 64 rows run without forward_with_cache's barrier,
+    # 128 and 256 with it
+    shard = ",".join(str(d) for d in cache["k"].shape)
+    for rows in (64, 128, 256):
+        check(f"prefill chunk of {rows} rows into one slot of 8 x 2048, "
+              "one device",
+              partial(prefill_chunk, rows), forbid=rf"\[{shard}\]\S* copy\(")
 
     for n in (1, 4):
         check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "

@@ -111,6 +111,123 @@ def test_chunked_prefill_decodes_while_prefilling(engine_setup):
     assert solo.generate(list(range(1, 200)), max_tokens=4) == long.generated
 
 
+@pytest.mark.parametrize("kind, dtype, max_seq, want", [
+    # peak FLOP/s over HBM bytes/s, times bytes a parameter over 2, to
+    # the nearest power of two: 240 rows of bf16 on a v5e
+    ("TPU v5 lite", "bfloat16", 2048, 256),
+    ("TPU v5 lite", "bfloat16", 4096, 256),
+    ("TPU v5 lite", "float32", 4096, 512),    # twice the bytes to read
+    ("TPU v5 lite", "int8", 4096, 128),
+    ("TPU v4", "bfloat16", 2048, 256),        # 224
+    ("TPU v5p", "bfloat16", 8192, 128),       # 166
+    ("TPU v6 lite", "bfloat16", 8192, 512),   # 560
+    ("cpu", "bfloat16", 2048, 128),           # unknown: the smallest ratio
+    ("some later chip", "float32", 2048, 256),
+    ("TPU v5 lite", "bfloat16", 64, 64),      # capped by max_seq
+    ("TPU v5 lite", "bfloat16", 384, 128),    # halved until it divides
+])
+def test_prefill_chunk_is_derived_from_the_chip(kind, dtype, max_seq, want):
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    import jax.numpy as jnp
+
+    chunk = derived_prefill_chunk(kind, jnp.dtype(dtype).itemsize, max_seq)
+    assert chunk == want and max_seq % chunk == 0
+
+
+@pytest.mark.parametrize("asked, chunk, buckets", [
+    (None, 256, [64, 128, 256]),   # float32 weights, a kind not in the table
+    (64, 64, [16, 32, 64]),        # an explicit size wins
+    (16, 16, [16]),
+    (512, 256, [64, 128, 256]),    # and is fitted to max_seq as before
+])
+def test_engine_chunk_and_its_three_buckets(engine_setup, asked, chunk,
+                                            buckets):
+    import jax
+
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    cfg, params = engine_setup
+    kw = {} if asked is None else {"prefill_chunk": asked}
+    eng = LlamaEngine(cfg, params, max_batch=2, max_seq=256, **kw)
+    assert (eng.prefill_chunk, eng.buckets) == (chunk, buckets)
+    if asked is None:
+        assert chunk == derived_prefill_chunk(
+            jax.devices()[0].device_kind, 4, 256)
+    # no more compiled programs than before: decode and three buckets
+    eng.warm_up()
+    assert sorted(eng.compiled_programs()) == sorted(
+        ["decode"] + [f"prefill_{b}" for b in buckets])
+
+
+@pytest.mark.parametrize("chunk", [16, 64, None])
+def test_chunked_prefill_matches_one_full_forward(engine_setup, chunk):
+    """A prompt prefilled through the engine's program in chunks of 16,
+    of 64 and of the derived size: every call's logits, which the head
+    computes for the one row it returns, equal that row of the logits
+    over all the chunk's rows to the last bit; the first token and the
+    cache rows are those of the whole prompt in one call."""
+    import jax.numpy as jnp
+
+    cfg, params = engine_setup
+    prompt = [1 + (7 * j) % 500 for j in range(150)]
+    kw = dict(max_batch=2, max_seq=256)
+    eng = LlamaEngine(cfg, params, prefill_chunk=chunk, **kw)
+    onehot = np.zeros(2, np.float32)
+    onehot[1] = 1.0
+    all_rows = eng._jax.jit(
+        lambda cache, tokens, start: llama.forward_with_cache(
+            params, tokens, cache, start, cfg, slot=jnp.int32(1))[0])
+
+    def prefill(eng):
+        shard = eng.shards[0]
+        for pos in range(0, len(prompt), eng.prefill_chunk):
+            part = prompt[pos:pos + eng.prefill_chunk]
+            bucket = next(b for b in eng.buckets if b >= len(part))
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :len(part)] = part
+            start = np.asarray([pos], np.int32)
+            want = all_rows(shard.cache, tokens, start)[0, len(part) - 1]
+            got, shard.cache = eng._prefill(
+                eng.params, shard.cache, tokens, onehot, start, len(part),
+                bucket=bucket)
+            assert got.dtype == jnp.float32 and got.shape == (cfg.vocab_size,)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return np.asarray(got), shard.cache
+
+    got, cache = prefill(eng)
+    whole, whole_cache = prefill(
+        LlamaEngine(cfg, params, prefill_chunk=256, **kw))
+    full = llama.forward(params, jnp.asarray([prompt]), cfg)[0, -1]
+    assert got.argmax() == whole.argmax() == int(full.argmax())
+    np.testing.assert_allclose(got, whole, atol=0.1)
+    for name in ("k", "v"):
+        rows, whole_rows = (np.asarray(c[name][:, 1, :, :len(prompt)],
+                                       np.float32) for c in (cache, whole_cache))
+        assert np.abs(whole_rows).max() > 0.5
+        np.testing.assert_allclose(rows, whole_rows, atol=0.06)
+        # the other slot was never written
+        assert not np.asarray(cache[name][:, 0], np.float32).any()
+
+
+def test_warm_up_runs_every_program_and_changes_no_answer(engine_setup):
+    cfg, params = engine_setup
+    kw = dict(max_batch=2, max_seq=128)
+    eng, fresh = LlamaEngine(cfg, params, **kw), LlamaEngine(cfg, params, **kw)
+    eng.warm_up()
+    assert (eng.stats.prefill_chunks, eng.stats.decode_calls) == (0, 0)
+    assert not eng.num_active()
+    prompt = list(range(1, 100))
+    # the sampling key is as it was: sampled answers agree too
+    warm, cold = ([e.generate(prompt[:n], max_tokens=5, temperature=t)
+                   for n, t in [(99, 0.0), (3, 0.0), (40, 0.7)]]
+                  for e in (eng, fresh))
+    assert warm == cold
+    assert eng.add_request(GenRequest("r", prompt, max_tokens=5))
+    with pytest.raises(RuntimeError, match="idle"):
+        eng.warm_up()
+
+
 def test_slot_growth_beyond_max_batch(engine_setup):
     """More concurrent requests than max_batch: the engine grows by
     cache shards (same compiled programs) up to max_slots."""
@@ -276,6 +393,61 @@ def test_engine_serves_on_after_a_fault_and_abort_all(engine_setup, fault):
     fresh = LlamaEngine(cfg, params, **kw)
     assert eng.generate(prompt, max_tokens=6) == fresh.generate(
         prompt, max_tokens=6)
+
+
+@pytest.fixture(scope="module")
+def started_server():
+    """An in-process LLMServer, and the number of programs lowered
+    since it started (the event the benchmark's
+    ``nothing_compiled_in_window`` counts; it fires on a cache hit too)."""
+    import jax.monitoring
+
+    from ray_tpu.llm.serve import LLMServer
+
+    server = LLMServer(LLMConfig(
+        model_config=tiny_cfg(), max_batch_size=2, max_seq_len=64))
+    lowered = []
+
+    def on_event(event, duration, **kwargs):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    yield server, lowered
+    server.shutdown()
+    jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+def test_a_started_server_lowers_no_program_for_any_prompt(started_server):
+    """LLMServer runs every engine program before it takes a request:
+    prompts of every length up to max_seq - 1 reach every chunk bucket
+    and the decode program, and nothing is lowered for them."""
+    server, lowered = started_server
+    eng = server.engine
+    assert eng.buckets == [16, 32, 64] and eng.stats.prefill_chunks == 0
+    for n in range(1, eng.max_seq):
+        out = server.generate([1 + i % 7 for i in range(n)], max_tokens=3)
+        # a sequence ends before the cache's scratch row
+        assert len(out) == 3 or (n > eng.max_seq - 6 and out)
+    assert lowered == []
+    assert eng._buckets_run == set(eng.buckets)
+
+
+def test_engine_stats_count_rows_beside_tokens(started_server):
+    """``prefill_rows`` is what the calls computed (their buckets),
+    ``prefill_tokens`` what of it was prompt: their quotient is how full
+    the chunks were."""
+    server, _ = started_server
+    server.generate(list(range(1, 40)), max_tokens=2)   # 39 tokens in 64 rows
+    stats = server.engine_stats()["engine"]
+    assert stats["prefill_rows"] >= stats["prefill_tokens"] > 0
+    assert stats["prefill_rows"] % 16 == 0
+    before = stats
+    server.generate(list(range(1, 18)), max_tokens=2)   # 17 tokens in 32 rows
+    after = server.engine_stats()["engine"]
+    assert after["prefill_tokens"] - before["prefill_tokens"] == 17
+    assert after["prefill_rows"] - before["prefill_rows"] == 32
+    assert after["prefill_chunks"] - before["prefill_chunks"] == 1
 
 
 def test_generation_from_checkpoint(engine_setup, tmp_path):
