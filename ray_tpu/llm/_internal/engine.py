@@ -5,7 +5,7 @@ The role vLLM plays for the reference's ray.llm
 re-designed for XLA instead of wrapped:
 
 - Slot-based continuous batching: cache SHARDS of ``max_batch`` slots;
-  every decode step advances one shard's active slots in one jitted
+  a decode call advances one shard's active slots in one jitted
   (B, 1) program (static shapes; no recompiles as requests come and
   go). When all slots are busy the engine GROWS by allocating another
   shard — same compiled programs, more concurrent sequences — up to
@@ -40,13 +40,41 @@ re-designed for XLA instead of wrapped:
   ``shard.cache`` from the result, and ``abort_all()`` re-allocates the
   cache of a shard whose failed call took it along. Per-slot lengths
   mask attention (models/llama.py forward_with_cache).
-- Sampling (greedy / temperature) is jitted with the decode step.
+- Sampling (greedy / temperature) is jitted with the decode step; a
+  prompt's first token is drawn the same way by ``first_token``, a
+  program of a few instructions over the logits its last chunk returned.
+- The host runs ONE decode step behind the device. ``step()`` first
+  dispatches and reads nothing: every shard's prefill chunk, then every
+  shard's decode N+1, whose ``last_tokens`` is the device array that
+  shard's decode N returned (``shard.tokens``; ``first_token`` writes a
+  finished prompt's first token into its lane there, so the lane joins
+  without the host having seen it) and whose ``lengths`` is the host's
+  own count. Only then does it read decode N's tokens (dispatched by the
+  call before), book them, read the first token of a prompt this call
+  finished (ready when its chunk ends, ahead of the decodes just
+  queued), and return those pairs. While the caller hands tokens on and
+  admits, the device runs N+1. Endings by count (``max_tokens``, the
+  cache's end) are known before dispatch: such a lane is not dispatched
+  again. An ``eos_id`` ending is known at the read, when the lane has
+  ridden one decode more: that token is never read, emitted or appended
+  (``lanes_discarded``), and the cache row it wrote lies beyond the
+  freed slot's length, where the slot's next prompt or decode writes
+  before anything attends. The queue is one decode a shard deep, never
+  deeper, and empty whenever ``num_active()`` is 0. A greedy request's
+  tokens are exactly those of a loop that reads every call before the
+  next; a sampled one's are drawn from the same keys in dispatch order
+  (all chunks of a step before its decodes).
 - Observation: every request carries five monotonic stamps (its four
   phases: queue_wait, prefill_wait, prefill, decode), ``EngineStats``
   counts what the steps did, and each part of ``step()`` is a
   ``tracing.phase`` span (``ray_tpu.llm.*`` in a profiler trace, on the
-  device's clock). The jitted programs carry a ``sample`` scope next to
-  the model's own (``kv_write``, ``kv_slice``, ``attn_cached``, ...).
+  device's clock). ``llm.decode_sync`` is the wait for step N's tokens
+  while N+1 runs, ``llm.first_token_sync`` the wait for a chunk dispatched
+  in the same call; a ``step()`` lasts what the device needs for one
+  round of programs, not one call's dispatch-to-read. ``decode_ahead`` /
+  ``decode_calls`` is how often the device had its next decode queued.
+  The jitted programs carry a ``sample`` scope next to the model's own
+  (``kv_write``, ``kv_slice``, ``attn_cached``, ...).
 """
 
 from __future__ import annotations
@@ -127,14 +155,25 @@ class GenRequest:
 
 @dataclass
 class _Shard:
-    """One (B, max_seq) KV cache block plus its slot bookkeeping."""
+    """One (B, max_seq) KV cache block plus its slot bookkeeping.
+    ``lengths`` counts a slot's cache rows as the host has dispatched
+    them, which is one decode ahead of what it has read."""
 
     cache: Any
     lengths: np.ndarray
     free_slots: List[int]
     index: int = 0
+    # a request stays active until its last token has been read
     active: Dict[int, GenRequest] = field(default_factory=dict)
     prefilling: "deque[GenRequest]" = field(default_factory=deque)
+    # every lane's last token, on the device: what the newest decode
+    # returned, a finished prompt's first token written into its lane
+    tokens: Any = None
+    # the decode dispatched and not read yet: its tokens and the lanes
+    # (slot, request) it advanced
+    unread: Optional[Tuple[Any, List[Tuple[int, GenRequest]]]] = None
+    # a finished prompt's first token, not read yet: (request, scalar)
+    first: Optional[Tuple[GenRequest, Any]] = None
 
 
 class EngineStats:
@@ -150,6 +189,9 @@ class EngineStats:
         "prefill_chunks",
         "prefill_tokens", "prefill_rows",  # real tokens; the buckets' rows
         "decode_calls", "decode_lanes_active", "decode_lanes_total",
+        # decode calls dispatched while the shard's last one was unread;
+        # lane-steps computed for a request that had already ended
+        "decode_ahead", "lanes_discarded",
         "shards_grown", "requests_finished", "requests_refused",
     )
 
@@ -201,7 +243,6 @@ class LlamaEngine:
         self.max_slots = max(max_batch, (want_slots // max_batch) * max_batch)
         self._rng = jax.random.PRNGKey(seed)
         self._jax = jax
-        self._jnp = jnp
         self._llama = llama
         self.stats = EngineStats()
         self.shards: List[_Shard] = []
@@ -244,22 +285,39 @@ class LlamaEngine:
                 toks = jnp.where(temps > 0, sampled, greedy)
                 return toks.astype(jnp.int32), new_cache, keys[0]
 
-        self._prefill, self._decode = self._jit_programs(prefill, decode)
-        self._program_fns = (prefill, decode)  # for compiled_programs()
+        def first_token(tokens, logits, slot, temp, rng):
+            # a finished prompt's first token, drawn as decode draws and
+            # written into its lane of the shard's token vector; the key
+            # moves only for a sampled request
+            with jax.named_scope("sample"):
+                keys = jax.random.split(rng)
+                sampled = jax.random.categorical(
+                    keys[1], logits / jnp.maximum(temp, 1e-4))
+                tok = jnp.where(temp > 0, sampled, jnp.argmax(logits))
+                tok = tok.astype(jnp.int32)
+                return (tokens.at[slot].set(tok), tok,
+                        jnp.where(temp > 0, keys[0], rng))
+
+        self._program_fns = (prefill, decode, first_token)
+        self._prefill, self._decode, self._first_token = self._jit_programs(
+            *self._program_fns)
         self._buckets_run: set = set()
         self._lock = threading.Lock()
 
-    def _jit_programs(self, prefill, decode):
+    def _jit_programs(self, prefill, decode, first_token):
         """The cache (argument 1) is donated: with the model's carried
         scan the programs update it in place, and the buffer a caller
         passed in is gone once the call is dispatched."""
         jit = self._jax.jit
         return (jit(prefill, static_argnames=("bucket",), donate_argnums=(1,)),
-                jit(decode, donate_argnums=(1,)))
+                jit(decode, donate_argnums=(1,)), jit(first_token))
 
     def _new_cache(self):
         return self._llama.init_kv_cache(
             self.config, self.max_batch, self.max_seq)
+
+    def _new_tokens(self):
+        return self._jax.device_put(np.zeros(self.max_batch, np.int32))
 
     def _new_shard(self) -> _Shard:
         return _Shard(
@@ -267,15 +325,17 @@ class LlamaEngine:
             lengths=np.zeros(self.max_batch, dtype=np.int32),
             free_slots=list(range(self.max_batch)),
             index=len(self.shards),
+            tokens=self._new_tokens(),
         )
 
     def warm_up(self) -> None:
         """Run every program once (each chunk bucket into slot 0 of the
-        first shard, then decode on its scratch row) and wait for them,
-        so that no request pays a compile. Counts nothing in ``stats``
-        and leaves the sampling key as it was; the rows it writes are
-        overwritten by the slot's next prompt before anything attends to
-        them. The engine must be idle."""
+        first shard, a first token off the last one's logits, then decode
+        on its scratch row, its tokens on the device as ``step()`` passes
+        them) and wait for them, so that no request pays a compile.
+        Counts nothing in ``stats`` and leaves the sampling key as it
+        was; the rows it writes are overwritten by the slot's next prompt
+        before anything attends to them. The engine must be idle."""
         with self._lock:
             if self.num_active():
                 raise RuntimeError("warm_up needs an idle engine")
@@ -283,11 +343,13 @@ class LlamaEngine:
             onehot = np.zeros(self.max_batch, np.float32)
             onehot[0] = 1.0
             for bucket in self.buckets:
-                _, shard.cache = self._prefill(
+                logits, shard.cache = self._prefill(
                     self.params, shard.cache, np.zeros((1, bucket), np.int32),
                     onehot, np.zeros(1, np.int32), 1, bucket=bucket)
+            tokens, _, _ = self._first_token(
+                shard.tokens, logits, np.int32(0), np.float32(0), self._rng)
             toks, shard.cache, _ = self._decode(
-                self.params, shard.cache, np.zeros(self.max_batch, np.int32),
+                self.params, shard.cache, tokens,
                 np.full(self.max_batch, self.max_seq - 1, np.int32),
                 np.zeros(self.max_batch, np.float32), self._rng)
             self._jax.block_until_ready((toks, shard.cache))
@@ -300,6 +362,9 @@ class LlamaEngine:
         return len(self.shards) * self.max_batch < self.max_slots
 
     def num_active(self) -> int:
+        """Requests with a slot. At 0 nothing is in flight either: a
+        request is active until its last token has been read, and a
+        result that no live request waits for is dropped in ``step()``."""
         return sum(
             len(s.active) + len(s.prefilling) for s in self.shards
         )
@@ -312,10 +377,11 @@ class LlamaEngine:
         return out
 
     def abort_all(self) -> List[GenRequest]:
-        """Drop every in-flight request (engine fault path); returns
-        them so the caller can fail their waiters. A call that failed
-        after it was dispatched has taken the shard's donated cache with
-        it: such a shard gets a new one, so the engine can go on."""
+        """Drop every in-flight request (engine fault path) and every
+        result not read yet; returns the requests so the caller can fail
+        their waiters. A call that failed after it was dispatched has
+        taken the shard's donated cache with it: such a shard gets a new
+        one, so the engine can go on."""
         with self._lock:
             dropped = self.in_flight_requests()
             for s in self.shards:
@@ -323,6 +389,9 @@ class LlamaEngine:
                     self._release(s, s.active.pop(slot))
                 while s.prefilling:
                     self._release(s, s.prefilling.popleft())
+                # a failed call's tokens would fail the next one too
+                s.unread = s.first = None
+                s.tokens = self._new_tokens()
                 if any(leaf.is_deleted() for leaf in s.cache.values()):
                     s.cache = self._new_cache()
             return dropped
@@ -371,14 +440,16 @@ class LlamaEngine:
 
     def _pump_prefill(self, shard: _Shard, out: List[Tuple[GenRequest, int]]):
         """Write ONE chunk of the oldest pending prompt into the cache;
-        on prompt completion, sample the first token and activate the
-        slot for decoding."""
+        on prompt completion its first token is drawn on the device and
+        written into its lane, which joins the next decode without the
+        host having seen it. ``step()`` reads it (``shard.first``) and
+        puts it in ``out``."""
         if not shard.prefilling:
             return
         req = shard.prefilling[0]
         stats = self.stats
-        ids = {"request_id": req.request_id, "shard": shard.index}
-        with phase("llm.prefill_dispatch", stats.phases, **ids):
+        with phase("llm.prefill_dispatch", stats.phases,
+                   request_id=req.request_id, shard=shard.index):
             n = len(req.prompt_ids)
             pos = req.prefill_pos
             chunk = min(self.prefill_chunk, n - pos)
@@ -393,112 +464,145 @@ class LlamaEngine:
                 self.params, shard.cache, tokens, onehot,
                 np.asarray([pos], np.int32), chunk, bucket=bucket,
             )
+            if pos + chunk == n:  # prompt complete: the lane goes live
+                shard.tokens, tok, self._rng = self._first_token(
+                    shard.tokens, last_logits, np.int32(req.slot),
+                    np.float32(req.temperature), self._rng)
+                shard.prefilling.popleft()
+                shard.lengths[req.slot] = n
+                shard.active[req.slot] = req
+                shard.first = (req, tok)
         self._buckets_run.add(bucket)
         req.prefill_pos = pos + chunk
         req.prefill_chunks += 1
         stats.prefill_chunks += 1
         stats.prefill_tokens += chunk
         stats.prefill_rows += bucket
-        if req.prefill_pos < n:
-            return
-        # prompt complete: first generated token from the last logits
-        shard.prefilling.popleft()
-        with phase("llm.first_token_sync", stats.phases, **ids):
-            lg = np.asarray(last_logits)
-            if req.temperature > 0:
-                self._rng, sub = self._jax.random.split(self._rng)
-                tok = int(self._jax.random.categorical(
-                    sub, self._jnp.asarray(lg) / max(req.temperature, 1e-4)))
-            else:
-                tok = int(lg.argmax())
-        req.first_token = time.monotonic()
+
+    def _last_by_count(self, req: GenRequest, count: int) -> bool:
+        """Whether a request's ``count``-th token is its last whatever
+        it is: ``max_tokens`` reached, or (from the second on) the cache
+        row before the scratch row. Known before the token is."""
+        return count >= req.max_tokens or (
+            count > 1 and len(req.prompt_ids) + count >= self.max_seq - 1)
+
+    def _dispatch_decode(self, shard: _Shard):
+        """Dispatch one decode for the shard's lanes that have a token
+        to come, fed the device's own last tokens; returns what
+        ``shard.unread`` will hold, None if no lane wants one."""
+        stats = self.stats
+        # tokens a lane has coming: its first and a decode a row written
+        lanes = [(slot, req) for slot, req in shard.active.items()
+                 if not self._last_by_count(
+                     req, shard.lengths[slot] - len(req.prompt_ids) + 1)]
+        if not lanes:
+            return None
+        self.peak_active = max(self.peak_active, len(lanes))
+        with phase("llm.decode_prepare", stats.phases, shard=shard.index):
+            temps = np.zeros(self.max_batch, np.float32)
+            # inactive lanes (free, mid-prefill or at their last token)
+            # still ride the batched decode; point their cache write at
+            # the scratch row (max_seq-1, provably never attended:
+            # sequences finish before reaching it) so they cannot
+            # corrupt a half-prefilled prompt's rows
+            lens = np.full(self.max_batch, self.max_seq - 1, np.int32)
+            for slot, req in lanes:
+                temps[slot] = req.temperature
+                lens[slot] = shard.lengths[slot]
+                # the decode consumes the lane's last token: account it
+                shard.lengths[slot] += 1
+        with phase("llm.decode_dispatch", stats.phases, shard=shard.index):
+            shard.tokens, shard.cache, self._rng = self._decode(
+                self.params, shard.cache, shard.tokens,
+                lens, temps, self._rng,
+            )
+        stats.decode_calls += 1
+        stats.decode_ahead += shard.unread is not None
+        stats.decode_lanes_active += len(lanes)
+        stats.decode_lanes_total += self.max_batch
+        return shard.tokens, lanes
+
+    def _take(self, shard: _Shard, req: GenRequest, tok: int,
+              out: List[Tuple[GenRequest, int]]):
         req.generated.append(tok)
-        shard.lengths[req.slot] = n
-        shard.active[req.slot] = req
         out.append((req, tok))
-        if (req.eos_id is not None and tok == req.eos_id) or (
-            len(req.generated) >= req.max_tokens
-        ):
+        if (req.eos_id is not None and tok == req.eos_id
+                or self._last_by_count(req, len(req.generated))):
             self._finish(shard, req.slot)
 
     def step(self) -> List[Tuple[GenRequest, int]]:
-        """One engine step: per shard, one prefill chunk (if a prompt is
-        pending) then one decode for every active slot. Returns
-        (request, new_token) pairs emitted this step — the FIRST token
-        of a request (sampled off its prefill) arrives here too."""
+        """One engine step, the host one decode behind the device (the
+        module docstring has the order and why). Dispatches every shard's
+        prefill chunk and decode, then returns the (request, token) pairs
+        of the decodes the call BEFORE this one dispatched and the first
+        token of a prompt this call finished. A call that finds nothing
+        unread (the first after an idle engine) returns no decode token;
+        while the caller hands the pairs on and admits, the device runs
+        the decodes of this call."""
         stats = self.stats
         with self._lock, phase("llm.step", stats.phases):
             out: List[Tuple[GenRequest, int]] = []
             for shard in self.shards:
                 self._pump_prefill(shard, out)
-                if not shard.active:
+            ahead = [self._dispatch_decode(shard) for shard in self.shards]
+            for shard in self.shards:
+                if shard.unread is None:
                     continue
-                self.peak_active = max(self.peak_active, len(shard.active))
-                with phase("llm.decode_prepare", stats.phases,
-                           shard=shard.index):
-                    last = np.zeros(self.max_batch, np.int32)
-                    temps = np.zeros(self.max_batch, np.float32)
-                    # inactive lanes (free or mid-prefill) still ride the
-                    # batched decode; point their cache write at the
-                    # scratch row (max_seq-1, provably never attended:
-                    # sequences finish before reaching it) so they cannot
-                    # corrupt a half-prefilled prompt's rows
-                    lens = np.full(self.max_batch, self.max_seq - 1, np.int32)
-                    for slot, req in shard.active.items():
-                        last[slot] = req.generated[-1]
-                        temps[slot] = req.temperature
-                        lens[slot] = shard.lengths[slot]
-                with phase("llm.decode_dispatch", stats.phases,
-                           shard=shard.index):
-                    toks, shard.cache, self._rng = self._decode(
-                        self.params, shard.cache, last,
-                        lens, temps, self._rng,
-                    )
-                stats.decode_calls += 1
-                stats.decode_lanes_active += len(shard.active)
-                stats.decode_lanes_total += self.max_batch
+                toks, lanes = shard.unread
                 with phase("llm.decode_sync", stats.phases,
                            shard=shard.index):
                     toks = np.asarray(toks)
                 with phase("llm.decode_bookkeep", stats.phases,
                            shard=shard.index):
-                    for slot in list(shard.active.keys()):
-                        req = shard.active[slot]
-                        # the decode consumed the previous token: account it
-                        shard.lengths[slot] += 1
-                        tok = int(toks[slot])
-                        req.generated.append(tok)
-                        out.append((req, tok))
-                        total_len = shard.lengths[slot] + 1
-                        if (
-                            (req.eos_id is not None and tok == req.eos_id)
-                            or len(req.generated) >= req.max_tokens
-                            or total_len >= self.max_seq - 1
-                        ):
-                            self._finish(shard, slot)
+                    for slot, req in lanes:
+                        self._take(shard, req, int(toks[slot]), out)
+            for shard in self.shards:
+                if shard.first is None:
+                    continue
+                req, tok = shard.first
+                shard.first = None
+                with phase("llm.first_token_sync", stats.phases,
+                           request_id=req.request_id, shard=shard.index):
+                    tok = int(tok)
+                req.first_token = time.monotonic()
+                self._take(shard, req, tok, out)
+            for shard, unread in zip(self.shards, ahead):
+                # a request an eos_id ended at this read has ridden the
+                # decode just dispatched: that token is never read (the
+                # cache row it wrote lies beyond anything attended to)
+                if unread is not None:
+                    toks, lanes = unread
+                    live = [(slot, req) for slot, req in lanes if not req.done]
+                    stats.lanes_discarded += len(lanes) - len(live)
+                    unread = (toks, live) if live else None
+                shard.unread = unread
             stats.steps += 1
             stats.tokens_emitted += len(out)
             return out
 
     def compiled_programs(self) -> Dict[str, Any]:
-        """The engine's programs as compiled executables: ``decode`` and
-        ``prefill_<bucket>`` for each chunk bucket run so far. For
-        reading their text (``jax_utils.scope_map``) next to a device
-        trace. Each is jitted afresh and compiled again (or loaded from
-        the persistent cache; ``compile_with_scopes`` says why), so call
-        this outside anything timed."""
+        """The engine's programs as compiled executables: ``decode``,
+        ``first_token`` and ``prefill_<bucket>`` for each chunk bucket
+        run so far. For reading their text (``jax_utils.scope_map``) next
+        to a device trace. Each is jitted afresh and compiled again (or
+        loaded from the persistent cache; ``compile_with_scopes`` says
+        why), so call this outside anything timed."""
         from ray_tpu._private.jax_utils import compile_with_scopes
 
         # new function objects under the old names: new traces, new modules
-        prefill, decode = self._jit_programs(
+        prefill, decode, first_token = self._jit_programs(
             *(wraps(fn)(partial(fn)) for fn in self._program_fns))
         cache = self.shards[0].cache
         i32, f32 = np.int32, np.float32
-        decode_args = (
-            np.zeros(self.max_batch, i32), np.zeros(self.max_batch, i32),
-            np.zeros(self.max_batch, f32), self._rng)
-        out = {"decode": compile_with_scopes(decode.lower(
-            self.params, cache, *decode_args))}
+        tokens = np.zeros(self.max_batch, i32)
+        out = {
+            "decode": compile_with_scopes(decode.lower(
+                self.params, cache, tokens, np.zeros(self.max_batch, i32),
+                np.zeros(self.max_batch, f32), self._rng)),
+            "first_token": compile_with_scopes(first_token.lower(
+                tokens, np.zeros(self.config.vocab_size, f32), i32(0), f32(0),
+                self._rng)),
+        }
         for bucket in sorted(self._buckets_run):
             out[f"prefill_{bucket}"] = compile_with_scopes(prefill.lower(
                 self.params, cache, np.zeros((1, bucket), i32),

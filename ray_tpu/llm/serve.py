@@ -205,13 +205,19 @@ class LLMServer:
                         if q is not None:
                             q.put(("error", e))
                     continue
+                # the device is running the decodes step() dispatched
+                # while this thread emits and, next round, admits
                 with tracing.phase("llm.emit", self._loop_phases):
+                    done = {}
                     for req, tok in emitted:
                         q = self._token_queues.get(req.request_id)
                         if q is not None:
                             q.put(("token", tok))
                             if req.done:
-                                q.put(("done", req))
+                                done[req.request_id] = (q, req)
+                    # behind every token of the step, the request's last too
+                    for q, req in done.values():
+                        q.put(("done", req))
             if not stepped and not admitted:
                 with tracing.phase("llm.idle_sleep", self._loop_phases):
                     time.sleep(0.005)

@@ -151,7 +151,7 @@ def test_engine_programs_are_exposed_for_their_text(tiny_params):
     eng = make_engine(tiny_params)
     eng.generate(list(range(1, 20)), max_tokens=2)   # buckets 16 only
     programs = eng.compiled_programs()
-    assert sorted(programs) == ["decode", "prefill_16"]
+    assert sorted(programs) == ["decode", "first_token", "prefill_16"]
     assert all(scope_map(c) for c in programs.values())
 
 
@@ -214,13 +214,14 @@ def test_profiler_trace_holds_the_engine_spans_nested(tiny_params, tmp_path):
     names = [e[2] for e in events]
     steps = [e for e in events if e[2] == "ray_tpu.llm.step"]
     assert len(steps) == 3
-    # r0: chunks in steps 1 and 2, first token in 2; r1's first chunk and
-    # r0's second decode in step 3
+    # r0: chunks in steps 1 and 2; step 2 also dispatches its first
+    # decode and then reads its first token; step 3 dispatches r1's first
+    # chunk and r0's second decode, and only then reads the first one
     assert names.count("ray_tpu.llm.prefill_dispatch") == 3
     assert names.count("ray_tpu.llm.first_token_sync") == 1
-    for part in ("decode_prepare", "decode_dispatch", "decode_sync",
-                 "decode_bookkeep"):
-        assert names.count(f"ray_tpu.llm.{part}") == 2
+    for part, times in (("decode_prepare", 2), ("decode_dispatch", 2),
+                        ("decode_sync", 1), ("decode_bookkeep", 1)):
+        assert names.count(f"ray_tpu.llm.{part}") == times
     for start, end, name, ids in events:
         if name == "ray_tpu.llm.step":
             continue
@@ -229,10 +230,13 @@ def test_profiler_trace_holds_the_engine_spans_nested(tiny_params, tmp_path):
         assert ids["shard"] == 0
         if "prefill" in name or "first_token" in name:
             assert ids["request_id"] in ("r0", "r1")
-    in_last = [e[2].rsplit(".", 1)[1] for e in events
-               if steps[2][0] <= e[0] and e[1] <= steps[2][1]]
-    assert in_last == ["step", "prefill_dispatch", "decode_prepare",
-                       "decode_dispatch", "decode_sync", "decode_bookkeep"]
+    in_step = [[e[2].rsplit(".", 1)[1] for e in events
+                if step[0] <= e[0] and e[1] <= step[1]] for step in steps]
+    # everything is dispatched before anything is read
+    assert in_step[1] == ["step", "prefill_dispatch", "decode_prepare",
+                          "decode_dispatch", "first_token_sync"]
+    assert in_step[2] == ["step", "prefill_dispatch", "decode_prepare",
+                          "decode_dispatch", "decode_sync", "decode_bookkeep"]
 
 
 # --------------------------------------------------------- named scopes

@@ -154,10 +154,10 @@ def test_engine_chunk_and_its_three_buckets(engine_setup, asked, chunk,
     if asked is None:
         assert chunk == derived_prefill_chunk(
             jax.devices()[0].device_kind, 4, 256)
-    # no more compiled programs than before: decode and three buckets
+    # decode, three buckets, and the first token's few instructions
     eng.warm_up()
     assert sorted(eng.compiled_programs()) == sorted(
-        ["decode"] + [f"prefill_{b}" for b in buckets])
+        ["decode", "first_token"] + [f"prefill_{b}" for b in buckets])
 
 
 @pytest.mark.parametrize("chunk", [16, 64, None])
@@ -348,7 +348,8 @@ def test_engine_programs_update_the_cache_in_place():
     instruction = re.compile(
         r"^\s+(?:ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
     programs = eng.compiled_programs()
-    assert sorted(programs) == ["decode", "prefill_16"]
+    assert sorted(programs) == ["decode", "first_token", "prefill_16"]
+    del programs["first_token"]            # takes no cache
     for name, compiled in programs.items():
         text = compiled.as_text()
         header = text.split("\n", 1)[0]   # input_output_alias={ {1}: (12, ...
@@ -393,6 +394,198 @@ def test_engine_serves_on_after_a_fault_and_abort_all(engine_setup, fault):
     fresh = LlamaEngine(cfg, params, **kw)
     assert eng.generate(prompt, max_tokens=6) == fresh.generate(
         prompt, max_tokens=6)
+
+
+# ---------------------------------------- the host one decode behind
+AHEAD_KW = dict(max_batch=2, max_seq=128, prefill_chunk=16, max_slots=6)
+
+
+def plain_loop(eng, prompt, max_tokens, eos_id=None):
+    """Greedy tokens of one prompt through the engine's two programs in
+    slot 0 of its first shard, every call read on the host before the
+    next is dispatched: what ``step()`` did before it ran ahead."""
+    shard = eng.shards[0]
+    onehot = np.zeros(eng.max_batch, np.float32)
+    onehot[0] = 1.0
+    for pos in range(0, len(prompt), eng.prefill_chunk):
+        part = prompt[pos:pos + eng.prefill_chunk]
+        bucket = next(b for b in eng.buckets if b >= len(part))
+        tokens = np.zeros((1, bucket), np.int32)
+        tokens[0, :len(part)] = part
+        logits, shard.cache = eng._prefill(
+            eng.params, shard.cache, tokens, onehot,
+            np.asarray([pos], np.int32), len(part), bucket=bucket)
+    out = [int(np.asarray(logits).argmax())]
+    lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
+    temps = np.zeros(eng.max_batch, np.float32)
+    while len(out) < max_tokens and out[-1] != eos_id and (
+            len(out) == 1 or len(prompt) + len(out) < eng.max_seq - 1):
+        last = np.zeros(eng.max_batch, np.int32)
+        last[0], lens[0] = out[-1], len(prompt) + len(out) - 1
+        toks, shard.cache, _ = eng._decode(
+            eng.params, shard.cache, last, lens, temps, eng._rng)
+        out.append(int(np.asarray(toks)[0]))
+    return out
+
+
+def _prompt(n, k=0):
+    return [1 + (5 * j + 11 * k) % 500 for j in range(n)]
+
+
+def _run_dry(eng):
+    while eng.num_active():
+        eng.step()
+
+
+def _three_shards_of_mixed_lengths(eng, plain):
+    reqs = [GenRequest(f"r{i}", _prompt(n, i), max_tokens=5 + i)
+            for i, n in enumerate([3, 20, 40, 7, 33, 17])]
+    for r in reqs:
+        assert eng.add_request(r)
+    assert len(eng.shards) == 3
+    _run_dry(eng)
+    s = eng.stats
+    # every decode but each shard's first found the one before it unread
+    assert s.decode_ahead == s.decode_calls - 3 and s.lanes_discarded == 0
+    # up to the scratch row too
+    long = GenRequest("long", _prompt(100), max_tokens=64)
+    assert eng.add_request(long)
+    _run_dry(eng)
+    assert len(long.generated) == eng.max_seq - 1 - 100
+    return reqs + [long]
+
+
+def _eos_while_the_next_lane_is_in_flight(eng, plain):
+    stays = GenRequest("stays", _prompt(9, 1), max_tokens=20)
+    free = plain_loop(plain, _prompt(5), 6)
+    eos = free[2]
+    assert eos not in free[:2]
+    ends = GenRequest("ends", _prompt(5), max_tokens=20, eos_id=eos)
+    assert eng.add_request(stays) and eng.add_request(ends)
+    emitted = []
+    while not ends.done:
+        emitted += [tok for req, tok in eng.step() if req is ends]
+    # its lane rode one decode more: that token is nobody's
+    assert emitted == ends.generated == free[:3]
+    assert eng.stats.lanes_discarded == 1
+    # the freed slot's next prompt queues behind that decode at once; its
+    # own rows pass the row the discarded lane wrote (5 + 2)
+    reuses = GenRequest("reuses", _prompt(3, 2), max_tokens=10)
+    assert eng.add_request(reuses)
+    assert (reuses.shard, reuses.slot) == (ends.shard, ends.slot)
+    _run_dry(eng)
+    assert eng.stats.lanes_discarded == 1
+    # alone on its shard: the decode it rode last is dropped whole
+    alone = GenRequest("alone", _prompt(5), max_tokens=20, eos_id=eos)
+    assert eng.add_request(alone)
+    _run_dry(eng)
+    assert eng.stats.lanes_discarded == 2
+    return [stays, ends, reuses, alone]
+
+
+def _max_tokens(n):
+    def case(eng, plain):
+        reqs = [GenRequest(f"r{i}", _prompt(4 + 13 * i, i), max_tokens=n)
+                for i in range(3)]
+        for r in reqs:
+            assert eng.add_request(r)
+        _run_dry(eng)
+        assert [len(r.generated) for r in reqs] == [n] * 3
+        assert eng.stats.decode_lanes_active == 3 * (n - 1)
+        return reqs
+    return case
+
+
+def _last_chunk_lands_while_a_decode_is_in_flight(eng, plain):
+    runs = GenRequest("runs", _prompt(6), max_tokens=30)
+    assert eng.add_request(runs)
+    while len(runs.generated) < 3:
+        eng.step()
+    late = GenRequest("late", _prompt(40, 3), max_tokens=8)
+    assert eng.add_request(late) and late.shard == runs.shard
+    shard = eng.shards[0]
+    while late.prefill_pos < len(late.prompt_ids):
+        in_flight = shard.unread
+        eng.step()
+    # the chunk went behind a decode nobody had read, and its first token
+    # into the lane of the decode dispatched in the same call
+    assert in_flight is not None and late.generated and shard.unread
+    assert late in [req for _, req in shard.unread[1]]
+    _run_dry(eng)
+    return [runs, late]
+
+
+def _abort_all_with_results_unread(eng, plain):
+    reqs = [GenRequest(f"r{i}", _prompt(5 + i, i), max_tokens=20)
+            for i in range(3)]
+    for r in reqs:
+        assert eng.add_request(r)
+    while not all(s.unread for s in eng.shards):
+        eng.step()
+    had = [list(r.generated) for r in reqs]
+    assert eng.abort_all() == reqs and not eng.num_active()
+    assert all(s.unread is None and s.first is None for s in eng.shards)
+    assert eng.step() == [] and [r.generated for r in reqs] == had
+    fresh = GenRequest("fresh", _prompt(21, 4), max_tokens=7)
+    assert eng.add_request(fresh)
+    _run_dry(eng)
+    return [fresh]
+
+
+AHEAD_CASES = {
+    "three_shards_of_mixed_lengths": _three_shards_of_mixed_lengths,
+    "eos_while_the_next_lane_is_in_flight":
+        _eos_while_the_next_lane_is_in_flight,
+    "max_tokens_1": _max_tokens(1),
+    "max_tokens_2": _max_tokens(2),
+    "max_tokens_3": _max_tokens(3),
+    "last_chunk_lands_while_a_decode_is_in_flight":
+        _last_chunk_lands_while_a_decode_is_in_flight,
+    "abort_all_with_results_unread": _abort_all_with_results_unread,
+}
+
+
+@pytest.mark.parametrize("case", list(AHEAD_CASES))
+def test_engine_a_step_ahead_gives_the_plain_loops_tokens(engine_setup, case):
+    """``step()`` dispatches every shard's programs before it reads a
+    token and feeds a decode the device's own last tokens. Every greedy
+    request still gets, token for token, what a loop gets that reads each
+    call of the same two programs before it dispatches the next."""
+    cfg, params = engine_setup
+    eng = LlamaEngine(cfg, params, **AHEAD_KW)
+    plain = LlamaEngine(cfg, params, **AHEAD_KW)
+    reqs = AHEAD_CASES[case](eng, plain)
+    assert not eng.num_active()
+    for r in reqs:
+        assert r.done and r.generated == plain_loop(
+            plain, r.prompt_ids, r.max_tokens, r.eos_id), r.request_id
+
+
+def test_an_idle_engine_has_nothing_in_flight(engine_setup):
+    """``num_active() == 0`` means no result is unread, also behind a
+    request that an eos_id ended with its next lane dispatched: so
+    ``warm_up()`` (and the benchmark's comparison through the same
+    programs) may follow traffic at once."""
+    cfg, params = engine_setup
+    eng = LlamaEngine(cfg, params, **AHEAD_KW)
+    plain = LlamaEngine(cfg, params, **AHEAD_KW)
+    eos = plain_loop(plain, _prompt(5), 2)[1]
+    reqs = [GenRequest("eos", _prompt(5), max_tokens=9, eos_id=eos),
+            GenRequest("count", _prompt(30, 1), max_tokens=4),
+            GenRequest("other_shard", _prompt(8, 2), max_tokens=6)]
+    for r in reqs:
+        assert eng.add_request(r)
+    was_busy = False
+    while eng.num_active():
+        eng.step()
+        was_busy |= any(s.unread is not None for s in eng.shards)
+        if not eng.num_active():
+            assert all(s.unread is None and s.first is None
+                       for s in eng.shards)
+    assert was_busy and eng.stats.lanes_discarded == 1
+    eng.warm_up()
+    prompt = _prompt(19, 3)
+    assert eng.generate(prompt, max_tokens=5) == plain_loop(plain, prompt, 5)
 
 
 @pytest.fixture(scope="module")
@@ -448,6 +641,25 @@ def test_engine_stats_count_rows_beside_tokens(started_server):
     assert after["prefill_tokens"] - before["prefill_tokens"] == 17
     assert after["prefill_rows"] - before["prefill_rows"] == 32
     assert after["prefill_chunks"] - before["prefill_chunks"] == 1
+
+
+@pytest.mark.parametrize("steps_a_call", [1, 2])
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_server_generate_returns_every_token_asked_for(
+        started_server, monkeypatch, n, steps_a_call):
+    """"done" goes on a request's queue behind every token of the step,
+    its last too: also where the first and the last token leave the
+    engine in one call (two steps made one here; before the engine ran a
+    step ahead, ``max_tokens=2`` did that by itself and lost a token)."""
+    server, _ = started_server
+    step = server.engine.step
+    monkeypatch.setattr(
+        server.engine, "step",
+        lambda: [pair for _ in range(steps_a_call) for pair in step()])
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+    got = server.generate(prompt, max_tokens=n)
+    monkeypatch.undo()
+    assert len(got) == n and got == server.generate(prompt, max_tokens=3)[:n]
 
 
 def test_generation_from_checkpoint(engine_setup, tmp_path):
