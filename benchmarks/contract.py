@@ -36,7 +36,7 @@ def check_last_line(obj, declared: Dict[str, str], *, traced: bool,
     if not isinstance(obj, dict):
         return ["the result is not a JSON object"]
     bad = []
-    allowed = set(KEYS) | ({"breakdown"} if traced else set())
+    allowed = set(KEYS) | {"compared"} | ({"breakdown"} if traced else set())
     for key in KEYS:
         if key not in obj:
             bad.append(f"key {key!r} is missing")
@@ -112,6 +112,16 @@ def check_last_line(obj, declared: Dict[str, str], *, traced: bool,
             bad.append(f"device.busy_s {busy} exceeds device.window_s "
                        f"{window}: is it summed over the chips instead of "
                        "averaged?")
+    if "compared" in obj:
+        c = obj["compared"]
+        if list(obj)[-1] != "compared":
+            bad.append("compared is not the line's last key")
+        if not isinstance(c, dict) or not c or not all(
+                isinstance(k, str) and NAME.match(k)
+                and isinstance(v, list) and len(v) == 2
+                and all(x is None or _number(x) for x in v)  # None: not finite
+                for k, v in c.items()):
+            bad.append("compared is not {name: [number, limit]}")
     if "breakdown" in obj:
         b = obj["breakdown"]
         if not isinstance(b, dict) or set(b) - {"device_ops", "idle_gaps"}:

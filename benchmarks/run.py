@@ -24,6 +24,7 @@ PROCESS_START = time.time()
 import argparse  # noqa: E402
 import ctypes  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import signal  # noqa: E402
 import sys  # noqa: E402
@@ -165,6 +166,13 @@ def main() -> int:
     }
     if args.trace and result["breakdown"]:
         last["breakdown"] = result["breakdown"]
+    # each number `correct` compared, beside its limit: last on the
+    # line, and the last lines of standard error
+    compared = {
+        name: [x if math.isfinite(x) else None for x in pair]   # valid JSON
+        for name, pair in result["notes"].get("compared", {}).items()}
+    if compared:
+        last["compared"] = compared
     problems = contract.check_last_line(
         last, {n: m["unit"] for n, m in declared.items()},
         traced=bool(args.trace), chips=chips,
@@ -175,6 +183,8 @@ def main() -> int:
             print(f"benchmarks/run.py: malformed result: {p}", file=sys.stderr)
             _note("malformed", p)
         return 1
+    for name, (value, limit) in compared.items():
+        print(f"compared {name}: {value!r} limit {limit!r}", file=sys.stderr)
     print(json.dumps(last), flush=True)
     return 0
 

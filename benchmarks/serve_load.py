@@ -10,6 +10,7 @@ sending its next request when the last answer is complete.
 
 from __future__ import annotations
 
+import math
 import statistics
 import threading
 import time
@@ -99,6 +100,55 @@ def _closed_loop(stream, requests, prompts, seconds, clients, on_window):
     return records, origin, origin + seconds, pool, futures
 
 
+# -- the reference comparison's decision ------------------------------
+# the lists of readings the comparison gives (reference_check: the
+# seeded probe through the engine's programs; served_check: a sample of
+# the requests the window finished), each with the key of its limit in
+# the cell's ``reference_check`` block
+READINGS = {"prefill_rel_rms": "rel_rms_tol",
+            "after_decode_rel_rms": "rel_rms_tol",
+            "decode_choice_gap": "choice_gap_tol",
+            "served_choice_gap": "choice_gap_tol"}
+# how many of the window's finished requests are read where the cell's
+# ``reference_check`` block does not say
+SERVED_REQUESTS = 8
+
+
+def served_sample(records, seed: int, count: int) -> list:
+    """``count`` of the requests the window finished, drawn from the
+    seed, the longest (prompt and answer) among them."""
+    done = [r for r in records if not r.error and r.tokens]
+    if not done:
+        return []
+    longest = max(range(len(done)), key=lambda i: (
+        done[i].request.prompt_len + len(done[i].tokens), -i))
+    rest = [i for i in range(len(done)) if i != longest]
+    drawn = traffic.sample(seed, len(rest), min(count - 1, len(rest)))
+    return [done[longest]] + [done[rest[i]] for i in drawn]
+
+
+def summary(ref: dict, check: dict) -> dict:
+    """Of each list of readings its median, its largest, and the share
+    over the limit; its limit beside them."""
+    out = {}
+    for name, limit in READINGS.items():
+        xs, tol = ref[name], check[limit]
+        out[name] = {
+            "n": len(xs), "median": statistics.median(xs) if xs else None,
+            "max": max(xs) if xs else None, "limit": tol,
+            "outlier_share": sum(not x <= tol for x in xs) / max(len(xs), 1)}
+    return out
+
+
+def matches_reference(ref: dict, check: dict) -> bool:
+    """Every list read, every reading finite and at or under its limit:
+    the decision is on every position and every served token read."""
+    return bool(ref["finite"]) and all(
+        ref[name] and all(math.isfinite(x) and x <= check[limit]
+                          for x in ref[name])
+        for name, limit in READINGS.items())
+
+
 def run(cell: dict, args, per_layer: dict) -> dict:
     from ray_tpu import serve
     from ray_tpu.llm import build_llm_app
@@ -140,10 +190,6 @@ def run(cell: dict, args, per_layer: dict) -> dict:
         warm = call("warm_up", sv["warm_up_prompt_lens"])
         phases["warmed_up"] = time.time()
         check = sv["reference_check"]
-        ref = call("reference_check", args.seed, hp, check["length"],
-                   check["decode_steps"])
-        notes["reference"] = ref
-        phases["reference_done"] = time.time()
         probe = traffic.prompt_tokens(
             args.seed, traffic.Request(10**6, 0.0, tr["probe_prompt_len"], 8),
             hp["vocab_size"])
@@ -193,9 +239,9 @@ def run(cell: dict, args, per_layer: dict) -> dict:
     except BaseException:
         serve.shutdown()
         raise
+    in_window = [r for r in records if r.due >= t0 - 1e-9]
 
     # -- samples, as the client saw them --------------------------------
-    in_window = [r for r in records if r.due >= t0 - 1e-9]
     ttft, itl, late = [], [], []
     done_tokens = done = failed = 0
     lengths_ok = vocab_ok = True
@@ -233,9 +279,28 @@ def run(cell: dict, args, per_layer: dict) -> dict:
         result["per_layer"] = report["per_layer"]
         result["metrics_not_read"] = report["metrics_not_read"]
         result["breakdown"] = report["breakdown"]
-    serve.shutdown()
 
-    tol = sv["reference_check"]
+    # -- the comparison with the reference ------------------------------
+    # once the window has closed, every request is answered (the engine
+    # is idle) and memory_peak_bytes has been read, so none of it is in
+    # setup_s: the seeded probe through the engine's programs, then a
+    # sample of what the window itself served, the longest request in it
+    try:
+        ref = call("reference_check", args.seed, hp, check)
+        sample = served_sample(
+            in_window, args.seed, check.get("served_requests",
+                                            SERVED_REQUESTS))
+        ref.update(call("served_check", hp, [
+            (traffic.prompt_tokens(args.seed, r.request, hp["vocab_size"]),
+             r.tokens) for r in sample]))
+    finally:
+        serve.shutdown()
+    notes["reference"] = {**ref, "summary": summary(ref, check)}
+    # each number compared, beside its limit
+    notes["compared"] = {
+        name: [of["max"] if of["n"] else math.inf, of["limit"]]
+        for name, of in notes["reference"]["summary"].items()}
+
     result["checks"] = {
         "every_request_answered": failed == 0 and len(in_window) > 0,
         "every_answer_full_length": lengths_ok,
@@ -243,10 +308,7 @@ def run(cell: dict, args, per_layer: dict) -> dict:
         "equal_prompts_equal_tokens":
             probes[0] == probes[1] and len(probes[0]) == 8,
         "nothing_compiled_in_window": at_end["compiled_in_window"] == 0,
-        "engine_matches_reference": ref["finite"]
-            and ref["prefill_rel_rms"] <= tol["rel_rms_tol"]
-            and ref["after_decode_rel_rms"] <= tol["rel_rms_tol"]
-            and ref["decode_choice_gap"] <= tol["choice_gap_tol"],
+        "engine_matches_reference": matches_reference(ref, check),
         "on_one_chip": warm["count"] == cell["chips"],
     }
 

@@ -39,6 +39,173 @@ def _new_counters() -> dict:
             "prefill_s": 0.0, "engine_step_ms": [], "prefill_chunks": 0}
 
 
+# how many positions a serving cell's comparison reads where its
+# ``reference_check`` block does not say
+POSITIONS, DECODE_STEPS = 32, 16
+
+
+def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
+    """The engine's own jitted ``_prefill`` and ``_decode``, into slot 0
+    of its first cache shard, against the family's float32
+    ``reference_logits`` over the same tokens, read at many positions
+    (``check``: the cell's ``reference_check`` block). The engine must
+    be idle.
+
+    *Prefill, through the cache.* ``length`` seeded tokens go in chunk
+    by chunk as a prompt does; the probe is at least one whole chunk
+    plus ``positions``, so the compared chunk attends to rows an earlier
+    call wrote. The last chunk is then dispatched again ``positions`` - 1
+    times with its ``length`` argument shortened by 1, 2, ...: the same
+    tokens at the same start into the same slot, so the same rows are
+    written, and each call returns the logits of its last real row.
+    ``prefill_rel_rms`` holds the relative RMS of each against the
+    reference's row, the probe's last position first.
+
+    *Decode.* ``decode_steps`` greedy steps. The decode program returns
+    tokens, not logits, so a step is judged twice: by how far the token
+    it chose lies under the reference's largest logit at that position
+    (``decode_choice_gap``), and through the cache rows it wrote: after
+    each step a one-token ``_prefill`` of the token just chosen, at its
+    position, returns the logits the next step chooses from and attends
+    to every row the decodes wrote (``after_decode_rel_rms``). The next
+    decode writes that row again, the same token at the same position.
+    """
+    import numpy as np
+
+    length = check["length"]
+    positions = check.get("positions", POSITIONS)
+    decode_steps = check.get("decode_steps", DECODE_STEPS)
+    seq = traffic.probe_sequence(seed, length, hp["vocab_size"])
+    starts = range(0, length, eng.prefill_chunk)
+    tail = length - starts[-1]          # the last chunk's real tokens
+    if len(starts) < 2 or tail < positions:
+        raise ValueError(
+            f"reference_check: a probe of {length} tokens in chunks of "
+            f"{eng.prefill_chunk} ends in a chunk of {tail} behind "
+            f"{len(starts) - 1} whole one(s); it needs one whole chunk and "
+            f"then {positions} positions or more in the last")
+    # the one-token prefill behind the last decode writes a whole
+    # bucket of rows, and one that ran past the end would be moved back
+    if length + decode_steps + eng.buckets[0] > eng.max_seq:
+        raise ValueError(
+            f"reference_check: {length} tokens, {decode_steps} decode steps "
+            f"and a bucket of {eng.buckets[0]} rows do not fit the cache's "
+            f"{eng.max_seq} rows")
+    onehot = np.zeros(eng.max_batch, np.float32)
+    onehot[0] = 1.0
+    shard = eng.shards[0]
+    t0 = time.perf_counter()
+
+    def prefill(tokens, pos, real=None):
+        """Dispatches one call; the logits stay on the device."""
+        bucket = next(b for b in eng.buckets if b >= len(tokens))
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :len(tokens)] = tokens
+        logits, shard.cache = eng._prefill(
+            eng.params, shard.cache, padded, onehot,
+            np.asarray([pos], np.int32), real or len(tokens), bucket=bucket)
+        return logits
+
+    def fetched(rows):
+        return np.stack([np.asarray(x, np.float32) for x in rows])
+
+    with eng._lock:
+        if eng.num_active():
+            raise RuntimeError("reference_check needs an idle engine")
+        for pos in starts:
+            last = prefill(seq[pos:pos + eng.prefill_chunk], pos)
+        got_prefill = [last] + [
+            prefill(seq[starts[-1]:], starts[-1], real=tail - back)
+            for back in range(1, positions)]
+        got_prefill = fetched(got_prefill)
+        chosen = [int(got_prefill[0].argmax())]
+        lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
+        temps = np.zeros(eng.max_batch, np.float32)
+        got_after = []
+        for i in range(decode_steps):
+            tokens = np.zeros(eng.max_batch, np.int32)
+            tokens[0], lens[0] = chosen[-1], length + i
+            toks, shard.cache, eng._rng = eng._decode(
+                eng.params, shard.cache, tokens, lens, temps, eng._rng)
+            chosen.append(int(np.asarray(toks)[0]))
+            got_after.append(prefill(chosen[-1:], length + i + 1))
+        got_after = fetched(got_after)
+    engine_s = time.perf_counter() - t0
+
+    # the reference's rows: positions length - positions .. length +
+    # decode_steps, the last of them the row behind the last decode
+    full = np.concatenate([seq, np.asarray(chosen, np.int32)])
+    want = np.asarray(family.reference_logits(
+        eng.params, full, hp, last=positions + decode_steps + 1), np.float32)
+    at = positions - 1      # want[at] is the probe's last row, length - 1
+
+    def rel_rms(got, ref):
+        return float(np.sqrt(np.mean((got - ref) ** 2))
+                     / np.sqrt(np.mean(ref ** 2)))
+
+    return {
+        "prefill_rel_rms": [rel_rms(got_prefill[back], want[at - back])
+                            for back in range(positions)],
+        "after_decode_rel_rms": [rel_rms(got_after[i], want[at + i + 2])
+                                 for i in range(decode_steps)],
+        # how far under the reference's best logit each decode's token is
+        "decode_choice_gap": [
+            float(want[at + i + 1].max() - want[at + i + 1][chosen[i + 1]])
+            for i in range(decode_steps)],
+        "logit_rms": float(np.sqrt(np.mean(want ** 2))),
+        "finite": bool(np.isfinite(got_prefill).all()
+                       and np.isfinite(got_after).all()),
+        "engine_s": engine_s,
+        "total_s": time.perf_counter() - t0,
+    }
+
+
+# the reference's pass over a served request is padded (behind the
+# tokens, which a causal model does not see) to a whole number of these,
+# and its head taken over a whole number of HEAD_ROWS, so that a handful
+# of programs serve every length a cell's traffic has
+PAD_TOKENS, HEAD_ROWS = 512, 256
+
+
+def served_readings(params, family, hp: dict, served) -> dict:
+    """What the window itself produced, against the reference: for each
+    of ``served`` (``[(prompt ids, served tokens)]``, greedy requests the
+    engine finished with whatever else was alive in its lanes), one pass
+    of the family's float32 ``reference_logits`` over the prompt with its
+    served tokens, and for every served token how far its logit lies
+    under the reference's largest at that position
+    (``served_choice_gap``, request after request). A token the engine
+    altered, took from another lane or chose from rows another request
+    wrote reads a gap of whole logits; bf16 against float32 reads 0 but
+    for near-ties."""
+    import numpy as np
+
+    t0 = time.perf_counter()
+    gaps, by_request, agree = [], [], 0
+    for prompt, tokens in served:
+        n, first = len(tokens), len(prompt) - 1   # row `first` chose token 0
+        seq = np.asarray(list(prompt) + list(tokens[:-1]), np.int32)
+        total = -(-len(seq) // PAD_TOKENS) * PAD_TOKENS
+        last = min(-(-(total - first) // HEAD_ROWS) * HEAD_ROWS, total)
+        padded = np.zeros(total, np.int32)
+        padded[:len(seq)] = seq
+        want = family.reference_logits(params, padded, hp, last=last)
+        at = first - (total - last)
+        rows = np.asarray(want[at:at + n], np.float32)
+        own = rows[np.arange(n), np.asarray(tokens)]
+        gap = rows.max(axis=-1) - own
+        gaps.extend(float(g) for g in gap)
+        agree += int((gap == 0).sum())
+        by_request.append([len(prompt), n, float(gap.max())])
+    return {
+        "served_choice_gap": gaps,
+        # prompt tokens, served tokens, widest gap of each request read
+        "served_by_request": by_request,
+        "served_agree_share": agree / max(len(gaps), 1),
+        "served_s": time.perf_counter() - t0,
+    }
+
+
 class BenchLLMServer(LLMServer):
     def __init__(self, llm_config: SeededLLMConfig):
         self._compiles = holder.CompileCounter()
@@ -89,77 +256,18 @@ class BenchLLMServer(LLMServer):
             self.generate([1 + i % 7 for i in range(n)], max_tokens=max_tokens)
         return dict(self._info)
 
-    def reference_check(self, seed: int, hp: dict, length: int,
-                        decode_steps: int) -> dict:
-        """Prefill ``length`` seeded tokens, decode ``decode_steps``
-        greedy tokens, then prefill one more token, all through the
-        engine's own jitted programs into slot 0 of its first cache
-        shard, and compare with the float32 reference's full forward
-        over the same tokens. The engine must be idle.
+    def reference_check(self, seed: int, hp: dict, check: dict) -> dict:
+        """``reference_readings`` of this replica's engine, which must
+        be idle: the cell's ``reference_check`` block says how long the
+        probe is and at how many positions it is read."""
+        return reference_readings(self.engine, spec.family_of(hp), seed, hp,
+                                  check)
 
-        The decode program returns tokens, not logits, so a decode step
-        is judged twice: by how far the token it chose lies under the
-        reference's largest logit, and through the cache rows it wrote,
-        which the final one-token prefill attends to.
-        """
-        import numpy as np
-
-        eng = self.engine
-        seq = traffic.probe_sequence(seed, length, hp["vocab_size"])
-        onehot = np.zeros(eng.max_batch, np.float32)
-        onehot[0] = 1.0
-        t0 = time.perf_counter()
-
-        def prefill(tokens, pos):
-            chunk = len(tokens)
-            bucket = next(b for b in eng.buckets if b >= chunk)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :chunk] = tokens
-            shard = eng.shards[0]
-            logits, shard.cache = eng._prefill(
-                eng.params, shard.cache, padded, onehot,
-                np.asarray([pos], np.int32), chunk, bucket=bucket)
-            return np.asarray(logits, np.float32)
-
-        with eng._lock:
-            if eng.num_active():
-                raise RuntimeError("reference_check needs an idle engine")
-            for pos in range(0, length, eng.prefill_chunk):
-                got_prefill = prefill(seq[pos:pos + eng.prefill_chunk], pos)
-            chosen = [int(got_prefill.argmax())]
-            lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
-            temps = np.zeros(eng.max_batch, np.float32)
-            for i in range(decode_steps):
-                last = np.zeros(eng.max_batch, np.int32)
-                last[0], lens[0] = chosen[-1], length + i
-                shard = eng.shards[0]
-                toks, shard.cache, eng._rng = eng._decode(
-                    eng.params, shard.cache, last, lens, temps, eng._rng)
-                chosen.append(int(np.asarray(toks)[0]))
-            got_after = prefill(chosen[-1:], length + decode_steps)
-        engine_s = time.perf_counter() - t0
-
-        full = np.concatenate([seq, np.asarray(chosen, np.int32)])
-        want = np.asarray(spec.family_of(hp).reference_logits(
-            eng.params, full, hp, last=decode_steps + 2))
-
-        def rel_rms(got, ref):
-            return float(np.sqrt(np.mean((got - ref) ** 2))
-                         / np.sqrt(np.mean(ref ** 2)))
-
-        return {
-            "prefill_rel_rms": rel_rms(got_prefill, want[0]),
-            "after_decode_rel_rms": rel_rms(got_after, want[-1]),
-            # how far under the reference's best logit each chosen token is
-            "decode_choice_gap": max(
-                float(want[i].max() - want[i][chosen[i]])
-                for i in range(decode_steps + 1)),
-            "logit_rms": float(np.sqrt(np.mean(want ** 2))),
-            "finite": bool(np.isfinite(got_prefill).all()
-                           and np.isfinite(got_after).all()),
-            "engine_s": engine_s,
-            "total_s": time.perf_counter() - t0,
-        }
+    def served_check(self, hp: dict, served: list) -> dict:
+        """``served_readings`` of requests the window finished, once it
+        has closed and ``memory_peak_bytes`` has been read."""
+        return served_readings(self.engine.params, spec.family_of(hp), hp,
+                               served)
 
     # -- the window -----------------------------------------------------
     def _engine_stats(self):
@@ -225,9 +333,15 @@ class BenchLLMServer(LLMServer):
         return {k: scope_map(c) for k, c in programs().items()}
 
     def _cache_shapes(self) -> list:
-        """The shape of one KV-cache shard's ``k`` as a trace names a
-        result, ``bf16[24,8,8,2048,128]``."""
+        """The shape of every leaf of one cache shard as a trace names a
+        result, ``bf16[24,8,8,2048,128]``: each once, in the pytree's
+        order (keys and values share one; a latent cache, a recurrent
+        state or a window's rows beside a full layer's bring theirs)."""
+        import jax
+
         short = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
-        k = self.engine.shards[0].cache["k"]
-        return [f"{short.get(str(k.dtype), str(k.dtype))}"
-                f"[{','.join(str(d) for d in k.shape)}]"]
+        shapes = [f"{short.get(str(a.dtype), str(a.dtype))}"
+                  f"[{','.join(str(d) for d in a.shape)}]"
+                  for a in jax.tree_util.tree_leaves(
+                      self.engine.shards[0].cache)]
+        return list(dict.fromkeys(shapes))

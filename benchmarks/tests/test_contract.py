@@ -42,6 +42,33 @@ def test_good_lines_pass():
     assert check(json.loads(json.dumps(TRACED)), True) == []
 
 
+COMPARED = {"prefill_rel_rms": [0.0141, 0.03], "decode_choice_gap": [0.0, 0.1]}
+
+
+@pytest.mark.parametrize("compared,problem", [
+    (COMPARED, None),
+    ({"first_nll_rms": [0.0138, 0.038]}, None),
+    ({"prefill_rel_rms": [None, 0.03]}, None),      # a reading not finite
+    ({}, "compared is not"),
+    ({"prefill_rel_rms": 0.0141}, "compared is not"),
+    ({"prefill_rel_rms": [0.0141]}, "compared is not"),
+    ({"prefill_rel_rms": ["0.0141", 0.03]}, "compared is not"),
+    ({"prefill rel rms": [0.0141, 0.03]}, "compared is not"),
+    ([0.0141, 0.03], "compared is not"),
+])
+def test_the_numbers_compared_ride_last_on_either_line(compared, problem):
+    """Each number `correct` compared, beside its limit, under a key of
+    its own that comes last on the line (run.py prints the same as the
+    last lines of standard error)."""
+    for line, traced in ((UNTRACED, False), (TRACED, True)):
+        bad = check({**line, "compared": compared}, traced)
+        assert (bad == []) if problem is None else any(
+            problem in b for b in bad), bad
+    if problem is None:
+        first = {"compared": compared, **UNTRACED}
+        assert any("last key" in b for b in check(first, False))
+
+
 def edit(obj, path, value="__delete__"):
     obj = copy.deepcopy(obj)
     node = obj

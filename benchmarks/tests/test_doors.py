@@ -13,6 +13,7 @@ llama recordings of PR 23 and PR 24. So a PR that adds a family and a
 cell adds its recording, and no test file changes."""
 
 import ast
+import functools
 import glob
 import gzip
 import json
@@ -20,6 +21,7 @@ import math
 import os
 import subprocess
 import sys
+import tempfile
 
 import numpy as np
 import pytest
@@ -41,11 +43,28 @@ CHAT, DOCS, OVER = ("internlm2-1.8b.serve-chat",
 LLAMA_TRAIN = {"trace": "trace_4chip.xplane.pb", "chips": 4, "seq": 256,
                "seqs_per_step": 8, "traced_steps": 2,
                "scopes": {"step": {}}, "reads_nothing": {}}
-# what PR 24 recorded and this PR declares: they read what the parent of
-# PR 24 lacks (engine_stats(), compiled_programs(), iter_stats)
-FROM_STATS = {"queue_wait_p95_ms", "prefill_wait_p95_ms", "prefill_p95_ms",
-              "engine_host_ms", "decode_occupancy_pct", "input_stage_ms"}
-FROM_PROGRAMS = {"kv_cache_move_share_pct", "scope_unattributed_pct"}
+# the samples only a program since PR 24 records (engine_stats(),
+# iter_stats: holder.engine_deltas, holder.phase_deltas)
+PROGRAM_SAMPLES = ("stats.", "phase_s.", "phase_n.", "req.")
+
+
+def reads_the_programs_samples(metric):
+    """Whether a metric's ``args`` name a sample that only the program's
+    counters, spans or stamps give: read from its own file alone, so a
+    data-only metric over a new counter needs no edit here. (A reader
+    that joins with the programs' scope maps says so itself, by what it
+    returns where there are none.)"""
+    def strings(x):
+        if isinstance(x, str):
+            yield x
+        elif isinstance(x, dict):
+            x = list(x.values())
+        if isinstance(x, (list, tuple)):
+            for item in x:
+                yield from strings(item)
+
+    return any(name.startswith(PROGRAM_SAMPLES)
+               for name in strings(metric.get("args", {})))
 
 
 def harness_files():
@@ -98,8 +117,31 @@ def serving(tmp_path_factory):
     return trace, found, pair
 
 
+@functools.lru_cache(maxsize=None)
+def serving_recording(stem):
+    """A family's own serving recording: its reduced trace and its
+    sidecar (scope maps, cache shapes, the two snapshots, the steps, and
+    what it says it cannot hold)."""
+    with gzip.open(stem + ".json.gz", "rt") as f:
+        rec = json.load(f)
+    with gzip.open(stem + ".xplane.pb.gz", "rb") as f, \
+            tempfile.NamedTemporaryFile(suffix=".xplane.pb") as out:
+        out.write(f.read())
+        out.flush()
+        trace = tr.load(out.name)
+    return trace, rec
+
+
 def serving_ctx(cell_name, serving, with_program_stats=True):
-    trace, found, pair = serving
+    """On the family's own recording where it brings one
+    (``trace_<family>.serve.*``; tree/record_toy_moe_serve_fixture.py is
+    the shape), else on the llama recording of PR 24."""
+    stem = recording_of(cell_name)
+    if stem:
+        trace, found = serving_recording(stem)
+        pair, reads_nothing = found, found["reads_nothing"]
+    else:
+        (trace, found, pair), reads_nothing = serving, {}
     steps = found["steps"]
     samples = {"engine_steps": steps, "engine_tokens": 2 * steps,
                "engine_step_s": 0.5, "prefill_s": 0.1,
@@ -109,7 +151,9 @@ def serving_ctx(cell_name, serving, with_program_stats=True):
     return {"cell": spec.load_cell(cell_name, True), "chips": 1,
             "samples": samples, "trace": trace, "peak": {},
             "scopes": found["scopes"] if with_program_stats else None,
-            "cache_shapes": found["cache_shapes"], "reads_nothing": {}}
+            "cache_shapes": found["cache_shapes"],
+            # not for the readers: what this recording cannot hold
+            "reads_nothing": reads_nothing}
 
 
 def train_ctx(cell_name, tmp_path, with_program_stats=True):
@@ -157,7 +201,8 @@ def ctx_for(cell_name, serving, tmp_path, **kw):
     ("toy-moe.serve",
      {"engine_tokens_per_step.docs", "engine_host_ms.docs",
       "decode_occupancy_pct.docs", "prefill_wait_p95_ms.docs",
-      "kv_cache_move_share_pct.docs", "scope_unattributed_pct.docs"},
+      "kv_cache_move_share_pct.docs", "scope_unattributed_pct.docs",
+      "decode_ahead_pct.toy", "sample_share_pct.toy"},
      {"prefill_device_share_pct.docs"}),
 ])
 def test_a_second_family_runs_through_every_door(cell, read, not_read):
@@ -178,6 +223,35 @@ def test_a_second_family_runs_through_every_door(cell, read, not_read):
     last = lines[-1]
     checks = next(x["checks"] for x in lines if "checks" in x)
     assert all(checks.values()), checks      # the reference comparison too
+    # each number compared beside its limit: last on the line, and the
+    # last lines of standard error
+    assert list(last)[-1] == "compared"
+    assert all(value <= limit for value, limit in last["compared"].values())
+    assert [x.split(":")[0] for x in done.stderr.splitlines()[
+        -len(last["compared"]):]] == [f"compared {k}" for k in last["compared"]]
+    notes = next(x["notes"] for x in lines if "notes" in x)
+    loaded = spec.load_cell(cell, rehearse=True)
+    if loaded["kind"] == "serve":
+        # the comparison read at every position the cell asks for
+        check, ref = loaded["serve"]["reference_check"], notes["reference"]
+        assert len(ref["prefill_rel_rms"]) == check["positions"]
+        assert len(ref["after_decode_rel_rms"]) == len(
+            ref["decode_choice_gap"]) == check["decode_steps"]
+        assert set(ref["summary"]) == set(last["compared"])
+        for name, of in ref["summary"].items():
+            assert of["n"] == len(ref[name]) and of["outlier_share"] == 0.0
+            assert of["median"] <= of["max"] <= of["limit"]
+            assert last["compared"][name] == [of["max"], of["limit"]]
+        # and what the window itself served, more than one request of it
+        assert len(ref["served_by_request"]) > 1
+        assert len(ref["served_choice_gap"]) == sum(
+            n for _, n, _ in ref["served_by_request"]) > 8
+    else:
+        # beside the RMS, how the per-token differences are spread
+        first = notes["first_forward"]
+        assert 0 < first["nll_abs_median"] <= first["nll_abs_p80"] \
+            <= first["nll_abs_max"]
+        assert first["nll_abs_median"] < first["nll_rms"] < first["nll_abs_max"]
     assert set(last["metrics"]) == read
     noted = next(x["metrics_not_read"] for x in lines
                  if "metrics_not_read" in x)
@@ -277,25 +351,35 @@ def test_every_declared_per_layer_metric_reads_a_number(cell, serving,
 
 
 @pytest.mark.parametrize(
-    "cell", [c for c in cells() if recording_of(c) is None])
+    "cell", [c for c in cells() if recording_of(c) is None
+             or c in cells_of_kind("serve")])
 def test_the_parent_of_pr_24_ends_in_a_valid_line(cell, serving, tmp_path):
     """An engine without engine_stats() and compiled_programs(), a
     Dataset without iter_stats, a program without scope_map: the metrics
     that read them are named as not read, every other reads as before,
     and the traced line passes the contract. For the cells whose program
     existed then: those tried on the llama recordings, which PR 23 and
-    PR 24 made. A family that brings a recording of its own came later,
-    and what its program lacked at that parent is everything."""
+    PR 24 made, and a served family's on its own recording (an engine is
+    an engine). A trained family that brings a recording of its own came
+    later, and what its program lacked at that parent is everything.
+    Which metrics read nothing follows from each metric's own file."""
     declared = spec.cell_metrics(cell, traced=True)
-    out, not_read = spec.evaluate(
-        declared, ctx_for(cell, serving, tmp_path, with_program_stats=False))
-    lacking = {n for n in declared if n.split(".")[0] in FROM_STATS
-               or (n.split(".")[0] in FROM_PROGRAMS and n.endswith(
-                   (".chat", ".docs", ".over")))}
-    by_scope = {n for n, m in declared.items()
-                if m["reader"].startswith("trace_scope_")}
-    assert set(not_read) == lacking | by_scope
-    assert lacking and all(not_read.values())
+    ctx = ctx_for(cell, serving, tmp_path, with_program_stats=False)
+    out, not_read = spec.evaluate(declared, ctx)
+    assert set(out) | set(not_read) == set(declared)
+    assert all(not_read.values())                   # each with its reason
+    # what reads nothing: whatever names one of the program's own
+    # samples, by its file; whatever joins with the programs' scope
+    # maps, by what its reader returns where there are none; what the
+    # recording cannot hold; and nothing else
+    whole = ctx_for(cell, serving, tmp_path)
+    assert set(spec.evaluate(declared, whole)[1]) == set(ctx["reads_nothing"])
+    from_scopes = set(spec.evaluate(declared, {**whole, "scopes": None})[1])
+    from_samples = {n for n, m in declared.items()
+                    if reads_the_programs_samples(m)}
+    assert from_samples and from_samples <= set(not_read)
+    assert set(not_read) == from_samples | from_scopes | set(
+        ctx["reads_nothing"])
     line = {**LINE, "metrics": out,
             "device": {**LINE["device"], "count": ctx_for(
                 cell, serving, tmp_path)["chips"]}}
@@ -396,6 +480,127 @@ def test_a_train_cell_of_a_second_family_is_tried_on_its_own_recording(
         {"optimizer_ms": declared["optimizer_ms"]},
         train_ctx(llama_cell, tmp_path))
     assert on_llama["optimizer_ms"]["value"] == 0.0     # an empty scope map
+
+
+def test_a_serve_cell_of_a_second_family_is_tried_on_its_own_recording(
+        serving, tmp_path):
+    """``toy-moe.serve``, its two metrics (one over counters no harness
+    file names, one over a scope through ``trace_scope_share_pct``) and
+    its serving recording exist under tests/tree alone, made by
+    tree/record_toy_moe_serve_fixture.py. ``serving_ctx`` finds the
+    recording by the family's name; every number the two metrics read
+    from it is made a second time by hand; what the recording cannot
+    hold is what its sidecar says."""
+    toy = [c for c in cells_of_kind("serve") if recording_of(c)]
+    assert toy == ["toy-moe.serve"]
+    assert recording_of(toy[0]) == os.path.join(
+        spec.FIXTURE_TREE, "trace_toy_moe.serve")
+    own = {e["name"]: e for e in spec.declared("per_layer")
+           if e["name"].endswith(".toy") and e["workloads"] == toy}
+    assert set(own) == {"decode_ahead_pct.toy", "sample_share_pct.toy"}
+    assert not [e for e in spec.benchmark_json()["per_layer"]
+                if e["name"] in own]
+    for path in harness_files() + glob.glob(os.path.join(HERE, "*.py")):
+        if path != os.path.abspath(__file__):
+            with open(path) as f:
+                text = f.read()
+            assert "decode_ahead_pct" not in text, path
+            assert "sample_share_pct" not in text, path
+
+    ctx = ctx_for(toy[0], serving, tmp_path)
+    trace, rec = serving_recording(recording_of(toy[0]))
+    reads_nothing = rec["reads_nothing"]
+    assert ctx["trace"] is trace and trace is not serving[0]
+    assert ctx["scopes"] == rec["scopes"] and set(rec["scopes"]) >= {
+        "decode", "first_token"}
+    assert ctx["cache_shapes"] == rec["cache_shapes"] == ["bf16[2,4,2,128,16]"]
+    declared = spec.cell_metrics(toy[0], traced=True)
+    out, not_read = spec.evaluate(declared, ctx)
+    assert set(not_read) == set(reads_nothing) == set(ctx["reads_nothing"])
+    was, now = rec["before"]["engine"], rec["after"]["engine"]
+    calls = now["decode_calls"] - was["decode_calls"]
+    ahead = now["decode_ahead"] - was["decode_ahead"]
+    assert 0 < ahead < calls
+    assert out["decode_ahead_pct.toy"]["value"] == pytest.approx(
+        100 * ahead / calls)
+    programs = tp.of(trace)
+    under = tp.scope_seconds(programs, rec["scopes"], ["sample"])
+    assert out["sample_share_pct.toy"]["value"] == pytest.approx(
+        100 * under / tp.op_seconds(programs))
+    if rec["platform"] == "tpu":    # a CPU trace names no program
+        assert 0 < under < tp.op_seconds(programs)
+        assert not reads_nothing
+    # what the llama recording could never give it: PR 24's engine had
+    # no such counter
+    _, on_llama = spec.evaluate(declared, {**serving_ctx(DOCS, serving),
+                                           "cell": ctx["cell"]})
+    assert "stats.decode_ahead" in on_llama["decode_ahead_pct.toy"]
+
+
+def test_the_new_shares_by_scope_equal_the_hand_count(serving):
+    """``attn_cached_share_pct.*`` and ``mlp_share_pct.*`` on the chip
+    recording of PR 24: each the scope's op seconds over all op seconds,
+    every op's scope from its own program's map; the two and the cache's
+    movement are parts of one whole. ``prefill_tokens_per_chunk.*`` is
+    the engine's two counters of PR 29, one over the other."""
+    trace, found, pair = serving
+    programs = tp.of(trace)
+    total = tp.op_seconds(programs)
+    which = tp.assign_maps(programs, found["scopes"])
+    was, now = pair["before"]["engine"], pair["after"]["engine"]
+    for cell, suffix in ((CHAT, "chat"), (DOCS, "docs"), (OVER, "over")):
+        out, not_read = spec.evaluate(
+            spec.cell_metrics(cell, True), serving_ctx(cell, serving))
+        assert not_read == {}
+        shares = {}
+        for scope in ("attn_cached", "mlp"):
+            by_hand = 0.0
+            for c in programs.chips:
+                for i, module in enumerate(c.modules):
+                    path = found["scopes"].get(which.get(module), {}).get(
+                        c.names[i], "")
+                    if scope in path.split("/"):
+                        by_hand += float(c.end[i] - c.start[i])
+            shares[scope] = out[f"{scope}_share_pct.{suffix}"]["value"]
+            assert shares[scope] == pytest.approx(
+                100 * by_hand / len(programs.chips) / total)
+        assert 1 < shares["attn_cached"] < 60 and 1 < shares["mlp"] < 60
+        moved = out.get(f"kv_cache_move_share_pct.{suffix}", {"value": 0.0})
+        assert sum(shares.values()) + moved["value"] < 100
+        assert out[f"prefill_tokens_per_chunk.{suffix}"]["value"] \
+            == pytest.approx(
+                (now["prefill_tokens"] - was["prefill_tokens"])
+                / (now["prefill_chunks"] - was["prefill_chunks"]))
+    # a program without maps reads nothing and says why; maps that hold
+    # none of the names read 0.0
+    ctx = serving_ctx(CHAT, serving)
+    metric = {"x": {"reader": "trace_scope_share_pct", "unit": "%",
+                    "args": {"scopes": ["experts"]}}}
+    assert spec.evaluate(metric, ctx)[0]["x"]["value"] == 0.0
+    assert "compiled_programs" in spec.evaluate(
+        metric, {**ctx, "scopes": None})[1]["x"]
+
+
+def test_cache_shapes_are_every_leaf_of_a_shard_once():
+    """``_cache_shapes`` names every leaf of ``shards[0].cache``, each
+    shape once, in the pytree's order: keys and values share one; a
+    cache that is not keys and values brings its own."""
+    from benchmarks.server import BenchLLMServer
+
+    def replica(cache):
+        return type("Replica", (), {"engine": type("Engine", (), {
+            "shards": [type("Shard", (), {"cache": cache})()]})()})()
+
+    f16, f32 = np.dtype("float16"), np.dtype("float32")
+    kv = {"k": np.zeros((2, 4, 2, 8, 16), f16),
+          "v": np.zeros((2, 4, 2, 8, 16), f16)}
+    assert BenchLLMServer._cache_shapes(replica(kv)) == ["f16[2,4,2,8,16]"]
+    latent = {"latent": np.zeros((7, 8, 64, 576), f16),
+              "window": {"k": np.zeros((1, 8, 2, 16, 64), f16),
+                         "v": np.zeros((1, 8, 2, 16, 64), f16)},
+              "state": np.zeros((7, 8, 128), f32)}
+    assert BenchLLMServer._cache_shapes(replica(latent)) == [
+        "f16[7,8,64,576]", "f32[7,8,128]", "f16[1,8,2,16,64]"]
 
 
 def test_metrics_from_the_trace_by_program_equal_the_hand_count(serving):

@@ -102,6 +102,21 @@ def fp8(a):
                                      mantissa_bits=3) * scale).astype(a.dtype)
 
 
+def mlp_in_fp8(config, x, layer):
+    """``llama.mlp_sublayer`` with every operand of its three matmuls in
+    fp8 and nothing else changed: the control, patched over the model in
+    the test's (or a scratch script's) own process, never in the
+    program."""
+    from ray_tpu.models import llama
+
+    c = config
+    h = fp8(llama.rms_norm(x, layer["mlp_norm"], c.norm_eps))
+    gate = jnp.einsum("bsd,df->bsf", h, fp8(layer["w_gate"].astype(c.dtype)))
+    up = jnp.einsum("bsd,df->bsf", h, fp8(layer["w_up"].astype(c.dtype)))
+    return x + jnp.einsum("bsf,fd->bsd", fp8(jax.nn.silu(gate) * up),
+                          fp8(layer["w_down"].astype(c.dtype)))
+
+
 @pytest.mark.parametrize("seed", [3, 2**31 + 5, 2**31 + 77])
 @pytest.mark.parametrize("cell", TRAIN)
 def test_the_control_fails_where_the_program_passes(cell, seed, monkeypatch):
@@ -116,14 +131,6 @@ def test_the_control_fails_where_the_program_passes(cell, seed, monkeypatch):
     sound = train_loop.first_forward(
         family, cfg, mesh, hp, params, probe, tokens)
     assert sound["finite"] and sound["nll_rms"] <= 0.6 * tol, sound
-
-    def mlp_in_fp8(config, x, layer):
-        c = config
-        h = fp8(llama.rms_norm(x, layer["mlp_norm"], c.norm_eps))
-        gate = jnp.einsum("bsd,df->bsf", h, fp8(layer["w_gate"].astype(c.dtype)))
-        up = jnp.einsum("bsd,df->bsf", h, fp8(layer["w_up"].astype(c.dtype)))
-        return x + jnp.einsum("bsf,fd->bsd", fp8(jax.nn.silu(gate) * up),
-                              fp8(layer["w_down"].astype(c.dtype)))
 
     monkeypatch.setattr(llama, "mlp_sublayer", mlp_in_fp8)
     control = train_loop.first_forward(

@@ -1,0 +1,318 @@
+"""The serving comparison with the reference: the decision rule on
+made-up readings; on the cells' rehearsal engines, in this process,
+``server.reference_readings`` (the seeded probe, read at many positions)
+and ``server.served_readings`` over a sample of what a stretch of the
+cell's traffic through ``engine.step()`` finished; the control of PERF.md
+section 4 at a size a test can hold (``models/llama.py``'s
+``mlp_sublayer`` with its matmuls' operands in fp8, patched here and
+never in the program), which has to move *every* position's reading over
+the limit where the program as it is stays under it with room; and the
+faults a serving cell can have, planted under the timed path where the
+decode program's tokens are produced, in a lane that is not the probe's.
+``serving_control.py`` beside this file reads the same on the chip at the
+cells' own sizes."""
+
+import math
+
+import pytest
+import serving_control
+from test_first_forward import mlp_in_fp8
+
+from benchmarks import serve_load, server, spec, traffic
+
+SERVE = [w["name"] for w in spec.benchmark_json()["workloads"]
+         if spec.load_cell(w["name"], True)["kind"] == "serve"]
+LIMITS = {"rel_rms_tol": 0.03, "choice_gap_tol": 0.1}
+
+
+def readings(rel_rms, gaps=(0.0,) * 16, finite=True, served=(0.0,) * 300):
+    return {"prefill_rel_rms": list(rel_rms),
+            "after_decode_rel_rms": [0.011] * 16,
+            "decode_choice_gap": list(gaps),
+            "served_choice_gap": list(served), "finite": finite}
+
+
+# --------------------------------------------------------------- the rule
+@pytest.mark.parametrize("rel_rms,passes", [
+    # one position in 32 over the limit, wherever it falls: no position
+    # is spared (a routed family's flipped choice would read so; none is
+    # in the benchmark, and a rule for one waits for its readings)
+    ([0.011] * 31 + [0.13], False),
+    ([0.13] + [0.011] * 31, False),
+    ([0.011] * 26 + [0.13] * 6, False),
+    # an fp8 sublayer moves every position
+    ([0.12] * 32, False),
+    # at the limit is under it
+    ([0.03] * 32, True),
+    ([0.011] * 32, True),
+    ([0.011] * 31 + [0.0301], False),
+])
+def test_every_reading_has_to_lie_at_or_under_its_limit(rel_rms, passes):
+    ref = readings(rel_rms)
+    assert serve_load.matches_reference(ref, LIMITS) is passes
+    of = serve_load.summary(ref, LIMITS)["prefill_rel_rms"]
+    assert of["n"] == 32 and of["max"] == max(rel_rms)
+    assert of["outlier_share"] == sum(x > 0.03 for x in rel_rms) / 32
+    assert (of["max"] <= of["limit"]) is passes
+
+
+@pytest.mark.parametrize("bad", [math.nan, math.inf, -math.inf])
+@pytest.mark.parametrize("name", list(serve_load.READINGS))
+def test_a_reading_that_is_not_finite_fails_in_every_list(bad, name):
+    sound = readings([0.011] * 32)
+    assert serve_load.matches_reference(sound, LIMITS)
+    assert not serve_load.matches_reference(
+        dict(sound, **{name: sound[name][:-1] + [bad]}), LIMITS)
+    # logits that were not finite, whatever the readings made of them
+    assert not serve_load.matches_reference(dict(sound, finite=False), LIMITS)
+
+
+@pytest.mark.parametrize("name", list(serve_load.READINGS))
+def test_each_list_is_held_to_its_own_limit_and_has_to_be_read(name):
+    sound = readings([0.011] * 32)
+    limit = LIMITS[serve_load.READINGS[name]]
+    at = dict(sound, **{name: sound[name][:-1] + [limit]})
+    over = dict(sound, **{name: sound[name][:-1] + [1.01 * limit]})
+    assert serve_load.matches_reference(at, LIMITS)
+    assert not serve_load.matches_reference(over, LIMITS)
+    assert serve_load.summary(over, LIMITS)[name]["outlier_share"] \
+        == 1 / len(sound[name])
+    # a gap that passes a relative RMS's limit does not pass its own
+    if name.endswith("choice_gap"):
+        assert not serve_load.matches_reference(
+            dict(sound, **{name: [0.2]}), {**LIMITS, "rel_rms_tol": 0.3})
+    # nothing read (no request finished, no position asked for) fails
+    nothing = dict(sound, **{name: []})
+    assert not serve_load.matches_reference(nothing, LIMITS)
+    of = serve_load.summary(nothing, LIMITS)[name]
+    assert of["n"] == 0 and of["max"] is None
+
+
+class _Done:
+    def __init__(self, index, prompt_len, tokens, error=None):
+        self.request = traffic.Request(index, 0.0, prompt_len, len(tokens))
+        self.tokens, self.error = tokens, error
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
+def test_the_sample_of_the_window_holds_its_longest_request(seed):
+    done = [_Done(i, 10 + 7 * i % 90, [1] * (3 + i % 11)) for i in range(40)]
+    done[13] = _Done(13, 95, [1] * 40)                  # the longest
+    done[5] = _Done(5, 500, [], error="refused")        # never answered
+    done[6] = _Done(6, 500, [])
+    got = serve_load.served_sample(done, seed, 8)
+    assert len(got) == len({r.request.index for r in got}) == 8
+    assert got[0] is done[13]
+    assert not {5, 6} & {r.request.index for r in got}
+    again = serve_load.served_sample(done, seed, 8)
+    assert [r.request.index for r in again] == [r.request.index for r in got]
+    other = serve_load.served_sample(done, seed + 1, 8)
+    assert [r.request.index for r in other] != [r.request.index for r in got]
+    # fewer finished than asked for: all of them; none: nothing
+    assert len(serve_load.served_sample(done[:4], seed, 8)) == 4
+    assert serve_load.served_sample([done[5], done[6]], seed, 8) == []
+
+
+def test_the_cells_of_the_benchmark_read_every_position_and_the_window():
+    """The three serving cells decide on every position, 32 + 16 + 16 of
+    the probe behind a whole chunk, and on every served token of a
+    sample of the window's requests."""
+    assert len(SERVE) == 3
+    for name in SERVE:
+        check = spec.load_cell(name, False)["serve"]["reference_check"]
+        assert "quantile" not in check
+        assert (check["length"], check["positions"],
+                check["decode_steps"]) == (384, 32, 16)
+        assert check["served_requests"] >= 8
+        assert len(check["tolerance_why"]) > 100
+
+
+# ------------------------------------------- the engine's own programs
+STRETCH_S = 3.0     # of the rehearsal's arrivals, a reading
+
+
+def probe_of(cell, fault=None, every_lane=False):
+    probe = serving_control.Probe(cell, rehearse=True, fault=fault,
+                                  stretch_s=STRETCH_S)
+    # every finished request is read, so what a test sees is no draw
+    probe.check = {**probe.check, "served_requests": 10**6}
+    if every_lane:      # and no clock in what a test sees
+        probe.clients = probe.sv["max_batch_size"]
+    return probe
+
+
+@pytest.fixture(scope="module")
+def probes():
+    return {name: probe_of(name, every_lane=True) for name in SERVE}
+
+
+@pytest.mark.parametrize("cell", SERVE)
+def test_the_program_reads_under_the_limit_at_every_position(cell, probes):
+    probe = probes[cell]
+    check = probe.check
+    for seed in (3, 2**31 + 5):
+        got = probe.read(seed)
+        assert len(got["prefill_rel_rms"]) == check["positions"]
+        assert len(got["after_decode_rel_rms"]) == check["decode_steps"]
+        assert len(got["decode_choice_gap"]) == check["decode_steps"]
+        assert serve_load.matches_reference(got, check), got
+        both = got["prefill_rel_rms"] + got["after_decode_rel_rms"]
+        assert 0 < min(both) and max(both) <= 0.6 * check["rel_rms_tol"], got
+        assert max(got["decode_choice_gap"]) <= 0.5 * check["choice_gap_tol"]
+    # the compared chunk lies behind a whole one, its rows in the cache
+    assert check["length"] - check["positions"] >= probe.eng.prefill_chunk
+
+
+@pytest.mark.parametrize("cell", SERVE)
+def test_what_the_timed_path_served_reads_under_the_limit(cell, probes):
+    """A stretch of the cell's traffic through ``engine.step()``, more
+    than one lane alive: every served token of every finished request
+    lies at or near the reference's best, the first token (the prefill's)
+    among them."""
+    probe = probes[cell]
+    got = probe.read(2**31 + 11)
+    assert probe.peak_alive == probe.sv["max_batch_size"]
+    assert len(set(probe.lanes)) == probe.peak_alive     # lane 0 too
+    by_request = got["served_by_request"]
+    assert len(by_request) >= 6
+    assert sum(n for _, n, _ in by_request) == len(got["served_choice_gap"])
+    # prompts of more than one bucket, and on docbatch of two chunks
+    assert len({next(b for b in probe.eng.buckets + [10**6] if b >= p)
+                for p, _, _ in by_request}) > 1
+    assert max(got["served_choice_gap"]) <= 0.5 * probe.check["choice_gap_tol"]
+    assert got["served_agree_share"] > 0.9
+    assert serve_load.matches_reference(got, probe.check)
+
+
+def test_served_readings_find_the_rows_behind_the_padding():
+    """The reference's pass is padded behind the tokens and its head
+    taken over whole blocks of rows: the gap read for each served token
+    is the one a plain pass over prompt and answer gives."""
+    import numpy as np
+
+    class Family:
+        @staticmethod
+        def reference_logits(params, tokens, hp, last=0):
+            # a "model" whose row p prefers token (7 p + sum of the
+            # tokens so far) % vocab by 1.0 over the one behind it by 0.25
+            tokens = np.asarray(tokens)
+            out = np.zeros((len(tokens), hp["vocab_size"]), np.float32)
+            for p in range(len(tokens)):
+                best = (7 * p + int(tokens[:p + 1].sum())) % hp["vocab_size"]
+                out[p, best] = 1.0
+                out[p, (best + 1) % hp["vocab_size"]] = 0.75
+            return out[-last:] if last else out
+
+    hp = {"vocab_size": 97}
+    served = []
+    for plen, n in ((5, 3), (511, 2), (512, 9), (700, 130), (1, 1)):
+        seq = [(3 * i) % 97 for i in range(plen)]
+        answer = []
+        for _ in range(n):
+            answer.append((7 * (len(seq) - 1) + sum(seq)) % 97)
+            seq.append(answer[-1])
+        served.append((seq[:plen], answer))
+    got = server.served_readings(None, Family, hp, served)
+    assert got["served_choice_gap"] == [0.0] * sum(
+        len(a) for _, a in served)
+    assert got["served_agree_share"] == 1.0
+    # the second best everywhere, and one token that is neither
+    off = [(p, [(t + 1) % 97 for t in a[:1]]) for p, a in served]
+    assert server.served_readings(None, Family, hp, off)[
+        "served_choice_gap"] == [0.25] * len(served)
+    wrong = [(served[3][0], served[3][1][:50] + [(served[3][1][50] + 2) % 97])]
+    gaps = server.served_readings(None, Family, hp, wrong)["served_choice_gap"]
+    assert gaps[:50] == [0.0] * 50 and gaps[50] == 1.0
+    assert got["served_by_request"][3] == [700, 130, 0.0]
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 5, 2**31 + 77])
+@pytest.mark.parametrize("cell", SERVE)
+def test_the_control_moves_every_position_over_the_limit(cell, seed,
+                                                         monkeypatch):
+    """One sublayer's operands in fp8, nothing else changed: every one
+    of the readings through the prefill and behind the decodes is over
+    the cell's limit."""
+    from ray_tpu.models import llama
+
+    monkeypatch.setattr(llama, "mlp_sublayer", mlp_in_fp8)
+    probe = probe_of(cell)                               # traced patched
+    got = probe.read(seed)
+    tol = probe.check["rel_rms_tol"]
+    assert min(got["prefill_rel_rms"]) > tol, got
+    assert min(got["after_decode_rel_rms"]) > tol, got
+    assert not serve_load.matches_reference(got, probe.check)
+    of = serve_load.summary(got, probe.check)
+    assert of["prefill_rel_rms"]["outlier_share"] == 1.0
+    assert of["after_decode_rel_rms"]["outlier_share"] == 1.0
+
+
+@pytest.mark.parametrize("cell", SERVE)
+def test_a_token_altered_where_it_is_produced_fails_the_choice_gap(cell):
+    """The decode program's tokens each moved to the next of the
+    vocabulary: the rows it wrote and the logits behind them are still
+    that token's, so the two lists of relative RMS stay under their
+    limit and the choice gaps alone are over their own, the probe's at
+    every step."""
+    vocab = spec.load_cell(cell, True)["hp"]["vocab_size"]
+    probe = probe_of(cell, fault=serving_control.next_token(vocab))
+    got = probe.read(2**31 + 5)
+    check = probe.check
+    assert not serve_load.matches_reference(got, check)
+    assert min(got["decode_choice_gap"]) > check["choice_gap_tol"], got
+    assert max(got["prefill_rel_rms"] + got["after_decode_rel_rms"]) \
+        <= check["rel_rms_tol"]
+    sound = dict(got, decode_choice_gap=[0.0] * check["decode_steps"],
+                 served_choice_gap=[0.0])
+    assert serve_load.matches_reference(sound, check)
+
+
+@pytest.mark.parametrize("fault", ["one_lane", "crossed"])
+@pytest.mark.parametrize("cell", SERVE)
+def test_a_fault_outside_the_probes_lane_fails_the_served_tokens(cell, fault):
+    """The timed path broken underneath, the rest of the comparison as a
+    run drives it. A token altered where it is produced in ONE lane that
+    is not lane 0: the seeded probe (lane 0, the others idle) reads as
+    on a sound engine, and the served tokens of the requests that passed
+    through that lane read whole logits under the reference's best.
+    Lanes given each other's tokens: the probe's decode gaps may see it,
+    the served tokens do, in every lane that decoded."""
+    loaded = spec.load_cell(cell, True)
+    lane = loaded["serve"]["max_batch_size"] - 2      # the second filled
+    change = (serving_control.next_token(loaded["hp"]["vocab_size"], lane)
+              if fault == "one_lane" else serving_control.crossed)
+    probe = probe_of(cell, fault=change, every_lane=True)
+    got = probe.read(2**31 + 5)
+    check = probe.check
+    assert not serve_load.matches_reference(got, check)
+    assert max(got["served_choice_gap"]) > 4 * check["choice_gap_tol"], got
+    assert max(got["prefill_rel_rms"] + got["after_decode_rel_rms"]) \
+        <= check["rel_rms_tol"]
+    if fault == "one_lane":
+        assert lane != 0 and max(got["decode_choice_gap"]) \
+            <= check["choice_gap_tol"]
+        # but for the served tokens the run would have read correct
+        assert serve_load.matches_reference(
+            dict(got, served_choice_gap=[0.0]), check)
+        # the requests of the other lanes read as on a sound engine
+        assert any(at == lane for _, at in probe.lanes)
+        assert sum(g > check["choice_gap_tol"]
+                   for _, _, g in got["served_by_request"]) \
+            < len(got["served_by_request"])
+
+
+# ---------------------------------------------------- a probe that fits
+class _Engine:
+    prefill_chunk, buckets, max_seq = 32, [16, 32], 128
+
+
+@pytest.mark.parametrize("check,why", [
+    ({"length": 40, "positions": 16}, "16 positions or more in the last"),
+    ({"length": 24, "positions": 8}, "one whole chunk"),
+    ({"length": 32, "positions": 8}, "one whole chunk"),
+    ({"length": 104, "positions": 8, "decode_steps": 16}, "do not fit"),
+])
+def test_a_probe_that_does_not_cross_a_chunk_or_fit_is_refused(check, why):
+    with pytest.raises(ValueError, match=why):
+        server.reference_readings(
+            _Engine(), None, 1, {"vocab_size": 64}, check)

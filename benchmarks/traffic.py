@@ -132,6 +132,13 @@ def prompt_tokens(seed: int, request: Request, vocab: int) -> List[int]:
     return rng.integers(0, vocab, request.prompt_len).tolist()
 
 
+def sample(seed: int, n: int, count: int) -> List[int]:
+    """``count`` of range(n), drawn from the seed without replacement:
+    which of the window's finished requests the reference reads."""
+    return sorted(_rng(seed, 4).choice(
+        n, size=max(count, 0), replace=False).tolist())
+
+
 def offered(requests: List[Request]) -> dict:
     window = [r for r in requests if r.due_s >= 0]
     return {

@@ -73,8 +73,15 @@ def first_forward(family, cfg, mesh, hp, params, probe, tokens) -> dict:
         def rms(x):
             return jnp.sqrt(jnp.mean(jnp.square(x)))
 
+        # how the differences are spread, beside their RMS: a routed
+        # family's outliers (a near-tied choice flipped) show here
+        # before a limit is set; they decide nothing
+        gap = jnp.abs(got_nll - want_nll)
         return {
             "nll_rms": rms(got_nll - want_nll),
+            "nll_abs_median": jnp.median(gap),
+            "nll_abs_p80": jnp.percentile(gap, 80),
+            "nll_abs_max": gap.max(),
             "nll_mean": got_nll.mean(),
             "reference_nll_mean": want_nll.mean(),
             "last_logits_rel_rms": rms(got_last - want_last) / rms(want_last),
