@@ -22,6 +22,19 @@ toy preset on the CPU. ``tests/test_serving_reference.py`` keeps the
 control and the fault at that size. Set-up is long (weights, six
 programs), so one process reads the program's dozen seeds and the
 control's three.
+
+    python benchmarks/tests/serving_control.py standin <seeds> <reach seeds>
+
+reads ``routed_standin.py`` instead, a routed family's arithmetic with no
+engine, at the widths such a family would bring to one chip
+(``--rehearse``: at a test's): which positions flip a choice that
+involves a held expert between bf16 and the float32 side, how they and
+the others read, the float32 side's margin at the flipped ones, what a
+margin of 1, 1.3 and 2 x the largest of them marks and leaves, the
+served tokens' choice gap at each group, the fp8 control at the unmarked
+positions, and how far a flip reaches the rows behind it through
+attention (PERF.md section 6, PR 34: what a rule that spares a routed
+family's near-ties would have to fit, and does not yet).
 """
 
 import json
@@ -174,9 +187,150 @@ def seeds_of(text: str) -> list:
     return list(range(first, first + n))
 
 
+# the three serving cells' rel_rms_tol and choice_gap_tol: what a sound
+# position of the stand-in should not read over, and its control has to
+STANDIN_LIMIT, STANDIN_GAP_LIMIT = 0.03, 0.2
+# the margins are summed up from this row on: a serving cell's first
+# compared row lies behind a prompt of 32 tokens or more, and a
+# sequence's first rows attend to so few that a flip there moves them
+# whole
+STANDIN_FROM = 32
+
+
+def standin(seeds: list, reach_seeds: list, rehearse: bool) -> None:
+    """``routed_standin.readings`` over ``seeds`` and ``reach`` over
+    ``reach_seeds``, summed up; every position's readings go to
+    ``chiprun_out/serving_control.standin.npz``."""
+    import numpy as np
+    import routed_standin
+
+    sizes = routed_standin.TOY if rehearse else routed_standin.CHIP
+    rows = {}
+    for seed in seeds:
+        t0 = time.time()
+        got = routed_standin.readings(seed, sizes)
+        for k, v in got.items():
+            rows.setdefault(k, []).append(v)
+        f = got["flipped"]
+        print("standin", seed, "flipped", int(f.sum()), "of", f.size,
+              "their bf16", spread(got["bf16_rel_rms"][f]), "margin at most",
+              float(got["margin"][f].max()) if f.any() else None, "others",
+              spread(got["bf16_rel_rms"][~f]), "fp8",
+              spread(got["fp8_rel_rms"]), f"{time.time() - t0:.1f}s",
+              flush=True)
+    got = {k: np.stack(v) for k, v in rows.items()}      # (seeds, B, S)
+    out = {"sizes": sizes, "seeds": seeds, **standin_summary(got),
+           "reach": {}}
+    for seed in reach_seeds:
+        r = routed_standin.reach(seed, {**sizes, "seqs": 2},
+                                 sizes["seq"] // 4)
+        out["reach"][seed] = {
+            "at": [float(x) for x in r["at"]],
+            "before_largest": float(r["before"].max()),
+            "behind": spread(r["behind"]),
+            "behind_choices_held": spread(r["behind"][~r["behind_flipped"]]),
+            # each row behind that reads over the limit: rows behind,
+            # reading, the sound side's margin, a choice of its own flipped
+            "behind_over_limit": [
+                [int(j) + 1, float(r["behind"][i, j]),
+                 float(r["behind_margin"][i, j]),
+                 bool(r["behind_flipped"][i, j])]
+                for i, j in zip(*np.nonzero(r["behind"] > STANDIN_LIMIT))],
+            "behind_flipped_margin": spread(
+                r["behind_margin"][r["behind_flipped"]])}
+        print("standin reach", seed, out["reach"][seed], flush=True)
+    print("standin over", len(seeds), "seeds:", json.dumps(out), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    np.savez_compressed(
+        "chiprun_out/serving_control.standin.npz",
+        **{k: v.astype(np.float16) if k.endswith(("rms", "gap")) else v
+           for k, v in got.items()})
+    with open("chiprun_out/serving_control.standin.json", "w") as fh:
+        json.dump(out, fh)
+
+
+def standin_summary(got: dict) -> dict:
+    """Of ``routed_standin.readings`` stacked over seeds (seeds, B, S):
+    what PERF.md section 6 (PR 34) quotes."""
+    import numpy as np
+
+    f, m = got["flipped"], got["margin"]
+    b, c = got["bf16_rel_rms"], got["fp8_rel_rms"]
+    g, cg = got["bf16_choice_gap"], got["fp8_choice_gap"]
+    behind = np.logical_or.accumulate(f, -1) & ~f        # a flip before it
+    over = ~f & (b > STANDIN_LIMIT)
+    late = np.s_[..., min(STANDIN_FROM, f.shape[-1] // 4):]
+    largest = float(m[late][f[late]].max())
+    edges = [0, 0.0025, 0.005, 0.01, 0.015, 0.02, 0.025, 0.03, 0.035, 0.04,
+             0.045, 0.05, 0.06, 0.07, 0.08, 0.1, 0.15]
+    out = {"positions": int(f.size),
+           "limits": [STANDIN_LIMIT, STANDIN_GAP_LIMIT],
+           "logit_rms": spread(got["logit_rms"]),
+           "flipped_share": float(f.mean()),
+           "flipped_share_a_sequence_most": float(f.mean(-1).max()),
+           "flipped_rel_rms": spread(b[f]), "other_rel_rms": spread(b[~f]),
+           "other_over_limit": int(over.sum()),
+           # where in their sequences they lie, and what is left later
+           "other_over_limit_rows": sorted(set(np.nonzero(over)[-1].tolist())),
+           "other_late_largest": float(b[late][~f[late]].max()),
+           # the rows before any flipped one of their sequence
+           "other_before_any_flip": spread(b[~behind & ~f]),
+           "flipped_choice_gap": spread(g[f]),
+           "flipped_choice_differs": float((g[f] > 0).mean()),
+           "other_choice_gap": spread(g[~f]),
+           "choice_gap_over_limit": [int((g[f] > STANDIN_GAP_LIMIT).sum()),
+                                     int((g[~f] > STANDIN_GAP_LIMIT).sum())],
+           "fp8_rel_rms": spread(c), "fp8_choice_gap": spread(cg),
+           "fp8_choice_gap_over_limit_share": float(
+               (cg > STANDIN_GAP_LIMIT).mean()),
+           "fp8_choice_gap_a_sequence_least": float(cg.max(-1).min()),
+           "flipped_margin": spread(m[f]), "largest_flipped_all_rows": float(
+               m[f].max()),
+           "late_from_row": late[-1].start, "largest_flipped_late": largest,
+           # how the largest grows with the positions read
+           "largest_flipped_late_by_seeds": {
+               n: float(m[:n][late][f[:n][late]].max())
+               for n in (1, 2, 4, 8, 16, 32, 64, 128) if n <= len(f)},
+           # [from, to, positions, flipped among them]
+           "flips_by_margin": [
+               [lo, hi, int(((m >= lo) & (m < hi)).sum()),
+                int((f & (m >= lo) & (m < hi)).sum())]
+               for lo, hi in zip(edges, edges[1:])],
+           "late_by_factor": {}}
+    f, m, b, c, g, cg = (x[late] for x in (f, m, b, c, g, cg))
+    for factor in (1, 1.3, 2):
+        marked = m < factor * largest * (1 + 1e-6)
+        out["late_by_factor"][factor] = {
+            "tie_margin": factor * largest,
+            "marked_share": float(marked.mean()),
+            "marked_share_a_sequence_most": float(marked.mean(-1).max()),
+            "unmarked_over_limit": int((b[~marked] > STANDIN_LIMIT).sum()),
+            "unmarked_largest": float(b[~marked].max()),
+            "marked_unflipped_choice_gap": spread(g[marked & ~f]),
+            "unmarked_choice_gap": spread(g[~marked]),
+            "fp8_unmarked_least": float(c[~marked].min()),
+            "fp8_unmarked_at_or_under_limit": int(
+                (c[~marked] <= STANDIN_LIMIT).sum())}
+    return out
+
+
+def spread(xs) -> list:
+    """least, median, 99th percentile, 99.9th, largest; and how many."""
+    import numpy as np
+
+    xs = np.asarray(xs, np.float64).ravel()
+    if not xs.size:
+        return []
+    return [float(q) for q in np.quantile(xs, [0, 0.5, 0.99, 0.999, 1])] \
+        + [int(xs.size)]
+
+
 def main(argv) -> int:
     rehearse = "--rehearse" in argv
     cell_name, seeds, control_seeds = [a for a in argv if a != "--rehearse"]
+    if cell_name == "standin":
+        standin(seeds_of(seeds), seeds_of(control_seeds), rehearse)
+        return 0
     from ray_tpu._private.jax_utils import ensure_compilation_cache_dir
 
     ensure_compilation_cache_dir()

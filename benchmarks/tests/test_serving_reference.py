@@ -10,11 +10,17 @@ the limit where the program as it is stays under it with room; and the
 faults a serving cell can have, planted under the timed path where the
 decode program's tokens are produced, in a lane that is not the probe's.
 ``serving_control.py`` beside this file reads the same on the chip at the
-cells' own sizes."""
+cells' own sizes. And ``routed_standin.py``, a routed family's arithmetic
+with no engine, at a test's size: what today's decision makes of a sound
+routed stack, and what a rule that spares its near-ties would have to go
+by (PERF.md section 6, PR 34, has the chip's readings, which fit none
+yet)."""
 
 import math
 
+import numpy as np
 import pytest
+import routed_standin
 import serving_control
 from test_first_forward import mlp_in_fp8
 
@@ -86,6 +92,93 @@ def test_each_list_is_held_to_its_own_limit_and_has_to_be_read(name):
     assert not serve_load.matches_reference(nothing, LIMITS)
     of = serve_load.summary(nothing, LIMITS)[name]
     assert of["n"] == 0 and of["max"] is None
+
+
+# ---------------- a routed family's arithmetic, no engine (routed_standin.py)
+# read as a serving cell reads a model: from row 16 on (a cell's first
+# compared row lies behind a prompt), a relative RMS held to STANDIN_TOL.
+# STANDIN_MARGIN, in units of the router's logits, is twice the largest
+# margin at which these three seeds flip at this size. That factor fits
+# a toy and nothing else: at the widths a chip would hold, 1.3 x the
+# largest flipped margin marks 35 % of 131 072 positions and 43 % of a
+# million, because the largest grows with the positions read (PERF.md
+# section 6, PR 34). These tests keep the arithmetic, not a rule.
+STANDIN_SEEDS = (3, 2**31 + 5, 2**31 + 77)
+STANDIN_FROM, STANDIN_TOL, STANDIN_MARGIN = 16, 0.015, 0.016
+STANDIN_CHECK = {"rel_rms_tol": STANDIN_TOL, "choice_gap_tol": 0.2}
+
+
+@pytest.fixture(scope="module")
+def standin():
+    return {seed: {k: v[:, STANDIN_FROM:] for k, v in routed_standin.readings(
+        seed, routed_standin.TOY).items() if k != "logit_rms"}
+        for seed in STANDIN_SEEDS}
+
+
+@pytest.mark.parametrize("seed", STANDIN_SEEDS)
+def test_the_stand_ins_outliers_are_the_positions_whose_routing_flips(
+        seed, standin):
+    """bf16 against float32 on the same weights: the positions where a
+    choice that involves a held expert flips read far over the limit and
+    nothing else does; each of them has a small margin on the float32
+    side alone; and the control, the shared expert's operands in fp8,
+    reads over the limit at every position, near a tie or not."""
+    got = standin[seed]
+    flipped, rel_rms = got["flipped"], got["bf16_rel_rms"]
+    assert 1 <= flipped.sum() <= 0.03 * flipped.size
+    assert rel_rms[flipped].min() > 4 * STANDIN_TOL
+    assert rel_rms[~flipped].max() < 0.75 * STANDIN_TOL
+    assert 0 < got["margin"].min()
+    assert got["margin"][flipped].max() < 0.6 * STANDIN_MARGIN
+    near = got["margin"] < STANDIN_MARGIN
+    assert flipped.mean() < near.mean() < 0.15      # most near-ties hold
+    assert got["fp8_rel_rms"].min() > 1.4 * STANDIN_TOL
+
+
+def test_a_flip_moves_the_gap_of_the_token_chosen_from_its_row_too(standin):
+    """The served tokens' reading at the stand-in's positions: where no
+    choice flips the bf16 side puts first the float32 side's best token
+    or one within a near-tie of it; of the few flipped positions a toy
+    has, some read a gap ten times that (on the chip 27 % of them read
+    over ``choice_gap_tol``, up to 4.3: PERF.md section 6, PR 34); the
+    fp8 control
+    reads gaps over the limit where bf16 reads none."""
+    flipped = np.stack([standin[s]["flipped"] for s in STANDIN_SEEDS])
+    gap = np.stack([standin[s]["bf16_choice_gap"] for s in STANDIN_SEEDS])
+    control = np.stack([standin[s]["fp8_choice_gap"] for s in STANDIN_SEEDS])
+    tol = STANDIN_CHECK["choice_gap_tol"]
+    assert (gap >= 0).all() and (gap[~flipped] == 0).mean() > 0.9
+    assert gap[~flipped].max() < 0.1 * tol
+    assert gap[flipped].max() > 5 * gap[~flipped].max()
+    assert not (gap > tol).any() and (control > tol).sum() >= 5
+
+
+@pytest.mark.parametrize("side", ["bf16", "fp8"])
+def test_todays_decision_reads_a_sound_routed_sequence_false_where_it_flips(
+        side, standin):
+    """Every position decides (``matches_reference``): a sequence of the
+    sound bf16 side is correct where no choice flipped and not correct
+    where one did, which is why a routed family cannot bring a serving
+    cell yet; the control is not correct in any."""
+    seed = STANDIN_SEEDS[0]
+    flipped = standin[seed]["flipped"]
+    assert 2 <= flipped.any(-1).sum() < len(flipped)
+    for sequence, rel_rms in enumerate(standin[seed][f"{side}_rel_rms"]):
+        ref = readings([float(x) for x in rel_rms])
+        assert serve_load.matches_reference(ref, STANDIN_CHECK) is (
+            side == "bf16" and not flipped[sequence].any())
+
+
+def test_a_flip_made_on_purpose_reaches_no_row_before_it():
+    at = 24
+    by = routed_standin.reach(5, {**routed_standin.TOY, "seqs": 2}, at)
+    assert by["before"].max() == 0.0            # causal
+    assert by["at"].min() > 4 * STANDIN_TOL     # a held expert more or less
+    # behind it, through attention: little, where no choice of the row's
+    # own flipped with its moved input
+    held = ~by["behind_flipped"]
+    assert by["behind"][held].max() < 0.5 * STANDIN_TOL
+    assert by["behind_margin"].shape == by["behind"].shape
 
 
 class _Done:
