@@ -28,9 +28,9 @@ re-designed for XLA instead of wrapped:
   PR 29). What bounds a chunk from above is how long the decode step
   behind it may wait, which no cell judges yet. Three chunk buckets
   (C/4, C/2, C) bound compilations. The head runs on the one row a
-  chunk returns logits for. ``warm_up()`` runs every program once;
-  ``LLMServer`` calls it before it takes a request, a bare engine
-  compiles on first use.
+  chunk returns logits for. ``warm_up()`` runs every program once, at
+  every read window; ``LLMServer`` calls it before it takes a request,
+  a bare engine compiles on first use.
 - KV cache is preallocated per shard (L, B, KVH, max_seq, hd) and
   UPDATED IN PLACE: both programs are jitted with the cache donated,
   the cached forward carries it through its layer scan and writes only
@@ -40,6 +40,18 @@ re-designed for XLA instead of wrapped:
   ``shard.cache`` from the result, and ``abort_all()`` re-allocates the
   cache of a shard whose failed call took it along. Per-slot lengths
   mask attention (models/llama.py forward_with_cache).
+- Attention READS ONLY THE ROWS A LIVE SEQUENCE CAN ATTEND TO. Each
+  program is compiled for two read windows, the whole cache and its
+  first half (1024 / 2048 rows of a 2048-row cache), and a call runs
+  the least that holds its rows: a whole chunk's ``start + bucket``, a
+  decode's longest live lane plus the row it writes (a bucket under the
+  chunk reads every row: its call is the weights' read). The choice is
+  made on the host from the numpy arguments every caller hands over
+  anyway (``_prefill`` / ``_decode``), so a short conversation in a long
+  cache does not pay for the cache's length. A window that holds every
+  attendable row gives the full read's result (a masked row weighs 0
+  exactly). ``attn_rows_read`` / ``attn_rows_full`` says how much of the
+  cache's length the calls read.
 - Sampling (greedy / temperature) is jitted with the decode step; a
   prompt's first token is drawn the same way by ``first_token``, a
   program of a few instructions over the logits its last chunk returned.
@@ -188,6 +200,10 @@ class EngineStats:
         "steps", "tokens_emitted",
         "prefill_chunks",
         "prefill_tokens", "prefill_rows",  # real tokens; the buckets' rows
+        # cache rows a sequence the prefill and decode calls read for
+        # attention (their read windows), and max_seq a call; counted
+        # where the window is chosen (``_prefill`` / ``_decode``)
+        "attn_rows_read", "attn_rows_full",
         "decode_calls", "decode_lanes_active", "decode_lanes_total",
         # decode calls dispatched while the shard's last one was unread;
         # lane-steps computed for a request that had already ended
@@ -255,24 +271,37 @@ class LlamaEngine:
         # its rows, so a bucket far under the chunk saves little
         c = self.prefill_chunk
         self.buckets = [b for b in (c // 4, c // 2) if b >= 16] + [c]
+        # read windows: attention reads the first `rows` rows of a
+        # sequence's cache, the least of these that holds every row a
+        # call can attend to: the whole cache and, where a chunk fits
+        # it, its first half. No finer: every variant of a program is
+        # 0.2 to 0.7 s of a replica's start (traced, lowered, loaded),
+        # and the first halving is most of what there is to gain
+        # (PERF.md section 6, PR 35)
+        self.windows = [max_seq]
+        if max_seq % 2 == 0 and max_seq // 2 >= c:
+            self.windows.insert(0, max_seq // 2)
 
-        def prefill(params, cache, tokens, slot_onehot, start, length, bucket):
+        def prefill(params, cache, tokens, slot_onehot, start, length,
+                    bucket, rows):
             # tokens (1, bucket) padded; writes into the slot's rows at
             # offset `start` and returns logits at the chunk's last real
-            # token (used only when the chunk completes the prompt)
+            # token (used only when the chunk completes the prompt);
+            # attends to the slot's first `rows` rows
             del bucket
             logits, new_cache = llama.forward_with_cache(
                 params, tokens, cache, start, config,
                 slot=jnp.argmax(slot_onehot),
-                logits_at=jnp.reshape(length - 1, (1,)),
+                logits_at=jnp.reshape(length - 1, (1,)), rows=rows,
             )
             return logits[0, 0], new_cache
 
-        def decode(params, cache, last_tokens, lengths, temps, rng):
+        def decode(params, cache, last_tokens, lengths, temps, rng, rows):
             # one token for every slot: tokens (B,), lengths (B,) = count
             # already in cache; inactive slots just waste a lane
             logits, new_cache = llama.forward_with_cache(
-                params, last_tokens[:, None], cache, lengths, config
+                params, last_tokens[:, None], cache, lengths, config,
+                rows=rows,
             )
             with jax.named_scope("sample"):
                 logits = logits[:, 0]  # (B, V)
@@ -299,9 +328,11 @@ class LlamaEngine:
                         jnp.where(temp > 0, keys[0], rng))
 
         self._program_fns = (prefill, decode, first_token)
-        self._prefill, self._decode, self._first_token = self._jit_programs(
-            *self._program_fns)
-        self._buckets_run: set = set()
+        self._jit_prefill, self._jit_decode, self._first_token = (
+            self._jit_programs(*self._program_fns))
+        # the variants run so far: (bucket, rows) of prefill, rows of decode
+        self._prefills_run: set = set()
+        self._decodes_run: set = set()
         self._lock = threading.Lock()
 
     def _jit_programs(self, prefill, decode, first_token):
@@ -309,8 +340,67 @@ class LlamaEngine:
         scan the programs update it in place, and the buffer a caller
         passed in is gone once the call is dispatched."""
         jit = self._jax.jit
-        return (jit(prefill, static_argnames=("bucket",), donate_argnums=(1,)),
-                jit(decode, donate_argnums=(1,)), jit(first_token))
+        return (jit(prefill, static_argnames=("bucket", "rows"),
+                    donate_argnums=(1,)),
+                jit(decode, static_argnames=("rows",), donate_argnums=(1,)),
+                jit(first_token))
+
+    def _prefill_variants(self) -> List[Tuple[int, int]]:
+        """Every (bucket, rows) ``prefill_window`` can choose."""
+        return [(b, w) for b in self.buckets
+                for w in (self.windows if b == self.prefill_chunk
+                          else [self.max_seq])]
+
+    def _window(self, need: int) -> int:
+        """The least read window of ``need`` rows or more."""
+        return next(w for w in self.windows if w >= need)
+
+    def prefill_window(self, start, bucket: int) -> int:
+        """Rows a prefill call reads: a whole chunk ends at ``start`` +
+        ``bucket`` and attends to nothing behind. A bucket under the
+        chunk (a prompt's last rows) reads every row: such a call is the
+        weights' read, its attention a few per cent whatever the rows,
+        and a window's variant of it would not pay for its place in a
+        replica's start. ``start`` is the (1,) numpy array the call is
+        handed."""
+        if bucket < self.prefill_chunk:
+            return self.max_seq
+        return self._window(int(start[0]) + bucket)
+
+    def decode_window(self, lengths) -> int:
+        """Rows a decode call reads: a lane of ``lengths[b]`` rows
+        writes one more and attends to all of them. Idle lanes carry the
+        scratch row ``max_seq - 1`` and what they compute is dropped; a
+        live lane is dispatched at ``max_seq - 3`` at most
+        (``_last_by_count``). ``lengths`` is the numpy array the call is
+        handed."""
+        live = lengths[lengths != self.max_seq - 1]
+        return self._window(int(live.max()) + 1) if live.size else self.max_seq
+
+    # The two programs as every caller knows them (``step()``, the
+    # benchmark's probe): the read window is chosen here, on the host,
+    # from the numpy arguments they pass anyway, and counted here, so
+    # that ``attn_rows_read`` is what was dispatched whoever called.
+    def _count_rows(self, rows: int) -> None:
+        self.stats.attn_rows_read += rows
+        self.stats.attn_rows_full += self.max_seq
+
+    def _prefill(self, params, cache, tokens, slot_onehot, start, length, *,
+                 bucket):
+        """-> (logits (V,) of the chunk's last real token, cache)."""
+        rows = self.prefill_window(start, bucket)
+        self._prefills_run.add((bucket, rows))
+        self._count_rows(rows)
+        return self._jit_prefill(params, cache, tokens, slot_onehot, start,
+                                 length, bucket=bucket, rows=rows)
+
+    def _decode(self, params, cache, last_tokens, lengths, temps, rng):
+        """-> (tokens (B,), cache, the next sampling key)."""
+        rows = self.decode_window(lengths)
+        self._decodes_run.add(rows)
+        self._count_rows(rows)
+        return self._jit_decode(params, cache, last_tokens, lengths, temps,
+                                rng, rows=rows)
 
     def _new_cache(self):
         return self._llama.init_kv_cache(
@@ -329,31 +419,37 @@ class LlamaEngine:
         )
 
     def warm_up(self) -> None:
-        """Run every program once (each chunk bucket into slot 0 of the
-        first shard, a first token off the last one's logits, then decode
-        on its scratch row, its tokens on the device as ``step()`` passes
-        them) and wait for them, so that no request pays a compile.
-        Counts nothing in ``stats`` and leaves the sampling key as it
-        was; the rows it writes are overwritten by the slot's next prompt
-        before anything attends to them. The engine must be idle."""
+        """Run every program once, in every variant ``step()`` can ask
+        for (the whole chunk at each read window and the smaller buckets
+        at the top one, into slot 0 of the first shard; a first token off
+        the last one's logits; then decode at each window on the scratch
+        row, its tokens on the device as ``step()`` passes them) and wait
+        for them, so that no request pays a compile. Each variant is
+        asked for by name, not through the host's choice. Counts nothing
+        in ``stats`` and leaves the sampling key as it was; the rows it
+        writes are overwritten by the slot's next prompt before anything
+        attends to them. The engine must be idle."""
         with self._lock:
             if self.num_active():
                 raise RuntimeError("warm_up needs an idle engine")
             shard = self.shards[0]
             onehot = np.zeros(self.max_batch, np.float32)
             onehot[0] = 1.0
-            for bucket in self.buckets:
-                logits, shard.cache = self._prefill(
+            for bucket, rows in self._prefill_variants():
+                logits, shard.cache = self._jit_prefill(
                     self.params, shard.cache, np.zeros((1, bucket), np.int32),
-                    onehot, np.zeros(1, np.int32), 1, bucket=bucket)
+                    onehot, np.zeros(1, np.int32), 1, bucket=bucket, rows=rows)
             tokens, _, _ = self._first_token(
                 shard.tokens, logits, np.int32(0), np.float32(0), self._rng)
-            toks, shard.cache, _ = self._decode(
-                self.params, shard.cache, tokens,
-                np.full(self.max_batch, self.max_seq - 1, np.int32),
-                np.zeros(self.max_batch, np.float32), self._rng)
+            for rows in self.windows:
+                toks, shard.cache, _ = self._jit_decode(
+                    self.params, shard.cache, tokens,
+                    np.full(self.max_batch, self.max_seq - 1, np.int32),
+                    np.zeros(self.max_batch, np.float32), self._rng,
+                    rows=rows)
             self._jax.block_until_ready((toks, shard.cache))
-        self._buckets_run.update(self.buckets)
+        self._prefills_run.update(self._prefill_variants())
+        self._decodes_run.update(self.windows)
 
     # ------------------------------------------------------------------
     def has_capacity(self) -> bool:
@@ -472,7 +568,6 @@ class LlamaEngine:
                 shard.lengths[req.slot] = n
                 shard.active[req.slot] = req
                 shard.first = (req, tok)
-        self._buckets_run.add(bucket)
         req.prefill_pos = pos + chunk
         req.prefill_chunks += 1
         stats.prefill_chunks += 1
@@ -481,10 +576,14 @@ class LlamaEngine:
 
     def _last_by_count(self, req: GenRequest, count: int) -> bool:
         """Whether a request's ``count``-th token is its last whatever
-        it is: ``max_tokens`` reached, or (from the second on) the cache
-        row before the scratch row. Known before the token is."""
-        return count >= req.max_tokens or (
-            count > 1 and len(req.prompt_ids) + count >= self.max_seq - 1)
+        it is: ``max_tokens`` reached, or the cache row before the
+        scratch row (a prompt that ends there or on it gets its first
+        token and no decode, so no live lane is ever dispatched with the
+        scratch row's number for a length, which is how
+        ``decode_window`` tells the idle lanes). Known before the token
+        is."""
+        return (count >= req.max_tokens
+                or len(req.prompt_ids) + count >= self.max_seq - 1)
 
     def _dispatch_decode(self, shard: _Shard):
         """Dispatch one decode for the shard's lanes that have a token
@@ -581,12 +680,15 @@ class LlamaEngine:
             return out
 
     def compiled_programs(self) -> Dict[str, Any]:
-        """The engine's programs as compiled executables: ``decode``,
-        ``first_token`` and ``prefill_<bucket>`` for each chunk bucket
-        run so far. For reading their text (``jax_utils.scope_map``) next
-        to a device trace. Each is jitted afresh and compiled again (or
-        loaded from the persistent cache; ``compile_with_scopes`` says
-        why), so call this outside anything timed."""
+        """The engine's programs as compiled executables, one for each
+        variant run so far: ``first_token``, ``decode_<rows>`` for each
+        read window and ``prefill_<bucket>_<rows>`` for each chunk bucket
+        at each. For reading their text (``jax_utils.scope_map``) next
+        to a device trace, where every variant of a program runs under
+        the one name it was jitted under. Each is jitted afresh and
+        compiled again (or loaded from the persistent cache;
+        ``compile_with_scopes`` says why), so call this outside anything
+        timed."""
         from ray_tpu._private.jax_utils import compile_with_scopes
 
         # new function objects under the old names: new traces, new modules
@@ -595,19 +697,19 @@ class LlamaEngine:
         cache = self.shards[0].cache
         i32, f32 = np.int32, np.float32
         tokens = np.zeros(self.max_batch, i32)
-        out = {
-            "decode": compile_with_scopes(decode.lower(
+        out = {"first_token": compile_with_scopes(first_token.lower(
+            tokens, np.zeros(self.config.vocab_size, f32), i32(0), f32(0),
+            self._rng))}
+        for rows in sorted(self._decodes_run):
+            out[f"decode_{rows}"] = compile_with_scopes(decode.lower(
                 self.params, cache, tokens, np.zeros(self.max_batch, i32),
-                np.zeros(self.max_batch, f32), self._rng)),
-            "first_token": compile_with_scopes(first_token.lower(
-                tokens, np.zeros(self.config.vocab_size, f32), i32(0), f32(0),
-                self._rng)),
-        }
-        for bucket in sorted(self._buckets_run):
-            out[f"prefill_{bucket}"] = compile_with_scopes(prefill.lower(
-                self.params, cache, np.zeros((1, bucket), i32),
-                np.zeros(self.max_batch, f32), np.zeros(1, i32), 1,
-                bucket=bucket))
+                np.zeros(self.max_batch, f32), self._rng, rows=rows))
+        for bucket, rows in sorted(self._prefills_run):
+            out[f"prefill_{bucket}_{rows}"] = compile_with_scopes(
+                prefill.lower(
+                    self.params, cache, np.zeros((1, bucket), i32),
+                    np.zeros(self.max_batch, f32), np.zeros(1, i32), 1,
+                    bucket=bucket, rows=rows))
         return out
 
     # ------------------------------------------------------------------

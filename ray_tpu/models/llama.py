@@ -466,6 +466,7 @@ def forward_with_cache(
     *,
     slot: Optional[jax.Array] = None,
     logits_at: Optional[jax.Array] = None,
+    rows: Optional[int] = None,
 ):
     """Incremental forward: tokens (B, T) appended at per-sequence
     offsets ``start_pos`` (B,). Returns (logits (B, T, V) fp32, updated
@@ -480,6 +481,14 @@ def forward_with_cache(
     ``slot + b`` where ``slot`` (a traced scalar) is given: the engine
     prefills one sequence, tokens (1, T), into its slot of a shard.
 
+    ``rows`` (static; default all ``max_seq``) is the read window:
+    attention reads cache rows ``[0, rows)`` of each sequence and no
+    more. The caller vouches that every row a live query may attend to
+    (``start_pos + T`` of them) lies inside; a masked row weighs
+    exp(-1e30 - max) = 0 exactly, so any such window gives the full
+    read's result. Writes go to the full cache wherever ``start_pos``
+    says, inside the window or not (an idle lane's to its scratch row).
+
     The cache is updated in place: the stacked k/v ride in the layer
     scan's carry, a layer writes its T new rows into them and reads its
     own rows for attention out of them. Under a jit that donates the
@@ -488,6 +497,7 @@ def forward_with_cache(
     c = config
     B, T = tokens.shape
     _, _, KVH, max_seq, hd = cache["k"].shape
+    rows = max_seq if rows is None else rows
     with jax.named_scope("embed"):
         x = params["embed"].astype(c.dtype)[tokens]
     cos_full, sin_full = rope_table(c, max_seq)
@@ -506,9 +516,9 @@ def forward_with_cache(
         return stack
 
     def read(stack, layer):
-        # this layer's rows of the B sequences (B, KVH, max_seq, hd)
+        # this layer's first `rows` rows of the B sequences
         return jax.lax.dynamic_slice(
-            stack, (layer, first, 0, 0, 0), (1, B, KVH, max_seq, hd))[0]
+            stack, (layer, first, 0, 0, 0), (1, B, KVH, rows, hd))[0]
 
     def body(carry, layer):
         x, k_all, v_all, i = carry

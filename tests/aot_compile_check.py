@@ -150,30 +150,52 @@ def main() -> int:
     cache = abstract(jax.eval_shape(
         partial(llama.init_kv_cache, serve_cfg, 8, 2048)))
 
-    def prefill_chunk(rows):
+    params = abstract(jax.eval_shape(
+        partial(llama.init_params, config=serve_cfg), jax.random.PRNGKey(0)))
+
+    def prefill_chunk(rows, window=None):
         """What the engine's prefill program does with a chunk of one
-        sequence: its rows into one slot of a donated cache shard."""
+        sequence: its rows into one slot of a donated cache shard,
+        attention over the first ``window`` rows of the slot."""
         def chunk(params, cache, tokens, start, slot, at):
             return llama.forward_with_cache(
                 params, tokens, cache, start, serve_cfg, slot=slot,
-                logits_at=at)
+                logits_at=at, rows=window)
 
-        params = abstract(jax.eval_shape(
-            partial(llama.init_params, config=serve_cfg), jax.random.PRNGKey(0)))
         return jax.jit(chunk, donate_argnums=(1,)).lower(
             params, cache, sds((1, rows), jnp.int32), sds((1,), jnp.int32),
             sds((), jnp.int32), sds((1,), jnp.int32))
+
+    def decode_step(window):
+        """The engine's decode program without its sampling: one row a
+        lane, every lane of the shard."""
+        def step(params, cache, tokens, lengths):
+            return llama.forward_with_cache(
+                params, tokens[:, None], cache, lengths, serve_cfg,
+                rows=window)
+
+        return jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, sds((8,), jnp.int32), sds((8,), jnp.int32))
 
     # the cache is updated in place: no instruction copies a whole leaf
     # of the shard (layout assignment once bracketed the layer scan with
     # two, for chunks of 128 rows and more). The three buckets of a v5e's
     # derived chunk: 64 rows run without forward_with_cache's barrier,
-    # 128 and 256 with it
+    # 128 and 256 with it, all reading the whole slot, the top one of the
+    # engine's read windows. Then the other window at 8 x 2048, the
+    # first 1024 rows, where the layer reads fewer rows than the carried
+    # stack holds, for the whole chunk, and the decode step at both
     shard = ",".join(str(d) for d in cache["k"].shape)
+    no_shard_copy = rf"\[{shard}\]\S* copy\("
     for rows in (64, 128, 256):
         check(f"prefill chunk of {rows} rows into one slot of 8 x 2048, "
-              "one device",
-              partial(prefill_chunk, rows), forbid=rf"\[{shard}\]\S* copy\(")
+              "one device", partial(prefill_chunk, rows), forbid=no_shard_copy)
+    check("prefill chunk of 256 rows reading 1024 of 8 x 2048, one device",
+          partial(prefill_chunk, 256, 1024), forbid=no_shard_copy)
+    for window in (1024, 2048):
+        check(f"decode step of 8 lanes reading {window} of 8 x 2048, "
+              "one device", partial(decode_step, window),
+              forbid=no_shard_copy)
 
     for n in (1, 4):
         check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "

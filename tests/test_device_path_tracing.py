@@ -149,9 +149,10 @@ def test_engine_stats_equal_the_hand_count(tiny_params):
 
 def test_engine_programs_are_exposed_for_their_text(tiny_params):
     eng = make_engine(tiny_params)
-    eng.generate(list(range(1, 20)), max_tokens=2)   # buckets 16 only
+    # bucket 16 only; 19 tokens lie in the cache's first half, 64 rows
+    eng.generate(list(range(1, 20)), max_tokens=2)
     programs = eng.compiled_programs()
-    assert sorted(programs) == ["decode", "first_token", "prefill_16"]
+    assert sorted(programs) == ["decode_64", "first_token", "prefill_16_64"]
     assert all(scope_map(c) for c in programs.values())
 
 
@@ -176,16 +177,16 @@ def test_engine_programs_carry_scopes_over_a_stale_compile_cache(
     programs = eng.compiled_programs()
     words = {k: set().union(*(scopes_in(p) for p in scope_map(c).values()))
              for k, c in programs.items()}
-    assert {"mlp", "attn", "kv_write", "sample"} <= words["decode"]
-    assert {"mlp", "kv_slice", "kv_write"} <= words["prefill_16"]
+    assert {"mlp", "attn", "kv_write", "sample"} <= words["decode_48"]
+    assert {"mlp", "kv_slice", "kv_write"} <= words["prefill_16_48"]
     # the same instructions as the program the jitted function runs
     args = (np.zeros(3, np.int32), np.zeros(3, np.int32),
             np.zeros(3, np.float32), eng._rng)
-    running = eng._decode.lower(
-        eng.params, eng.shards[0].cache, *args).compile().as_text()
+    running = eng._jit_decode.lower(
+        eng.params, eng.shards[0].cache, *args, rows=48).compile().as_text()
     names = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ", re.M)
     assert names.findall(running) == names.findall(
-        programs["decode"].as_text())
+        programs["decode_48"].as_text())
 
 
 # ------------------------------------------------- the profiler's trace
@@ -273,9 +274,9 @@ PROGRAM_SCOPES = {
     "train_remat": (partial(_train_step_text, True),
                     MODEL_SCOPES | {"ce", "loss_and_grad", "optimizer",
                                     "rematted_computation"}),
-    "prefill": (partial(_engine_text, "prefill_16"),
+    "prefill": (partial(_engine_text, "prefill_16_64"),
                 MODEL_SCOPES | {"kv_slice", "kv_write", "attn_cached"}),
-    "decode": (partial(_engine_text, "decode"),
+    "decode": (partial(_engine_text, "decode_64"),
                MODEL_SCOPES | {"kv_write", "attn_cached", "sample"}),
 }
 

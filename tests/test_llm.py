@@ -3,6 +3,7 @@ continuous batching, serving (handle + HTTP + streaming), Data batch
 inference, and TP x PP placement sizing (reference:
 python/ray/llm/_internal/serve/.../vllm_models.py:123-142)."""
 
+import contextlib
 import dataclasses
 
 import numpy as np
@@ -135,14 +136,15 @@ def test_prefill_chunk_is_derived_from_the_chip(kind, dtype, max_seq, want):
     assert chunk == want and max_seq % chunk == 0
 
 
-@pytest.mark.parametrize("asked, chunk, buckets", [
-    (None, 256, [64, 128, 256]),   # float32 weights, a kind not in the table
-    (64, 64, [16, 32, 64]),        # an explicit size wins
-    (16, 16, [16]),
-    (512, 256, [64, 128, 256]),    # and is fitted to max_seq as before
+@pytest.mark.parametrize("asked, chunk, buckets, windows", [
+    # float32 weights, a kind not in the table; no window under a chunk
+    (None, 256, [64, 128, 256], [256]),
+    (64, 64, [16, 32, 64], [128, 256]),         # an explicit size wins
+    (16, 16, [16], [128, 256]),                 # nor under max_seq / 2
+    (512, 256, [64, 128, 256], [256]),  # and is fitted to max_seq as before
 ])
 def test_engine_chunk_and_its_three_buckets(engine_setup, asked, chunk,
-                                            buckets):
+                                            buckets, windows):
     import jax
 
     from ray_tpu.llm._internal.engine import derived_prefill_chunk
@@ -151,13 +153,17 @@ def test_engine_chunk_and_its_three_buckets(engine_setup, asked, chunk,
     kw = {} if asked is None else {"prefill_chunk": asked}
     eng = LlamaEngine(cfg, params, max_batch=2, max_seq=256, **kw)
     assert (eng.prefill_chunk, eng.buckets) == (chunk, buckets)
+    assert eng.windows == windows
     if asked is None:
         assert chunk == derived_prefill_chunk(
             jax.devices()[0].device_kind, 4, 256)
-    # decode, three buckets, and the first token's few instructions
+    # decode and the whole chunk at every read window, the smaller
+    # buckets at the top one, and the first token's few instructions
     eng.warm_up()
     assert sorted(eng.compiled_programs()) == sorted(
-        ["decode", "first_token"] + [f"prefill_{b}" for b in buckets])
+        ["first_token"] + [f"decode_{w}" for w in windows]
+        + [f"prefill_{chunk}_{w}" for w in windows]
+        + [f"prefill_{b}_256" for b in buckets[:-1]])
 
 
 @pytest.mark.parametrize("chunk", [16, 64, None])
@@ -176,8 +182,9 @@ def test_chunked_prefill_matches_one_full_forward(engine_setup, chunk):
     onehot = np.zeros(2, np.float32)
     onehot[1] = 1.0
     all_rows = eng._jax.jit(
-        lambda cache, tokens, start: llama.forward_with_cache(
-            params, tokens, cache, start, cfg, slot=jnp.int32(1))[0])
+        lambda cache, tokens, start, rows: llama.forward_with_cache(
+            params, tokens, cache, start, cfg, slot=jnp.int32(1),
+            rows=rows)[0], static_argnames="rows")
 
     def prefill(eng):
         shard = eng.shards[0]
@@ -187,7 +194,8 @@ def test_chunked_prefill_matches_one_full_forward(engine_setup, chunk):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :len(part)] = part
             start = np.asarray([pos], np.int32)
-            want = all_rows(shard.cache, tokens, start)[0, len(part) - 1]
+            rows = eng.prefill_window(start, bucket)
+            want = all_rows(shard.cache, tokens, start, rows)[0, len(part) - 1]
             got, shard.cache = eng._prefill(
                 eng.params, shard.cache, tokens, onehot, start, len(part),
                 bucket=bucket)
@@ -348,7 +356,8 @@ def test_engine_programs_update_the_cache_in_place():
     instruction = re.compile(
         r"^\s+(?:ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
     programs = eng.compiled_programs()
-    assert sorted(programs) == ["decode", "first_token", "prefill_16"]
+    # 19 tokens and then 3, all inside the cache's first half
+    assert sorted(programs) == ["decode_64", "first_token", "prefill_16_64"]
     del programs["first_token"]            # takes no cache
     for name, compiled in programs.items():
         text = compiled.as_text()
@@ -418,8 +427,8 @@ def plain_loop(eng, prompt, max_tokens, eos_id=None):
     out = [int(np.asarray(logits).argmax())]
     lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
     temps = np.zeros(eng.max_batch, np.float32)
-    while len(out) < max_tokens and out[-1] != eos_id and (
-            len(out) == 1 or len(prompt) + len(out) < eng.max_seq - 1):
+    while (len(out) < max_tokens and out[-1] != eos_id
+           and len(prompt) + len(out) < eng.max_seq - 1):
         last = np.zeros(eng.max_batch, np.int32)
         last[0], lens[0] = out[-1], len(prompt) + len(out) - 1
         toks, shard.cache, _ = eng._decode(
@@ -588,17 +597,27 @@ def test_an_idle_engine_has_nothing_in_flight(engine_setup):
     assert eng.generate(prompt, max_tokens=5) == plain_loop(plain, prompt, 5)
 
 
-@pytest.fixture(scope="module")
-def started_server():
-    """An in-process LLMServer, and the number of programs lowered
-    since it started (the event the benchmark's
-    ``nothing_compiled_in_window`` counts; it fires on a cache hit too)."""
+# ------------------------------------------------ the read windows
+WINDOW_KW = dict(max_batch=4, max_seq=128, prefill_chunk=16, max_slots=4)
+SCRATCH = WINDOW_KW["max_seq"] - 1      # an idle lane's length
+
+
+def _every_row(cfg, params, **kw):
+    """An engine with the one window that is the whole cache: every
+    call reads all ``max_seq`` rows, as every call did before there were
+    windows."""
+    eng = LlamaEngine(cfg, params, **kw)
+    eng.windows = [eng.max_seq]
+    return eng
+
+
+@contextlib.contextmanager
+def _programs_lowered():
+    """The programs lowered inside the block, one entry each (the event
+    the benchmark's ``nothing_compiled_in_window`` counts; it fires on a
+    cache hit too)."""
     import jax.monitoring
 
-    from ray_tpu.llm.serve import LLMServer
-
-    server = LLMServer(LLMConfig(
-        model_config=tiny_cfg(), max_batch_size=2, max_seq_len=64))
     lowered = []
 
     def on_event(event, duration, **kwargs):
@@ -606,9 +625,221 @@ def started_server():
             lowered.append(event)
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
-    yield server, lowered
+    try:
+        yield lowered
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+# tokens, start_pos, slot; the rows a live query attends to; live rows
+WINDOW_CALLS = {
+    # lanes 0 and 2 alive at 20 and 5 rows, the others on the scratch row
+    "decode_among_idle_lanes": dict(
+        tokens=np.asarray([[7], [0], [9], [0]], np.int32),
+        start=np.asarray([20, SCRATCH, 5, SCRATCH], np.int32), slot=None,
+        need=21, live=[0, 2]),
+    # 16 rows at offset 32 of slot 2, which attend to the 32 before them
+    "chunk_behind_an_offset": dict(
+        tokens=np.asarray([[1 + (3 * j) % 500 for j in range(16)]], np.int32),
+        start=np.asarray([32], np.int32), slot=2, need=48, live=[0]),
+}
+
+
+@pytest.mark.parametrize("call, rows", [
+    ("decode_among_idle_lanes", 32), ("decode_among_idle_lanes", 64),
+    ("decode_among_idle_lanes", 128),
+    ("chunk_behind_an_offset", 64), ("chunk_behind_an_offset", 128),
+])
+def test_a_window_that_holds_the_sequence_reads_what_all_rows_read(
+        engine_setup, call, rows):
+    """``forward_with_cache(rows=r)``, for windows of 32, 64 and 128
+    rows where they hold the rows a live query attends to: the logits of
+    the live sequences and the cache (but an idle lane's scratch row, which is
+    written from what that lane computed and never attended to) equal
+    the full read's within float32 rounding (a masked row weighs 0), and
+    ``rows=max_seq`` is the full read to the last bit."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, params = engine_setup
+    cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    cache = llama.init_kv_cache(cfg, 4, 128)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    # rows other calls wrote: every slot full of them up to row 100
+    cache = {n: a.at[:, :, :, :100].set(jax.random.normal(
+        k, a[:, :, :, :100].shape, a.dtype))
+        for (n, a), k in zip(cache.items(), keys)}
+    c = WINDOW_CALLS[call]
+    assert rows >= c["need"]
+    tol = dict(rtol=0, atol=0) if rows == 128 else dict(rtol=1e-5, atol=1e-5)
+
+    def run(rows):
+        slot = None if c["slot"] is None else jnp.int32(c["slot"])
+        return llama.forward_with_cache(
+            params, c["tokens"], cache, c["start"], cfg, slot=slot,
+            rows=rows)
+
+    logits, new = run(rows)
+    full_logits, full = run(None)
+    assert np.abs(np.asarray(full_logits)).max() > 0.1
+    np.testing.assert_allclose(
+        np.asarray(logits)[c["live"]], np.asarray(full_logits)[c["live"]],
+        **tol)
+    for name in ("k", "v"):
+        got, want = np.asarray(new[name]), np.asarray(full[name])
+        assert (got != np.asarray(cache[name])).any()     # rows were written
+        np.testing.assert_allclose(
+            got[..., :SCRATCH, :], want[..., :SCRATCH, :], **tol)
+
+
+@pytest.mark.parametrize("call, args, rows", [
+    # a chunk reads the least window that holds start + bucket
+    ("prefill", (0, 32), 64), ("prefill", (32, 32), 64),
+    ("prefill", (64, 32), 128), ("prefill", (96, 32), 128),
+    # a bucket under the chunk every row, wherever it lies
+    ("prefill", (0, 16), 128), ("prefill", (32, 16), 128),
+    # a decode the longest live lane and the row it writes
+    ("decode", [0], 64), ("decode", [62], 64), ("decode", [63], 64),
+    ("decode", [64], 128), ("decode", [3, 63, 8], 64),
+    ("decode", [3, 64, 8], 128),
+    # idle lanes are not counted, whatever their number
+    ("decode", [SCRATCH, 10, SCRATCH, SCRATCH], 64),
+    # two rows under the scratch row: the last a live lane is dispatched at
+    ("decode", [SCRATCH - 2, 2], 128), ("decode", [2, SCRATCH, SCRATCH - 2], 128),
+    # nothing alive (warm_up's call): every row
+    ("decode", [SCRATCH] * 4, 128),
+])
+def test_the_host_picks_the_least_window_that_holds_the_call(
+        engine_setup, call, args, rows):
+    cfg, params = engine_setup
+    eng = LlamaEngine(cfg, params, **{**WINDOW_KW, "prefill_chunk": 32})
+    assert (eng.buckets, eng.windows) == ([16, 32], [64, 128])
+    if call == "prefill":
+        start = np.asarray([args[0]], np.int32)
+        assert eng.prefill_window(start, args[1]) == rows
+    else:
+        lengths = np.full(4, SCRATCH, np.int32)
+        lengths[:len(args)] = args
+        assert eng.decode_window(lengths) == rows
+
+
+@pytest.mark.parametrize("n, tokens", [
+    (SCRATCH, 1), (SCRATCH - 1, 1),     # the longest prompt admitted
+    (SCRATCH - 2, 2), (SCRATCH - 3, 3),
+])
+def test_a_prompt_up_to_the_scratch_row_beside_a_short_live_lane(
+        engine_setup, n, tokens):
+    """A lane is idle in a decode call iff its length is the scratch
+    row's number: a prompt that ends on that row or the one before gets
+    its first token and no decode, so a live lane never carries it, and
+    a long prompt decoded beside a short live lane of its shard (whose
+    rows alone would take the half window) gets the tokens of the engine
+    that reads every row in every call."""
+    cfg, params = engine_setup
+
+    def run(make):
+        eng = make(cfg, params, **WINDOW_KW)
+        short = GenRequest("short", _prompt(5, 1), max_tokens=40)
+        long = GenRequest("long", _prompt(n), max_tokens=4)
+        assert eng.add_request(short) and eng.add_request(long)
+        assert short.slot != long.slot and len(eng.shards) == 1
+        decode, seen = eng._decode, []
+
+        def watched(params, cache, last, lens, temps, rng):
+            if lens[long.slot] != SCRATCH:      # the long lane is decoded
+                assert lens[short.slot] < 63    # beside the short one
+                seen.append(eng.decode_window(lens))
+            return decode(params, cache, last, lens, temps, rng)
+
+        eng._decode = watched
+        _run_dry(eng)
+        assert seen == [128] * (tokens - 1)
+        return short.generated, long.generated
+
+    got = run(LlamaEngine)
+    assert got == run(_every_row)
+    assert (len(got[0]), len(got[1])) == (40, tokens)
+
+
+def test_a_sequence_that_outgrows_its_windows_gets_the_full_reads_tokens(
+        engine_setup):
+    """Greedy ``generate()`` over a prompt whose decode crosses from
+    one window size to the other (64 -> 128 rows) beside the engine that
+    reads every row in every call: the same tokens, and both were run."""
+    cfg, params = engine_setup
+    eng = LlamaEngine(cfg, params, **WINDOW_KW)
+    prompt = _prompt(28)
+    got = eng.generate(prompt, max_tokens=50)
+    assert got == _every_row(cfg, params, **WINDOW_KW).generate(
+        prompt, max_tokens=50)
+    assert len(got) == 50
+    assert eng._decodes_run == {64, 128}
+    assert eng._prefills_run == {(16, 64)}
+
+
+def test_a_warm_engine_lowers_nothing_in_any_window(engine_setup):
+    """After ``warm_up()`` a mixed run of ``step()`` (prompts of one to
+    six chunks, decodes that pass every window) lowers no program, and
+    ``compiled_programs()`` names every variant that ran."""
+    cfg, params = engine_setup
+    eng = LlamaEngine(cfg, params, **{**WINDOW_KW, "prefill_chunk": 32})
+    assert (eng.buckets, eng.windows) == ([16, 32], [64, 128])
+    eng.warm_up()
+    # the whole chunk at every window, the bucket under it at the top
+    assert eng._prefills_run == {(32, 64), (32, 128), (16, 128)}
+    assert eng._decodes_run == set(eng.windows)
+    eng._prefills_run.clear(), eng._decodes_run.clear()
+    reqs = [GenRequest(f"r{i}", _prompt(n, i), max_tokens=m)
+            for i, (n, m) in enumerate(
+                [(3, 40), (100, 20), (40, 30), (70, 8), (17, 12), (90, 30)])]
+    pending = list(reqs)
+    with _programs_lowered() as lowered:
+        while pending or eng.num_active():
+            while pending and eng.has_capacity():
+                assert eng.add_request(pending.pop(0))
+            eng.step()
+    assert lowered == [] and all(r.done for r in reqs)
+    # the run reached every window of both programs
+    assert eng._decodes_run == set(eng.windows)
+    assert {w for _, w in eng._prefills_run} == set(eng.windows)
+    assert sorted(eng.compiled_programs()) == sorted(
+        ["first_token"] + [f"decode_{w}" for w in eng._decodes_run]
+        + [f"prefill_{b}_{w}" for b, w in eng._prefills_run])
+
+
+@pytest.mark.parametrize("make, read, full", [
+    # 70 tokens: four chunks end inside the first 64 rows, the fifth
+    # and the decode behind it do not
+    (LlamaEngine, 4 * 64 + 128 + 128, 6 * 128),
+    (_every_row, 6 * 128, 6 * 128),
+])
+def test_engine_stats_count_the_rows_the_calls_read(engine_setup, make, read,
+                                                    full):
+    """``attn_rows_read`` is the read window of every prefill and decode
+    call, ``attn_rows_full`` ``max_seq`` a call: their quotient is the
+    share of the cache's length attention read, 1 where every call ran
+    the top window."""
+    cfg, params = engine_setup
+    eng = make(cfg, params, **WINDOW_KW)
+    eng.warm_up()                                  # counts nothing
+    assert (eng.stats.attn_rows_read, eng.stats.attn_rows_full) == (0, 0)
+    eng.generate(_prompt(70), max_tokens=2)
+    s = eng.stats.snapshot()
+    assert (s["prefill_chunks"], s["decode_calls"]) == (5, 1)
+    assert (s["attn_rows_read"], s["attn_rows_full"]) == (read, full)
+
+
+@pytest.fixture(scope="module")
+def started_server():
+    """An in-process LLMServer, and the programs lowered since it
+    started."""
+    from ray_tpu.llm.serve import LLMServer
+
+    server = LLMServer(LLMConfig(
+        model_config=tiny_cfg(), max_batch_size=2, max_seq_len=64))
+    with _programs_lowered() as lowered:
+        yield server, lowered
     server.shutdown()
-    jax.monitoring.unregister_event_duration_listener(on_event)
 
 
 def test_a_started_server_lowers_no_program_for_any_prompt(started_server):
@@ -623,7 +854,8 @@ def test_a_started_server_lowers_no_program_for_any_prompt(started_server):
         # a sequence ends before the cache's scratch row
         assert len(out) == 3 or (n > eng.max_seq - 6 and out)
     assert lowered == []
-    assert eng._buckets_run == set(eng.buckets)
+    assert eng._prefills_run == {(b, 64) for b in eng.buckets}
+    assert eng._decodes_run == set(eng.windows) == {64}
 
 
 def test_engine_stats_count_rows_beside_tokens(started_server):
