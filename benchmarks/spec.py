@@ -102,7 +102,9 @@ def apply_environment() -> None:
 def load_cell(name: str, rehearse: bool) -> dict:
     """The cell's file with its configuration resolved under ``hp``
     (the sizes as run). A cell may override only keys its configuration
-    lists under ``reduced``; the rehearsal swaps in the toy presets."""
+    lists under ``reduced``; the rehearsal swaps in the toy presets.
+    What its kind's module holds of a cell's file (``check_cell(cell)``,
+    where it gives one) is held here, on the cell as loaded."""
     cell = load_json("workloads", f"{name}.json")
     hp = load_json("configs", f"{cell['config']}.json")
     overrides = cell.get("config_overrides", {})
@@ -116,6 +118,9 @@ def load_cell(name: str, rehearse: bool) -> dict:
         hp = {**hp, **hp["rehearsal"]}
         cell = _merge(cell, cell.get("rehearsal", {}))
     cell["hp"] = hp
+    held = getattr(kind_of(cell), "check_cell", None)
+    if held is not None:
+        held(cell)
     return cell
 
 

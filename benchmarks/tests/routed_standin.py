@@ -1,27 +1,43 @@
-"""A routed family's arithmetic without an engine: a short stack of
-pre-norm layers, each a causal latent attention (queries and keys /
-values through a normed low-rank bottleneck, so that its logits are as
-flat at these seeded weights as such a model's are) and then a top-k
-mixture of SwiGLU experts of which this chip holds a few (sigmoid or
-softmax scores, a correction bias in the selection, the chosen weights
-renormalised and scaled, a shared expert), and a head. ``forward`` is
-one function of seeded bf16 weights, evaluated in float32 at ``highest``
-(what a family's reference does), in bf16 with float32 accumulation
-(what its engine would do), and in bf16 with the shared expert's matmul
-operands in fp8 (the control). It returns the logits, each layer's
-choices, and the margin by which an expert held here was chosen or
-passed over, least over the held experts and the layers, in units of
-the router's logits: what a rule that spares near-tied positions would
-have to mark them by (README.md, "A served family"; PERF.md section 6,
-PR 34, has what the chip read and why no such rule is in the harness).
-``readings`` lays the sides beside each other position by position.
+"""A routed family's arithmetic, and the two programs an engine has of
+it: dense leading layers and then routed ones, each a pre-norm causal
+latent attention (queries and keys / values through a normed low-rank
+bottleneck, a rotary part beside it where ``rope_dim`` is set, so that
+its logits are as flat at these seeded weights as such a model's are)
+and then a dense SwiGLU or a top-k mixture of SwiGLU experts of which
+this chip holds a few (sigmoid or softmax scores, a correction bias in
+the selection, groups of experts of which the best few are kept by the
+sum of their two best scores, the chosen weights renormalised and
+scaled, a shared expert), an embedding and a head.
+
+``forward`` is one function of seeded bf16 weights over whole sequences,
+evaluated in float32 at ``highest`` (``reference_logits``: what a
+family's reference does), in bf16 with float32 accumulation, and in bf16
+with the shared expert's matmul operands in fp8 (the control). It
+returns the logits, each routed layer's choices, and the margin by which
+an expert held here was chosen or passed over, least over the held
+experts and the layers, in units of the router's logits (PERF.md section
+6, PR 34: what a rule that spares near-tied positions would have had to
+mark them by). ``readings`` lays the sides beside each other position by
+position; ``reach`` makes a flip on purpose and reads the rows behind.
+
+``Engine`` is what ``benchmarks/server.py`` ``reference_readings`` holds
+of an engine and nothing more of one (benchmarks/README.md, "A served
+family", has the list): ``_prefill`` of one chunk of one sequence into a
+slot of a latent bf16 cache with ``length`` traced, ``_decode`` of one
+greedy token a lane, attention in the absorbed form over the cached
+latent rows (another order of summation than ``forward``'s, as an
+engine's is). No scheduler and no shards: ``serve`` is a loop of decodes
+over the lanes, which gives served tokens for ``served_readings``.
 
 Imports nothing of ``ray_tpu``, of ``benchmarks`` nor of any family.
-``serving_control.py standin`` reads it on the chip at ``CHIP``'s widths;
-``test_serving_reference.py`` keeps it at ``TOY``'s.
+``serving_control.py standin`` reads it on the chip at ``CHIP``'s and
+``CHIP_GROUPED``'s widths; ``test_serving_reference.py`` keeps it at
+``TOY``'s and ``TOY_GROUPED``'s.
 """
 
 import math
+import threading
+import types
 from functools import partial
 
 import jax
@@ -33,14 +49,43 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 # a routed decoder's widths as one of 32 chips that share each layer
 # holds them: 12 of 384 experts, an eighth of the vocabulary's rows
 CHIP = dict(width=7168, experts=384, expert_width=2048, top_k=8, held=12,
-            layers=6, vocab=20480, heads=64, head_dim=128, q_rank=1536,
+            groups=1, groups_kept=1, layers=6, dense_layers=0, dense_width=0,
+            vocab=20480, heads=64, head_dim=128, rope_dim=0, q_rank=1536,
             kv_rank=512, seq=2048, seqs=8, score="sigmoid", scale=2.827,
             std=0.02)
-# a test's size: the weights wider, so that a sublayer still adds about
+# and with the second discrete choice, as one of 16 chips holds them: 16
+# of 256 experts (half of one of 8 groups, of which 4 are kept), a dense
+# leading layer, a rotary part of 64 beside each head's 128, an eighth
+# of the vocabulary: 5.5 G weights, 11 GB of bf16
+CHIP_GROUPED = dict(width=7168, experts=256, expert_width=2048, top_k=8,
+                    held=16, groups=8, groups_kept=4, layers=6,
+                    dense_layers=1, dense_width=18432, vocab=16160,
+                    heads=128, head_dim=128, rope_dim=64, q_rank=1536,
+                    kv_rank=512, seq=1024, seqs=2, score="sigmoid",
+                    scale=2.5, std=0.02)
+# a test's sizes: the weights wider, so that a sublayer still adds about
 # what the residual stream carries
-TOY = dict(width=128, experts=32, expert_width=64, top_k=4, held=4, layers=3,
-           vocab=128, heads=2, head_dim=16, q_rank=64, kv_rank=32, seq=64,
+TOY = dict(width=128, experts=32, expert_width=64, top_k=4, held=4, groups=1,
+           groups_kept=1, layers=3, dense_layers=0, dense_width=0, vocab=128,
+           heads=2, head_dim=16, rope_dim=0, q_rank=64, kv_rank=32, seq=64,
            seqs=8, score="sigmoid", scale=2.0, std=0.09)
+TOY_GROUPED = dict(TOY, groups=4, groups_kept=2, layers=4, dense_layers=1,
+                   dense_width=256, rope_dim=8, seq=128)
+SIZES = tuple(CHIP)     # the keys a set of sizes has
+
+
+def frozen(sizes: dict) -> tuple:
+    """``sizes`` (or a configuration's dict that holds them, its
+    ``vocab_size`` the vocabulary) as a jitted function's static
+    argument."""
+    sizes = dict(sizes, vocab=sizes.get("vocab", sizes.get("vocab_size")))
+    return tuple(sorted((k, sizes[k]) for k in SIZES))
+
+
+def key_of(seed: int):
+    """A key from any whole-number seed (the driver's pass 2**31)."""
+    return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
+                              seed >> 31)
 
 
 def fp8(a):
@@ -54,9 +99,22 @@ def fp8(a):
 
 def _mm(a, b, side):
     if side == "f32":
-        return jnp.matmul(a, b.astype(F32), precision="highest")
+        return jnp.matmul(a.astype(F32), b.astype(F32), precision="highest")
     return jnp.matmul(a.astype(BF16), b,
                       preferred_element_type=F32).astype(BF16)
+
+
+def _ein(spec, a, b, side):
+    if side == "f32":
+        return jnp.einsum(spec, a.astype(F32), b.astype(F32),
+                          precision="highest")
+    a, b = a.astype(BF16), b.astype(BF16)
+    if jax.default_backend() == "cpu":
+        # its dot has no bf16 x bf16 -> f32 for every shape; bf16
+        # products are exact in float32, so this is the same sum
+        return jnp.einsum(spec, a.astype(F32), b.astype(F32),
+                          precision="highest")
+    return jnp.einsum(spec, a, b, preferred_element_type=F32)
 
 
 def _norm(x):
@@ -72,40 +130,96 @@ def _swiglu(h, w, side, lower=lambda a: a):
     return _mm(lower(jax.nn.silu(gate) * up), lower(w[2].T), side)
 
 
+def _rope(x, pos):
+    """x (n, ..., r) turned by its row's position, in float32."""
+    r = x.shape[-1]
+    inv = 1.0 / (10000.0 ** (jnp.arange(0, r, 2, dtype=F32) / r))
+    ang = pos.astype(F32)[:, None] * inv
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
+    f = x.astype(F32)
+    a, b = f[..., :r // 2], f[..., r // 2:]
+    return jnp.concatenate([a * jnp.cos(ang) - b * jnp.sin(ang),
+                            b * jnp.cos(ang) + a * jnp.sin(ang)],
+                           -1).astype(x.dtype)
+
+
 @partial(jax.jit, static_argnames=("sizes",))
-def layer_weights(key, *, sizes):
-    """One layer's weights, bf16 N(0, std); the router's correction
-    bias float32 N(0, 0.01)."""
+def init_params(key, *, sizes):
+    """Every weight from the key in one program, bf16 N(0, std) (the
+    embedding N(0, 1); a dense layer's down projection narrower, so that
+    the layer adds what an expert does; the router's correction bias
+    float32 N(0, 0.01)), an expert at a time so that no float32 copy of
+    a stack is ever alive: ``embed``, ``head``, ``dense`` (a list of
+    layers) and ``routed`` (the routed layers stacked)."""
     s = dict(sizes)
-    d, f, hd = s["width"], s["expert_width"], s["heads"] * s["head_dim"]
-    shapes = {"wq_a": (d, s["q_rank"]), "wq_b": (s["q_rank"], hd),
-              "wkv_a": (d, s["kv_rank"]), "wkv_b": (s["kv_rank"], 2 * hd),
-              "wo": (hd, d),
-              "router": (d, s["experts"]), "shared": (3, d, f),
-              "held": (s["held"], 3, d, f)}
-    keys = jax.random.split(key, len(shapes) + 1)
-    w = {name: s["std"] * jax.random.normal(k, shape, BF16)
-         for k, (name, shape) in zip(keys, shapes.items())}
-    w["bias"] = 0.01 * jax.random.normal(keys[-1], (s["experts"],), F32)
-    return w
+    d, f, hd, r = s["width"], s["expert_width"], s["head_dim"], s["rope_dim"]
+    attn = {"wq_a": (d, s["q_rank"]),
+            "wq_b": (s["q_rank"], s["heads"] * (hd + r)),
+            "wkv_a": (d, s["kv_rank"] + r),
+            "wkv_b": (s["kv_rank"], s["heads"] * 2 * hd),
+            "wo": (s["heads"] * hd, d)}
+
+    def normal(k, shape, std=s["std"]):
+        return (std * jax.random.normal(k, shape, F32)).astype(BF16)
+
+    def layer(k, more):
+        shapes = {**attn, **more}
+        keys = jax.random.split(k, len(shapes) + 2)
+        w = {name: normal(kk, shape)
+             for kk, (name, shape) in zip(keys, shapes.items())}
+        return w, keys[-2], keys[-1]
+
+    def dense(k):
+        w, k_mlp, _ = layer(k, {})
+        fd = s["dense_width"]
+        ks = jax.random.split(k_mlp, 3)
+        w["dense"] = jnp.stack([
+            normal(ks[0], (d, fd)), normal(ks[1], (d, fd)),
+            normal(ks[2], (d, fd), s["std"] * math.sqrt(f / fd))])
+        return w
+
+    def routed(k):
+        w, k_held, k_bias = layer(k, {"router": (d, s["experts"]),
+                                      "shared": (3, d, f)})
+        w["held"] = jax.lax.map(lambda kk: normal(kk, (3, d, f)),
+                                jax.random.split(k_held, s["held"]))
+        w["bias"] = 0.01 * jax.random.normal(k_bias, (s["experts"],), F32)
+        return w
+
+    n_dense = s["dense_layers"]
+    keys = jax.random.split(key, s["layers"] + 2)
+    return {"embed": normal(keys[-2], (s["vocab"], d), 1.0),
+            "head": normal(keys[-1], (d, s["vocab"])),
+            "dense": [dense(k) for k in keys[:n_dense]],
+            "routed": jax.lax.map(routed, keys[n_dense:s["layers"]])}
 
 
 def _route(h, w, s, force):
     """The router in float32 on every side, as such models run it:
     (chosen (S, k), the held experts' weights (S, held), margin (S,)).
+    Where the experts come in groups, only those of the ``groups_kept``
+    groups whose two best selection scores sum highest can be chosen.
     ``force`` (S,) moves the best held expert that was passed over into
-    the choice: a flip made on purpose."""
+    the choice: a flip made on purpose. The margin is the experts' alone:
+    it does not see a group's near-tie."""
     k, held = s["top_k"], s["held"]
     z = jnp.matmul(h.astype(F32), w["router"].astype(F32), precision="highest")
     scores = jax.nn.sigmoid(z) if s["score"] == "sigmoid" \
         else jax.nn.softmax(z, -1)
     select = scores + w["bias"]
+    if s["groups"] > 1:
+        by_group = select.reshape(select.shape[0], s["groups"], -1)
+        best = jax.lax.top_k(by_group, 2)[0].sum(-1)            # (S, groups)
+        last_kept = jax.lax.top_k(best, s["groups_kept"])[0][:, -1:]
+        select = jnp.where((best >= last_kept)[:, :, None], by_group,
+                           -jnp.inf).reshape(select.shape)
     if force is not None:
         last_in = jax.lax.top_k(select, k)[0][:, -1:]
         passed = jnp.where(select[:, :held] >= last_in, -jnp.inf,
-                           select[:, :held])
-        select = select + force[:, None] * 10.0 * jax.nn.one_hot(
-            passed.argmax(-1), s["experts"])
+                           scores[:, :held])
+        select = jnp.where(
+            (force[:, None] > 0) & (jax.nn.one_hot(
+                passed.argmax(-1), s["experts"]) > 0), jnp.inf, select)
     ranked, order = jax.lax.top_k(select, k + 1)
     chosen = order[:, :k]
     picked = jnp.take_along_axis(scores, chosen, -1)
@@ -125,26 +239,61 @@ def _route(h, w, s, force):
     return chosen, of_held, margin
 
 
-def _layer(x, w, s, side, force):
-    """One sequence x (S, D) through attention and the mixture."""
-    n, heads, hd = x.shape[0], s["heads"], s["head_dim"]
-    h = _norm(x)
+def _qkv(h, w, s, side, pos):
+    """Of normed rows h (n, D) at positions ``pos``: the queries' plain
+    part (n, H, hd), their rotary part (n, H, r) or None, and the row a
+    cache keeps: the normed latent and, behind it, the rotary key."""
+    n, hd, r, c = h.shape[0], s["head_dim"], s["rope_dim"], s["kv_rank"]
     q = _mm(_norm(_mm(h, w["wq_a"], side)), w["wq_b"], side).reshape(
-        n, heads, hd)
-    k, v = jnp.split(_mm(_norm(_mm(h, w["wkv_a"], side)), w["wkv_b"], side
-                         ).reshape(n, heads, 2 * hd), 2, -1)
-    if side == "f32":
-        att = jnp.einsum("shk,thk->hst", q, k, precision="highest")
-    else:
-        att = jnp.einsum("shk,thk->hst", q, k, preferred_element_type=F32)
-    att = jnp.where(jnp.tril(jnp.ones((n, n), bool)), att / math.sqrt(hd),
-                    -jnp.inf)
-    probs = jax.nn.softmax(att, -1).astype(q.dtype)
-    mixed = jnp.einsum("hst,thk->shk", probs, v, precision=(
-        "highest" if side == "f32" else None),
-        preferred_element_type=F32).astype(q.dtype)
-    x = x + _mm(mixed.reshape(n, heads * hd), w["wo"], side)
+        n, s["heads"], hd + r)
+    kv = _mm(h, w["wkv_a"], side)
+    if not r:
+        return q, None, _norm(kv)
+    return q[..., :hd], _rope(q[..., hd:], pos), jnp.concatenate(
+        [_norm(kv[:, :c]), _rope(kv[:, c:], pos)], -1)
+
+
+def _attend_whole(qn, qr, latent, w, s, side):
+    """A whole sequence's causal attention, keys and values expanded
+    from the latent rows: (n, H * hd)."""
+    n, heads, hd, c = qn.shape[0], s["heads"], s["head_dim"], s["kv_rank"]
+    k, v = jnp.split(_mm(latent[:, :c], w["wkv_b"], side).reshape(
+        n, heads, 2 * hd), 2, -1)
+    att = _ein("shk,thk->hst", qn, k, side)
+    if qr is not None:
+        att = att + _ein("shr,tr->hst", qr, latent[:, c:], side)
+    att = jnp.where(jnp.tril(jnp.ones((n, n), bool)),
+                    att / math.sqrt(hd + s["rope_dim"]), -jnp.inf)
+    probs = jax.nn.softmax(att, -1).astype(qn.dtype)
+    return _ein("hst,thk->shk", probs, v, side).astype(qn.dtype).reshape(
+        n, heads * hd)
+
+
+def _attend_cached(qn, qr, rows, pos, w, s, side):
+    """Queries (n, H, hd) at positions ``pos`` (n,) over one slot's
+    cached rows (T, c + r), those at or before each query's position; in
+    the absorbed form: the keys' expansion folded into the queries, the
+    values' applied after the rows are mixed."""
+    heads, hd, c = s["heads"], s["head_dim"], s["kv_rank"]
+    wk, wv = jnp.split(w["wkv_b"].reshape(c, heads, 2 * hd), 2, -1)
+    q_lat = _ein("nhk,chk->nhc", qn, wk, side).astype(qn.dtype)
+    att = _ein("nhc,tc->hnt", q_lat, rows[:, :c], side)
+    if qr is not None:
+        att = att + _ein("nhr,tr->hnt", qr, rows[:, c:], side)
+    seen = jnp.arange(rows.shape[0])[None, :] <= pos[:, None]
+    att = jnp.where(seen[None], att / math.sqrt(hd + s["rope_dim"]), -jnp.inf)
+    probs = jax.nn.softmax(att, -1).astype(qn.dtype)
+    mixed = _ein("hnt,tc->nhc", probs, rows[:, :c], side).astype(qn.dtype)
+    return _ein("nhc,chk->nhk", mixed, wv, side).astype(qn.dtype).reshape(
+        qn.shape[0], heads * hd)
+
+
+def _mlp(x, w, s, side, force):
+    """The layer's second half on rows x (n, D): (x after it, chosen
+    (n, k) or None in a dense layer, margin (n,) or None)."""
     h = _norm(x)
+    if "dense" in w:
+        return x + _swiglu(h, w["dense"], side), None, None
     chosen, of_held, margin = _route(h, w, s, force)
     out = _swiglu(h, w["shared"], side, fp8 if side == "fp8" else lambda a: a)
 
@@ -157,47 +306,83 @@ def _layer(x, w, s, side, force):
     return x + out, chosen, margin
 
 
-@partial(jax.jit, static_argnames=("sizes", "side", "forced"))
-def _layer_of_all(x, w, force, *, sizes, side, forced):
+def _layers(x, params, s, side, attend, caches, force):
+    """x (n, D) through the dense layers and then the routed ones
+    (scanned over their stacked weights). ``attend(h, w, cache)`` is a
+    layer's attention over its normed input and its share of ``caches``
+    (the layers' leading axis; None without a cache), returning what the
+    attention adds and the layer's cache as it leaves it. ``force``
+    (n,) flips a choice in the first routed layer. Returns x, the caches
+    stacked again, chosen (routed layers, n, k) and the least margin."""
+    n_dense = len(params["dense"])
+    out_caches = []
+    for i, w in enumerate(params["dense"]):
+        add, cache = attend(_norm(x), w, None if caches is None else caches[i])
+        x, _, _ = _mlp(x + add, w, s, side, None)
+        out_caches.append(cache)
+
+    n_routed = s["layers"] - n_dense
+    forces = None if force is None else jnp.zeros(
+        (n_routed,) + force.shape, F32).at[0].set(force)
+
+    def body(x, layer):
+        w, cache, f = layer
+        add, cache = attend(_norm(x), w, cache)
+        x, chosen, margin = _mlp(x + add, w, s, side, f)
+        return x, (cache, chosen, margin)
+
+    x, (routed_caches, chosen, margin) = jax.lax.scan(
+        body, x, (params["routed"],
+                  None if caches is None else caches[n_dense:], forces))
+    if caches is not None:
+        caches = jnp.concatenate(
+            [jnp.stack(out_caches), routed_caches]) if out_caches \
+            else routed_caches
+    return x, caches, chosen, margin.min(0)
+
+
+def _whole(side, s):
+    def attend(h, w, cache):
+        qn, qr, latent = _qkv(h, w, s, side, jnp.arange(h.shape[0]))
+        return _mm(_attend_whole(qn, qr, latent, w, s, side), w["wo"],
+                   side), cache
+    return attend
+
+
+@partial(jax.jit, static_argnames=("sizes", "side", "last"))
+def _forward(params, tokens, force, *, sizes, side, last):
     s = dict(sizes)
-    return jax.lax.map(
-        lambda row: _layer(row[0], w, s, side, row[1] if forced else None),
-        (x, force))
+
+    def one(row):
+        toks, f = row
+        x = params["embed"][toks].astype(F32 if side == "f32" else BF16)
+        x, _, chosen, margin = _layers(x, params, s, side, _whole(side, s),
+                                       None, f)
+        x = x[-last:] if last else x
+        return _mm(_norm(x), params["head"], side).astype(F32), chosen, margin
+
+    return jax.lax.map(one, (tokens, force))
 
 
-@partial(jax.jit, static_argnames=("side",))
-def _head(x, head, *, side):
-    out = _mm(_norm(x), head, side)
-    return out.astype(F32)
-
-
-def forward(seed: int, sizes: dict, side: str, force=None) -> dict:
-    """The stack on ``sizes['seqs']`` seeded sequences: logits (B, S, V)
-    float32, ``chosen`` (L, B, S, k), and ``margin`` (B, S), the least
-    over the layers. ``force`` (B, S)
-    flips a choice in the first layer at the positions it marks. The
-    weights are made from the seed layer by layer, the same on every
-    side, one layer's alive at a time."""
-    s = sizes
-    key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
-    x = jax.random.normal(jax.random.fold_in(key, 10**6),
-                          (s["seqs"], s["seq"], s["width"]), BF16)
-    x = x.astype(F32 if side == "f32" else BF16)
-    none = jnp.zeros((s["seqs"], s["seq"]), F32)
-    chosen, margin = [], jnp.full((s["seqs"], s["seq"]), jnp.inf, F32)
-    frozen = tuple(sorted(s.items()))
-    for i in range(s["layers"]):
-        w = layer_weights(jax.random.fold_in(key, i), sizes=frozen)
-        forced = force is not None and i == 0
-        x, picked, m = _layer_of_all(
-            x, w, jnp.asarray(force, F32) if forced else none, sizes=frozen,
-            side=side, forced=forced)
-        chosen.append(picked)
-        margin = jnp.minimum(margin, m)
-    head = s["std"] * jax.random.normal(jax.random.fold_in(key, 10**6 + 1),
-                                    (s["width"], s["vocab"]), BF16)
-    return {"logits": _head(x, head, side=side), "chosen": jnp.stack(chosen),
+def forward(params, tokens, sizes: dict, side: str, force=None,
+            last: int = 0) -> dict:
+    """The stack over whole sequences ``tokens`` (B, S): logits (B, S or
+    ``last``, V) float32, ``chosen`` (routed layers, B, S, k), and
+    ``margin`` (B, S), the least over the layers. ``force`` (B, S) flips
+    a choice in the first routed layer at the positions it marks."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    logits, chosen, margin = _forward(
+        params, tokens, None if force is None else jnp.asarray(force, F32),
+        sizes=frozen(sizes), side=side, last=last)
+    return {"logits": logits, "chosen": jnp.moveaxis(chosen, 0, 1),
             "margin": margin}
+
+
+def reference_logits(params, tokens, hp: dict, last: int = 0):
+    """What a family module gives: the float32 side at ``highest`` over
+    one sequence, (S or ``last``, V)."""
+    return forward(params, np.asarray(tokens)[None], hp, "f32",
+                   last=last)["logits"][0]
 
 
 @jax.jit
@@ -224,17 +409,26 @@ def flipped(a, b, held: int):
     return np.asarray((has(a) != has(b)).any((0, -1)))
 
 
+def seeded(seed: int, sizes: dict):
+    """The weights and ``seqs`` sequences of ``seq`` tokens of a seed."""
+    key = key_of(seed)
+    return init_params(key, sizes=frozen(sizes)), jax.random.randint(
+        jax.random.fold_in(key, 10**6), (sizes["seqs"], sizes["seq"]), 0,
+        sizes["vocab"])
+
+
 def readings(seed: int, sizes: dict) -> dict:
     """Position by position (B, S): the bf16 side's relative RMS and
     choice gap against the float32 side, whether a choice that involves
     a held expert flipped between them, the float32 side's margin, and
     the fp8 control's relative RMS and choice gap; ``logit_rms``, the
     float32 logits' own size, for a gap to be read against."""
-    ref = forward(seed, sizes, "f32")
+    params, tokens = seeded(seed, sizes)
+    ref = forward(params, tokens, sizes, "f32")
     out = {"margin": np.asarray(ref["margin"]),
            "logit_rms": float(jnp.sqrt(jnp.mean(ref["logits"] ** 2)))}
     for side in ("bf16", "fp8"):
-        got = forward(seed, sizes, side)
+        got = forward(params, tokens, sizes, side)
         out[f"{side}_rel_rms"] = np.asarray(rel_rms(got["logits"],
                                                     ref["logits"]))
         out[f"{side}_choice_gap"] = np.asarray(choice_gap(got["logits"],
@@ -247,16 +441,165 @@ def readings(seed: int, sizes: dict) -> dict:
 
 def reach(seed: int, sizes: dict, at: int) -> dict:
     """A flip made on purpose at position ``at`` of every sequence, in
-    the first layer of the float32 side: the relative RMS it moves that
-    row by, and the rows before and behind it (through attention); of
-    the rows behind, the sound side's margin and whether a choice of
-    their own flipped with their moved input."""
+    the first routed layer of the float32 side: the relative RMS it
+    moves that row by, and the rows before and behind it (through
+    attention); of the rows behind, the sound side's margin and whether
+    a choice of their own flipped with their moved input."""
+    params, tokens = seeded(seed, sizes)
     force = np.zeros((sizes["seqs"], sizes["seq"]), np.float32)
     force[:, at] = 1.0
-    sound = forward(seed, sizes, "f32")
-    moved = forward(seed, sizes, "f32", force=force)
+    sound = forward(params, tokens, sizes, "f32")
+    moved = forward(params, tokens, sizes, "f32", force=force)
     by = np.asarray(rel_rms(moved["logits"], sound["logits"]))
     return {"at": by[:, at], "before": by[:, :at], "behind": by[:, at + 1:],
             "behind_margin": np.asarray(sound["margin"])[:, at + 1:],
             "behind_flipped": flipped(sound["chosen"], moved["chosen"],
                                       sizes["held"])[:, at + 1:]}
+
+
+# ------------------------------------------------ the engine's two programs
+def _cached(side, s, slot_rows, write, pos):
+    """A layer's attention through the cache: the new rows' latent
+    written into ``cache`` (``write``), then the queries at ``pos`` over
+    the rows of ``slot_rows(cache)``."""
+    def attend(h, w, cache):
+        qn, qr, latent = _qkv(h, w, s, side, pos.reshape(-1))
+        cache = write(cache, latent.astype(BF16))
+        return _mm(slot_rows(cache, qn, qr, w), w["wo"], side), cache
+    return attend
+
+
+@partial(jax.jit, static_argnames=("sizes", "side", "bucket"),
+         donate_argnums=(1,))
+def _prefill(params, cache, tokens, slot_onehot, start, length, *, sizes,
+             side, bucket):
+    """One chunk ``tokens`` (1, bucket) of one sequence into the slot
+    ``slot_onehot`` marks, at rows ``start`` (1,) on: all ``bucket`` rows
+    are written, and the logits (V,) of row ``length`` - 1 of the chunk
+    returned."""
+    s = dict(sizes)
+    slot = slot_onehot.argmax()
+    pos = start[0] + jnp.arange(bucket)
+
+    def write(cache, latent):       # (B, T, c + r): the slot's rows
+        rows = jax.lax.dynamic_update_slice(
+            cache[slot], latent, (start[0], 0))
+        return jax.lax.dynamic_update_index_in_dim(cache, rows, slot, 0)
+
+    def over(cache, qn, qr, w):
+        return _attend_cached(qn, qr, cache[slot], pos, w, s, side)
+
+    x = params["embed"][tokens[0]]
+    x, cache, _, _ = _layers(x, params, s, side,
+                             _cached(side, s, over, write, pos),
+                             cache["latent"], None)
+    row = jax.lax.dynamic_index_in_dim(x, length - 1, 0, keepdims=True)
+    logits = _mm(_norm(row), params["head"], side).astype(F32)[0]
+    return logits, {"latent": cache}
+
+
+@partial(jax.jit, static_argnames=("sizes", "side"), donate_argnums=(1,))
+def _decode(params, cache, last_tokens, lengths, temps, rng, *, sizes, side):
+    """One greedy token a lane: lane b's ``last_tokens[b]`` written at
+    row ``lengths[b]`` of its slot and attended from there (an idle lane
+    writes the scratch row ``max_seq`` - 1)."""
+    s = dict(sizes)
+    lanes = jnp.arange(lengths.shape[0])
+
+    def write(cache, latent):       # one row a lane
+        return cache.at[lanes, lengths].set(latent)
+
+    def over(cache, qn, qr, w):
+        return jax.vmap(lambda q, r, rows, at: _attend_cached(
+            q[None], None if r is None else r[None], rows, at[None], w, s,
+            side)[0])(qn, qr, cache, lengths)
+
+    x = params["embed"][last_tokens]
+    x, cache, _, _ = _layers(x, params, s, side,
+                             _cached(side, s, over, write, lengths),
+                             cache["latent"], None)
+    logits = _mm(_norm(x), params["head"], side).astype(F32)
+    return logits.argmax(-1).astype(jnp.int32), {"latent": cache}, rng
+
+
+class Engine:
+    """What ``server.reference_readings`` and ``serving_control.broken``
+    hold of an engine: the two programs on ``side`` (``bf16``, or
+    ``fp8``: the control), one cache shard of ``max_batch`` slots of
+    ``max_seq`` latent rows a layer, and an engine that is always
+    idle."""
+
+    def __init__(self, sizes: dict, params, side: str = "bf16",
+                 max_batch: int = 8, max_seq: int = 2048,
+                 buckets=(16, 256)):
+        self.sizes, self.side, self.params = frozen(sizes), side, params
+        self.buckets, self.prefill_chunk = list(buckets), buckets[-1]
+        self.max_batch, self.max_seq = max_batch, max_seq
+        s = dict(self.sizes)
+        self.shards = [types.SimpleNamespace(cache={"latent": jnp.zeros(
+            (s["layers"], max_batch, max_seq, s["kv_rank"] + s["rope_dim"]),
+            BF16)})]
+        self._lock, self._rng = threading.Lock(), jax.random.PRNGKey(0)
+
+    def num_active(self) -> int:
+        return 0
+
+    def _prefill(self, params, cache, tokens, slot_onehot, start, length,
+                 bucket):
+        return _prefill(params, cache, jnp.asarray(tokens),
+                        jnp.asarray(slot_onehot), jnp.asarray(start), length,
+                        sizes=self.sizes, side=self.side, bucket=bucket)
+
+    def _decode(self, params, cache, last_tokens, lengths, temps, rng):
+        return _decode(params, cache, jnp.asarray(last_tokens),
+                       jnp.asarray(lengths), temps, rng, sizes=self.sizes,
+                       side=self.side)
+
+    def serve(self, prompts: list, max_tokens: list) -> list:
+        """Greedy answers of ``max_tokens[i]`` tokens to ``prompts[i]``,
+        as many requests at once as there are lanes, a finished lane
+        given the next: a prompt goes in chunk by chunk and its last
+        row's logits choose the first token; then one ``_decode`` a step
+        over every lane, each fed the token the call before returned for
+        it. Returns the tokens and the lane of each request."""
+        shard, idle = self.shards[0], self.max_seq - 1
+        out, lane_of = [[] for _ in prompts], [None] * len(prompts)
+        at = [None] * self.max_batch            # the request in each lane
+        lens = np.full(self.max_batch, idle, np.int32)
+        last = np.zeros(self.max_batch, np.int32)
+        temps, todo = np.zeros(self.max_batch, np.float32), 0
+        while todo < len(prompts) or any(r is not None for r in at):
+            for lane in range(self.max_batch):
+                if at[lane] is None and todo < len(prompts):
+                    at[lane], lane_of[todo] = todo, lane
+                    prompt = np.asarray(prompts[todo], np.int32)
+                    onehot = np.zeros(self.max_batch, np.float32)
+                    onehot[lane] = 1.0
+                    for pos in range(0, len(prompt), self.prefill_chunk):
+                        part = prompt[pos:pos + self.prefill_chunk]
+                        bucket = next(b for b in self.buckets
+                                      if b >= len(part))
+                        padded = np.zeros((1, bucket), np.int32)
+                        padded[0, :len(part)] = part
+                        logits, shard.cache = self._prefill(
+                            self.params, shard.cache, padded, onehot,
+                            np.asarray([pos], np.int32), len(part),
+                            bucket=bucket)
+                    last[lane], lens[lane] = int(logits.argmax()), len(prompt)
+                    out[todo].append(int(last[lane]))
+                    todo += 1
+            for lane, request in enumerate(at):     # ended by its count
+                if request is not None and len(
+                        out[request]) >= max_tokens[request]:
+                    at[lane], lens[lane] = None, idle
+            if all(r is None for r in at):
+                continue
+            tokens, shard.cache, self._rng = self._decode(
+                self.params, shard.cache, last, lens, temps, self._rng)
+            tokens = np.asarray(tokens)
+            for lane, request in enumerate(at):
+                if request is not None:
+                    last[lane] = tokens[lane]
+                    lens[lane] += 1
+                    out[request].append(int(tokens[lane]))
+        return out, lane_of

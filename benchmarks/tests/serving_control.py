@@ -25,16 +25,17 @@ control's three.
 
     python benchmarks/tests/serving_control.py standin <seeds> <reach seeds>
 
-reads ``routed_standin.py`` instead, a routed family's arithmetic with no
-engine, at the widths such a family would bring to one chip
-(``--rehearse``: at a test's): which positions flip a choice that
-involves a held expert between bf16 and the float32 side, how they and
-the others read, the float32 side's margin at the flipped ones, what a
-margin of 1, 1.3 and 2 x the largest of them marks and leaves, the
-served tokens' choice gap at each group, the fp8 control at the unmarked
-positions, and how far a flip reaches the rows behind it through
-attention (PERF.md section 6, PR 34: what a rule that spares a routed
-family's near-ties would have to fit, and does not yet).
+reads ``routed_standin.py`` instead, a routed family's arithmetic with
+the two programs an engine has of it, at the widths such a family would
+bring to one chip (``--grouped``: with groups of experts, a dense
+leading layer and a rotary part; ``--rehearse``: at a test's sizes):
+on every seed ``server.reference_readings`` through its ``_prefill`` and
+``_decode`` at the probe a routed cell would bring, ``served_readings``
+over a request a lane, and ``serve_load.matches_reference`` itself under
+the two shares such a cell would state, for the sound side, the control
+(the shared expert's operands in fp8) and the fault (one lane's token
+moved to the next of the vocabulary); then how far a flip made on
+purpose reaches the rows behind it (PERF.md section 6, PR 36).
 """
 
 import json
@@ -187,130 +188,211 @@ def seeds_of(text: str) -> list:
     return list(range(first, first + n))
 
 
-# the three serving cells' rel_rms_tol and choice_gap_tol: what a sound
-# position of the stand-in should not read over, and its control has to
-STANDIN_LIMIT, STANDIN_GAP_LIMIT = 0.03, 0.2
-# the margins are summed up from this row on: a serving cell's first
-# compared row lies behind a prompt of 32 tokens or more, and a
-# sequence's first rows attend to so few that a flip there moves them
-# whole
-STANDIN_FROM = 32
+# what a routed cell would bring: the probe, the limits of the three
+# serving cells, and the two shares its sound engine reads over them:
+# the stand-in's own at each set of sizes, the means of 64 seeds on the
+# chip rounded up (PERF.md section 6, PR 36; a test's sizes take the
+# fixture cell's)
+STANDIN_CHECK = {"length": 448, "positions": 96, "decode_steps": 32,
+                 "rel_rms_tol": 0.03, "choice_gap_tol": 0.2}
+STANDIN_SHARES = {"CHIP": (0.028, 0.009), "CHIP_GROUPED": (0.06, 0.014),
+                  "TOY": (0.03, 0.01), "TOY_GROUPED": (0.03, 0.01)}
+# its served requests, one a lane: prompts and answers of so many
+# tokens, the shortest and the longest answer in every seed
+STANDIN_PROMPTS, STANDIN_ANSWERS = (32, 480), (48, 256)
 
 
-def standin(seeds: list, reach_seeds: list, rehearse: bool) -> None:
-    """``routed_standin.readings`` over ``seeds`` and ``reach`` over
-    ``reach_seeds``, summed up; every position's readings go to
-    ``chiprun_out/serving_control.standin.npz``."""
+def standin_requests(seed: int, lanes: int, vocab: int, prompts, answers):
+    """One request a lane: (prompt ids, answer length) drawn from the
+    seed, the answers' lengths spread evenly from the shortest to the
+    longest and dealt to the lanes in the seed's order."""
+    import numpy as np
+
+    rng = np.random.default_rng([seed, 7])
+    lens = np.linspace(answers[0], answers[1], lanes).round().astype(int)
+    rng.shuffle(lens)
+    return [(traffic.prompt_tokens(seed, traffic.Request(
+        i, 0.0, int(rng.integers(prompts[0], prompts[1] + 1)), int(n)),
+        vocab), int(n)) for i, n in enumerate(lens)]
+
+
+def standin_sides(sizes: dict, lanes: int, max_seq: int, buckets):
+    """The stand-in's engine three times over one set of weights: sound,
+    the control (the shared expert's operands in fp8) and the fault (the
+    decode's token of the lane before the last moved to the next of the
+    vocabulary)."""
+    import routed_standin
+
+    def engine(side):
+        return routed_standin.Engine(sizes, None, side, max_batch=lanes,
+                                     max_seq=max_seq, buckets=buckets)
+
+    sides = {"sound": engine("bf16"), "control": engine("fp8"),
+             "fault": engine("bf16")}
+    sides["fault"]._decode = broken(
+        sides["fault"]._decode, next_token(sizes["vocab"], lanes - 2))
+    return sides
+
+
+def standin_read(eng, family, seed: int, hp: dict, check: dict,
+                 requests) -> dict:
+    """The comparison as a run makes it, of one engine on one seed:
+    ``reference_readings`` (the probe), ``served_readings`` over what
+    ``eng.serve`` answered to ``requests`` ((prompt ids, answer length)
+    each), the decision and what it counted."""
+    got = server.reference_readings(eng, family, seed, hp, check)
+    answers, lanes = eng.serve([p for p, _ in requests],
+                               [n for _, n in requests])
+    got.update(server.served_readings(
+        eng.params, family, hp,
+        [(p, a) for (p, _), a in zip(requests, answers)]))
+    got["lanes"] = lanes
+    got["counted"] = serve_load.counted(got, check)
+    got["correct"] = serve_load.matches_reference(got, check)
+    return got
+
+
+def standin(seeds: list, reach_seeds: list, rehearse: bool,
+            grouped: bool) -> None:
+    """The stand-in's engine through the comparison a routed cell would
+    bring, on every seed the sound side, the control and the fault; then
+    ``routed_standin.reach`` over ``reach_seeds``. Every reading goes to
+    ``chiprun_out/serving_control.standin.<sizes>.<first seed>.json``,
+    written anew after every seed."""
     import numpy as np
     import routed_standin
 
-    sizes = routed_standin.TOY if rehearse else routed_standin.CHIP
-    rows = {}
+    name = ("TOY" if rehearse else "CHIP") + ("_GROUPED" if grouped else "")
+    sizes = getattr(routed_standin, name)
+    check = dict(STANDIN_CHECK, **dict(zip(
+        serve_load.SHARE_CEILINGS, STANDIN_SHARES[name])))
+    lanes, max_seq, buckets = 8, 2048, (16, 256)
+    prompts, answers = STANDIN_PROMPTS, STANDIN_ANSWERS
+    if rehearse:
+        check.update(length=96, positions=32, decode_steps=8)
+        lanes, max_seq, buckets = 4, 192, (8, 32)
+        prompts, answers = (32, 80), (8, 40)
+    hp = {**sizes, "vocab_size": sizes["vocab"]}
+    sides = standin_sides(sizes, lanes, max_seq, buckets)
+    out = {"sizes": sizes, "name": name, "check": check, "risk":
+           serve_load.RISK, "seeds": seeds, "by_seed": {}, "reach": {}}
+    os.makedirs("chiprun_out", exist_ok=True)
+    path = f"chiprun_out/serving_control.standin.{name}.{seeds[0]}.json"
+
+    def keep():     # after every seed: a call may be cut
+        with open(path, "w") as fh:
+            json.dump(out, fh)
+
     for seed in seeds:
         t0 = time.time()
-        got = routed_standin.readings(seed, sizes)
-        for k, v in got.items():
-            rows.setdefault(k, []).append(v)
-        f = got["flipped"]
-        print("standin", seed, "flipped", int(f.sum()), "of", f.size,
-              "their bf16", spread(got["bf16_rel_rms"][f]), "margin at most",
-              float(got["margin"][f].max()) if f.any() else None, "others",
-              spread(got["bf16_rel_rms"][~f]), "fp8",
-              spread(got["fp8_rel_rms"]), f"{time.time() - t0:.1f}s",
-              flush=True)
-    got = {k: np.stack(v) for k, v in rows.items()}      # (seeds, B, S)
-    out = {"sizes": sizes, "seeds": seeds, **standin_summary(got),
-           "reach": {}}
+        for eng in sides.values():
+            eng.params = None
+        params = routed_standin.init_params(
+            routed_standin.key_of(seed), sizes=routed_standin.frozen(sizes))
+        requests = standin_requests(seed, lanes, sizes["vocab"], prompts,
+                                    answers)
+        row = out["by_seed"][seed] = {}
+        for side, eng in sides.items():
+            eng.params = params
+            got = row[side] = standin_read(eng, routed_standin, seed, hp,
+                                           check, requests)
+            rel = got["prefill_rel_rms"] + got["after_decode_rel_rms"]
+            over = sorted(x for x in rel if x > check["rel_rms_tol"])
+            tol = check["choice_gap_tol"]
+            print("standin", name, seed, side, "correct", got["correct"],
+                  got["counted"], "rel_rms over",
+                  [round(x, 4) for x in over[:3] + over[-2:]],
+                  "others at most", round(max(
+                      [x for x in rel if x <= check["rel_rms_tol"]] + [0]), 5),
+                  "probe gaps over", sum(
+                      g > tol for g in got["decode_choice_gap"]),
+                  "by request (lane, tokens, over)", [
+                      [lane, n, sum(g > tol for g in gaps)]
+                      for lane, (_, n, _), gaps in zip(
+                          got["lanes"], got["served_by_request"],
+                          serve_load.by_request(got)[1:])],
+                  f"{time.time() - t0:.1f}s", flush=True)
+        del params
+        keep()
+    for eng in sides.values():
+        eng.params = None
     for seed in reach_seeds:
-        r = routed_standin.reach(seed, {**sizes, "seqs": 2},
-                                 sizes["seq"] // 4)
+        small = {**sizes, "seqs": 2, "seq": min(sizes["seq"], 1024)}
+        r = routed_standin.reach(seed, small, small["seq"] // 4)
+        held = ~r["behind_flipped"]
         out["reach"][seed] = {
             "at": [float(x) for x in r["at"]],
             "before_largest": float(r["before"].max()),
             "behind": spread(r["behind"]),
-            "behind_choices_held": spread(r["behind"][~r["behind_flipped"]]),
+            "behind_choices_held": spread(r["behind"][held]),
             # each row behind that reads over the limit: rows behind,
-            # reading, the sound side's margin, a choice of its own flipped
+            # reading, a choice of its own flipped
             "behind_over_limit": [
                 [int(j) + 1, float(r["behind"][i, j]),
-                 float(r["behind_margin"][i, j]),
                  bool(r["behind_flipped"][i, j])]
-                for i, j in zip(*np.nonzero(r["behind"] > STANDIN_LIMIT))],
-            "behind_flipped_margin": spread(
-                r["behind_margin"][r["behind_flipped"]])}
-        print("standin reach", seed, out["reach"][seed], flush=True)
-    print("standin over", len(seeds), "seeds:", json.dumps(out), flush=True)
-    os.makedirs("chiprun_out", exist_ok=True)
-    np.savez_compressed(
-        "chiprun_out/serving_control.standin.npz",
-        **{k: v.astype(np.float16) if k.endswith(("rms", "gap")) else v
-           for k, v in got.items()})
-    with open("chiprun_out/serving_control.standin.json", "w") as fh:
-        json.dump(out, fh)
+                for i, j in zip(*np.nonzero(
+                    r["behind"] > check["rel_rms_tol"]))]}
+        print("standin reach", name, seed, out["reach"][seed], flush=True)
+        keep()
+    out["summary"] = standin_summary(out["by_seed"], check, lanes - 2)
+    print("standin", name, "over", len(seeds), "seeds:",
+          json.dumps(out["summary"]), flush=True)
+    keep()
 
 
-def standin_summary(got: dict) -> dict:
-    """Of ``routed_standin.readings`` stacked over seeds (seeds, B, S):
-    what PERF.md section 6 (PR 34) quotes."""
-    import numpy as np
+def standin_summary(by_seed: dict, check: dict, faulty: int) -> dict:
+    """Of each side over the seeds: how many read correct; the rows and
+    the tokens over their limit (pooled over the seeds: the share a cell
+    would state; a seed's least and most beside their allowance); the
+    readings over the limit and the others; of the requests, the most
+    tokens over beside that request's allowance, and for the fault the
+    requests through lane ``faulty`` apart from the others ([tokens
+    over, tokens])."""
+    tol, gap_tol = check["rel_rms_tol"], check["choice_gap_tol"]
+    out = {}
+    for side in next(iter(by_seed.values())):
+        rows = [r[side] for r in by_seed.values()]
+        flat_rel = [x for r in rows for x in
+                    r["prefill_rel_rms"] + r["after_decode_rel_rms"]]
+        flat_gap = [g for r in rows for g in
+                    r["decode_choice_gap"] + r["served_choice_gap"]]
+        requests = [[lane, sum(g > gap_tol for g in gs), len(gs)]
+                    for r in rows for lane, gs in zip(
+                        [0] + r["lanes"], serve_load.by_request(r))]
+        worst = {"over_share_most": max(o / n for _, o, n in requests)}
+        if side == "fault":
+            worst = {"through_the_lane_least": min(
+                         [o, n] for l, o, n in requests if l == faulty),
+                     "through_others_most": max(
+                         [o, n] for l, o, n in requests if l != faulty)}
 
-    f, m = got["flipped"], got["margin"]
-    b, c = got["bf16_rel_rms"], got["fp8_rel_rms"]
-    g, cg = got["bf16_choice_gap"], got["fp8_choice_gap"]
-    behind = np.logical_or.accumulate(f, -1) & ~f        # a flip before it
-    over = ~f & (b > STANDIN_LIMIT)
-    late = np.s_[..., min(STANDIN_FROM, f.shape[-1] // 4):]
-    largest = float(m[late][f[late]].max())
-    edges = [0, 0.0025, 0.005, 0.01, 0.015, 0.02, 0.025, 0.03, 0.035, 0.04,
-             0.045, 0.05, 0.06, 0.07, 0.08, 0.1, 0.15]
-    out = {"positions": int(f.size),
-           "limits": [STANDIN_LIMIT, STANDIN_GAP_LIMIT],
-           "logit_rms": spread(got["logit_rms"]),
-           "flipped_share": float(f.mean()),
-           "flipped_share_a_sequence_most": float(f.mean(-1).max()),
-           "flipped_rel_rms": spread(b[f]), "other_rel_rms": spread(b[~f]),
-           "other_over_limit": int(over.sum()),
-           # where in their sequences they lie, and what is left later
-           "other_over_limit_rows": sorted(set(np.nonzero(over)[-1].tolist())),
-           "other_late_largest": float(b[late][~f[late]].max()),
-           # the rows before any flipped one of their sequence
-           "other_before_any_flip": spread(b[~behind & ~f]),
-           "flipped_choice_gap": spread(g[f]),
-           "flipped_choice_differs": float((g[f] > 0).mean()),
-           "other_choice_gap": spread(g[~f]),
-           "choice_gap_over_limit": [int((g[f] > STANDIN_GAP_LIMIT).sum()),
-                                     int((g[~f] > STANDIN_GAP_LIMIT).sum())],
-           "fp8_rel_rms": spread(c), "fp8_choice_gap": spread(cg),
-           "fp8_choice_gap_over_limit_share": float(
-               (cg > STANDIN_GAP_LIMIT).mean()),
-           "fp8_choice_gap_a_sequence_least": float(cg.max(-1).min()),
-           "flipped_margin": spread(m[f]), "largest_flipped_all_rows": float(
-               m[f].max()),
-           "late_from_row": late[-1].start, "largest_flipped_late": largest,
-           # how the largest grows with the positions read
-           "largest_flipped_late_by_seeds": {
-               n: float(m[:n][late][f[:n][late]].max())
-               for n in (1, 2, 4, 8, 16, 32, 64, 128) if n <= len(f)},
-           # [from, to, positions, flipped among them]
-           "flips_by_margin": [
-               [lo, hi, int(((m >= lo) & (m < hi)).sum()),
-                int((f & (m >= lo) & (m < hi)).sum())]
-               for lo, hi in zip(edges, edges[1:])],
-           "late_by_factor": {}}
-    f, m, b, c, g, cg = (x[late] for x in (f, m, b, c, g, cg))
-    for factor in (1, 1.3, 2):
-        marked = m < factor * largest * (1 + 1e-6)
-        out["late_by_factor"][factor] = {
-            "tie_margin": factor * largest,
-            "marked_share": float(marked.mean()),
-            "marked_share_a_sequence_most": float(marked.mean(-1).max()),
-            "unmarked_over_limit": int((b[~marked] > STANDIN_LIMIT).sum()),
-            "unmarked_largest": float(b[~marked].max()),
-            "marked_unflipped_choice_gap": spread(g[marked & ~f]),
-            "unmarked_choice_gap": spread(g[~marked]),
-            "fp8_unmarked_least": float(c[~marked].min()),
-            "fp8_unmarked_at_or_under_limit": int(
-                (c[~marked] <= STANDIN_LIMIT).sum())}
+        def counts(pool, i=0):      # each seed's count (0) or allowance (1)
+            return [r["counted"][pool][i] for r in rows]
+
+        seconds = [r["total_s"] + r["served_s"] for r in rows]
+        out[side] = {
+            "correct": sum(r["correct"] for r in rows), "seeds": len(rows),
+            "rows": len(flat_rel),
+            "rows_over_share": sum(x > tol for x in flat_rel) / len(flat_rel),
+            "rows_over_a_seed": [min(counts("rel_rms_over")),
+                                 max(counts("rel_rms_over")),
+                                 counts("rel_rms_over", 1)[0]],
+            "rel_rms_over": spread([x for x in flat_rel if x > tol]),
+            "rel_rms_others": spread([x for x in flat_rel if x <= tol]),
+            "tokens": len(flat_gap),
+            "tokens_over_share": sum(g > gap_tol for g in flat_gap)
+            / len(flat_gap),
+            "tokens_over_a_seed": [
+                min(counts("choice_gap_over")), max(counts("choice_gap_over")),
+                [min(counts("choice_gap_over", 1)),
+                 max(counts("choice_gap_over", 1))]],
+            "gap_over": spread([g for g in flat_gap if g > gap_tol]),
+            "gap_others": spread([g for g in flat_gap if g <= gap_tol]),
+            "request_furthest_over": max(
+                (r["counted"]["request_choice_gap_over"] for r in rows),
+                key=lambda pair: pair[0] - pair[1]),
+            "requests": worst,
+            "seconds": [min(seconds), max(seconds)]}
     return out
 
 
@@ -326,10 +408,11 @@ def spread(xs) -> list:
 
 
 def main(argv) -> int:
-    rehearse = "--rehearse" in argv
-    cell_name, seeds, control_seeds = [a for a in argv if a != "--rehearse"]
+    rehearse, grouped = "--rehearse" in argv, "--grouped" in argv
+    cell_name, seeds, control_seeds = [
+        a for a in argv if a not in ("--rehearse", "--grouped")]
     if cell_name == "standin":
-        standin(seeds_of(seeds), seeds_of(control_seeds), rehearse)
+        standin(seeds_of(seeds), seeds_of(control_seeds), rehearse, grouped)
         return 0
     from ray_tpu._private.jax_utils import ensure_compilation_cache_dir
 

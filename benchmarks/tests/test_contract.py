@@ -183,3 +183,47 @@ def test_a_cell_may_override_only_reduced_keys(tmp_path, monkeypatch):
     monkeypatch.setattr(spec, "load_json", fake)
     with pytest.raises(ValueError, match="reduced"):
         spec.load_cell("internlm2-1.8b.train-2k", False)
+
+
+def test_the_fixture_trees_cells_are_held_as_the_benchmarks_are():
+    """Every cell of the tests' tree loads, real and rehearsed, with
+    what its kind holds of a cell's file held (``check_cell``), its
+    configuration found by name and its family by the configuration's;
+    a serve cell's shares of readings over the limits, where it states
+    them, lie under the harness's ceilings, and one of them does."""
+    import glob
+
+    from benchmarks import serve_load
+
+    names = sorted(os.path.basename(p)[:-len(".json")] for p in glob.glob(
+        os.path.join(spec.FIXTURE_TREE, "workloads", "*.json")))
+    assert len(names) >= 3
+    stating = []
+    for name in names:
+        for rehearse in (False, True):
+            cell = spec.load_cell(name, rehearse)
+            assert NAME.match(cell["name"]) and cell["name"] == name
+            assert spec.kind_of(cell).run
+            assert spec.family_of(cell["hp"]).reference_logits
+            assert len(spec.cell_metrics(name, False)) >= 1
+            assert len(spec.cell_metrics(name, True)) >= 1
+        if cell["kind"] != "serve":
+            continue
+        check = cell["serve"]["reference_check"]
+        stated = {k: check[k] for k in serve_load.SHARE_CEILINGS if k in check}
+        for share, value in stated.items():
+            assert 0 < value <= serve_load.SHARE_CEILINGS[share]
+        if stated:
+            stating.append(name)
+            assert set(stated) == set(serve_load.SHARE_CEILINGS)
+            assert check["length"] - check["positions"] \
+                >= serve_load.SHARES_FROM_ROW
+            assert cell["traffic"]["prompt_len"]["min"] \
+                >= serve_load.SHARES_FROM_ROW
+    assert stating == ["routed-standin.serve"]
+    # and no cell of BENCHMARK.json states one
+    for w in spec.benchmark_json()["workloads"]:
+        cell = spec.load_cell(w["name"], False)
+        if cell["kind"] == "serve":
+            assert not set(serve_load.SHARE_CEILINGS) & set(
+                cell["serve"]["reference_check"])
