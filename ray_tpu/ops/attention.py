@@ -12,7 +12,16 @@ logits tensor. Three consumers share the core accumulate step:
 - ``flash_attention`` — the Pallas TPU kernel
   (ray_tpu.ops.pallas_attention), mapped by hand over the ambient mesh
   (ops/_partition.py). On a TPU backend it is that kernel or an
-  exception, never another implementation.
+  exception, never another implementation. Its three kernels (forward,
+  ``bwd_dkv``, ``bwd_dq``) visit only the tile pairs a causal row can
+  see, work on one (batch, kv head) a grid step with the G q heads of
+  the group handled while their K and V are in VMEM, and sum dk and dv
+  over the group inside ``bwd_dkv``; operands sit in VMEM whole where
+  they fit and are streamed pair by pair where they do not, by their
+  bytes alone. They want q, k and v head-major: the transposes around
+  the call are folded by XLA's layout assignment into the projections
+  and RoPE (no copy of their own in a compiled train step; PERF.md §6,
+  PR 38), so they stay here in plain sight.
 
 Supports GQA (n_kv_heads divides n_heads). Layout: q (B, S, H, hd),
 k/v (B, T, KVH, hd) — the layout ray_tpu.models uses.
@@ -178,7 +187,8 @@ def flash_attention(
     Mosaic on a TPU, interpreted on the CPU test mesh. Shapes it cannot
     tile are an error on a TPU; on the CPU, where nothing is measured,
     they run the blockwise XLA formulation. ``block_q``/``block_kv``
-    apply to that formulation only; the kernel picks its own tiles.
+    apply to that formulation only; the kernel picks its own tiles
+    (and whether K and V stay resident in VMEM) from the shapes.
     """
     from .pallas_attention import pallas_flash_attention, untileable
 

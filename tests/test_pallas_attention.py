@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import pallas_attention
 from ray_tpu.ops.attention import blockwise_attention
 from ray_tpu.ops.pallas_attention import pallas_flash_attention
 
@@ -104,3 +105,83 @@ def test_uneven_q_kv_lengths():
     out = pallas_flash_attention(q, k, v, False, block_q=128, block_kv=128)
     ref = _naive(q, k, v, False)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# the tile schedule (PR 38): which pairs are visited, where the mask
+# runs, the group reduction inside bwd_dkv, resident and streamed forms
+# ----------------------------------------------------------------------
+
+@pytest.fixture
+def form(request, monkeypatch):
+    """Both forms of every kernel at shapes the resident one would take:
+    with no VMEM to hold an operand whole, ``_resident`` streams."""
+    if request.param == "streamed":
+        monkeypatch.setattr(pallas_attention, "_RESIDENT_BYTES", 0)
+    return request.param
+
+
+both_forms = pytest.mark.parametrize(
+    "form", ["resident", "streamed"], indirect=True)
+
+
+def _check(q, k, v, causal, block_q, block_kv):
+    """Forward and all three gradients against ``_naive``, at the
+    tolerances of the tests above."""
+    def loss(attend):
+        def f(q, k, v):
+            o = attend(q, k, v)
+            return jnp.sum(o * jnp.cos(o)), o
+        return f
+
+    kernel = loss(lambda q, k, v: pallas_flash_attention(
+        q, k, v, causal, block_q=block_q, block_kv=block_kv))
+    naive = loss(lambda q, k, v: _naive(q, k, v, causal))
+    gp, out = jax.grad(kernel, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    gn, ref = jax.grad(naive, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for a, b, name in zip(gp, gn, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+def _qkv(seed, B, S, T, H, kvh, hd=128):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (_rand((B, S, H, hd), ks[0]), _rand((B, T, kvh, hd), ks[1]),
+            _rand((B, T, kvh, hd), ks[2]))
+
+
+@both_forms
+@pytest.mark.parametrize("block_q,block_kv",
+                         [(128, 128), (256, 128), (128, 256)])
+def test_causal_tile_schedule(form, block_q, block_kv):
+    # 4 x 4 tiles of 128 rows: tiles below the diagonal run unmasked,
+    # the diagonal's masked, those above it are never visited; uneven
+    # tiles put two kv tiles, or half of one, on a q tile's diagonal
+    _check(*_qkv(4, 1, 512, 512, 2, 1), True, block_q, block_kv)
+
+
+@both_forms
+@pytest.mark.parametrize("heads,kvh", [(8, 2), (4, 2), (2, 2), (4, 1)])
+def test_group_summed_inside_dkv(form, heads, kvh):
+    # dk and dv of a kv head are the sum over its G = 4, 2, 1, 4 q
+    # heads, accumulated in the kernel's scratch and written once
+    _check(*_qkv(5, 2, 256, 256, heads, kvh), True, 128, 128)
+
+
+@both_forms
+@pytest.mark.parametrize("causal,S,T", [
+    (False, 128, 384), (False, 384, 128), (True, 128, 384), (True, 384, 128),
+])
+def test_uneven_lengths_all_gradients(form, causal, S, T):
+    # causal with T > S: kv tiles no row sees get zero dk and dv
+    _check(*_qkv(6, 1, S, T, 4, 2), causal, 128, 128)
+
+
+def test_streamed_by_shape():
+    # 8 MB of K, V, q and dO a head: more than a kernel holds resident,
+    # so all three kernels enumerate their visible pairs instead
+    B, S, H, hd = 1, 2048, 1, 1024
+    assert not pallas_attention._resident(S, hd, 4)
+    assert pallas_attention._resident(2048, 128, 2)
+    _check(*_qkv(7, B, S, S, H, H, hd), True, 512, 512)
