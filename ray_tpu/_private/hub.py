@@ -310,9 +310,13 @@ class StreamEntry:
     """State of one streaming-generator task (reference:
     core_worker streaming generator + ObjectRefGenerator _raylet.pyx:280):
     yielded object ids in order, consumer cursor for backpressure, and
-    waiters blocked on indices not yet produced."""
+    waiters blocked on indices not yet produced. ``t_walls`` holds, for
+    each id, the producer's stamp of when it yielded the value (None
+    from a worker that sent none, and for the error ref): it rides the
+    STREAM_NEXT reply so the consumer can read the item's transit."""
 
     oids: List[bytes] = field(default_factory=list)
+    t_walls: List[Optional[float]] = field(default_factory=list)
     ended: bool = False
     consumed: int = 0
     next_waiters: Dict[int, List[Tuple[Any, int]]] = field(default_factory=dict)
@@ -2567,9 +2571,11 @@ class Hub:
             node_id=self._conn_node(conn),
         )
         s.oids.append(p["object_id"])
+        s.t_walls.append(p.get("t_wall"))
         for wconn, req_id in s.next_waiters.pop(idx, []):
             s.consumed = max(s.consumed, idx + 1)
-            self._reply(wconn, req_id, object_id=p["object_id"])
+            self._reply(wconn, req_id, object_id=p["object_id"],
+                        t_wall=p.get("t_wall"))
         self._wake_credit_waiters(s)
 
     def _on_stream_end(self, conn, p):
@@ -2589,6 +2595,7 @@ class Hub:
             self._object_ready(err_oid, P.VAL_ERROR, p["error"], 0)
             idx = len(s.oids)
             s.oids.append(err_oid)
+            s.t_walls.append(None)
             for wconn, req_id in s.next_waiters.pop(idx, []):
                 self._reply(wconn, req_id, object_id=err_oid)
         s.ended = True
@@ -2614,7 +2621,8 @@ class Hub:
         idx = p["index"]
         if idx < len(s.oids):
             s.consumed = max(s.consumed, idx + 1)
-            self._reply(conn, p["req_id"], object_id=s.oids[idx])
+            self._reply(conn, p["req_id"], object_id=s.oids[idx],
+                        t_wall=s.t_walls[idx])
             self._wake_credit_waiters(s)
         elif s.ended:
             self._reply(conn, p["req_id"], end=True)
@@ -2623,6 +2631,7 @@ class Hub:
             # the registry cannot grow without bound
             if s.oids:
                 s.oids = []
+                s.t_walls = []
                 self._ended_streams.append(p["task_id"])
                 while len(self._ended_streams) > 10000:
                     old = self._ended_streams.popleft()

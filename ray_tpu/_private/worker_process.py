@@ -73,6 +73,16 @@ class _ExecTrace:
             self._tok = None
         self.stamp("exec1")
 
+    def drive_stream(self, stream, p: dict, result) -> None:
+        """``stream(p, result)`` under the trace's context: a streaming
+        task's body runs lazily in there, after exit_exec, and what it
+        records or submits belongs to this trace all the same."""
+        tok = _t.push_context((self.trace_id, self.exec_id))
+        try:
+            stream(p, result)
+        finally:
+            _t.pop_context(tok)
+
     def emit(self, name: str, error: Optional[str] = None,
              **extra) -> None:
         t = self.t
@@ -196,6 +206,10 @@ class WorkerRuntime:
     def _stream_yield_one(self, p: dict, value) -> None:
         from .ids import ObjectID
 
+        # when the generator handed the value over, as an anchored wall
+        # stamp: the hub keeps it beside the object id and the consumer
+        # reads its transit off it (serve.stream_transit)
+        t_wall = _t.wall_at(time.monotonic())
         oid = ObjectID.generate()
         kind, payload, size = self.client.encode_value(oid, value)
         self.client.send(
@@ -206,6 +220,7 @@ class WorkerRuntime:
                 "kind": kind,
                 "payload": payload,
                 "size": size,
+                "t_wall": t_wall,
             },
         )
 
@@ -303,7 +318,9 @@ class WorkerRuntime:
                     # _stream_results; the execute span here covers
                     # only its construction
                     et.emit(fn_name, streaming=True)
-                self._stream_results(p, result)
+                    et.drive_stream(self._stream_results, p, result)
+                else:
+                    self._stream_results(p, result)
                 return
             if et is not None:
                 et.stamp("store0")
@@ -428,7 +445,9 @@ class WorkerRuntime:
             if (p.get("options") or {}).get("streaming"):
                 if et is not None:
                     et.emit(method_name, streaming=True)
-                self._stream_results(p, result)
+                    et.drive_stream(self._stream_results, p, result)
+                else:
+                    self._stream_results(p, result)
                 return
             if et is not None:
                 et.stamp("store0")

@@ -76,8 +76,9 @@ re-designed for XLA instead of wrapped:
   tokens are exactly those of a loop that reads every call before the
   next; a sampled one's are drawn from the same keys in dispatch order
   (all chunks of a step before its decodes).
-- Observation: every request carries five monotonic stamps (its four
-  phases: queue_wait, prefill_wait, prefill, decode), ``EngineStats``
+- Observation: every request carries seven monotonic stamps (its six
+  phases: ingress and accept before the server's pending queue, then
+  queue_wait, prefill_wait, prefill, decode), ``EngineStats``
   counts what the steps did, and each part of ``step()`` is a
   ``tracing.phase`` span (``ray_tpu.llm.*`` in a profiler trace, on the
   device's clock). ``llm.decode_sync`` is the wait for step N's tokens
@@ -103,8 +104,11 @@ import numpy as np
 
 from ray_tpu.util.tracing import PhaseStats, phase
 
-# a request's phases, each the gap between two of its stamps
-REQUEST_PHASES = ("queue_wait", "prefill_wait", "prefill", "decode")
+# a request's phases, each the gap between two of its stamps: the two
+# before the server's pending queue (the handle's route entry to the
+# replica's entry, that to the queue), then the engine's four
+REQUEST_PHASES = ("ingress", "accept",
+                  "queue_wait", "prefill_wait", "prefill", "decode")
 
 
 def derived_prefill_chunk(device_kind: str, bytes_per_param: float,
@@ -145,22 +149,35 @@ class GenRequest:
     prefill_pos: int = 0  # prompt tokens already written to cache
     generated: List[int] = field(default_factory=list)
     done: bool = False
-    # time.monotonic() stamps, 0.0 until reached: put on the server's
-    # pending queue, given a slot, first chunk dispatched, first token
-    # emitted, finished
+    # time.monotonic() stamps, 0.0 until reached: the handle's
+    # ``_route`` entered (in this process's clock), the replica's method
+    # entered, put on the server's pending queue, given a slot, first
+    # chunk dispatched, first token read by the host, finished. A
+    # request nobody routed (a bare engine, a server called directly)
+    # has both of the first two at ``submitted``
+    routed: float = 0.0
+    received: float = 0.0
     submitted: float = 0.0
     admitted: float = 0.0
     prefill_started: float = 0.0
     first_token: float = 0.0
     finished: float = 0.0
     prefill_chunks: int = 0
+    # written by the request's own thread in ``LLMServer.generate_stream``
+    # alone: when it had the first token in hand, and the seconds and
+    # count of its tokens' waits between the batching loop's read of
+    # their step and that thread's ``q.get`` returning
+    first_yielded: float = 0.0
+    handoff_s: float = 0.0
+    handoff_n: int = 0
 
     def phases(self) -> Dict[str, float]:
-        """Seconds in queue_wait (no slot yet), prefill_wait (a slot,
-        behind the other prompts of its shard), prefill and decode; they
-        sum to finished - submitted."""
-        stamps = (self.submitted, self.admitted, self.prefill_started,
-                  self.first_token, self.finished)
+        """Seconds in ingress (route entry to replica entry), accept
+        (the replica's own work before the pending queue), queue_wait
+        (no slot yet), prefill_wait (a slot, behind the other prompts of
+        its shard), prefill and decode; they sum to finished - routed."""
+        stamps = (self.routed, self.received, self.submitted, self.admitted,
+                  self.prefill_started, self.first_token, self.finished)
         return {name: b - a for name, a, b in
                 zip(REQUEST_PHASES, stamps, stamps[1:])}
 
@@ -194,7 +211,9 @@ class EngineStats:
     snapshots and subtracts. ``phases`` holds the seconds and counts of
     the parts of ``step()``; ``requests`` the last few thousand finished
     requests as (submitted stamp, queue_wait, prefill_wait, prefill,
-    decode seconds, prefill chunks)."""
+    decode seconds, prefill chunks, ingress, accept seconds): the two
+    phases before ``submitted`` stand last, so a reader by index of the
+    first six reads what it read."""
 
     COUNTERS = (
         "steps", "tokens_emitted",
@@ -516,8 +535,11 @@ class LlamaEngine:
             req.shard = si
             req.prefill_pos = 0
             req.admitted = time.monotonic()
-            if not req.submitted:  # handed to the engine directly
-                req.submitted = req.admitted
+            # handed to the engine directly, or by a caller no handle
+            # routed: the stamps it lacks are the next one's
+            req.submitted = req.submitted or req.admitted
+            req.received = req.received or req.submitted
+            req.routed = req.routed or req.received
             shard.prefilling.append(req)
             return True
 
@@ -531,8 +553,10 @@ class LlamaEngine:
         self._release(shard, req)
         req.finished = time.monotonic()
         self.stats.requests_finished += 1
+        ingress, accept, *engine_phases = req.phases().values()
         self.stats.requests.append(
-            (req.submitted, *req.phases().values(), req.prefill_chunks))
+            (req.submitted, *engine_phases, req.prefill_chunks,
+             ingress, accept))
 
     def _pump_prefill(self, shard: _Shard, out: List[Tuple[GenRequest, int]]):
         """Write ONE chunk of the oldest pending prompt into the cache;

@@ -6,6 +6,12 @@ Llama with an XLA KV cache (llm/_internal/engine.py), not a wrapped
 vLLM; requests stream tokens through the serve streaming-response path
 (handle.options(stream=True) over num_returns="streaming").
 
+What a request spends outside the engine is recorded beside the
+engine's own stamps (``GenRequest.routed`` / ``received`` /
+``first_yielded``; ``engine_stats()["loop_phases"]``: ``serve.ingress``,
+``llm.accept``, ``llm.first_token_handoff``, ``llm.token_handoff``),
+always on, at the cost of a ``time.monotonic()`` and a float.
+
 HTTP: `serve.run(build_llm_app(cfg))` exposes POST /<name> with JSON
 {"prompt_ids": [...], "max_tokens": N, "temperature": t, "stream": bool}
 via the existing serve proxy.
@@ -68,6 +74,10 @@ class LLMServer:
         # seconds and counts of the batching loop's own parts (llm.admit,
         # llm.emit, llm.idle_sleep); only the loop's thread adds to them
         self._loop_phases = tracing.PhaseStats()
+        # what the requests' own threads fold in as each ends, under
+        # self._lock: serve.ingress, llm.accept, llm.first_token_handoff
+        # (a count a request), llm.token_handoff (a count a token)
+        self._request_phases = tracing.PhaseStats()
         self._running = True
         self._loop_thread = threading.Thread(
             target=self._batching_loop, daemon=True, name="llm-batching"
@@ -203,8 +213,12 @@ class LLMServer:
                     for req in eng.abort_all():
                         q = self._token_queues.get(req.request_id)
                         if q is not None:
-                            q.put(("error", e))
+                            q.put(("error", e, 0.0))
                     continue
+                # when this thread had the step's tokens: each rides the
+                # queue with it, and its request's thread reads the
+                # hand-off's length off it (llm.token_handoff)
+                t_read = time.monotonic()
                 # the device is running the decodes step() dispatched
                 # while this thread emits and, next round, admits
                 with tracing.phase("llm.emit", self._loop_phases):
@@ -212,12 +226,12 @@ class LLMServer:
                     for req, tok in emitted:
                         q = self._token_queues.get(req.request_id)
                         if q is not None:
-                            q.put(("token", tok))
+                            q.put(("token", tok, t_read))
                             if req.done:
                                 done[req.request_id] = (q, req)
                     # behind every token of the step, the request's last too
                     for q, req in done.values():
-                        q.put(("done", req))
+                        q.put(("done", req, t_read))
             if not stepped and not admitted:
                 with tracing.phase("llm.idle_sleep", self._loop_phases):
                     time.sleep(0.005)
@@ -239,7 +253,7 @@ class LLMServer:
                     eng = self._engine_for(req.adapter_id)
                 except Exception as e:
                     if q is not None:
-                        q.put(("error", e))
+                        q.put(("error", e, 0.0))
                     continue
                 if not eng.has_capacity():
                     requeue.append(req)
@@ -250,7 +264,7 @@ class LLMServer:
                     # a bad request (e.g. prompt >= max_seq) must fail
                     # its own caller, never the batching thread
                     if q is not None:
-                        q.put(("error", e))
+                        q.put(("error", e, 0.0))
                     continue
                 if ok is False:
                     # no slot after all (has_capacity raced a concurrent
@@ -276,58 +290,93 @@ class LLMServer:
     ):
         """Generator: yields token ids as the engine produces them
         (invoked through serve's streaming path)."""
+        from ray_tpu.serve._private.observability import request_stamps
+
         from ._internal.engine import GenRequest
 
-        if adapter_id is None:
-            # serve routing: handle.options(multiplexed_model_id=...)
-            from ray_tpu.serve import get_multiplexed_model_id
-
-            adapter_id = get_multiplexed_model_id()
-        if adapter_id:
-            # cold-load in THIS thread (see _engine_for docstring): load
-            # errors also surface here, at submission, with a stack
-            self._engine_for(adapter_id)
         rid = f"req{next(self._id_counter)}"
-        q: "queue.Queue" = queue.Queue()
-        with self._lock:
-            self._token_queues[rid] = q
-        # set by serve.execute for a sampled request; None otherwise
-        trace_ctx = tracing.current_context()
-        self._pending.put(
-            GenRequest(
+        # the replica's work on this request before the pending queue,
+        # once a request and in its own thread
+        with tracing.phase("serve.accept", request_id=rid):
+            if adapter_id is None:
+                # serve routing: handle.options(multiplexed_model_id=...)
+                from ray_tpu.serve import get_multiplexed_model_id
+
+                adapter_id = get_multiplexed_model_id()
+            if adapter_id:
+                # cold-load in THIS thread (see _engine_for docstring):
+                # load errors also surface here, at submission, with a
+                # stack
+                self._engine_for(adapter_id)
+            q: "queue.Queue" = queue.Queue()
+            with self._lock:
+                self._token_queues[rid] = q
+            # set by serve.execute for a sampled request; None otherwise
+            trace_ctx = tracing.current_context()
+            req = GenRequest(
                 request_id=rid,
                 prompt_ids=list(prompt_ids),
                 max_tokens=max_tokens,
                 temperature=temperature,
                 eos_id=eos_id,
                 adapter_id=adapter_id or "",
-                submitted=time.monotonic(),
             )
-        )
+            # the handle's and the replica's stamps; a caller no handle
+            # routed (a test, a server used in-process) has none, and
+            # both count as submitted
+            routed, received = request_stamps() or (0.0, 0.0)
+            req.submitted = time.monotonic()
+            req.routed = routed or req.submitted
+            req.received = received or req.submitted
+            self._pending.put(req)
         try:
+            # the wait for the first token, to just before its yield
+            with tracing.phase("llm.first_yield", request_id=rid):
+                item = q.get(timeout=120)
             while True:
-                kind, tok = q.get(timeout=120)
+                kind, tok, t_read = item
                 if kind == "done":
                     if trace_ctx is not None:
                         self._emit_request_span(trace_ctx, tok)
                     return
                 if kind == "error":
                     raise tok
+                now = time.monotonic()
+                req.handoff_s += now - t_read
+                req.handoff_n += 1
+                req.first_yielded = req.first_yielded or now
                 yield tok
+                item = q.get(timeout=120)
         finally:
             with self._lock:
                 self._token_queues.pop(rid, None)
+                self._fold_request(req)
+
+    def _fold_request(self, req) -> None:
+        """A request's time outside the engine into ``_request_phases``,
+        by its own thread as it ends, under ``self._lock``."""
+        add = self._request_phases.add
+        add("serve.ingress", req.received - req.routed)
+        add("llm.accept", req.submitted - req.received)
+        if req.first_yielded:
+            add("llm.first_token_handoff",
+                req.first_yielded - req.first_token)
+            add("llm.token_handoff", req.handoff_s, req.handoff_n)
 
     @staticmethod
     def _emit_request_span(trace_ctx, req) -> None:
         """One ``llm.request`` span record for a finished request that
-        arrived under a sampled trace: submission to its last token,
-        with its four phases and chunk count as attributes."""
+        arrived under a sampled trace: the handle's route entry (the
+        replica's, where no handle stamped it) to its last token, with
+        its six phases, the first token's hand-off to the request's
+        thread and the chunk count as attributes."""
         tracing._emit(tracing.make_runtime_record(
             "llm.request", "llm.request", trace_ctx[0], trace_ctx[1],
-            req.submitted, req.finished,
+            req.routed, req.finished,
             attrs={"request_id": req.request_id,
                    "prefill_chunks": req.prefill_chunks,
+                   "first_token_handoff_s": round(
+                       req.first_yielded - req.first_token, 6),
                    **{f"{k}_s": round(v, 6)
                       for k, v in req.phases().items()}},
         ))
@@ -374,6 +423,8 @@ class LLMServer:
     def engine_stats(self) -> Dict[str, Any]:
         from ray_tpu._private.jax_utils import device_report
 
+        with self._lock:  # the requests' threads fold under it
+            request_phases = self._request_phases.snapshot()
         return {
             "active": self.engine.num_active(),
             "peak_active": self.engine.peak_active,
@@ -386,7 +437,10 @@ class LLMServer:
             # finished requests' phases (EngineStats.snapshot); a reader
             # takes two of these and subtracts
             "engine": self.engine.stats.snapshot(),
-            "loop_phases": self._loop_phases.snapshot(),
+            # the batching loop's own parts, and the requests' time
+            # outside the engine (the names do not collide)
+            "loop_phases": {**self._loop_phases.snapshot(),
+                            **request_phases},
             **device_report(),
         }
 

@@ -112,6 +112,10 @@ class ObjectRefGenerator:
     def __init__(self, task_id: bytes):
         self._task_id = task_id
         self._idx = 0
+        # the producer's stamp of when it yielded the ref last returned
+        # (an anchored wall time, tracing.wall_at); None where it sent
+        # none. It came with the STREAM_NEXT reply: no message of its own
+        self.last_yield_wall: Optional[float] = None
 
     def __iter__(self):
         return self
@@ -127,6 +131,7 @@ class ObjectRefGenerator:
         if reply.get("end"):
             raise StopIteration
         self._idx += 1
+        self.last_yield_wall = reply.get("t_wall")
         return ObjectRef(ObjectID(reply["object_id"]))
 
     def __aiter__(self):

@@ -28,6 +28,7 @@ never fail because observability did.
 
 from __future__ import annotations
 
+import contextvars
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -159,10 +160,50 @@ def emit_span(
 def mono_at_wall(wall: float, now_mono: Optional[float] = None) -> float:
     """Invert tracing.wall_at for a wall stamp taken in ANOTHER process
     on the same host: the monotonic instant (in THIS process's clock)
-    that renders to that wall time. Lets the replica open its
-    serve.queue_wait span at the handle's enqueue moment."""
+    that renders to that wall time, never later than now. Lets the
+    replica open its serve.queue_wait span at the handle's enqueue
+    moment, and carries a request's ``routed_wall`` and a streamed
+    item's ``t_wall`` into the receiver's clock (tracing.wall_at says
+    how exact that is)."""
     now = time.monotonic() if now_mono is None else now_mono
     return now - max(0.0, _tracing.wall_at(now) - wall)
+
+
+# (routed, received) of the request this thread is handling, both on
+# THIS process's monotonic clock: when the handle's _route was entered
+# and when the replica's method was. Set by Replica.handle_request /
+# handle_request_streaming beside the model-id and deadline contexts;
+# the deployment's own code (LLMServer.generate_stream) copies them
+# onto what it records of the request. None outside a replica's call.
+_request_stamps_ctx: contextvars.ContextVar[
+    Optional[Tuple[float, float]]
+] = contextvars.ContextVar("serve_request_stamps", default=None)
+
+
+def stamp_received(request_meta: Optional[Dict[str, Any]]):
+    """Stamp this request's entry into the replica and set
+    ``request_stamps()`` for the thread; returns the reset token. A
+    request whose caller sent no ``routed_wall`` (a handle from before
+    it, a direct actor call) counts as routed when it was received."""
+    received = time.monotonic()
+    routed = received
+    if request_meta and "routed_wall" in request_meta:
+        routed = mono_at_wall(request_meta["routed_wall"], received)
+    return _request_stamps_ctx.set((routed, received))
+
+
+def clear_stamps(token=None) -> None:
+    """Undo ``stamp_received``: by its token where the caller leaves in
+    the context it entered in, outright where it may not (a generator
+    closed from elsewhere)."""
+    if token is None:
+        _request_stamps_ctx.set(None)
+    else:
+        _request_stamps_ctx.reset(token)
+
+
+def request_stamps() -> Optional[Tuple[float, float]]:
+    return _request_stamps_ctx.get()
 
 
 # ----------------------------------------------------------------- metrics

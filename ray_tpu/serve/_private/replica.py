@@ -106,6 +106,7 @@ class Replica:
         from . import observability as obs
         from . import payloads as _payloads
 
+        stamps_token = obs.stamp_received(request_meta)
         with self._lock:
             self._ongoing += 1
             self._total += 1
@@ -123,6 +124,7 @@ class Replica:
             if deadline_mono <= t_now:
                 with self._lock:
                     self._ongoing -= 1
+                obs.clear_stamps(stamps_token)
                 obs.count_expired(self.deployment_name)
                 from ray_tpu.exceptions import RequestExpiredError
 
@@ -220,6 +222,7 @@ class Replica:
                 )
             _deadline_ctx.reset(dl_token)
             _model_id_ctx.reset(token)
+            obs.clear_stamps(stamps_token)
             with self._lock:
                 self._ongoing -= 1
 
@@ -229,18 +232,39 @@ class Replica:
         args: Tuple,
         kwargs: Dict,
         multiplexed_model_id: str = "",
+        request_meta: Optional[Dict[str, Any]] = None,
     ):
         """Generator variant: invoked with num_returns="streaming" so
         each yielded chunk becomes an incremental stream object
-        (reference: Serve streaming responses over ObjectRefGenerator)."""
+        (reference: Serve streaming responses over ObjectRefGenerator).
+        ``request_meta`` is what ``handle_request`` takes (the router's
+        ``routed_wall``, and ``enq_wall`` when sampled); a caller from
+        before it sends none."""
+        from ...util import tracing as _tracing
         from ..multiplex import _model_id_ctx
+        from . import observability as obs
 
+        # no reset tokens: the executor drives one task at a time, and
+        # generator frames don't carry their own context anyway
+        obs.stamp_received(request_meta)
         with self._lock:
             self._ongoing += 1
             self._total += 1
-        # no reset token: the executor drives one task at a time, and
-        # generator frames don't carry their own context anyway
         _model_id_ctx.set(multiplexed_model_id)
+        # sampled: the worker holds the trace's context while it drives
+        # this generator; serve.queue_wait and serve.execute as on the
+        # unary path, the deployment's own spans parent under the latter
+        ctx = _tracing.current_context()
+        if ctx is not None:
+            t0 = time.monotonic()
+            if request_meta and "enq_wall" in request_meta:
+                obs.emit_span(
+                    "serve.queue_wait", "serve.queue_wait", ctx[0], ctx[1],
+                    obs.mono_at_wall(request_meta["enq_wall"], t0), t0,
+                    deployment=self.deployment_name,
+                )
+            exec_sid = _tracing.new_span_id()
+            _tracing.push_context((ctx[0], exec_sid))
         try:
             target = (
                 self.instance
@@ -272,6 +296,16 @@ class Replica:
                     result = _run_coro(_with_ctx(result))
                 yield result
         finally:
+            if ctx is not None:
+                # pushed again, not popped by token: a generator may be
+                # closed from another context than the one it began in
+                _tracing.push_context(ctx)
+                obs.emit_span(
+                    "serve.execute", "serve.execute", ctx[0], ctx[1],
+                    t0, time.monotonic(), span_id=exec_sid,
+                    deployment=self.deployment_name, method=method_name,
+                )
+            obs.clear_stamps()
             with self._lock:
                 self._ongoing -= 1
 

@@ -10,10 +10,13 @@ replica-list refresh. ``.remote()`` returns a DeploymentResponse future
 
 from __future__ import annotations
 
+import contextlib
 import random
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from ..util import tracing as _tracing
 
 _REFRESH_PERIOD_S = 1.0
 
@@ -244,22 +247,69 @@ class DeploymentResponse:
 
 class DeploymentResponseGenerator:
     """Iterates a streaming deployment call's yielded values (parity:
-    serve's DeploymentResponseGenerator over an ObjectRefGenerator)."""
+    serve's DeploymentResponseGenerator over an ObjectRefGenerator).
 
-    def __init__(self, ref_gen):
+    Each item, once its value is in hand, adds the time since the
+    replica's worker yielded it (the stamp the STREAM_NEXT reply
+    carries) to the handle's ``stream_stats()``; the stream's end sends
+    the one latency observation (or error count) a unary call's
+    ``result()`` sends. A stream the consumer abandons sends neither."""
+
+    def __init__(self, ref_gen, handle=None, t0: Optional[float] = None):
         self._ref_gen = ref_gen
+        self._handle = handle
+        self._t0 = time.monotonic() if t0 is None else t0
+        self._items = 0
+
+    def _note_item(self) -> None:
+        t_wall = self._ref_gen.last_yield_wall
+        if self._handle is None or t_wall is None:
+            return
+        # both stamps as anchored wall times; a negative gap (another
+        # host's clock ahead of this one) reads 0
+        transit = max(0.0, _tracing.wall_at(time.monotonic()) - t_wall)
+        self._handle._note_transit(transit, first=not self._items)
+        self._items += 1
+
+    def _record_outcome(self, error: bool) -> None:
+        if self._handle is None:
+            return
+        from ._private import observability as obs
+
+        dep, route = self._handle.deployment_name, self._handle._metric_route
+        if error:
+            obs.count_error(dep, route)
+        else:
+            obs.observe_latency(dep, route, time.monotonic() - self._t0)
+
+    @contextlib.contextmanager
+    def _recorded(self):
+        """The stream's outcome, sent once when its iteration ends: not
+        at all where the consumer closes it early (GeneratorExit)."""
+        try:
+            yield
+        except GeneratorExit:
+            raise
+        except BaseException:
+            self._record_outcome(error=True)
+            raise
+        self._record_outcome(error=False)
 
     def __iter__(self):
         import ray_tpu
 
-        for ref in self._ref_gen:
-            yield ray_tpu.get(ref)
+        with self._recorded():
+            for ref in self._ref_gen:
+                value = ray_tpu.get(ref)
+                self._note_item()
+                yield value
 
     async def __aiter__(self):
-        import ray_tpu
-
-        async for ref in self._ref_gen:
-            yield await ref
+        with self._recorded():
+            async for ref in self._ref_gen:
+                value = await ref
+                self._note_item()
+                yield value
 
 
 class DeploymentHandle:
@@ -288,6 +338,11 @@ class DeploymentHandle:
         self._fail_streaks: Dict[bytes, int] = {}
         self._ejected: Dict[bytes, Any] = {}
         self._prober: Optional[threading.Thread] = None
+        # streamed items' way back (serve.stream_transit, every item;
+        # serve.stream_first_transit, a stream's first): seconds and
+        # counts, added by whichever thread iterates a stream, under
+        # the lock beside them; options() views share both
+        self._stream_phases = (_tracing.PhaseStats(), threading.Lock())
 
     def __reduce__(self):
         # handles travel inside deployment init args (composition);
@@ -328,7 +383,28 @@ class DeploymentHandle:
         # same deployment must not resurrect an ejected replica
         h._fail_streaks = self._fail_streaks
         h._ejected = self._ejected
+        h._stream_phases = self._stream_phases
         return h
+
+    def stream_stats(self) -> Dict[str, Dict[str, float]]:
+        """{name: {"seconds", "count"}} of this process's streamed calls
+        through this handle and its ``options()`` views, cumulative (take
+        two and subtract): ``serve.stream_transit`` is the time from the
+        replica's worker yielding an item to the consumer holding its
+        value (encode, STREAM_YIELD, the hub, STREAM_NEXT's reply, the
+        get), ``serve.stream_first_transit`` the same for a stream's
+        first item alone. Exact on one host; from another host it holds
+        the two wall clocks' skew."""
+        stats, lock = self._stream_phases
+        with lock:
+            return stats.snapshot()
+
+    def _note_transit(self, seconds: float, first: bool) -> None:
+        stats, lock = self._stream_phases
+        with lock:
+            stats.add("serve.stream_transit", seconds)
+            if first:
+                stats.add("serve.stream_first_transit", seconds)
 
     def __getattr__(self, name: str):
         if name.startswith("_"):
@@ -391,8 +467,6 @@ class DeploymentHandle:
         self, method: str, args, kwargs, _retry_deadline: Optional[float] = None
     ) -> DeploymentResponse:
         from ray_tpu.exceptions import RequestExpiredError, RequestShedError
-
-        from ..util import tracing as _tracing
 
         from ._private import observability as obs
 
@@ -509,49 +583,45 @@ class DeploymentHandle:
                 replicas = holders
         replica = self._pick(replicas)
         rid = _rid(replica)
+        obs.count_request(self.deployment_name, self._metric_route)
+        # request_meta always rides: when _route was entered (the
+        # replica's ingress stamp) and the deadline (its pre-execute
+        # expiry check and its batch queue); the enqueue wall stamp is
+        # added only when traced
+        meta: Dict[str, Any] = {"routed_wall": _tracing.wall_at(t_route0)}
+        if deadline_mono is not None:
+            meta["deadline_wall"] = _tracing.wall_at(deadline_mono)
         if self._stream:
             # streamed responses flow as an ObjectRefGenerator; no
             # transparent replica retry (a half-consumed stream is not
             # transparently re-executable), and no _outstanding
             # accounting — there is no single completion ref to credit
             # the count back against
-            ref_gen = replica.handle_request_streaming.options(
+            call = replica.handle_request_streaming.options(
                 num_returns="streaming"
-            ).remote(method, args, kwargs, self._model_id)
-            return DeploymentResponseGenerator(ref_gen)
-        with self._lock:
-            self._outstanding[rid] = self._outstanding.get(rid, 0) + 1
-        obs.count_request(self.deployment_name, self._metric_route)
-        handle_request = replica.handle_request
-        if payload_deps:
-            # spilled payload ids ride the dispatch's arg_deps: the hub
-            # pins them while the call is in flight, so a caller dropping
-            # the response (and its holds) early can't free a payload the
-            # replica hasn't fetched yet
-            handle_request = handle_request.options(_extra_arg_deps=payload_deps)
-        # request_meta always rides now: the deadline propagates to the
-        # replica (pre-execute expiry check) and its batch queue; the
-        # enqueue wall stamp is added only when traced
-        meta: Optional[Dict[str, Any]] = None
-        if deadline_mono is not None:
-            meta = {"deadline_wall": _tracing.wall_at(deadline_mono)}
-        if tr is None:
-            ref = handle_request.remote(
-                method, args, kwargs, self._model_id, meta
             )
+        else:
+            with self._lock:
+                self._outstanding[rid] = self._outstanding.get(rid, 0) + 1
+            call = replica.handle_request
+            if payload_deps:
+                # spilled payload ids ride the dispatch's arg_deps: the
+                # hub pins them while the call is in flight, so a caller
+                # dropping the response (and its holds) early can't free
+                # a payload the replica hasn't fetched yet
+                call = call.options(_extra_arg_deps=payload_deps)
+        if tr is None:
+            ref = call.remote(method, args, kwargs, self._model_id, meta)
         else:
             # the enqueue wall stamp rides as an ordinary pickled arg;
             # the replica opens serve.queue_wait at this instant. The
             # ambient push makes the task-layer submit span (and the
             # replica's execute chain) parent under serve.route.
             route_sid = _tracing.new_span_id()
-            meta = dict(meta or {})
             meta["enq_wall"] = _tracing.wall_at(time.monotonic())
             token = _tracing.push_context((tr[0], route_sid))
             try:
-                ref = handle_request.remote(
-                    method, args, kwargs, self._model_id, meta
-                )
+                ref = call.remote(method, args, kwargs, self._model_id, meta)
             finally:
                 _tracing.pop_context(token)
             obs.emit_span(
@@ -559,6 +629,8 @@ class DeploymentHandle:
                 t_route0, time.monotonic(), span_id=route_sid,
                 deployment=self.deployment_name, method=method,
             )
+        if self._stream:
+            return DeploymentResponseGenerator(ref, self, t_route0)
         with self._lock:
             self._inflight[ref] = rid
         resp = DeploymentResponse(ref, self, method, args, kwargs)

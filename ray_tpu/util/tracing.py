@@ -38,7 +38,15 @@ lands in the ``.xplane.pb`` host plane on the same clock as the device's
 ops, and adds its duration and a count to ``stats`` (a
 :class:`PhaseStats`) for the operator's counters. There is no switch:
 with no session the annotation is a flag check, and nothing goes to the
-hub.
+hub. A streamed request's time OUTSIDE the engine is kept the same way,
+as monotonic stamps and ``PhaseStats`` entries and two spans a request
+(``serve.accept``, ``llm.first_yield``, in the request's own thread):
+``serve.ingress`` and ``llm.accept`` before the server's pending queue,
+``llm.first_token_handoff`` / ``llm.token_handoff`` from the batching
+loop to the request's thread (``LLMServer.engine_stats()``), and
+``serve.stream_transit`` from the replica's worker to the consumer
+(``DeploymentHandle.stream_stats()``). A stamp crosses a process as
+``wall_at`` renders it.
 
 Clock discipline (graftlint GL008, which covers this file): span
 start/end are positioned in wall time for cross-process stitching, but
@@ -57,11 +65,27 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+
+def _anchor() -> Tuple[float, float]:
+    """(monotonic, wall) read as one instant: the tightest of a few
+    wall reads bracketed by two monotonic ones, so a stamp carried to
+    another process of this host (``wall_at`` there, ``mono_at_wall``
+    here) is off by microseconds, not by a preemption between two
+    reads."""
+    best = None
+    for _ in range(5):
+        m0 = time.monotonic()
+        wall = time.time()
+        m1 = time.monotonic()
+        if best is None or m1 - m0 < best[0]:
+            best = (m1 - m0, (m0 + m1) / 2, wall)
+    return best[1], best[2]
+
+
 # one wall anchor per process: all span timestamps are monotonic stamps
 # re-based onto this anchor (same-host processes share the wall clock,
 # so cross-process spans land on one coherent timeline)
-_MONO_ANCHOR = time.monotonic()
-_WALL_ANCHOR = time.time()
+_MONO_ANCHOR, _WALL_ANCHOR = _anchor()
 
 _enabled = os.environ.get("RAY_TPU_TRACING", "") in ("1", "true", "yes")
 # (trace_id, span_id) of the innermost open span — user spans AND the
@@ -73,7 +97,15 @@ _ctx: contextvars.ContextVar[Optional[Tuple[str, str]]] = contextvars.ContextVar
 
 
 def wall_at(mono: float) -> float:
-    """Render a time.monotonic() stamp as an anchored wall timestamp."""
+    """Render a time.monotonic() stamp as an anchored wall timestamp.
+    It is how a stamp crosses a process boundary (a request's
+    ``routed_wall``, a streamed item's ``t_wall``): the receiver turns
+    it back with ``serve/_private/observability.mono_at_wall``. On one
+    host both sides read the same CLOCK_MONOTONIC, so the trip is exact
+    to the two anchors' jitter; across hosts it is as good as the
+    hosts' wall clocks agree, and a difference taken over it
+    (``serve.ingress``, ``serve.stream_transit``) is off by their skew
+    (a negative one reads 0)."""
     return _WALL_ANCHOR + (mono - _MONO_ANCHOR)
 
 
@@ -236,9 +268,9 @@ class PhaseStats:
         self.seconds: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
 
-    def add(self, name: str, seconds: float) -> None:
+    def add(self, name: str, seconds: float, count: int = 1) -> None:
         self.seconds[name] = self.seconds.get(name, 0.0) + seconds
-        self.counts[name] = self.counts.get(name, 0) + 1
+        self.counts[name] = self.counts.get(name, 0) + count
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         seconds, counts = dict(self.seconds), dict(self.counts)
@@ -282,36 +314,6 @@ class phase:
         if self._stats is not None:
             self._stats.add(self._name, seconds)
         return False
-
-
-def traced(name: Optional[str] = None):
-    """Decorator form: ``@tracing.traced()`` wraps calls in a span."""
-
-    def wrap(fn):
-        import functools
-        import inspect
-
-        span_name = name or getattr(fn, "__qualname__", fn.__name__)
-
-        if inspect.iscoroutinefunction(fn):
-            # the span must cover the awaited body, not the instant
-            # coroutine construction — and the context must be live
-            # while the body executes so child spans parent correctly
-            @functools.wraps(fn)
-            async def ainner(*args, **kwargs):
-                with span(span_name):
-                    return await fn(*args, **kwargs)
-
-            return ainner
-
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with span(span_name):
-                return fn(*args, **kwargs)
-
-        return inner
-
-    return wrap
 
 
 # --------------------------------------------------- critical-path analysis
@@ -380,8 +382,9 @@ STAGE_PRECEDENCE: Dict[str, int] = {
     "podracer.traj_handoff": 74,
     "podracer.param_sync": 74,
     # ---- LLM engine (llm/serve.py generate_stream). One span per
-    # sampled request, submission to the last token, with its four
-    # phases (queue_wait, prefill_wait, prefill, decode) as attributes.
+    # sampled request, the handle's route entry to the last token, with
+    # its six phases (ingress, accept, queue_wait, prefill_wait,
+    # prefill, decode) and first_token_handoff as attributes.
     # It lies inside the replica's handler, so it sits just above
     # serve.execute: the slice is named for the engine, and batch-wait
     # or a payload fetch inside the same handler still keep their names.
@@ -493,7 +496,6 @@ __all__ = [
     "span",
     "phase",
     "PhaseStats",
-    "traced",
     "current_context",
     "context",
     "push_context",

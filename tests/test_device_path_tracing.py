@@ -89,12 +89,17 @@ def test_request_phases_in_order_and_sum_for_a_request_behind_another(
     assert behind.shard == first.shard
     run_dry(eng)
     for req in (first, behind):
-        stamps = [req.submitted, req.admitted, req.prefill_started,
-                  req.first_token, req.finished]
+        stamps = [req.routed, req.received, req.submitted, req.admitted,
+                  req.prefill_started, req.first_token, req.finished]
         assert all(s > 0 for s in stamps)
         assert stamps == sorted(stamps)
         phases = req.phases()
         assert tuple(phases) == REQUEST_PHASES
+        assert sum(phases.values()) == pytest.approx(
+            req.finished - req.routed, abs=1e-9)
+        # no handle routed it: the two phases before the queue read 0.0
+        # and the four old ones what they read before
+        assert (phases["ingress"], phases["accept"]) == (0.0, 0.0)
         assert sum(phases.values()) == pytest.approx(
             req.finished - req.submitted, abs=1e-9)
     # handed to the engine directly: submitted is its admission
@@ -105,7 +110,9 @@ def test_request_phases_in_order_and_sum_for_a_request_behind_another(
     assert behind.phases()["prefill_wait"] > first.phases()["prefill_wait"]
     assert (first.prefill_chunks, behind.prefill_chunks) == (3, 2)
     ring = {r[0]: r for r in eng.stats.requests}
-    assert ring[behind.submitted][1:] == (*behind.phases().values(), 2)
+    # the six fields a reader by index knows, the new phases behind them
+    engine_phases = [behind.phases()[n] for n in REQUEST_PHASES[2:]]
+    assert ring[behind.submitted][1:] == (*engine_phases, 2, 0.0, 0.0)
 
 
 # --------------------------------------------------------- EngineStats
@@ -408,6 +415,7 @@ def test_generate_sends_a_span_only_under_a_sampled_trace(
     assert all(p >= 0.0 for p in phases)
     assert sum(phases) == pytest.approx(
         record["end"] - record["start"], abs=1e-4)
+    assert float(attrs["first_token_handoff_s"]) > 0.0
 
 
 def test_engine_stats_call_returns_the_snapshot(llm_server):
@@ -421,7 +429,312 @@ def test_engine_stats_call_returns_the_snapshot(llm_server):
     eng = after["engine"]
     assert eng["requests_finished"] == 1 and eng["tokens_emitted"] == 4
     assert eng["prefill_tokens"] == 39
-    [(submitted, *phases, chunks)] = eng["requests"]
+    [(submitted, *phases, chunks, ingress, accept)] = eng["requests"]
     assert submitted > 0 and len(phases) == 4 and chunks == 1
-    assert after["loop_phases"]["llm.admit"]["count"] == 1
-    assert after["loop_phases"]["llm.emit"]["count"] >= 3
+    # called in-process: no handle routed it, no replica received it
+    assert (ingress, accept) == (0.0, 0.0)
+    loop = after["loop_phases"]
+    assert loop["llm.admit"]["count"] == 1
+    assert loop["llm.emit"]["count"] >= 3
+    # the request's own thread folded its time outside the engine in
+    assert loop["serve.ingress"] == {"seconds": 0.0, "count": 1}
+    assert loop["llm.accept"] == {"seconds": 0.0, "count": 1}
+    assert loop["llm.first_token_handoff"]["count"] == 1
+    assert loop["llm.token_handoff"]["count"] == 4
+    assert (loop["llm.token_handoff"]["seconds"]
+            >= loop["llm.first_token_handoff"]["seconds"] > 0.0)
+
+
+# ------------------------------------- the serve stack around the engine
+class _StampServer:
+    """Mixed into ``LLMServer`` in the replica: keeps every request's
+    stamps as its thread folds them, for the test to read."""
+
+    def _fold_request(self, req):
+        super()._fold_request(req)
+        if not hasattr(self, "folded"):
+            self.folded = []
+        self.folded.append({
+            "stamps": [req.routed, req.received, req.submitted, req.admitted,
+                       req.prefill_started, req.first_token,
+                       req.first_yielded, req.finished],
+            "phases": req.phases(), "tokens": len(req.generated),
+            "handoff": (req.handoff_s, req.handoff_n)})
+
+    def folded_requests(self):
+        return list(getattr(self, "folded", []))
+
+
+def _serve_tiny_llm(name):
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.llm import build_llm_app
+    from ray_tpu.llm.serve import LLMServer
+
+    ray_tpu.init(num_cpus=4, max_workers=4, ignore_reinit_error=True)
+    server_cls = type("StampLLMServer", (_StampServer, LLMServer), {})
+    app = build_llm_app(
+        LLMConfig(model_config=tiny_cfg(), max_batch_size=2, max_seq_len=64,
+                  accelerator_type=""),
+        name=name, server_cls=server_cls)
+    return serve.run(app, name=name)
+
+
+def _served_routes(name):
+    """{requests, latency count} of the deployment's direct-handle route,
+    once the hub's registry has them."""
+    from ray_tpu.util import state as state_api
+
+    dep = state_api.summarize_serve()["deployments"].get(name) or {}
+    route = dep.get("routes", {}).get("", {})
+    return {"requests": route.get("requests", 0),
+            "latency": (route.get("latency_s") or {}).get("count", 0)}
+
+
+def _wait_for(read, want, timeout_s=15.0):
+    import time
+
+    deadline = time.monotonic() + timeout_s
+    while (got := read()) != want and time.monotonic() < deadline:
+        time.sleep(0.1)
+    return got
+
+
+STREAMS, TOKENS = 3, 5
+
+
+@pytest.fixture(scope="module")
+def streamed():
+    """A tiny LLM behind ``serve.run``: STREAMS requests of TOKENS tokens
+    through a streaming handle, then one unary call and one old-style
+    call of the replica's streaming method with no ``request_meta``."""
+    import time
+
+    import ray_tpu
+    from ray_tpu import serve
+
+    handle = _serve_tiny_llm("llm-stamps")
+    stream = handle.options(method_name="generate_stream", stream=True)
+    list(stream.remote(list(range(1, 12)), 2))            # warm the path
+    stats0 = stream.stream_stats()
+    engine0 = handle.engine_stats.remote().result()
+    folded0 = len(handle.folded_requests.remote().result())
+    # the warming stream and the two unary calls above
+    routes0 = _wait_for(lambda: _served_routes("llm-stamps"),
+                        {"requests": 3, "latency": 3})
+    durations, answers = [], []
+    for i in range(STREAMS):
+        t0 = time.monotonic()
+        answers.append(list(stream.remote(list(range(1, 20 + i)), TOKENS)))
+        durations.append(time.monotonic() - t0)
+    routes1 = _wait_for(lambda: _served_routes("llm-stamps"),
+                        {"requests": 3 + STREAMS, "latency": 3 + STREAMS})
+    out = {
+        "answers": answers, "durations": durations,
+        "routes": (routes0, routes1),
+        "stream_stats": (stats0, stream.stream_stats()),
+        # an options() view shares the handle's
+        "stream_stats_of_handle": handle.stream_stats(),
+        "engine": (engine0, handle.engine_stats.remote().result()),
+        "folded": handle.folded_requests.remote().result()[folded0:],
+    }
+    # a unary call is stamped too
+    unary = handle.remote({"prompt_ids": list(range(1, 20)),
+                           "max_tokens": TOKENS}).result()
+    out["unary"] = (unary, handle.folded_requests.remote().result()[-1])
+    # a caller from before request_meta: four positional arguments
+    handle._refresh(force=True)
+    [replica] = handle._replicas
+    old_style = replica.handle_request_streaming.options(
+        num_returns="streaming").remote(
+            "generate_stream", (list(range(1, 20)), TOKENS), {}, "")
+    out["old_style"] = ([ray_tpu.get(ref) for ref in old_style],
+                        handle.folded_requests.remote().result()[-1])
+    yield out
+    serve.shutdown()
+    ray_tpu.shutdown()
+
+
+def _loop_delta(streamed, name):
+    before, after = (e["loop_phases"] for e in streamed["engine"])
+    was = before.get(name, {"seconds": 0.0, "count": 0})
+    return {k: after[name][k] - was[k] for k in ("seconds", "count")}
+
+
+def _stamps_in_order(streamed):
+    assert len(streamed["folded"]) == STREAMS
+    for req in streamed["folded"]:
+        assert all(s > 0 for s in req["stamps"])
+        assert req["stamps"] == sorted(req["stamps"])
+        routed, received, submitted = req["stamps"][:3]
+        assert routed < received < submitted      # another process sent it
+
+
+def _phases_sum_to_finished_minus_routed(streamed):
+    for req in streamed["folded"]:
+        assert tuple(req["phases"]) == REQUEST_PHASES
+        assert all(v >= 0.0 for v in req["phases"].values())
+        assert sum(req["phases"].values()) == pytest.approx(
+            req["stamps"][-1] - req["stamps"][0], abs=1e-9)
+
+
+def _loop_phases_count_a_request_and_a_token(streamed):
+    for name in ("serve.ingress", "llm.accept", "llm.first_token_handoff"):
+        assert _loop_delta(streamed, name)["count"] == STREAMS, name
+    assert _loop_delta(streamed, "llm.token_handoff")["count"] == (
+        STREAMS * TOKENS)
+    folded = streamed["folded"]
+    assert [r["handoff"][1] for r in folded] == [TOKENS] * STREAMS
+    assert _loop_delta(streamed, "llm.token_handoff")["seconds"] == (
+        pytest.approx(sum(r["handoff"][0] for r in folded)))
+    assert _loop_delta(streamed, "serve.ingress")["seconds"] == (
+        pytest.approx(sum(r["phases"]["ingress"] for r in folded)))
+    assert _loop_delta(streamed, "llm.first_token_handoff")["seconds"] == (
+        pytest.approx(sum(r["stamps"][6] - r["stamps"][5] for r in folded)))
+    # the loop's own parts are still there beside them
+    assert {"llm.admit", "llm.emit"} <= set(streamed["engine"][1][
+        "loop_phases"])
+
+
+def _ring_rows_keep_their_six_fields_and_gain_two(streamed):
+    rows = streamed["engine"][1]["engine"]["requests"][-STREAMS:]
+    for row, req in zip(rows, streamed["folded"]):
+        p = req["phases"]
+        assert row[0] == req["stamps"][2]                  # submitted
+        assert tuple(row[1:5]) == tuple(p[n] for n in REQUEST_PHASES[2:])
+        assert row[5] >= 1                                 # prefill chunks
+        assert tuple(row[6:]) == (p["ingress"], p["accept"])
+
+
+def _stream_stats_count_a_token(streamed):
+    before, after = streamed["stream_stats"]
+    assert after == streamed["stream_stats_of_handle"]
+    all_items = (after["serve.stream_transit"]["count"]
+                 - before["serve.stream_transit"]["count"])
+    firsts = (after["serve.stream_first_transit"]["count"]
+              - before["serve.stream_first_transit"]["count"])
+    assert (all_items, firsts) == (STREAMS * TOKENS, STREAMS)
+    assert [len(a) for a in streamed["answers"]] == [TOKENS] * STREAMS
+    seconds = (after["serve.stream_transit"]["seconds"]
+               - before["serve.stream_transit"]["seconds"])
+    first_seconds = (after["serve.stream_first_transit"]["seconds"]
+                     - before["serve.stream_first_transit"]["seconds"])
+    # every reading is >= 0, so the first items' sum is under the sum,
+    # and a token's transit lies inside its request
+    assert 0.0 <= first_seconds <= seconds
+    assert seconds / all_items < min(streamed["durations"])
+    assert seconds < sum(streamed["durations"])
+
+
+def _a_streamed_request_is_counted_and_timed(streamed):
+    before, after = streamed["routes"]
+    assert after["requests"] - before["requests"] == STREAMS
+    assert after["latency"] - before["latency"] == STREAMS
+
+
+def _a_unary_call_is_stamped_too(streamed):
+    answer, req = streamed["unary"]
+    assert answer["num_generated"] == TOKENS == req["tokens"]
+    routed, received, submitted = req["stamps"][:3]
+    assert 0 < routed < received < submitted
+    assert answer["token_ids"] == streamed["answers"][0]   # the same prompt
+
+
+def _an_old_style_call_still_streams(streamed):
+    tokens, req = streamed["old_style"]
+    assert tokens == streamed["answers"][0]
+    # nobody stamped its route: ingress reads 0.0, accept is the replica's
+    assert req["phases"]["ingress"] == 0.0 and req["phases"]["accept"] > 0.0
+
+
+@pytest.mark.parametrize("check", [
+    _stamps_in_order,
+    _phases_sum_to_finished_minus_routed,
+    _loop_phases_count_a_request_and_a_token,
+    _ring_rows_keep_their_six_fields_and_gain_two,
+    _stream_stats_count_a_token,
+    _a_streamed_request_is_counted_and_timed,
+    _a_unary_call_is_stamped_too,
+    _an_old_style_call_still_streams,
+], ids=lambda f: f.__name__.strip("_"))
+def test_a_served_requests_time_outside_the_engine(streamed, check):
+    check(streamed)
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_a_streamed_item_carries_its_yield_stamp(monkeypatch, shards):
+    """STREAM_YIELD's ``t_wall`` comes back on the STREAM_NEXT reply,
+    whether the consumer asked before the item was there (the yield's
+    waiters) or after (the index lookup), single hub and sharded."""
+    import time
+
+    import ray_tpu
+
+    monkeypatch.setenv("RAY_TPU_HUB_SHARDS", str(shards))
+    ray_tpu.init(num_cpus=2, max_workers=2, ignore_reinit_error=True)
+    try:
+        @ray_tpu.remote(num_returns="streaming")
+        def slow_then_fast():
+            time.sleep(0.3)      # the consumer is waiting by then
+            yield 0
+            yield 1
+            yield 2
+
+        gen = slow_then_fast.remote()
+        assert gen.last_yield_wall is None
+        t0 = tracing.wall_at(time.monotonic())
+        stamps = []
+        for i, ref in enumerate(gen):
+            if i == 1:
+                time.sleep(0.3)  # items 1 and 2 are there before the ask
+            stamps.append(gen.last_yield_wall)
+            assert ray_tpu.get(ref) == i
+        now = tracing.wall_at(time.monotonic())
+        assert len(stamps) == 3 and stamps == sorted(stamps)
+        assert t0 < stamps[0] and stamps[-1] < now
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_a_sampled_stream_parents_under_serve_execute(monkeypatch):
+    """A streamed request under a sampled trace leaves serve.route,
+    serve.queue_wait and serve.execute as a unary call does, and its
+    llm.request span parents under serve.execute."""
+    import time
+
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu._private import worker
+
+    monkeypatch.setenv("RAY_TPU_TRACING", "1")
+    handle = _serve_tiny_llm("llm-sampled")
+    try:
+        stream = handle.options(method_name="generate_stream", stream=True)
+        assert len(list(stream.remote(list(range(1, 20)), TOKENS))) == TOKENS
+        client = worker.get_client()
+        want = {"serve.route", "serve.queue_wait", "serve.execute",
+                "llm.request"}
+        deadline, spans = time.monotonic() + 20, []
+        while time.monotonic() < deadline:
+            for row in client.list_state("traces"):
+                spans = client.list_state("traces", trace_id=row["trace_id"])
+                if want <= {s["name"] for s in spans}:
+                    break
+            else:
+                time.sleep(0.1)
+                continue
+            break
+        by_name = {s["name"]: s for s in spans}
+        assert want <= set(by_name), sorted(by_name)
+        request, execute = by_name["llm.request"], by_name["serve.execute"]
+        assert request["parent_id"] == execute["span_id"]
+        assert by_name["serve.queue_wait"]["parent_id"] == execute["parent_id"]
+        attrs = request["attrs"]
+        for phase_name in (*REQUEST_PHASES, "first_token_handoff"):
+            assert float(attrs[f"{phase_name}_s"]) >= 0.0
+        assert float(attrs["ingress_s"]) > 0.0
+        # the streamed body lies inside serve.execute
+        assert execute["start"] <= request["end"] <= execute["end"] + 1e-3
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
