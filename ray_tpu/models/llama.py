@@ -17,7 +17,10 @@ torch translation:
   ffn-hidden, Megatron-style, with XLA GSPMD inserting the collectives.
 - GQA (n_kv_heads < n_heads), RoPE, RMSNorm, SwiGLU — Llama-2/3
   architecture. ``jax.checkpoint`` (remat) wraps each block when
-  ``config.remat`` so activations are recomputed in backward.
+  ``config.remat``: the backward recomputes the block's activations
+  from its input, all but the flash kernel's output and row statistics,
+  which are kept by name (``remat_policy``) so the forward kernel runs
+  once a layer. Attention paths without the kernel keep nothing.
 
 No reference-code lineage: the reference (Ray) ships no transformer;
 this exists so the framework's Train/Serve/Data stacks have a real
@@ -310,6 +313,19 @@ def block_fn(config: LlamaConfig, x: jax.Array, layer: Dict[str, jax.Array],
     return mlp_sublayer(config, x, layer)
 
 
+def remat_policy():
+    """What a checkpointed block keeps for its backward: the two
+    residuals the flash kernel's forward rule names (its output and its
+    row statistics, B x S x H x (hd + 2) elements a layer), so the
+    backward does not run the forward kernel a second time; everything
+    else is recomputed. A block in which the kernel did not run (xla or
+    ring attention, the CPU's blockwise fallback) carries no such name
+    and keeps nothing."""
+    from ray_tpu.ops.pallas_attention import SAVED_NAMES
+
+    return jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+
+
 def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
                    config: LlamaConfig) -> jax.Array:
     """tokens (B, S) int32 → final-norm hidden states (B, S, D) in
@@ -322,9 +338,7 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
 
     blk = partial(block_fn, c)
     if c.remat:
-        blk = jax.checkpoint(
-            blk, policy=jax.checkpoint_policies.nothing_saveable
-        )
+        blk = jax.checkpoint(blk, policy=remat_policy())
 
     def scan_body(carry, layer):
         return blk(carry, layer, cos, sin), None

@@ -30,6 +30,7 @@ from .llama import (
     init_attn_params,
     make_dense_init,
     masked_ce,
+    remat_policy,
     rms_norm,
     rope_table,
     unpack_batch,
@@ -171,9 +172,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
 
     blk = partial(block_fn, c)
     if c.remat:
-        blk = jax.checkpoint(
-            blk, policy=jax.checkpoint_policies.nothing_saveable
-        )
+        blk = jax.checkpoint(blk, policy=remat_policy())
 
     def scan_body(carry, layer):
         x, aux_sum = carry

@@ -67,8 +67,13 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# ``jax.ad_checkpoint.checkpoint_name`` tags of the forward kernel's two
+# results, o and lse, which the backward kernels take as residuals
+SAVED_NAMES = ("flash_out", "flash_lse")
 
 _LANES = 128
 _NEG_INF = float("-inf")
@@ -599,7 +604,10 @@ def _flash(q, k, v, causal, block_q, block_kv):
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_kv):
-    o, lse = _fwd(q, k, v, causal, block_q, block_kv)
+    # named so that a caller's ``jax.checkpoint`` can keep the two and
+    # its backward not run ``_fwd`` again
+    o, lse = map(checkpoint_name, _fwd(q, k, v, causal, block_q, block_kv),
+                 SAVED_NAMES)
     return o, (q, k, v, o, lse)
 
 

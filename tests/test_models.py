@@ -140,3 +140,124 @@ def test_ring_attention_impl_matches_xla():
     ref = llama.forward(params, toks, cfg)
     out = llama.forward(params, toks, cfg_ring)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-3)
+
+
+# ----------------------------------------------------------------------
+# remat keeps the flash kernel's output and row statistics by name
+# (PR 43): the backward does not run the forward kernel again
+# ----------------------------------------------------------------------
+
+# the smallest shapes the kernel tiles: head_dim 128, S = 128; G = 2 so
+# that k and v (B, KVH, S, hd) differ in shape from q and o (B, H, S, hd)
+_REMAT_CFG = llama.LlamaConfig(
+    vocab_size=256, dim=512, n_layers=2, n_heads=4, n_kv_heads=2,
+    ffn_dim=256, max_seq_len=128, rope_theta=10000.0, remat=True,
+    dtype=jnp.float32,
+)
+_NOTHING = jax.checkpoint_policies.nothing_saveable
+
+
+def _kept(fn, *args):
+    """Elements of each array ``fn``'s backward is handed besides
+    ``fn``'s own arguments, from ``print_saved_residuals``' lines
+    (``f32[4,4,128,128] named ...``). A residual kept inside a
+    shard_map is listed with its shards stacked: the same elements."""
+    import contextlib
+    import io
+    import math
+
+    from jax.ad_checkpoint import print_saved_residuals
+
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        print_saved_residuals(fn, *args)
+    return sorted(
+        math.prod(map(int, line[line.index("[") + 1:line.index("]")].split(",")))
+        for line in out.getvalue().splitlines()
+        if "from the argument" not in line)
+
+
+@pytest.mark.parametrize("impl,meshed", [
+    ("flash", False),
+    ("flash", True),   # the kernel inside shard_map, as fsdp runs it
+    ("ring", False),   # no seq axis: falls back to the flash kernel
+    ("xla", False),
+    ("xla", True),
+])
+def test_remat_keeps_flash_residuals_by_name(impl, meshed, monkeypatch):
+    import contextlib
+    import dataclasses
+    from functools import partial
+
+    from jaxpr_kernels import kernel_calls, scans
+    from ray_tpu import parallel
+
+    cfg = dataclasses.replace(_REMAT_CFG, attention_impl=impl)
+    B, S, H, hd = 4, 128, cfg.n_heads, cfg.head_dim
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (B, S + 1), 0, cfg.vocab_size)
+    mesh = parallel.make_mesh(fsdp=4, model=2) if meshed else None
+    if meshed:  # the program's devices are those its arguments live on
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        params = jax.device_put(params, NamedSharding(mesh, P()))
+        tokens = jax.device_put(tokens, parallel.batch_sharding(mesh))
+
+    def ambient():
+        return (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+                if meshed else contextlib.nullcontext())
+
+    def grad_fn(c):
+        def f(params, tokens):
+            with ambient():
+                return jax.value_and_grad(
+                    lambda p: llama.loss_fn(p, {"tokens": tokens}, c))(params)
+        return f
+
+    def block(policy):
+        blk = jax.checkpoint(partial(llama.block_fn, cfg), policy=policy)
+
+        def f(x, layer, cos, sin):
+            with ambient():
+                return blk(x, layer, cos, sin)
+        return f
+
+    def readings():
+        """[forward kernels, backward kernels] in each scan of the
+        gradient program that calls a kernel, what one checkpointed
+        block keeps, and the loss and gradients."""
+        f = grad_fn(cfg)
+        calls = [kernel_calls(body)
+                 for body in scans(jax.make_jaxpr(f)(params, tokens))]
+        calls = [[c["flash_attention_fwd"], c["flash_attention_bwd_dkv"]]
+                 for c in calls if c]
+        layer = jax.tree.map(lambda a: a[0], params["blocks"])
+        x = jnp.zeros((B, S, cfg.dim), cfg.dtype)
+        kept = _kept(block(llama.remat_policy()), x, layer,
+                     *llama.rope_table(cfg, S))
+        return calls, kept, jax.jit(f)(params, tokens)
+
+    calls, kept, (loss, grads) = readings()
+    monkeypatch.setattr(llama, "remat_policy", lambda: _NOTHING)
+    calls_parent, kept_parent, (loss_parent, grads_parent) = readings()
+    loss_plain, grads_plain = jax.jit(
+        grad_fn(dataclasses.replace(cfg, remat=False)))(params, tokens)
+
+    assert kept_parent == []  # the parent's block keeps its arguments alone
+    if impl != "xla":
+        # the forward scan, then the backward's: the parent's backward
+        # runs the forward kernel a second time
+        assert calls == [[1, 0], [0, 1]]
+        assert calls_parent == [[1, 0], [1, 1]]
+        # lse and o: one array of q's size, not two, none of k's and v's
+        assert kept == [B * H * S, B * H * S * hd]
+    else:
+        assert calls == calls_parent == []
+        assert kept == kept_parent
+
+    for other_loss, other in ((loss_parent, grads_parent), (loss_plain, grads_plain)):
+        np.testing.assert_allclose(loss, other_loss, rtol=1e-6)
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+            grads, other)

@@ -118,3 +118,45 @@ def test_pad_tokens_excluded_from_moe():
     # running the unpadded prefix alone
     _, aux_ref = moe_ffn(p, x[:, :4], cfg)
     np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
+
+
+def test_remat_keeps_flash_residuals_and_the_gradients():
+    """The MoE block is checkpointed under the dense model's policy
+    (``llama.remat_policy``): with the flash kernel in the shared
+    attention sublayer its backward runs no second forward kernel, and
+    loss and gradients are those of the program that keeps everything
+    and of the one that keeps nothing."""
+    import dataclasses
+
+    from jaxpr_kernels import kernel_calls
+    from ray_tpu.models import llama
+
+    # head_dim 128, S = 128: the smallest shapes the kernel tiles
+    cfg = dataclasses.replace(
+        MOE_TINY, dim=256, n_heads=2, n_kv_heads=1, remat=True,
+        attention_impl="flash", dtype=jnp.float32)
+    params = moe_llama.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (2, 129), 0, cfg.vocab_size)
+
+    def grad_fn(c):
+        return jax.value_and_grad(
+            lambda p: moe_llama.loss_fn(p, {"tokens": tokens}, c))
+
+    calls = kernel_calls(jax.make_jaxpr(grad_fn(cfg))(params))
+    assert calls["flash_attention_fwd"] == calls["flash_attention_bwd_dkv"] == 1
+    loss, grads = jax.jit(grad_fn(cfg))(params)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe_llama, "remat_policy",
+                      lambda: jax.checkpoint_policies.nothing_saveable)
+        calls = kernel_calls(jax.make_jaxpr(grad_fn(cfg))(params))
+        assert calls["flash_attention_fwd"] == 2
+        others = [jax.jit(grad_fn(cfg))(params)]
+    others.append(jax.jit(grad_fn(dataclasses.replace(cfg, remat=False)))(params))
+    for other_loss, other in others:
+        np.testing.assert_allclose(loss, other_loss, rtol=1e-6)
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+            grads, other)
+    assert moe_llama.remat_policy is llama.remat_policy

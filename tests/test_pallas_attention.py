@@ -185,3 +185,38 @@ def test_streamed_by_shape():
     assert not pallas_attention._resident(S, hd, 4)
     assert pallas_attention._resident(2048, 128, 2)
     _check(*_qkv(7, B, S, S, H, H, hd), True, 512, 512)
+
+
+# ----------------------------------------------------------------------
+# the forward rule names its two residuals (PR 43): a caller's
+# jax.checkpoint may keep them and spare the second forward kernel
+# ----------------------------------------------------------------------
+
+@both_forms
+def test_named_residuals_spare_the_second_forward(form):
+    from jaxpr_kernels import kernel_calls
+
+    policies = jax.checkpoint_policies
+    q, k, v = _qkv(8, 1, 256, 256, 4, 2)
+
+    def grads(policy):
+        attend = jax.checkpoint(
+            lambda q, k, v: pallas_flash_attention(q, k, v, True),
+            policy=policy)
+        grad = jax.grad(lambda *qkv: jnp.sum(jnp.sin(attend(*qkv))),
+                        argnums=(0, 1, 2))
+        return kernel_calls(jax.make_jaxpr(grad)(q, k, v)), grad(q, k, v)
+
+    calls, kept = grads(
+        policies.save_only_these_names(*pallas_attention.SAVED_NAMES))
+    calls_all, recomputed = grads(policies.nothing_saveable)
+    backward = {"flash_attention_bwd_dkv": 1, "flash_attention_bwd_dq": 1}
+    assert calls == {"flash_attention_fwd": 1, **backward}
+    assert calls_all == {"flash_attention_fwd": 2, **backward}
+    # the kept o and lse are the ones a second forward would produce
+    for a, b in zip(kept, recomputed):
+        np.testing.assert_array_equal(a, b)
+    # each name alone keeps one residual of the two: the kernel runs again
+    for name in pallas_attention.SAVED_NAMES:
+        calls_one, _ = grads(policies.save_only_these_names(name))
+        assert calls_one["flash_attention_fwd"] == 2, name
