@@ -44,31 +44,155 @@ def _new_counters() -> dict:
 POSITIONS, DECODE_STEPS = 32, 16
 
 
+def room_for_a_copy(cache) -> None:
+    """``probe_rows`` keeps one copy of the cache alive beside the real
+    one, so on every chip it wants as many bytes free as the cache holds
+    there, and a program's temporaries over that, as any call does.
+    Where a chip says how much it has (``memory_stats``; the CPU of a
+    rehearsal says nothing) and that is less, this raises and says so,
+    before the first call: an allocation that failed inside a program
+    would read as a run that failed, not as a cell whose comparison has
+    no room. README.md, "A served family", has the sum a cell's author
+    makes: weights + 2 x cache + temporaries, and then the reference's
+    float32 pass beside the weights and one cache."""
+    import jax
+
+    held = {}
+    for leaf in jax.tree_util.tree_leaves(cache):
+        for part in leaf.addressable_shards:
+            held[part.device] = held.get(part.device, 0) + part.data.nbytes
+    for device, need in held.items():
+        stats = device.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            continue
+        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+        if free < need:
+            raise RuntimeError(
+                f"reference_check: the probe reads its rows on a copy of the "
+                f"cache, {need} bytes on {device}, and {free} of "
+                f"{stats['bytes_limit']} are free there: the cell's weights "
+                "and two caches have to fit the chip (benchmarks/README.md, "
+                "'A served family')")
+
+
+def probe_rows(eng, seq, positions: int, decode_steps: int):
+    """The seeded probe ``seq`` through the engine's own jitted
+    ``_prefill`` and ``_decode``, into slot 0 of its first cache shard;
+    the engine must be idle. Returns the logits of the probe's last
+    ``positions`` rows (the last first), the tokens the probe's last row
+    and then ``decode_steps`` greedy decodes chose, and the logits of the
+    row behind each decode, all float32 on the host.
+
+    The programs return logits only here, so most of these calls are
+    made only to read a row, and a call that a request would not make
+    may not leave a trace: **every read-only call runs on a copy of the
+    cache, and the cache it returns is dropped; the calls a request
+    would make advance the real cache, once each.** No call is ever
+    repeated on a cache that holds its result: rows a position would
+    survive that (the same values written again), a recurrent state or a
+    convolution's tail would not. The copy is a device copy of every
+    leaf of ``shard.cache``, whatever the pytree holds, made before the
+    call because the programs donate their cache; nothing of a family,
+    a leaf's layout or a slot's axis is known here.
+
+    *Prefill.* The whole chunks go in as a prompt's do. Before the last
+    chunk goes in, each of the ``positions`` - 1 calls with ``length``
+    shortened by 1, 2, ... (the chunk's tokens at its start into the
+    same slot, returning the logits of its last real row) runs on a copy
+    of the cache as it is then; the call with all of the chunk's tokens
+    then runs on the real cache. Each copy is waited for and dropped
+    before the next is made: the real cache and one copy are the most
+    alive at once (``room_for_a_copy`` asks for that room first).
+    *Decode.* Each greedy step advances the real cache;
+    the one-token ``_prefill`` of the token it chose, at its position,
+    which returns the logits the next step chooses from and attends to
+    every row the decodes wrote, runs on a copy of the cache the decode
+    returned; the next decode feeds that token to the real cache, which
+    has not seen it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    length = len(seq)
+    starts = range(0, length, eng.prefill_chunk)
+    tail = length - starts[-1]          # the last chunk's real tokens
+    onehot = np.zeros(eng.max_batch, np.float32)
+    onehot[0] = 1.0
+    shard = eng.shards[0]
+
+    def prefill(tokens, pos, real=None, scratch=False):
+        """One call: on the real cache, which it advances (dispatched,
+        the logits left on the device), or, ``scratch``, on a copy of it,
+        which is dropped, and then waited for, so that no second copy is
+        made while this one is alive."""
+        bucket = next(b for b in eng.buckets if b >= len(tokens))
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :len(tokens)] = tokens
+        logits, cache = eng._prefill(
+            eng.params, jax.tree_util.tree_map(jnp.copy, shard.cache)
+            if scratch else shard.cache, padded, onehot,
+            np.asarray([pos], np.int32), real or len(tokens), bucket=bucket)
+        if not scratch:
+            shard.cache = cache
+            return logits
+        del cache
+        return jax.block_until_ready(logits)
+
+    def fetched(rows):
+        return np.stack([np.asarray(x, np.float32) for x in rows])
+
+    with eng._lock:
+        if eng.num_active():
+            raise RuntimeError("reference_check needs an idle engine")
+        room_for_a_copy(shard.cache)
+        for pos in starts[:-1]:
+            prefill(seq[pos:pos + eng.prefill_chunk], pos)
+        shortened = [
+            prefill(seq[starts[-1]:], starts[-1], real=tail - back,
+                    scratch=True) for back in range(1, positions)]
+        got_prefill = fetched(
+            [prefill(seq[starts[-1]:], starts[-1])] + shortened)
+        chosen = [int(got_prefill[0].argmax())]
+        lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
+        temps = np.zeros(eng.max_batch, np.float32)
+        got_after = []
+        for i in range(decode_steps):
+            tokens = np.zeros(eng.max_batch, np.int32)
+            tokens[0], lens[0] = chosen[-1], length + i
+            toks, shard.cache, eng._rng = eng._decode(
+                eng.params, shard.cache, tokens, lens, temps, eng._rng)
+            chosen.append(int(np.asarray(toks)[0]))
+            got_after.append(prefill(chosen[-1:], length + i + 1,
+                                     scratch=True))
+        got_after = fetched(got_after)
+    return got_prefill, chosen, got_after
+
+
 def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
-    """The engine's own jitted ``_prefill`` and ``_decode``, into slot 0
-    of its first cache shard, against the family's float32
+    """``probe_rows`` of the engine against the family's float32
     ``reference_logits`` over the same tokens, read at many positions
     (``check``: the cell's ``reference_check`` block). The engine must
     be idle.
 
-    *Prefill, through the cache.* ``length`` seeded tokens go in chunk
-    by chunk as a prompt does; the probe is at least one whole chunk
-    plus ``positions``, so the compared chunk attends to rows an earlier
-    call wrote. The last chunk is then dispatched again ``positions`` - 1
-    times with its ``length`` argument shortened by 1, 2, ...: the same
-    tokens at the same start into the same slot, so the same rows are
-    written, and each call returns the logits of its last real row.
-    ``prefill_rel_rms`` holds the relative RMS of each against the
-    reference's row, the probe's last position first.
+    *Prefill, through the cache.* ``length`` seeded tokens; the probe is
+    at least one whole chunk plus ``positions``, so the compared chunk
+    attends to rows (or starts from a state) an earlier call left.
+    ``prefill_rel_rms`` holds the relative RMS of the logits of each of
+    the probe's last ``positions`` rows against the reference's row, the
+    probe's last position first.
 
     *Decode.* ``decode_steps`` greedy steps. The decode program returns
     tokens, not logits, so a step is judged twice: by how far the token
     it chose lies under the reference's largest logit at that position
-    (``decode_choice_gap``), and through the cache rows it wrote: after
-    each step a one-token ``_prefill`` of the token just chosen, at its
-    position, returns the logits the next step chooses from and attends
-    to every row the decodes wrote (``after_decode_rel_rms``). The next
-    decode writes that row again, the same token at the same position.
+    (``decode_choice_gap``), and through what it left in the cache: the
+    logits of the row behind it (``after_decode_rel_rms``).
+
+    What the engine is held to: a ``_prefill`` call leaves the slot as a
+    sequence of ``start + length`` tokens, and whatever it wrote behind
+    them is never read; a call with ``start`` 0 begins a sequence and
+    reads nothing the slot held; a ``_decode`` leaves a live lane one
+    token longer and may leave an idle lane's slot in any state; no call
+    is ever repeated on a cache that holds its result (``probe_rows``).
     """
     import numpy as np
 
@@ -84,53 +208,19 @@ def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
             f"{eng.prefill_chunk} ends in a chunk of {tail} behind "
             f"{len(starts) - 1} whole one(s); it needs one whole chunk and "
             f"then {positions} positions or more in the last")
-    # the one-token prefill behind the last decode writes a whole
+    # the one-token prefill behind the last decode computes a whole
     # bucket of rows, and one that ran past the end would be moved back
+    # and read its row's logits from the wrong rows
     if length + decode_steps + eng.buckets[0] > eng.max_seq:
         raise ValueError(
             f"reference_check: {length} tokens, {decode_steps} decode steps "
             f"and a bucket of {eng.buckets[0]} rows do not fit the cache's "
             f"{eng.max_seq} rows")
-    onehot = np.zeros(eng.max_batch, np.float32)
-    onehot[0] = 1.0
-    shard = eng.shards[0]
     t0 = time.perf_counter()
-
-    def prefill(tokens, pos, real=None):
-        """Dispatches one call; the logits stay on the device."""
-        bucket = next(b for b in eng.buckets if b >= len(tokens))
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(tokens)] = tokens
-        logits, shard.cache = eng._prefill(
-            eng.params, shard.cache, padded, onehot,
-            np.asarray([pos], np.int32), real or len(tokens), bucket=bucket)
-        return logits
-
-    def fetched(rows):
-        return np.stack([np.asarray(x, np.float32) for x in rows])
-
-    with eng._lock:
-        if eng.num_active():
-            raise RuntimeError("reference_check needs an idle engine")
-        for pos in starts:
-            last = prefill(seq[pos:pos + eng.prefill_chunk], pos)
-        got_prefill = [last] + [
-            prefill(seq[starts[-1]:], starts[-1], real=tail - back)
-            for back in range(1, positions)]
-        got_prefill = fetched(got_prefill)
-        chosen = [int(got_prefill[0].argmax())]
-        lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
-        temps = np.zeros(eng.max_batch, np.float32)
-        got_after = []
-        for i in range(decode_steps):
-            tokens = np.zeros(eng.max_batch, np.int32)
-            tokens[0], lens[0] = chosen[-1], length + i
-            toks, shard.cache, eng._rng = eng._decode(
-                eng.params, shard.cache, tokens, lens, temps, eng._rng)
-            chosen.append(int(np.asarray(toks)[0]))
-            got_after.append(prefill(chosen[-1:], length + i + 1))
-        got_after = fetched(got_after)
+    got_prefill, chosen, got_after = probe_rows(eng, seq, positions,
+                                                decode_steps)
     engine_s = time.perf_counter() - t0
+    engine_peak = holder.memory_peak_bytes()
 
     # the reference's rows: positions length - positions .. length +
     # decode_steps, the last of them the row behind the last decode
@@ -156,6 +246,9 @@ def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
         "finite": bool(np.isfinite(got_prefill).all()
                        and np.isfinite(got_after).all()),
         "engine_s": engine_s,
+        # the fullest chip's peak once the probe's calls are through,
+        # the real cache and its copies among them
+        "engine_memory_peak_bytes": engine_peak,
         "total_s": time.perf_counter() - t0,
     }
 

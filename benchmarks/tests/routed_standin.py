@@ -1,13 +1,27 @@
 """A routed family's arithmetic, and the two programs an engine has of
 it: dense leading layers and then routed ones, each a pre-norm causal
-latent attention (queries and keys / values through a normed low-rank
-bottleneck, a rotary part beside it where ``rope_dim`` is set, so that
-its logits are as flat at these seeded weights as such a model's are)
-and then a dense SwiGLU or a top-k mixture of SwiGLU experts of which
-this chip holds a few (sigmoid or softmax scores, a correction bias in
-the selection, groups of experts of which the best few are kept by the
-sum of their two best scores, the chosen weights renormalised and
-scaled, a shared expert), an embedding and a head.
+mixer over the positions and then a dense SwiGLU or a top-k mixture of
+SwiGLU experts of which this chip holds a few (sigmoid or softmax
+scores, a correction bias in the selection, groups of experts of which
+the best few are kept by the sum of their two best scores, the chosen
+weights renormalised and scaled, a shared expert), an embedding and a
+head. ``pattern`` says which mixer each layer of a period has:
+
+``L``  latent attention: queries (through a normed low-rank bottleneck
+       where ``q_rank`` is set) and keys / values through a normed
+       low-rank bottleneck, a second part of ``rope_dim`` beside each
+       head's (turned by its row's position unless ``rotary`` is 0), so
+       that its logits are as flat at these seeded weights as such a
+       model's are. What a slot keeps of it is a row a position.
+``S``  a gated delta rule over a state: q, k and v through a causal
+       depthwise convolution of 4 rows and SiLU, q and k of unit length
+       a head, a decay a channel from a gate of rank ``state_dim``, a
+       beta a head; ``S_t = (I - b k k^T) diag(a) S_{t-1} + b k v^T``,
+       ``o = S_t^T q``; each head's output normed, gated by a sigmoid
+       from a second such gate, and projected. What a slot keeps of it
+       is one float32 state ``(heads, dk, dv)`` and the convolution's
+       last 3 input rows, whatever its length: a call made
+       twice moves it twice (``server.probe_rows`` makes none twice).
 
 ``forward`` is one function of seeded bf16 weights over whole sequences,
 evaluated in float32 at ``highest`` (``reference_logits``: what a
@@ -23,18 +37,23 @@ position; ``reach`` makes a flip on purpose and reads the rows behind.
 ``Engine`` is what ``benchmarks/server.py`` ``reference_readings`` holds
 of an engine and nothing more of one (benchmarks/README.md, "A served
 family", has the list): ``_prefill`` of one chunk of one sequence into a
-slot of a latent bf16 cache with ``length`` traced, ``_decode`` of one
-greedy token a lane, attention in the absorbed form over the cached
-latent rows (another order of summation than ``forward``'s, as an
-engine's is). No scheduler and no shards: ``serve`` is a loop of decodes
-over the lanes, which gives served tokens for ``served_readings``.
+slot of its cache with ``length`` traced, ``_decode`` of one greedy
+token a lane; attention in the absorbed form over the cached latent rows
+(another order of summation than ``forward``'s, as an engine's is), a
+state layer's state and convolution tail carried through the chunk's
+real rows and left alone by the rows at or behind ``length``, both
+zeroed by a call that starts a sequence. No scheduler and no shards:
+``serve`` is a loop of decodes over the lanes, which gives served tokens
+for ``served_readings``.
 
 Imports nothing of ``ray_tpu``, of ``benchmarks`` nor of any family.
-``serving_control.py standin`` reads it on the chip at ``CHIP``'s and
-``CHIP_GROUPED``'s widths; ``test_serving_reference.py`` keeps it at
-``TOY``'s and ``TOY_GROUPED``'s.
+``serving_control.py standin`` reads it on the chip at ``CHIP``'s,
+``CHIP_GROUPED``'s and ``CHIP_HYBRID``'s widths;
+``test_serving_reference.py`` keeps it at ``TOY``'s, ``TOY_GROUPED``'s
+and ``TOY_HYBRID``'s.
 """
 
+import itertools
 import math
 import threading
 import types
@@ -46,9 +65,12 @@ import numpy as np
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
+# what a stack of latent attention alone states of the keys that came
+# with the state layers (a set of sizes from before them states none)
+NO_STATE = dict(pattern="L", rotary=1, state_heads=0, state_dim=0)
 # a routed decoder's widths as one of 32 chips that share each layer
 # holds them: 12 of 384 experts, an eighth of the vocabulary's rows
-CHIP = dict(width=7168, experts=384, expert_width=2048, top_k=8, held=12,
+CHIP = dict(NO_STATE, width=7168, experts=384, expert_width=2048, top_k=8, held=12,
             groups=1, groups_kept=1, layers=6, dense_layers=0, dense_width=0,
             vocab=20480, heads=64, head_dim=128, rope_dim=0, q_rank=1536,
             kv_rank=512, seq=2048, seqs=8, score="sigmoid", scale=2.827,
@@ -57,7 +79,7 @@ CHIP = dict(width=7168, experts=384, expert_width=2048, top_k=8, held=12,
 # of 256 experts (half of one of 8 groups, of which 4 are kept), a dense
 # leading layer, a rotary part of 64 beside each head's 128, an eighth
 # of the vocabulary: 5.5 G weights, 11 GB of bf16
-CHIP_GROUPED = dict(width=7168, experts=256, expert_width=2048, top_k=8,
+CHIP_GROUPED = dict(NO_STATE, width=7168, experts=256, expert_width=2048, top_k=8,
                     held=16, groups=8, groups_kept=4, layers=6,
                     dense_layers=1, dense_width=18432, vocab=16160,
                     heads=128, head_dim=128, rope_dim=64, q_rank=1536,
@@ -65,21 +87,55 @@ CHIP_GROUPED = dict(width=7168, experts=256, expert_width=2048, top_k=8,
                     scale=2.5, std=0.02)
 # a test's sizes: the weights wider, so that a sublayer still adds about
 # what the residual stream carries
-TOY = dict(width=128, experts=32, expert_width=64, top_k=4, held=4, groups=1,
+TOY = dict(NO_STATE, width=128, experts=32, expert_width=64, top_k=4, held=4, groups=1,
            groups_kept=1, layers=3, dense_layers=0, dense_width=0, vocab=128,
            heads=2, head_dim=16, rope_dim=0, q_rank=64, kv_rank=32, seq=64,
            seqs=8, score="sigmoid", scale=2.0, std=0.09)
 TOY_GROUPED = dict(TOY, groups=4, groups_kept=2, layers=4, dense_layers=1,
                    dense_width=256, rope_dim=8, seq=128)
+# a hybrid: three state layers to one of latent attention, as one of 4
+# chips that share each layer holds its first 8 layers: a dense leading
+# layer, 64 of 256 experts, a quarter of the vocabulary, the queries not
+# through a bottleneck and no part turned: 3.77 G weights, 7.5 GB of
+# bf16; a lane keeps 12.6 MB of state and 2304 B a token of latent rows
+CHIP_HYBRID = dict(width=2304, experts=256, expert_width=1024, top_k=8,
+                   held=64, groups=1, groups_kept=1, layers=8, dense_layers=1,
+                   dense_width=9216, vocab=40960, heads=32, head_dim=128,
+                   rope_dim=64, rotary=0, q_rank=0, kv_rank=512,
+                   pattern="SSSL", state_heads=32, state_dim=128, seq=1024,
+                   seqs=2, score="sigmoid", scale=2.446, std=0.02)
+# a test's: its three state layers are the dense leading ones, so that a
+# routed layer's flip is carried by latent attention alone and the rows
+# behind it read as a sound row does (through a state layer they do not:
+# PERF.md section 6, PR 44), and five routed layers behind them, so that
+# the control (their shared experts in fp8) reads over the sound rows'
+# noise, which eight layers spread wider than four
+TOY_HYBRID = dict(TOY, layers=8, dense_layers=3, dense_width=256, rope_dim=8,
+                  rotary=0, q_rank=0, pattern="SSSLLLLL", state_heads=2,
+                  state_dim=16, seq=128)
 SIZES = tuple(CHIP)     # the keys a set of sizes has
+CONV = 4                # rows a state layer's convolution spans
+KINDS = "LS"            # the mixers a pattern is written in
 
 
 def frozen(sizes: dict) -> tuple:
     """``sizes`` (or a configuration's dict that holds them, its
     ``vocab_size`` the vocabulary) as a jitted function's static
     argument."""
-    sizes = dict(sizes, vocab=sizes.get("vocab", sizes.get("vocab_size")))
+    sizes = dict(NO_STATE, **sizes)
+    sizes["vocab"] = sizes.get("vocab", sizes.get("vocab_size"))
     return tuple(sorted((k, sizes[k]) for k in SIZES))
+
+
+def kinds_of(s: dict) -> list:
+    """The mixer of each layer: ``pattern``, period after period."""
+    return [s["pattern"][i % len(s["pattern"])] for i in range(s["layers"])]
+
+
+def runs_of(s: dict) -> list:
+    """The routed layers in runs of one mixer: [(mixer, layers)]."""
+    return [(kind, len(list(run))) for kind, run in itertools.groupby(
+        kinds_of(s)[s["dense_layers"]:])]
 
 
 def key_of(seed: int):
@@ -148,29 +204,48 @@ def init_params(key, *, sizes):
     """Every weight from the key in one program, bf16 N(0, std) (the
     embedding N(0, 1); a dense layer's down projection narrower, so that
     the layer adds what an expert does; the router's correction bias
-    float32 N(0, 0.01)), an expert at a time so that no float32 copy of
-    a stack is ever alive: ``embed``, ``head``, ``dense`` (a list of
-    layers) and ``routed`` (the routed layers stacked)."""
+    float32 N(0, 0.01); a state layer's convolution N(0, 1 / 4), and
+    its decay's float32 ``rate``, a head, from 1 to 16 and ``dt_bias``,
+    a channel, the softplus' inverse of 0.001 to 0.1, both spread evenly
+    in the logarithm, as such layers start), an expert at a time so that
+    no float32 copy of a stack is ever alive: ``embed``, ``head``,
+    ``dense`` (a list of layers) and ``routed`` (a list of runs: the
+    routed layers that follow each other with one mixer, stacked)."""
     s = dict(sizes)
     d, f, hd, r = s["width"], s["expert_width"], s["head_dim"], s["rope_dim"]
-    attn = {"wq_a": (d, s["q_rank"]),
-            "wq_b": (s["q_rank"], s["heads"] * (hd + r)),
-            "wkv_a": (d, s["kv_rank"] + r),
-            "wkv_b": (s["kv_rank"], s["heads"] * 2 * hd),
-            "wo": (s["heads"] * hd, d)}
+    hs, c = s["state_heads"], s["state_heads"] * s["state_dim"]
+    queries = {"wq_a": (d, s["q_rank"]),
+               "wq_b": (s["q_rank"], s["heads"] * (hd + r))} if s["q_rank"] \
+        else {"wq": (d, s["heads"] * (hd + r))}
+    mixer = {"L": {**queries, "wkv_a": (d, s["kv_rank"] + r),
+                   "wkv_b": (s["kv_rank"], s["heads"] * 2 * hd),
+                   "wo": (s["heads"] * hd, d)},
+             "S": {"wqkv": (d, 3 * c), "wconv": (CONV, 3 * c),
+                   "wf_a": (d, s["state_dim"]), "wf_b": (s["state_dim"], c),
+                   "wbeta": (d, hs), "wg_a": (d, s["state_dim"]),
+                   "wg_b": (s["state_dim"], c), "wo": (c, d)}}
 
     def normal(k, shape, std=s["std"]):
         return (std * jax.random.normal(k, shape, F32)).astype(BF16)
 
-    def layer(k, more):
-        shapes = {**attn, **more}
+    def layer(k, kind, more):
+        shapes = {**mixer[kind], **more}
         keys = jax.random.split(k, len(shapes) + 2)
         w = {name: normal(kk, shape)
              for kk, (name, shape) in zip(keys, shapes.items())}
+        if kind == "S":
+            k_conv, k_rate, k_dt = jax.random.split(
+                jax.random.fold_in(k, len(shapes)), 3)
+            w["wconv"] = normal(k_conv, shapes["wconv"], 1.0 / CONV)
+            w["rate"] = jnp.exp(jax.random.uniform(
+                k_rate, (hs,), F32, 0.0, math.log(16.0)))
+            dt = jnp.exp(jax.random.uniform(
+                k_dt, (c,), F32, math.log(0.001), math.log(0.1)))
+            w["dt_bias"] = jnp.log(jnp.expm1(dt))
         return w, keys[-2], keys[-1]
 
-    def dense(k):
-        w, k_mlp, _ = layer(k, {})
+    def dense(k, kind):
+        w, k_mlp, _ = layer(k, kind, {})
         fd = s["dense_width"]
         ks = jax.random.split(k_mlp, 3)
         w["dense"] = jnp.stack([
@@ -178,20 +253,25 @@ def init_params(key, *, sizes):
             normal(ks[2], (d, fd), s["std"] * math.sqrt(f / fd))])
         return w
 
-    def routed(k):
-        w, k_held, k_bias = layer(k, {"router": (d, s["experts"]),
-                                      "shared": (3, d, f)})
+    def routed(k, kind):
+        w, k_held, k_bias = layer(k, kind, {"router": (d, s["experts"]),
+                                            "shared": (3, d, f)})
         w["held"] = jax.lax.map(lambda kk: normal(kk, (3, d, f)),
                                 jax.random.split(k_held, s["held"]))
         w["bias"] = 0.01 * jax.random.normal(k_bias, (s["experts"],), F32)
         return w
 
-    n_dense = s["dense_layers"]
+    n_dense, kinds = s["dense_layers"], kinds_of(s)
     keys = jax.random.split(key, s["layers"] + 2)
+    ends = list(itertools.accumulate([n for _, n in runs_of(s)],
+                                     initial=n_dense))
     return {"embed": normal(keys[-2], (s["vocab"], d), 1.0),
             "head": normal(keys[-1], (d, s["vocab"])),
-            "dense": [dense(k) for k in keys[:n_dense]],
-            "routed": jax.lax.map(routed, keys[n_dense:s["layers"]])}
+            "dense": [dense(k, kind) for k, kind in zip(
+                keys[:n_dense], kinds)],
+            "routed": [jax.lax.map(partial(routed, kind=kind), keys[lo:hi])
+                       for (kind, _), lo, hi in zip(
+                           runs_of(s), ends, ends[1:])]}
 
 
 def _route(h, w, s, force):
@@ -242,15 +322,18 @@ def _route(h, w, s, force):
 def _qkv(h, w, s, side, pos):
     """Of normed rows h (n, D) at positions ``pos``: the queries' plain
     part (n, H, hd), their rotary part (n, H, r) or None, and the row a
-    cache keeps: the normed latent and, behind it, the rotary key."""
+    cache keeps: the normed latent and, behind it, the rotary key (the
+    second part is not turned where ``rotary`` is 0)."""
     n, hd, r, c = h.shape[0], s["head_dim"], s["rope_dim"], s["kv_rank"]
-    q = _mm(_norm(_mm(h, w["wq_a"], side)), w["wq_b"], side).reshape(
-        n, s["heads"], hd + r)
+    q = _mm(_norm(_mm(h, w["wq_a"], side)), w["wq_b"], side) if s["q_rank"] \
+        else _mm(h, w["wq"], side)
+    q = q.reshape(n, s["heads"], hd + r)
     kv = _mm(h, w["wkv_a"], side)
     if not r:
         return q, None, _norm(kv)
-    return q[..., :hd], _rope(q[..., hd:], pos), jnp.concatenate(
-        [_norm(kv[:, :c]), _rope(kv[:, c:], pos)], -1)
+    turned = partial(_rope, pos=pos) if s["rotary"] else lambda x: x
+    return q[..., :hd], turned(q[..., hd:]), jnp.concatenate(
+        [_norm(kv[:, :c]), turned(kv[:, c:])], -1)
 
 
 def _attend_whole(qn, qr, latent, w, s, side):
@@ -288,6 +371,71 @@ def _attend_cached(qn, qr, rows, pos, w, s, side):
         qn.shape[0], heads * hd)
 
 
+def _delta_step(state, q, k, v, a, b):
+    """One position of the gated delta rule, float32 at ``highest``:
+    state (H, dk, dv), q, k, a (H, dk), v (H, dv), b (H,):
+    ``S = (I - b k k^T) diag(a) S + b k v^T``, ``o = S^T q``."""
+    state = a[..., None] * state
+    seen = jnp.einsum("hk,hkv->hv", k, state, precision="highest")
+    state = state + k[..., None] * (b[:, None] * (v - seen))[:, None, :]
+    return state, jnp.einsum("hk,hkv->hv", q, state, precision="highest")
+
+
+def _state_rows(h, w, s, side):
+    """What a state layer takes of its normed rows h (n, D) row by row:
+    q, k and v before the convolution (n, 3 H d), the decay a channel
+    (n, H, d) and the beta a head (n, H), both float32, and the gate on
+    the output (n, H d)."""
+    n, hs, ds = h.shape[0], s["state_heads"], s["state_dim"]
+    gate = _mm(_mm(h, w["wf_a"], side), w["wf_b"], side).astype(F32)
+    decay = jnp.exp(-jnp.repeat(w["rate"], ds) * jax.nn.softplus(
+        gate + w["dt_bias"])).reshape(n, hs, ds)
+    beta = jax.nn.sigmoid(_mm(h, w["wbeta"], side).astype(F32))
+    return (_mm(h, w["wqkv"], side), decay, beta,
+            _mm(_mm(h, w["wg_a"], side), w["wg_b"], side))
+
+
+def _state_scan(x, decay, beta, state, tail, w, s, length):
+    """One sequence's rows through the convolution and the recurrence,
+    from ``state`` (H, dk, dv) and ``tail`` (CONV - 1, 3 H d), the rows
+    before: each head's output (n, H, dv) float32, and the state and the
+    tail as row ``length`` - 1 leaves them (``length`` None: the last
+    row); a row at or behind ``length`` moves neither."""
+    n, hs, ds = x.shape[0], s["state_heads"], s["state_dim"]
+    rows = jnp.concatenate([tail.astype(x.dtype), x])
+    mixed = jax.nn.silu(sum(
+        rows[i:i + n].astype(F32) * w["wconv"][i].astype(F32)
+        for i in range(CONV))).astype(x.dtype).astype(F32)
+    q, k, v = (part.reshape(n, hs, ds) for part in jnp.split(mixed, 3, -1))
+    q, k = (part * jax.lax.rsqrt(jnp.sum(part * part, -1, keepdims=True)
+                                 + 1e-6) for part in (q, k))
+
+    def step(state, row):
+        at, q, k, v, a, b = row
+        moved, out = _delta_step(state, q, k, v, a, b)
+        return (moved if length is None
+                else jnp.where(at < length, moved, state)), out
+
+    state, out = jax.lax.scan(step, state,
+                              (jnp.arange(n), q, k, v, decay, beta))
+    return out, state, jax.lax.dynamic_slice_in_dim(
+        rows, n if length is None else length, CONV - 1, 0)
+
+
+def _state_out(out, gate, w, side):
+    """Each head's output normed, gated and projected: (n, D)."""
+    n = out.shape[0]
+    gated = _norm(out).reshape(n, -1) * jax.nn.sigmoid(gate.astype(F32))
+    return _mm(gated.astype(gate.dtype), w["wo"], side)
+
+
+def _no_state(s, dtype):
+    """A sequence's state and convolution tail before its first row."""
+    hs, ds = s["state_heads"], s["state_dim"]
+    return (jnp.zeros((hs, ds, ds), F32),
+            jnp.zeros((CONV - 1, 3 * hs * ds), dtype))
+
+
 def _mlp(x, w, s, side, force):
     """The layer's second half on rows x (n, D): (x after it, chosen
     (n, k) or None in a dense layer, margin (n,) or None)."""
@@ -307,42 +455,59 @@ def _mlp(x, w, s, side, force):
 
 
 def _layers(x, params, s, side, attend, caches, force):
-    """x (n, D) through the dense layers and then the routed ones
-    (scanned over their stacked weights). ``attend(h, w, cache)`` is a
-    layer's attention over its normed input and its share of ``caches``
-    (the layers' leading axis; None without a cache), returning what the
-    attention adds and the layer's cache as it leaves it. ``force``
+    """x (n, D) through the dense layers and then the routed ones (each
+    run of them scanned over its stacked weights).
+    ``attend(kind, h, w, cache)`` is a layer's mixer over its normed
+    input and its share of ``caches`` ({kind: what the layers of that
+    mixer keep, their leading axis}; None without a cache), returning
+    what the mixer adds and the layer's cache as it leaves it. ``force``
     (n,) flips a choice in the first routed layer. Returns x, the caches
     stacked again, chosen (routed layers, n, k) and the least margin."""
-    n_dense = len(params["dense"])
-    out_caches = []
-    for i, w in enumerate(params["dense"]):
-        add, cache = attend(_norm(x), w, None if caches is None else caches[i])
+    done = dict.fromkeys(KINDS, 0)          # layers of each mixer so far
+    left = {kind: [] for kind in KINDS}     # and their caches, in order
+
+    def share(kind, n):
+        lo = done[kind]
+        done[kind] += n
+        return None if caches is None else jax.tree_util.tree_map(
+            lambda a: a[lo:lo + n], caches[kind])
+
+    for kind, w in zip(kinds_of(s), params["dense"]):
+        add, cache = attend(kind, _norm(x), w, jax.tree_util.tree_map(
+            lambda a: a[0], share(kind, 1)))
         x, _, _ = _mlp(x + add, w, s, side, None)
-        out_caches.append(cache)
+        left[kind].append(jax.tree_util.tree_map(lambda a: a[None], cache))
 
-    n_routed = s["layers"] - n_dense
-    forces = None if force is None else jnp.zeros(
-        (n_routed,) + force.shape, F32).at[0].set(force)
+    chosen, margins = [], []
+    for (kind, n), weights in zip(runs_of(s), params["routed"]):
+        forces = None if force is None else jnp.zeros(
+            (n,) + force.shape, F32).at[0].set(0.0 if chosen else force)
 
-    def body(x, layer):
-        w, cache, f = layer
-        add, cache = attend(_norm(x), w, cache)
-        x, chosen, margin = _mlp(x + add, w, s, side, f)
-        return x, (cache, chosen, margin)
+        def body(x, layer, kind=kind):
+            w, cache, f = layer
+            add, cache = attend(kind, _norm(x), w, cache)
+            x, picked, margin = _mlp(x + add, w, s, side, f)
+            return x, (cache, picked, margin)
 
-    x, (routed_caches, chosen, margin) = jax.lax.scan(
-        body, x, (params["routed"],
-                  None if caches is None else caches[n_dense:], forces))
+        x, (cache, picked, margin) = jax.lax.scan(
+            body, x, (weights, share(kind, n), forces))
+        left[kind].append(cache)
+        chosen.append(picked)
+        margins.append(margin.min(0))
     if caches is not None:
-        caches = jnp.concatenate(
-            [jnp.stack(out_caches), routed_caches]) if out_caches \
-            else routed_caches
-    return x, caches, chosen, margin.min(0)
+        caches = {kind: jax.tree_util.tree_map(
+            lambda *parts: jnp.concatenate(parts), *left[kind])
+            for kind in caches}
+    return x, caches, jnp.concatenate(chosen), jnp.stack(margins).min(0)
 
 
 def _whole(side, s):
-    def attend(h, w, cache):
+    def attend(kind, h, w, cache):
+        if kind == "S":
+            x, decay, beta, gate = _state_rows(h, w, s, side)
+            out, _, _ = _state_scan(x, decay, beta, *_no_state(s, x.dtype),
+                                    w, s, None)
+            return _state_out(out, gate, w, side), cache
         qn, qr, latent = _qkv(h, w, s, side, jnp.arange(h.shape[0]))
         return _mm(_attend_whole(qn, qr, latent, w, s, side), w["wo"],
                    side), cache
@@ -458,11 +623,37 @@ def reach(seed: int, sizes: dict, at: int) -> dict:
 
 
 # ------------------------------------------------ the engine's two programs
-def _cached(side, s, slot_rows, write, pos):
-    """A layer's attention through the cache: the new rows' latent
-    written into ``cache`` (``write``), then the queries at ``pos`` over
-    the rows of ``slot_rows(cache)``."""
-    def attend(h, w, cache):
+def _by_kind(cache: dict) -> dict:
+    """An engine's cache, ``latent`` (layers of L, B, T, c + r) and,
+    where the pattern has state layers, ``state`` (layers of S, B, H,
+    dk, dv) float32 and ``conv`` (layers of S, B, CONV - 1, 3 H d), as
+    ``_layers`` takes it: what the layers of each mixer keep."""
+    out = {"L": cache["latent"]} if "latent" in cache else {}
+    if "state" in cache:
+        out["S"] = (cache["state"], cache["conv"])
+    return out
+
+
+def _by_name(caches: dict) -> dict:
+    """And back."""
+    out = {"latent": caches["L"]} if "L" in caches else {}
+    if "S" in caches:
+        out["state"], out["conv"] = caches["S"]
+    return out
+
+
+def _cached(side, s, slot_rows, write, pos, through):
+    """A layer's mixer through the cache. Latent attention: the new
+    rows' latent written into ``cache`` (``write``), then the queries at
+    ``pos`` over the rows of ``slot_rows(cache)``. A state layer:
+    ``through(x, decay, beta, cache)`` carries the state and the tail
+    the cache holds through the rows and returns each head's output and
+    the cache."""
+    def attend(kind, h, w, cache):
+        if kind == "S":
+            x, decay, beta, gate = _state_rows(h, w, s, side)
+            out, cache = through(x, decay, beta, cache, w)
+            return _state_out(out, gate, w, side), cache
         qn, qr, latent = _qkv(h, w, s, side, pos.reshape(-1))
         cache = write(cache, latent.astype(BF16))
         return _mm(slot_rows(cache, qn, qr, w), w["wo"], side), cache
@@ -474,9 +665,11 @@ def _cached(side, s, slot_rows, write, pos):
 def _prefill(params, cache, tokens, slot_onehot, start, length, *, sizes,
              side, bucket):
     """One chunk ``tokens`` (1, bucket) of one sequence into the slot
-    ``slot_onehot`` marks, at rows ``start`` (1,) on: all ``bucket`` rows
-    are written, and the logits (V,) of row ``length`` - 1 of the chunk
-    returned."""
+    ``slot_onehot`` marks, at rows ``start`` (1,) on: all ``bucket``
+    latent rows are written (those at or behind ``length`` are never
+    read), a state layer's state and tail are carried through the first
+    ``length`` rows, from nothing where ``start`` is 0, and the logits
+    (V,) of row ``length`` - 1 of the chunk returned."""
     s = dict(sizes)
     slot = slot_onehot.argmax()
     pos = start[0] + jnp.arange(bucket)
@@ -489,20 +682,30 @@ def _prefill(params, cache, tokens, slot_onehot, start, length, *, sizes,
     def over(cache, qn, qr, w):
         return _attend_cached(qn, qr, cache[slot], pos, w, s, side)
 
+    def through(x, decay, beta, cache, w):      # (B, ...) each: the slot's
+        held = [jnp.where(start[0] == 0, fresh, mine[slot])
+                for fresh, mine in zip(_no_state(s, x.dtype), cache)]
+        out, *held = _state_scan(x, decay, beta, *held, w, s, length)
+        return out, tuple(
+            jax.lax.dynamic_update_index_in_dim(mine, new, slot, 0)
+            for mine, new in zip(cache, held))
+
     x = params["embed"][tokens[0]]
     x, cache, _, _ = _layers(x, params, s, side,
-                             _cached(side, s, over, write, pos),
-                             cache["latent"], None)
+                             _cached(side, s, over, write, pos, through),
+                             _by_kind(cache), None)
     row = jax.lax.dynamic_index_in_dim(x, length - 1, 0, keepdims=True)
     logits = _mm(_norm(row), params["head"], side).astype(F32)[0]
-    return logits, {"latent": cache}
+    return logits, _by_name(cache)
 
 
 @partial(jax.jit, static_argnames=("sizes", "side"), donate_argnums=(1,))
 def _decode(params, cache, last_tokens, lengths, temps, rng, *, sizes, side):
     """One greedy token a lane: lane b's ``last_tokens[b]`` written at
     row ``lengths[b]`` of its slot and attended from there (an idle lane
-    writes the scratch row ``max_seq`` - 1)."""
+    writes the scratch row ``max_seq`` - 1), every lane's state and tail
+    moved by its token (an idle lane's too: the call that next starts a
+    sequence there begins from nothing)."""
     s = dict(sizes)
     lanes = jnp.arange(lengths.shape[0])
 
@@ -514,20 +717,27 @@ def _decode(params, cache, last_tokens, lengths, temps, rng, *, sizes, side):
             q[None], None if r is None else r[None], rows, at[None], w, s,
             side)[0])(qn, qr, cache, lengths)
 
+    def through(x, decay, beta, cache, w):      # one row a lane
+        out, *held = jax.vmap(lambda x, a, b, state, tail: _state_scan(
+            x[None], a[None], b[None], state, tail, w, s, None))(
+                x, decay, beta, *cache)
+        return out[:, 0], tuple(held)
+
     x = params["embed"][last_tokens]
     x, cache, _, _ = _layers(x, params, s, side,
-                             _cached(side, s, over, write, lengths),
-                             cache["latent"], None)
+                             _cached(side, s, over, write, lengths, through),
+                             _by_kind(cache), None)
     logits = _mm(_norm(x), params["head"], side).astype(F32)
-    return logits.argmax(-1).astype(jnp.int32), {"latent": cache}, rng
+    return logits.argmax(-1).astype(jnp.int32), _by_name(cache), rng
 
 
 class Engine:
     """What ``server.reference_readings`` and ``serving_control.broken``
     hold of an engine: the two programs on ``side`` (``bf16``, or
-    ``fp8``: the control), one cache shard of ``max_batch`` slots of
-    ``max_seq`` latent rows a layer, and an engine that is always
-    idle."""
+    ``fp8``: the control), one cache shard of ``max_batch`` slots (of
+    ``max_seq`` latent rows a layer of latent attention; of a float32
+    state and a convolution's tail a state layer), and an engine that is
+    always idle."""
 
     def __init__(self, sizes: dict, params, side: str = "bf16",
                  max_batch: int = 8, max_seq: int = 2048,
@@ -536,9 +746,15 @@ class Engine:
         self.buckets, self.prefill_chunk = list(buckets), buckets[-1]
         self.max_batch, self.max_seq = max_batch, max_seq
         s = dict(self.sizes)
-        self.shards = [types.SimpleNamespace(cache={"latent": jnp.zeros(
-            (s["layers"], max_batch, max_seq, s["kv_rank"] + s["rope_dim"]),
-            BF16)})]
+        of = {kind: kinds_of(s).count(kind) for kind in KINDS}
+        hs, ds = s["state_heads"], s["state_dim"]
+        cache = {"latent": jnp.zeros((of["L"], max_batch, max_seq, s[
+            "kv_rank"] + s["rope_dim"]), BF16)} if of["L"] else {}
+        if of["S"]:
+            cache["state"] = jnp.zeros((of["S"], max_batch, hs, ds, ds), F32)
+            cache["conv"] = jnp.zeros(
+                (of["S"], max_batch, CONV - 1, 3 * hs * ds), BF16)
+        self.shards = [types.SimpleNamespace(cache=cache)]
         self._lock, self._rng = threading.Lock(), jax.random.PRNGKey(0)
 
     def num_active(self) -> int:

@@ -36,14 +36,21 @@ writes into its cell's file and its ``tolerance_why``.
 reads ``routed_standin.py`` instead, a routed family's arithmetic with
 the two programs an engine has of it, at the widths such a family would
 bring to one chip (``--grouped``: with groups of experts, a dense
-leading layer and a rotary part; ``--rehearse``: at a test's sizes):
+leading layer and a rotary part; ``--hybrid``: three state layers to one
+of latent attention, a cache that is not rows a position; ``--parents``:
+the sound side once more through the probe as it stood until PR 44,
+``parents_probe_rows``; ``--rehearse``: at a test's sizes):
 on every seed ``server.reference_readings`` through its ``_prefill`` and
 ``_decode`` at the probe a routed cell would bring, ``served_readings``
 over a request a lane, and ``serve_load.matches_reference`` itself under
 the two shares such a cell would state, for the sound side, the control
 (the shared expert's operands in fp8) and the fault (one lane's token
 moved to the next of the vocabulary); then how far a flip made on
-purpose reaches the rows behind it (PERF.md section 6, PR 36).
+purpose reaches the rows behind it, all of them and by how far behind,
+and what the arithmetic alone reads with no engine, no cache and no
+probe: ``routed_standin.readings``, bf16 against float32 over whole
+sequences, the witness for a share the engine reads (PERF.md section 6,
+PRs 36 and 44).
 """
 
 import contextlib
@@ -196,6 +203,59 @@ def broken(decode, change):
     return wrapped
 
 
+def parents_probe_rows(eng, seq, positions: int, decode_steps: int):
+    """``server.probe_rows`` as it stood until PR 44, kept to pin what it
+    does to a cache that is not addressed by position: every call runs
+    on the real cache, the last chunk ``positions`` times at the same
+    start (a state then holds the chunk before all but the first), and
+    the token behind each decode goes in twice, once by the one-token
+    prefill that reads its row and once by the next decode."""
+    import numpy as np
+
+    length, shard = len(seq), eng.shards[0]
+    starts = range(0, length, eng.prefill_chunk)
+    onehot = np.zeros(eng.max_batch, np.float32)
+    onehot[0] = 1.0
+
+    def prefill(tokens, pos, real=None):
+        bucket = next(b for b in eng.buckets if b >= len(tokens))
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :len(tokens)] = tokens
+        logits, shard.cache = eng._prefill(
+            eng.params, shard.cache, padded, onehot,
+            np.asarray([pos], np.int32), real or len(tokens), bucket=bucket)
+        return np.asarray(logits, np.float32)
+
+    for pos in starts:
+        last = prefill(seq[pos:pos + eng.prefill_chunk], pos)
+    got_prefill = np.stack([last] + [
+        prefill(seq[starts[-1]:], starts[-1], real=length - starts[-1] - back)
+        for back in range(1, positions)])
+    chosen, got_after = [int(got_prefill[0].argmax())], []
+    lens = np.full(eng.max_batch, eng.max_seq - 1, np.int32)
+    for i in range(decode_steps):
+        tokens = np.zeros(eng.max_batch, np.int32)
+        tokens[0], lens[0] = chosen[-1], length + i
+        toks, shard.cache, eng._rng = eng._decode(
+            eng.params, shard.cache, tokens, lens,
+            np.zeros(eng.max_batch, np.float32), eng._rng)
+        chosen.append(int(np.asarray(toks)[0]))
+        got_after.append(prefill(chosen[-1:], length + i + 1))
+    return got_prefill, chosen, np.stack(got_after)
+
+
+@contextlib.contextmanager
+def parents_protocol():
+    """``server.reference_readings`` under it reads through
+    ``parents_probe_rows``."""
+    sound = server.probe_rows
+    server.probe_rows = parents_probe_rows
+    try:
+        yield
+    finally:
+        server.probe_rows = sound
+
+
 def next_token(vocab: int, lane=None):
     """A token altered where it is produced: every lane's (or only
     ``lane``'s) moved to the next of the vocabulary."""
@@ -226,7 +286,13 @@ def seeds_of(text: str) -> list:
 STANDIN_CHECK = {"length": 448, "positions": 96, "decode_steps": 32,
                  "rel_rms_tol": 0.03, "choice_gap_tol": 0.2}
 STANDIN_SHARES = {"CHIP": (0.028, 0.009), "CHIP_GROUPED": (0.06, 0.014),
-                  "TOY": (0.03, 0.01), "TOY_GROUPED": (0.03, 0.01)}
+                  "TOY": (0.03, 0.01), "TOY_GROUPED": (0.03, 0.01),
+                  # the ceilings, the most a cell may state, so that
+                  # the command runs and prints its readings: no run
+                  # comes out correct under them, with 64 of 256 experts
+                  # held the sound side read 89 % of its rows over
+                  # (PERF.md section 6, PR 44)
+                  "CHIP_HYBRID": (0.1, 0.03), "TOY_HYBRID": (0.03, 0.01)}
 # its served requests, one a lane: prompts and answers of so many
 # tokens, the shortest and the longest answer in every seed
 STANDIN_PROMPTS, STANDIN_ANSWERS = (32, 480), (48, 256)
@@ -246,11 +312,13 @@ def standin_requests(seed: int, lanes: int, vocab: int, prompts, answers):
         vocab), int(n)) for i, n in enumerate(lens)]
 
 
-def standin_sides(sizes: dict, lanes: int, max_seq: int, buckets):
+def standin_sides(sizes: dict, lanes: int, max_seq: int, buckets,
+                  parents: bool = False):
     """The stand-in's engine three times over one set of weights: sound,
     the control (the shared expert's operands in fp8) and the fault (the
     decode's token of the lane before the last moved to the next of the
-    vocabulary)."""
+    vocabulary); with ``parents`` a fourth, sound, which ``standin``
+    reads under ``parents_protocol``."""
     import routed_standin
 
     def engine(side):
@@ -261,6 +329,8 @@ def standin_sides(sizes: dict, lanes: int, max_seq: int, buckets):
              "fault": engine("bf16")}
     sides["fault"]._decode = broken(
         sides["fault"]._decode, next_token(sizes["vocab"], lanes - 2))
+    if parents:
+        sides["parents"] = engine("bf16")
     return sides
 
 
@@ -282,8 +352,39 @@ def standin_read(eng, family, seed: int, hp: dict, check: dict,
     return got
 
 
+# rows behind a forced flip, from each of these to the next: how far
+# the flip reaches
+REACH_FROM = (1, 2, 3, 4, 8, 16, 32, 64, 128, 256, 512)
+
+
+def arithmetic(seed: int, sizes: dict, tol: float, gap_tol: float) -> dict:
+    """``routed_standin.readings`` from row 32 on, summed up: the share
+    of positions whose routing flipped between bf16 and float32, what
+    they and the others read, the others that lie before any flip of
+    their sequence apart, the shares over the two limits, and the fp8
+    control."""
+    import numpy as np
+    import routed_standin
+
+    whole = routed_standin.readings(seed, sizes)
+    clean = ~np.logical_or.accumulate(whole["flipped"], axis=1)
+    r = {k: v[:, serve_load.SHARES_FROM_ROW:]
+         for k, v in dict(whole, clean=clean).items() if k != "logit_rms"}
+    flipped, rel, clean = r["flipped"], r["bf16_rel_rms"], r["clean"]
+    return {"positions": int(flipped.size),
+            "flipped_share": float(flipped.mean()),
+            "rows_over_share": float((rel > tol).mean()),
+            "unflipped_over_share": float((rel[~flipped] > tol).mean()),
+            "tokens_over_share": float((r["bf16_choice_gap"] > gap_tol).mean()),
+            "flipped": spread(rel[flipped]), "others": spread(rel[~flipped]),
+            "before_any_flip": spread(rel[clean]),
+            "control": spread(r["fp8_rel_rms"]),
+            "control_tokens_over_share": float(
+                (r["fp8_choice_gap"] > gap_tol).mean())}
+
+
 def standin(seeds: list, reach_seeds: list, rehearse: bool,
-            grouped: bool) -> None:
+            variant: str = "", parents: bool = False) -> None:
     """The stand-in's engine through the comparison a routed cell would
     bring, on every seed the sound side, the control and the fault; then
     ``routed_standin.reach`` over ``reach_seeds``. Every reading goes to
@@ -292,7 +393,7 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
     import numpy as np
     import routed_standin
 
-    name = ("TOY" if rehearse else "CHIP") + ("_GROUPED" if grouped else "")
+    name = ("TOY" if rehearse else "CHIP") + variant
     sizes = getattr(routed_standin, name)
     check = dict(STANDIN_CHECK, **dict(zip(
         serve_load.SHARE_CEILINGS, STANDIN_SHARES[name])))
@@ -303,9 +404,10 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
         lanes, max_seq, buckets = 4, 192, (8, 32)
         prompts, answers = (32, 80), (8, 40)
     hp = {**sizes, "vocab_size": sizes["vocab"]}
-    sides = standin_sides(sizes, lanes, max_seq, buckets)
+    sides = standin_sides(sizes, lanes, max_seq, buckets, parents)
     out = {"sizes": sizes, "name": name, "check": check, "risk":
-           serve_load.RISK, "seeds": seeds, "by_seed": {}, "reach": {}}
+           serve_load.RISK, "seeds": seeds, "by_seed": {}, "reach": {},
+           "arithmetic": {}}
     os.makedirs("chiprun_out", exist_ok=True)
     path = f"chiprun_out/serving_control.standin.{name}.{seeds[0]}.json"
 
@@ -324,8 +426,10 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
         row = out["by_seed"][seed] = {}
         for side, eng in sides.items():
             eng.params = params
-            got = row[side] = standin_read(eng, routed_standin, seed, hp,
-                                           check, requests)
+            with parents_protocol() if side == "parents" \
+                    else contextlib.nullcontext():
+                got = row[side] = standin_read(eng, routed_standin, seed, hp,
+                                               check, requests)
             rel = got["prefill_rel_rms"] + got["after_decode_rel_rms"]
             over = sorted(x for x in rel if x > check["rel_rms_tol"])
             tol = check["choice_gap_tol"]
@@ -348,13 +452,25 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
         eng.params = None
     for seed in reach_seeds:
         small = {**sizes, "seqs": 2, "seq": min(sizes["seq"], 1024)}
+        out["arithmetic"][seed] = arithmetic(
+            seed, small, check["rel_rms_tol"], check["choice_gap_tol"])
+        print("standin arithmetic", name, seed, out["arithmetic"][seed],
+              flush=True)
         r = routed_standin.reach(seed, small, small["seq"] // 4)
-        held = ~r["behind_flipped"]
+        steady = ~r["behind_flipped"]    # the rows whose own choices held
+        ends = REACH_FROM[1:] + (r["behind"].shape[1] + 1,)
         out["reach"][seed] = {
+            # [rows behind from, to, then ``spread`` of the rows there
+            # whose own choices held]
+            "behind_by_distance": [
+                [lo, hi - 1] + spread(r["behind"][:, lo - 1:hi - 1][
+                    steady[:, lo - 1:hi - 1]])
+                for lo, hi in zip(REACH_FROM, ends)
+                if lo <= r["behind"].shape[1]],
             "at": [float(x) for x in r["at"]],
             "before_largest": float(r["before"].max()),
             "behind": spread(r["behind"]),
-            "behind_choices_held": spread(r["behind"][held]),
+            "behind_choices_held": spread(r["behind"][steady]),
             # each row behind that reads over the limit: rows behind,
             # reading, a choice of its own flipped
             "behind_over_limit": [
@@ -458,11 +574,14 @@ def shares_read(rows: list, check: dict) -> dict:
 
 
 def main(argv) -> int:
-    rehearse, grouped = "--rehearse" in argv, "--grouped" in argv
-    cell_name, seeds, control_seeds = [
-        a for a in argv if a not in ("--rehearse", "--grouped")]
+    flags = ("--rehearse", "--grouped", "--hybrid", "--parents")
+    rehearse = "--rehearse" in argv
+    cell_name, seeds, control_seeds = [a for a in argv if a not in flags]
     if cell_name == "standin":
-        standin(seeds_of(seeds), seeds_of(control_seeds), rehearse, grouped)
+        standin(seeds_of(seeds), seeds_of(control_seeds), rehearse,
+                "_GROUPED" if "--grouped" in argv
+                else "_HYBRID" if "--hybrid" in argv else "",
+                "--parents" in argv)
         return 0
     from ray_tpu._private.jax_utils import ensure_compilation_cache_dir
 

@@ -229,7 +229,7 @@ def test_the_fixture_trees_cells_are_held_as_the_benchmarks_are():
             assert len(spec.cell_metrics(name, True)) >= 1
         if cell["kind"] == "serve" and stated_shares(cell):
             stating.append(name)
-    assert stating == ["routed-standin.serve"]
+    assert stating == ["hybrid-standin.serve", "routed-standin.serve"]
     for w in spec.benchmark_json()["workloads"]:
         for rehearse in (False, True):
             cell = spec.load_cell(w["name"], rehearse)

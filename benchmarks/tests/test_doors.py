@@ -546,27 +546,30 @@ def test_a_serve_cell_of_a_second_family_is_tried_on_its_own_recording(
     assert "stats.decode_ahead" in on_llama["decode_ahead_pct.toy"]
 
 
-def test_a_routed_serve_cell_states_its_shares_by_files_alone():
-    """``routed-standin.serve``, its configuration and its family
-    ``routed`` exist under tests/tree alone: the cell is found by its
-    kind, the family by the configuration's name, the two shares it
+@pytest.mark.parametrize("name", ["routed-standin.serve",
+                                  "hybrid-standin.serve"])
+def test_a_routed_serve_cell_states_its_shares_by_files_alone(name):
+    """``routed-standin.serve`` and ``hybrid-standin.serve`` (the same
+    stand-in with state layers before its latent attention: a cache
+    that is not addressed by position), their configurations and their
+    family ``routed`` exist under tests/tree alone: the cell is found by
+    its kind, the family by the configuration's name, the two shares it
     states are held by ``spec.load_cell`` through the kind's own
     ``check_cell``, and no harness file knows the family, the cell or a
     share's value. It brings no recording, so the tests parametrised
-    over cells try it on the llama family's. Of the tree's own cells it
-    is the one that states them; what a cell of BENCHMARK.json that
-    states them is held to is ``test_contract.py``'s and
+    over cells try it on the llama family's. Of the tree's own cells
+    these are the ones that state them; what a cell of BENCHMARK.json
+    that states them is held to is ``test_contract.py``'s and
     ``test_serving_reference.py``'s, by that property alone."""
     from benchmarks import serve_load
 
     stating = [c for c in cells_of_kind("serve", tree_cells()) if any(
         share in spec.load_cell(c, True)["serve"]["reference_check"]
         for share in serve_load.SHARE_CEILINGS)]
-    assert stating == ["routed-standin.serve"]
-    cell = spec.load_cell(stating[0], True)
-    assert recording_of(stating[0]) is None
-    assert stating[0] not in [
-        w["name"] for w in spec.benchmark_json()["workloads"]]
+    assert stating == ["hybrid-standin.serve", "routed-standin.serve"]
+    cell = spec.load_cell(name, True)
+    assert recording_of(name) is None
+    assert name not in [w["name"] for w in spec.benchmark_json()["workloads"]]
     family = spec.family_of(cell["hp"])
     assert family.__name__ == "benchmarks.families.routed"
     assert os.path.realpath(family.__file__).startswith(
@@ -579,12 +582,12 @@ def test_a_routed_serve_cell_states_its_shares_by_files_alone():
     for path in harness_files():
         with open(path) as f:
             text = f.read()
-        assert "routed-standin" not in text and "families.routed" not in text
+        assert "-standin" not in text and "families.routed" not in text
         assert "routed_standin" not in text
     # the cell reports through the declarations of the tests' tree alone
-    assert set(spec.cell_metrics(stating[0], traced=False)) == {
+    assert set(spec.cell_metrics(name, traced=False)) == {
         "setup_s", "serve_tokens_per_s"}
-    assert len(spec.cell_metrics(stating[0], traced=True)) >= 3
+    assert len(spec.cell_metrics(name, traced=True)) >= 3
 
 
 def test_the_new_shares_by_scope_equal_the_hand_count(serving):

@@ -10,6 +10,20 @@ there but ``BENCHMARK.json``:
 (b) a serve cell of a routed family that states the two shares of its
     sound engine's readings over the limits (the tree's
     ``routed-standin.serve`` and its configuration under other names),
+    and
+(c) one of a hybrid, whose engine keeps a recurrent state and a
+    convolution's tail beside its latent rows, a cache that is not
+    addressed by position (the tree's ``hybrid-standin.serve``), and
+    with it a family of its own, as such a PR brings one:
+    ``files_alone/family.py`` and ``files_alone/control.py`` as
+    ``tree/families/<family>.py`` and ``tree/control_<family>.py`` (the
+    tests' own families live in their tree; the name lookups are the
+    ones ``benchmarks/families/`` and ``tests/`` go through). It is the
+    tree's ``routed`` under another schema: the configuration states its
+    layers' mixers under ``mixers`` where the fixture's says
+    ``pattern``, and the engine keeps what a slot holds under other
+    names than the stand-in's, so a test shared by every cell that
+    turned on a key or a leaf of the fixture's would fail here,
 
 each with a handful of per-layer metrics (files copied from the
 ``.docs`` ones, entries of their own) and a probe of its own (32
@@ -18,14 +32,14 @@ positions behind a whole chunk and 16 decodes: what
 property, and other sizes than any cell here has). Then it runs
 ``python -m pytest benchmarks/tests`` there, this file left out, and
 holds the outcome: every case passes but those listed under
-``NEEDS_THE_PROGRAM``, each of which drives (b)'s cell through
+``NEEDS_THE_PROGRAM``, each of which drives (b)'s or (c)'s cell through
 ``run.py``, where ``LLMServer`` builds the engine of ``models/llama.py``
 and cannot serve a routed family yet (PERF.md section 7, items 1-3: the
 ``model_config`` PR's to bring). Everything the tests themselves hold of
 such a cell (the control found by the family's name, the decision by
 its counts, the probe's sizes by property) passes.
 
-Slow (it is the whole of ``benchmarks/tests`` once more, with two more
+Slow (it is the whole of ``benchmarks/tests`` once more, with three more
 cells); ``benchmarks/tests`` is not tier-1.
 
     python benchmarks/tests/test_files_alone.py <checkout>
@@ -46,7 +60,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
-# (a) and (b): the fixture cell each is a copy of, its new name, the
+# (a), (b) and (c): the fixture cell each is a copy of, its new name, the
 # suffix of its metrics, the `.docs` metrics copied for it, and what its
 # ``reference_check`` states otherwise than the fixture's
 SERVED = {
@@ -70,19 +84,30 @@ ROUTED = {
                 "decode_occupancy_pct"],
     "check": {"length": 96, "positions": 32, "decode_steps": 16},
 }
-# the cases that need a program that serves (b)'s family: each runs its
-# cell through run.py and so through ``LLMServer``
+HYBRID = {
+    "of": "hybrid-standin.serve", "config": "sixth-hybrid",
+    "traffic": "serve", "suffix": "sixth",
+    # a family of its own (files_alone/), and the keys of the fixture's
+    # configuration that its schema states under another name
+    "family": "sixth_kind", "renamed": {"pattern": "mixers"},
+    "metrics": ["engine_tokens_per_step", "engine_host_ms",
+                "decode_occupancy_pct"],
+    "check": {"length": 96, "positions": 32, "decode_steps": 16},
+}
+# the cases that need a program that serves (b)'s and (c)'s family: each
+# runs its cell through run.py and so through ``LLMServer``
 NEEDS_THE_PROGRAM = {
     ("test_scopes", "test_rehearsal_reaches_a_valid_last_line"
-     f"[{ROUTED['config']}.{ROUTED['traffic']}]"),
+     f"[{new['config']}.{new['traffic']}]") for new in (ROUTED, HYBRID)
 }
 
 
 def declare(root: str) -> list:
-    """Adds ``SERVED`` and ``ROUTED`` to the checkout at ``root`` as a PR
-    would: ``configs/<config>.json``, ``workloads/<cell>.json`` and
-    ``metrics/<metric>.<suffix>.json`` as new files, and their entries
-    in ``BENCHMARK.json``. Returns the new cells' names."""
+    """Adds ``SERVED``, ``ROUTED`` and ``HYBRID`` to the checkout at
+    ``root`` as a PR would: ``configs/<config>.json``,
+    ``workloads/<cell>.json`` and ``metrics/<metric>.<suffix>.json`` as
+    new files (for ``HYBRID`` its family's two modules too), and their
+    entries in ``BENCHMARK.json``. Returns the new cells' names."""
     bench = os.path.join(root, "benchmarks")
     tree = os.path.join(bench, "tests", "tree")
 
@@ -101,7 +126,7 @@ def declare(root: str) -> list:
     serve_rate = next(e for e in declared["end_to_end"]
                       if e["name"] == "serve_tokens_per_s")
     names = []
-    for new in (SERVED, ROUTED):
+    for new in (SERVED, ROUTED, HYBRID):
         cell = load(tree, "workloads", f"{new['of']}.json")
         config = load(tree, "configs", f"{cell['config']}.json")
         name = f"{new['config']}.{new['traffic']}"
@@ -109,6 +134,16 @@ def declare(root: str) -> list:
         config.update(name=new["config"], source=(
             f"none: a copy of the tests' tree's {cell['config']}, declared "
             "in a scratch checkout by tests/test_files_alone.py"))
+        if "family" in new:
+            config["family"] = new["family"]
+            for theirs, ours in new["renamed"].items():
+                config[ours] = config.pop(theirs)
+            for ours, there in (
+                    ("family.py", ("families", f"{new['family']}.py")),
+                    ("control.py", (f"control_{new['family']}.py",))):
+                path = os.path.join(tree, *there)
+                assert not os.path.exists(path), f"{path} is there already"
+                shutil.copy(os.path.join(HERE, "files_alone", ours), path)
         add(config, "configs", f"{new['config']}.json")
         cell.update(name=name, config=new["config"])
         cell["serve"]["reference_check"].update(new["check"])

@@ -808,6 +808,71 @@ def test_a_fault_outside_the_probes_lane_fails_the_served_tokens(
             assert over <= serve_load.allowed(len(gaps), share)
 
 
+PROBE_LISTS = ("prefill_rel_rms", "after_decode_rel_rms",
+               "decode_choice_gap")
+
+
+@pytest.fixture(scope="module")
+def under_both_protocols(probes):
+    """cell -> (its sound engine's probe readings as ``server.probe_rows``
+    reads them, and as ``serving_control.parents_probe_rows`` did until
+    PR 44), on one seed and the same weights; read once a cell."""
+    @functools.lru_cache(maxsize=None)
+    def read(cell):
+        probe = probes[cell]
+        seed = SEEDS[states_shares(cell)]["sound"][0]
+        sound = probe.read(seed, served=False)
+        with serving_control.parents_protocol():
+            return sound, probe.read(seed, served=False)
+    return read
+
+
+@pytest.mark.parametrize("cell", SERVE)
+def test_no_call_is_repeated_on_a_cache_that_holds_its_result(
+        cell, probes, under_both_protocols):
+    """``server.probe_rows`` runs every call that only reads a row on a
+    copy of the cache. Against the protocol it replaced (the last chunk
+    dispatched ``positions`` times into the real cache, the token behind
+    each decode fed twice), on the same engine and seed, one of two
+    things is observed, and which is not asked of the cell, its family's
+    sizes or its cache's leaves. Either a call made twice leaves the
+    cache as a call made once (rows a position: keys and values, latent
+    rows): the three lists are then equal to the last digit. Or it does
+    not (a recurrent state, a convolution's tail): then only the first
+    call, a request's own, reads as under the new protocol, every other
+    compared row reads over the limit and well over what the new
+    protocol reads, and the parent's protocol would have had a sound
+    engine not correct on every run. Nothing between the two passes."""
+    check = probes[cell].check
+    sound, parents = under_both_protocols(cell)
+    served = {"served_choice_gap": [0.0], "served_by_request": None}
+    assert serve_load.matches_reference({**sound, **served}, check)
+    if all(parents[name] == sound[name] for name in PROBE_LISTS):
+        return
+    assert parents["prefill_rel_rms"][0] == sound["prefill_rel_rms"][0]
+    twice = rows_of(parents)[1:]
+    assert min(twice) > check["rel_rms_tol"], parents
+    assert min(twice) > 2 * max(x for x in rows_of(sound)
+                                if x <= check["rel_rms_tol"])
+    assert not serve_load.matches_reference({**parents, **served}, check)
+
+
+def test_the_trees_own_cells_keep_both_sides_of_that_door(
+        under_both_protocols):
+    """The keeper, by the fixture cells' names (the tests' tree is the
+    tests' own): of its serve cells ``hybrid-standin.serve`` alone has
+    an engine whose cache a call made twice alters, so the test above
+    takes each of its two ways on some cell whatever BENCHMARK.json
+    declares."""
+    def differ(cell):
+        sound, parents = under_both_protocols(cell)
+        return any(parents[name] != sound[name] for name in PROBE_LISTS)
+
+    altered = [cell for cell in TREE_SERVE if differ(cell)]
+    assert altered == ["hybrid-standin.serve"]
+    assert len(TREE_SERVE) > len(altered)
+
+
 # ---------------------------------------------------- a probe that fits
 class _Engine:
     prefill_chunk, buckets, max_seq = 32, [16, 32], 128
@@ -823,3 +888,32 @@ def test_a_probe_that_does_not_cross_a_chunk_or_fit_is_refused(check, why):
     with pytest.raises(ValueError, match=why):
         server.reference_readings(
             _Engine(), None, 1, {"vocab_size": 64}, check)
+
+
+def _leaf(device, nbytes):
+    import types
+
+    return types.SimpleNamespace(addressable_shards=[types.SimpleNamespace(
+        device=device, data=types.SimpleNamespace(nbytes=nbytes))])
+
+
+@pytest.mark.parametrize("stats,fits", [
+    # the probe keeps one copy of the cache alive: the bytes the cache
+    # holds on a chip have to be free there once more
+    ({"bytes_limit": 1000, "bytes_in_use": 600}, True),
+    ({"bytes_limit": 1000, "bytes_in_use": 601}, False),
+    # a device that says nothing (the CPU of a rehearsal) is not asked
+    ({}, True),
+    (None, True),
+])
+def test_a_cache_with_no_room_for_its_copy_is_refused_by_name(stats, fits):
+    class Device:
+        def memory_stats(self):
+            return stats
+
+    device = Device()
+    cache = {"rows": _leaf(device, 300), "more": [_leaf(device, 100)]}
+    if fits:
+        return server.room_for_a_copy(cache)
+    with pytest.raises(RuntimeError, match="copy of the cache, 400 bytes"):
+        server.room_for_a_copy(cache)
