@@ -31,6 +31,13 @@ re-designed for XLA instead of wrapped:
   chunk returns logits for. ``warm_up()`` runs every program once, at
   every read window; ``LLMServer`` calls it before it takes a request,
   a bare engine compiles on first use.
+- The model is the configuration's: ``config.model_module`` names the
+  module that gives ``forward_with_cache``, ``init_cache`` and
+  ``attn_rows_read`` (``models/llama.py``; ``models/window_moe.py``,
+  whose cache is rows by position for its full layers, a ring of a
+  window's rows for its sliding ones, and the ``moe_*`` counters its
+  programs accumulate on the device). Nothing below knows what a cache
+  holds: it is a pytree the programs take and return.
 - KV cache is preallocated per shard (L, B, KVH, max_seq, hd) and
   UPDATED IN PLACE: both programs are jitted with the cache donated,
   the cached forward carries it through its layer scan and writes only
@@ -95,6 +102,7 @@ from __future__ import annotations
 import math
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from functools import partial, wraps
@@ -203,6 +211,8 @@ class _Shard:
     unread: Optional[Tuple[Any, List[Tuple[int, GenRequest]]]] = None
     # a finished prompt's first token, not read yet: (request, scalar)
     first: Optional[Tuple[GenRequest, Any]] = None
+    # the device counters of this shard's cache when last read
+    counted: Dict[str, int] = field(default_factory=dict)
 
 
 class EngineStats:
@@ -228,6 +238,13 @@ class EngineStats:
         # lane-steps computed for a request that had already ended
         "decode_ahead", "lanes_discarded",
         "shards_grown", "requests_finished", "requests_refused",
+        # a routed family's expert layers, summed over layers and calls:
+        # rows that are somebody's tokens (no padding of a chunk, no idle
+        # lane) times experts per token; experts with such a row or
+        # more; experts held. Accumulated on the device, in the cache the
+        # programs carry, and read when a snapshot is taken
+        # (``device_counters``); 0 for a family that has none
+        "moe_assignments", "moe_experts_touched", "moe_expert_slots",
     )
 
     def __init__(self, ring: int = 4096):
@@ -235,8 +252,15 @@ class EngineStats:
             setattr(self, name, 0)
         self.phases = PhaseStats()
         self.requests: "deque[tuple]" = deque(maxlen=ring)
+        # set by the engine: () -> {counter: total so far} of what its
+        # programs count on the device; a snapshot waits for them,
+        # ``step()`` never does
+        self.device_counters = None
 
     def snapshot(self) -> Dict[str, Any]:
+        if self.device_counters is not None:
+            for name, total in self.device_counters().items():
+                setattr(self, name, total)
         out: Dict[str, Any] = {n: getattr(self, n) for n in self.COUNTERS}
         out["phases"] = self.phases.snapshot()
         out["requests"] = list(self.requests)
@@ -255,10 +279,16 @@ class LlamaEngine:
         prefill_chunk: Optional[int] = None,
         max_slots: Optional[int] = None,
     ):
+        import importlib
+
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
+        # the model's functions are the configuration's: the module it
+        # names gives forward_with_cache, init_cache and attn_rows_read
+        # (models/llama.py; models/window_moe.py), and read_counters
+        # where its programs count on the device
+        model = importlib.import_module(config.model_module)
 
         self.config = config
         self.params = params
@@ -278,10 +308,9 @@ class LlamaEngine:
         self.max_slots = max(max_batch, (want_slots // max_batch) * max_batch)
         self._rng = jax.random.PRNGKey(seed)
         self._jax = jax
-        self._llama = llama
+        self._model = model
         self.stats = EngineStats()
         self.shards: List[_Shard] = []
-        self.shards.append(self._new_shard())
         # most slots one decode call has advanced so far: whether
         # requests were ever batched, not merely queued
         self.peak_active = 0
@@ -300,6 +329,20 @@ class LlamaEngine:
         self.windows = [max_seq]
         if max_seq % 2 == 0 and max_seq // 2 >= c:
             self.windows.insert(0, max_seq // 2)
+        self.shards.append(self._new_shard())
+        self._rows_read = {
+            w: model.attn_rows_read(config, self.shards[0].cache, w)
+            for w in self.windows}
+        # what caches that a failed call took along had counted when
+        # last read (``abort_all``): the totals only ever rise
+        self._counted_before: Dict[str, int] = {}
+        if hasattr(model, "read_counters"):
+            # through a weak reference: the engine, its weights and its
+            # caches go when their last holder lets go, with no cycle
+            # for the collector to find first
+            me = weakref.ref(self)
+            self.stats.device_counters = lambda: (
+                me()._device_counters() if me() is not None else {})
 
         def prefill(params, cache, tokens, slot_onehot, start, length,
                     bucket, rows):
@@ -308,7 +351,7 @@ class LlamaEngine:
             # token (used only when the chunk completes the prompt);
             # attends to the slot's first `rows` rows
             del bucket
-            logits, new_cache = llama.forward_with_cache(
+            logits, new_cache = model.forward_with_cache(
                 params, tokens, cache, start, config,
                 slot=jnp.argmax(slot_onehot),
                 logits_at=jnp.reshape(length - 1, (1,)), rows=rows,
@@ -318,7 +361,7 @@ class LlamaEngine:
         def decode(params, cache, last_tokens, lengths, temps, rng, rows):
             # one token for every slot: tokens (B,), lengths (B,) = count
             # already in cache; inactive slots just waste a lane
-            logits, new_cache = llama.forward_with_cache(
+            logits, new_cache = model.forward_with_cache(
                 params, last_tokens[:, None], cache, lengths, config,
                 rows=rows,
             )
@@ -401,8 +444,23 @@ class LlamaEngine:
     # from the numpy arguments they pass anyway, and counted here, so
     # that ``attn_rows_read`` is what was dispatched whoever called.
     def _count_rows(self, rows: int) -> None:
-        self.stats.attn_rows_read += rows
+        # the layers' mean: a layer that keeps a window's rows in a ring
+        # reads the ring whatever the window (models/window_moe.py)
+        self.stats.attn_rows_read += self._rows_read[rows]
         self.stats.attn_rows_full += self.max_seq
+
+    def _device_counters(self) -> Dict[str, int]:
+        """What the programs have counted in every shard's cache, with
+        what dropped caches had; under the lock, so that no call takes
+        a cache away between finding it and reading it."""
+        with self._lock:
+            total = dict(self._counted_before)
+            for shard in self.shards:
+                if not self._cache_gone(shard):
+                    shard.counted = self._model.read_counters(shard.cache)
+                for name, n in shard.counted.items():
+                    total[name] = total.get(name, 0) + n
+            return total
 
     def _prefill(self, params, cache, tokens, slot_onehot, start, length, *,
                  bucket):
@@ -421,9 +479,15 @@ class LlamaEngine:
         return self._jit_decode(params, cache, last_tokens, lengths, temps,
                                 rng, rows=rows)
 
+    def _cache_gone(self, shard: _Shard) -> bool:
+        """Whether a call that failed after it was dispatched took the
+        shard's donated cache with it."""
+        return any(leaf.is_deleted() for leaf in
+                   self._jax.tree_util.tree_leaves(shard.cache))
+
     def _new_cache(self):
-        return self._llama.init_kv_cache(
-            self.config, self.max_batch, self.max_seq)
+        return self._model.init_cache(
+            self.config, self.max_batch, self.max_seq, self.prefill_chunk)
 
     def _new_tokens(self):
         return self._jax.device_put(np.zeros(self.max_batch, np.int32))
@@ -507,8 +571,12 @@ class LlamaEngine:
                 # a failed call's tokens would fail the next one too
                 s.unread = s.first = None
                 s.tokens = self._new_tokens()
-                if any(leaf.is_deleted() for leaf in s.cache.values()):
+                if self._cache_gone(s):
                     s.cache = self._new_cache()
+                    for name, n in s.counted.items():
+                        self._counted_before[name] = (
+                            self._counted_before.get(name, 0) + n)
+                    s.counted = {}
             return dropped
 
     def add_request(self, req: GenRequest) -> bool:
@@ -568,11 +636,13 @@ class LlamaEngine:
             return
         req = shard.prefilling[0]
         stats = self.stats
+        n = len(req.prompt_ids)
+        pos = req.prefill_pos
+        chunk = min(self.prefill_chunk, n - pos)
+        # ``rows``: the tokens this call carries, as a decode's span says
+        # its live lanes: what a trace of a few steps was asked for
         with phase("llm.prefill_dispatch", stats.phases,
-                   request_id=req.request_id, shard=shard.index):
-            n = len(req.prompt_ids)
-            pos = req.prefill_pos
-            chunk = min(self.prefill_chunk, n - pos)
+                   request_id=req.request_id, shard=shard.index, rows=chunk):
             bucket = next(b for b in self.buckets if b >= chunk)
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :chunk] = req.prompt_ids[pos:pos + chunk]
@@ -634,7 +704,8 @@ class LlamaEngine:
                 lens[slot] = shard.lengths[slot]
                 # the decode consumes the lane's last token: account it
                 shard.lengths[slot] += 1
-        with phase("llm.decode_dispatch", stats.phases, shard=shard.index):
+        with phase("llm.decode_dispatch", stats.phases, shard=shard.index,
+                   rows=len(lanes)):
             shard.tokens, shard.cache, self._rng = self._decode(
                 self.params, shard.cache, shard.tokens,
                 lens, temps, self._rng,

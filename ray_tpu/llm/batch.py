@@ -21,12 +21,10 @@ class _EngineUDF:
                  temperature: float):
         from ._internal.engine import LlamaEngine
 
-        from ray_tpu.models import llama
-
         self.max_tokens = max_tokens
         self.temperature = temperature
         self.engine = LlamaEngine(
-            llm_config.model_config or llama.LLAMA_TINY,
+            llm_config.resolved_model_config(),
             llm_config.load_params(),
             max_batch=llm_config.max_batch_size,
             max_seq=llm_config.max_seq_len,

@@ -20,7 +20,9 @@ class LLMConfig:
 
     model_id: str = "base"            # name openai-style bodies use for
     # the base model ({"model": model_id} routes to base, not a LoRA)
-    model_config: Any = None          # ray_tpu.models.llama.LlamaConfig
+    model_config: Any = None          # a model's configuration object
+    # (models.llama.LlamaConfig, models.window_moe.WindowMoEConfig): its
+    # ``model_module`` names the module that initialises and serves it
     checkpoint_path: Optional[str] = None  # orbax/npz dir; None = random init
     tensor_parallel_size: int = 1
     pipeline_parallel_size: int = 1
@@ -57,19 +59,27 @@ class LLMConfig:
             "SPREAD",
         )
 
+    def resolved_model_config(self):
+        """``model_config``, or the toy llama where none is given."""
+        if self.model_config is not None:
+            return self.model_config
+        from ray_tpu.models import llama
+
+        return llama.LLAMA_TINY
+
     def load_params(self):
         """Materialize model params: from checkpoint_path if given
         (orbax dir or .npz), else fresh initialization — one jitted
         program that writes every leaf in the configuration's
         param_dtype, not an eager float32 op per leaf."""
+        import importlib
         from functools import partial
 
         import jax
 
-        from ray_tpu.models import llama
-
-        cfg = self.model_config or llama.LLAMA_TINY
-        init = jax.jit(partial(llama.init_params, config=cfg))
+        cfg = self.resolved_model_config()
+        model = importlib.import_module(cfg.model_module)
+        init = jax.jit(partial(model.init_params, config=cfg))
         if not self.checkpoint_path:
             return init(jax.random.PRNGKey(0))
         import os

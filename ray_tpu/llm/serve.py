@@ -39,10 +39,10 @@ class LLMServer:
         params = llm_config.load_params()
         from ._internal.engine import LlamaEngine
 
-        from ray_tpu.models import llama
-
         self._base_params = params
-        self._model_config = llm_config.model_config or llama.LLAMA_TINY
+        # the engine builds what the configuration says: its model
+        # module's programs and cache
+        self._model_config = llm_config.resolved_model_config()
         self.engine = LlamaEngine(
             self._model_config,
             params,

@@ -58,10 +58,19 @@ class LlamaConfig:
     # B*S % 128 == 0, vocab % 128 == 0, no logit softcap)
     # logits softcap (Gemma-style) kept for generality; 0 disables.
     logit_softcap: float = 0.0
+    # a head's size where the heads do not add up to the hidden size;
+    # None: dim // n_heads. Read it as ``head_dim``. (A field of that
+    # name would be carried, resolved, through ``dataclasses.replace``
+    # into a configuration of another ``dim``.)
+    head_size: Optional[int] = None
+
+    # the module whose init_params / init_cache / forward_with_cache
+    # serve this configuration (llm/_internal/engine.py, llm/config.py)
+    model_module = "ray_tpu.models.llama"
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
 
     @property
     def q_per_kv(self) -> int:
@@ -441,8 +450,12 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
 # writes, length-masked attention, one jitted program per bucket).
 # ---------------------------------------------------------------------
 
-def init_kv_cache(config: LlamaConfig, batch: int, max_seq: int):
-    """Preallocated cache: k/v (L, B, KVH, max_seq, hd) in config.dtype."""
+def init_kv_cache(config: LlamaConfig, batch: int, max_seq: int,
+                  chunk: Optional[int] = None):
+    """Preallocated cache: k/v (L, B, KVH, max_seq, hd) in config.dtype.
+    ``chunk`` is the most rows one call writes, which the engine tells
+    every family and rows addressed by position do not need to know."""
+    del chunk
     c = config
     shape = (c.n_layers, batch, c.n_kv_heads, max_seq, c.head_dim)
     return {
@@ -451,10 +464,43 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_seq: int):
     }
 
 
-def _attention_cached(q, k_cache, v_cache, pos, config: LlamaConfig):
+init_cache = init_kv_cache      # the name the engine asks a family for
+
+
+def attn_rows_read(config: LlamaConfig, cache, rows: int) -> int:
+    """Cache rows a sequence one call reads for attention at the read
+    window ``rows``, the layers' mean: every layer reads the window."""
+    del config, cache
+    return rows
+
+
+def write_rows(stack, new, layer, first, start_pos):
+    """``new`` (B, T, KVH, hd) into the stacked cache (L, B', KVH, S, hd)
+    at layer ``layer``: sequence ``b``'s T rows from row ``start_pos[b]``
+    of cache row ``first + b``, and nothing else."""
+    new = new.astype(stack.dtype).transpose(0, 2, 1, 3)  # (B, KVH, T, hd)
+    for b in range(new.shape[0]):
+        stack = jax.lax.dynamic_update_slice(
+            stack, new[None, b:b + 1],
+            (layer, first + b, 0, start_pos[b], 0))
+    return stack
+
+
+def read_rows(stack, layer, first, batch: int, rows: int):
+    """Layer ``layer``'s first ``rows`` rows of the ``batch`` sequences
+    from cache row ``first`` on: (batch, KVH, rows, hd)."""
+    _, _, kvh, _, hd = stack.shape
+    return jax.lax.dynamic_slice(
+        stack, (layer, first, 0, 0, 0), (1, batch, kvh, rows, hd))[0]
+
+
+def _attention_cached(q, k_cache, v_cache, pos, config: LlamaConfig,
+                      mask=None):
     """q (B, T, H, hd) new queries at absolute positions ``pos`` (B, T);
     k/v_cache (B, KVH, S, hd) hold all tokens written so far (including
-    the new ones). Rows attend to cache slots <= their position."""
+    the new ones). Rows attend to cache slots <= their position, or to
+    the slots ``mask`` (B, T, S) names where the cache's rows are not
+    addressed by position."""
     B, T, H, hd = q.shape
     KVH, S = k_cache.shape[1:3]
     G = H // KVH
@@ -464,7 +510,8 @@ def _attention_cached(q, k_cache, v_cache, pos, config: LlamaConfig):
         "btkgh,bksh->bkgts", qg, k_cache,
         preferred_element_type=jnp.float32,
     ) * scale
-    mask = jnp.arange(S)[None, None, :] <= pos[:, :, None]  # (B, T, S)
+    if mask is None:
+        mask = jnp.arange(S)[None, None, :] <= pos[:, :, None]  # (B, T, S)
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkgts,bksh->btkgh", probs, v_cache)
@@ -521,18 +568,10 @@ def forward_with_cache(
     first = 0 if slot is None else slot  # the cache row of tokens' row 0
 
     def write(stack, new, layer):
-        # the T new rows of every sequence, and nothing else
-        new = new.astype(stack.dtype).transpose(0, 2, 1, 3)  # (B, KVH, T, hd)
-        for b in range(B):
-            stack = jax.lax.dynamic_update_slice(
-                stack, new[None, b:b + 1],
-                (layer, first + b, 0, start_pos[b], 0))
-        return stack
+        return write_rows(stack, new, layer, first, start_pos)
 
     def read(stack, layer):
-        # this layer's first `rows` rows of the B sequences
-        return jax.lax.dynamic_slice(
-            stack, (layer, first, 0, 0, 0), (1, B, KVH, rows, hd))[0]
+        return read_rows(stack, layer, first, B, rows)
 
     def body(carry, layer):
         x, k_all, v_all, i = carry

@@ -1,0 +1,499 @@
+"""Decoders whose layers follow a pattern and whose feed-forward is
+routed: every layer is pre-norm GQA attention and a dropless top-k
+mixture of SwiGLU experts, and ``layer_types`` says of each layer whether
+its attention is **sliding** (row i attends to rows j with
+0 <= i - j < ``sliding_window``, rotary table at ``rope_theta``) or
+**full** (every earlier row, the table of ``full_rope``: YaRN where it
+is given). Served through ``llm/_internal/engine.py`` as the llama family
+is; not trained (``ops/moe.py`` has no backward pass of the dropless
+layer).
+
+The shared pieces come from ``models/llama.py``: ``rms_norm``,
+``apply_rope``, the projections' layout, ``_attention_cached`` (a full
+layer is the llama family's cached attention at a head size of its own,
+a sliding layer the same einsums under another mask), the cache's row
+writes and reads.
+
+**The cache is not one pair of stacks.** A full layer keeps rows by
+position, ``(Lf, B, KVH, max_seq, hd)`` as the llama family does. A
+sliding layer keeps a ring of ``ring`` = ``sliding_window`` + the most
+rows a call writes (rounded up to a multiple of 8): position p lives in
+slot p mod ring, and which position a slot holds follows from the
+call's own ``start_pos`` and rows alone (the largest position at or
+before the call's last row that is congruent to the slot), so nothing a
+slot held before a sequence began is ever read, and rows a padded chunk
+wrote behind its real tokens fall outside every live query's window
+until the sequence overwrites them (that is what the ring's extra chunk
+of rows is for). One slot more, ``ring`` itself, is the scratch row of
+the engine's idle decode lanes (their position is ``max_seq - 1``, which
+no live sequence writes): position mod ring would land in a live row of
+a lane that is mid-prefill. Beside the rows rides ``counts``, the
+``moe_*`` counters of ``EngineStats`` accumulated on the device.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops import moe
+
+from .llama import (
+    LlamaConfig,
+    _attention_cached,
+    apply_rope,
+    init_attn_params,
+    make_dense_init,
+    read_rows,
+    rms_norm,
+    write_rows,
+)
+
+SLIDING, FULL = "sliding", "full"
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """A YaRN rotary table from the six numbers a configuration gives.
+    ``attention_factor`` None is 0.1 ln(factor) + 1."""
+
+    theta: float
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowMoEConfig(LlamaConfig):
+    # one entry a layer, "sliding" or "full"; a whole number of periods
+    layer_types: Tuple[str, ...] = (SLIDING, SLIDING, SLIDING, FULL)
+    sliding_window: int = 1024
+    # the full layers' rotary table; None: the default table at
+    # rope_theta, which is always the sliding layers'
+    full_rope: Optional[YarnRope] = None
+    n_experts: int = 64
+    experts_per_token: int = 8
+    expert_dim: int = 896
+    norm_topk_prob: bool = True
+
+    model_module = "ray_tpu.models.window_moe"
+
+    def __post_init__(self):
+        if len(self.layer_types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"n_layers is {self.n_layers}")
+        unknown = set(self.layer_types) - {SLIDING, FULL}
+        if unknown:
+            raise ValueError(f"layer_types holds {sorted(unknown)}")
+        if self.n_layers % len(self.period):
+            raise ValueError("layer_types is not a whole number of periods")
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The shortest pattern whose repetition begins ``layer_types``
+        (the last repetition may be cut: __post_init__ refuses that)."""
+        types = self.layer_types
+        for n in range(1, len(types) + 1):
+            if all(types[i] == types[i % n] for i in range(len(types))):
+                return types[:n]
+        return types
+
+    @property
+    def moe(self) -> moe.MoEConfig:
+        return moe.MoEConfig(
+            d_model=self.dim, d_ff=self.expert_dim, n_experts=self.n_experts,
+            k=self.experts_per_token, norm_topk_prob=self.norm_topk_prob)
+
+
+WINDOW_MOE_TINY = WindowMoEConfig(
+    vocab_size=512, dim=64, n_layers=4, n_heads=4, n_kv_heads=2,
+    head_size=32, ffn_dim=0, max_seq_len=256, rope_theta=10000.0,
+    remat=False, sliding_window=16,
+    full_rope=YarnRope(theta=10000.0, factor=4.0, original_max_position=64),
+    n_experts=8, experts_per_token=2, expert_dim=32,
+)
+
+
+# -- rotary tables -----------------------------------------------------
+def yarn_inv_freq(rope: YarnRope, head_dim: int) -> np.ndarray:
+    """The blended inverse frequencies: ``theta^(-2i/d)`` where a
+    dimension turns more than ``beta_fast`` times over the original
+    context, the same over ``factor`` where it turns fewer than
+    ``beta_slow`` times, a linear ramp over the dimensions between the
+    two correction dims (rounded down and up to whole dimensions)."""
+    half = head_dim // 2
+    extrapolation = rope.theta ** -(np.arange(half, dtype=np.float64) / half)
+    interpolation = extrapolation / rope.factor
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(rope.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return interpolation * ramp + extrapolation * (1.0 - ramp)
+
+
+def rope_cos_sin(config: WindowMoEConfig, kind: str, pos: jax.Array):
+    """cos and sin (..., hd/2) float32 at the positions ``pos`` for a
+    layer of ``kind``; a YaRN table's are scaled by its attention
+    factor."""
+    hd = config.head_dim
+    rope = config.full_rope if kind == FULL else None
+    if rope is None:
+        inv_freq = config.rope_theta ** -(
+            np.arange(0, hd, 2, dtype=np.float64) / hd)
+        scale = 1.0
+    else:
+        inv_freq = yarn_inv_freq(rope, hd)
+        scale = rope.attention_factor
+        if scale is None:
+            scale = 0.1 * math.log(rope.factor) + 1.0
+    freqs = pos[..., None].astype(jnp.float32) * jnp.asarray(
+        inv_freq, jnp.float32)
+    return jnp.cos(freqs) * scale, jnp.sin(freqs) * scale
+
+
+# -- parameters --------------------------------------------------------
+def param_specs(config: WindowMoEConfig) -> Dict[str, Any]:
+    """The llama attention shardings; the experts and the routers whole
+    on every device, which is how ``moe.moe_ffn_dropless`` takes them
+    under a mesh (its rows are split, its weights are not)."""
+    whole = P(None, None, None, None)
+    return {
+        "embed": P("model", "fsdp"),
+        "blocks": {
+            "attn_norm": P(None, None),
+            "wq": P(None, "fsdp", "model", None),
+            "wk": P(None, "fsdp", "model", None),
+            "wv": P(None, "fsdp", "model", None),
+            "wo": P(None, "model", None, "fsdp"),
+            "mlp_norm": P(None, None),
+            "router": P(None, None, None),
+            "w_gate": whole, "w_up": whole, "w_down": whole,
+        },
+        "final_norm": P(None),
+        "lm_head": P("fsdp", "model"),
+    }
+
+
+def init_params(rng: jax.Array, config: WindowMoEConfig) -> Dict[str, Any]:
+    """Stacked-layer parameters in ``param_dtype``; the router stays
+    float32, as ``moe_llama.init_params`` keeps it."""
+    c = config
+    (k_embed, k_q, k_k, k_v, k_o, k_r, k_g, k_u, k_d,
+     k_lm) = jax.random.split(rng, 10)
+    dense = make_dense_init(c)
+    L, E, D, F = c.n_layers, c.n_experts, c.dim, c.expert_dim
+    router = jax.random.normal(k_r, (L, D, E), jnp.float32) / math.sqrt(D)
+    return {
+        "embed": dense(k_embed, (c.vocab_size, D), D),
+        "blocks": {
+            **init_attn_params(c, (k_q, k_k, k_v, k_o), dense),
+            "router": router,
+            "w_gate": dense(k_g, (L, E, D, F), D),
+            "w_up": dense(k_u, (L, E, D, F), D),
+            "w_down": dense(k_d, (L, E, F, D), F),
+        },
+        "final_norm": jnp.ones((D,), c.param_dtype),
+        "lm_head": dense(k_lm, (D, c.vocab_size), D),
+    }
+
+
+# -- the sublayers -----------------------------------------------------
+def _qkv(c: WindowMoEConfig, x, layer, kind: str, pos):
+    """The pre-norm projections, turned by the layer's own table."""
+    h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
+    cos, sin = rope_cos_sin(c, kind, pos)
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def moe_sublayer(c: WindowMoEConfig, x, layer, experts, index, live=None):
+    """Pre-norm routed feed-forward + residual -> (x, counts int32[3]).
+    ``layer``: this layer's norm and router; ``experts``: every layer's
+    expert weights, stacked, of which this layer is ``index`` (the
+    grouped matmul takes the stack whole: ``ops/moe.py`` ``expert_ffn``
+    says why); ``live`` (B, T): the rows to count."""
+    with jax.named_scope("moe"):
+        h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
+        out, counts = moe.moe_ffn_dropless(
+            {"router": layer["router"],
+             **{k: w.astype(c.dtype) for k, w in experts.items()}},
+            h, c.moe, layer=index, live=live)
+        return x + out, counts
+
+
+def _layers_by_period(c: WindowMoEConfig, blocks):
+    """(the stacked blocks (L, ...) but the experts' as (periods, layers
+    a period, ...), for the scan over periods; the experts' weights,
+    stacked as they are)."""
+    n = len(c.period)
+    scanned = {k: a.reshape(c.n_layers // n, n, *a.shape[1:])
+               for k, a in blocks.items() if k not in EXPERT_WEIGHTS}
+    return scanned, {k: blocks[k] for k in EXPERT_WEIGHTS}
+
+
+def forward(params: Dict[str, Any], tokens: jax.Array,
+            config: WindowMoEConfig) -> jax.Array:
+    """tokens (B, S) int32 -> logits (B, S, V) float32: whole sequences,
+    XLA attention under each layer's own mask, no cache."""
+    c = config
+    B, S = tokens.shape
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(c.dtype)[tokens]
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    behind = pos[:, :, None] - jnp.arange(S)[None, None, :]   # i - j
+    masks = {FULL: behind >= 0,
+             SLIDING: (behind >= 0) & (behind < c.sliding_window)}
+
+    scanned, experts = _layers_by_period(c, params["blocks"])
+
+    def body(carry, period):
+        x, p = carry
+        for j, kind in enumerate(c.period):
+            layer = jax.tree_util.tree_map(lambda a: a[j], period)
+            with jax.named_scope("attn"):
+                q, k, v = _qkv(c, x, layer, kind, pos)
+                attn = _attention_cached(
+                    q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pos,
+                    c, mask=masks[kind])
+                x = x + jnp.einsum(
+                    "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+            x, _ = moe_sublayer(c, x, layer, experts,
+                                p * len(c.period) + j)
+        return (x, p + 1), None
+
+    with jax.named_scope("layers"):
+        (x, _), _ = jax.lax.scan(body, (x, jnp.int32(0)), scanned)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
+        return logits.astype(jnp.float32)
+
+
+# -- the cache ---------------------------------------------------------
+# the counters' low words carry into the high ones from here
+_CARRY_BITS = 30
+# slots a ring has past its rows: the first is the idle lanes' scratch
+# row, the rest keep the rows a multiple of 8
+_SCRATCH_SLOTS = 8
+
+
+def ring_rows(config: WindowMoEConfig, chunk: int) -> int:
+    """Slots of a sliding layer's ring for calls of ``chunk`` rows at
+    the most: the window and a chunk, to a multiple of 8."""
+    return -(-(config.sliding_window + chunk) // 8) * 8
+
+
+def init_cache(config: WindowMoEConfig, batch: int, max_seq: int,
+               chunk: int):
+    """``full``: k/v (full layers, B, KVH, max_seq, hd) by position;
+    ``ring``: k/v (sliding layers, B, KVH, ring + _SCRATCH_SLOTS, hd),
+    slot ``ring`` the idle lanes' scratch row; ``counts``: int32 (3, 2),
+    the high and low words of moe_assignments, moe_experts_touched,
+    moe_expert_slots. ``chunk``: the most rows a call will write."""
+    c = config
+    n_full = c.layer_types.count(FULL)
+    ring = min(ring_rows(c, chunk), -(-max_seq // 8) * 8)
+
+    def stacks(layers, rows):
+        shape = (layers, batch, c.n_kv_heads, rows, c.head_dim)
+        return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
+
+    return {"full": stacks(n_full, max_seq),
+            "ring": stacks(c.n_layers - n_full, ring + _SCRATCH_SLOTS),
+            "counts": jnp.zeros((3, 2), jnp.int32)}
+
+
+def attn_rows_read(config: WindowMoEConfig, cache, rows: int) -> float:
+    """Cache rows a sequence one call reads for attention at the read
+    window ``rows``, the layers' mean: a full layer's the window, a
+    sliding layer's its ring."""
+    ring = cache["ring"]["k"].shape[3] - _SCRATCH_SLOTS
+    n_full = config.layer_types.count(FULL)
+    n_ring = config.n_layers - n_full
+    return (n_full * rows + n_ring * ring) / config.n_layers
+
+
+def read_counters(cache) -> Dict[str, int]:
+    """The ``moe_*`` counters one cache shard has accumulated (waits for
+    the program that last wrote it)."""
+    hi_lo = np.asarray(cache["counts"]).astype(np.int64)
+    totals = (hi_lo[:, 0] << _CARRY_BITS) + hi_lo[:, 1]
+    return dict(zip(("moe_assignments", "moe_experts_touched",
+                     "moe_expert_slots"), (int(t) for t in totals)))
+
+
+def _ring_write(stack, new, layer, first, start_pos, ring: int, max_seq: int):
+    """``new`` (B, T, KVH, hd) into the ring stack at layer ``layer``:
+    sequence b's row t to slot (start_pos[b] + t) mod ring of cache row
+    ``first + b``. One row (a decode) is one update, an idle lane's
+    (position max_seq - 1) to the scratch slot. T rows may wrap, so they
+    go in as two blocks of T slots, each read, merged and written back:
+    the block that ends at the ring's end at the latest and the block at
+    its start."""
+    new = new.astype(stack.dtype).transpose(0, 2, 1, 3)      # (B, KVH, T, hd)
+    B, KVH, T, hd = new.shape
+    if T > ring:
+        raise ValueError(f"a call of {T} rows into a ring of {ring}")
+    at = jnp.arange(T)[None, :, None]                        # (1, T, 1)
+    for b in range(B):
+        row, lane, o = new[b], first + b, start_pos[b] % ring
+        if T == 1:
+            slot = jnp.where(start_pos[b] == max_seq - 1, ring, o)
+            stack = jax.lax.dynamic_update_slice(
+                stack, row[None, None], (layer, lane, 0, slot, 0))
+            continue
+        before_wrap = jnp.minimum(T, ring - o)
+        a0 = jnp.minimum(o, ring - T)
+        blocks = (
+            # slots [a0, a0 + T): new row i - (o - a0) from slot o on
+            (a0, jnp.roll(row, o - a0, axis=1), at >= o - a0),
+            # slots [0, T): new rows before_wrap, before_wrap + 1, ...
+            (0, jnp.roll(row, -before_wrap, axis=1), at < T - before_wrap))
+        for slot, rolled, fresh in blocks:
+            old = jax.lax.dynamic_slice(
+                stack, (layer, lane, 0, slot, 0), (1, 1, KVH, T, hd))
+            stack = jax.lax.dynamic_update_slice(
+                stack, jnp.where(fresh, rolled, old[0, 0])[None, None],
+                (layer, lane, 0, slot, 0))
+    return stack
+
+
+def _ring_mask(pos, start_pos, T: int, ring: int, slots: int, window: int):
+    """(B, T, slots): which slots of its ring each query attends to.
+    Slot r holds the largest position at or before the call's last row
+    that is congruent to r mod ring; it is attended to where that
+    position is no earlier than 0, no later than the query's, and
+    inside its window. The scratch slots past ``ring`` never are."""
+    last = (start_pos + T - 1)[:, None]                          # (B, 1)
+    r = jnp.arange(slots)[None, :]
+    held = last - (last - r) % ring                              # (B, slots)
+    behind = pos[:, :, None] - held[:, None, :]              # (B, T, slots)
+    ok = (r < ring) & (held >= 0)
+    return ok[:, None, :] & (behind >= 0) & (behind < window)
+
+
+def forward_with_cache(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    cache: Dict[str, Any],
+    start_pos: jax.Array,
+    config: WindowMoEConfig,
+    *,
+    slot: Optional[jax.Array] = None,
+    logits_at: Optional[jax.Array] = None,
+    rows: Optional[int] = None,
+):
+    """``llama.forward_with_cache``'s signature and meaning (tokens
+    (B, T) appended at ``start_pos`` (B,), ``slot``, ``logits_at``,
+    ``rows``) over this family's cache (``init_cache``). ``rows`` bounds
+    the full layers' read and leaves the rings alone. The layer scan runs
+    over whole periods, a period's layers unrolled inside it; the stacks
+    and the counters ride in its carry and are updated in place under a
+    jit that donates the cache."""
+    c = config
+    B, T = tokens.shape
+    max_seq = cache["full"]["k"].shape[3]
+    slots = cache["ring"]["k"].shape[3]
+    ring = slots - _SCRATCH_SLOTS
+    rows = max_seq if rows is None else rows
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(c.dtype)[tokens]
+    pos = start_pos[:, None] + jnp.arange(T)[None, :]            # (B, T)
+    first = 0 if slot is None else slot
+    # whose rows are somebody's tokens, for the moe_* counters: not an
+    # idle decode lane (the engine gives it position max_seq - 1), not
+    # the rows of a padded chunk behind the one its logits are taken at
+    if T == 1:
+        live = start_pos[:, None] != max_seq - 1
+    elif logits_at is not None:
+        live = jnp.arange(T)[None, :] <= logits_at[:, None]
+    else:
+        live = None
+    ring_mask = _ring_mask(pos, start_pos, T, ring, slots, c.sliding_window)
+    period = c.period
+    per = {kind: period.count(kind) for kind in (FULL, SLIDING)}
+
+    def body(carry, layers):
+        x, stacks, counts, p = carry
+        seen = {FULL: 0, SLIDING: 0}
+        for j, kind in enumerate(period):
+            layer = jax.tree_util.tree_map(lambda a: a[j], layers)
+            i = p * per[kind] + seen[kind]      # this layer among its kind
+            seen[kind] += 1
+            k_all, v_all = stacks[kind]
+            with jax.named_scope("attn"):
+                q, k, v = _qkv(c, x, layer, kind, pos)
+                with jax.named_scope("kv_write"):
+                    if kind == FULL:
+                        k_all = write_rows(k_all, k, i, first, start_pos)
+                        v_all = write_rows(v_all, v, i, first, start_pos)
+                    else:
+                        k_all = _ring_write(k_all, k, i, first, start_pos,
+                                            ring, max_seq)
+                        v_all = _ring_write(v_all, v, i, first, start_pos,
+                                            ring, max_seq)
+                with jax.named_scope("kv_slice"):
+                    n = rows if kind == FULL else slots
+                    k_c = read_rows(k_all, i, first, B, n)
+                    v_c = read_rows(v_all, i, first, B, n)
+                    if T >= 128:    # llama.forward_with_cache says why
+                        k_c = jax.lax.optimization_barrier(k_c)
+                if kind == FULL:
+                    with jax.named_scope("attn_cached"):
+                        attn = _attention_cached(q, k_c, v_c, pos, c)
+                else:
+                    with jax.named_scope("attn_window"):
+                        attn = _attention_cached(q, k_c, v_c, pos, c,
+                                                 mask=ring_mask)
+                x = x + jnp.einsum(
+                    "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+            stacks = {**stacks, kind: (k_all, v_all)}
+            x, counted = moe_sublayer(c, x, layer, experts,
+                                      p * len(period) + j, live)
+            counts = counts + counted
+        return (x, stacks, counts, p + 1), None
+
+    stacks = {kind: (cache[name]["k"], cache[name]["v"])
+              for kind, name in ((FULL, "full"), (SLIDING, "ring"))}
+    scanned, experts = _layers_by_period(c, params["blocks"])
+    with jax.named_scope("layers"):
+        (x, stacks, counted, _), _ = jax.lax.scan(
+            body, (x, stacks, jnp.zeros(3, jnp.int32), jnp.int32(0)),
+            scanned)
+        low = cache["counts"][:, 1] + counted
+        counts = jnp.stack(
+            [cache["counts"][:, 0] + (low >> _CARRY_BITS),
+             low & ((1 << _CARRY_BITS) - 1)], axis=1)
+    with jax.named_scope("head"):
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
+    new_cache = {
+        "full": dict(zip(("k", "v"), stacks[FULL])),
+        "ring": dict(zip(("k", "v"), stacks[SLIDING])),
+        "counts": counts}
+    return logits.astype(jnp.float32), new_cache

@@ -12,6 +12,17 @@ Pieces:
   the standard load-balancing auxiliary loss.
 - ``moe_ffn``: routed expert FFN (SwiGLU experts) usable inside any
   jitted model; shard params' leading E dim on the `expert` axis.
+- ``moe_ffn_dropless``: the same layer for inference, with no capacity
+  and no token dropped: the assignments are sorted by expert and each
+  expert multiplies its own rows (``jax.lax.ragged_dot``, which the TPU
+  compiler lowers to a grouped-matmul kernel that visits an expert's
+  weights once and only the row tiles that hold its rows). A call of a
+  few rows whose assignments reach every expert anyway (a decode call)
+  multiplies its rows by every expert's weights in one batched matmul
+  and weighs what an expert was not chosen for by 0: the weights' read
+  bounds both, and the batched matmul comes close to it
+  (``EVERY_EXPERT_ROWS``). No backward pass is written for it; training
+  keeps ``moe_ffn``.
 """
 
 from __future__ import annotations
@@ -22,6 +33,9 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ._partition import ambient_partition
 
 
 class GatingResult(NamedTuple):
@@ -99,7 +113,10 @@ class MoEConfig:
     d_ff: int
     n_experts: int
     k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the training layer's alone
+    # the chosen experts' weights renormalised to sum to 1 (the
+    # dropless layer; the training layer always does)
+    norm_topk_prob: bool = True
 
 
 def init_moe_params(key, config: MoEConfig, dtype=jnp.bfloat16):
@@ -146,3 +163,160 @@ def moe_ffn(
     # combine back: (E,C,D),(T,E,C) -> (T,D)
     out = jnp.einsum("ecd,tec->td", ye, gate.combine.astype(x.dtype))
     return out.reshape(B, S, D), gate.aux_loss
+
+
+# ---------------------------------------------------------------------
+# The dropless layer (inference). Four named scopes, so that a device
+# trace says what routing costs beside the matmuls: moe_router,
+# moe_dispatch, moe_experts, moe_combine.
+# ---------------------------------------------------------------------
+
+def route_top_k(x: jax.Array, router: jax.Array, config: MoEConfig):
+    """x (T, D), router (D, E) -> (weights (T, k) float32, experts
+    (T, k) int32): a softmax over all experts in float32, the k
+    largest, renormalised to sum to 1 where the configuration says so."""
+    probs = jax.nn.softmax(
+        x.astype(jnp.float32) @ router.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, config.k)
+    if config.norm_topk_prob:
+        weights = weights / weights.sum(-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def expert_ffn(xs, w_gate, w_up, w_down, group_sizes, layer=None):
+    """The grouped SwiGLU: rows ``xs`` (N, D) sorted by expert,
+    ``group_sizes`` (E,) rows each; (E, D, F) / (E, F, D) weights ->
+    (N, D) float32. An expert with no rows costs nothing and its weights
+    are not read; all rows at one expert is one plain matmul.
+
+    With ``layer`` (a traced index) the weights are a model's stacked
+    (L, E, D, F) / (L, E, F, D) and the experts are that layer's: the
+    stack goes to the grouped matmul whole, as L * E groups of which all
+    but this layer's E have no rows. A layer's experts sliced out of the
+    stack would be copied, all of them, before every call (the grouped
+    matmul is a custom call, which no slice fuses into): at 64 experts
+    of 2304 x 896 that is 0.8 GB a layer a call."""
+    if layer is not None:
+        L, E = w_gate.shape[:2]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros(L * E, group_sizes.dtype), group_sizes, (layer * E,))
+        w_gate, w_up, w_down = (
+            w.reshape(L * E, *w.shape[2:]) for w in (w_gate, w_up, w_down))
+    gate = jax.lax.ragged_dot(xs, w_gate, group_sizes)
+    up = jax.lax.ragged_dot(xs, w_up, group_sizes)
+    return jax.lax.ragged_dot(
+        jax.nn.silu(gate) * up, w_down, group_sizes,
+        preferred_element_type=jnp.float32)
+
+
+# The most rows of a call that go through every expert
+# (``expert_ffn_every``) where they make as many assignments as there are
+# experts. Under the ridge (240 rows a weight on a v5e) a weight's read
+# bounds its matmul whatever rows it multiplies, and T * k >= E
+# assignments leave few experts without a row (16 rows, top-8 of 64: 88 %
+# have one), so every expert over every row asks for the read the grouped
+# matmul asks for; the compiler's grouped kernel at two rows an expert
+# took six times that read on a v5e (PERF.md section 6, PR 46). Past
+# these rows the (E, T, D) float32 products are no longer small and the
+# matmuls' own time shows, so a prefill chunk stays grouped.
+EVERY_EXPERT_ROWS = 64
+
+
+def expert_ffn_every(x, w_gate, w_up, w_down, combine, layer=None):
+    """Every row through every expert: ``x`` (T, D), ``combine`` (T, E)
+    float32 a row's weight at each expert it chose and 0 at the others
+    -> (T, D) float32, the sum ``expert_ffn`` and the combine give. The
+    weights are read once, as one batched matmul; ``layer`` takes a
+    layer's experts out of a model's stack (a slice that fuses into the
+    matmul, which a grouped kernel's does not)."""
+    if layer is not None:
+        w_gate, w_up, w_down = (
+            jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+            for w in (w_gate, w_up, w_down))
+    gate = jnp.einsum("td,edf->etf", x, w_gate)
+    up = jnp.einsum("td,edf->etf", x, w_up)
+    ys = jnp.einsum("etf,efd->etd", jax.nn.silu(gate) * up, w_down,
+                    preferred_element_type=jnp.float32)
+    # weighed and summed in float32 as the grouped path's combine is (a
+    # float32 matmul at the default precision would round both to bf16)
+    return (ys * combine.T[:, :, None]).sum(0)
+
+
+def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
+                   config: MoEConfig, layer=None, sum_over=None):
+    """x (T, D), live (T,) bool -> (out (T, D), counts int32[3]: the
+    live rows' assignments, experts with a live row or more, experts
+    held); ``sum_over``: the mesh axes the rows are split over, inside a
+    shard_map."""
+    T, D = x.shape
+    E, k = config.n_experts, config.k
+    with jax.named_scope("moe_router"):
+        weights, experts = route_top_k(x, params["router"], config)
+    if E <= T * k and T <= EVERY_EXPERT_ROWS:
+        with jax.named_scope("moe_dispatch"):
+            combine = jnp.zeros((T, E), jnp.float32).at[
+                jnp.arange(T)[:, None], experts].set(weights)
+        with jax.named_scope("moe_experts"):
+            out = expert_ffn_every(
+                x, params["w_gate"], params["w_up"], params["w_down"],
+                combine, layer).astype(x.dtype)
+    else:
+        with jax.named_scope("moe_dispatch"):
+            flat = experts.reshape(T * k)
+            order = jnp.argsort(flat, stable=True)    # assignments by expert
+            group_sizes = jnp.zeros(E, jnp.int32).at[flat].add(1)
+            xs = x[order // k]                        # (T*k, D)
+        with jax.named_scope("moe_experts"):
+            ys = expert_ffn(xs, params["w_gate"], params["w_up"],
+                            params["w_down"], group_sizes, layer)
+        with jax.named_scope("moe_combine"):
+            back = jnp.argsort(order)                 # each assignment's row
+            ys = ys[back].reshape(T, k, D)
+            out = (ys * weights[..., None]).sum(1).astype(x.dtype)
+    with jax.named_scope("moe_combine"):
+        # what was asked for: a padded chunk's rows behind its tokens and
+        # an idle decode lane are computed and not counted
+        asked = jnp.zeros(E, jnp.int32).at[experts].add(
+            live[:, None].astype(jnp.int32))
+        counts = jnp.stack([live.sum().astype(jnp.int32) * k,
+                            (asked > 0).sum().astype(jnp.int32),
+                            jnp.int32(E)])
+        if sum_over:
+            # every shard of the rows visits its own experts
+            counts = jax.lax.psum(counts, sum_over)
+    return out, counts
+
+
+def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
+                     layer=None, live=None):
+    """Routed SwiGLU expert FFN with no capacity: every token goes to
+    its ``config.k`` experts. x (..., D) -> (out (..., D), counts): the
+    int32 vector (assignments, experts touched, experts held) of this
+    call, for the engine's ``moe_*`` counters. ``layer``: the expert
+    weights are a model's stacked ones and this is the layer among them
+    (``expert_ffn``); the router is the layer's own. ``live`` (...) bool:
+    the rows that are somebody's tokens (default: all); the others are
+    computed like them and left out of the counts.
+
+    The grouped matmul is a kernel the TPU compiler puts in (a Mosaic
+    custom call), which GSPMD cannot partition: under a mesh with more
+    than one device the rows are split over the batch axes by hand, each
+    shard sorting and multiplying its own rows against the whole of the
+    experts (the weights replicated), as ``ops/attention.py`` maps its
+    kernel."""
+    lead, D = x.shape[:-1], x.shape[-1]
+    rows = x.reshape(-1, D)
+    live = (jnp.ones(rows.shape[0], bool) if live is None
+            else jnp.broadcast_to(live, lead).reshape(-1))
+    part = ambient_partition()
+    if part is None or part.batch is None:
+        out, counts = _dropless_rows(params, rows, live, config, layer)
+    else:
+        out, counts = jax.shard_map(
+            lambda p, r, a, i: _dropless_rows(p, r, a, config, i,
+                                              sum_over=part.batch),
+            in_specs=(P(), P(part.batch, None), P(part.batch), P()),
+            out_specs=(P(part.batch, None), P()),
+            axis_names=part.axes, check_vma=False,
+        )(params, rows, live, layer)
+    return out.reshape(*lead, D), counts
