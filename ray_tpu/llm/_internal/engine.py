@@ -14,23 +14,39 @@ re-designed for XLA instead of wrapped:
   engine step, interleaved with decode — a long prompt cannot stall
   the decode of already-running sequences (vLLM's chunked-prefill
   scheduler, reference llm/_internal/batch/stages/vllm_engine_stage.py
-  wraps the same idea). The chunk is sized from the chip, not set.
-  Every call reads all the weights once, so a chunk wants rows enough
-  to pay for that read. The rule taken is the roofline's ridge: the
-  rows at which a call's matmuls take as long as the read (the chip's
-  FLOPs per HBM byte, times the weights' bytes per parameter over 2:
-  240 rows of bf16 on a v5e), to the nearest power of two
-  (``derived_prefill_chunk``; 256 on a v5e), whatever the model. It
-  is a rule of thumb, not a knee that was measured: on a v5e a call's
-  time grew nearly in line with its rows from 128 on (attention over
-  the whole cache and the activations grow with them), and 512 rows
-  served every benchmark cell better than 256 (PERF.md section 6,
-  PR 29). What bounds a chunk from above is how long the decode step
-  behind it may wait, which no cell judges yet. Three chunk buckets
-  (C/4, C/2, C) bound compilations. The head runs on the one row a
-  chunk returns logits for. ``warm_up()`` runs every program once, at
-  every read window; ``LLMServer`` calls it before it takes a request,
-  a bare engine compiles on first use.
+  wraps the same idea). The chunk is sized from the chip and the
+  model, not set. Every call reads the weights once, so a chunk wants
+  rows enough to pay for that read. The rule taken is the roofline's
+  ridge: the rows at which a matmul takes as long as reading its
+  weight (the chip's FLOPs per HBM byte, times the weights' bytes per
+  parameter over 2: 240 rows of bf16 on a v5e), counted for the
+  weights that hold most of a call's bytes, over the share of the
+  call's rows that multiply them, to the nearest power of two
+  (``derived_prefill_chunk``). In ``models/llama.py`` every row meets
+  every weight: share 1, 240 -> 256 rows on a v5e. In
+  ``models/window_moe.py`` the experts are 95 % of a layer's bytes
+  and a row meets ``experts_per_token`` of ``n_experts`` of them
+  (the module's ``weight_row_share``): for 8 of 64 an expert sees an
+  eighth of a call's rows, so 240 x 8 = 1920 -> 2048 rows. Not the
+  call's FLOPs over its bytes, which would give 1024 there: a call's
+  matmuls run one after another, the dense projections are past their
+  ridge at 240 rows whatever the chunk, and the expert matrices stay
+  read-bound until each sees 240. It is a rule of thumb, not a knee
+  that was measured: on a v5e a llama call's time grew nearly in line
+  with its rows from 128 on (attention over the whole cache and the
+  activations grow with them) and 512 rows served every llama cell
+  better than 256 (PERF.md section 6, PR 29); the routed cell
+  completes 39 % more tokens a second at 2048 rows than at 256 and as
+  many at 1024 as at 2048, the compiler's grouped matmul being far
+  under either roof at any of them (PERF.md section 6, PR 47). What
+  bounds a chunk from above is how long the decode step behind it may
+  wait (a token's gap at the 99th percentile is a quarter longer at
+  2048 rows than at 256), which no cell judges yet and no rule here
+  accounts for. Three chunk buckets (C/4, C/2, C) bound compilations.
+  The head runs on the one row a chunk returns logits for.
+  ``warm_up()`` runs every program once, at every read window;
+  ``LLMServer`` calls it before it takes a request, a bare engine
+  compiles on first use.
 - The model is the configuration's: ``config.model_module`` names the
   module that gives ``forward_with_cache``, ``init_cache`` and
   ``attn_rows_read`` (``models/llama.py``; ``models/window_moe.py``,
@@ -120,15 +136,19 @@ REQUEST_PHASES = ("ingress", "accept",
 
 
 def derived_prefill_chunk(device_kind: str, bytes_per_param: float,
-                          max_seq: int) -> int:
+                          max_seq: int, row_share: float = 1.0) -> int:
     """The prefill chunk for weights of ``bytes_per_param`` on a chip of
-    ``device_kind``: the power of two nearest the rows at which a call's
-    matmuls (2 FLOPs a row a parameter) take as long as reading its
-    weights once, fitted to ``max_seq``. Measured on a v5e only (256
-    rows there); the other kinds' sizes follow from the table alone."""
+    ``device_kind``: the power of two nearest the rows at which the
+    matmuls over the weights that hold most of a call's bytes (2 FLOPs
+    a row a parameter) take as long as reading those weights once,
+    fitted to ``max_seq``. ``row_share`` is the share of a call's rows
+    that multiply such a weight: 1 where every row meets every weight
+    (240 -> 256 rows of bf16 on a v5e), ``experts_per_token / n_experts``
+    for routed experts (8 of 64: 240 x 8 = 1920 -> 2048). Measured on a
+    v5e only; the other kinds' sizes follow from the table alone."""
     from ray_tpu._private.accelerators.tpu import flops_per_hbm_byte
 
-    rows = flops_per_hbm_byte(device_kind) * bytes_per_param / 2
+    rows = flops_per_hbm_byte(device_kind) * bytes_per_param / 2 / row_share
     return _fit_chunk(2 ** round(math.log2(rows)), max_seq)
 
 
@@ -286,8 +306,9 @@ class LlamaEngine:
 
         # the model's functions are the configuration's: the module it
         # names gives forward_with_cache, init_cache and attn_rows_read
-        # (models/llama.py; models/window_moe.py), and read_counters
-        # where its programs count on the device
+        # (models/llama.py; models/window_moe.py), read_counters where
+        # its programs count on the device, and weight_row_share where
+        # not every row of a call meets its heaviest weights
         model = importlib.import_module(config.model_module)
 
         self.config = config
@@ -296,10 +317,12 @@ class LlamaEngine:
         self.max_seq = max_seq
         if prefill_chunk is None:
             leaves = jax.tree_util.tree_leaves(params)
+            share = (model.weight_row_share(config)
+                     if hasattr(model, "weight_row_share") else 1.0)
             self.prefill_chunk = derived_prefill_chunk(
                 jax.devices()[0].device_kind,
                 sum(a.nbytes for a in leaves) / sum(a.size for a in leaves),
-                max_seq)
+                max_seq, share)
         else:
             self.prefill_chunk = _fit_chunk(prefill_chunk, max_seq)
         # growth is whole-shard; round the cap to shard granularity so

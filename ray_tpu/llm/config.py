@@ -32,9 +32,12 @@ class LLMConfig:
     # passed to LlamaEngine (max_slots=...). The prefill chunk is not
     # among what a deployment sets: the engine takes the roofline's
     # ridge of its chip (FLOPs per HBM byte, times the weights' bytes
-    # per parameter over 2) as a rule of thumb, 256 rows of bf16 on a
-    # v5e, the one chip it was measured on. The server runs every engine
-    # program once (LlamaEngine.warm_up) before it takes a request
+    # per parameter over 2), over the share of a call's rows that its
+    # model's heaviest weights see, as a rule of thumb: 256 rows of bf16
+    # for a dense model and 2048 for experts of which a row meets 8 of
+    # 64, on a v5e, the one chip it was measured on. The server runs
+    # every engine program once (LlamaEngine.warm_up) before it takes a
+    # request
     engine_kwargs: Dict[str, Any] = field(default_factory=dict)
     # LoRA multiplexing (reference: ray.llm LoraConfig):
     #   {"dynamic_lora_loading_path": dir with <adapter_id>.npz,

@@ -432,6 +432,9 @@ class LLMServer:
                 len(s.free_slots) for s in self.engine.shards
             ),
             "max_batch": self.engine.max_batch,
+            # the rows a prefill call carries at the most, as the engine
+            # derived them or was given them
+            "prefill_chunk": self.engine.prefill_chunk,
             "shards": len(self.engine.shards),
             # cumulative counters, seconds per part of step(), and the
             # finished requests' phases (EngineStats.snapshot); a reader

@@ -29,6 +29,15 @@ the engine's idle decode lanes (their position is ``max_seq - 1``, which
 no live sequence writes): position mod ring would land in a live row of
 a lane that is mid-prefill. Beside the rows rides ``counts``, the
 ``moe_*`` counters of ``EngineStats`` accumulated on the device.
+
+**What the engine's chunk rule is told.** The experts' three matrices
+hold most of a layer's bytes (95 % at 64 experts of 3 x 2304 x 896
+beside 21 M of attention projections and router), and a row multiplies
+``experts_per_token`` of the ``n_experts`` of them, so an expert sees
+that share of a call's rows. ``weight_row_share`` says so from the
+configuration's two counts, and ``engine.derived_prefill_chunk``
+divides the ridge's rows by it: a chunk then gives each expert the
+rows that pay for reading it (8 of 64 on a v5e: 2048 rows, not 256).
 """
 
 from __future__ import annotations
@@ -121,6 +130,15 @@ WINDOW_MOE_TINY = WindowMoEConfig(
     full_rope=YarnRope(theta=10000.0, factor=4.0, original_max_position=64),
     n_experts=8, experts_per_token=2, expert_dim=32,
 )
+
+
+def weight_row_share(config: WindowMoEConfig) -> float:
+    """The share of a call's rows that multiply one of the weights that
+    hold most of its bytes: an expert's, which a row in
+    ``experts_per_token / n_experts`` meets (dropless, so every
+    assignment is computed). A family of dense and routed layers would
+    answer for whichever holds most of a layer's bytes."""
+    return config.experts_per_token / config.n_experts
 
 
 # -- rotary tables -----------------------------------------------------

@@ -426,6 +426,8 @@ def test_engine_stats_call_returns_the_snapshot(llm_server):
     # what it returned before this PR is still there
     assert {"active", "peak_active", "free_slots", "max_batch", "shards",
             "platform", "pid"} <= set(after)
+    # the chunk that served the run, derived or given
+    assert after["prefill_chunk"] == llm_server.engine.prefill_chunk
     eng = after["engine"]
     assert eng["requests_finished"] == 1 and eng["tokens_emitted"] == 4
     assert eng["prefill_tokens"] == 39

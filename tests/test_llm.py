@@ -112,7 +112,7 @@ def test_chunked_prefill_decodes_while_prefilling(engine_setup):
     assert solo.generate(list(range(1, 200)), max_tokens=4) == long.generated
 
 
-@pytest.mark.parametrize("kind, dtype, max_seq, want", [
+DENSE_CHUNKS = [
     # peak FLOP/s over HBM bytes/s, times bytes a parameter over 2, to
     # the nearest power of two: 240 rows of bf16 on a v5e
     ("TPU v5 lite", "bfloat16", 2048, 256),
@@ -126,13 +126,34 @@ def test_chunked_prefill_decodes_while_prefilling(engine_setup):
     ("some later chip", "float32", 2048, 256),
     ("TPU v5 lite", "bfloat16", 64, 64),      # capped by max_seq
     ("TPU v5 lite", "bfloat16", 384, 128),    # halved until it divides
+]
+
+
+@pytest.mark.parametrize("kind, dtype, max_seq, share, want", [
+    # every row meets every weight: the three-argument call, and a share
+    # of 1 stated, give the same table to the row
+    *[(*row[:3], share, row[3]) for share in (None, 1.0)
+      for row in DENSE_CHUNKS],
+    # a row meets 8 of 64 experts, which hold the bytes: an expert sees
+    # an eighth of a call's rows, so the ridge's 240 are 1920 of the call
+    ("TPU v5 lite", "bfloat16", 8192, 8 / 64, 2048),
+    ("TPU v5 lite", "bfloat16", 4096, 8 / 64, 2048),
+    ("TPU v5 lite", "bfloat16", 1024, 8 / 64, 1024),  # capped by max_seq
+    ("TPU v5 lite", "bfloat16", 3072, 8 / 64, 1024),  # until it divides
+    ("TPU v4", "bfloat16", 8192, 8 / 64, 2048),       # 1791
+    ("TPU v5p", "bfloat16", 8192, 8 / 64, 1024),      # 1328
+    ("TPU v6 lite", "bfloat16", 8192, 8 / 64, 4096),  # 4478
+    ("TPU v5 lite", "bfloat16", 8192, 2 / 8, 1024),   # 962
+    ("TPU v5 lite", "float32", 8192, 1 / 2, 1024),    # 962
 ])
-def test_prefill_chunk_is_derived_from_the_chip(kind, dtype, max_seq, want):
+def test_prefill_chunk_is_derived_from_the_chip(kind, dtype, max_seq, share,
+                                                want):
     from ray_tpu.llm._internal.engine import derived_prefill_chunk
 
     import jax.numpy as jnp
 
-    chunk = derived_prefill_chunk(kind, jnp.dtype(dtype).itemsize, max_seq)
+    chunk = derived_prefill_chunk(kind, jnp.dtype(dtype).itemsize, max_seq,
+                                  *(() if share is None else (share,)))
     assert chunk == want and max_seq % chunk == 0
 
 

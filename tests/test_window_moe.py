@@ -448,6 +448,48 @@ def test_engine_serves_the_family_under_continuous_batching(toy):
     assert eng.stats.snapshot()["moe_assignments"] == s["moe_assignments"]
 
 
+@pytest.mark.parametrize("family, chunk", [("llama", 256),
+                                           ("window_moe", 1024)])
+def test_engine_sizes_its_chunk_by_the_rows_its_heaviest_weights_see(
+        family, chunk):
+    """Built with no ``prefill_chunk``: a llama engine has the ridge's
+    rows as before; the routed family's has them over the share of a
+    call's rows an expert sees (2 of 8 here), with the buckets, the
+    windows and the ring that follow, and serves the tokens a 16-row
+    chunk does."""
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    f32 = {"dtype": jnp.float32, "param_dtype": jnp.float32, "remat": False}
+    if family == "llama":
+        cfg = dataclasses.replace(llama.LLAMA_TINY, **f32)
+        params, share = llama.init_params(jax.random.PRNGKey(0), cfg), 1.0
+        assert not hasattr(llama, "weight_row_share")
+    else:
+        cfg = dataclasses.replace(wm.WINDOW_MOE_TINY, **f32)
+        params, share = wm.init_params(jax.random.PRNGKey(0), cfg), 2 / 8
+        assert wm.weight_row_share(cfg) == share
+    kind = jax.devices()[0].device_kind
+    assert chunk == derived_prefill_chunk(kind, 4, 2048, share)
+    eng = LlamaEngine(cfg, params, max_batch=2, max_seq=2048)
+    assert eng.prefill_chunk == chunk
+    assert eng.buckets == [chunk // 4, chunk // 2, chunk]
+    assert eng.windows == [1024, 2048]
+    assert len(eng._prefill_variants()) == 4
+    if family == "llama":
+        return
+    ring = eng.shards[0].cache["ring"]["k"].shape[3] - 8
+    assert ring == cfg.sliding_window + chunk
+    small = LlamaEngine(cfg, params, max_batch=2, max_seq=2048,
+                        prefill_chunk=16)
+    rng = np.random.default_rng(1)
+    # a bucket under the chunk, a whole chunk and a tail, a few rows
+    for n in (300, 1100, 7):
+        prompt = [int(t) for t in rng.integers(0, cfg.vocab_size, n)]
+        assert (eng.generate(prompt, max_tokens=6)
+                == small.generate(prompt, max_tokens=6))
+    assert eng.stats.prefill_chunks == 4 < small.stats.prefill_chunks
+
+
 def test_abort_all_takes_a_cache_of_any_leaves(toy):
     _, cfg, params = toy
     eng = LlamaEngine(cfg, params, max_batch=2, max_seq=64, prefill_chunk=16)
