@@ -126,7 +126,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ray_tpu.util.tracing import PhaseStats, phase
+from ray_tpu.util.tracing import PhaseStats, phase, recording
 
 # a request's phases, each the gap between two of its stamps: the two
 # before the server's pending queue (the handle's route entry to the
@@ -278,10 +278,15 @@ class EngineStats:
         self.device_counters = None
 
     def snapshot(self) -> Dict[str, Any]:
+        counted = {}
         if self.device_counters is not None:
-            for name, total in self.device_counters().items():
+            counted = self.device_counters()
+            for name, total in counted.items():
                 setattr(self, name, total)
-        out: Dict[str, Any] = {n: getattr(self, n) for n in self.COUNTERS}
+        # a model's further counters (models/latent_moe.py: the rows its
+        # two attention forms attend to) stand under the names it gives
+        out: Dict[str, Any] = {n: getattr(self, n)
+                               for n in (*self.COUNTERS, *counted)}
         out["phases"] = self.phases.snapshot()
         out["requests"] = list(self.requests)
         return out
@@ -663,9 +668,12 @@ class LlamaEngine:
         pos = req.prefill_pos
         chunk = min(self.prefill_chunk, n - pos)
         # ``rows``: the tokens this call carries, as a decode's span says
-        # its live lanes: what a trace of a few steps was asked for
+        # its live lanes: what a trace of a few steps was asked for.
+        # ``start``: the row of the sequence they begin at, so that a
+        # trace also says how many rows the call's attention had to see
         with phase("llm.prefill_dispatch", stats.phases,
-                   request_id=req.request_id, shard=shard.index, rows=chunk):
+                   request_id=req.request_id, shard=shard.index, rows=chunk,
+                   start=pos):
             bucket = next(b for b in self.buckets if b >= chunk)
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :chunk] = req.prompt_ids[pos:pos + chunk]
@@ -727,8 +735,13 @@ class LlamaEngine:
                 lens[slot] = shard.lengths[slot]
                 # the decode consumes the lane's last token: account it
                 shard.lengths[slot] += 1
+        # ``attended``, where a trace records the span: the rows its live
+        # lanes attend to, all together (each its own length and the row
+        # it writes)
+        seen = {"attended": int(sum(lens[slot] for slot, _ in lanes))
+                + len(lanes)} if recording() else {}
         with phase("llm.decode_dispatch", stats.phases, shard=shard.index,
-                   rows=len(lanes)):
+                   rows=len(lanes), **seen):
             shard.tokens, shard.cache, self._rng = self._decode(
                 self.params, shard.cache, shard.tokens,
                 lens, temps, self._rng,

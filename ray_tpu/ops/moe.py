@@ -21,18 +21,25 @@ Pieces:
   multiplies its rows by every expert's weights in one batched matmul
   and weighs what an expert was not chosen for by 0: the weights' read
   bounds both, and the batched matmul comes close to it
-  (``EVERY_EXPERT_ROWS``). No backward pass is written for it; training
-  keeps ``moe_ffn``.
+  (``EVERY_EXPERT_ROWS``). A chip that holds its share of a layer's
+  experts (``MoEConfig.held``) routes over all of them and computes its
+  own experts' part of the sum; what the absent experts would add is
+  left out, and nothing stands in for the chips that hold them. The
+  scores are a softmax's or a sigmoid's (``scoring``), the chosen
+  weights scaled by ``routed_scale``, and a shared expert, where the
+  parameters bring one, is added once. No backward pass is written for
+  it; training keeps ``moe_ffn``.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ._partition import ambient_partition
@@ -117,6 +124,25 @@ class MoEConfig:
     # the chosen experts' weights renormalised to sum to 1 (the
     # dropless layer; the training layer always does)
     norm_topk_prob: bool = True
+    # the dropless layer's alone. ``scoring``: an expert's score is its
+    # "softmax" share over all experts or its own "sigmoid"; the chosen
+    # weights are multiplied by ``routed_scale`` (after renormalising).
+    # ``held``: the ids, among the router's ``n_experts``, of the experts
+    # whose weights the parameters hold, in the order they are stacked;
+    # None: every one
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    held: Optional[Tuple[int, ...]] = None
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r}")
+        if self.held is not None and (
+                len(set(self.held)) != len(self.held)
+                or not all(0 <= e < self.n_experts for e in self.held)):
+            raise ValueError(
+                f"held {self.held} are not distinct ids under "
+                f"{self.n_experts}")
 
 
 def init_moe_params(key, config: MoEConfig, dtype=jnp.bfloat16):
@@ -173,13 +199,17 @@ def moe_ffn(
 
 def route_top_k(x: jax.Array, router: jax.Array, config: MoEConfig):
     """x (T, D), router (D, E) -> (weights (T, k) float32, experts
-    (T, k) int32): a softmax over all experts in float32, the k
-    largest, renormalised to sum to 1 where the configuration says so."""
-    probs = jax.nn.softmax(
-        x.astype(jnp.float32) @ router.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, config.k)
+    (T, k) int32): the scores of all experts in float32 (a softmax over
+    them, or each one's sigmoid), the k largest, renormalised to sum to
+    1 where the configuration says so, then scaled."""
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    scores = (jax.nn.sigmoid(logits) if config.scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    weights, experts = jax.lax.top_k(scores, config.k)
     if config.norm_topk_prob:
         weights = weights / weights.sum(-1, keepdims=True)
+    if config.routed_scale != 1.0:
+        weights = weights * config.routed_scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -245,17 +275,29 @@ def expert_ffn_every(x, w_gate, w_up, w_down, combine, layer=None):
 def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
                    config: MoEConfig, layer=None, sum_over=None):
     """x (T, D), live (T,) bool -> (out (T, D), counts int32[3]: the
-    live rows' assignments, experts with a live row or more, experts
-    held); ``sum_over``: the mesh axes the rows are split over, inside a
-    shard_map."""
+    live rows' assignments to experts held here, experts held here with
+    a live row or more, experts held here); ``sum_over``: the mesh axes
+    the rows are split over, inside a shard_map."""
     T, D = x.shape
-    E, k = config.n_experts, config.k
+    k = config.k
+    E = config.n_experts if config.held is None else len(config.held)
     with jax.named_scope("moe_router"):
         weights, experts = route_top_k(x, params["router"], config)
+        if config.held is not None:
+            # an assignment's place among the experts held here; one to
+            # an absent expert gets the place past them, weighs 0, sorts
+            # behind every group and is multiplied by no weight
+            place = np.full(config.n_experts, E, np.int32)
+            place[list(config.held)] = np.arange(E)
+            experts = jnp.asarray(place)[experts]
+            weights = jnp.where(experts < E, weights, 0.0)
+    # where experts are absent, the scatters below have their place too,
+    # which is cut off again: no index is ever out of bounds
+    places = E if config.held is None else E + 1
     if E <= T * k and T <= EVERY_EXPERT_ROWS:
         with jax.named_scope("moe_dispatch"):
-            combine = jnp.zeros((T, E), jnp.float32).at[
-                jnp.arange(T)[:, None], experts].set(weights)
+            combine = jnp.zeros((T, places), jnp.float32).at[
+                jnp.arange(T)[:, None], experts].set(weights)[:, :E]
         with jax.named_scope("moe_experts"):
             out = expert_ffn_every(
                 x, params["w_gate"], params["w_up"], params["w_down"],
@@ -264,21 +306,32 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
         with jax.named_scope("moe_dispatch"):
             flat = experts.reshape(T * k)
             order = jnp.argsort(flat, stable=True)    # assignments by expert
-            group_sizes = jnp.zeros(E, jnp.int32).at[flat].add(1)
+            group_sizes = jnp.zeros(places, jnp.int32).at[flat].add(1)[:E]
             xs = x[order // k]                        # (T*k, D)
         with jax.named_scope("moe_experts"):
             ys = expert_ffn(xs, params["w_gate"], params["w_up"],
                             params["w_down"], group_sizes, layer)
+            if config.held is not None:
+                # rows behind the last group are no expert's: whatever
+                # the grouped matmul leaves there is not a number to keep
+                ys = jnp.where((flat[order] < E)[:, None], ys, 0.0)
         with jax.named_scope("moe_combine"):
             back = jnp.argsort(order)                 # each assignment's row
             ys = ys[back].reshape(T, k, D)
             out = (ys * weights[..., None]).sum(1).astype(x.dtype)
+    if "shared_gate" in params:
+        with jax.named_scope("moe_shared"):
+            # the expert every row goes through, added once: on every
+            # chip of a deployment alike, so not a part of the routed sum
+            gate = x @ params["shared_gate"]
+            up = x @ params["shared_up"]
+            out = out + (jax.nn.silu(gate) * up) @ params["shared_down"]
     with jax.named_scope("moe_combine"):
         # what was asked for: a padded chunk's rows behind its tokens and
         # an idle decode lane are computed and not counted
-        asked = jnp.zeros(E, jnp.int32).at[experts].add(
-            live[:, None].astype(jnp.int32))
-        counts = jnp.stack([live.sum().astype(jnp.int32) * k,
+        asked = jnp.zeros(places, jnp.int32).at[experts].add(
+            live[:, None].astype(jnp.int32))[:E]
+        counts = jnp.stack([asked.sum().astype(jnp.int32),
                             (asked > 0).sum().astype(jnp.int32),
                             jnp.int32(E)])
         if sum_over:
@@ -292,7 +345,13 @@ def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
     """Routed SwiGLU expert FFN with no capacity: every token goes to
     its ``config.k`` experts. x (..., D) -> (out (..., D), counts): the
     int32 vector (assignments, experts touched, experts held) of this
-    call, for the engine's ``moe_*`` counters. ``layer``: the expert
+    call, for the engine's ``moe_*`` counters. ``params``: ``router``
+    (D, ``config.n_experts``), ``w_gate`` / ``w_up`` (E, D, F) and
+    ``w_down`` (E, F, D) of the E experts held (``config.held``; all of
+    them where it is None: an assignment to an expert that is not held
+    adds nothing and is not counted), and, where the layer has a shared
+    expert, ``shared_gate`` / ``shared_up`` (D, Fs) and ``shared_down``
+    (Fs, D). ``layer``: the expert
     weights are a model's stacked ones and this is the layer among them
     (``expert_ffn``); the router is the layer's own. ``live`` (...) bool:
     the rows that are somebody's tokens (default: all); the others are

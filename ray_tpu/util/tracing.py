@@ -316,6 +316,18 @@ class phase:
         return False
 
 
+def recording() -> bool:
+    """Whether a profiler session is on, so that a :class:`phase`'s ids
+    land in a trace: a caller asks before it computes an id that only a
+    trace's reader wants."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation.is_enabled()
+
+
 # --------------------------------------------------- critical-path analysis
 # Stage catalog: every runtime span carries attrs["stage"] drawn from
 # this set. Precedence resolves overlap — when two stages cover the same

@@ -208,7 +208,9 @@ def test_profiler_trace_holds_the_engine_spans_nested(tiny_params, tmp_path):
             GenRequest(f"r{i}", list(range(1, 20)), max_tokens=4))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level, opts.host_tracer_level = 0, 2
+    assert not tracing.recording()
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    assert tracing.recording()
     for _ in range(3):
         eng.step()
     jax.profiler.stop_trace()
@@ -238,6 +240,13 @@ def test_profiler_trace_holds_the_engine_spans_nested(tiny_params, tmp_path):
         assert ids["shard"] == 0
         if "prefill" in name or "first_token" in name:
             assert ids["request_id"] in ("r0", "r1")
+    # what a trace's reader wants of a call's work: the row a chunk's
+    # tokens start at, the rows a decode's live lanes attend to (r0's 19
+    # prompt tokens and the row it writes, then one more)
+    assert [(ids["start"], ids["rows"]) for _, _, name, ids in events
+            if name.endswith("prefill_dispatch")] == [(0, 16), (16, 3), (0, 16)]
+    assert [ids["attended"] for _, _, name, ids in events
+            if name.endswith("decode_dispatch")] == [20, 21]
     in_step = [[e[2].rsplit(".", 1)[1] for e in events
                 if step[0] <= e[0] and e[1] <= step[1]] for step in steps]
     # everything is dispatched before anything is read
