@@ -15,35 +15,65 @@ re-designed for XLA instead of wrapped:
   the decode of already-running sequences (vLLM's chunked-prefill
   scheduler, reference llm/_internal/batch/stages/vllm_engine_stage.py
   wraps the same idea). The chunk is sized from the chip and the
-  model, not set. Every call reads the weights once, so a chunk wants
-  rows enough to pay for that read. The rule taken is the roofline's
-  ridge: the rows at which a matmul takes as long as reading its
-  weight (the chip's FLOPs per HBM byte, times the weights' bytes per
-  parameter over 2: 240 rows of bf16 on a v5e), counted for the
-  weights that hold most of a call's bytes, over the share of the
-  call's rows that multiply them, to the nearest power of two
-  (``derived_prefill_chunk``). In ``models/llama.py`` every row meets
-  every weight: share 1, 240 -> 256 rows on a v5e. In
-  ``models/window_moe.py`` the experts are 95 % of a layer's bytes
-  and a row meets ``experts_per_token`` of ``n_experts`` of them
-  (the module's ``weight_row_share``): for 8 of 64 an expert sees an
-  eighth of a call's rows, so 240 x 8 = 1920 -> 2048 rows. Not the
-  call's FLOPs over its bytes, which would give 1024 there: a call's
-  matmuls run one after another, the dense projections are past their
-  ridge at 240 rows whatever the chunk, and the expert matrices stay
-  read-bound until each sees 240. It is a rule of thumb, not a knee
-  that was measured: on a v5e a llama call's time grew nearly in line
-  with its rows from 128 on (attention over the whole cache and the
-  activations grow with them) and 512 rows served every llama cell
-  better than 256 (PERF.md section 6, PR 29); the routed cell
-  completes 39 % more tokens a second at 2048 rows than at 256 and as
-  many at 1024 as at 2048, the compiler's grouped matmul being far
-  under either roof at any of them (PERF.md section 6, PR 47). What
-  bounds a chunk from above is how long the decode step behind it may
-  wait (a token's gap at the 99th percentile is a quarter longer at
-  2048 rows than at 256), which no cell judges yet and no rule here
-  accounts for. Three chunk buckets (C/4, C/2, C) bound compilations.
-  The head runs on the one row a chunk returns logits for.
+  model, not set. Every call pays some things once whatever its rows,
+  so a chunk wants rows enough to pay for them. The rule taken is the
+  roofline's ridge: the rows whose own work takes as long as what the
+  call pays once, to the nearest power of two
+  (``derived_prefill_chunk``). What a call pays once is first the read
+  of its weights, and a row's work is its matmuls over them: the rows
+  at which a matmul takes as long as reading its weight are the chip's
+  FLOPs per HBM byte, times the weights' bytes per parameter over 2
+  (240 rows of bf16 on a v5e). They are counted for the weights that
+  hold most of a call's bytes, and a model's module says in
+  ``chunk_terms`` what the configuration adds to that:
+  - *the share of a call's rows that multiply such a weight.* In
+    ``models/llama.py`` every row meets every weight (the module says
+    nothing): 240 -> 256 rows on a v5e. In ``models/window_moe.py`` the
+    experts are 95 % of a layer's bytes and a row meets
+    ``experts_per_token`` of ``n_experts`` of them: for 8 of 64 an
+    expert sees an eighth of a call's rows, so 240 x 8 = 1920 -> 2048
+    rows. Not the call's FLOPs over its bytes, which would give 1024
+    there: a call's matmuls run one after another, the dense
+    projections are past their ridge at 240 rows whatever the chunk,
+    and the expert matrices stay read-bound until each sees 240.
+  - *the bytes a call reads beside those weights that its rows do not
+    turn into work*, as a share of theirs. ``models/latent_moe.py``
+    holds 8 of 256 experts beside attention, a dense layer and shared
+    experts that every row meets: the latter hold most of the bytes
+    (3.5 GB beside 3.0 and a gathered embedding) and set the row's
+    work, and the held experts' 3.0 GB are read by every call for the
+    thirty-second of the assignments that reach them, which no chunk
+    under 7680 rows makes compute-bound. Their read is paid once like
+    the others': 240 x (1 + 3.0 / 3.5) = 449 rows.
+  - *the work a call does once whatever its rows*, in rows' worth of
+    the counted weights' work (both run at the chip's peak, so the
+    ratio is the same on every chip). A latent-attention chunk makes
+    keys and values of every row it attends to (``latent_expand``, 168
+    MFLOP a row over 5 layers) before a row of its own is scored: at
+    the rows a lane of ``max_seq`` holds on average over a prompt, half
+    of them, that is 394 rows' worth at 16 384: 449 + 394 = 843 ->
+    1024 rows.
+  It is a rule of thumb, not a knee that was measured, and on a v5e
+  each family's answer read otherwise than its premise has it: a llama
+  call's time grew nearly in line with its rows from 128 on (attention
+  over the whole cache and the activations grow with them) and 512
+  rows served every llama cell better than 256 (PERF.md section 6,
+  PR 29); the routed cell completes 39 % more tokens a second at 2048
+  rows than at 256 and as many at 1024 as at 2048, the compiler's
+  grouped matmul being far under either roof at any of them (PR 47);
+  the latent cell completes 18 % more at 1024 rows than at 256, 3 %
+  more than at 512, and at 2048 fewer than at 256, and not because a
+  row got cheaper: a chunk call's time is in line with its rows at
+  every size (its matmuls run at half the peak with the weights' read
+  under them, and the expansion rides inside the scores' fusions), so
+  what a larger chunk buys is the decode calls that no longer stand
+  one behind every chunk (PR 49). What bounds a chunk from above is
+  how long the decode step behind it may wait (a token's gap at the
+  99th percentile is a quarter longer at 2048 rows than at 256 in the
+  routed cell, and three times as long at 1024 as at 256 in the latent
+  one), which no cell judges yet and no rule here accounts for. Three
+  chunk buckets (C/4, C/2, C) bound compilations. The head runs on the
+  one row a chunk returns logits for.
   ``warm_up()`` runs every program once, at every read window;
   ``LLMServer`` calls it before it takes a request, a bare engine
   compiles on first use.
@@ -136,19 +166,36 @@ REQUEST_PHASES = ("ingress", "accept",
 
 
 def derived_prefill_chunk(device_kind: str, bytes_per_param: float,
-                          max_seq: int, row_share: float = 1.0) -> int:
+                          max_seq: int, row_share: float = 1.0,
+                          read_beside: float = 0.0,
+                          once_rows: float = 0.0) -> int:
     """The prefill chunk for weights of ``bytes_per_param`` on a chip of
-    ``device_kind``: the power of two nearest the rows at which the
-    matmuls over the weights that hold most of a call's bytes (2 FLOPs
-    a row a parameter) take as long as reading those weights once,
-    fitted to ``max_seq``. ``row_share`` is the share of a call's rows
-    that multiply such a weight: 1 where every row meets every weight
-    (240 -> 256 rows of bf16 on a v5e), ``experts_per_token / n_experts``
-    for routed experts (8 of 64: 240 x 8 = 1920 -> 2048). Measured on a
-    v5e only; the other kinds' sizes follow from the table alone."""
+    ``device_kind``: the power of two nearest the rows whose work takes
+    as long as what a call pays once, fitted to ``max_seq``. The work is
+    the matmuls over the weights that hold most of a call's bytes (2
+    FLOPs a row a parameter), and what is paid once is their read, so
+    the rows are the chip's ridge: 240 of bf16 on a v5e. A model's
+    ``chunk_terms`` adds what its configuration says:
+
+    - ``row_share``, the share of a call's rows that multiply such a
+      weight: 1 where every row meets every weight (240 -> 256 rows),
+      ``experts_per_token / n_experts`` for routed experts (8 of 64:
+      240 x 8 = 1920 -> 2048);
+    - ``read_beside``, the bytes a call reads beside those weights for
+      rows too few to make them work, as a share of those weights'
+      bytes: paid once as well (8 of 256 experts held, 3.0 GB beside
+      3.5: 240 x 1.87 = 449);
+    - ``once_rows``, the work a call does once whatever its rows, over
+      the work of one row in the counted weights: so many rows' worth
+      on any chip (a latent chunk's expansion of the rows it attends
+      to: 394 at ``max_seq`` 16 384, so 843 -> 1024).
+
+    Measured on a v5e only; the other kinds' sizes follow from the
+    table alone."""
     from ray_tpu._private.accelerators.tpu import flops_per_hbm_byte
 
-    rows = flops_per_hbm_byte(device_kind) * bytes_per_param / 2 / row_share
+    ridge = flops_per_hbm_byte(device_kind) * bytes_per_param / 2
+    rows = ridge / row_share * (1 + read_beside) + once_rows
     return _fit_chunk(2 ** round(math.log2(rows)), max_seq)
 
 
@@ -312,8 +359,8 @@ class LlamaEngine:
         # the model's functions are the configuration's: the module it
         # names gives forward_with_cache, init_cache and attn_rows_read
         # (models/llama.py; models/window_moe.py), read_counters where
-        # its programs count on the device, and weight_row_share where
-        # not every row of a call meets its heaviest weights
+        # its programs count on the device, and chunk_terms where a
+        # call pays otherwise than by every row meeting every weight
         model = importlib.import_module(config.model_module)
 
         self.config = config
@@ -322,12 +369,12 @@ class LlamaEngine:
         self.max_seq = max_seq
         if prefill_chunk is None:
             leaves = jax.tree_util.tree_leaves(params)
-            share = (model.weight_row_share(config)
-                     if hasattr(model, "weight_row_share") else 1.0)
+            terms = (model.chunk_terms(config, max_seq)
+                     if hasattr(model, "chunk_terms") else {})
             self.prefill_chunk = derived_prefill_chunk(
                 jax.devices()[0].device_kind,
                 sum(a.nbytes for a in leaves) / sum(a.size for a in leaves),
-                max_seq, share)
+                max_seq, **terms)
         else:
             self.prefill_chunk = _fit_chunk(prefill_chunk, max_seq)
         # growth is whole-shard; round the cap to shard granularity so

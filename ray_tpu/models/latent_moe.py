@@ -32,7 +32,8 @@ one runs follows from the call alone:
 
 - a call of more than one row a sequence (a prefill chunk) **expands**:
   block by block of the cache's rows it makes the heads' keys and values
-  from the latent rows (``latent_expand``) and attends to them with a
+  from the latent rows (``latent_expand``), once for all the chunk's
+  rows, which attend to them a tile of rows at a time, each tile with a
   running maximum and sum (``attn_latent_prefill``), so no score of the
   chunk's rows x the cache's rows x the heads ever exists, and a block
   past the chunk's last row is never read;
@@ -145,13 +146,19 @@ COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
 # the counters' low words carry into the high ones from here; no call
 # may count this much at once (2048 rows x 16 384 x 5 layers is 2^27)
 _CARRY_BITS = 30
-# cache rows an attention loop takes at a time: a chunk's score of one
-# block is heads x rows x PREFILL_BLOCK float32 (34 MB at 128 heads and
-# 256 rows), a decode's lanes x heads x DECODE_BLOCK. Read on a v5e at
-# the published widths (PERF.md section 6, PR 48): a 256-row chunk call
-# takes 2.3 us an attended row at blocks of 256, 2.6 at 128, 3.3 at 512,
-# 4.8 at 1024; a decode call of 32 lanes the same at 512, 1024 and 2048
+# cache rows an attention loop takes at a time, and the rows of a chunk
+# that attend to them at a time: a chunk's score of one tile and block is
+# heads x PREFILL_TILE x PREFILL_BLOCK float32 (34 MB at 128 heads), a
+# decode's lanes x heads x DECODE_BLOCK. Read on a v5e at the published
+# widths: a 256-row chunk call takes 2.3 us an attended row at blocks of
+# 256, 2.6 at 128, 3.3 at 512, 4.8 at 1024; a decode call of 32 lanes
+# the same at 512, 1024 and 2048 (PERF.md section 6, PR 48). A 1024-row
+# call takes 11 us an attended row in tiles of 256, 14 in tiles of 128,
+# 16 in tiles of 512 and 21 as one tile, where four 256-row calls take
+# 9: a score costs what it costs at 256 rows only in tiles of 256
+# (PERF.md section 6, PR 49)
 PREFILL_BLOCK = 256
+PREFILL_TILE = 256
 DECODE_BLOCK = 1024
 
 
@@ -168,14 +175,33 @@ def _attn_shapes(c: LatentMoEConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]:
     }
 
 
-def weight_row_share(config: LatentMoEConfig) -> float:
-    """The share of a call's rows that multiply one of the weights that
-    hold most of its bytes (``engine.derived_prefill_chunk``). Every row
+def chunk_terms(config: LatentMoEConfig, max_seq: int) -> Dict[str, float]:
+    """What ``engine.derived_prefill_chunk`` is told beside the chip,
+    from the configuration and the cache's length alone. Every row
     meets the attention projections, the dense layers' MLPs, the shared
-    experts and the head; a held expert is met by the rows routed to it,
-    ``experts_per_token / n_experts`` of them. Whichever of the two
-    holds more of the model answers (at 8 of 256 experts held beside
-    7680-wide latent attention: the former, 1.9 G of 3.4 G parameters)."""
+    experts and the head; a held expert is met by the rows routed to
+    it, ``experts_per_token / n_experts`` of them. Whichever of the two
+    holds more of the model sets what a row's work is counted in
+    (``row_share``). Where that is the former (8 of 256 experts held
+    beside 7680-wide latent attention: 1.74 G parameters beside 1.51 G
+    and an embedding that is gathered, not multiplied), two things more
+    are paid once a call:
+
+    - the held experts' read (``read_beside``: 1.51 G parameters over
+      1.74 G, 0.87). At the rows that pay for the weights every row
+      meets, an expert sees a thirty-second of them and is read all
+      the same;
+    - the expansion of every latent row the chunk attends to into the
+      heads' keys and values (``latent_expand``: 2 x kv_rank x heads x
+      (nope + v) FLOPs a row a layer), before a row of the chunk's own
+      is scored. A lane of ``max_seq`` holds half of them on average
+      over a prompt; over a row's 2 FLOPs a parameter it meets that is
+      ``once_rows`` (394 rows' worth at 16 384).
+
+    Where the held experts hold more, their read is what the rows pay
+    for, each expert seeing its share of them; the weights every row
+    meets are past their own ridge by then and the expansion is small
+    beside rows so many."""
     c = config
     attention = sum(math.prod(shape) for shape, _ in _attn_shapes(c).values())
     every_row = (c.n_layers * attention
@@ -183,7 +209,12 @@ def weight_row_share(config: LatentMoEConfig) -> float:
                  + c.n_routed_layers * 3 * c.dim * c.shared_dim
                  + c.dim * c.vocab_size)
     routed = c.n_routed_layers * c.n_held * 3 * c.dim * c.expert_dim
-    return 1.0 if every_row >= routed else c.experts_per_token / c.n_experts
+    if every_row < routed:
+        return {"row_share": c.experts_per_token / c.n_experts}
+    expand = (c.n_layers * 2 * c.kv_rank * c.n_heads
+              * (c.nope_dim + c.v_dim)) * max_seq / 2
+    return {"read_beside": routed / every_row,
+            "once_rows": expand / (2 * every_row)}
 
 
 # -- parameters --------------------------------------------------------
@@ -287,54 +318,62 @@ def attend_expanded(c: LatentMoEConfig, q_nope, q_rope, read, S: int, pos,
     rows (B, size, kv_rank), rotary keys (B, rope, size)), the call's
     own among them -> (B, T, H, v) in the compute type. ``PREFILL_BLOCK``
     rows of the cache at a time: their keys and values are made from the
-    latent rows, the chunk's rows attend to them, and a running maximum
-    and sum carry the softmax; the loop ends with the block that holds
-    the call's last position."""
+    latent rows once, the chunk's rows attend to them ``PREFILL_TILE``
+    at a time, each tile with a running maximum and sum of its own that
+    carry its softmax; the loop ends with the block that holds the
+    call's last position."""
     B, T, H, _ = q_nope.shape
     block = _blocks(S, PREFILL_BLOCK)
+    tile = _blocks(T, PREFILL_TILE)
     scale = 1.0 / math.sqrt(c.nope_dim + c.rope_dim)
     wuk, wuv = layer["wuk"].astype(c.dtype), layer["wuv"].astype(c.dtype)
+    # a tile's queries and positions, cut out here and not once a block
+    queries = [(q_nope[:, t:t + tile], q_rope[:, t:t + tile],
+                pos[:, t:t + tile, None]) for t in range(0, T, tile)]
 
     def step(i, carry):
-        m, l, acc = carry
         with jax.named_scope("attn_latent_prefill"), \
                 jax.named_scope("kv_slice"):
             rows, k_rope = read(i * block, block)
         with jax.named_scope("latent_expand"):
-            # the compiler makes these inside the score's fusions, which
-            # carry ``attn_latent_prefill``: a trace reads the two scopes
-            # together. Behind an optimization barrier they are this
-            # scope's own and read a seventh of a chunk call, which then
-            # takes 14-19 % longer (PERF.md section 6, PR 48)
+            # in a call of one tile the compiler makes these inside the
+            # score's fusions, which carry ``attn_latent_prefill``: a
+            # trace reads the two scopes together. Behind an optimization
+            # barrier they are this scope's own and read a seventh of a
+            # 256-row chunk call, which then takes 14-19 % longer
+            # (PERF.md section 6, PR 48)
             k_nope = jnp.einsum("bsc,chk->bshk", rows, wuk)
             v = jnp.einsum("bsc,chk->bshk", rows, wuv)
-        with jax.named_scope("attn_latent_prefill"):
-            s = (jnp.einsum("bthk,bshk->bhts", q_nope, k_nope,
-                            preferred_element_type=jnp.float32)
-                 + jnp.einsum("bthr,brs->bhts", q_rope, k_rope,
-                              preferred_element_type=jnp.float32)) * scale
-            at = i * block + jnp.arange(block)
-            seen = at[None, None, :] <= pos[:, :, None]          # (B, T, blk)
-            s = jnp.where(seen[:, None], s, -1e30)
-            m_new = jnp.maximum(m, s.max(-1))
-            p = jnp.exp(s - m_new[..., None])
-            fade = jnp.exp(m - m_new)
-            l = l * fade + p.sum(-1)
-            acc = acc * fade[..., None] + jnp.einsum(
-                "bhts,bshk->bhtk", p.astype(c.dtype), v,
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
+        at = i * block + jnp.arange(block)
+        out = []
+        for (q_nope_t, q_rope_t, pos_t), (m, l, acc) in zip(queries, carry):
+            with jax.named_scope("attn_latent_prefill"):
+                s = (jnp.einsum("bthk,bshk->bhts", q_nope_t, k_nope,
+                                preferred_element_type=jnp.float32)
+                     + jnp.einsum("bthr,brs->bhts", q_rope_t, k_rope,
+                                  preferred_element_type=jnp.float32)) * scale
+                seen = at[None, None, :] <= pos_t              # (B, tile, blk)
+                s = jnp.where(seen[:, None], s, -1e30)
+                m_new = jnp.maximum(m, s.max(-1))
+                p = jnp.exp(s - m_new[..., None])
+                fade = jnp.exp(m - m_new)
+                out.append((m_new, l * fade + p.sum(-1),
+                            acc * fade[..., None] + jnp.einsum(
+                                "bhts,bshk->bhtk", p.astype(c.dtype), v,
+                                preferred_element_type=jnp.float32)))
+        return tuple(out)
 
     # row 0 is seen by every query, so the first block sets every
     # maximum and a masked score weighs exp(-1e30 - m) = 0 exactly
     blocks = jnp.minimum(pos.max() // block + 1, S // block)
-    m0 = jnp.full((B, H, T), -1e30, jnp.float32)
-    _, l, acc = jax.lax.fori_loop(
-        0, blocks, step,
-        (m0, jnp.zeros((B, H, T), jnp.float32),
-         jnp.zeros((B, H, T, c.v_dim), jnp.float32)))
+    first = (jnp.full((B, H, tile), -1e30, jnp.float32),
+             jnp.zeros((B, H, tile), jnp.float32),
+             jnp.zeros((B, H, tile, c.v_dim), jnp.float32))
+    done = jax.lax.fori_loop(0, blocks, step, (first,) * len(queries))
     with jax.named_scope("attn_latent_prefill"):
-        return (acc / l[..., None]).astype(c.dtype).transpose(0, 2, 1, 3)
+        return jnp.concatenate(
+            [(acc / l[..., None]).astype(c.dtype).transpose(0, 2, 1, 3)
+             for _, l, acc in done], axis=1)
 
 
 def attend_absorbed(c: LatentMoEConfig, q_nope, q_rope, read, S: int, pos,

@@ -34,7 +34,7 @@ a lane that is mid-prefill. Beside the rows rides ``counts``, the
 hold most of a layer's bytes (95 % at 64 experts of 3 x 2304 x 896
 beside 21 M of attention projections and router), and a row multiplies
 ``experts_per_token`` of the ``n_experts`` of them, so an expert sees
-that share of a call's rows. ``weight_row_share`` says so from the
+that share of a call's rows. ``chunk_terms`` says so from the
 configuration's two counts, and ``engine.derived_prefill_chunk``
 divides the ridge's rows by it: a chunk then gives each expert the
 rows that pay for reading it (8 of 64 on a v5e: 2048 rows, not 256).
@@ -132,13 +132,17 @@ WINDOW_MOE_TINY = WindowMoEConfig(
 )
 
 
-def weight_row_share(config: WindowMoEConfig) -> float:
-    """The share of a call's rows that multiply one of the weights that
-    hold most of its bytes: an expert's, which a row in
+def chunk_terms(config: WindowMoEConfig, max_seq: int) -> Dict[str, float]:
+    """What ``engine.derived_prefill_chunk`` is told beside the chip:
+    the share of a call's rows that multiply one of the weights that
+    hold most of its bytes, an expert's, which a row in
     ``experts_per_token / n_experts`` meets (dropless, so every
-    assignment is computed). A family of dense and routed layers would
-    answer for whichever holds most of a layer's bytes."""
-    return config.experts_per_token / config.n_experts
+    assignment is computed). The projections beside the experts are
+    past their own ridge well before, and a call does nothing once
+    whatever its rows (``models/latent_moe.py`` answers for a family
+    where neither holds)."""
+    del max_seq
+    return {"row_share": config.experts_per_token / config.n_experts}
 
 
 # -- rotary tables -----------------------------------------------------

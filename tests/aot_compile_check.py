@@ -12,7 +12,9 @@ a program that does not fit the chip's memory. It cannot say that a
 program runs, is right or is fast.
 
 Prints one line per program and exits non-zero if any failed to
-compile; exits 77 when this libtpu cannot describe the topology.
+compile; exits 77 when this libtpu cannot describe the topology. Words
+on the command line keep to the programs whose line holds one of them
+(``python tests/aot_compile_check.py latent``).
 tests/test_chip_path.py runs it in a subprocess with ``--quick``: the
 same programs at the same widths, one layer and a shorter, smaller
 batch, because each compile costs about a minute of CPU time.
@@ -56,8 +58,12 @@ def main() -> int:
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     failures = 0
 
+    only = [w for w in sys.argv[1:] if not w.startswith("--")]
+
     def check(name, lower, expect=(), forbid=None):
         nonlocal failures
+        if only and not any(w in name for w in only):
+            return
         t0 = time.perf_counter()
         try:
             compiled = lower().compile()
@@ -207,11 +213,59 @@ def main() -> int:
               "one device", partial(decode_step, window),
               forbid=no_shard_copy)
 
+    if not quick:
+        latent_chunks(check, sds)
+
     for n in (1, 4):
         check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "
               f"{B}x{S} per device, {n} device(s)",
               partial(train_step, n), flash_names)
     return 1 if failures else 0
+
+
+def latent_chunks(check, sds):
+    """The chunk programs of ``openpangu-ultra-moe-718b.serve-longdoc``
+    at its published widths and the cell's 16 x 16 384 cache: the three
+    buckets of the chunk a v5e's engine derives (1024 rows) reading the
+    whole slot, and the whole chunk at the other read window. None may
+    copy a leaf of the shard (as one 576-wide leaf the cache was
+    bracketed by two transposing copies of 3 GB, PERF.md section 6,
+    PR 48), and the line says what temporaries a call of so many rows
+    takes beside the 8.3 GB of weights and cache."""
+    from benchmarks import spec
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+    from ray_tpu.models import latent_moe
+
+    cell = spec.load_cell("openpangu-ultra-moe-718b.serve-longdoc", False)
+    cfg = spec.family_of(cell["hp"]).model_config(cell["hp"])
+    lanes, max_seq = (cell["serve"][k] for k in ("max_batch_size",
+                                                 "max_seq_len"))
+    abstract = partial(jax.tree.map, lambda a: sds(a.shape, a.dtype))
+    params = abstract(jax.eval_shape(
+        partial(latent_moe.init_params, config=cfg), jax.random.PRNGKey(0)))
+    cache = abstract(jax.eval_shape(
+        partial(latent_moe.init_cache, cfg, lanes, max_seq)))
+    chunk = derived_prefill_chunk(
+        "TPU v5 lite", 2, max_seq, **latent_moe.chunk_terms(cfg, max_seq))
+
+    def prefill_chunk(rows, window):
+        def call(params, cache, tokens, start, slot, at):
+            return latent_moe.forward_with_cache(
+                params, tokens, cache, start, cfg, slot=slot, logits_at=at,
+                rows=window)
+
+        return jax.jit(call, donate_argnums=(1,)).lower(
+            params, cache, sds((1, rows), jnp.int32), sds((1,), jnp.int32),
+            sds((), jnp.int32), sds((1,), jnp.int32))
+
+    leaves = "|".join(",".join(str(d) for d in cache[k].shape)
+                      for k in ("latent", "rope_key"))
+    no_shard_copy = rf"\[(?:{leaves})\]\S* copy\("
+    for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
+                         (chunk, max_seq), (chunk, max_seq // 2)):
+        check(f"latent prefill chunk of {rows} rows reading {window} of "
+              f"{lanes} x {max_seq}, published widths, one device",
+              partial(prefill_chunk, rows, window), forbid=no_shard_copy)
 
 
 if __name__ == "__main__":

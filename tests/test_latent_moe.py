@@ -163,13 +163,16 @@ def attention_inputs(c, seed, batch, rows_held, T, start):
     return layer, q_nope, q_rope, latent, pos
 
 
-@pytest.mark.parametrize("block", [8, 16, 64])
+@pytest.mark.parametrize("block, tile", [(8, 256), (16, 256), (64, 256),
+                                         (8, 4), (16, 8), (64, 16)])
 def test_blockwise_prefill_attention_equals_the_whole_matrix(
-        block, monkeypatch):
+        block, tile, monkeypatch):
     """A chunk that starts mid-cache, two sequences at different
-    starts, blocks smaller than, equal to and larger than the chunk."""
+    starts, blocks smaller than, equal to and larger than the chunk, its
+    rows attending in one tile, in two and in four."""
     c = F32
     monkeypatch.setattr(lm, "PREFILL_BLOCK", block)
+    monkeypatch.setattr(lm, "PREFILL_TILE", tile)
     layer, q_nope, q_rope, latent, pos = attention_inputs(
         c, 3, 2, 64, 16, jnp.asarray([24, 37]))
     want = attend_whole(c, q_nope, q_rope, latent, pos, layer)
@@ -436,12 +439,94 @@ def test_the_cells_configuration_is_the_published_one_cut_by_its_share():
     cache = jax.eval_shape(lambda: lm.init_cache(cfg, lanes, 16384))
     assert sum(a.size * a.dtype.itemsize for a in
                jax.tree_util.tree_leaves(cache)) // 10 ** 7 == 150  # 1.51 GB
-    assert lm.weight_row_share(cfg) == 1.0
-    assert derived_prefill_chunk("TPU v5 lite", 2, 16384, 1.0) == 256
+    # the held experts (1.51 G parameters) are read beside the 1.74 G
+    # every row multiplies; a lane of 16 384 holds 8192 rows on average,
+    # whose expansion (168 MFLOP a row over 5 layers) is 394 rows' worth
+    # of 2 FLOPs a parameter: 240 x 1.87 + 394 = 843 rows on a v5e
+    terms = lm.chunk_terms(cfg, 16384)
+    held = sum(shapes["routed"][k].size for k in lm.EXPERT_WEIGHTS)
+    every_row = (
+        sum(shapes[kind][k].size for kind in ("dense", "routed")
+            for k in ("wdq", "wuq", "wdkv", "wuk", "wuv", "wo"))
+        + sum(shapes["dense"][k].size for k in lm.EXPERT_WEIGHTS)
+        + sum(shapes["routed"][k].size for k in lm.SHARED_WEIGHTS)
+        + shapes["lm_head"].size)
+    assert terms == {
+        "read_beside": held / every_row,
+        "once_rows": 5 * 2 * 512 * 128 * 256 * 8192 / (2 * every_row)}
+    assert (round(terms["read_beside"], 2), round(terms["once_rows"])) == (
+        0.87, 394)
+    assert derived_prefill_chunk("TPU v5 lite", 2, 16384, **terms) == 1024
+    assert derived_prefill_chunk(
+        "TPU v5 lite", 2, 16384, read_beside=terms["read_beside"]) == 512
 
 
 def test_the_chunk_rule_is_told_which_weights_hold_most_of_the_model():
-    assert lm.weight_row_share(F32) == 1.0
+    """By hand at the tiny widths: 124 928 parameters every row
+    multiplies (three layers' attention, a dense MLP, two shared
+    experts, the head) beside 49 152 of held experts, so the experts'
+    read and the expansion are paid once; with every expert held and
+    wider the experts hold most and an expert's rows are what counts."""
+    every_row = 3 * 18432 + 3 * 64 * 128 + 2 * 3 * 64 * 32 + 64 * 512
+    assert lm.chunk_terms(F32, 256) == {
+        "read_beside": 2 * 4 * 3 * 64 * 32 / every_row,
+        "once_rows": 3 * 2 * 32 * 4 * (16 + 16) * 128 / (2 * every_row)}
+    assert (lm.chunk_terms(F32, 512)["once_rows"]
+            == 2 * lm.chunk_terms(F32, 256)["once_rows"])
     mostly_experts = dataclasses.replace(
         F32, held_experts=tuple(range(16)), expert_dim=64, n_dense_layers=0)
-    assert lm.weight_row_share(mostly_experts) == 4 / 16
+    assert lm.chunk_terms(mostly_experts, 256) == {"row_share": 4 / 16}
+
+
+@pytest.mark.parametrize("kind, max_seq, chunk", [
+    # 8 of 256 experts held beside latent attention at the published
+    # widths, bf16: the ridge x 1.87 + the expansion's rows' worth
+    ("TPU v5 lite", 16384, 1024),   # 449 + 394 = 843
+    ("TPU v4", 16384, 1024),        # 418 + 394 = 812
+    ("TPU v5", 16384, 512),         # 310 + 394 = 704
+    ("TPU v5p", 16384, 512),
+    ("TPU v6 lite", 16384, 1024),   # 1044 + 394 = 1439
+    ("cpu", 16384, 512),            # unknown: the smallest ratio
+    # a shorter cache holds fewer rows to expand: 449 + 197 = 646
+    ("TPU v5 lite", 8192, 512),
+    ("TPU v5 lite", 32768, 1024),   # 449 + 788 = 1237
+    ("TPU v5 lite", 512, 512),      # 449 + 12, capped by max_seq
+    ("TPU v5 lite", 256, 256),
+    ("TPU v5 lite", 15872, 512),    # 831 -> 1024, halved until it divides
+])
+def test_the_cells_chunk_follows_from_configuration_cache_and_chip(
+        kind, max_seq, chunk):
+    from benchmarks import spec
+    from ray_tpu._private.accelerators.tpu import CHIP_PEAKS
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    assert kind in CHIP_PEAKS or kind == "cpu"
+    hp = spec.load_cell("openpangu-ultra-moe-718b.serve-longdoc", False)["hp"]
+    cfg = spec.family_of(hp).model_config(hp)
+    got = derived_prefill_chunk(kind, 2, max_seq,
+                                **lm.chunk_terms(cfg, max_seq))
+    assert got == chunk <= max_seq and max_seq % got == 0
+
+
+def test_an_engine_of_the_derived_chunk_serves_what_one_of_256_rows_does():
+    """Built with no ``prefill_chunk``, the tiny configuration's engine
+    takes the rule's answer for its own widths (float32 on a kind the
+    table lacks: 332 x 1.39 + 101 rows' worth of expansion at 2048 ->
+    512), with the buckets and windows that follow, and a seeded
+    prompt's greedy tokens are those of 256-row chunks."""
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    params = lm.init_params(jax.random.PRNGKey(0), F32)
+    kw = dict(max_batch=2, max_seq=2048, max_slots=2)
+    eng = LlamaEngine(F32, params, **kw)
+    assert eng.prefill_chunk == 512 == derived_prefill_chunk(
+        jax.devices()[0].device_kind, 4, 2048, **lm.chunk_terms(F32, 2048))
+    assert eng.buckets == [128, 256, 512] and eng.windows == [1024, 2048]
+    small = LlamaEngine(F32, params, prefill_chunk=256, **kw)
+    rng = np.random.default_rng(49)
+    # whole chunks and a tail, a bucket under the chunk, a few rows
+    for n in (1300, 200, 9):
+        prompt = [int(t) for t in rng.integers(1, F32.vocab_size, n)]
+        assert (eng.generate(prompt, max_tokens=6)
+                == small.generate(prompt, max_tokens=6))
+    assert eng.stats.prefill_chunks == 5 < small.stats.prefill_chunks

@@ -5,6 +5,7 @@ python/ray/llm/_internal/serve/.../vllm_models.py:123-142)."""
 
 import contextlib
 import dataclasses
+import math
 
 import numpy as np
 import pytest
@@ -155,6 +156,44 @@ def test_prefill_chunk_is_derived_from_the_chip(kind, dtype, max_seq, share,
     chunk = derived_prefill_chunk(kind, jnp.dtype(dtype).itemsize, max_seq,
                                   *(() if share is None else (share,)))
     assert chunk == want and max_seq % chunk == 0
+
+
+@pytest.mark.parametrize("kind, llama_chunk", [
+    ("TPU v4", 256), ("TPU v5 lite", 256), ("TPU v5", 128), ("TPU v5p", 128),
+    ("TPU v6 lite", 512)])
+@pytest.mark.parametrize("terms, times", [
+    # nothing told, or told that nothing is paid beside the weights'
+    # read: the ridge's rows, as before a model could say more
+    ({}, 1), ({"read_beside": 0.0, "once_rows": 0.0}, 1),
+    # as much again read beside the counted weights: twice the rows
+    ({"read_beside": 1.0}, 2),
+    # work done once that is three ridges' worth of a row's: four times
+    ({"once_rows": 3.0}, 4),
+    # both, behind an expert's share of a quarter of the rows
+    ({"row_share": 1 / 4, "read_beside": 1.0, "once_rows": 8.0}, 16),
+])
+def test_what_a_call_pays_once_is_counted_in_rows(kind, llama_chunk, terms,
+                                                  times):
+    """Over every kind of the chip's table: the llama answer is the
+    parent's, and each term a model may state moves it as the rule says
+    (``once_rows`` given in ridges here, so that a case holds on every
+    kind)."""
+    from ray_tpu._private.accelerators.tpu import (CHIP_PEAKS,
+                                                   flops_per_hbm_byte)
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    assert set(CHIP_PEAKS) == {"TPU v4", "TPU v5 lite", "TPU v5", "TPU v5p",
+                               "TPU v6 lite"}
+    terms = dict(terms)
+    if "once_rows" in terms:
+        terms["once_rows"] *= flops_per_hbm_byte(kind)
+    max_seq = 1 << 16
+    chunk = derived_prefill_chunk(kind, 2, max_seq, **terms)
+    assert chunk == llama_chunk * times and max_seq % chunk == 0
+    for short in (64, 3 * 64, 3 * 1024, 5 * 4096):
+        fitted = derived_prefill_chunk(kind, 2, short, **terms)
+        assert fitted == (math.gcd(chunk, short) if chunk < short else short)
+        assert fitted <= short and short % fitted == 0
 
 
 @pytest.mark.parametrize("asked, chunk, buckets, windows", [

@@ -463,11 +463,11 @@ def test_engine_sizes_its_chunk_by_the_rows_its_heaviest_weights_see(
     if family == "llama":
         cfg = dataclasses.replace(llama.LLAMA_TINY, **f32)
         params, share = llama.init_params(jax.random.PRNGKey(0), cfg), 1.0
-        assert not hasattr(llama, "weight_row_share")
+        assert not hasattr(llama, "chunk_terms")
     else:
         cfg = dataclasses.replace(wm.WINDOW_MOE_TINY, **f32)
         params, share = wm.init_params(jax.random.PRNGKey(0), cfg), 2 / 8
-        assert wm.weight_row_share(cfg) == share
+        assert wm.chunk_terms(cfg, 2048) == {"row_share": share}
     kind = jax.devices()[0].device_kind
     assert chunk == derived_prefill_chunk(kind, 4, 2048, share)
     eng = LlamaEngine(cfg, params, max_batch=2, max_seq=2048)
@@ -488,6 +488,32 @@ def test_engine_sizes_its_chunk_by_the_rows_its_heaviest_weights_see(
         assert (eng.generate(prompt, max_tokens=6)
                 == small.generate(prompt, max_tokens=6))
     assert eng.stats.prefill_chunks == 4 < small.stats.prefill_chunks
+
+
+@pytest.mark.parametrize("kind, chunk", [
+    # 8 of 64 experts a row: an expert sees an eighth of a call's rows,
+    # and nothing else is told, so the sizes are the parent's (PR 47)
+    ("TPU v5 lite", 2048),   # 240 x 8 = 1924
+    ("TPU v4", 2048),        # 1792
+    ("TPU v5", 1024),        # 1328
+    ("TPU v5p", 1024),
+    ("TPU v6 lite", 4096),   # 4478
+])
+def test_the_cells_chunk_is_what_an_experts_rows_alone_give(kind, chunk):
+    from ray_tpu._private.accelerators.tpu import (CHIP_PEAKS,
+                                                   flops_per_hbm_byte)
+    from ray_tpu.llm._internal.engine import derived_prefill_chunk
+
+    assert kind in CHIP_PEAKS
+    cell = spec.load_cell(CELL, rehearse=False)
+    cfg = spec.family_of(cell["hp"]).model_config(cell["hp"])
+    max_seq = cell["serve"]["max_seq_len"]
+    terms = wm.chunk_terms(cfg, max_seq)
+    assert terms == {"row_share": 8 / 64}
+    got = derived_prefill_chunk(kind, 2, max_seq, **terms)
+    # the parent's rule, to the operation: the ridge over the share
+    rows = flops_per_hbm_byte(kind) * 2 / 2 / (8 / 64)
+    assert got == chunk == 2 ** round(np.log2(rows)) and max_seq % got == 0
 
 
 def test_abort_all_takes_a_cache_of_any_leaves(toy):
