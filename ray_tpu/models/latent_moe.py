@@ -32,11 +32,16 @@ one runs follows from the call alone:
 
 - a call of more than one row a sequence (a prefill chunk) **expands**:
   block by block of the cache's rows it makes the heads' keys and values
-  from the latent rows (``latent_expand``), once for all the chunk's
-  rows, which attend to them a tile of rows at a time, each tile with a
-  running maximum and sum (``attn_latent_prefill``), so no score of the
-  chunk's rows x the cache's rows x the heads ever exists, and a block
-  past the chunk's last row is never read;
+  from the latent rows, once for all the chunk's rows, which attend to
+  them a tile of rows at a time, each tile with a running maximum and
+  sum, so no score of the chunk's rows x the cache's rows x the heads
+  ever exists, and a block past the chunk's last row is never read.
+  Where the widths tile (the published ones do) that is one Pallas
+  kernel a layer, ``ops/pallas_latent_attention.py``, which reads the
+  cache's stacks where they lie and keeps a head's score, softmax and
+  accumulator in VMEM (scope ``attn_latent_prefill``); elsewhere a
+  ``jax.numpy`` loop with the same arithmetic (``latent_expand`` +
+  ``attn_latent_prefill``), which is also the kernel's reference;
 - a call of one row a sequence (a decode) **absorbs**: ``q_nope . (c
   Wuk) = (q_nope Wuk^T) . c`` and ``sum_s p_s (c_s Wuv) = (sum_s p_s
   c_s) Wuv``, so every head attends to the latent rows themselves, one
@@ -67,6 +72,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -146,17 +152,24 @@ COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
 # the counters' low words carry into the high ones from here; no call
 # may count this much at once (2048 rows x 16 384 x 5 layers is 2^27)
 _CARRY_BITS = 30
-# cache rows an attention loop takes at a time, and the rows of a chunk
-# that attend to them at a time: a chunk's score of one tile and block is
-# heads x PREFILL_TILE x PREFILL_BLOCK float32 (34 MB at 128 heads), a
-# decode's lanes x heads x DECODE_BLOCK. Read on a v5e at the published
-# widths: a 256-row chunk call takes 2.3 us an attended row at blocks of
-# 256, 2.6 at 128, 3.3 at 512, 4.8 at 1024; a decode call of 32 lanes
-# the same at 512, 1024 and 2048 (PERF.md section 6, PR 48). A 1024-row
-# call takes 11 us an attended row in tiles of 256, 14 in tiles of 128,
-# 16 in tiles of 512 and 21 as one tile, where four 256-row calls take
-# 9: a score costs what it costs at 256 rows only in tiles of 256
-# (PERF.md section 6, PR 49)
+# What the ``jax.numpy`` loops take at a time: cache rows a block, and
+# (the prefill form's loop, ``attend_expanded_blockwise``, which since
+# PR 50 is the form of the widths the kernel cannot tile and the tests'
+# reference) the rows of a chunk that attend to them at a time: its
+# score of one tile and block is heads x PREFILL_TILE x PREFILL_BLOCK
+# float32 (34 MB at 128 heads), a decode's lanes x heads x DECODE_BLOCK.
+# Read on a v5e at the published widths, when the loop was the cell's
+# path: a 256-row chunk call 2.3 us an attended row at blocks of 256,
+# 2.6 at 128, 3.3 at 512, 4.8 at 1024; a 1024-row call 11 us in tiles of
+# 256, 14 in tiles of 128, 16 in tiles of 512 and 21 as one tile; a
+# decode call of 32 lanes the same at 512, 1024 and 2048 (PERF.md
+# section 6, PR 48 and PR 49). The kernel (``ops/
+# pallas_latent_attention.py``) sizes itself: there 1024 rows at row
+# 3072, a layer, read 8.1 ms at blocks of 256 rows and 2 heads a step,
+# 4.2 at 512 rows and 4 heads in tiles of 512, 4.1 at 1024 rows and at
+# 8 heads, 4.5 in tiles of 256 (the row maxima's lane reductions are
+# paid once a tile and block whatever its width; PERF.md section 6,
+# PR 50)
 PREFILL_BLOCK = 256
 PREFILL_TILE = 256
 DECODE_BLOCK = 1024
@@ -310,18 +323,69 @@ def _blocks(rows: int, block: int) -> int:
     return block
 
 
-def attend_expanded(c: LatentMoEConfig, q_nope, q_rope, read, S: int, pos,
-                    layer):
-    """The prefill form. q_nope (B, T, H, nope), q_rope (B, T, H, rope)
-    at the positions ``pos`` (B, T); ``read(start, size)`` gives rows
-    ``[start, start + size)`` of the sequences' ``S`` cache rows (latent
-    rows (B, size, kv_rank), rotary keys (B, rope, size)), the call's
-    own among them -> (B, T, H, v) in the compute type. ``PREFILL_BLOCK``
-    rows of the cache at a time: their keys and values are made from the
-    latent rows once, the chunk's rows attend to them ``PREFILL_TILE``
-    at a time, each tile with a running maximum and sum of its own that
-    carry its softmax; the loop ends with the block that holds the
-    call's last position."""
+def _stack_reader(stack, layer, first, B: int):
+    """``read(start, size)`` over the cache's two stacks: rows ``[start,
+    start + size)`` of layer ``layer`` and sequences ``first .. first +
+    B`` (latent rows (B, size, kv_rank), rotary keys (B, rope, size)),
+    the block alone out of the layers' stacks."""
+    latents, keys = stack
+
+    def read(start, size):
+        return (jax.lax.dynamic_slice(
+                    latents, (layer, first, start, 0),
+                    (1, B, size, latents.shape[3]))[0],
+                jax.lax.dynamic_slice(
+                    keys, (layer, first, 0, start),
+                    (1, B, keys.shape[2], size))[0])
+    return read
+
+
+def attend_expanded(c: LatentMoEConfig, q_nope, q_rope, stack, index, first,
+                    S: int, start_pos, layer):
+    """The prefill form. q_nope (B, T, H, nope), q_rope (B, T, H, rope),
+    sequence b's T rows at the positions ``start_pos[b] ..``; ``stack``
+    the cache's two stacks (latent (L, B', max_seq, kv_rank), rope_key
+    (L, B', rope, max_seq)) of which layer ``index``, sequences ``first
+    .. first + B`` and the rows ``[0, S)`` are read, the call's own among
+    them; ``layer`` holds ``wuk`` and ``wuv`` -> (B, T, H, v) in the
+    compute type. Which of the two implementations runs follows from
+    the shapes alone: ``ops/pallas_latent_attention.py``'s kernel where
+    it can tile them, ``attend_expanded_blockwise`` elsewhere."""
+    # imported beside whatever followed this module's own import
+    # (``_import_kernel``); waits here for what is left of it
+    from ray_tpu.ops import pallas_latent_attention as kernel
+
+    B, T = q_nope.shape[:2]
+    wuk, wuv = layer["wuk"].astype(c.dtype), layer["wuv"].astype(c.dtype)
+    with jax.named_scope("latent_q"):
+        # head-major, as the kernel takes them: the turn is folded into
+        # the fusions that make the queries
+        by_head = q_nope.transpose(0, 2, 1, 3), q_rope.transpose(0, 2, 1, 3)
+    if kernel.untileable(*by_head, *stack, wuk, wuv, S) is None:
+        with jax.named_scope("attn_latent_prefill"):
+            return kernel.latent_prefill_attention(
+                *by_head, *stack, wuk, wuv, layer=index, slot=first,
+                start_pos=start_pos, rows=S,
+                scale=1.0 / math.sqrt(c.nope_dim + c.rope_dim))
+    pos = start_pos[:, None] + jnp.arange(T)[None, :]
+    return attend_expanded_blockwise(
+        c, q_nope, q_rope, _stack_reader(stack, index, first, B), S, pos,
+        layer)
+
+
+def attend_expanded_blockwise(c: LatentMoEConfig, q_nope, q_rope, read,
+                              S: int, pos, layer):
+    """The prefill form in ``jax.numpy``, for the shapes the kernel
+    cannot tile and as its numerical reference. q_nope (B, T, H, nope),
+    q_rope (B, T, H, rope) at the positions ``pos`` (B, T); ``read(
+    start, size)`` gives rows ``[start, start + size)`` of the
+    sequences' ``S`` cache rows (latent rows (B, size, kv_rank), rotary
+    keys (B, rope, size)), the call's own among them -> (B, T, H, v) in
+    the compute type. ``PREFILL_BLOCK`` rows of the cache at a time:
+    their keys and values are made from the latent rows once, the
+    chunk's rows attend to them ``PREFILL_TILE`` at a time, each tile
+    with a running maximum and sum of its own that carry its softmax;
+    the loop ends with the block that holds the call's last position."""
     B, T, H, _ = q_nope.shape
     block = _blocks(S, PREFILL_BLOCK)
     tile = _blocks(T, PREFILL_TILE)
@@ -572,21 +636,13 @@ def forward_with_cache(
             with jax.named_scope("kv_write"):
                 latents = _write_rows(latents, new, i, first, start_pos)
 
-            def read(start, size):
-                # out of the layers' stacks, the block alone
-                return (jax.lax.dynamic_slice(
-                            latents[0], (i, first, start, 0),
-                            (1, B, size, c.kv_rank))[0],
-                        jax.lax.dynamic_slice(
-                            latents[1], (i, first, 0, start),
-                            (1, B, c.rope_dim, size))[0])
-
             if T == 1:
-                attn = attend_absorbed(c, q_nope, q_rope, read, rows, pos,
-                                       layer, last)
+                attn = attend_absorbed(
+                    c, q_nope, q_rope, _stack_reader(latents, i, first, B),
+                    rows, pos, layer, last)
             else:
-                attn = attend_expanded(c, q_nope, q_rope, read, rows, pos,
-                                       layer)
+                attn = attend_expanded(c, q_nope, q_rope, latents, i, first,
+                                       rows, start_pos, layer)
             return attn_out(c, x, attn, layer), latents
 
     def dense_body(carry, layer):
@@ -621,3 +677,17 @@ def forward_with_cache(
                             preferred_element_type=jnp.float32)
     return logits, {"latent": latents[0], "rope_key": latents[1],
                     "counts": counts}
+
+
+def _import_kernel():
+    from ray_tpu.ops import pallas_latent_attention  # noqa: F401
+
+
+# Pallas takes 1.2 s to import on a replica's host, a chunk program's
+# first trace needs it, and ``setup_s`` is a metric with a bound. A
+# process that imports this module to serve goes on to open its chip,
+# nine seconds in which Python has nothing to do: the import runs beside
+# that, and ``attend_expanded``'s own import finds it done (or waits on
+# the module's lock for the rest). PERF.md section 6, PR 50.
+threading.Thread(target=_import_kernel, name="import-latent-kernel",
+                 daemon=True).start()

@@ -176,14 +176,14 @@ def test_blockwise_prefill_attention_equals_the_whole_matrix(
     layer, q_nope, q_rope, latent, pos = attention_inputs(
         c, 3, 2, 64, 16, jnp.asarray([24, 37]))
     want = attend_whole(c, q_nope, q_rope, latent, pos, layer)
-    got = lm.attend_expanded(c, q_nope, q_rope, rows_of(c, latent), 64, pos,
-                             layer)
+    got = lm.attend_expanded_blockwise(
+        c, q_nope, q_rope, rows_of(c, latent), 64, pos, layer)
     assert rel_rms(got, want) < 1e-5
     # nothing behind the call's last row was read: other garbage there,
     # the same result to the last bit
     other = latent.at[:, 53:].set(1e6)
-    again = lm.attend_expanded(c, q_nope, q_rope, rows_of(c, other), 64, pos,
-                               layer)
+    again = lm.attend_expanded_blockwise(
+        c, q_nope, q_rope, rows_of(c, other), 64, pos, layer)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
 
 
@@ -205,22 +205,90 @@ def test_the_absorbed_form_equals_the_expanded_form_on_the_same_cache(
     assert rel_rms(got[live], want[live]) < 1e-5
 
 
-def test_no_program_holds_a_score_of_chunk_by_cache_by_heads():
-    """The compiled chunk call at a cache of 4096 rows: its largest
-    float32 buffer is a block's score, not the cache's."""
-    c = dataclasses.replace(F32, max_seq_len=4096)
+# widths the prefill kernel can tile (``ops/pallas_latent_attention.py``;
+# ``test_pallas_latent_attention.py`` has the kernel's own cases)
+TILEABLE = dataclasses.replace(F32, n_heads=2, nope_dim=128, rope_dim=64,
+                               v_dim=128, kv_rank=128)
+
+
+def chunk_program(c, T, S):
+    """The chunk call of T rows over a cache of one lane of S rows,
+    lowered from shapes alone."""
     params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0), c))
-    cache = jax.eval_shape(lambda: lm.init_cache(c, 1, 4096))
-    text = jax.jit(lambda p, t, k, s: lm.forward_with_cache(
+    cache = jax.eval_shape(lambda: lm.init_cache(c, 1, S))
+    return cache, jax.jit(lambda p, t, k, s: lm.forward_with_cache(
         p, t, k, s, c, logits_at=jnp.zeros(1, jnp.int32))).lower(
-            params, jax.ShapeDtypeStruct((1, 64), jnp.int32), cache,
-            jax.ShapeDtypeStruct((1,), jnp.int32)).as_text()
-    T, H, S = 64, c.n_heads, 4096
+            params, jax.ShapeDtypeStruct((1, T), jnp.int32), cache,
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("form", ["block_loop", "kernel"])
+def test_no_program_holds_a_score_of_chunk_by_cache_by_heads(form):
+    """The chunk call at a cache of 4096 rows, in the ``jax.numpy`` form
+    (widths the kernel cannot tile: a block's score is its largest
+    float32 buffer) and in the kernel's (no float32 buffer of heads x
+    rows x block at all: a score is a tile's, a head at a time)."""
+    c, T = (F32, 64) if form == "block_loop" else (TILEABLE, 128)
+    c = dataclasses.replace(c, max_seq_len=4096)
+    cache, lowered = chunk_program(c, T, 4096)
+    text = lowered.as_text()
+    H, S = c.n_heads, 4096
     assert f"{H}x{T}x{S}x" not in text and f"{T}x{S}x" not in text
-    assert f"{H}x{T}x{lm.PREFILL_BLOCK}xf32" in text
-    # 32 + 8 values a token a layer, nothing per head
+    held = f"{H}x{T}x{lm.PREFILL_BLOCK}xf32" in text
+    assert held == (form == "block_loop")
+    # kv_rank + rope values a token a layer, nothing per head
     assert {k: v.shape for k, v in cache.items() if k != "counts"} == {
-        "latent": (3, 1, 4096, 32), "rope_key": (3, 1, 8, 4096)}
+        "latent": (3, 1, 4096, c.kv_rank),
+        "rope_key": (3, 1, c.rope_dim, 4096)}
+
+
+def test_the_kernels_instructions_carry_the_prefill_forms_scope():
+    """``scope_map`` of a compiled chunk program at widths the kernel
+    tiles: whatever the kernel became (on the CPU its interpreted ops,
+    on a TPU one custom call) lies under ``attn_latent_prefill``, which
+    the benchmark's two readers of that scope join a trace with; and no
+    block is sliced out of the cache for it (``kv_slice``)."""
+    from ray_tpu._private.jax_utils import scope_map
+
+    _, lowered = chunk_program(TILEABLE, 128, 256)
+    scopes = scope_map(lowered.compile())
+    kernels = [s for s in scopes.values() if "latent_attention_prefill" in s]
+    # (a few instructions the CPU compiler makes of the interpreted
+    # kernel's reductions keep the path from the kernel's name on only)
+    placed = [s for s in kernels
+              if not s.startswith("latent_attention_prefill")]
+    assert len(placed) > 0.9 * len(kernels) > 0
+    assert all("attn/attn_latent_prefill/" in s for s in placed)
+    assert not any("kv_slice" in s for s in scopes.values())
+
+
+def test_the_control_reaches_the_kernel_through_the_layers_weights():
+    """``benchmarks/tests/control_pangu.py`` patches ``attend_expanded``
+    by name and rounds ``wuk`` and ``wuv`` in the ``layer`` it finds
+    among the arguments: on the kernel's path the result changes with
+    them, by fp8's rounding and not by nothing."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "tests"))
+    import control_pangu
+
+    c = TILEABLE
+    keys = jax.random.split(jax.random.PRNGKey(8), 6)
+    stack = (jax.random.normal(keys[0], (2, 2, 256, c.kv_rank)),
+             jax.random.normal(keys[1], (2, 2, c.rope_dim, 256)))
+    layer = {"wuk": jax.random.normal(keys[2], (c.kv_rank, 2, 128)) / 6,
+             "wuv": jax.random.normal(keys[3], (c.kv_rank, 2, 128)) / 6}
+    q_nope = jax.random.normal(keys[4], (1, 128, 2, 128))
+    q_rope = jax.random.normal(keys[5], (1, 128, 2, 64))
+    args = (c, q_nope, q_rope, stack, 1, 1, 256, jnp.asarray([100]), layer)
+    from ray_tpu.ops import pallas_latent_attention as kernel
+    assert kernel.untileable(
+        q_nope.transpose(0, 2, 1, 3), q_rope.transpose(0, 2, 1, 3), *stack,
+        layer["wuk"], layer["wuv"], 256) is None
+    sound = lm.attend_expanded(*args)
+    with control_pangu.fp8():
+        rounded = lm.attend_expanded(*args)
+    assert lm.attend_expanded(*args) is not None      # the patch is gone
+    assert 0.01 < rel_rms(rounded, sound) < 0.2
 
 
 # ------------------------------------------------------ the held share
