@@ -20,9 +20,9 @@ _ID_LEN = 14  # bytes; 112 bits of randomness — collision-free in practice
 # Batched entropy: os.urandom is a syscall, and ID generation sits on
 # the submit hot path (TaskID + per-return ObjectID per call) — at 1k
 # submits/s the per-call syscalls measurably steal GIL time from the
-# in-process hub thread (BENCH_NOTE.md). One urandom refill serves 1024
-# IDs; the bytes come from the same CSPRNG, so collision behavior is
-# unchanged. Per-thread buffers keep this lock-free.
+# in-process hub thread. One urandom refill serves 1024 IDs; the bytes
+# come from the same CSPRNG, so collision behavior is unchanged.
+# Per-thread buffers keep this lock-free.
 _ID_POOL_IDS = 1024
 _entropy = threading.local()
 if hasattr(os, "register_at_fork"):
@@ -72,7 +72,7 @@ def id_pair() -> tuple:
     """Two pooled ids in one draw — the per-call ``.remote()`` shape
     (one task id + one return object id). Same entropy pool as
     ``id_slab``, minus the per-call slab bookkeeping: this sits on the
-    client's batched-submit hot path (bench_core submit_path_overhead)."""
+    client's batched-submit hot path."""
     buf = getattr(_entropy, "buf", None)
     pos = getattr(_entropy, "pos", 0)
     end = pos + 2 * _ID_LEN

@@ -78,12 +78,14 @@ re-designed for XLA instead of wrapped:
   ``LLMServer`` calls it before it takes a request, a bare engine
   compiles on first use.
 - The model is the configuration's: ``config.model_module`` names the
-  module that gives ``forward_with_cache``, ``init_cache`` and
-  ``attn_rows_read`` (``models/llama.py``; ``models/window_moe.py``,
-  whose cache is rows by position for its full layers, a ring of a
-  window's rows for its sliding ones, and the ``moe_*`` counters its
-  programs accumulate on the device). Nothing below knows what a cache
-  holds: it is a pytree the programs take and return.
+  family's module, written on ``models/decoder.py``. It gives
+  ``init_params``, ``init_cache``, ``forward_with_cache`` and
+  ``attn_rows_read``; ``read_counters`` where its programs count on the
+  device; ``chunk_terms`` where a call pays otherwise than by every row
+  meeting every weight. Nothing below knows what a cache holds: it is a
+  pytree the programs take and return. Beside the signature the engine
+  and the programs share one thing, ``decoder.idle_position``: the
+  length a lane that is nobody's is dispatched at.
 - KV cache is preallocated per shard (L, B, KVH, max_seq, hd) and
   UPDATED IN PLACE: both programs are jitted with the cache donated,
   the cached forward carries it through its layer scan and writes only
@@ -356,17 +358,15 @@ class LlamaEngine:
         import jax
         import jax.numpy as jnp
 
-        # the model's functions are the configuration's: the module it
-        # names gives forward_with_cache, init_cache and attn_rows_read
-        # (models/llama.py; models/window_moe.py), read_counters where
-        # its programs count on the device, and chunk_terms where a
-        # call pays otherwise than by every row meeting every weight
+        from ray_tpu.models.decoder import idle_position
+
         model = importlib.import_module(config.model_module)
 
         self.config = config
         self.params = params
         self.max_batch = max_batch
         self.max_seq = max_seq
+        self._idle = idle_position(max_seq)     # an idle lane's length
         if prefill_chunk is None:
             leaves = jax.tree_util.tree_leaves(params)
             terms = (model.chunk_terms(config, max_seq)
@@ -507,11 +507,11 @@ class LlamaEngine:
     def decode_window(self, lengths) -> int:
         """Rows a decode call reads: a lane of ``lengths[b]`` rows
         writes one more and attends to all of them. Idle lanes carry the
-        scratch row ``max_seq - 1`` and what they compute is dropped; a
-        live lane is dispatched at ``max_seq - 3`` at most
+        idle position, the scratch row, and what they compute is
+        dropped; a live lane is dispatched two rows short of it at most
         (``_last_by_count``). ``lengths`` is the numpy array the call is
         handed."""
-        live = lengths[lengths != self.max_seq - 1]
+        live = lengths[lengths != self._idle]
         return self._window(int(live.max()) + 1) if live.size else self.max_seq
 
     # The two programs as every caller knows them (``step()``, the
@@ -602,7 +602,7 @@ class LlamaEngine:
             for rows in self.windows:
                 toks, shard.cache, _ = self._jit_decode(
                     self.params, shard.cache, tokens,
-                    np.full(self.max_batch, self.max_seq - 1, np.int32),
+                    np.full(self.max_batch, self._idle, np.int32),
                     np.zeros(self.max_batch, np.float32), self._rng,
                     rows=rows)
             self._jax.block_until_ready((toks, shard.cache))
@@ -755,7 +755,7 @@ class LlamaEngine:
         ``decode_window`` tells the idle lanes). Known before the token
         is."""
         return (count >= req.max_tokens
-                or len(req.prompt_ids) + count >= self.max_seq - 1)
+                or len(req.prompt_ids) + count >= self._idle)
 
     def _dispatch_decode(self, shard: _Shard):
         """Dispatch one decode for the shard's lanes that have a token
@@ -773,10 +773,10 @@ class LlamaEngine:
             temps = np.zeros(self.max_batch, np.float32)
             # inactive lanes (free, mid-prefill or at their last token)
             # still ride the batched decode; point their cache write at
-            # the scratch row (max_seq-1, provably never attended:
-            # sequences finish before reaching it) so they cannot
-            # corrupt a half-prefilled prompt's rows
-            lens = np.full(self.max_batch, self.max_seq - 1, np.int32)
+            # the scratch row (the idle position, provably never
+            # attended: sequences finish before reaching it) so they
+            # cannot corrupt a half-prefilled prompt's rows
+            lens = np.full(self.max_batch, self._idle, np.int32)
             for slot, req in lanes:
                 temps[slot] = req.temperature
                 lens[slot] = shard.lengths[slot]

@@ -2,7 +2,7 @@
 
 The reference (Ray) delegates model code to torch/vLLM downstream; this
 framework ships JAX-native models so its ML libraries have first-class
-workloads (flagship: Llama — BASELINE.json north star).
+workloads (flagship: Llama).
 """
 
 from . import llama, moe_llama, vit

@@ -1,0 +1,156 @@
+"""What every served family's cached forward has in common. A family's
+module (``models/llama.py``, ``window_moe.py``, ``latent_moe.py``) states
+its cache, its mixer (what turns a layer's input into the attended rows,
+writing the cache on the way) and its feed-forward; the call around them
+is here, once: **the call's rows** (``Call``; the idle position is
+defined here and the engine dispatches what it says), **embedding and
+head**, **the layer scan** (``scan_layers``) and **the device counters**
+(two int32 words each in the cache, ``fold_counts``, ``read_counters``).
+
+It imports no family's module and takes no argument that says which one
+calls it. The scopes it opens (``embed``, ``layers``, ``head``) are
+names ``benchmarks/`` reads out of a trace.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    dt = x.dtype
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)).astype(dt) * weight.astype(dt)
+
+
+def idle_position(max_seq: int) -> int:
+    """The position of a decode lane that is nobody's (free, mid-prefill
+    or past its last token): the cache's last row, which no live
+    sequence reaches, so what the lane writes is never attended to."""
+    return max_seq - 1
+
+
+class Call:
+    """The rows of one cached call: ``tokens`` (B, T) appended at the
+    per-sequence offsets ``start_pos`` (B,) of a cache ``max_seq`` rows
+    long. T is static (bucketed by the engine); ``start_pos`` is traced.
+
+    Row ``b`` of ``tokens`` belongs to row ``b`` of the cache, or to row
+    ``slot + b`` where ``slot`` (a traced scalar) is given: the engine
+    prefills one sequence, tokens (1, T), into its slot of a shard.
+
+    With ``logits_at`` (B,), the row of each sequence's T whose logits
+    the caller keeps, the final norm and the head run on those rows
+    alone and the logits are (B, 1, V): a prefill chunk samples from
+    its last real token only.
+
+    ``rows`` (static; default all ``max_seq``) is the read window:
+    attention reads cache rows ``[0, rows)`` of each sequence and no
+    more. The caller vouches that every row a live query may attend to
+    (``start_pos + T`` of them) lies inside; a masked row weighs
+    exp(-1e30 - max) = 0 exactly, so any such window gives the full
+    read's result. Writes go to the full cache wherever ``start_pos``
+    says, inside the window or not (an idle lane's to its scratch row).
+    """
+
+    def __init__(self, tokens, start_pos, max_seq: int, *, slot=None,
+                 logits_at=None, rows: Optional[int] = None):
+        self.B, self.T = tokens.shape
+        self.start_pos = start_pos
+        self.pos = start_pos[:, None] + jnp.arange(self.T)[None, :]  # (B, T)
+        self.first = 0 if slot is None else slot
+        self.window = max_seq if rows is None else rows
+        self.logits_at = logits_at
+        self.max_seq = max_seq
+
+    def live(self) -> jax.Array:
+        """(B, T) bool: the rows that are somebody's tokens. Not a
+        decode lane at the idle position; not the rows of a padded chunk
+        behind the one its logits are taken at. They are computed like
+        the others and left out of every count."""
+        if self.T == 1:
+            return self.start_pos[:, None] != idle_position(self.max_seq)
+        if self.logits_at is not None:
+            return jnp.arange(self.T)[None, :] <= self.logits_at[:, None]
+        return jnp.ones((self.B, self.T), bool)
+
+
+def embed(params: Dict[str, Any], tokens: jax.Array, config) -> jax.Array:
+    with jax.named_scope("embed"):
+        return params["embed"].astype(config.dtype)[tokens]
+
+
+def final_rows(params: Dict[str, Any], x: jax.Array, config,
+               logits_at=None) -> jax.Array:
+    """The rows the head runs on, through the final norm: row
+    ``logits_at[b]`` of each sequence (B, 1, D), or all of them."""
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+    return rms_norm(x, params["final_norm"], config.norm_eps)
+
+
+def head(params: Dict[str, Any], x: jax.Array, config,
+         logits_at=None) -> jax.Array:
+    """-> float32 logits of ``final_rows``: the product in the compute
+    type, widened."""
+    with jax.named_scope("head"):
+        x = final_rows(params, x, config, logits_at)
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"].astype(config.dtype))
+        return logits.astype(jnp.float32)
+
+
+def scan_layers(step, x: jax.Array, state, layers, n_counted: int = 0):
+    """``step(x, state, layer, i) -> (x, state, counted)`` over the
+    stacked ``layers``, ``i`` the traced index of the layer among them;
+    ``state`` is whatever of the cache the step writes and reads,
+    carried so that a jit that donates it updates it in place.
+    ``counted``: int32 (n_counted,), summed over the layers; a step that
+    counts nothing returns None. -> (x, state, the sum or None).
+
+    Operations scoped ``layers`` and nothing deeper are the scan's own:
+    a layer's weights sliced out of the stack."""
+    def body(carry, layer):
+        x, state, counts, i = carry
+        x, state, counted = step(x, state, layer, i)
+        if counts is not None:
+            counts = counts + counted
+        return (x, state, counts, i + 1), None
+
+    with jax.named_scope("layers"):
+        counts = jnp.zeros(n_counted, jnp.int32) if n_counted else None
+        (x, state, counts, _), _ = jax.lax.scan(
+            body, (x, state, counts, jnp.int32(0)), layers)
+    return x, state, counts
+
+
+# A counter is two int32 words, high and low; the low word carries into
+# the high one from here, and no call may count this much at once.
+_CARRY_BITS = 30
+
+
+def counter_words(n: int) -> jax.Array:
+    """``n`` counters at zero, as a cache holds them: int32 (n, 2)."""
+    return jnp.zeros((n, 2), jnp.int32)
+
+
+def fold_counts(words: jax.Array, counted: jax.Array) -> jax.Array:
+    """``words`` (n, 2) with one call's ``counted`` int32 (n,) added."""
+    with jax.named_scope("layers"):     # where a trace has always had them
+        low = words[:, 1] + counted
+        return jnp.stack([words[:, 0] + (low >> _CARRY_BITS),
+                          low & ((1 << _CARRY_BITS) - 1)], axis=1)
+
+
+def read_counters(cache, names: Sequence[str]) -> Dict[str, int]:
+    """What the programs that wrote one cache shard have counted under
+    each of ``names``, the order of its ``counts`` (waits for the
+    program that last wrote it)."""
+    hi_lo = np.asarray(cache["counts"]).astype(np.int64)
+    totals = (hi_lo[:, 0] << _CARRY_BITS) + hi_lo[:, 1]
+    return dict(zip(names, (int(t) for t in totals)))

@@ -54,11 +54,12 @@ absorbed form pays the wider key and value at every pair).
 
 **Parameters are stacked by kind of layer**: ``dense`` and ``routed``
 each hold their layers' attention and norms, and the MLP of their own
-shape; the layer scan runs over the one and then over the other, the
-cache and the counters riding in both carries.
+shape; ``decoder.scan_layers`` runs over the one and then over the
+other, the cache riding in both carries. The call around the layers
+(its rows, embedding, head, the counters' words) is
+``models/decoder.py``'s.
 
-**Counters** ride in the cache as ``models/window_moe.py``'s do
-(``counts``, read by ``read_counters``): the ``moe_*`` three of
+**Counters** (``COUNTERS``, in the cache's ``counts``): the ``moe_*`` three of
 ``EngineStats`` (held experts only), ``moe_assignments_all`` (every live
 row's ``experts_per_token``, so that the held share of the routing is
 read and not assumed), and for the two attention forms
@@ -73,6 +74,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import threading
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -82,7 +84,9 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import moe
 
-from .llama import LlamaConfig, apply_rope, make_dense_init, rms_norm
+from . import decoder
+from .decoder import rms_norm
+from .llama import LlamaConfig, apply_rope, make_dense_init
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,14 +148,12 @@ LATENT_MOE_TINY = LatentMoEConfig(
     held_experts=(4, 5, 6, 7), shared_dim=32,
 )
 
-EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 SHARED_WEIGHTS = ("shared_gate", "shared_up", "shared_down")
 COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
             "moe_assignments_all", "attn_pairs_prefill", "attn_rows_prefill",
             "attn_rows_decode")
-# the counters' low words carry into the high ones from here; no call
-# may count this much at once (2048 rows x 16 384 x 5 layers is 2^27)
-_CARRY_BITS = 30
+# (the most a call counts at once, 2048 rows x 16 384 x 5 layers, is
+# 2^27: under the carry of ``decoder``'s counter words)
 # What the ``jax.numpy`` loops take at a time: cache rows a block, and
 # (the prefill form's loop, ``attend_expanded_blockwise``, which since
 # PR 50 is the form of the widths the kernel cannot tile and the tests'
@@ -523,27 +525,19 @@ def moe_mlp(c: LatentMoEConfig, x, layer, experts, index, live=None):
         return x + rms_norm(out, layer["mlp_post_norm"], c.norm_eps), counts
 
 
-def _split_routed(routed):
-    """(what the scan over routed layers slices, the experts' stacks
-    that go to the grouped matmul whole)."""
-    return ({k: a for k, a in routed.items() if k not in EXPERT_WEIGHTS},
-            {k: routed[k] for k in EXPERT_WEIGHTS})
-
-
 # -- the cache ---------------------------------------------------------
 def init_cache(config: LatentMoEConfig, batch: int, max_seq: int,
                chunk: Optional[int] = None):
     """``latent`` (L, B, max_seq, kv_rank) and ``rope_key`` (L, B,
     rope_dim, max_seq) in the compute type, a row a position;
-    ``counts`` int32 (len(COUNTERS), 2), the counters' high and low
-    words."""
+    ``counts``: the device words of ``COUNTERS``."""
     del chunk
     c = config
     return {"latent": jnp.zeros((c.n_layers, batch, max_seq, c.kv_rank),
                                 c.dtype),
             "rope_key": jnp.zeros((c.n_layers, batch, c.rope_dim, max_seq),
                                   c.dtype),
-            "counts": jnp.zeros((len(COUNTERS), 2), jnp.int32)}
+            "counts": decoder.counter_words(len(COUNTERS))}
 
 
 def attn_rows_read(config: LatentMoEConfig, cache, rows: int) -> int:
@@ -555,12 +549,8 @@ def attn_rows_read(config: LatentMoEConfig, cache, rows: int) -> int:
     return rows
 
 
-def read_counters(cache) -> Dict[str, int]:
-    """What one cache shard's programs have counted (waits for the
-    program that last wrote it)."""
-    hi_lo = np.asarray(cache["counts"]).astype(np.int64)
-    totals = (hi_lo[:, 0] << _CARRY_BITS) + hi_lo[:, 1]
-    return dict(zip(COUNTERS, (int(t) for t in totals)))
+# what one cache shard's programs have counted
+read_counters = partial(decoder.read_counters, names=COUNTERS)
 
 
 def _write_rows(stack, new, layer, first, start_pos):
@@ -599,28 +589,17 @@ def forward_with_cache(
     cache and the counters ride in the carries of the two layer scans
     and are updated in place under a jit that donates the cache."""
     c = config
-    B, T = tokens.shape
-    max_seq = cache["latent"].shape[2]
-    rows = max_seq if rows is None else rows
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(c.dtype)[tokens]
-    pos = start_pos[:, None] + jnp.arange(T)[None, :]            # (B, T)
+    call = decoder.Call(tokens, start_pos, cache["latent"].shape[2],
+                        slot=slot, logits_at=logits_at, rows=rows)
+    B, T, pos, first = call.B, call.T, call.pos, call.first
+    x = decoder.embed(params, tokens, c)
     cos, sin = rope_cos_sin(c, pos)
-    first = 0 if slot is None else slot
-    # whose rows are somebody's tokens, for the counters: not an idle
-    # decode lane (the engine gives it position max_seq - 1), not the
-    # rows of a padded chunk behind the one its logits are taken at
-    if T == 1:
-        live = start_pos[:, None] != max_seq - 1
-    elif logits_at is not None:
-        live = jnp.arange(T)[None, :] <= logits_at[:, None]
-    else:
-        live = jnp.ones((B, T), bool)
+    live = call.live()
     seen_by_live = jnp.where(live, pos + 1, 0)       # rows a live row sees
     zero = jnp.int32(0)
     if T == 1:
         # the furthest row a live lane attends to bounds the loop
-        last = jnp.minimum(jnp.where(live, pos, 0).max(), rows - 1)
+        last = jnp.minimum(jnp.where(live, pos, 0).max(), call.window - 1)
         attended = jnp.stack([zero, zero, seen_by_live.sum()])
     else:
         attended = jnp.stack([seen_by_live.sum(),
@@ -639,44 +618,37 @@ def forward_with_cache(
             if T == 1:
                 attn = attend_absorbed(
                     c, q_nope, q_rope, _stack_reader(latents, i, first, B),
-                    rows, pos, layer, last)
+                    call.window, pos, layer, last)
             else:
                 attn = attend_expanded(c, q_nope, q_rope, latents, i, first,
-                                       rows, start_pos, layer)
+                                       call.window, start_pos, layer)
             return attn_out(c, x, attn, layer), latents
 
-    def dense_body(carry, layer):
-        x, latents, i = carry
+    def dense_step(x, latents, layer, i):
         x, latents = attention(x, latents, layer, i)
-        return (dense_mlp(c, x, layer), latents, i + 1), None
+        return dense_mlp(c, x, layer), latents, None
 
-    scanned, experts = _split_routed(params["routed"])
+    scanned, experts = moe.split_experts(params["routed"])
 
-    def routed_body(carry, layer):
-        x, latents, counts, i = carry
+    def routed_step(x, latents, layer, i):
         x, latents = attention(x, latents, layer, c.n_dense_layers + i)
         x, counted = moe_mlp(c, x, layer, experts, i, live)
-        return (x, latents, counts + counted, i + 1), None
+        return x, latents, counted
 
-    with jax.named_scope("layers"):
-        (x, latents, _), _ = jax.lax.scan(
-            dense_body, (x, (cache["latent"], cache["rope_key"]), zero),
-            params["dense"])
-        (x, latents, counted, _), _ = jax.lax.scan(
-            routed_body, (x, latents, jnp.zeros(4, jnp.int32), zero), scanned)
-        low = cache["counts"][:, 1] + jnp.concatenate(
-            [counted, attended * c.n_layers])
-        counts = jnp.stack(
-            [cache["counts"][:, 0] + (low >> _CARRY_BITS),
-             low & ((1 << _CARRY_BITS) - 1)], axis=1)
+    x, latents, _ = decoder.scan_layers(
+        dense_step, x, (cache["latent"], cache["rope_key"]), params["dense"])
+    x, latents, counted = decoder.scan_layers(
+        routed_step, x, latents, scanned, 4)
+    with jax.named_scope("layers"):     # counted beside the scans
+        counted = jnp.concatenate([counted, attended * c.n_layers])
     with jax.named_scope("head"):
-        if logits_at is not None:
-            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        # accumulated in float32, where the other families round the
+        # product to the compute type and widen (ROADMAP Queue 3 item 1)
+        x = decoder.final_rows(params, x, c, logits_at)
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(c.dtype),
                             preferred_element_type=jnp.float32)
     return logits, {"latent": latents[0], "rope_key": latents[1],
-                    "counts": counts}
+                    "counts": decoder.fold_counts(cache["counts"], counted)}
 
 
 def _import_kernel():

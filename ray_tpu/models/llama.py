@@ -1,8 +1,7 @@
 """Llama-family decoder-only transformer, TPU-first.
 
-Flagship model for the framework (BASELINE.json north star: Llama-3-8B
-on TPU pods). Design choices are deliberately XLA-shaped rather than a
-torch translation:
+Flagship model for the framework. Design choices are deliberately
+XLA-shaped rather than a torch translation:
 
 - Parameters are a flat pytree of arrays with **stacked layers**
   (leading ``n_layers`` axis) consumed by ``lax.scan`` — one compiled
@@ -37,6 +36,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from . import decoder
+from .decoder import rms_norm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,17 +188,35 @@ def init_params(rng: jax.Array, config: LlamaConfig) -> Dict[str, Any]:
     }
 
 
+def init_routed_params(rng: jax.Array, config, expert_dim: int):
+    """``init_params``' tree for a decoder whose every feed-forward is
+    ``config.n_experts`` routed experts ``expert_dim`` wide: (L, E, ...)
+    expert matrices beside a router, which stays float32 (tiny, and
+    routing is precision-sensitive)."""
+    c = config
+    (k_embed, k_q, k_k, k_v, k_o, k_r, k_g, k_u, k_d,
+     k_lm) = jax.random.split(rng, 10)
+    dense = make_dense_init(c)
+    L, E, D, F = c.n_layers, c.n_experts, c.dim, expert_dim
+    return {
+        "embed": dense(k_embed, (c.vocab_size, D), D),
+        "blocks": {
+            **init_attn_params(c, (k_q, k_k, k_v, k_o), dense),
+            "router": jax.random.normal(
+                k_r, (L, D, E), jnp.float32) / math.sqrt(D),
+            "w_gate": dense(k_g, (L, E, D, F), D),
+            "w_up": dense(k_u, (L, E, D, F), D),
+            "w_down": dense(k_d, (L, E, F, D), F),
+        },
+        "final_norm": jnp.ones((D,), c.param_dtype),
+        "lm_head": dense(k_lm, (D, c.vocab_size), D),
+    }
+
+
 def param_count(config: LlamaConfig) -> int:
     c = config
     per_layer = attn_param_count(c) + 3 * c.dim * c.ffn_dim
     return c.vocab_size * c.dim * 2 + c.n_layers * per_layer + c.dim
-
-
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    dt = x.dtype
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps)).astype(dt) * weight.astype(dt)
 
 
 def rope_table(config: LlamaConfig, seq_len: int) -> Tuple[jax.Array, jax.Array]:
@@ -286,9 +306,14 @@ def _attention(q, k, v, config: LlamaConfig):
 
 def attention_sublayer(config: LlamaConfig, x: jax.Array,
                        layer: Dict[str, jax.Array],
-                       cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Pre-norm GQA attention + residual (shared by the dense and MoE
-    model families — fix attention once, both models follow)."""
+                       cos: jax.Array, sin: jax.Array,
+                       mixer=_attention) -> jax.Array:
+    """Pre-norm GQA attention + residual: the one spelling of the
+    projections and their rotation, for train, prefill and decode and
+    for every family whose layers project so. ``mixer(q, k, v, config)``
+    turns the rotated queries and keys and the values into the attended
+    rows (B, S, H, hd): whole-sequence causal attention, or a closure
+    over a cache."""
     c = config
     with jax.named_scope("attn"):
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
@@ -297,7 +322,7 @@ def attention_sublayer(config: LlamaConfig, x: jax.Array,
         v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        attn = _attention(q, k, v, c)
+        attn = mixer(q, k, v, c)
         return x + jnp.einsum(
             "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
 
@@ -518,6 +543,32 @@ def _attention_cached(q, k_cache, v_cache, pos, config: LlamaConfig,
     return out.reshape(B, T, H, hd)
 
 
+def write_and_read(stacks, k, v, layer, call: decoder.Call, rows: int,
+                   write=write_rows):
+    """A layer's new keys and values (B, T, KVH, hd) into the ``k`` and
+    ``v`` of ``stacks`` (``write(stack, new, layer, first, start_pos)``),
+    then the first ``rows`` rows of the call's sequences read back out
+    of them -> (k (B, KVH, rows, hd), v, the stacks)."""
+    k_all, v_all = stacks["k"], stacks["v"]
+    with jax.named_scope("kv_write"):
+        k_all = write(k_all, k, layer, call.first, call.start_pos)
+        v_all = write(v_all, v, layer, call.first, call.start_pos)
+    with jax.named_scope("kv_slice"):
+        k_c = read_rows(k_all, layer, call.first, call.B, rows)
+        v_c = read_rows(v_all, layer, call.first, call.B, rows)
+        if call.T >= 128:
+            # From a chunk of 128 rows (a register's lanes) the TPU
+            # compiler gives QK^T its K with the rows minor. Read
+            # straight off the carry, that layout goes to the whole
+            # stack, and the scan is bracketed by two transposing copies
+            # of the shard. Behind the barrier only these sequences'
+            # rows of this layer are laid out anew. Under 128 rows there
+            # are no such copies and the barrier would only add one.
+            # (tests/aot_compile_check.py compiles both sides.)
+            k_c = jax.lax.optimization_barrier(k_c)
+    return k_c, v_c, {"k": k_all, "v": v_all}
+
+
 def forward_with_cache(
     params: Dict[str, Any],
     tokens: jax.Array,
@@ -530,97 +581,33 @@ def forward_with_cache(
     rows: Optional[int] = None,
 ):
     """Incremental forward: tokens (B, T) appended at per-sequence
-    offsets ``start_pos`` (B,). Returns (logits (B, T, V) fp32, updated
-    cache). T is static (bucketed by the engine); start_pos is traced.
-
-    With ``logits_at`` (B,), the row of each sequence's T whose logits
-    the caller keeps, the final norm and the head run on those rows
-    alone and the logits are (B, 1, V): a prefill chunk samples from
-    its last real token only.
-
-    Row ``b`` of ``tokens`` belongs to row ``b`` of the cache, or to row
-    ``slot + b`` where ``slot`` (a traced scalar) is given: the engine
-    prefills one sequence, tokens (1, T), into its slot of a shard.
-
-    ``rows`` (static; default all ``max_seq``) is the read window:
-    attention reads cache rows ``[0, rows)`` of each sequence and no
-    more. The caller vouches that every row a live query may attend to
-    (``start_pos + T`` of them) lies inside; a masked row weighs
-    exp(-1e30 - max) = 0 exactly, so any such window gives the full
-    read's result. Writes go to the full cache wherever ``start_pos``
-    says, inside the window or not (an idle lane's to its scratch row).
+    offsets ``start_pos`` (B,); ``slot``, ``logits_at`` and ``rows`` mean
+    what ``decoder.Call`` says. Returns (logits fp32, (B, T, V) or with
+    ``logits_at`` (B, 1, V); the updated cache).
 
     The cache is updated in place: the stacked k/v ride in the layer
-    scan's carry, a layer writes its T new rows into them and reads its
-    own rows for attention out of them. Under a jit that donates the
-    cache nothing else of it moves.
+    scan's carry (``decoder.scan_layers``), a layer writes its T new
+    rows into them and reads its own rows for attention out of them.
+    Under a jit that donates the cache nothing else of it moves.
     """
     c = config
-    B, T = tokens.shape
-    _, _, KVH, max_seq, hd = cache["k"].shape
-    rows = max_seq if rows is None else rows
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(c.dtype)[tokens]
+    max_seq = cache["k"].shape[3]
+    call = decoder.Call(tokens, start_pos, max_seq, slot=slot,
+                        logits_at=logits_at, rows=rows)
+    x = decoder.embed(params, tokens, c)
     cos_full, sin_full = rope_table(c, max_seq)
-    pos = start_pos[:, None] + jnp.arange(T)[None, :]          # (B, T)
-    cos = cos_full[pos]                                         # (B, T, hd/2)
-    sin = sin_full[pos]
-    first = 0 if slot is None else slot  # the cache row of tokens' row 0
+    cos, sin = cos_full[call.pos], sin_full[call.pos]       # (B, T, hd/2)
 
-    def write(stack, new, layer):
-        return write_rows(stack, new, layer, first, start_pos)
-
-    def read(stack, layer):
-        return read_rows(stack, layer, first, B, rows)
-
-    def body(carry, layer):
-        x, k_all, v_all, i = carry
-        with jax.named_scope("attn"):
-            h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
-            k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
-            v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            with jax.named_scope("kv_write"):
-                k_all = write(k_all, k, i)
-                v_all = write(v_all, v, i)
-            with jax.named_scope("kv_slice"):
-                k_c, v_c = read(k_all, i), read(v_all, i)
-                if T >= 128:
-                    # From a chunk of 128 rows (a register's lanes) the
-                    # TPU compiler gives QK^T its K with the rows minor.
-                    # Read straight off the carry, that layout goes to
-                    # the whole stack, and the scan is bracketed by two
-                    # transposing copies of the shard. Behind the
-                    # barrier only these sequences' rows of this layer
-                    # are laid out anew. Under 128 rows there are no
-                    # such copies and the barrier would only add one.
-                    # (tests/aot_compile_check.py compiles both sides.)
-                    k_c = jax.lax.optimization_barrier(k_c)
+    def step(x, stacks, layer, i):
+        def mixer(q, k, v, c):
+            nonlocal stacks     # the stacks with this layer's new rows
+            k_c, v_c, stacks = write_and_read(
+                stacks, k, v, i, call, call.window)
             with jax.named_scope("attn_cached"):
-                attn = _attention_cached(q, k_c, v_c, pos, c)
-            x = x + jnp.einsum(
-                "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
-        return (mlp_sublayer(c, x, layer), k_all, v_all, i + 1), None
+                return _attention_cached(q, k_c, v_c, call.pos, c)
 
-    # ops scoped "layers" and nothing deeper are the scan's own: a layer's
-    # weights sliced out of the stack
-    with jax.named_scope("layers"):
-        (x, new_k, new_v, _), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"], jnp.int32(0)), params["blocks"]
-        )
-    with jax.named_scope("head"):
-        if logits_at is not None:
-            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
-        return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+        x = attention_sublayer(c, x, layer, cos, sin, mixer)
+        return mlp_sublayer(c, x, layer), stacks, None
 
-
-def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
-    """Approx training FLOPs/token: 6*N matmul + attention term."""
-    n = param_count(config) - config.vocab_size * config.dim  # non-embed approx
-    attn = 12 * config.n_layers * config.dim * seq_len  # 2*2*3 * L * D * S
-    return 6.0 * n + attn
+    x, cache, _ = decoder.scan_layers(step, x, cache, params["blocks"])
+    return decoder.head(params, x, c, logits_at), cache

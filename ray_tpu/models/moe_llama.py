@@ -13,7 +13,6 @@ all-to-all — the §2.5 EP strategy as a real model.
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, Dict
 
@@ -27,14 +26,14 @@ from .llama import (
     LlamaConfig,
     attention_sublayer,
     attn_param_count,
-    init_attn_params,
-    make_dense_init,
+    init_routed_params,
     masked_ce,
     remat_policy,
     rms_norm,
     rope_table,
     unpack_batch,
 )
+from .llama import param_specs as dense_param_specs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,47 +73,17 @@ def param_specs(config: MoELlamaConfig) -> Dict[str, Any]:
     Expert matrices are (L, E, D, F): E shards over `expert` (EP), and
     the per-expert matrices additionally shard fsdp/model exactly like
     the dense FFN — EP composes with TP/FSDP."""
-    return {
-        "embed": P("model", "fsdp"),
-        "blocks": {
-            "attn_norm": P(None, None),
-            "wq": P(None, "fsdp", "model", None),
-            "wk": P(None, "fsdp", "model", None),
-            "wv": P(None, "fsdp", "model", None),
-            "wo": P(None, "model", None, "fsdp"),
-            "mlp_norm": P(None, None),
-            "router": P(None, "fsdp", None),            # (L, D, E)
-            "w_gate": P(None, "expert", "fsdp", "model"),  # (L, E, D, F)
-            "w_up": P(None, "expert", "fsdp", "model"),
-            "w_down": P(None, "expert", "model", "fsdp"),  # (L, E, F, D)
-        },
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "model"),
-    }
+    specs = dense_param_specs(config)
+    specs["blocks"].update(
+        router=P(None, "fsdp", None),                   # (L, D, E)
+        w_gate=P(None, "expert", "fsdp", "model"),      # (L, E, D, F)
+        w_up=P(None, "expert", "fsdp", "model"),
+        w_down=P(None, "expert", "model", "fsdp"))      # (L, E, F, D)
+    return specs
 
 
 def init_params(rng: jax.Array, config: MoELlamaConfig) -> Dict[str, Any]:
-    c = config
-    keys = jax.random.split(rng, 10)
-    (k_embed, k_q, k_k, k_v, k_o, k_r, k_g, k_u, k_d, k_lm) = keys
-    dense = make_dense_init(c)
-    L, E = c.n_layers, c.n_experts
-    return {
-        "embed": dense(k_embed, (c.vocab_size, c.dim), c.dim),
-        "blocks": {
-            **init_attn_params(c, (k_q, k_k, k_v, k_o), dense),
-            # router stays float32: tiny, and routing is precision-
-            # sensitive (standard MoE practice)
-            "router": (
-                jax.random.normal(k_r, (L, c.dim, E)) / math.sqrt(c.dim)
-            ).astype(jnp.float32),
-            "w_gate": dense(k_g, (L, E, c.dim, c.ffn_dim), c.dim),
-            "w_up": dense(k_u, (L, E, c.dim, c.ffn_dim), c.dim),
-            "w_down": dense(k_d, (L, E, c.ffn_dim, c.dim), c.ffn_dim),
-        },
-        "final_norm": jnp.ones((c.dim,), c.param_dtype),
-        "lm_head": dense(k_lm, (c.dim, c.vocab_size), c.dim),
-    }
+    return init_routed_params(rng, config, config.ffn_dim)
 
 
 def param_count(config: MoELlamaConfig) -> int:

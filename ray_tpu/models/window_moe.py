@@ -8,11 +8,11 @@ is given). Served through ``llm/_internal/engine.py`` as the llama family
 is; not trained (``ops/moe.py`` has no backward pass of the dropless
 layer).
 
-The shared pieces come from ``models/llama.py``: ``rms_norm``,
-``apply_rope``, the projections' layout, ``_attention_cached`` (a full
-layer is the llama family's cached attention at a head size of its own,
-a sliding layer the same einsums under another mask), the cache's row
-writes and reads.
+Written on ``models/decoder.py`` (the call's rows, embedding and head,
+the layer scan, the counters' words) and on ``models/llama.py``'s
+attention sublayer, whose mixer here writes and reads this family's
+cache: a full layer is the llama family's cached attention at a head
+size of its own, a sliding layer the same einsums under another mask.
 
 **The cache is not one pair of stacks.** A full layer keeps rows by
 position, ``(Lf, B, KVH, max_seq, hd)`` as the llama family does. A
@@ -25,10 +25,10 @@ slot held before a sequence began is ever read, and rows a padded chunk
 wrote behind its real tokens fall outside every live query's window
 until the sequence overwrites them (that is what the ring's extra chunk
 of rows is for). One slot more, ``ring`` itself, is the scratch row of
-the engine's idle decode lanes (their position is ``max_seq - 1``, which
-no live sequence writes): position mod ring would land in a live row of
-a lane that is mid-prefill. Beside the rows rides ``counts``, the
-``moe_*`` counters of ``EngineStats`` accumulated on the device.
+idle decode lanes (the idle position is no live sequence's): position
+mod ring would land in a live row of a lane that is mid-prefill. Beside
+the rows rides ``counts``, the ``moe_*`` counters of ``EngineStats``
+accumulated on the device.
 
 **What the engine's chunk rule is told.** The experts' three matrices
 hold most of a layer's bytes (95 % at 64 experts of 3 x 2304 x 896
@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -53,16 +54,17 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import moe
 
+from . import decoder
+from .decoder import rms_norm
 from .llama import (
     LlamaConfig,
     _attention_cached,
-    apply_rope,
-    init_attn_params,
-    make_dense_init,
-    read_rows,
-    rms_norm,
+    attention_sublayer,
+    init_routed_params,
+    write_and_read,
     write_rows,
 )
+from .llama import param_specs as dense_param_specs
 
 SLIDING, FULL = "sliding", "full"
 
@@ -194,61 +196,19 @@ def param_specs(config: WindowMoEConfig) -> Dict[str, Any]:
     """The llama attention shardings; the experts and the routers whole
     on every device, which is how ``moe.moe_ffn_dropless`` takes them
     under a mesh (its rows are split, its weights are not)."""
+    specs = dense_param_specs(config)
     whole = P(None, None, None, None)
-    return {
-        "embed": P("model", "fsdp"),
-        "blocks": {
-            "attn_norm": P(None, None),
-            "wq": P(None, "fsdp", "model", None),
-            "wk": P(None, "fsdp", "model", None),
-            "wv": P(None, "fsdp", "model", None),
-            "wo": P(None, "model", None, "fsdp"),
-            "mlp_norm": P(None, None),
-            "router": P(None, None, None),
-            "w_gate": whole, "w_up": whole, "w_down": whole,
-        },
-        "final_norm": P(None),
-        "lm_head": P("fsdp", "model"),
-    }
+    specs["blocks"].update(router=P(None, None, None), w_gate=whole,
+                           w_up=whole, w_down=whole)
+    return specs
 
 
 def init_params(rng: jax.Array, config: WindowMoEConfig) -> Dict[str, Any]:
-    """Stacked-layer parameters in ``param_dtype``; the router stays
-    float32, as ``moe_llama.init_params`` keeps it."""
-    c = config
-    (k_embed, k_q, k_k, k_v, k_o, k_r, k_g, k_u, k_d,
-     k_lm) = jax.random.split(rng, 10)
-    dense = make_dense_init(c)
-    L, E, D, F = c.n_layers, c.n_experts, c.dim, c.expert_dim
-    router = jax.random.normal(k_r, (L, D, E), jnp.float32) / math.sqrt(D)
-    return {
-        "embed": dense(k_embed, (c.vocab_size, D), D),
-        "blocks": {
-            **init_attn_params(c, (k_q, k_k, k_v, k_o), dense),
-            "router": router,
-            "w_gate": dense(k_g, (L, E, D, F), D),
-            "w_up": dense(k_u, (L, E, D, F), D),
-            "w_down": dense(k_d, (L, E, F, D), F),
-        },
-        "final_norm": jnp.ones((D,), c.param_dtype),
-        "lm_head": dense(k_lm, (D, c.vocab_size), D),
-    }
+    """Stacked-layer parameters in ``param_dtype``, the router float32."""
+    return init_routed_params(rng, config, config.expert_dim)
 
 
 # -- the sublayers -----------------------------------------------------
-def _qkv(c: WindowMoEConfig, x, layer, kind: str, pos):
-    """The pre-norm projections, turned by the layer's own table."""
-    h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
-    cos, sin = rope_cos_sin(c, kind, pos)
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
-
-
-EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
-
-
 def moe_sublayer(c: WindowMoEConfig, x, layer, experts, index, live=None):
     """Pre-norm routed feed-forward + residual -> (x, counts int32[3]).
     ``layer``: this layer's norm and router; ``experts``: every layer's
@@ -264,14 +224,44 @@ def moe_sublayer(c: WindowMoEConfig, x, layer, experts, index, live=None):
         return x + out, counts
 
 
-def _layers_by_period(c: WindowMoEConfig, blocks):
-    """(the stacked blocks (L, ...) but the experts' as (periods, layers
-    a period, ...), for the scan over periods; the experts' weights,
-    stacked as they are)."""
-    n = len(c.period)
+def scan_periods(c: WindowMoEConfig, blocks, x, pos, attend, state=None,
+                 live=None):
+    """The layers over ``x`` (B, T, D) at the positions ``pos`` (B, T):
+    ``decoder.scan_layers`` over whole periods, a period's layers
+    unrolled inside it (the stacked ``blocks`` as (periods, layers a
+    period, ...), but the experts' weights, which stay stacked as they
+    are). ``attend(kind, i, state, q, k, v) -> (the attended rows,
+    state)`` is the mixer of layer ``i`` among the layers of its
+    ``kind``; ``live`` the rows ``moe_sublayer`` counts ->
+    (x, state, counts int32[3])."""
+    period = c.period
+    n = len(period)
+    per = {kind: period.count(kind) for kind in (FULL, SLIDING)}
+    scanned, experts = moe.split_experts(blocks)
     scanned = {k: a.reshape(c.n_layers // n, n, *a.shape[1:])
-               for k, a in blocks.items() if k not in EXPERT_WEIGHTS}
-    return scanned, {k: blocks[k] for k in EXPERT_WEIGHTS}
+               for k, a in scanned.items()}
+
+    def step(x, state, layers, p):
+        seen = {FULL: 0, SLIDING: 0}
+        counted = []
+        for j, kind in enumerate(period):
+            i = p * per[kind] + seen[kind]
+            seen[kind] += 1
+
+            def mixer(q, k, v, _):
+                nonlocal state
+                attn, state = attend(kind, i, state, q, k, v)
+                return attn
+
+            layer = jax.tree_util.tree_map(lambda a: a[j], layers)
+            with jax.named_scope("attn"):   # the table is the sublayer's
+                cos, sin = rope_cos_sin(c, kind, pos)
+            x = attention_sublayer(c, x, layer, cos, sin, mixer)
+            x, counts = moe_sublayer(c, x, layer, experts, p * n + j, live)
+            counted.append(counts)
+        return x, state, sum(counted[1:], counted[0])
+
+    return decoder.scan_layers(step, x, state, scanned, len(COUNTERS))
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array,
@@ -280,63 +270,38 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
     XLA attention under each layer's own mask, no cache."""
     c = config
     B, S = tokens.shape
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(c.dtype)[tokens]
     pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     behind = pos[:, :, None] - jnp.arange(S)[None, None, :]   # i - j
     masks = {FULL: behind >= 0,
              SLIDING: (behind >= 0) & (behind < c.sliding_window)}
 
-    scanned, experts = _layers_by_period(c, params["blocks"])
+    def attend(kind, i, state, q, k, v):
+        return _attention_cached(
+            q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pos, c,
+            mask=masks[kind]), state
 
-    def body(carry, period):
-        x, p = carry
-        for j, kind in enumerate(c.period):
-            layer = jax.tree_util.tree_map(lambda a: a[j], period)
-            with jax.named_scope("attn"):
-                q, k, v = _qkv(c, x, layer, kind, pos)
-                attn = _attention_cached(
-                    q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pos,
-                    c, mask=masks[kind])
-                x = x + jnp.einsum(
-                    "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
-            x, _ = moe_sublayer(c, x, layer, experts,
-                                p * len(c.period) + j)
-        return (x, p + 1), None
-
-    with jax.named_scope("layers"):
-        (x, _), _ = jax.lax.scan(body, (x, jnp.int32(0)), scanned)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
-        return logits.astype(jnp.float32)
+    x, _, _ = scan_periods(c, params["blocks"],
+                           decoder.embed(params, tokens, c), pos, attend)
+    return decoder.head(params, x, c)
 
 
 # -- the cache ---------------------------------------------------------
-# the counters' low words carry into the high ones from here
-_CARRY_BITS = 30
+COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots")
 # slots a ring has past its rows: the first is the idle lanes' scratch
 # row, the rest keep the rows a multiple of 8
 _SCRATCH_SLOTS = 8
-
-
-def ring_rows(config: WindowMoEConfig, chunk: int) -> int:
-    """Slots of a sliding layer's ring for calls of ``chunk`` rows at
-    the most: the window and a chunk, to a multiple of 8."""
-    return -(-(config.sliding_window + chunk) // 8) * 8
 
 
 def init_cache(config: WindowMoEConfig, batch: int, max_seq: int,
                chunk: int):
     """``full``: k/v (full layers, B, KVH, max_seq, hd) by position;
     ``ring``: k/v (sliding layers, B, KVH, ring + _SCRATCH_SLOTS, hd),
-    slot ``ring`` the idle lanes' scratch row; ``counts``: int32 (3, 2),
-    the high and low words of moe_assignments, moe_experts_touched,
-    moe_expert_slots. ``chunk``: the most rows a call will write."""
+    slot ``ring`` the idle lanes' scratch row; ``counts``: the device
+    words of ``COUNTERS``. ``chunk``: the most rows a call will write."""
     c = config
     n_full = c.layer_types.count(FULL)
-    ring = min(ring_rows(c, chunk), -(-max_seq // 8) * 8)
+    # the window and a chunk, to a multiple of 8; no more than the cache
+    ring = -(-min(c.sliding_window + chunk, max_seq) // 8) * 8
 
     def stacks(layers, rows):
         shape = (layers, batch, c.n_kv_heads, rows, c.head_dim)
@@ -344,7 +309,7 @@ def init_cache(config: WindowMoEConfig, batch: int, max_seq: int,
 
     return {"full": stacks(n_full, max_seq),
             "ring": stacks(c.n_layers - n_full, ring + _SCRATCH_SLOTS),
-            "counts": jnp.zeros((3, 2), jnp.int32)}
+            "counts": decoder.counter_words(len(COUNTERS))}
 
 
 def attn_rows_read(config: WindowMoEConfig, cache, rows: int) -> float:
@@ -357,20 +322,15 @@ def attn_rows_read(config: WindowMoEConfig, cache, rows: int) -> float:
     return (n_full * rows + n_ring * ring) / config.n_layers
 
 
-def read_counters(cache) -> Dict[str, int]:
-    """The ``moe_*`` counters one cache shard has accumulated (waits for
-    the program that last wrote it)."""
-    hi_lo = np.asarray(cache["counts"]).astype(np.int64)
-    totals = (hi_lo[:, 0] << _CARRY_BITS) + hi_lo[:, 1]
-    return dict(zip(("moe_assignments", "moe_experts_touched",
-                     "moe_expert_slots"), (int(t) for t in totals)))
+# the ``moe_*`` counters one cache shard has accumulated
+read_counters = partial(decoder.read_counters, names=COUNTERS)
 
 
-def _ring_write(stack, new, layer, first, start_pos, ring: int, max_seq: int):
+def _ring_write(stack, new, layer, first, start_pos, ring: int, idle: int):
     """``new`` (B, T, KVH, hd) into the ring stack at layer ``layer``:
     sequence b's row t to slot (start_pos[b] + t) mod ring of cache row
     ``first + b``. One row (a decode) is one update, an idle lane's
-    (position max_seq - 1) to the scratch slot. T rows may wrap, so they
+    (position ``idle``) to the scratch slot. T rows may wrap, so they
     go in as two blocks of T slots, each read, merged and written back:
     the block that ends at the ring's end at the latest and the block at
     its start."""
@@ -382,7 +342,7 @@ def _ring_write(stack, new, layer, first, start_pos, ring: int, max_seq: int):
     for b in range(B):
         row, lane, o = new[b], first + b, start_pos[b] % ring
         if T == 1:
-            slot = jnp.where(start_pos[b] == max_seq - 1, ring, o)
+            slot = jnp.where(start_pos[b] == idle, ring, o)
             stack = jax.lax.dynamic_update_slice(
                 stack, row[None, None], (layer, lane, 0, slot, 0))
             continue
@@ -435,87 +395,30 @@ def forward_with_cache(
     and the counters ride in its carry and are updated in place under a
     jit that donates the cache."""
     c = config
-    B, T = tokens.shape
     max_seq = cache["full"]["k"].shape[3]
     slots = cache["ring"]["k"].shape[3]
     ring = slots - _SCRATCH_SLOTS
-    rows = max_seq if rows is None else rows
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(c.dtype)[tokens]
-    pos = start_pos[:, None] + jnp.arange(T)[None, :]            # (B, T)
-    first = 0 if slot is None else slot
-    # whose rows are somebody's tokens, for the moe_* counters: not an
-    # idle decode lane (the engine gives it position max_seq - 1), not
-    # the rows of a padded chunk behind the one its logits are taken at
-    if T == 1:
-        live = start_pos[:, None] != max_seq - 1
-    elif logits_at is not None:
-        live = jnp.arange(T)[None, :] <= logits_at[:, None]
-    else:
-        live = None
-    ring_mask = _ring_mask(pos, start_pos, T, ring, slots, c.sliding_window)
-    period = c.period
-    per = {kind: period.count(kind) for kind in (FULL, SLIDING)}
+    call = decoder.Call(tokens, start_pos, max_seq, slot=slot,
+                        logits_at=logits_at, rows=rows)
+    ring_mask = _ring_mask(call.pos, start_pos, call.T, ring, slots,
+                           c.sliding_window)
+    ring_write = partial(_ring_write, ring=ring,
+                         idle=decoder.idle_position(max_seq))
 
-    def body(carry, layers):
-        x, stacks, counts, p = carry
-        seen = {FULL: 0, SLIDING: 0}
-        for j, kind in enumerate(period):
-            layer = jax.tree_util.tree_map(lambda a: a[j], layers)
-            i = p * per[kind] + seen[kind]      # this layer among its kind
-            seen[kind] += 1
-            k_all, v_all = stacks[kind]
-            with jax.named_scope("attn"):
-                q, k, v = _qkv(c, x, layer, kind, pos)
-                with jax.named_scope("kv_write"):
-                    if kind == FULL:
-                        k_all = write_rows(k_all, k, i, first, start_pos)
-                        v_all = write_rows(v_all, v, i, first, start_pos)
-                    else:
-                        k_all = _ring_write(k_all, k, i, first, start_pos,
-                                            ring, max_seq)
-                        v_all = _ring_write(v_all, v, i, first, start_pos,
-                                            ring, max_seq)
-                with jax.named_scope("kv_slice"):
-                    n = rows if kind == FULL else slots
-                    k_c = read_rows(k_all, i, first, B, n)
-                    v_c = read_rows(v_all, i, first, B, n)
-                    if T >= 128:    # llama.forward_with_cache says why
-                        k_c = jax.lax.optimization_barrier(k_c)
-                if kind == FULL:
-                    with jax.named_scope("attn_cached"):
-                        attn = _attention_cached(q, k_c, v_c, pos, c)
-                else:
-                    with jax.named_scope("attn_window"):
-                        attn = _attention_cached(q, k_c, v_c, pos, c,
-                                                 mask=ring_mask)
-                x = x + jnp.einsum(
-                    "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
-            stacks = {**stacks, kind: (k_all, v_all)}
-            x, counted = moe_sublayer(c, x, layer, experts,
-                                      p * len(period) + j, live)
-            counts = counts + counted
-        return (x, stacks, counts, p + 1), None
+    # a kind's rows read, its writer, its attention's scope and mask
+    reads = {FULL: (call.window, write_rows, "attn_cached", None),
+             SLIDING: (slots, ring_write, "attn_window", ring_mask)}
 
-    stacks = {kind: (cache[name]["k"], cache[name]["v"])
-              for kind, name in ((FULL, "full"), (SLIDING, "ring"))}
-    scanned, experts = _layers_by_period(c, params["blocks"])
-    with jax.named_scope("layers"):
-        (x, stacks, counted, _), _ = jax.lax.scan(
-            body, (x, stacks, jnp.zeros(3, jnp.int32), jnp.int32(0)),
-            scanned)
-        low = cache["counts"][:, 1] + counted
-        counts = jnp.stack(
-            [cache["counts"][:, 0] + (low >> _CARRY_BITS),
-             low & ((1 << _CARRY_BITS) - 1)], axis=1)
-    with jax.named_scope("head"):
-        if logits_at is not None:
-            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x, params["lm_head"].astype(c.dtype))
-    new_cache = {
-        "full": dict(zip(("k", "v"), stacks[FULL])),
-        "ring": dict(zip(("k", "v"), stacks[SLIDING])),
-        "counts": counts}
-    return logits.astype(jnp.float32), new_cache
+    def attend(kind, i, stacks, q, k, v):
+        n, write, scope, mask = reads[kind]
+        k_c, v_c, new = write_and_read(stacks[kind], k, v, i, call, n, write)
+        with jax.named_scope(scope):
+            attn = _attention_cached(q, k_c, v_c, call.pos, c, mask=mask)
+        return attn, {**stacks, kind: new}
+
+    x, stacks, counted = scan_periods(
+        c, params["blocks"], decoder.embed(params, tokens, c), call.pos,
+        attend, {FULL: cache["full"], SLIDING: cache["ring"]}, call.live())
+    new_cache = {"full": stacks[FULL], "ring": stacks[SLIDING],
+                 "counts": decoder.fold_counts(cache["counts"], counted)}
+    return decoder.head(params, x, c, logits_at), new_cache

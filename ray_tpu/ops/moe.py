@@ -213,6 +213,16 @@ def route_top_k(x: jax.Array, router: jax.Array, config: MoEConfig):
     return weights, experts.astype(jnp.int32)
 
 
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def split_experts(stack: dict):
+    """A stack of routed layers' parameters -> (the leaves a layer scan
+    slices, the experts' weights, which ``expert_ffn`` takes whole)."""
+    return ({k: a for k, a in stack.items() if k not in EXPERT_WEIGHTS},
+            {k: stack[k] for k in EXPERT_WEIGHTS})
+
+
 def expert_ffn(xs, w_gate, w_up, w_down, group_sizes, layer=None):
     """The grouped SwiGLU: rows ``xs`` (N, D) sorted by expert,
     ``group_sizes`` (E,) rows each; (E, D, F) / (E, F, D) weights ->

@@ -8,7 +8,7 @@ numerical reference in tests/test_pallas_attention.py.
 
 Reference parity note: the reference (Ray) has no attention kernels at
 all (SURVEY.md §5.7 — delegated to vLLM/torch); this is TPU-native
-net-new capability, required to hit the BASELINE.md MFU bar.
+net-new capability.
 
 Layout contract (matches ray_tpu.models):
     q (B, S, H, hd); k/v (B, T, KVH, hd), H = G * KVH.

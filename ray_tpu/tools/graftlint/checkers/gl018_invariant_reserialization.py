@@ -6,7 +6,7 @@ options re-encoded per ``.remote()`` call, a template dict re-dumped
 per task before ``send_bytes`` — when one encode hoisted above the
 loop (or one cached opcode prefix, ``serialization.submit_frame_prefix``)
 serves every iteration. At 10k calls/s the redundant encode is the
-dominant client-side cost (bench_core ``submit_path_overhead``).
+dominant client-side cost.
 
 The checker flags a ``dumps``-family call (``dumps`` /
 ``dumps_frame`` / ``dumps_inline`` / ``dumps_function`` — covering

@@ -12,7 +12,10 @@ a program that does not fit the chip's memory. It cannot say that a
 program runs, is right or is fast.
 
 Prints one line per program and exits non-zero if any failed to
-compile; exits 77 when this libtpu cannot describe the topology. Words
+compile; exits 77 when this libtpu cannot describe the topology. Each
+line ends with the program's fingerprint (``fingerprint``): two trees
+whose lines agree compile to the same instructions under the same
+scopes, whatever their Python looks like. Words
 on the command line keep to the programs whose line holds one of them
 (``python tests/aot_compile_check.py latent``).
 tests/test_chip_path.py runs it in a subprocess with ``--quick``: the
@@ -23,6 +26,7 @@ batch, because each compile costs about a minute of CPU time.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import os
 import re
 import sys
@@ -34,6 +38,37 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+
+
+# an instruction of an optimized module's text: its result type with
+# layout, its opcode, and the scope path jax gave the operation
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%\S+ = (.+?) ([a-z][a-z0-9-]*)\((?:.*?op_name=\"([^\"]*)\")?")
+
+
+def signatures(text: str):
+    """The optimized module ``text`` as a sorted list of computations,
+    each the sorted list of its instructions' (opcode, result type with
+    layout, ``op_name``): no instruction's or computation's name, no
+    order inside a computation, no operand, no source location."""
+    computations, current = [], None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            current = []
+        elif line == "}" and current is not None:
+            computations.append(sorted(current))
+            current = None
+        elif current is not None:
+            found = _INSTRUCTION.match(line)
+            if found:
+                shape, opcode, op_name = found.groups()
+                current.append((opcode, shape, op_name or ""))
+    return sorted(computations)
+
+
+def fingerprint(text: str) -> str:
+    """Twelve hex digits over ``signatures(text)``."""
+    return hashlib.sha256(repr(signatures(text)).encode()).hexdigest()[:12]
 
 
 def main() -> int:
@@ -85,6 +120,7 @@ def main() -> int:
             + (f", kernels missing from the program: {missing}" if missing else "")
             + (f", instructions that must not be there: {unwanted}"
                if unwanted else "")
+            + f", fingerprint {fingerprint(text)}"
         )
 
     quick = "--quick" in sys.argv[1:]
@@ -165,33 +201,10 @@ def main() -> int:
     abstract = partial(jax.tree.map, lambda a: sds(a.shape, a.dtype))
     cache = abstract(jax.eval_shape(
         partial(llama.init_kv_cache, serve_cfg, 8, 2048)))
-
     params = abstract(jax.eval_shape(
         partial(llama.init_params, config=serve_cfg), jax.random.PRNGKey(0)))
-
-    def prefill_chunk(rows, window=None):
-        """What the engine's prefill program does with a chunk of one
-        sequence: its rows into one slot of a donated cache shard,
-        attention over the first ``window`` rows of the slot."""
-        def chunk(params, cache, tokens, start, slot, at):
-            return llama.forward_with_cache(
-                params, tokens, cache, start, serve_cfg, slot=slot,
-                logits_at=at, rows=window)
-
-        return jax.jit(chunk, donate_argnums=(1,)).lower(
-            params, cache, sds((1, rows), jnp.int32), sds((1,), jnp.int32),
-            sds((), jnp.int32), sds((1,), jnp.int32))
-
-    def decode_step(window):
-        """The engine's decode program without its sampling: one row a
-        lane, every lane of the shard."""
-        def step(params, cache, tokens, lengths):
-            return llama.forward_with_cache(
-                params, tokens[:, None], cache, lengths, serve_cfg,
-                rows=window)
-
-        return jax.jit(step, donate_argnums=(1,)).lower(
-            params, cache, sds((8,), jnp.int32), sds((8,), jnp.int32))
+    prefill_chunk = partial(lower_chunk, sds, llama, serve_cfg, params, cache)
+    decode_step = partial(lower_decode, sds, llama, serve_cfg, params, cache)
 
     # the cache is updated in place: no instruction copies a whole leaf
     # of the shard (layout assignment once bracketed the layer scan with
@@ -201,8 +214,7 @@ def main() -> int:
     # engine's read windows. Then the other window at 8 x 2048, the
     # first 1024 rows, where the layer reads fewer rows than the carried
     # stack holds, for the whole chunk, and the decode step at both
-    shard = ",".join(str(d) for d in cache["k"].shape)
-    no_shard_copy = rf"\[{shard}\]\S* copy\("
+    no_shard_copy = no_copy_of(cache["k"])
     for rows in (64, 128, 256):
         check(f"prefill chunk of {rows} rows into one slot of 8 x 2048, "
               "one device", partial(prefill_chunk, rows), forbid=no_shard_copy)
@@ -210,11 +222,12 @@ def main() -> int:
           partial(prefill_chunk, 256, 1024), forbid=no_shard_copy)
     for window in (1024, 2048):
         check(f"decode step of 8 lanes reading {window} of 8 x 2048, "
-              "one device", partial(decode_step, window),
+              "one device", partial(decode_step, 8, window),
               forbid=no_shard_copy)
 
     if not quick:
         latent_chunks(check, sds)
+        window_pair(check, sds)
 
     for n in (1, 4):
         check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "
@@ -223,55 +236,112 @@ def main() -> int:
     return 1 if failures else 0
 
 
-def latent_chunks(check, sds):
-    """The chunk programs of ``openpangu-ultra-moe-718b.serve-longdoc``
-    at its published widths and the cell's 16 x 16 384 cache: the three
-    buckets of the chunk a v5e's engine derives (1024 rows) reading the
-    whole slot, and the whole chunk at the other read window. None may
-    copy a leaf of the shard (as one 576-wide leaf the cache was
-    bracketed by two transposing copies of 3 GB, PERF.md section 6,
-    PR 48), each holds the prefill form's kernel
-    (``ops/pallas_latent_attention.py``), and the line says what
-    temporaries a call of so many rows takes beside the 8.3 GB of
-    weights and cache."""
+def lower_chunk(sds, model, cfg, params, cache, rows, window=None):
+    """What the engine's prefill program does with a chunk of one
+    sequence, lowered: its ``rows`` rows into one slot of a donated cache
+    shard, attention over the first ``window`` rows of the slot."""
+    def chunk(params, cache, tokens, start, slot, at):
+        return model.forward_with_cache(
+            params, tokens, cache, start, cfg, slot=slot, logits_at=at,
+            rows=window)
+
+    return jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, sds((1, rows), jnp.int32), sds((1,), jnp.int32),
+        sds((), jnp.int32), sds((1,), jnp.int32))
+
+
+def lower_decode(sds, model, cfg, params, cache, lanes, window):
+    """The engine's decode program without its sampling, lowered: one
+    row a lane, every lane of the shard."""
+    def step(params, cache, tokens, lengths):
+        return model.forward_with_cache(
+            params, tokens[:, None], cache, lengths, cfg, rows=window)
+
+    return jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((lanes,), jnp.int32), sds((lanes,), jnp.int32))
+
+
+def no_copy_of(*leaves) -> str:
+    """The pattern of an instruction that copies the whole of one of
+    the cache's ``leaves``."""
+    shapes = "|".join(",".join(str(d) for d in a.shape) for a in leaves)
+    return rf"\[(?:{shapes})\]\S* copy\("
+
+
+def serving_cell(sds, name: str, model):
+    """-> (configuration, parameters, cache, lanes, max_seq, chunk) of
+    the serving cell ``name`` as a v5e's engine would hold them, the
+    arrays as shapes: its published widths, its cell's lanes and cache
+    length, the chunk the engine derives."""
     from benchmarks import spec
     from ray_tpu.llm._internal.engine import derived_prefill_chunk
-    from ray_tpu.models import latent_moe
 
-    cell = spec.load_cell("openpangu-ultra-moe-718b.serve-longdoc", False)
+    cell = spec.load_cell(name, False)
     cfg = spec.family_of(cell["hp"]).model_config(cell["hp"])
     lanes, max_seq = (cell["serve"][k] for k in ("max_batch_size",
                                                  "max_seq_len"))
+    chunk = derived_prefill_chunk(
+        "TPU v5 lite", 2, max_seq, **model.chunk_terms(cfg, max_seq))
     abstract = partial(jax.tree.map, lambda a: sds(a.shape, a.dtype))
     params = abstract(jax.eval_shape(
-        partial(latent_moe.init_params, config=cfg), jax.random.PRNGKey(0)))
+        partial(model.init_params, config=cfg), jax.random.PRNGKey(0)))
     cache = abstract(jax.eval_shape(
-        partial(latent_moe.init_cache, cfg, lanes, max_seq)))
-    chunk = derived_prefill_chunk(
-        "TPU v5 lite", 2, max_seq, **latent_moe.chunk_terms(cfg, max_seq))
+        partial(model.init_cache, cfg, lanes, max_seq, chunk)))
+    return cfg, params, cache, lanes, max_seq, chunk
 
-    def prefill_chunk(rows, window):
-        def call(params, cache, tokens, start, slot, at):
-            return latent_moe.forward_with_cache(
-                params, tokens, cache, start, cfg, slot=slot, logits_at=at,
-                rows=window)
 
-        return jax.jit(call, donate_argnums=(1,)).lower(
-            params, cache, sds((1, rows), jnp.int32), sds((1,), jnp.int32),
-            sds((), jnp.int32), sds((1,), jnp.int32))
+def latent_chunks(check, sds):
+    """The programs of ``openpangu-ultra-moe-718b.serve-longdoc`` at its
+    published widths and the cell's 16 x 16 384 cache: the three buckets
+    of the chunk a v5e's engine derives (1024 rows) reading the whole
+    slot, the whole chunk at the other read window, and the decode step
+    at both. None may copy a leaf of the shard (as one 576-wide leaf the
+    cache was bracketed by two transposing copies of 3 GB, PERF.md
+    section 6, PR 48), each chunk holds the prefill form's kernel
+    (``ops/pallas_latent_attention.py``), and the line says what
+    temporaries a call of so many rows takes beside the 8.3 GB of
+    weights and cache."""
+    from ray_tpu.models import latent_moe
 
-    leaves = "|".join(",".join(str(d) for d in cache[k].shape)
-                      for k in ("latent", "rope_key"))
+    cfg, params, cache, lanes, max_seq, chunk = serving_cell(
+        sds, "openpangu-ultra-moe-718b.serve-longdoc", latent_moe)
+    no_leaf_copy = no_copy_of(cache["latent"], cache["rope_key"])
     # nor hold a float32 score of all the heads (the block loop's was
     # heads x tile x block; the kernel's is a head's, in VMEM)
-    forbidden = (rf"\[(?:{leaves})\]\S* copy\("
-                 rf"|f32\[{cfg.n_heads},\d+,\d+\]")
+    forbidden = rf"{no_leaf_copy}|f32\[{cfg.n_heads},\d+,\d+\]"
     for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
                          (chunk, max_seq), (chunk, max_seq // 2)):
         check(f"latent prefill chunk of {rows} rows reading {window} of "
               f"{lanes} x {max_seq}, published widths, one device",
-              partial(prefill_chunk, rows, window),
+              partial(lower_chunk, sds, latent_moe, cfg, params, cache, rows,
+                      window),
               expect=("latent_attention_prefill",), forbid=forbidden)
+    for window in (max_seq // 2, max_seq):
+        check(f"latent decode step of {lanes} lanes reading {window} of "
+              f"{lanes} x {max_seq}, published widths, one device",
+              partial(lower_decode, sds, latent_moe, cfg, params, cache,
+                      lanes, window), forbid=no_leaf_copy)
+
+
+def window_pair(check, sds):
+    """The whole chunk and the decode step of
+    ``mellum2-12b-a2.5b.serve-ide-mix`` at its published widths, its two
+    periods of layers (one would make no scan, and nothing to bracket)
+    and its 16 x 8192 cache: neither may copy a whole stack of the full
+    layers' rows or of the rings."""
+    from ray_tpu.models import window_moe
+
+    cfg, params, cache, lanes, max_seq, chunk = serving_cell(
+        sds, "mellum2-12b-a2.5b.serve-ide-mix", window_moe)
+    no_stack_copy = no_copy_of(cache["full"]["k"], cache["ring"]["k"])
+    check(f"window_moe prefill chunk of {chunk} rows reading {max_seq} of "
+          f"{lanes} x {max_seq}, published widths, one device",
+          partial(lower_chunk, sds, window_moe, cfg, params, cache, chunk,
+                  max_seq), forbid=no_stack_copy)
+    check(f"window_moe decode step of {lanes} lanes reading {max_seq} of "
+          f"{lanes} x {max_seq}, published widths, one device",
+          partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
+                  max_seq), forbid=no_stack_copy)
 
 
 if __name__ == "__main__":

@@ -325,7 +325,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(seed, shares, path):
     for i, held in enumerate(shares):
         ids = jnp.asarray(held)
         part = {"router": params["router"],
-                **{k: params[k][ids] for k in lm.EXPERT_WEIGHTS}}
+                **{k: params[k][ids] for k in moe.EXPERT_WEIGHTS}}
         out, counts = moe.moe_ffn_dropless(
             part, x, dataclasses.replace(SHARE, held=held))
         routed = routed + out
@@ -337,7 +337,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(seed, shares, path):
             once = with_shared - out
     assert assignments == 24 * 4
     layer = {"router": params["router"], **{
-        k: params[k] for k in lm.EXPERT_WEIGHTS + lm.SHARED_WEIGHTS}}
+        k: params[k] for k in moe.EXPERT_WEIGHTS + lm.SHARED_WEIGHTS}}
     with jax.default_matmul_precision("highest"):
         want = pangu_reference.routed_part(
             x, layer, held=tuple(range(16)), top_k=4, norm_topk=True,
@@ -512,11 +512,11 @@ def test_the_cells_configuration_is_the_published_one_cut_by_its_share():
     # whose expansion (168 MFLOP a row over 5 layers) is 394 rows' worth
     # of 2 FLOPs a parameter: 240 x 1.87 + 394 = 843 rows on a v5e
     terms = lm.chunk_terms(cfg, 16384)
-    held = sum(shapes["routed"][k].size for k in lm.EXPERT_WEIGHTS)
+    held = sum(shapes["routed"][k].size for k in moe.EXPERT_WEIGHTS)
     every_row = (
         sum(shapes[kind][k].size for kind in ("dense", "routed")
             for k in ("wdq", "wuq", "wdkv", "wuk", "wuv", "wo"))
-        + sum(shapes["dense"][k].size for k in lm.EXPERT_WEIGHTS)
+        + sum(shapes["dense"][k].size for k in moe.EXPERT_WEIGHTS)
         + sum(shapes["routed"][k].size for k in lm.SHARED_WEIGHTS)
         + shapes["lm_head"].size)
     assert terms == {
