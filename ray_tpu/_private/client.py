@@ -1525,15 +1525,25 @@ class CoreClient:
                 else:
                     val = self.decode_value(oid_bytes, kind, payload)
                     out[oid_bytes] = val
-                    with self._obj_cache_lock:
-                        if len(self._obj_cache) >= 4096:
-                            # crude half-eviction keeps the cache bounded
-                            for k in list(self._obj_cache)[:2048]:
-                                del self._obj_cache[k]
-                        self._obj_cache[oid_bytes] = val
+                    self._cache_value(oid_bytes, val)
             if errs:
                 raise errs[0]
         return [out[o.binary()] for o in object_ids]
+
+    def _cache_value(self, oid_bytes: bytes, val: Any) -> None:
+        with self._obj_cache_lock:
+            if len(self._obj_cache) >= 4096:
+                # crude half-eviction keeps the cache bounded
+                for k in list(self._obj_cache)[:2048]:
+                    del self._obj_cache[k]
+            self._obj_cache[oid_bytes] = val
+
+    def hold_inline(self, oid_bytes: bytes, payload: Any) -> None:
+        """An inline value that came with another reply (a streamed item
+        with its STREAM_NEXT): decoded into the cache a ``get`` reads
+        first, so that the ref's ``get`` sends nothing."""
+        self._cache_value(oid_bytes,
+                          self.decode_value(oid_bytes, P.VAL_INLINE, payload))
 
     def wait(
         self,

@@ -319,6 +319,10 @@ class StreamEntry:
     t_walls: List[Optional[float]] = field(default_factory=list)
     ended: bool = False
     consumed: int = 0
+    # the producer waits for credit (STREAM_YIELD's ``bound``, known
+    # with the first item): a consumer is then handed one item a
+    # STREAM_NEXT, so that ``consumed`` stays what it has read
+    bounded: bool = False
     next_waiters: Dict[int, List[Tuple[Any, int]]] = field(default_factory=dict)
     credit_waiters: List[Tuple[int, Any, int]] = field(default_factory=list)
 
@@ -2572,11 +2576,26 @@ class Hub:
         )
         s.oids.append(p["object_id"])
         s.t_walls.append(p.get("t_wall"))
+        s.bounded = bool(p.get("bound"))
         for wconn, req_id in s.next_waiters.pop(idx, []):
             s.consumed = max(s.consumed, idx + 1)
-            self._reply(wconn, req_id, object_id=p["object_id"],
-                        t_wall=p.get("t_wall"))
+            self._reply(wconn, req_id, items=self._stream_items(s, idx, 1))
         self._wake_credit_waiters(s)
+
+    def _stream_items(self, s: StreamEntry, idx: int, limit: int) -> list:
+        """The stream's items from ``idx`` on that are there, ``limit``
+        at the most: (object id, the producer's yield stamp, the value
+        where the object holds it inline, else None). A consumer that
+        has fallen behind is handed what has queued up in one reply, and
+        an inline value needs no GET of its own: a streamed token costs
+        the hub one message, not three round trips."""
+        items = []
+        for j in range(idx, min(len(s.oids), idx + limit)):
+            e = self.objects.get(s.oids[j])
+            inline = (e.payload if e is not None and e.ready
+                      and e.kind == P.VAL_INLINE else None)
+            items.append((s.oids[j], s.t_walls[j], inline))
+        return items
 
     def _on_stream_end(self, conn, p):
         s = self._stream(p["task_id"])
@@ -2597,7 +2616,7 @@ class Hub:
             s.oids.append(err_oid)
             s.t_walls.append(None)
             for wconn, req_id in s.next_waiters.pop(idx, []):
-                self._reply(wconn, req_id, object_id=err_oid)
+                self._reply(wconn, req_id, items=[(err_oid, None, None)])
         s.ended = True
         for idx, waiters in list(s.next_waiters.items()):
             if idx >= len(s.oids):
@@ -2620,9 +2639,10 @@ class Hub:
         s = self._stream(p["task_id"])
         idx = p["index"]
         if idx < len(s.oids):
-            s.consumed = max(s.consumed, idx + 1)
-            self._reply(conn, p["req_id"], object_id=s.oids[idx],
-                        t_wall=s.t_walls[idx])
+            items = self._stream_items(
+                s, idx, 1 if s.bounded else p.get("batch", 1))
+            s.consumed = max(s.consumed, idx + len(items))
+            self._reply(conn, p["req_id"], items=items)
             self._wake_credit_waiters(s)
         elif s.ended:
             self._reply(conn, p["req_id"], end=True)

@@ -122,7 +122,12 @@ STACK_REPLY = "stack_reply"      # process -> hub: {token, threads:
 # streaming generators (reference: _raylet.pyx:280 ObjectRefGenerator)
 STREAM_YIELD = "stream_yield"    # worker -> hub: one yielded value
 STREAM_END = "stream_end"        # worker -> hub: generator exhausted/raised
-STREAM_NEXT = "stream_next"      # client -> hub: resolve the i-th ref
+STREAM_NEXT = "stream_next"      # client -> hub: the refs from the i-th on
+                                 # that are there (``batch`` at the most,
+                                 # one from a producer that waits for
+                                 # credit), each with its yield stamp and,
+                                 # where the object holds one, its inline
+                                 # value: reply ``items``
 STREAM_CREDIT = "stream_credit"  # worker -> hub: backpressure wait
 
 # node agent <-> hub (multi-host: one agent per host, reference analogue

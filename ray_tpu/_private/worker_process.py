@@ -212,17 +212,20 @@ class WorkerRuntime:
         t_wall = _t.wall_at(time.monotonic())
         oid = ObjectID.generate()
         kind, payload, size = self.client.encode_value(oid, value)
-        self.client.send(
-            P.STREAM_YIELD,
-            {
-                "task_id": p["task_id"],
-                "object_id": oid.binary(),
-                "kind": kind,
-                "payload": payload,
-                "size": size,
-                "t_wall": t_wall,
-            },
-        )
+        item = {
+            "task_id": p["task_id"],
+            "object_id": oid.binary(),
+            "kind": kind,
+            "payload": payload,
+            "size": size,
+            "t_wall": t_wall,
+        }
+        if (p.get("options") or {}).get("_generator_backpressure_num_objects"):
+            # a producer that will wait for credit says so with every
+            # item, the first too: the hub hands its consumer one item a
+            # STREAM_NEXT, so that what it counts consumed was read
+            item["bound"] = True
+        self.client.send(P.STREAM_YIELD, item)
 
     def _stream_results(self, p: dict, gen) -> None:
         """Drive a generator task: yield values become incremental stream

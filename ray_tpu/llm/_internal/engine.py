@@ -85,7 +85,13 @@ re-designed for XLA instead of wrapped:
   meeting every weight. Nothing below knows what a cache holds: it is a
   pytree the programs take and return. Beside the signature the engine
   and the programs share one thing, ``decoder.idle_position``: the
-  length a lane that is nobody's is dispatched at.
+  length a lane that is nobody's is dispatched at. A family whose cache
+  holds a state and not rows by position (``models/hybrid_ssm.py``)
+  owes that lane and a new sequence what rows get for free: its mixer
+  leaves the state of a lane at the idle position, and behind a padded
+  chunk's last token, exactly as it was (a lane that is mid-prefill
+  rides every decode call between its chunks), and a call whose
+  ``start`` is 0 begins from a zero state whatever the slot held.
 - KV cache is preallocated per shard (L, B, KVH, max_seq, hd) and
   UPDATED IN PLACE: both programs are jitted with the cache donated,
   the cached forward carries it through its layer scan and writes only

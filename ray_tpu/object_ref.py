@@ -8,6 +8,7 @@ task args, actor calls, and nested data structures.
 
 from __future__ import annotations
 
+import collections
 import threading
 from concurrent.futures import Future
 from typing import Any, Optional
@@ -107,11 +108,29 @@ class ObjectRefGenerator:
     Parity: the reference's ObjectRefGenerator (_raylet.pyx:280) — sync
     and async iteration over ObjectRefs as the remote generator yields;
     a mid-stream exception surfaces as a final ref whose get() raises.
+
+    One STREAM_NEXT brings every item that is there (``_BATCH`` at the
+    most) with its inline value, so a consumer that fell behind a fast
+    producer (a served model's tokens, 128 streams at once) catches up
+    in one round trip and its ``get`` of a small value sends nothing:
+    an item then costs the hub its STREAM_YIELD and no more. Three round
+    trips an item held every stream of a process to some 900 items a
+    second together (PERF.md section 6, PR 52).
     """
+
+    # items one STREAM_NEXT asks for at the most: what has queued up
+    # behind a consumer that fell behind comes in one reply. Flat from
+    # 4 on (32 streams of one actor on a CPU: 1400 / 2470 / 2500 /
+    # 2560 / 2620 items/s at 1 / 4 / 16 / 64 / 256; PERF.md section 6,
+    # PR 52), so the value only has to cover a reader's longest stall
+    _BATCH = 64
 
     def __init__(self, task_id: bytes):
         self._task_id = task_id
         self._idx = 0
+        # handed over by the hub and not yet returned: (object id, the
+        # producer's yield stamp, the inline value or None)
+        self._ready: collections.deque = collections.deque()
         # the producer's stamp of when it yielded the ref last returned
         # (an anchored wall time, tracing.wall_at); None where it sent
         # none. It came with the STREAM_NEXT reply: no message of its own
@@ -125,14 +144,20 @@ class ObjectRefGenerator:
         from ._private import worker
 
         client = worker.get_client()
-        reply = client.request(
-            P.STREAM_NEXT, {"task_id": self._task_id, "index": self._idx}
-        )
-        if reply.get("end"):
-            raise StopIteration
+        if not self._ready:
+            reply = client.request(
+                P.STREAM_NEXT, {"task_id": self._task_id, "index": self._idx,
+                                "batch": self._BATCH}
+            )
+            if reply.get("end"):
+                raise StopIteration
+            self._ready.extend(reply["items"])
+        oid, self.last_yield_wall, inline = self._ready.popleft()
         self._idx += 1
-        self.last_yield_wall = reply.get("t_wall")
-        return ObjectRef(ObjectID(reply["object_id"]))
+        if inline is not None:
+            # the value came with the reply: ``get`` finds it here
+            client.hold_inline(oid, inline)
+        return ObjectRef(ObjectID(oid))
 
     def __aiter__(self):
         return self

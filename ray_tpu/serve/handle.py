@@ -391,8 +391,10 @@ class DeploymentHandle:
         through this handle and its ``options()`` views, cumulative (take
         two and subtract): ``serve.stream_transit`` is the time from the
         replica's worker yielding an item to the consumer holding its
-        value (encode, STREAM_YIELD, the hub, STREAM_NEXT's reply, the
-        get), ``serve.stream_first_transit`` the same for a stream's
+        value (encode, STREAM_YIELD, the hub, STREAM_NEXT's reply, which
+        carries an inline value with it and every item that queued up
+        behind a slow consumer; the get of a value that is not inline),
+        ``serve.stream_first_transit`` the same for a stream's
         first item alone. Exact on one host; from another host it holds
         the two wall clocks' skew."""
         stats, lock = self._stream_phases

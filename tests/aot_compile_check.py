@@ -228,6 +228,7 @@ def main() -> int:
     if not quick:
         latent_chunks(check, sds)
         window_pair(check, sds)
+        state_pair(check, sds)
 
     for n in (1, 4):
         check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "
@@ -281,7 +282,8 @@ def serving_cell(sds, name: str, model):
     lanes, max_seq = (cell["serve"][k] for k in ("max_batch_size",
                                                  "max_seq_len"))
     chunk = derived_prefill_chunk(
-        "TPU v5 lite", 2, max_seq, **model.chunk_terms(cfg, max_seq))
+        "TPU v5 lite", 2, max_seq,
+        **getattr(model, "chunk_terms", lambda *_: {})(cfg, max_seq))
     abstract = partial(jax.tree.map, lambda a: sds(a.shape, a.dtype))
     params = abstract(jax.eval_shape(
         partial(model.init_params, config=cfg), jax.random.PRNGKey(0)))
@@ -342,6 +344,36 @@ def window_pair(check, sds):
           f"{lanes} x {max_seq}, published widths, one device",
           partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
                   max_seq), forbid=no_stack_copy)
+
+
+def state_pair(check, sds):
+    """The programs of ``ai21-jamba2-3b.serve-reason`` at its published
+    widths, all 28 layers and the cell's 128 x 4096 cache: the three
+    buckets of the chunk a v5e's engine derives reading the whole slot,
+    the whole chunk at the other read window, and the decode step at
+    both. None may copy a whole leaf of the shard (the states, the
+    tails, the keys or the values), each chunk holds the scan's kernel
+    (``ops/selective_scan.py``), and the line says what temporaries a
+    call takes beside the 7.8 GB of weights and cache."""
+    from ray_tpu.models import hybrid_ssm
+    from ray_tpu.ops import selective_scan
+
+    selective_scan._interpret = lambda: False
+    cfg, params, cache, lanes, max_seq, chunk = serving_cell(
+        sds, "ai21-jamba2-3b.serve-reason", hybrid_ssm)
+    no_leaf_copy = no_copy_of(cache["state"], cache["tail"], cache["k"])
+    for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
+                         (chunk, max_seq), (chunk, max_seq // 2)):
+        check(f"state prefill chunk of {rows} rows reading {window} of "
+              f"{lanes} x {max_seq}, published widths, one device",
+              partial(lower_chunk, sds, hybrid_ssm, cfg, params, cache, rows,
+                      window),
+              expect=("selective_scan_chunk",), forbid=no_leaf_copy)
+    for window in (max_seq // 2, max_seq):
+        check(f"state decode step of {lanes} lanes reading {window} of "
+              f"{lanes} x {max_seq}, published widths, one device",
+              partial(lower_decode, sds, hybrid_ssm, cfg, params, cache,
+                      lanes, window), forbid=no_leaf_copy)
 
 
 if __name__ == "__main__":

@@ -143,3 +143,159 @@ def test_worker_death_ends_stream_with_error(ray_start_regular):
     assert ray_tpu.get(next(g)) == 1
     with pytest.raises(Exception):
         ray_tpu.get(next(g), timeout=10)
+
+
+def _count_requests(monkeypatch):
+    """{message type: requests this process's core client sent}."""
+    from ray_tpu._private import worker
+
+    client, sent = worker.get_client(), {}
+    inner = client.request
+
+    def counted(msg_type, payload, **kw):
+        sent[msg_type] = sent.get(msg_type, 0) + 1
+        return inner(msg_type, payload, **kw)
+
+    monkeypatch.setattr(client, "request", counted)
+    return sent
+
+
+def _wait_until_all_yielded(g, timeout_s=60.0):
+    """Until the generator's task is done: every item is at the hub."""
+    import time
+
+    from ray_tpu._private import worker
+
+    client, want = worker.get_client(), g._task_id.hex()
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        if any(t["task_id"] == want and t.get("state") == "FINISHED"
+               for t in client.list_state("tasks")):
+            return
+        time.sleep(0.05)
+    raise AssertionError("the generator's task never finished")
+
+
+@pytest.mark.parametrize("items", [1, 7, 150])
+def test_a_consumer_behind_is_handed_what_queued_up(
+        ray_start_regular, monkeypatch, items):
+    """Items that are there when the consumer asks come in one reply (64
+    at the most), their inline values with them: iterating and getting
+    them all costs a STREAM_NEXT a batch and the one that finds the end,
+    and no GET."""
+    from ray_tpu._private import protocol as P
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield i
+
+    g = gen.remote(items)
+    _wait_until_all_yielded(g)
+    sent = _count_requests(monkeypatch)
+    assert [ray_tpu.get(ref) for ref in g] == list(range(items))
+    assert sent.get(P.GET, 0) == 0
+    assert sent[P.STREAM_NEXT] == -(-items // ObjectRefGenerator._BATCH) + 1
+
+
+def test_a_bounded_producers_consumer_is_handed_one_item_a_reply(
+        ray_start_regular, monkeypatch):
+    """A producer that asks for credit counts what the consumer has
+    read: its items are not handed over ahead of the reading."""
+    import time
+
+    from ray_tpu._private import protocol as P
+
+    @ray_tpu.remote(
+        num_returns="streaming", _generator_backpressure_num_objects=2
+    )
+    def gen():
+        for i in range(6):
+            yield i
+
+    g = gen.remote()
+    time.sleep(1.0)
+    sent = _count_requests(monkeypatch)
+    assert [ray_tpu.get(r) for r in g] == list(range(6))
+    assert sent[P.STREAM_NEXT] == 7 and sent.get(P.GET, 0) == 0
+
+
+def test_a_large_streamed_value_is_still_fetched_by_its_get(
+        ray_start_regular, monkeypatch):
+    from ray_tpu._private import protocol as P
+
+    @ray_tpu.remote(num_returns="streaming")
+    def chunks():
+        yield np.ones((300_000,))
+        yield 5
+
+    g = chunks.remote()
+    sent = _count_requests(monkeypatch)
+    big, small = [ray_tpu.get(ref) for ref in g]
+    assert big.shape == (300_000,) and small == 5
+    assert sent.get(P.GET, 0) == 1
+
+
+@pytest.mark.parametrize("first_ask_after_s", [0.0, 0.02, 0.2])
+def test_a_bounded_producer_stays_within_its_bound_of_what_was_read(
+        ray_start_regular, tmp_path, monkeypatch, first_ask_after_s):
+    """The bound holds from the first item, whenever the consumer first
+    asks (at once, between the first yields and the producer's first
+    wait for credit, or behind it): the producer is never more than its
+    bound ahead of the items the consumer has taken, and every reply
+    holds one item."""
+    import time
+
+    from ray_tpu._private import protocol as P
+
+    bound, items, log = 2, 9, tmp_path / "yielded"
+
+    @ray_tpu.remote(
+        num_returns="streaming", _generator_backpressure_num_objects=bound
+    )
+    def gen(path):
+        for i in range(items):
+            with open(path, "a") as f:
+                f.write("x")
+            yield i
+
+    g = gen.remote(str(log))
+    time.sleep(first_ask_after_s)
+    sent = _count_requests(monkeypatch)
+    for taken in range(1, items + 1):
+        assert ray_tpu.get(next(g)) == taken - 1
+        if taken in (1, 4):
+            time.sleep(0.5)              # the producer runs as far as it may
+            # it writes the log before the yield that may have to wait
+            assert len(log.read_text()) <= taken + bound + 1
+    with pytest.raises(StopIteration):
+        next(g)
+    assert sent[P.STREAM_NEXT] == items + 1 and sent.get(P.GET, 0) == 0
+
+
+@pytest.mark.parametrize("bound", [True, False])
+def test_the_bound_holds_before_the_producers_first_wait_for_credit(
+        ray_start_regular, bound):
+    """A consumer's STREAM_NEXT that lands between a bounded producer's
+    first yields and its first STREAM_CREDIT is handed one item, not
+    all that are there: the test is the producer, so no credit was ever
+    asked for."""
+    import os
+
+    from ray_tpu._private import protocol as P
+    from ray_tpu._private import worker
+    from ray_tpu._private.ids import ObjectID
+
+    client, task_id = worker.get_client(), os.urandom(16)
+    for value in (10, 11, 12):
+        oid = ObjectID.generate()
+        kind, payload, size = client.encode_value(oid, value)
+        item = {"task_id": task_id, "object_id": oid.binary(), "kind": kind,
+                "payload": payload, "size": size, "t_wall": None}
+        if bound:
+            item["bound"] = True
+        client.send(P.STREAM_YIELD, item)
+    reply = client.request(
+        P.STREAM_NEXT, {"task_id": task_id, "index": 0, "batch": 64})
+    assert len(reply["items"]) == (1 if bound else 3)
+    client.send(P.STREAM_END, {"task_id": task_id, "error": None})
