@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -422,3 +423,18 @@ def forward_with_cache(
     new_cache = {"full": stacks[FULL], "ring": stacks[SLIDING],
                  "counts": decoder.fold_counts(cache["counts"], counted)}
     return decoder.head(params, x, c, logits_at), new_cache
+
+
+def _import_kernel():
+    from ray_tpu.ops import pallas_grouped_matmul  # noqa: F401
+
+
+# Pallas takes 1.2 s to import on a replica's host, a chunk program's
+# first trace needs it (``moe.expert_ffn``'s kernel), and ``setup_s`` is
+# a metric with a bound. A process that imports this module to serve
+# goes on to open its chip, and the import runs beside that, as
+# ``models/latent_moe.py``'s does. It hides less than hoped: the opening
+# read 1.2 s longer with the import beside it (PERF.md section 6,
+# PR 53); what the thread saves is the first chunk trace's wait.
+threading.Thread(target=_import_kernel, name="import-grouped-kernel",
+                 daemon=True).start()

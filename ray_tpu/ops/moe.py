@@ -14,9 +14,11 @@ Pieces:
   jitted model; shard params' leading E dim on the `expert` axis.
 - ``moe_ffn_dropless``: the same layer for inference, with no capacity
   and no token dropped: the assignments are sorted by expert and each
-  expert multiplies its own rows (``jax.lax.ragged_dot``, which the TPU
-  compiler lowers to a grouped-matmul kernel that visits an expert's
-  weights once and only the row tiles that hold its rows). A call of a
+  expert multiplies its own rows (``expert_ffn``: a grouped matmul that
+  visits an expert's weights once and only the row tiles that hold its
+  rows: the Pallas kernel of ``ops/pallas_grouped_matmul.py`` wherever
+  it can tile the shapes, ``jax.lax.ragged_dot``, the grouped kernel
+  the TPU compiler puts in, elsewhere). A call of a
   few rows whose assignments reach every expert anyway (a decode call)
   multiplies its rows by every expert's weights in one batched matmul
   and weighs what an expert was not chosen for by 0: the weights' read
@@ -227,15 +229,31 @@ def expert_ffn(xs, w_gate, w_up, w_down, group_sizes, layer=None):
     """The grouped SwiGLU: rows ``xs`` (N, D) sorted by expert,
     ``group_sizes`` (E,) rows each; (E, D, F) / (E, F, D) weights ->
     (N, D) float32. An expert with no rows costs nothing and its weights
-    are not read; all rows at one expert is one plain matmul.
+    are not read; all rows at one expert is one plain matmul. Rows
+    behind the last group are no expert's, and what comes out there is
+    not a number to keep.
 
     With ``layer`` (a traced index) the weights are a model's stacked
     (L, E, D, F) / (L, E, F, D) and the experts are that layer's: the
-    stack goes to the grouped matmul whole, as L * E groups of which all
-    but this layer's E have no rows. A layer's experts sliced out of the
-    stack would be copied, all of them, before every call (the grouped
-    matmul is a custom call, which no slice fuses into): at 64 experts
-    of 2304 x 896 that is 0.8 GB a layer a call."""
+    stack goes in whole. A layer's experts sliced out of the stack would
+    be copied, all of them, before every call (a grouped matmul is a
+    custom call, which no slice fuses into): at 64 experts of 2304 x 896
+    that is 0.8 GB a layer a call.
+
+    Which of the two implementations runs follows from the shapes alone:
+    ``ops/pallas_grouped_matmul.py``'s kernel where it can tile them
+    (row tiles that follow the groups, an expert's weights read once,
+    the layer an index of its block: a served model's chunk calls), and
+    elsewhere three ``jax.lax.ragged_dot``, the grouped matmul the TPU
+    compiler puts in, over ``L * E`` groups of which all but this
+    layer's E have no rows."""
+    # Pallas takes 1.2 s to import: a served family's module starts it
+    # in a thread of its own import, and this waits for what is left
+    from . import pallas_grouped_matmul as kernel
+
+    if kernel.untileable(xs, w_gate, w_down) is None:
+        return kernel.grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes,
+                                     layer)
     if layer is not None:
         L, E = w_gate.shape[:2]
         group_sizes = jax.lax.dynamic_update_slice(
@@ -255,9 +273,11 @@ def expert_ffn(xs, w_gate, w_up, w_down, group_sizes, layer=None):
 # bounds its matmul whatever rows it multiplies, and T * k >= E
 # assignments leave few experts without a row (16 rows, top-8 of 64: 88 %
 # have one), so every expert over every row asks for the read the grouped
-# matmul asks for; the compiler's grouped kernel at two rows an expert
-# took six times that read on a v5e (PERF.md section 6, PR 46). Past
-# these rows the (E, T, D) float32 products are no longer small and the
+# matmul asks for; the compiler's grouped kernel (``ragged_dot``) at two
+# rows an expert took six times that read on a v5e (PERF.md section 6,
+# PR 46), and the program's own (``ops/pallas_grouped_matmul.py``, PR 53)
+# visits a row tile an expert, 128 rows of matmul for two. Past these
+# rows the (E, T, D) float32 products are no longer small and the
 # matmuls' own time shows, so a prefill chunk stays grouped.
 EVERY_EXPERT_ROWS = 64
 
@@ -367,12 +387,14 @@ def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
     the rows that are somebody's tokens (default: all); the others are
     computed like them and left out of the counts.
 
-    The grouped matmul is a kernel the TPU compiler puts in (a Mosaic
-    custom call), which GSPMD cannot partition: under a mesh with more
-    than one device the rows are split over the batch axes by hand, each
-    shard sorting and multiplying its own rows against the whole of the
-    experts (the weights replicated), as ``ops/attention.py`` maps its
-    kernel."""
+    The grouped matmul is a Mosaic custom call whichever of
+    ``expert_ffn``'s two implementations the shapes choose (the
+    program's own Pallas kernel, or the one the TPU compiler makes of
+    ``ragged_dot``), which GSPMD cannot partition: under a mesh with
+    more than one device the rows are split over the batch axes by hand,
+    each shard sorting and multiplying its own rows against the whole of
+    the experts (the weights replicated), as ``ops/attention.py`` maps
+    its kernel."""
     lead, D = x.shape[:-1], x.shape[-1]
     rows = x.reshape(-1, D)
     live = (jnp.ones(rows.shape[0], bool) if live is None

@@ -17,7 +17,8 @@ line ends with the program's fingerprint (``fingerprint``): two trees
 whose lines agree compile to the same instructions under the same
 scopes, whatever their Python looks like. Words
 on the command line keep to the programs whose line holds one of them
-(``python tests/aot_compile_check.py latent``).
+(``python tests/aot_compile_check.py latent``; ``grouped``: the grouped
+SwiGLU of a chunk's experts at both routed configurations' widths).
 tests/test_chip_path.py runs it in a subprocess with ``--quick``: the
 same programs at the same widths, one layer and a shorter, smaller
 batch, because each compile costs about a minute of CPU time.
@@ -225,6 +226,7 @@ def main() -> int:
               "one device", partial(decode_step, 8, window),
               forbid=no_shard_copy)
 
+    grouped_swiglu(check, sds, quick)
     if not quick:
         latent_chunks(check, sds)
         window_pair(check, sds)
@@ -269,11 +271,10 @@ def no_copy_of(*leaves) -> str:
     return rf"\[(?:{shapes})\]\S* copy\("
 
 
-def serving_cell(sds, name: str, model):
-    """-> (configuration, parameters, cache, lanes, max_seq, chunk) of
-    the serving cell ``name`` as a v5e's engine would hold them, the
-    arrays as shapes: its published widths, its cell's lanes and cache
-    length, the chunk the engine derives."""
+def cell_config(name: str, model):
+    """-> (configuration, lanes, max_seq, chunk) of the serving cell
+    ``name``: its published widths, its cell's lanes and cache length,
+    the chunk a v5e's engine derives."""
     from benchmarks import spec
     from ray_tpu.llm._internal.engine import derived_prefill_chunk
 
@@ -284,12 +285,51 @@ def serving_cell(sds, name: str, model):
     chunk = derived_prefill_chunk(
         "TPU v5 lite", 2, max_seq,
         **getattr(model, "chunk_terms", lambda *_: {})(cfg, max_seq))
+    return cfg, lanes, max_seq, chunk
+
+
+def serving_cell(sds, name: str, model):
+    """-> (configuration, parameters, cache, lanes, max_seq, chunk) of
+    the serving cell ``name`` as a v5e's engine would hold them
+    (``cell_config``), the arrays as shapes."""
+    cfg, lanes, max_seq, chunk = cell_config(name, model)
     abstract = partial(jax.tree.map, lambda a: sds(a.shape, a.dtype))
     params = abstract(jax.eval_shape(
         partial(model.init_params, config=cfg), jax.random.PRNGKey(0)))
     cache = abstract(jax.eval_shape(
         partial(model.init_cache, cfg, lanes, max_seq, chunk)))
     return cfg, params, cache, lanes, max_seq, chunk
+
+
+def grouped_swiglu(check, sds, quick):
+    """The grouped SwiGLU of a chunk's experts
+    (``ops/pallas_grouped_matmul.py``) alone, at the published widths of
+    the two configurations that reach ``moe.expert_ffn`` and the
+    assignments of every chunk bucket of their cells (rows x top-8;
+    ``--quick``: the smallest bucket of each): 64 experts of 2304 x 896,
+    whose matrices go into VMEM whole, and 8 held experts of 7680 x
+    2048, whose columns are tiled; two layers' stacks, the layer a
+    traced index. Mosaic's answer on the tile shapes and on VMEM."""
+    from ray_tpu.models import latent_moe, window_moe
+    from ray_tpu.ops import moe
+
+    for name, model in (("mellum2-12b-a2.5b.serve-ide-mix", window_moe),
+                        ("openpangu-ultra-moe-718b.serve-longdoc",
+                         latent_moe)):
+        cfg, _, _, chunk = cell_config(name, model)
+        c = cfg.moe
+        E = c.n_experts if c.held is None else len(c.held)
+        up, down = (sds((2, E, *shape), cfg.dtype) for shape in (
+            (c.d_model, c.d_ff), (c.d_ff, c.d_model)))
+        for rows in (chunk // 4, chunk // 2, chunk)[:1 if quick else 3]:
+            check(f"grouped SwiGLU of a {rows}-row chunk's {rows * c.k} "
+                  f"assignments, {E} experts of {c.d_model} x {c.d_ff} "
+                  f"({name.rsplit('.', 1)[0]}), one device",
+                  lambda rows=rows: jax.jit(moe.expert_ffn).lower(
+                      sds((rows * c.k, c.d_model), cfg.dtype), up, up, down,
+                      sds((E,), jnp.int32), sds((), jnp.int32)),
+                  expect=("grouped_swiglu_gate_up", "grouped_swiglu_down"),
+                  forbid=r"ragged-dot|ragged_dot")
 
 
 def latent_chunks(check, sds):
@@ -339,11 +379,13 @@ def window_pair(check, sds):
     check(f"window_moe prefill chunk of {chunk} rows reading {max_seq} of "
           f"{lanes} x {max_seq}, published widths, one device",
           partial(lower_chunk, sds, window_moe, cfg, params, cache, chunk,
-                  max_seq), forbid=no_stack_copy)
+                  max_seq), expect=("grouped_swiglu_gate_up",),
+          forbid=rf"{no_stack_copy}|ragged-dot")
     check(f"window_moe decode step of {lanes} lanes reading {max_seq} of "
           f"{lanes} x {max_seq}, published widths, one device",
           partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
-                  max_seq), forbid=no_stack_copy)
+                  max_seq),
+          forbid=rf"{no_stack_copy}|ragged-dot|grouped_swiglu")
 
 
 def state_pair(check, sds):

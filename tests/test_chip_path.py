@@ -317,11 +317,14 @@ def test_device_programs_compile_for_a_v5e(aot_compile):
     """Flash attention, the fused cross entropy, prefill chunks of 64,
     128 and 256 rows, the 256-row chunk reading the cache's first half
     and the decode step reading that and every row, none of which
-    copies a whole cache leaf, and a one-layer train step at
-    LLAMA_BENCH's widths, on one device and on four, through Mosaic and
-    the TPU compiler (tests/aot_compile_check.py)."""
+    copies a whole cache leaf, the grouped SwiGLU of a chunk's experts
+    at the two routed configurations' published widths (their smallest
+    bucket: the kernel there and no ``ragged_dot``), and a one-layer
+    train step at LLAMA_BENCH's widths, on one device and on four,
+    through Mosaic and the TPU compiler (tests/aot_compile_check.py)."""
     out, _ = aot_compile.communicate(timeout=170)
     if aot_compile.returncode == 77:
         pytest.skip(out.strip())
     assert aot_compile.returncode == 0, out
-    assert out.count("\nok  ") + out.startswith("ok  ") == 10, out
+    assert out.count("\nok  ") + out.startswith("ok  ") == 12, out
+    assert out.count("ok   grouped SwiGLU") == 2, out

@@ -70,16 +70,27 @@ def test_dropless_layer_equals_a_per_token_loop(seed, path):
 def test_the_two_paths_are_chosen_by_the_rows_of_the_call():
     """Every expert over every row only where the call's assignments are
     as many as the experts and its rows are few; the grouped matmul
-    otherwise (one row; a chunk of rows)."""
-    params = moe_params(0)
+    otherwise (one row; a chunk of rows): ``ragged_dot`` at widths or
+    rows the program's own kernel does not tile, the kernel where it
+    does (rows of 128 lanes, assignments a multiple of its row tile)."""
+    def path(config, rows):
+        params = moe_params(0, config)
+        x = jnp.ones((rows, config.d_model))
+        text = str(jax.make_jaxpr(
+            lambda p, x: moe.moe_ffn_dropless(p, x, config))(params, x))
+        found = [name for name, word in (("ragged_dot", "ragged_dot"),
+                                         ("kernel", "grouped_swiglu"))
+                 if word in text]
+        assert len(found) <= 1
+        return found[0] if found else "every_expert"
 
-    def grouped(rows):
-        x = jnp.ones((rows, 32))
-        return "ragged_dot" in str(jax.make_jaxpr(
-            lambda p, x: moe.moe_ffn_dropless(p, x, MOE))(params, x))
-
-    assert [grouped(n) for n in (1, 3, 4, 16, 64, 65, 256)] == [
-        True, True, False, False, False, True, True]
+    assert [path(MOE, n) for n in (1, 3, 4, 16, 64, 65, 256)] == [
+        "ragged_dot", "ragged_dot", "every_expert", "every_expert",
+        "every_expert", "ragged_dot", "ragged_dot"]
+    wide = dataclasses.replace(MOE, d_model=128, d_ff=128)
+    assert [path(wide, n) for n in (1, 4, 64, 65, 128, 192, 200, 256)] == [
+        "ragged_dot", "every_expert", "every_expert", "ragged_dot", "kernel",
+        "kernel", "ragged_dot", "kernel"]
 
 
 def test_rows_that_are_nobodys_are_computed_and_not_counted(path):
