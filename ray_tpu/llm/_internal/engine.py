@@ -9,7 +9,21 @@ re-designed for XLA instead of wrapped:
   (B, 1) program (static shapes; no recompiles as requests come and
   go). When all slots are busy the engine GROWS by allocating another
   shard — same compiled programs, more concurrent sequences — up to
-  ``max_slots``.
+  ``max_slots``. Where a step finds lanes to decode in TWO shards it
+  dispatches ONE call over the pair (``_decode_pair``; three or four
+  live shards go two to a call, the odd one alone): the same ``decode``
+  program traced with the two caches, both donated, and the lanes of
+  both as ``2 x max_batch`` rows, at the top read window. Embedding, projections, feed-forward,
+  head and sampling run once over all the rows, so a step reads every
+  weight once and not once a shard; what touches a cache runs once a
+  shard on that shard's own buffer, in place (``models/decoder.py``
+  ``Call.by_shard``). An idle or mid-prefill lane of either shard rides
+  the call as it rides its own shard's: at the idle position. The
+  choice follows what the step observes, the shards with a lane to
+  decode, and no option or family name; a step with one such shard runs
+  ``_decode`` and the program it always ran. An engine that can never
+  hold a second shard (``max_slots == max_batch``) warms no such
+  variant.
 - CHUNKED prefill: prompts enter the cache ``prefill_chunk`` tokens per
   engine step, interleaved with decode — a long prompt cannot stall
   the decode of already-running sequences (vLLM's chunked-prefill
@@ -118,7 +132,8 @@ re-designed for XLA instead of wrapped:
   program of a few instructions over the logits its last chunk returned.
 - The host runs ONE decode step behind the device. ``step()`` first
   dispatches and reads nothing: every shard's prefill chunk, then every
-  shard's decode N+1, whose ``last_tokens`` is the device array that
+  shard's decode N+1 (two shards' in one call where two have lanes to
+  decode), whose ``last_tokens`` is the device array that
   shard's decode N returned (``shard.tokens``; ``first_token`` writes a
   finished prompt's first token into its lane there, so the lane joins
   without the host having seen it) and whose ``lengths`` is the host's
@@ -136,7 +151,16 @@ re-designed for XLA instead of wrapped:
   deeper, and empty whenever ``num_active()`` is 0. A greedy request's
   tokens are exactly those of a loop that reads every call before the
   next; a sampled one's are drawn from the same keys in dispatch order
-  (all chunks of a step before its decodes).
+  (all chunks of a step before its decodes). A call over a pair of
+  shards is ONE dispatch: one draw over all its ``2 x max_batch`` lanes
+  (by the backend's bit generator seeded from the engine's key, which
+  lowers in two instructions; the next key comes out of the same
+  stream), where two calls would have split the key twice, so a
+  sampled request's draws depend on whether its shard decoded alone;
+  a greedy one's tokens are the per-shard loop's up to what a matmul
+  over twice the rows rounds otherwise. Each shard's tokens and cache
+  come back as their own arrays and are rebound (``shard.tokens``,
+  ``shard.cache``, ``shard.unread``) as after a call of its own.
 - Observation: every request carries seven monotonic stamps (its six
   phases: ingress and accept before the server's pending queue, then
   queue_wait, prefill_wait, prefill, decode), ``EngineStats``
@@ -146,7 +170,10 @@ re-designed for XLA instead of wrapped:
   while N+1 runs, ``llm.first_token_sync`` the wait for a chunk dispatched
   in the same call; a ``step()`` lasts what the device needs for one
   round of programs, not one call's dispatch-to-read. ``decode_ahead`` /
-  ``decode_calls`` is how often the device had its next decode queued.
+  ``decode_calls`` is how often the device had its next decode queued;
+  ``decode_shards`` / ``decode_calls`` how many shards a decode call
+  advanced (1.0: never a pair; 2.0: always), and a pair's
+  ``llm.decode_dispatch`` span says ``shards=2`` beside its ``rows``.
   The jitted programs carry a ``sample`` scope next to the model's own
   (``kv_write``, ``kv_slice``, ``attn_cached``, ...).
 """
@@ -309,6 +336,9 @@ class EngineStats:
         # where the window is chosen (``_prefill`` / ``_decode``)
         "attn_rows_read", "attn_rows_full",
         "decode_calls", "decode_lanes_active", "decode_lanes_total",
+        # shards the decode calls advanced: one a call, two where a call
+        # ran over a pair, so over ``decode_calls`` how often it did
+        "decode_shards",
         # decode calls dispatched while the shard's last one was unread;
         # lane-steps computed for a request that had already ended
         "decode_ahead", "lanes_discarded",
@@ -441,7 +471,12 @@ class LlamaEngine:
 
         def decode(params, cache, last_tokens, lengths, temps, rng, rows):
             # one token for every slot: tokens (B,), lengths (B,) = count
-            # already in cache; inactive slots just waste a lane
+            # already in cache; inactive slots just waste a lane. Or two
+            # shards at once: ``cache`` and ``last_tokens`` a pair, lengths
+            # and temps of both shards' lanes, shard after shard
+            pair = isinstance(cache, tuple)
+            if pair:
+                last_tokens = jnp.concatenate(last_tokens)
             logits, new_cache = model.forward_with_cache(
                 params, last_tokens[:, None], cache, lengths, config,
                 rows=rows,
@@ -449,13 +484,33 @@ class LlamaEngine:
             with jax.named_scope("sample"):
                 logits = logits[:, 0]  # (B, V)
                 greedy = jnp.argmax(logits, axis=-1)
-                keys = jax.random.split(rng, logits.shape[0] + 1)
-                sampled = jax.vmap(
-                    lambda k, lg, t: jax.random.categorical(
-                        k, lg / jnp.maximum(t, 1e-4))
-                )(keys[1:], logits, temps)
-                toks = jnp.where(temps > 0, sampled, greedy)
-                return toks.astype(jnp.int32), new_cache, keys[0]
+                if pair:
+                    sampled, key = draw_block(rng, logits, temps)
+                else:
+                    keys = jax.random.split(rng, logits.shape[0] + 1)
+                    sampled = jax.vmap(
+                        lambda k, lg, t: jax.random.categorical(
+                            k, lg / jnp.maximum(t, 1e-4))
+                    )(keys[1:], logits, temps)
+                    key = keys[0]
+                toks = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+                if pair:
+                    toks = tuple(jnp.split(toks, len(cache)))
+                return toks, new_cache, key
+
+        def draw_block(rng, logits, temps):
+            # one draw over all the rows by the backend's bit generator,
+            # seeded from the engine's key, and the next key out of the
+            # same stream: two instructions to lower where the threefry
+            # of ``jax.random`` unrolls into hundreds (0.65 s of a decode
+            # variant's lowering at every start: PERF.md section 6, PR 54)
+            state = jnp.concatenate([rng, rng ^ jnp.uint32(0x9E3779B9)])
+            state, bits = jax.lax.rng_bit_generator(state, logits.shape)
+            _, key = jax.lax.rng_bit_generator(state, rng.shape)
+            uniform = (bits >> 9).astype(jnp.float32) * 2.0 ** -23  # [0, 1)
+            gumbel = -jnp.log(-jnp.log(jnp.maximum(uniform, 1e-20)))
+            scaled = logits / jnp.maximum(temps, 1e-4)[:, None]
+            return jnp.argmax(scaled + gumbel, axis=-1), key
 
         def first_token(tokens, logits, slot, temp, rng):
             # a finished prompt's first token, drawn as decode draws and
@@ -476,6 +531,7 @@ class LlamaEngine:
         # the variants run so far: (bucket, rows) of prefill, rows of decode
         self._prefills_run: set = set()
         self._decodes_run: set = set()
+        self._pair_run = False                  # decode over a pair
         self._lock = threading.Lock()
 
     def _jit_programs(self, prefill, decode, first_token):
@@ -560,6 +616,21 @@ class LlamaEngine:
         return self._jit_decode(params, cache, last_tokens, lengths, temps,
                                 rng, rows=rows)
 
+    def _decode_pair(self, params, caches, last_tokens, lengths, temps, rng):
+        """One decode over two shards: ``caches`` and ``last_tokens``
+        pairs, ``lengths`` and ``temps`` (2 x max_batch,) of the first
+        shard's lanes and then the second's -> (the two token vectors,
+        the two caches, the next sampling key). At the top read window
+        whatever the lanes hold: a variant a window is 1.3 to 2.5 s of a
+        replica's start where a call's rows are 16 (PERF.md section 6,
+        PR 54), and the rows a lower window would spare are a few per
+        cent of the weights the pair reads once."""
+        self._pair_run = True
+        for _ in caches:
+            self._count_rows(self.max_seq)
+        return self._jit_decode(params, caches, last_tokens, lengths, temps,
+                                rng, rows=self.max_seq)
+
     def _cache_gone(self, shard: _Shard) -> bool:
         """Whether a call that failed after it was dispatched took the
         shard's donated cache with it."""
@@ -587,12 +658,14 @@ class LlamaEngine:
         for (the whole chunk at each read window and the smaller buckets
         at the top one, into slot 0 of the first shard; a first token off
         the last one's logits; then decode at each window on the scratch
-        row, its tokens on the device as ``step()`` passes them) and wait
-        for them, so that no request pays a compile. Each variant is
-        asked for by name, not through the host's choice. Counts nothing
-        in ``stats`` and leaves the sampling key as it was; the rows it
-        writes are overwritten by the slot's next prompt before anything
-        attends to them. The engine must be idle."""
+        row, its tokens on the device as ``step()`` passes them; where
+        ``max_slots`` allows a second shard, the decode over a pair as
+        well, the pair's other cache made here and dropped) and wait for
+        them, so that no request pays a compile.
+        Each variant is asked for by name, not through the host's choice.
+        Counts nothing in ``stats`` and leaves the sampling key as it
+        was; the rows it writes are overwritten by the slot's next prompt
+        before anything attends to them. The engine must be idle."""
         with self._lock:
             if self.num_active():
                 raise RuntimeError("warm_up needs an idle engine")
@@ -605,15 +678,22 @@ class LlamaEngine:
                     onehot, np.zeros(1, np.int32), 1, bucket=bucket, rows=rows)
             tokens, _, _ = self._first_token(
                 shard.tokens, logits, np.int32(0), np.float32(0), self._rng)
+            idle = np.full(self.max_batch, self._idle, np.int32)
+            temps = np.zeros(self.max_batch, np.float32)
             for rows in self.windows:
                 toks, shard.cache, _ = self._jit_decode(
-                    self.params, shard.cache, tokens,
-                    np.full(self.max_batch, self._idle, np.int32),
-                    np.zeros(self.max_batch, np.float32), self._rng,
+                    self.params, shard.cache, tokens, idle, temps, self._rng,
                     rows=rows)
+            pair = self.max_slots >= 2 * self.max_batch    # a second shard
+            if pair:
+                _, (shard.cache, _), _ = self._jit_decode(
+                    self.params, (shard.cache, self._new_cache()),
+                    (toks, tokens), np.tile(idle, 2), np.tile(temps, 2),
+                    self._rng, rows=self.max_seq)
             self._jax.block_until_ready((toks, shard.cache))
         self._prefills_run.update(self._prefill_variants())
         self._decodes_run.update(self.windows)
+        self._pair_run = self._pair_run or pair
 
     # ------------------------------------------------------------------
     def has_capacity(self) -> bool:
@@ -763,19 +843,17 @@ class LlamaEngine:
         return (count >= req.max_tokens
                 or len(req.prompt_ids) + count >= self._idle)
 
-    def _dispatch_decode(self, shard: _Shard):
-        """Dispatch one decode for the shard's lanes that have a token
-        to come, fed the device's own last tokens; returns what
-        ``shard.unread`` will hold, None if no lane wants one."""
-        stats = self.stats
-        # tokens a lane has coming: its first and a decode a row written
-        lanes = [(slot, req) for slot, req in shard.active.items()
-                 if not self._last_by_count(
-                     req, shard.lengths[slot] - len(req.prompt_ids) + 1)]
-        if not lanes:
-            return None
-        self.peak_active = max(self.peak_active, len(lanes))
-        with phase("llm.decode_prepare", stats.phases, shard=shard.index):
+    def _lanes_to_decode(self, shard: _Shard) -> List[Tuple[int, GenRequest]]:
+        """The shard's lanes (slot, request) that have a token to come:
+        their first, and a decode for every row written since."""
+        return [(slot, req) for slot, req in shard.active.items()
+                if not self._last_by_count(
+                    req, shard.lengths[slot] - len(req.prompt_ids) + 1)]
+
+    def _prepare_decode(self, shard: _Shard, lanes):
+        """-> (lengths, temps) of the shard's lanes for a decode of
+        ``lanes``, each of which is accounted the row it will write."""
+        with phase("llm.decode_prepare", self.stats.phases, shard=shard.index):
             temps = np.zeros(self.max_batch, np.float32)
             # inactive lanes (free, mid-prefill or at their last token)
             # still ride the batched decode; point their cache write at
@@ -788,22 +866,61 @@ class LlamaEngine:
                 lens[slot] = shard.lengths[slot]
                 # the decode consumes the lane's last token: account it
                 shard.lengths[slot] += 1
+        return lens, temps
+
+    def _dispatch_decode(self, group: List[Tuple[_Shard, list]]):
+        """Dispatch ONE decode for the lanes to decode of one shard or
+        of two (``group``: (shard, lanes) of each), fed the device's own
+        last tokens, and rebind each shard's tokens and cache from what
+        it returns. One shard goes through ``_decode``, two through
+        ``_decode_pair``: the same program over twice the lanes, every
+        weight read once."""
+        stats = self.stats
+        shards = [shard for shard, _ in group]
+        live = sum(len(lanes) for _, lanes in group)
+        self.peak_active = max(self.peak_active, live)
+        lens, temps = zip(*(self._prepare_decode(*each) for each in group))
         # ``attended``, where a trace records the span: the rows its live
         # lanes attend to, all together (each its own length and the row
         # it writes)
-        seen = {"attended": int(sum(lens[slot] for slot, _ in lanes))
-                + len(lanes)} if recording() else {}
-        with phase("llm.decode_dispatch", stats.phases, shard=shard.index,
-                   rows=len(lanes), **seen):
-            shard.tokens, shard.cache, self._rng = self._decode(
-                self.params, shard.cache, shard.tokens,
-                lens, temps, self._rng,
-            )
+        seen = {"attended": live + int(sum(
+            of_shard[slot] for of_shard, (_, lanes) in zip(lens, group)
+            for slot, _ in lanes))} if recording() else {}
+        with phase("llm.decode_dispatch", stats.phases, shard=shards[0].index,
+                   shards=len(group), rows=live, **seen):
+            if len(group) == 1:
+                shard, = shards
+                shard.tokens, shard.cache, self._rng = self._decode(
+                    self.params, shard.cache, shard.tokens,
+                    lens[0], temps[0], self._rng,
+                )
+            else:
+                tokens, caches, self._rng = self._decode_pair(
+                    self.params, tuple(s.cache for s in shards),
+                    tuple(s.tokens for s in shards),
+                    np.concatenate(lens), np.concatenate(temps), self._rng)
+                for shard, toks, cache in zip(shards, tokens, caches):
+                    shard.tokens, shard.cache = toks, cache
         stats.decode_calls += 1
-        stats.decode_ahead += shard.unread is not None
-        stats.decode_lanes_active += len(lanes)
-        stats.decode_lanes_total += self.max_batch
-        return shard.tokens, lanes
+        stats.decode_shards += len(group)
+        stats.decode_ahead += any(s.unread is not None for s in shards)
+        stats.decode_lanes_active += live
+        stats.decode_lanes_total += len(group) * self.max_batch
+
+    def _dispatch_decodes(self) -> List[Optional[tuple]]:
+        """Every shard's decode, the shards with lanes to decode two to
+        a call in their order (a third rides with a fourth or alone: a
+        call over every live shard would want a program a count) ->
+        what each shard's ``unread`` will hold, None where no lane
+        wanted a decode."""
+        ready = [(shard, lanes) for shard in self.shards
+                 if (lanes := self._lanes_to_decode(shard))]
+        for i in range(0, len(ready), 2):
+            self._dispatch_decode(ready[i:i + 2])
+        ahead: List[Optional[tuple]] = [None] * len(self.shards)
+        for shard, lanes in ready:
+            ahead[shard.index] = (shard.tokens, lanes)
+        return ahead
 
     def _take(self, shard: _Shard, req: GenRequest, tok: int,
               out: List[Tuple[GenRequest, int]]):
@@ -827,7 +944,7 @@ class LlamaEngine:
             out: List[Tuple[GenRequest, int]] = []
             for shard in self.shards:
                 self._pump_prefill(shard, out)
-            ahead = [self._dispatch_decode(shard) for shard in self.shards]
+            ahead = self._dispatch_decodes()
             for shard in self.shards:
                 if shard.unread is None:
                     continue
@@ -866,10 +983,11 @@ class LlamaEngine:
     def compiled_programs(self) -> Dict[str, Any]:
         """The engine's programs as compiled executables, one for each
         variant run so far: ``first_token``, ``decode_<rows>`` for each
-        read window and ``prefill_<bucket>_<rows>`` for each chunk bucket
-        at each. For reading their text (``jax_utils.scope_map``) next
-        to a device trace, where every variant of a program runs under
-        the one name it was jitted under. Each is jitted afresh and
+        read window (``decode_<max_seq>_x2``: over a pair of shards) and
+        ``prefill_<bucket>_<rows>`` for each chunk bucket at each. For
+        reading their text (``jax_utils.scope_map``) next to a device
+        trace, where every variant of a program runs under the one name
+        it was jitted under. Each is jitted afresh and
         compiled again (or loaded from the persistent cache;
         ``compile_with_scopes`` says why), so call this outside anything
         timed."""
@@ -888,6 +1006,13 @@ class LlamaEngine:
             out[f"decode_{rows}"] = compile_with_scopes(decode.lower(
                 self.params, cache, tokens, np.zeros(self.max_batch, i32),
                 np.zeros(self.max_batch, f32), self._rng, rows=rows))
+        if self._pair_run:
+            out[f"decode_{self.max_seq}_x2"] = compile_with_scopes(
+                decode.lower(
+                    self.params, (cache, cache), (tokens, tokens),
+                    np.zeros(2 * self.max_batch, i32),
+                    np.zeros(2 * self.max_batch, f32), self._rng,
+                    rows=self.max_seq))
         for bucket, rows in sorted(self._prefills_run):
             out[f"prefill_{bucket}_{rows}"] = compile_with_scopes(
                 prefill.lower(

@@ -7,6 +7,20 @@ defined here and the engine dispatches what it says), **embedding and
 head**, **the layer scan** (``scan_layers``) and **the device counters**
 (two int32 words each in the cache, ``fold_counts``, ``read_counters``).
 
+**A call over several shards.** A family's ``forward_with_cache`` takes
+one shard's cache or a tuple of several (``caches_of``), the tokens and
+offsets of all their lanes in one batch, shard after shard. Everything
+that multiplies a weight (embedding, projections, feed-forward, final
+norm, head) runs once over all the rows, so a weight is read once a
+call; what touches a cache runs once a shard on that shard's rows and
+that shard's cache alone (``Call.by_shard``), every cache carried
+through the layer scan and updated in place. A lane of any shard rides
+such a call as it rides a call of its own shard: an idle or mid-prefill
+one at the idle position, writing its scratch row. What the call counts
+on the device is folded into the first cache's words. The rows are
+those of a call a shard up to what a matmul over more rows rounds
+otherwise. A call with one cache traces to the program it always was.
+
 It imports no family's module and takes no argument that says which one
 calls it. The scopes it opens (``embed``, ``layers``, ``head``) are
 names ``benchmarks/`` reads out of a trace.
@@ -14,7 +28,8 @@ names ``benchmarks/`` reads out of a trace.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence
+import copy
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +48,15 @@ def idle_position(max_seq: int) -> int:
     or past its last token): the cache's last row, which no live
     sequence reaches, so what the lane writes is never attended to."""
     return max_seq - 1
+
+
+def caches_of(cache) -> Tuple[tuple, Any]:
+    """A cached forward's ``cache`` argument, one shard's pytree or a
+    tuple of several shards' -> (the caches as a tuple, ``back``: the
+    tuple of caches the call leaves, as the caller gave them)."""
+    if isinstance(cache, tuple):
+        return cache, tuple
+    return (cache,), lambda caches: caches[0]
 
 
 class Call:
@@ -56,11 +80,18 @@ class Call:
     exp(-1e30 - max) = 0 exactly, so any such window gives the full
     read's result. Writes go to the full cache wherever ``start_pos``
     says, inside the window or not (an idle lane's to its scratch row).
+
+    ``shards``: how many caches the B sequences lie in, B / shards in
+    each, shard after shard (``by_shard``). Only whole shards ride
+    together: several of them leave no place for a ``slot``.
     """
 
     def __init__(self, tokens, start_pos, max_seq: int, *, slot=None,
-                 logits_at=None, rows: Optional[int] = None):
+                 logits_at=None, rows: Optional[int] = None, shards: int = 1):
         self.B, self.T = tokens.shape
+        if shards > 1 and (slot is not None or self.B % shards):
+            raise ValueError(
+                f"{self.B} sequences, slot {slot}, over {shards} caches")
         self.start_pos = start_pos
         self.pos = start_pos[:, None] + jnp.arange(self.T)[None, :]  # (B, T)
         self.first = 0 if slot is None else slot
@@ -78,6 +109,40 @@ class Call:
         if self.logits_at is not None:
             return jnp.arange(self.T)[None, :] <= self.logits_at[:, None]
         return jnp.ones((self.B, self.T), bool)
+
+    def by_shard(self, mix, states, *rows):
+        """``mix(part, state, *rows) -> (out, state)`` once a shard:
+        ``part`` is the call of that shard's sequences alone, ``state``
+        what the scan carries of its cache, ``rows`` arrays (or None)
+        with a leading axis of the call's sequences, cut alike ->
+        (the outs joined along that axis, the states as a tuple). With
+        one shard ``part`` is the call and nothing is cut or joined;
+        with several, ``mix`` is traced once and called a shard (it may
+        close over what the caller's trace holds, not write to it)."""
+        if len(states) == 1:
+            out, state = mix(self, states[0], *rows)
+            return out, (state,)
+        n = self.B // len(states)
+
+        # jitted, so that the shards share one trace and one lowering of
+        # the mixer (a lane's cache write is a few dozen operations, and
+        # tracing and lowering them is what a variant costs a replica's
+        # start); the compiler inlines the calls
+        @jax.jit
+        def shard(start_pos, pos, state, *rows):
+            part = copy.copy(self)
+            part.B, part.start_pos, part.pos = n, start_pos, pos
+            return mix(part, state, *rows)
+
+        outs, new = [], []
+        for s, state in enumerate(states):
+            at = slice(s * n, (s + 1) * n)
+            out, state = shard(self.start_pos[at], self.pos[at], state,
+                               *(r if r is None else r[at] for r in rows))
+            outs.append(out)
+            new.append(state)
+        return (jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs),
+                tuple(new))
 
 
 def embed(params: Dict[str, Any], tokens: jax.Array, config) -> jax.Array:
@@ -145,6 +210,14 @@ def fold_counts(words: jax.Array, counted: jax.Array) -> jax.Array:
         low = words[:, 1] + counted
         return jnp.stack([words[:, 0] + (low >> _CARRY_BITS),
                           low & ((1 << _CARRY_BITS) - 1)], axis=1)
+
+
+def folded(caches: tuple, counted: jax.Array) -> list:
+    """Every cache's counter words behind a call that counted
+    ``counted``: folded into the first's, the others' as they were (a
+    reader sums the shards')."""
+    return [fold_counts(caches[0]["counts"], counted),
+            *(each["counts"] for each in caches[1:])]
 
 
 def read_counters(cache, names: Sequence[str]) -> Dict[str, int]:

@@ -442,10 +442,14 @@ def forward_with_cache(
     the chunk form; ``rows`` bounds the attention layers' read and
     means nothing to a state layer. States, tails, keys and values ride
     in the layer scans' carry and are updated in place under a jit that
-    donates the cache."""
+    donates the cache. ``cache`` may be a tuple of several shards'
+    caches (``models/decoder.py``): a state layer then reads each
+    shard's lanes, steps them together and writes each shard's back."""
     c = config
-    call = decoder.Call(tokens, start_pos, cache["k"].shape[3], slot=slot,
-                        logits_at=logits_at, rows=rows)
+    caches, back = decoder.caches_of(cache)
+    call = decoder.Call(tokens, start_pos, caches[0]["k"].shape[3],
+                        slot=slot, logits_at=logits_at, rows=rows,
+                        shards=len(caches))
     B, T, first = call.B, call.T, call.first
     cos, sin = _unturned(c, B, T)
     live = call.live()
@@ -456,36 +460,49 @@ def forward_with_cache(
     # read and written once a call, and the scope holds both
     form = "ssm_step" if T == 1 else "ssm_scan"
 
-    def mix_state(x, carried, layer, i):
-        lanes, rows_kv = carried
+    # a shard's carry: ((states, tails), {"k", "v"})
+    def mix_state(x, shards, layer, i):
+        def read(part, carried):
+            return _lanes_read(carried[0], i, first, part.B), carried
+
+        def write(part, carried, state, tail):
+            return None, (_lanes_write(carried[0], i, first, state, tail),
+                          carried[1])
+
         with jax.named_scope("ssm"), jax.named_scope(form):
-            state, tail = _lanes_read(lanes, i, first, B)
+            (state, tail), shards = call.by_shard(read, shards)
         x, state, tail = ssm_sublayer(c, x, layer, state, tail, start_pos,
                                       live)
         with jax.named_scope("ssm"), jax.named_scope(form):
-            lanes = _lanes_write(lanes, i, first, state, tail)
-        return x, (lanes, rows_kv)
+            _, shards = call.by_shard(write, shards, state, tail)
+        return x, shards
 
-    def mix_attn(x, carried, layer, i):
-        lanes, rows_kv = carried
+    def mix_attn(x, shards, layer, i):
+        def attend(part, carried, q, k, v):
+            k_c, v_c, rows_kv = write_and_read(carried[1], k, v, i, part,
+                                               part.window)
+            with jax.named_scope("attn_cached"):
+                return (_attention_cached(q, k_c, v_c, part.pos, c),
+                        (carried[0], rows_kv))
 
         def mixer(q, k, v, _):
-            nonlocal rows_kv
-            k_c, v_c, rows_kv = write_and_read(rows_kv, k, v, i, call,
-                                               call.window)
-            with jax.named_scope("attn_cached"):
-                return _attention_cached(q, k_c, v_c, call.pos, c)
+            nonlocal shards
+            attn, shards = call.by_shard(attend, shards, q, k, v)
+            return attn
 
         x = attention_sublayer(c, x, layer, cos, sin, mixer)
-        return x, (lanes, rows_kv)
+        return x, shards
 
-    x, (lanes, rows_kv) = scan_periods(
+    x, shards = scan_periods(
         c, params, decoder.embed(params, tokens, c),
-        ((cache["state"], cache["tail"]), {"k": cache["k"], "v": cache["v"]}),
+        tuple(((each["state"], each["tail"]), {"k": each["k"], "v": each["v"]})
+              for each in caches),
         mix_state, mix_attn)
     with jax.named_scope("layers"):     # counted beside the scans
         counted = c.n_state_layers * jnp.stack(
             [jnp.int32(B * T), live.sum().astype(jnp.int32)])
-    new_cache = {"state": lanes[0], "tail": lanes[1], **rows_kv,
-                 "counts": decoder.fold_counts(cache["counts"], counted)}
-    return decoder.head(_tied(params), x, c, logits_at), new_cache
+    new_caches = tuple(
+        {"state": lanes[0], "tail": lanes[1], **rows_kv, "counts": words}
+        for (lanes, rows_kv), words in zip(
+            shards, decoder.folded(caches, counted)))
+    return decoder.head(_tied(params), x, c, logits_at), back(new_caches)

@@ -587,11 +587,16 @@ def forward_with_cache(
     decode and attends in the absorbed form, T over 1 a chunk and
     attends in the expanded one; ``rows`` bounds either's read. The
     cache and the counters ride in the carries of the two layer scans
-    and are updated in place under a jit that donates the cache."""
+    and are updated in place under a jit that donates the cache.
+    ``cache`` may be a tuple of several shards' caches
+    (``models/decoder.py``); what the call counts goes into the first
+    one's words."""
     c = config
-    call = decoder.Call(tokens, start_pos, cache["latent"].shape[2],
-                        slot=slot, logits_at=logits_at, rows=rows)
-    B, T, pos, first = call.B, call.T, call.pos, call.first
+    caches, back = decoder.caches_of(cache)
+    call = decoder.Call(tokens, start_pos, caches[0]["latent"].shape[2],
+                        slot=slot, logits_at=logits_at, rows=rows,
+                        shards=len(caches))
+    T, pos, first = call.T, call.pos, call.first
     x = decoder.embed(params, tokens, c)
     cos, sin = rope_cos_sin(c, pos)
     live = call.live()
@@ -606,39 +611,45 @@ def forward_with_cache(
                               seen_by_live.max(axis=1).sum(), zero])
     attended = attended.astype(jnp.int32)
 
-    def attention(x, latents, layer, i):
-        """-> (x, the cache with layer i's new rows)."""
+    def attention(x, shards, layer, i):
+        """-> (x, the shards' stacks with layer i's new rows)."""
+        def attend(part, latents, q_nope, q_rope, new):
+            with jax.named_scope("kv_write"):
+                latents = _write_rows(latents, new, i, first, part.start_pos)
+            if T == 1:
+                attn = attend_absorbed(
+                    c, q_nope, q_rope,
+                    _stack_reader(latents, i, first, part.B), call.window,
+                    part.pos, layer, last)
+            else:
+                attn = attend_expanded(c, q_nope, q_rope, latents, i, first,
+                                       call.window, part.start_pos, layer)
+            return attn, latents
+
         with jax.named_scope("attn"):
             h = rms_norm(x, layer["attn_norm"], c.norm_eps)
             q_nope, q_rope = latent_q(c, h, layer, cos, sin)
             new = latent_kv(c, h, layer, cos, sin)
-            with jax.named_scope("kv_write"):
-                latents = _write_rows(latents, new, i, first, start_pos)
+            attn, shards = call.by_shard(attend, shards, q_nope, q_rope, new)
+            return attn_out(c, x, attn, layer), shards
 
-            if T == 1:
-                attn = attend_absorbed(
-                    c, q_nope, q_rope, _stack_reader(latents, i, first, B),
-                    call.window, pos, layer, last)
-            else:
-                attn = attend_expanded(c, q_nope, q_rope, latents, i, first,
-                                       call.window, start_pos, layer)
-            return attn_out(c, x, attn, layer), latents
-
-    def dense_step(x, latents, layer, i):
-        x, latents = attention(x, latents, layer, i)
-        return dense_mlp(c, x, layer), latents, None
+    def dense_step(x, shards, layer, i):
+        x, shards = attention(x, shards, layer, i)
+        return dense_mlp(c, x, layer), shards, None
 
     scanned, experts = moe.split_experts(params["routed"])
 
-    def routed_step(x, latents, layer, i):
-        x, latents = attention(x, latents, layer, c.n_dense_layers + i)
+    def routed_step(x, shards, layer, i):
+        x, shards = attention(x, shards, layer, c.n_dense_layers + i)
         x, counted = moe_mlp(c, x, layer, experts, i, live)
-        return x, latents, counted
+        return x, shards, counted
 
-    x, latents, _ = decoder.scan_layers(
-        dense_step, x, (cache["latent"], cache["rope_key"]), params["dense"])
-    x, latents, counted = decoder.scan_layers(
-        routed_step, x, latents, scanned, 4)
+    x, shards, _ = decoder.scan_layers(
+        dense_step, x,
+        tuple((each["latent"], each["rope_key"]) for each in caches),
+        params["dense"])
+    x, shards, counted = decoder.scan_layers(
+        routed_step, x, shards, scanned, 4)
     with jax.named_scope("layers"):     # counted beside the scans
         counted = jnp.concatenate([counted, attended * c.n_layers])
     with jax.named_scope("head"):
@@ -647,8 +658,10 @@ def forward_with_cache(
         x = decoder.final_rows(params, x, c, logits_at)
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(c.dtype),
                             preferred_element_type=jnp.float32)
-    return logits, {"latent": latents[0], "rope_key": latents[1],
-                    "counts": decoder.fold_counts(cache["counts"], counted)}
+    return logits, back(tuple(
+        {"latent": latents, "rope_key": keys, "counts": words}
+        for (latents, keys), words in zip(
+            shards, decoder.folded(caches, counted))))
 
 
 def _import_kernel():

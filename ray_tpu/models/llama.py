@@ -589,25 +589,33 @@ def forward_with_cache(
     scan's carry (``decoder.scan_layers``), a layer writes its T new
     rows into them and reads its own rows for attention out of them.
     Under a jit that donates the cache nothing else of it moves.
+    ``cache`` may be a tuple of several shards' caches, the B sequences
+    theirs shard after shard (``models/decoder.py`` says what such a
+    call is): the tuple comes back updated.
     """
     c = config
-    max_seq = cache["k"].shape[3]
+    caches, back = decoder.caches_of(cache)
+    max_seq = caches[0]["k"].shape[3]
     call = decoder.Call(tokens, start_pos, max_seq, slot=slot,
-                        logits_at=logits_at, rows=rows)
+                        logits_at=logits_at, rows=rows, shards=len(caches))
     x = decoder.embed(params, tokens, c)
     cos_full, sin_full = rope_table(c, max_seq)
     cos, sin = cos_full[call.pos], sin_full[call.pos]       # (B, T, hd/2)
 
-    def step(x, stacks, layer, i):
-        def mixer(q, k, v, c):
-            nonlocal stacks     # the stacks with this layer's new rows
+    def step(x, caches, layer, i):
+        def attend(part, stacks, q, k, v):
             k_c, v_c, stacks = write_and_read(
-                stacks, k, v, i, call, call.window)
+                stacks, k, v, i, part, part.window)
             with jax.named_scope("attn_cached"):
-                return _attention_cached(q, k_c, v_c, call.pos, c)
+                return _attention_cached(q, k_c, v_c, part.pos, c), stacks
+
+        def mixer(q, k, v, c):
+            nonlocal caches     # the stacks with this layer's new rows
+            attn, caches = call.by_shard(attend, caches, q, k, v)
+            return attn
 
         x = attention_sublayer(c, x, layer, cos, sin, mixer)
-        return mlp_sublayer(c, x, layer), stacks, None
+        return mlp_sublayer(c, x, layer), caches, None
 
-    x, cache, _ = decoder.scan_layers(step, x, cache, params["blocks"])
-    return decoder.head(params, x, c, logits_at), cache
+    x, caches, _ = decoder.scan_layers(step, x, caches, params["blocks"])
+    return decoder.head(params, x, c, logits_at), back(caches)

@@ -394,13 +394,17 @@ def forward_with_cache(
     the full layers' read and leaves the rings alone. The layer scan runs
     over whole periods, a period's layers unrolled inside it; the stacks
     and the counters ride in its carry and are updated in place under a
-    jit that donates the cache."""
+    jit that donates the cache. ``cache`` may be a tuple of several
+    shards' caches (``models/decoder.py``): the experts then meet all
+    the shards' rows at once and count them as one call's, into the
+    first cache's words."""
     c = config
-    max_seq = cache["full"]["k"].shape[3]
-    slots = cache["ring"]["k"].shape[3]
+    caches, back = decoder.caches_of(cache)
+    max_seq = caches[0]["full"]["k"].shape[3]
+    slots = caches[0]["ring"]["k"].shape[3]
     ring = slots - _SCRATCH_SLOTS
     call = decoder.Call(tokens, start_pos, max_seq, slot=slot,
-                        logits_at=logits_at, rows=rows)
+                        logits_at=logits_at, rows=rows, shards=len(caches))
     ring_mask = _ring_mask(call.pos, start_pos, call.T, ring, slots,
                            c.sliding_window)
     ring_write = partial(_ring_write, ring=ring,
@@ -410,19 +414,26 @@ def forward_with_cache(
     reads = {FULL: (call.window, write_rows, "attn_cached", None),
              SLIDING: (slots, ring_write, "attn_window", ring_mask)}
 
-    def attend(kind, i, stacks, q, k, v):
+    def attend(kind, i, shards, q, k, v):
         n, write, scope, mask = reads[kind]
-        k_c, v_c, new = write_and_read(stacks[kind], k, v, i, call, n, write)
-        with jax.named_scope(scope):
-            attn = _attention_cached(q, k_c, v_c, call.pos, c, mask=mask)
-        return attn, {**stacks, kind: new}
 
-    x, stacks, counted = scan_periods(
+        def one(part, stacks, q, k, v, mask):
+            k_c, v_c, new = write_and_read(
+                stacks[kind], k, v, i, part, n, write)
+            with jax.named_scope(scope):
+                attn = _attention_cached(q, k_c, v_c, part.pos, c, mask=mask)
+            return attn, {**stacks, kind: new}
+
+        return call.by_shard(one, shards, q, k, v, mask)
+
+    x, shards, counted = scan_periods(
         c, params["blocks"], decoder.embed(params, tokens, c), call.pos,
-        attend, {FULL: cache["full"], SLIDING: cache["ring"]}, call.live())
-    new_cache = {"full": stacks[FULL], "ring": stacks[SLIDING],
-                 "counts": decoder.fold_counts(cache["counts"], counted)}
-    return decoder.head(params, x, c, logits_at), new_cache
+        attend, tuple({FULL: each["full"], SLIDING: each["ring"]}
+                      for each in caches), call.live())
+    new_caches = tuple(
+        {"full": stacks[FULL], "ring": stacks[SLIDING], "counts": words}
+        for stacks, words in zip(shards, decoder.folded(caches, counted)))
+    return decoder.head(params, x, c, logits_at), back(new_caches)
 
 
 def _import_kernel():

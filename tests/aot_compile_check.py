@@ -214,7 +214,9 @@ def main() -> int:
     # 128 and 256 with it, all reading the whole slot, the top one of the
     # engine's read windows. Then the other window at 8 x 2048, the
     # first 1024 rows, where the layer reads fewer rows than the carried
-    # stack holds, for the whole chunk, and the decode step at both
+    # stack holds, for the whole chunk, and the decode step at both; then
+    # the decode over a pair of shards, two donated caches in and two out,
+    # at the top window, the one the engine runs it at
     no_shard_copy = no_copy_of(cache["k"])
     for rows in (64, 128, 256):
         check(f"prefill chunk of {rows} rows into one slot of 8 x 2048, "
@@ -225,6 +227,8 @@ def main() -> int:
         check(f"decode step of 8 lanes reading {window} of 8 x 2048, "
               "one device", partial(decode_step, 8, window),
               forbid=no_shard_copy)
+    check("decode step of 2 x 8 lanes reading 2048 of a pair of 8 x 2048, "
+          "one device", partial(decode_step, 8, 2048, 2), forbid=no_shard_copy)
 
     grouped_swiglu(check, sds, quick)
     if not quick:
@@ -253,13 +257,16 @@ def lower_chunk(sds, model, cfg, params, cache, rows, window=None):
         sds((), jnp.int32), sds((1,), jnp.int32))
 
 
-def lower_decode(sds, model, cfg, params, cache, lanes, window):
+def lower_decode(sds, model, cfg, params, cache, lanes, window, shards=1):
     """The engine's decode program without its sampling, lowered: one
-    row a lane, every lane of the shard."""
+    row a lane, every lane of the shard; with ``shards`` of 2, of a pair
+    of shards in one call, both caches donated."""
     def step(params, cache, tokens, lengths):
         return model.forward_with_cache(
             params, tokens[:, None], cache, lengths, cfg, rows=window)
 
+    if shards > 1:
+        cache, lanes = (cache,) * shards, shards * lanes
     return jax.jit(step, donate_argnums=(1,)).lower(
         params, cache, sds((lanes,), jnp.int32), sds((lanes,), jnp.int32))
 
@@ -366,11 +373,11 @@ def latent_chunks(check, sds):
 
 
 def window_pair(check, sds):
-    """The whole chunk and the decode step of
-    ``mellum2-12b-a2.5b.serve-ide-mix`` at its published widths, its two
-    periods of layers (one would make no scan, and nothing to bracket)
-    and its 16 x 8192 cache: neither may copy a whole stack of the full
-    layers' rows or of the rings."""
+    """The whole chunk, the decode step and the decode step over a pair
+    of shards of ``mellum2-12b-a2.5b.serve-ide-mix`` at its published
+    widths, its two periods of layers (one would make no scan, and
+    nothing to bracket) and its 16 x 8192 cache: none may copy a whole
+    stack of the full layers' rows or of the rings."""
     from ray_tpu.models import window_moe
 
     cfg, params, cache, lanes, max_seq, chunk = serving_cell(
@@ -385,6 +392,11 @@ def window_pair(check, sds):
           f"{lanes} x {max_seq}, published widths, one device",
           partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
                   max_seq),
+          forbid=rf"{no_stack_copy}|ragged-dot|grouped_swiglu")
+    check(f"window_moe decode step of 2 x {lanes} lanes reading {max_seq} "
+          f"of a pair of {lanes} x {max_seq}, published widths, one device",
+          partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
+                  max_seq, 2),
           forbid=rf"{no_stack_copy}|ragged-dot|grouped_swiglu")
 
 

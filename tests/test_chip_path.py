@@ -316,7 +316,8 @@ def test_smoke_rehearsal_walks_every_phase(tmp_path):
 def test_device_programs_compile_for_a_v5e(aot_compile):
     """Flash attention, the fused cross entropy, prefill chunks of 64,
     128 and 256 rows, the 256-row chunk reading the cache's first half
-    and the decode step reading that and every row, none of which
+    and the decode step reading that and every row, of one shard and
+    of a pair of shards in one call, none of which
     copies a whole cache leaf, the grouped SwiGLU of a chunk's experts
     at the two routed configurations' published widths (their smallest
     bucket: the kernel there and no ``ragged_dot``), and a one-layer
@@ -326,5 +327,6 @@ def test_device_programs_compile_for_a_v5e(aot_compile):
     if aot_compile.returncode == 77:
         pytest.skip(out.strip())
     assert aot_compile.returncode == 0, out
-    assert out.count("\nok  ") + out.startswith("ok  ") == 12, out
+    assert out.count("\nok  ") + out.startswith("ok  ") == 13, out
+    assert out.count("lanes reading") == 3 and out.count("a pair of") == 1
     assert out.count("ok   grouped SwiGLU") == 2, out

@@ -138,15 +138,18 @@ def test_engine_stats_equal_the_hand_count(tiny_params):
     # every request: 1 token off its prefill, 3 from decodes
     assert s["tokens_emitted"] == 4 * 4
     assert s["decode_lanes_active"] == 4 * 3
-    assert s["decode_lanes_total"] == s["decode_calls"] * eng.max_batch
-    # per shard: prompt A decodes alone while B prefills (3 calls), then
-    # B decodes alone (3 calls)
-    assert s["decode_calls"] == 12
+    # per shard: prompt A decodes alone while B prefills (3 decodes),
+    # then B decodes alone (3 decodes); the two shards go in step, so
+    # every one of the 6 calls runs over the pair, prepared, read and
+    # booked a shard
+    assert (s["decode_calls"], s["decode_shards"]) == (6, 12)
+    assert s["decode_lanes_total"] == s["decode_shards"] * eng.max_batch
+    assert s["decode_ahead"] == 5       # every call but the first
     counts = {n: v["count"] for n, v in s["phases"].items()}
     assert counts == {
         "llm.step": steps, "llm.prefill_dispatch": 12,
         "llm.first_token_sync": 4, "llm.decode_prepare": 12,
-        "llm.decode_dispatch": 12, "llm.decode_sync": 12,
+        "llm.decode_dispatch": 6, "llm.decode_sync": 12,
         "llm.decode_bookkeep": 12}
     inner = sum(v["seconds"] for n, v in s["phases"].items()
                 if n != "llm.step")

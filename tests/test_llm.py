@@ -218,10 +218,13 @@ def test_engine_chunk_and_its_three_buckets(engine_setup, asked, chunk,
         assert chunk == derived_prefill_chunk(
             jax.devices()[0].device_kind, 4, 256)
     # decode and the whole chunk at every read window, the smaller
-    # buckets at the top one, and the first token's few instructions
+    # buckets at the top one, and the first token's few instructions;
+    # max_slots is four shards' here, so the decode over a pair as well
+    # (at the top window alone)
     eng.warm_up()
     assert sorted(eng.compiled_programs()) == sorted(
         ["first_token"] + [f"decode_{w}" for w in windows]
+        + ["decode_256_x2"]
         + [f"prefill_{chunk}_{w}" for w in windows]
         + [f"prefill_{b}_256" for b in buckets[:-1]])
 
@@ -514,8 +517,11 @@ def _three_shards_of_mixed_lengths(eng, plain):
     assert len(eng.shards) == 3
     _run_dry(eng)
     s = eng.stats
-    # every decode but each shard's first found the one before it unread
-    assert s.decode_ahead == s.decode_calls - 3 and s.lanes_discarded == 0
+    # every decode found one before it unread but the first shard's
+    # first (its 3 tokens are in while the others' 40 and 33 prefill) and
+    # the third's (alone: the second's first rides the call of the pair)
+    assert s.decode_ahead == s.decode_calls - 2 and s.lanes_discarded == 0
+    assert s.decode_calls < s.decode_shards < 2 * s.decode_calls
     # up to the scratch row too
     long = GenRequest("long", _prompt(100), max_tokens=64)
     assert eng.add_request(long)
@@ -837,18 +843,29 @@ def test_a_sequence_that_outgrows_its_windows_gets_the_full_reads_tokens(
     assert eng._prefills_run == {(16, 64)}
 
 
-def test_a_warm_engine_lowers_nothing_in_any_window(engine_setup):
+@pytest.mark.parametrize("max_slots", [4, 8])
+def test_a_warm_engine_lowers_nothing_in_any_window(engine_setup, max_slots):
     """After ``warm_up()`` a mixed run of ``step()`` (prompts of one to
     six chunks, decodes that pass every window) lowers no program, and
-    ``compiled_programs()`` names every variant that ran."""
+    ``compiled_programs()`` names every variant that ran. An engine of
+    one shard for good warms no decode over a pair; one that may grow a
+    second warms it, at the top window, and runs it."""
     cfg, params = engine_setup
-    eng = LlamaEngine(cfg, params, **{**WINDOW_KW, "prefill_chunk": 32})
+    eng = LlamaEngine(cfg, params, **{**WINDOW_KW, "prefill_chunk": 32,
+                                      "max_slots": max_slots})
     assert (eng.buckets, eng.windows) == ([16, 32], [64, 128])
-    eng.warm_up()
-    # the whole chunk at every window, the bucket under it at the top
+    with _programs_lowered() as warmed:
+        eng.warm_up()
+    pair = max_slots == 8
+    # the whole chunk at every window, the bucket under it at the top,
+    # the first token, decode at every window; over a pair at the top
+    assert len(warmed) == 3 + 1 + 2 + pair
     assert eng._prefills_run == {(32, 64), (32, 128), (16, 128)}
     assert eng._decodes_run == set(eng.windows)
+    assert eng._pair_run == pair
+    assert eng.stats.snapshot()["decode_shards"] == 0   # counts nothing
     eng._prefills_run.clear(), eng._decodes_run.clear()
+    eng._pair_run = False
     reqs = [GenRequest(f"r{i}", _prompt(n, i), max_tokens=m)
             for i, (n, m) in enumerate(
                 [(3, 40), (100, 20), (40, 30), (70, 8), (17, 12), (90, 30)])]
@@ -862,8 +879,12 @@ def test_a_warm_engine_lowers_nothing_in_any_window(engine_setup):
     # the run reached every window of both programs
     assert eng._decodes_run == set(eng.windows)
     assert {w for _, w in eng._prefills_run} == set(eng.windows)
+    assert eng._pair_run == pair and len(eng.shards) == max_slots // 4
+    s = eng.stats
+    assert (s.decode_shards > s.decode_calls) == pair
     assert sorted(eng.compiled_programs()) == sorted(
         ["first_token"] + [f"decode_{w}" for w in eng._decodes_run]
+        + ["decode_128_x2"] * pair
         + [f"prefill_{b}_{w}" for b, w in eng._prefills_run])
 
 

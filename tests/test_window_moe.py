@@ -446,16 +446,19 @@ def test_engine_serves_the_family_under_continuous_batching(toy):
                                        req.generated).max() == 0.0
     s = eng.stats.snapshot()
     calls = s["prefill_chunks"] + s["decode_calls"]
+    # a decode over the pair of shards reads each shard's rows
+    reads = s["prefill_chunks"] + s["decode_shards"]
+    assert s["decode_calls"] < s["decode_shards"] < 2 * s["decode_calls"]
     # the rows that were somebody's: no padding, no idle lane
     rows = s["prefill_tokens"] + s["decode_lanes_active"]
     assert rows < s["prefill_rows"] + s["decode_lanes_total"]
     assert s["moe_assignments"] == rows * cfg.experts_per_token * cfg.n_layers
     assert s["moe_expert_slots"] == calls * cfg.n_experts * cfg.n_layers
     assert 0 < s["moe_experts_touched"] <= s["moe_expert_slots"]
-    assert s["attn_rows_full"] == calls * 256
+    assert s["attn_rows_full"] == reads * 256
     # a sliding layer's rows are its ring's whatever the window
-    assert 3 / 4 * ring * calls < s["attn_rows_read"] < (
-        3 / 4 * ring + 256 / 4) * calls + 1
+    assert 3 / 4 * ring * reads < s["attn_rows_read"] < (
+        3 / 4 * ring + 256 / 4) * reads + 1
     assert eng.stats.snapshot()["moe_assignments"] == s["moe_assignments"]
 
 
