@@ -24,6 +24,9 @@ __all__ = [
     "HYBRID_SSM_TINY",
     "llama",
     "moe_llama",
+    "parallel_moe",
+    "ParallelMoEConfig",
+    "PARALLEL_MOE_TINY",
     "vit",
     "ViTConfig",
     "VIT_B_16",
@@ -39,3 +42,20 @@ __all__ = [
     "MIXTRAL_8X7B",
     "MOE_TINY",
 ]
+
+# ``parallel_moe`` (like ``window_moe`` and ``latent_moe``, which it is
+# written on) starts importing its Pallas kernels when it is imported,
+# beside a replica's opening of its chip; a process that serves another
+# family imports this package too and is not to pay for that, so the
+# three names are looked up when they are first asked for
+_LAZY = {"parallel_moe": None, "ParallelMoEConfig": "ParallelMoEConfig",
+         "PARALLEL_MOE_TINY": "PARALLEL_MOE_TINY"}
+
+
+def __getattr__(name):
+    if name not in _LAZY:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    import importlib
+
+    module = importlib.import_module(".parallel_moe", __name__)
+    return module if _LAZY[name] is None else getattr(module, _LAZY[name])

@@ -43,6 +43,16 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (x32 * jax.lax.rsqrt(var + eps)).astype(dt) * weight.astype(dt)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """A LayerNorm with a gain and no bias: the mean is subtracted,
+    which ``rms_norm`` does not do; mean and variance in float32."""
+    dt = x.dtype
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)).astype(dt) * weight.astype(dt)
+
+
 def idle_position(max_seq: int) -> int:
     """The position of a decode lane that is nobody's (free, mid-prefill
     or past its last token): the cache's last row, which no live
@@ -151,12 +161,13 @@ def embed(params: Dict[str, Any], tokens: jax.Array, config) -> jax.Array:
 
 
 def final_rows(params: Dict[str, Any], x: jax.Array, config,
-               logits_at=None) -> jax.Array:
-    """The rows the head runs on, through the final norm: row
-    ``logits_at[b]`` of each sequence (B, 1, D), or all of them."""
+               logits_at=None, norm=rms_norm) -> jax.Array:
+    """The rows the head runs on, through the final norm (``norm``: the
+    family's): row ``logits_at[b]`` of each sequence (B, 1, D), or all
+    of them."""
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-    return rms_norm(x, params["final_norm"], config.norm_eps)
+    return norm(x, params["final_norm"], config.norm_eps)
 
 
 def head(params: Dict[str, Any], x: jax.Array, config,

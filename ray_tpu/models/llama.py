@@ -242,6 +242,21 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def apply_rope_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """``apply_rope`` for tables that turn ADJACENT pairs: dimensions
+    (2i, 2i + 1) by the angle of frequency i (the GPT-J convention),
+    where ``apply_rope`` pairs i with i + hd/2. x: (B, S, H, hd);
+    cos/sin: (B, S, hd/2). Each value's partner is a lane to its left or
+    right (two lane rolls and a select), so nothing is de-interleaved."""
+    x32 = x.astype(jnp.float32)
+    cos = jnp.repeat(cos, 2, axis=-1)[:, :, None, :]
+    sin = jnp.repeat(sin, 2, axis=-1)[:, :, None, :]
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1),
+                        jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
+
+
 def _attention_xla(q, k, v, config: LlamaConfig, *, causal: bool = True):
     """Grouped-query causal attention via einsum — fuses cleanly in XLA.
 
@@ -304,27 +319,51 @@ def _attention(q, k, v, config: LlamaConfig):
     return _attention_xla(q, k, v, config)
 
 
+def attention_mix(config: LlamaConfig, h: jax.Array,
+                  layer: Dict[str, jax.Array], cos, sin,
+                  mixer=_attention, rotate=apply_rope) -> jax.Array:
+    """The projections of GQA attention over a normed input ``h``
+    (B, S, D) -> what the sublayer adds to the stream: the one spelling
+    of the projections and their rotation, for train, prefill and decode
+    and for every family whose layers project so. ``mixer(q, k, v,
+    config)`` turns the rotated queries and keys and the values into the
+    attended rows (B, S, H, hd): whole-sequence causal attention, or a
+    closure over a cache. ``rotate(x, cos, sin)`` turns queries and
+    keys; a layer without a position term gives ``cos`` None and
+    nothing is turned."""
+    c = config
+    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
+    if cos is not None:
+        q = rotate(q, cos, sin)
+        k = rotate(k, cos, sin)
+    attn = mixer(q, k, v, c)
+    return jnp.einsum("bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+
+
 def attention_sublayer(config: LlamaConfig, x: jax.Array,
                        layer: Dict[str, jax.Array],
                        cos: jax.Array, sin: jax.Array,
                        mixer=_attention) -> jax.Array:
-    """Pre-norm GQA attention + residual: the one spelling of the
-    projections and their rotation, for train, prefill and decode and
-    for every family whose layers project so. ``mixer(q, k, v, config)``
-    turns the rotated queries and keys and the values into the attended
-    rows (B, S, H, hd): whole-sequence causal attention, or a closure
-    over a cache."""
+    """Pre-norm GQA attention + residual: ``attention_mix`` behind the
+    layer's own norm. A block whose halves share a norm calls
+    ``attention_mix`` on the normed rows and adds the residual itself
+    (``models/parallel_moe.py``)."""
     c = config
     with jax.named_scope("attn"):
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(c.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(c.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(c.dtype))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        attn = mixer(q, k, v, c)
-        return x + jnp.einsum(
-            "bshk,hkd->bsd", attn, layer["wo"].astype(c.dtype))
+        return x + attention_mix(c, h, layer, cos, sin, mixer)
+
+
+def mlp_mix(config: LlamaConfig, h: jax.Array,
+            layer: Dict[str, jax.Array]) -> jax.Array:
+    """The SwiGLU MLP over a normed input: what the sublayer adds."""
+    c = config
+    gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(c.dtype))
+    up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(c.dtype))
+    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                      layer["w_down"].astype(c.dtype))
 
 
 def mlp_sublayer(config: LlamaConfig, x: jax.Array,
@@ -333,11 +372,7 @@ def mlp_sublayer(config: LlamaConfig, x: jax.Array,
     c = config
     with jax.named_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-        gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(c.dtype))
-        up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(c.dtype))
-        return x + jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up,
-            layer["w_down"].astype(c.dtype))
+        return x + mlp_mix(c, h, layer)
 
 
 def block_fn(config: LlamaConfig, x: jax.Array, layer: Dict[str, jax.Array],

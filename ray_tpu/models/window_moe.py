@@ -68,6 +68,8 @@ from .llama import (
 from .llama import param_specs as dense_param_specs
 
 SLIDING, FULL = "sliding", "full"
+# a layer's shared expert (or several side by side), where it has one
+SHARED_WEIGHTS = ("shared_gate", "shared_up", "shared_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,31 +212,53 @@ def init_params(rng: jax.Array, config: WindowMoEConfig) -> Dict[str, Any]:
 
 
 # -- the sublayers -----------------------------------------------------
+def moe_mix(c: WindowMoEConfig, h, layer, experts, index, live=None):
+    """The routed feed-forward over a normed input ``h`` -> (what the
+    sublayer adds, counts int32[3]). ``layer``: this layer's router (and
+    shared expert, where it has one); ``experts``: every layer's expert
+    weights, stacked, of which this layer is ``index`` (the grouped
+    matmul takes the stack whole: ``ops/moe.py`` ``expert_ffn`` says
+    why); ``live`` (B, T): the rows to count."""
+    shared = {k: layer[k].astype(c.dtype) for k in SHARED_WEIGHTS
+              if k in layer}
+    return moe.moe_ffn_dropless(
+        {"router": layer["router"], **shared,
+         **{k: w.astype(c.dtype) for k, w in experts.items()}},
+        h, c.moe, layer=index, live=live)
+
+
 def moe_sublayer(c: WindowMoEConfig, x, layer, experts, index, live=None):
-    """Pre-norm routed feed-forward + residual -> (x, counts int32[3]).
-    ``layer``: this layer's norm and router; ``experts``: every layer's
-    expert weights, stacked, of which this layer is ``index`` (the
-    grouped matmul takes the stack whole: ``ops/moe.py`` ``expert_ffn``
-    says why); ``live`` (B, T): the rows to count."""
+    """Pre-norm routed feed-forward + residual -> (x, counts int32[3]):
+    ``moe_mix`` behind the layer's own norm."""
     with jax.named_scope("moe"):
         h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-        out, counts = moe.moe_ffn_dropless(
-            {"router": layer["router"],
-             **{k: w.astype(c.dtype) for k, w in experts.items()}},
-            h, c.moe, layer=index, live=live)
+        out, counts = moe_mix(c, h, layer, experts, index, live)
         return x + out, counts
 
 
+def pre_norm_block(c: WindowMoEConfig, pos, kind, x, layer, mixer, experts,
+                   index, live):
+    """A layer of ``kind`` as this family has it: attention and the
+    experts one after the other, each behind its own norm and adding its
+    own residual -> (x, counts)."""
+    with jax.named_scope("attn"):   # the table is the sublayer's
+        cos, sin = rope_cos_sin(c, kind, pos)
+    x = attention_sublayer(c, x, layer, cos, sin, mixer)
+    return moe_sublayer(c, x, layer, experts, index, live)
+
+
 def scan_periods(c: WindowMoEConfig, blocks, x, pos, attend, state=None,
-                 live=None):
+                 live=None, block=pre_norm_block, n_counted: int = 3):
     """The layers over ``x`` (B, T, D) at the positions ``pos`` (B, T):
     ``decoder.scan_layers`` over whole periods, a period's layers
     unrolled inside it (the stacked ``blocks`` as (periods, layers a
     period, ...), but the experts' weights, which stay stacked as they
     are). ``attend(kind, i, state, q, k, v) -> (the attended rows,
     state)`` is the mixer of layer ``i`` among the layers of its
-    ``kind``; ``live`` the rows ``moe_sublayer`` counts ->
-    (x, state, counts int32[3])."""
+    ``kind``; ``live`` the rows the experts count; ``block(c, pos, kind,
+    x, layer, mixer, experts, index, live) -> (x, counts int32[
+    n_counted])`` is one layer, ``pre_norm_block`` or another family's
+    -> (x, state, counts)."""
     period = c.period
     n = len(period)
     per = {kind: period.count(kind) for kind in (FULL, SLIDING)}
@@ -255,14 +279,12 @@ def scan_periods(c: WindowMoEConfig, blocks, x, pos, attend, state=None,
                 return attn
 
             layer = jax.tree_util.tree_map(lambda a: a[j], layers)
-            with jax.named_scope("attn"):   # the table is the sublayer's
-                cos, sin = rope_cos_sin(c, kind, pos)
-            x = attention_sublayer(c, x, layer, cos, sin, mixer)
-            x, counts = moe_sublayer(c, x, layer, experts, p * n + j, live)
+            x, counts = block(c, pos, kind, x, layer, mixer, experts,
+                              p * n + j, live)
             counted.append(counts)
         return x, state, sum(counted[1:], counted[0])
 
-    return decoder.scan_layers(step, x, state, scanned, len(COUNTERS))
+    return decoder.scan_layers(step, x, state, scanned, n_counted)
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array,
@@ -294,11 +316,12 @@ _SCRATCH_SLOTS = 8
 
 
 def init_cache(config: WindowMoEConfig, batch: int, max_seq: int,
-               chunk: int):
+               chunk: int, counters: int = 3):
     """``full``: k/v (full layers, B, KVH, max_seq, hd) by position;
     ``ring``: k/v (sliding layers, B, KVH, ring + _SCRATCH_SLOTS, hd),
     slot ``ring`` the idle lanes' scratch row; ``counts``: the device
-    words of ``COUNTERS``. ``chunk``: the most rows a call will write."""
+    words of ``COUNTERS`` (``counters`` of them). ``chunk``: the most
+    rows a call will write."""
     c = config
     n_full = c.layer_types.count(FULL)
     # the window and a chunk, to a multiple of 8; no more than the cache
@@ -310,7 +333,7 @@ def init_cache(config: WindowMoEConfig, batch: int, max_seq: int,
 
     return {"full": stacks(n_full, max_seq),
             "ring": stacks(c.n_layers - n_full, ring + _SCRATCH_SLOTS),
-            "counts": decoder.counter_words(len(COUNTERS))}
+            "counts": decoder.counter_words(counters)}
 
 
 def attn_rows_read(config: WindowMoEConfig, cache, rows: int) -> float:
@@ -363,18 +386,102 @@ def _ring_write(stack, new, layer, first, start_pos, ring: int, idle: int):
     return stack
 
 
-def _ring_mask(pos, start_pos, T: int, ring: int, slots: int, window: int):
-    """(B, T, slots): which slots of its ring each query attends to.
-    Slot r holds the largest position at or before the call's last row
-    that is congruent to r mod ring; it is attended to where that
-    position is no earlier than 0, no later than the query's, and
-    inside its window. The scratch slots past ``ring`` never are."""
+def _ring_held(start_pos, T: int, ring: int, slots: int):
+    """(held (B, slots) int32, ok (B, slots) bool): slot r holds the
+    largest position at or before the call's last row that is congruent
+    to r mod ring; ``ok`` where that is a position of the sequence (no
+    earlier than 0) and the slot is one of the ring's (the scratch slots
+    past ``ring`` hold nobody's)."""
     last = (start_pos + T - 1)[:, None]                          # (B, 1)
     r = jnp.arange(slots)[None, :]
     held = last - (last - r) % ring                              # (B, slots)
+    return held, (r < ring) & (held >= 0)
+
+
+def _ring_mask(pos, start_pos, T: int, ring: int, slots: int, window: int):
+    """(B, T, slots): which slots of its ring each query attends to:
+    those that hold (``_ring_held``) a position no later than the
+    query's and inside its window."""
+    held, ok = _ring_held(start_pos, T, ring, slots)
     behind = pos[:, :, None] - held[:, None, :]              # (B, T, slots)
-    ok = (r < ring) & (held >= 0)
     return ok[:, None, :] & (behind >= 0) & (behind < window)
+
+
+def cached_periods(c: WindowMoEConfig, blocks, x, call: decoder.Call,
+                   caches: tuple, *, block=pre_norm_block,
+                   n_counted: int = 3, tiled: bool = False):
+    """The layers of one cached call over this family's caches (the
+    ``init_cache`` of each shard) -> (x, every shard's stacks by kind
+    with the call's rows written, the layers' counts, scored). A full
+    layer reads the call's read window of rows by position, a sliding
+    layer its ring; the stacks and what the layers count ride in the
+    period scan's carry.
+
+    ``tiled``: a call of more than one row a sequence (a chunk) attends
+    tile by tile on the chip (``ops/pallas_chunk_attention.py``, wherever
+    it can tile the shapes) and no score of the chunk's rows x the
+    cache's rows x the heads exists in HBM; ``scored`` is then (B, T)
+    int32, the ring slots each row of the call was scored against in one
+    sliding layer (every slot, for a decode call or untileable shapes).
+    Without it (Mellum2's programs, as they were) every call scores its
+    rows against every row read, and ``scored`` is None."""
+    slots = caches[0]["ring"]["k"].shape[3]
+    ring = slots - _SCRATCH_SLOTS
+    ring_write = partial(_ring_write, ring=ring,
+                         idle=decoder.idle_position(call.max_seq))
+    chunk = None
+    if tiled and call.T > 1:
+        # imported where it is asked for: a family that does not ask
+        # (Mellum2) starts without it
+        from ray_tpu.ops import pallas_chunk_attention as chunk
+        if chunk.untileable(call.T, c.n_heads, c.n_kv_heads, c.head_dim,
+                            (ring, call.window)) is not None:
+            chunk = None
+    if chunk is not None:
+        scale = 1.0 / math.sqrt(c.head_dim)
+        # a kind's rows read, its writer, its attention's scope, the
+        # position each row read holds and the window behind a query
+        held, ok = _ring_held(call.start_pos, call.T, ring, ring)
+        reads = {
+            FULL: (call.window, write_rows, "attn_cached",
+                   jnp.broadcast_to(jnp.arange(call.window),
+                                    (call.B, call.window)), chunk.NO_WINDOW),
+            SLIDING: (ring, ring_write, "attn_window",
+                      jnp.where(ok, held, chunk.NOT_HELD), c.sliding_window)}
+        scored = chunk.scored_slots(reads[SLIDING][3], call.start_pos,
+                                    call.T, c.sliding_window)
+
+        def attention(part, q, k_c, v_c, held, window):
+            return chunk.chunk_attention(
+                q, k_c, v_c, held, part.start_pos, window=window, scale=scale)
+    else:
+        # a kind's rows read, its writer, its attention's scope and mask
+        reads = {FULL: (call.window, write_rows, "attn_cached", None, None),
+                 SLIDING: (slots, ring_write, "attn_window",
+                           _ring_mask(call.pos, call.start_pos, call.T, ring,
+                                      slots, c.sliding_window), None)}
+        scored = jnp.full((call.B, call.T), slots, jnp.int32)
+
+        def attention(part, q, k_c, v_c, mask, _):
+            return _attention_cached(q, k_c, v_c, part.pos, c, mask=mask)
+
+    def attend(kind, i, shards, q, k, v):
+        n, write, scope, seen, window = reads[kind]
+
+        def one(part, stacks, q, k, v, seen):
+            k_c, v_c, new = write_and_read(
+                stacks[kind], k, v, i, part, n, write)
+            with jax.named_scope(scope):
+                attn = attention(part, q, k_c, v_c, seen, window)
+            return attn, {**stacks, kind: new}
+
+        return call.by_shard(one, shards, q, k, v, seen)
+
+    x, shards, counted = scan_periods(
+        c, blocks, x, call.pos, attend,
+        tuple({FULL: each["full"], SLIDING: each["ring"]}
+              for each in caches), call.live(), block, n_counted)
+    return x, shards, counted, scored if tiled else None
 
 
 def forward_with_cache(
@@ -400,40 +507,21 @@ def forward_with_cache(
     first cache's words."""
     c = config
     caches, back = decoder.caches_of(cache)
-    max_seq = caches[0]["full"]["k"].shape[3]
-    slots = caches[0]["ring"]["k"].shape[3]
-    ring = slots - _SCRATCH_SLOTS
-    call = decoder.Call(tokens, start_pos, max_seq, slot=slot,
-                        logits_at=logits_at, rows=rows, shards=len(caches))
-    ring_mask = _ring_mask(call.pos, start_pos, call.T, ring, slots,
-                           c.sliding_window)
-    ring_write = partial(_ring_write, ring=ring,
-                         idle=decoder.idle_position(max_seq))
+    call = decoder.Call(tokens, start_pos, caches[0]["full"]["k"].shape[3],
+                        slot=slot, logits_at=logits_at, rows=rows,
+                        shards=len(caches))
+    x, shards, counted, _ = cached_periods(
+        c, params["blocks"], decoder.embed(params, tokens, c), call, caches)
+    return decoder.head(params, x, c, logits_at), back(
+        new_caches(caches, shards, counted))
 
-    # a kind's rows read, its writer, its attention's scope and mask
-    reads = {FULL: (call.window, write_rows, "attn_cached", None),
-             SLIDING: (slots, ring_write, "attn_window", ring_mask)}
 
-    def attend(kind, i, shards, q, k, v):
-        n, write, scope, mask = reads[kind]
-
-        def one(part, stacks, q, k, v, mask):
-            k_c, v_c, new = write_and_read(
-                stacks[kind], k, v, i, part, n, write)
-            with jax.named_scope(scope):
-                attn = _attention_cached(q, k_c, v_c, part.pos, c, mask=mask)
-            return attn, {**stacks, kind: new}
-
-        return call.by_shard(one, shards, q, k, v, mask)
-
-    x, shards, counted = scan_periods(
-        c, params["blocks"], decoder.embed(params, tokens, c), call.pos,
-        attend, tuple({FULL: each["full"], SLIDING: each["ring"]}
-                      for each in caches), call.live())
-    new_caches = tuple(
+def new_caches(caches: tuple, shards: tuple, counted) -> tuple:
+    """Every shard's cache behind a call: its stacks as the call left
+    them, ``counted`` folded into the first's words."""
+    return tuple(
         {"full": stacks[FULL], "ring": stacks[SLIDING], "counts": words}
         for stacks, words in zip(shards, decoder.folded(caches, counted)))
-    return decoder.head(params, x, c, logits_at), back(new_caches)
 
 
 def _import_kernel():

@@ -135,6 +135,10 @@ class MoEConfig:
     scoring: str = "softmax"
     routed_scale: float = 1.0
     held: Optional[Tuple[int, ...]] = None
+    # what the shared expert's result is multiplied by before it is
+    # added: 1 / n where the parameters stack n shared experts along the
+    # width and the layer adds their mean
+    shared_scale: float = 1.0
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
@@ -355,7 +359,12 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
             # chip of a deployment alike, so not a part of the routed sum
             gate = x @ params["shared_gate"]
             up = x @ params["shared_up"]
-            out = out + (jax.nn.silu(gate) * up) @ params["shared_down"]
+            shared = (jax.nn.silu(gate) * up) @ params["shared_down"]
+            if config.shared_scale != 1.0:
+                # several shared experts side by side along the width:
+                # their sum, scaled to their mean
+                shared = shared * jnp.asarray(config.shared_scale, x.dtype)
+            out = out + shared
     with jax.named_scope("moe_combine"):
         # what was asked for: a padded chunk's rows behind its tokens and
         # an idle decode lane are computed and not counted

@@ -235,6 +235,7 @@ def main() -> int:
         latent_chunks(check, sds)
         window_pair(check, sds)
         state_pair(check, sds)
+        parallel_chunks(check, sds)
 
     for n in (1, 4):
         check(f"train step, LLAMA_BENCH width x {cfg.n_layers} layer(s), "
@@ -398,6 +399,39 @@ def window_pair(check, sds):
           partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
                   max_seq, 2),
           forbid=rf"{no_stack_copy}|ragged-dot|grouped_swiglu")
+
+
+def parallel_chunks(check, sds):
+    """The programs of ``command-a-plus-05-2026.serve-rag`` at its
+    published widths (128 query heads over 8 key/value heads, 16 held
+    experts of 4096 x 4096, a 16 x 16 384 cache): the whole chunk at
+    both read windows, the two smaller buckets, the decode step at both.
+    A chunk program holds the kernel of ``ops/pallas_chunk_attention.py``
+    and no float32 score of heads x chunk rows x cache rows; none copies
+    a whole stack of rows or of rings."""
+    from ray_tpu.models import parallel_moe
+
+    cfg, params, cache, lanes, max_seq, chunk = serving_cell(
+        sds, "command-a-plus-05-2026.serve-rag", parallel_moe)
+    no_stack_copy = no_copy_of(cache["full"]["k"], cache["ring"]["k"])
+    ring = cache["ring"]["k"].shape[3] - 8
+    for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
+                         (chunk, max_seq // 2), (chunk, max_seq)):
+        # a score of the group's 16 heads (or all 128) x the call's rows
+        # x a layer's rows read, in any order of the leading axes
+        score = rf"f32\[\d[\d,]*,{rows},(?:{ring}|{ring + 8}|{window})\]"
+        check(f"parallel_moe prefill chunk of {rows} rows reading {window} "
+              f"of {lanes} x {max_seq}, published widths, one device",
+              partial(lower_chunk, sds, parallel_moe, cfg, params, cache, rows,
+                      window),
+              expect=("chunk_attention", "grouped_swiglu_gate_up"),
+              forbid=rf"{no_stack_copy}|ragged-dot|{score}")
+    for window in (max_seq // 2, max_seq):
+        check(f"parallel_moe decode step of {lanes} lanes reading {window} of "
+              f"{lanes} x {max_seq}, published widths, one device",
+              partial(lower_decode, sds, parallel_moe, cfg, params, cache,
+                      lanes, window),
+              forbid=rf"{no_stack_copy}|ragged-dot|grouped_swiglu")
 
 
 def state_pair(check, sds):
