@@ -24,14 +24,18 @@ the rows by position of a full one, the period scan and the call over
 them are ``models/window_moe.py``'s (``cached_periods``), handed this
 family's block in the place of its own; the projections are
 ``llama.attention_mix``'s and the experts ``window_moe.moe_mix``'s, each
-given the one normed input. A chunk call attends tile by tile on the
-chip (``ops/pallas_chunk_attention.py``): at 128 query heads a 1024-row
-chunk's float32 scores against 8192 cache rows would be 4.3 GB a layer.
+given the one normed input. Which form a call's attention takes is
+``cached_periods``' choice by the call's shapes, as for its own family:
+at the published widths a chunk call attends tile by tile on the chip
+(``ops/pallas_chunk_attention.py``; at 128 query heads a 1024-row
+chunk's float32 scores against 8192 cache rows would be 4.3 GB a
+layer).
 
 **Counters** (``COUNTERS``, in the cache's ``counts``): the ``moe_*``
 three of ``EngineStats`` (held experts only), ``moe_assignments_all``
 (every live row's ``experts_per_token``, so the held share of the
-routing is read and not assumed), and of the sliding layers' attention
+routing is read and not assumed), and ``window_moe.ATTN_COUNTERS``,
+which ``cached_periods`` counts of the sliding layers' attention:
 ``attn_window_pairs_scored`` (the (live query row, ring slot) pairs a
 call computed a score for) and ``attn_window_pairs_visible`` (those of
 them inside the query's window: what a banded read would keep), each
@@ -50,7 +54,6 @@ decode for a second), so their bytes stand as ``read_beside``.
 from __future__ import annotations
 
 import dataclasses
-import threading
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -108,8 +111,8 @@ PARALLEL_MOE_TINY = ParallelMoEConfig(
     expert_dim=32, held_experts=(4, 5, 6, 7), n_shared_experts=2,
 )
 
-COUNTERS = (*window_moe.COUNTERS, "moe_assignments_all",
-            "attn_window_pairs_scored", "attn_window_pairs_visible")
+COUNTERS = (*window_moe.MOE_COUNTERS, "moe_assignments_all",
+            *window_moe.ATTN_COUNTERS)
 
 
 def chunk_terms(config: ParallelMoEConfig, max_seq: int) -> Dict[str, float]:
@@ -237,36 +240,14 @@ def forward_with_cache(
     rows: Optional[int] = None,
 ):
     """``window_moe.forward_with_cache``'s signature, meaning and cache,
-    with this family's block, a chunk's attention tiled on the chip, the
-    sliding layers' pairs counted and the tied head."""
+    with this family's block and the tied head."""
     c = config
     caches, back = decoder.caches_of(cache)
     call = decoder.Call(tokens, start_pos, caches[0]["full"]["k"].shape[3],
                         slot=slot, logits_at=logits_at, rows=rows,
                         shards=len(caches))
-    x, shards, counted, scored = window_moe.cached_periods(
+    x, shards, counted = window_moe.cached_periods(
         c, params["blocks"], decoder.embed(params, tokens, c), call, caches,
-        block=parallel_block, n_counted=4, tiled=True)
-    with jax.named_scope("layers"):     # counted beside the scan
-        live = call.live()
-        # a live row sees the rows of its window that exist: every one
-        # of them is in its ring (``window_moe.init_cache``)
-        visible = jnp.where(live, jnp.minimum(call.pos + 1, c.sliding_window),
-                            0).sum()
-        pairs = jnp.stack([jnp.where(live, scored, 0).sum(), visible])
-        counted = jnp.concatenate([
-            counted, (pairs * c.layer_types.count(SLIDING)).astype(jnp.int32)])
+        block=parallel_block, n_counted=4)
     return head(params, x, c, logits_at), back(
         window_moe.new_caches(caches, shards, counted))
-
-
-def _import_kernels():
-    from ray_tpu.ops import pallas_chunk_attention  # noqa: F401
-
-
-# Pallas takes 1.2 s to import on a replica's host and a chunk program's
-# first trace needs it: the import runs beside the chip's opening, as
-# the other served families' does (``window_moe``'s own thread imports
-# the grouped matmul's kernel)
-threading.Thread(target=_import_kernels, name="import-chunk-kernel",
-                 daemon=True).start()

@@ -13,6 +13,12 @@ the layer scan, the counters' words) and on ``models/llama.py``'s
 attention sublayer, whose mixer here writes and reads this family's
 cache: a full layer is the llama family's cached attention at a head
 size of its own, a sliding layer the same einsums under another mask.
+A prefill chunk whose shapes tile (the published widths: 8 query heads
+a key/value head of 128, a ring of 3072 slots, chunks of 512 to 2048
+rows) attends through ``ops/pallas_chunk_attention.py`` instead, the
+same arithmetic a tile at a time with the blocks of slots no row of the
+tile sees skipped (``cached_periods`` chooses by the shapes, for this
+family and ``models/parallel_moe.py`` alike).
 
 **The cache is not one pair of stacks.** A full layer keeps rows by
 position, ``(Lf, B, KVH, max_seq, hd)`` as the llama family does. A
@@ -27,8 +33,10 @@ until the sequence overwrites them (that is what the ring's extra chunk
 of rows is for). One slot more, ``ring`` itself, is the scratch row of
 idle decode lanes (the idle position is no live sequence's): position
 mod ring would land in a live row of a lane that is mid-prefill. Beside
-the rows rides ``counts``, the ``moe_*`` counters of ``EngineStats``
-accumulated on the device.
+the rows rides ``counts``, the words of ``COUNTERS`` accumulated on the
+device: the ``moe_*`` three of ``EngineStats`` and, of the sliding
+layers' attention, the pairs scored and the pairs visible
+(``cached_periods``).
 
 **What the engine's chunk rule is told.** The experts' three matrices
 hold most of a layer's bytes (95 % at 64 experts of 3 x 2304 x 896
@@ -309,14 +317,18 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
 
 
 # -- the cache ---------------------------------------------------------
-COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots")
+MOE_COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots")
+# what ``cached_periods`` counts of the sliding layers' attention, behind
+# whatever the family's layers count
+ATTN_COUNTERS = ("attn_window_pairs_scored", "attn_window_pairs_visible")
+COUNTERS = (*MOE_COUNTERS, *ATTN_COUNTERS)
 # slots a ring has past its rows: the first is the idle lanes' scratch
 # row, the rest keep the rows a multiple of 8
 _SCRATCH_SLOTS = 8
 
 
 def init_cache(config: WindowMoEConfig, batch: int, max_seq: int,
-               chunk: int, counters: int = 3):
+               chunk: int, counters: int = len(COUNTERS)):
     """``full``: k/v (full layers, B, KVH, max_seq, hd) by position;
     ``ring``: k/v (sliding layers, B, KVH, ring + _SCRATCH_SLOTS, hd),
     slot ``ring`` the idle lanes' scratch row; ``counts``: the device
@@ -346,11 +358,16 @@ def attn_rows_read(config: WindowMoEConfig, cache, rows: int) -> float:
     return (n_full * rows + n_ring * ring) / config.n_layers
 
 
-# the ``moe_*`` counters one cache shard has accumulated
+# what the programs that wrote one cache shard have counted
 read_counters = partial(decoder.read_counters, names=COUNTERS)
 
 
-def _ring_write(stack, new, layer, first, start_pos, ring: int, idle: int):
+# jitted, so that the keys and the values of every sliding layer of a
+# period trace and lower one writer between them (a lane's update is a
+# dozen operations, a decode call has 16 or 32 lanes, and a replica's
+# start pays for every one it traces); the compiler inlines the calls
+@partial(jax.jit, static_argnames=("ring", "idle"))
+def _ring_write(stack, new, layer, first, start_pos, *, ring: int, idle: int):
     """``new`` (B, T, KVH, hd) into the ring stack at layer ``layer``:
     sequence b's row t to slot (start_pos[b] + t) mod ring of cache row
     ``first + b``. One row (a decode) is one update, an idle lane's
@@ -409,30 +426,38 @@ def _ring_mask(pos, start_pos, T: int, ring: int, slots: int, window: int):
 
 def cached_periods(c: WindowMoEConfig, blocks, x, call: decoder.Call,
                    caches: tuple, *, block=pre_norm_block,
-                   n_counted: int = 3, tiled: bool = False):
+                   n_counted: int = 3):
     """The layers of one cached call over this family's caches (the
     ``init_cache`` of each shard) -> (x, every shard's stacks by kind
-    with the call's rows written, the layers' counts, scored). A full
-    layer reads the call's read window of rows by position, a sliding
-    layer its ring; the stacks and what the layers count ride in the
-    period scan's carry.
+    with the call's rows written, what the call counted: the layers'
+    ``n_counted`` words, then ``ATTN_COUNTERS``' two). A full layer
+    reads the call's read window of rows by position, a sliding layer
+    its ring; the stacks and what the layers count ride in the period
+    scan's carry.
 
-    ``tiled``: a call of more than one row a sequence (a chunk) attends
-    tile by tile on the chip (``ops/pallas_chunk_attention.py``, wherever
-    it can tile the shapes) and no score of the chunk's rows x the
-    cache's rows x the heads exists in HBM; ``scored`` is then (B, T)
-    int32, the ring slots each row of the call was scored against in one
-    sliding layer (every slot, for a decode call or untileable shapes).
-    Without it (Mellum2's programs, as they were) every call scores its
-    rows against every row read, and ``scored`` is None."""
+    **Which form of attention a call takes follows from its shapes.** A
+    call of more than one row a sequence (a chunk) whose shapes the
+    kernel tiles (``pallas_chunk_attention.untileable``: every published
+    width) attends tile by tile on the chip, and no score of the chunk's
+    rows x the cache's rows x the heads exists in HBM. A decode call,
+    and a chunk at shapes that do not tile (the toy presets of most
+    tests), score their rows against every row read
+    (``llama._attention_cached`` under the same masks). Whichever family
+    hands in its block gets the same choice.
+
+    The two words counted here are, of the sliding layers, the (live
+    query row, ring slot) pairs the call computed a score for, visible
+    or masked (every slot of the ring for the whole matrix; the slots of
+    the blocks a row's tile visits for the kernel), and those of them
+    inside the row's window."""
     slots = caches[0]["ring"]["k"].shape[3]
     ring = slots - _SCRATCH_SLOTS
     ring_write = partial(_ring_write, ring=ring,
                          idle=decoder.idle_position(call.max_seq))
     chunk = None
-    if tiled and call.T > 1:
-        # imported where it is asked for: a family that does not ask
-        # (Mellum2) starts without it
+    if call.T > 1:
+        # imported where a chunk is traced (the module's import thread
+        # has it by then): a decode program never needs it
         from ray_tpu.ops import pallas_chunk_attention as chunk
         if chunk.untileable(call.T, c.n_heads, c.n_kv_heads, c.head_dim,
                             (ring, call.window)) is not None:
@@ -477,11 +502,20 @@ def cached_periods(c: WindowMoEConfig, blocks, x, call: decoder.Call,
 
         return call.by_shard(one, shards, q, k, v, seen)
 
+    live = call.live()
     x, shards, counted = scan_periods(
         c, blocks, x, call.pos, attend,
         tuple({FULL: each["full"], SLIDING: each["ring"]}
-              for each in caches), call.live(), block, n_counted)
-    return x, shards, counted, scored if tiled else None
+              for each in caches), live, block, n_counted)
+    with jax.named_scope("layers"):     # counted beside the scan
+        # a live row sees the rows of its window that exist: every one
+        # of them is in its ring (``init_cache``)
+        visible = jnp.where(live, jnp.minimum(call.pos + 1, c.sliding_window),
+                            0).sum()
+        pairs = jnp.stack([jnp.where(live, scored, 0).sum(), visible])
+        counted = jnp.concatenate([
+            counted, (pairs * c.layer_types.count(SLIDING)).astype(jnp.int32)])
+    return x, shards, counted
 
 
 def forward_with_cache(
@@ -510,7 +544,7 @@ def forward_with_cache(
     call = decoder.Call(tokens, start_pos, caches[0]["full"]["k"].shape[3],
                         slot=slot, logits_at=logits_at, rows=rows,
                         shards=len(caches))
-    x, shards, counted, _ = cached_periods(
+    x, shards, counted = cached_periods(
         c, params["blocks"], decoder.embed(params, tokens, c), call, caches)
     return decoder.head(params, x, c, logits_at), back(
         new_caches(caches, shards, counted))
@@ -524,16 +558,18 @@ def new_caches(caches: tuple, shards: tuple, counted) -> tuple:
         for stacks, words in zip(shards, decoder.folded(caches, counted)))
 
 
-def _import_kernel():
+def _import_kernels():
+    from ray_tpu.ops import pallas_chunk_attention  # noqa: F401
     from ray_tpu.ops import pallas_grouped_matmul  # noqa: F401
 
 
 # Pallas takes 1.2 s to import on a replica's host, a chunk program's
-# first trace needs it (``moe.expert_ffn``'s kernel), and ``setup_s`` is
-# a metric with a bound. A process that imports this module to serve
-# goes on to open its chip, and the import runs beside that, as
-# ``models/latent_moe.py``'s does. It hides less than hoped: the opening
-# read 1.2 s longer with the import beside it (PERF.md section 6,
-# PR 53); what the thread saves is the first chunk trace's wait.
-threading.Thread(target=_import_kernel, name="import-grouped-kernel",
+# first trace needs it (``moe.expert_ffn``'s kernel and the chunk's
+# attention), and ``setup_s`` is a metric with a bound. A process that
+# imports this module to serve goes on to open its chip, and the import
+# runs beside that, as ``models/latent_moe.py``'s does. It hides less
+# than hoped: the opening read 1.2 s longer with the import beside it
+# (PERF.md section 6, PR 53); what the thread saves is the first chunk
+# trace's wait.
+threading.Thread(target=_import_kernels, name="import-chunk-kernels",
                  daemon=True).start()

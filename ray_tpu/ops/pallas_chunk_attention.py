@@ -3,10 +3,14 @@
 A prefill chunk's T rows attend to the rows their sequence's cache
 holds. ``llama._attention_cached`` scores every (head, query row, cache
 row) in float32 in HBM, which at 128 query heads, a 1024-row chunk and
-8192 cache rows is 4.3 GB a layer. This kernel is the same arithmetic
-with a tile's score, its running softmax and its accumulator kept in
-VMEM, and ``_attention_cached`` is its numerical reference
-(tests/test_parallel_block.py).
+8192 cache rows is 4.3 GB a layer (0.8 GB at 32 heads, 2048 rows and a
+ring of 3080 slots). This kernel is the same arithmetic with a tile's
+score, its running softmax and its accumulator kept in VMEM, and
+``_attention_cached`` is its numerical reference
+(tests/test_parallel_block.py, tests/test_window_moe.py). Which calls
+take it is ``window_moe.cached_periods``' choice, by ``untileable``
+alone: every family that serves through that function, at any shapes
+that tile.
 
 **One function for both kinds of cache row.** The caller says of every
 cache slot which position it holds (``held`` (B, S) int32: a row by
@@ -27,7 +31,8 @@ Design:
   of ``_BLOCK`` cache rows), the blocks innermost. A grid step holds the
   tile's queries of ALL the G = H / KVH heads of the group (G x tile x
   hd) and one block of keys and values, which those G heads share: a
-  block is fetched once for 16 heads' scores at the published widths.
+  block is fetched once for the group's scores (16 heads at
+  command-a-plus's published widths, 8 at Mellum2's).
 - **Blocks no query of the tile can see are skipped** (above the
   diagonal; behind the window; a ring's slots outside every query's
   window): a table of the visible (tile, block) pairs, made from

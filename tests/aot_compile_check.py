@@ -373,22 +373,46 @@ def latent_chunks(check, sds):
                       lanes, window), forbid=no_leaf_copy)
 
 
+def tiled_chunks(check, sds, label, model, cfg, params, cache, lanes,
+                 max_seq, chunk) -> str:
+    """The chunk programs of a family that serves through
+    ``window_moe.cached_periods`` (the two smaller buckets at the top
+    read window, the whole chunk at both): each holds the kernels of
+    ``ops/pallas_chunk_attention.py`` and of the grouped SwiGLU, no
+    float32 score of heads x chunk rows x cache rows, and no copy of a
+    whole stack of rows or of rings -> the pattern of such a copy."""
+    no_stack_copy = no_copy_of(cache["full"]["k"], cache["ring"]["k"])
+    ring = cache["ring"]["k"].shape[3] - 8
+    for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
+                         (chunk, max_seq // 2), (chunk, max_seq)):
+        # a score of a group's heads (or of all) x the call's rows x a
+        # layer's rows read, in any order of the leading axes
+        score = rf"f32\[\d[\d,]*,{rows},(?:{ring}|{ring + 8}|{window})\]"
+        check(f"{label} prefill chunk of {rows} rows reading {window} of "
+              f"{lanes} x {max_seq}, published widths, one device",
+              partial(lower_chunk, sds, model, cfg, params, cache, rows,
+                      window),
+              expect=("chunk_attention", "grouped_swiglu_gate_up"),
+              forbid=rf"{no_stack_copy}|ragged-dot|{score}")
+    return no_stack_copy
+
+
 def window_pair(check, sds):
-    """The whole chunk, the decode step and the decode step over a pair
-    of shards of ``mellum2-12b-a2.5b.serve-ide-mix`` at its published
-    widths, its two periods of layers (one would make no scan, and
-    nothing to bracket) and its 16 x 8192 cache: none may copy a whole
-    stack of the full layers' rows or of the rings."""
+    """The programs of ``mellum2-12b-a2.5b.serve-ide-mix`` at its
+    published widths, its two periods of layers (one would make no scan,
+    and nothing to bracket) and its 16 x 8192 cache: the whole chunk at
+    both read windows and the two smaller buckets, the decode step and
+    the decode step over a pair of shards. A chunk program holds the
+    kernel of ``ops/pallas_chunk_attention.py`` (8 query heads a
+    key/value head) and no float32 score of heads x chunk rows x cache
+    rows; none may copy a whole stack of the full layers' rows or of the
+    rings."""
     from ray_tpu.models import window_moe
 
     cfg, params, cache, lanes, max_seq, chunk = serving_cell(
         sds, "mellum2-12b-a2.5b.serve-ide-mix", window_moe)
-    no_stack_copy = no_copy_of(cache["full"]["k"], cache["ring"]["k"])
-    check(f"window_moe prefill chunk of {chunk} rows reading {max_seq} of "
-          f"{lanes} x {max_seq}, published widths, one device",
-          partial(lower_chunk, sds, window_moe, cfg, params, cache, chunk,
-                  max_seq), expect=("grouped_swiglu_gate_up",),
-          forbid=rf"{no_stack_copy}|ragged-dot")
+    no_stack_copy = tiled_chunks(check, sds, "window_moe", window_moe, cfg,
+                                 params, cache, lanes, max_seq, chunk)
     check(f"window_moe decode step of {lanes} lanes reading {max_seq} of "
           f"{lanes} x {max_seq}, published widths, one device",
           partial(lower_decode, sds, window_moe, cfg, params, cache, lanes,
@@ -413,19 +437,8 @@ def parallel_chunks(check, sds):
 
     cfg, params, cache, lanes, max_seq, chunk = serving_cell(
         sds, "command-a-plus-05-2026.serve-rag", parallel_moe)
-    no_stack_copy = no_copy_of(cache["full"]["k"], cache["ring"]["k"])
-    ring = cache["ring"]["k"].shape[3] - 8
-    for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
-                         (chunk, max_seq // 2), (chunk, max_seq)):
-        # a score of the group's 16 heads (or all 128) x the call's rows
-        # x a layer's rows read, in any order of the leading axes
-        score = rf"f32\[\d[\d,]*,{rows},(?:{ring}|{ring + 8}|{window})\]"
-        check(f"parallel_moe prefill chunk of {rows} rows reading {window} "
-              f"of {lanes} x {max_seq}, published widths, one device",
-              partial(lower_chunk, sds, parallel_moe, cfg, params, cache, rows,
-                      window),
-              expect=("chunk_attention", "grouped_swiglu_gate_up"),
-              forbid=rf"{no_stack_copy}|ragged-dot|{score}")
+    no_stack_copy = tiled_chunks(check, sds, "parallel_moe", parallel_moe,
+                                 cfg, params, cache, lanes, max_seq, chunk)
     for window in (max_seq // 2, max_seq):
         check(f"parallel_moe decode step of {lanes} lanes reading {window} of "
               f"{lanes} x {max_seq}, published widths, one device",

@@ -399,19 +399,37 @@ def test_a_chunk_call_goes_through_the_kernel_and_equals_the_whole_matrix(
         assert rel_rms(logits, want[pos]) < TOL, pos
 
 
-def test_mellum2s_chunk_calls_stay_on_the_whole_matrix():
-    """``window_moe.forward_with_cache`` does not ask for the tiled form:
-    that family's programs are what they were."""
-    c = dataclasses.replace(
-        wm.WINDOW_MOE_TINY, dim=128, n_heads=4, n_kv_heads=2, head_size=128,
-        sliding_window=128, max_seq_len=1024, full_rope=None)
+WINDOW_TILEABLE = dataclasses.replace(
+    wm.WINDOW_MOE_TINY, dim=128, n_heads=4, n_kv_heads=2, head_size=128,
+    sliding_window=128, max_seq_len=1024, full_rope=None)
+
+
+@pytest.mark.parametrize("c, T, chunk, kernel, scores", [
+    (WINDOW_TILEABLE, 128, 128, True, "1x2x2x128x264xf32"),
+    (WINDOW_TILEABLE, 1, 128, False, "1x2x2x1x264xf32"),
+    (wm.WINDOW_MOE_TINY, 16, 16, False, "1x2x2x16x40xf32")],
+    ids=["tileable_chunk", "one_row", "toy_chunk"])
+def test_mellum2s_calls_take_the_kernel_where_their_shapes_tile(
+        c, T, chunk, kernel, scores):
+    """``window_moe.forward_with_cache`` passes no flag: a chunk whose
+    shapes tile holds ``chunk_attention`` and no float32 buffer of heads
+    x chunk rows x ring slots (``scores``, which the whole matrix makes);
+    a one-row call at the same widths, and a chunk at the toy preset's,
+    hold the whole matrix and no kernel."""
     params = jax.eval_shape(lambda: wm.init_params(jax.random.PRNGKey(0), c))
-    cache = jax.eval_shape(lambda: wm.init_cache(c, 1, 1024, 128))
+    cache = jax.eval_shape(lambda: wm.init_cache(c, 1, 1024, chunk))
+    slot = {} if T == 1 else {"slot": jnp.int32(0),
+                              "logits_at": jnp.zeros(1, jnp.int32)}
+    # with the operations' sources: interpreted, the kernel is inlined,
+    # and its name stands in where its operations come from
     text = jax.jit(lambda p, t, k, s: wm.forward_with_cache(
-        p, t, k, s, c, slot=jnp.int32(0), logits_at=jnp.zeros(1, jnp.int32))
-    ).lower(params, jax.ShapeDtypeStruct((1, 128), jnp.int32), cache,
-            jax.ShapeDtypeStruct((1,), jnp.int32)).as_text()
-    assert "chunk_attention" not in text and "1x2x2x128x264xf32" in text
+        p, t, k, s, c, **slot)).lower(
+            params, jax.ShapeDtypeStruct((1, T), jnp.int32), cache,
+            jax.ShapeDtypeStruct((1,), jnp.int32)).as_text(debug_info=True)
+    assert ("chunk_attention" in text) == kernel
+    assert (scores in text) != kernel
+    if kernel:      # nor a score against the rows by position
+        assert "1x2x2x128x1024xf32" not in text
 
 
 # ---------------------------------------------------------- the engine

@@ -21,6 +21,7 @@ from benchmarks.families import mellum_reference  # noqa: E402
 from ray_tpu.llm import GenRequest, LlamaEngine  # noqa: E402
 from ray_tpu.models import llama, window_moe as wm  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
+from ray_tpu.ops import pallas_chunk_attention as chunk_kernel  # noqa: E402
 
 CELL = "mellum2-12b-a2.5b.serve-ide-mix"
 
@@ -301,18 +302,19 @@ def test_full_layers_turn_with_the_default_table_where_no_yarn_is_given(toy):
                          - wm.forward(params, tokens, cfg)).max()) > 1e-3
 
 
-def through_the_cache(cfg, params, cache, tokens, prompt, chunk, slot, lanes):
-    """``prompt`` tokens by chunks into ``slot``, then the rest one by
-    one through the decode path beside idle lanes: [(position, logits)]."""
+def through_buckets(cfg, params, cache, tokens, buckets, slot, lanes):
+    """The prompt by calls of ``buckets`` [(rows, real tokens)] into
+    ``slot`` (a call of fewer real tokens than rows is padded), then the
+    rest one by one through the decode path beside idle lanes:
+    ([(position, logits)], cache)."""
     pre = jax.jit(lambda p, t, c, s, at: wm.forward_with_cache(
         p, t, c, s, cfg, slot=jnp.int32(slot), logits_at=at),
         donate_argnums=(2,))
     dec = jax.jit(lambda p, t, c, s: wm.forward_with_cache(p, t, c, s, cfg),
                   donate_argnums=(2,))
     max_seq, got, pos = cache["full"]["k"].shape[3], [], 0
-    while pos < prompt:
-        n = min(chunk, prompt - pos)
-        padded = np.zeros((1, chunk), np.int32)
+    for rows, n in buckets:
+        padded = np.zeros((1, rows), np.int32)
         padded[0, :n] = tokens[pos:pos + n]
         logits, cache = pre(params, padded, cache, np.array([pos], np.int32),
                             np.array([n - 1], np.int32))
@@ -327,6 +329,14 @@ def through_the_cache(cfg, params, cache, tokens, prompt, chunk, slot, lanes):
         got.append((pos, logits[slot, 0]))
         pos += 1
     return got, cache
+
+
+def through_the_cache(cfg, params, cache, tokens, prompt, chunk, slot, lanes):
+    """``prompt`` tokens by chunks of ``chunk`` rows (``through_buckets``,
+    the last one padded)."""
+    buckets = [(chunk, min(chunk, prompt - pos))
+               for pos in range(0, prompt, chunk)]
+    return through_buckets(cfg, params, cache, tokens, buckets, slot, lanes)
 
 
 @pytest.mark.parametrize("chunk", [8, 16])
@@ -397,6 +407,136 @@ def test_the_read_window_bounds_the_full_layers_alone(toy):
     assert wm.attn_rows_read(cfg, cache, 64) == (64 + 3 * 56) / 4
 
 
+# ----------------------------------- a chunk's attention in the kernel
+# Widths the kernel tiles, Mellum2's in kind: 8 query heads over one
+# key/value head of 128, YaRN full layers beside default sliding ones
+TILEABLE = dataclasses.replace(
+    wm.WINDOW_MOE_TINY, dim=128, n_heads=8, n_kv_heads=1, head_size=128,
+    sliding_window=128, max_seq_len=1024, dtype=jnp.float32,
+    param_dtype=jnp.float32)
+# 256, 128, 256, 128 rows and a padded 256 (60 real): the ring of 384
+# slots wraps twice; then six decodes
+BUCKETS = [(256, 256), (128, 128), (256, 256), (128, 128), (256, 60)]
+PROMPT, DECODES, LANES = sum(n for _, n in BUCKETS), 6, 2
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+
+def _small_tiles(patch):
+    """Tiles of 128 rows and blocks of 128 slots, so that calls this
+    small have blocks to skip (on the chip they are 512)."""
+    patch.setattr(chunk_kernel, "_TILE", 128)
+    patch.setattr(chunk_kernel, "_BLOCK", 128)
+
+
+@pytest.fixture(scope="module")
+def served():
+    """(tokens, a shorter prompt's, params, {form: (logits of the long
+    sequence, then of the short one served in the slot it left)}): the
+    same calls with the kernel interpreted and with it refused
+    (``_attention_cached`` under the masks)."""
+    c = TILEABLE
+    assert c.full_rope is not None and c.n_heads // c.n_kv_heads == 8
+    params = wm.init_params(jax.random.PRNGKey(3), c)
+    tokens = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(4), (PROMPT + DECODES,), 0, c.vocab_size))
+    short = tokens[300:300 + 170]
+    got = {}
+    for form in ("kernel", "refused"):
+        with pytest.MonkeyPatch.context() as patch:
+            _small_tiles(patch)
+            if form == "refused":
+                patch.setattr(chunk_kernel, "untileable",
+                              lambda *a: "refused")
+            cache = wm.init_cache(c, LANES, 1024, 256)
+            assert cache["ring"]["k"].shape[3] - 8 == 384
+            with jax.default_matmul_precision("highest"):
+                long, cache = through_buckets(c, params, cache, tokens,
+                                              BUCKETS, 1, LANES)
+                again, _ = through_buckets(
+                    c, params, cache, short, [(128, 128), (128, 40)], 1,
+                    LANES)
+            got[form] = (long, again)
+    return tokens, short, params, got
+
+
+@pytest.mark.parametrize("against", ["refused", "forward"])
+def test_chunks_through_the_kernel_equal_the_whole_matrix(served, against):
+    """Unequal buckets past the ring's wrap, a padded last chunk, decodes
+    beside an idle lane, then the slot reused by a shorter prompt: the
+    kernel's logits against the same calls with the kernel refused, and
+    against ``forward``'s one pass over the whole sequence."""
+    tokens, short, params, got = served
+    long, again = got["kernel"]
+    assert len(long) == len(BUCKETS) + DECODES and len(again) == 2 + 2
+    if against == "refused":
+        for mine, theirs in zip(got["kernel"], got["refused"]):
+            for (pos, a), (at, b) in zip(mine, theirs):
+                assert pos == at and rel_rms(a, b) < 1e-5, pos
+        return
+    with jax.default_matmul_precision("highest"):
+        for rows, sequence in ((long, tokens), (again, short)):
+            want = np.asarray(wm.forward(params, sequence[None], TILEABLE)[0])
+            for pos, logits in rows:
+                assert rel_rms(logits, want[pos]) < 1e-5, pos
+
+
+@pytest.mark.parametrize("form", ["kernel", "refused", "decode"])
+def test_the_pairs_scored_and_visible_equal_a_count_made_by_hand(
+        form, monkeypatch):
+    """One call's two counters. A chunk of 256 rows at position 256,
+    200 of them live, window 128, in a ring of 384 slots held in blocks
+    of 128: a tile of 128 rows visits the block of its own rows and the
+    one before, 256 slots; refused the kernel, every row is scored
+    against all 392 slots; a decode's live lane against 392, and at
+    position 5 it sees 6 rows. Each in every sliding layer."""
+    c = TILEABLE
+    _small_tiles(monkeypatch)
+    if form == "refused":
+        monkeypatch.setattr(chunk_kernel, "untileable", lambda *a: "refused")
+    params = wm.init_params(jax.random.PRNGKey(0), c)
+    cache = wm.init_cache(c, 2, 1024, 256)
+    sliding = c.layer_types.count(wm.SLIDING)
+    assert cache["counts"].shape == (len(wm.COUNTERS), 2) and sliding == 3
+    if form == "decode":
+        _, cache = wm.forward_with_cache(
+            params, np.zeros((2, 1), np.int32), cache,
+            jnp.asarray([1023, 5]), c)
+        live, scored, visible = 1, 392, 6
+    else:
+        _, cache = wm.forward_with_cache(
+            params, np.zeros((1, 256), np.int32), cache, jnp.asarray([256]),
+            c, slot=jnp.int32(1), logits_at=jnp.asarray([199]))
+        live, scored, visible = 200, 256 if form == "kernel" else 392, 128
+    got = wm.read_counters(cache)
+    assert list(got) == list(wm.COUNTERS)
+    assert got["attn_window_pairs_scored"] == live * scored * sliding
+    assert got["attn_window_pairs_visible"] == live * visible * sliding
+    assert got["moe_assignments"] == live * c.experts_per_token * c.n_layers
+
+
+@pytest.mark.parametrize("T, start, blocks", [
+    (2048, 2048, 3), (1024, 3072, 3), (512, 1536, 3), (2048, 0, 1),
+    (512, 1700, 4)], ids=["2048", "1024", "512", "first_chunk", "unaligned"])
+def test_the_slots_a_published_chunk_is_scored_against(T, start, blocks):
+    """Mellum2's ring of 3072 slots behind a window of 1024, in blocks of
+    512: a tile of 512 rows at an aligned start visits its own block and
+    the two before it, two thirds of what it scores visible; a first
+    chunk's first tile only its own; one that starts inside a block a
+    fourth."""
+    held, ok = wm._ring_held(jnp.asarray([start]), T, 3072, 3072)
+    scored = chunk_kernel.scored_slots(
+        jnp.where(ok, held, chunk_kernel.NOT_HELD), jnp.asarray([start]), T,
+        1024)
+    assert scored.shape == (1, T)
+    assert int(scored[0, 0]) == blocks * 512
+    if start >= 1024 and start % 512 == 0:
+        assert (np.asarray(scored) == 3 * 512).all()
+
+
 def test_layer_types_are_held_to_whole_periods():
     with pytest.raises(ValueError, match="layer_types names"):
         dataclasses.replace(wm.WINDOW_MOE_TINY, n_layers=3)
@@ -460,6 +600,10 @@ def test_engine_serves_the_family_under_continuous_batching(toy):
     assert 3 / 4 * ring * reads < s["attn_rows_read"] < (
         3 / 4 * ring + 256 / 4) * reads + 1
     assert eng.stats.snapshot()["moe_assignments"] == s["moe_assignments"]
+    # the toy widths do not tile: every live row of every call was scored
+    # against all of its ring and its scratch slots, in 3 layers of 4
+    assert s["attn_window_pairs_scored"] == rows * (ring + 8) * 3
+    assert 0 < s["attn_window_pairs_visible"] <= rows * cfg.sliding_window * 3
 
 
 @pytest.mark.parametrize("family, chunk", [("llama", 256),
