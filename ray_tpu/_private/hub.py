@@ -312,11 +312,15 @@ class StreamEntry:
     yielded object ids in order, consumer cursor for backpressure, and
     waiters blocked on indices not yet produced. ``t_walls`` holds, for
     each id, the producer's stamp of when it yielded the value (None
-    from a worker that sent none, and for the error ref): it rides the
-    STREAM_NEXT reply so the consumer can read the item's transit."""
+    from a worker that sent none, and for the error ref), and
+    ``t_hubs`` this process's stamp of when it handled the item's
+    STREAM_YIELD (None for the error ref): both ride the STREAM_NEXT
+    reply, beside the reply's own stamp, so the consumer can read the
+    item's transit and which side of the hub held it."""
 
     oids: List[bytes] = field(default_factory=list)
     t_walls: List[Optional[float]] = field(default_factory=list)
+    t_hubs: List[Optional[float]] = field(default_factory=list)
     ended: bool = False
     consumed: int = 0
     # the producer waits for credit (STREAM_YIELD's ``bound``, known
@@ -607,13 +611,16 @@ class Hub:
         # (the state plane itself is payload-driven: a "trace" field in
         # the message is the signal, so client-mode tracing works even
         # when the head's own env has sampling off)
-        from ..util.tracing import make_runtime_record, runtime_sample_rate
+        from ..util.tracing import (make_runtime_record,
+                                    runtime_sample_rate, wall_at)
 
         self._trace_on = runtime_sample_rate() > 0.0
         # pre-bound record builder: _emit_runtime_span runs per traced
         # hub stage — the per-call `from ..util.tracing import ...`
         # lookup was measurable at sampling 1.0 (tracing_overhead row)
         self._make_runtime_record = make_runtime_record
+        # a streamed item's stamps as they cross to its consumer
+        self._wall_at = wall_at
         # ---- sampling profiler (profiling.py): folded collapsed-stack
         # counts from every process's PROFILE_BATCH flushes, keyed
         # (pid, proc kind, thread domain, stage, task, stack). Bounded
@@ -1304,6 +1311,23 @@ class Hub:
         self._bm_credit_stalls = bm(
             "ray_tpu_stream_credit_stalls_total", "counter",
             "streaming-generator producers parked on backpressure credit")
+        # a streamed item's way through this process: items over replies
+        # is how many a STREAM_NEXT's reply carried (a consumer that fell
+        # behind is handed what queued up); found against parked is who
+        # waited for whom (the item for its consumer, or the consumer
+        # for the item)
+        self._bm_stream_items = bm(
+            "ray_tpu_stream_items_total", "counter",
+            "streaming-generator items yielded (STREAM_YIELD handled)")
+        self._bm_stream_replies = bm(
+            "ray_tpu_stream_next_replies_total", "counter",
+            "STREAM_NEXT replies that carried items")
+        self._bm_stream_found = bm(
+            "ray_tpu_stream_next_found_total", "counter",
+            "STREAM_NEXTs that found their item already yielded")
+        self._bm_stream_parked = bm(
+            "ray_tpu_stream_next_parked_total", "counter",
+            "STREAM_NEXTs parked until their item was yielded")
         self._bm_events_total = bm(
             "ray_tpu_events_total", "counter",
             "flight-recorder events recorded")
@@ -2576,16 +2600,19 @@ class Hub:
         )
         s.oids.append(p["object_id"])
         s.t_walls.append(p.get("t_wall"))
+        s.t_hubs.append(self._wall_at(time.monotonic()))
         s.bounded = bool(p.get("bound"))
+        self._bm_stream_items["value"] += 1
         for wconn, req_id in s.next_waiters.pop(idx, []):
             s.consumed = max(s.consumed, idx + 1)
-            self._reply(wconn, req_id, items=self._stream_items(s, idx, 1))
+            self._reply_stream_items(wconn, req_id, s, idx, 1)
         self._wake_credit_waiters(s)
 
     def _stream_items(self, s: StreamEntry, idx: int, limit: int) -> list:
         """The stream's items from ``idx`` on that are there, ``limit``
         at the most: (object id, the producer's yield stamp, the value
-        where the object holds it inline, else None). A consumer that
+        where the object holds it inline, else None, this process's
+        stamp of the item's STREAM_YIELD). A consumer that
         has fallen behind is handed what has queued up in one reply, and
         an inline value needs no GET of its own: a streamed token costs
         the hub one message, not three round trips."""
@@ -2594,8 +2621,19 @@ class Hub:
             e = self.objects.get(s.oids[j])
             inline = (e.payload if e is not None and e.ready
                       and e.kind == P.VAL_INLINE else None)
-            items.append((s.oids[j], s.t_walls[j], inline))
+            items.append((s.oids[j], s.t_walls[j], inline, s.t_hubs[j]))
         return items
+
+    def _reply_stream_items(self, conn, req_id: int, s: StreamEntry,
+                            idx: int, limit: int) -> int:
+        """Answer a STREAM_NEXT with the items that are there, stamped
+        as it is sent (``t_reply``: an item's ``t_hub`` to it is the
+        item's wait for its consumer to ask) -> how many it carried."""
+        items = self._stream_items(s, idx, limit)
+        self._bm_stream_replies["value"] += 1
+        self._reply(conn, req_id, items=items,
+                    t_reply=self._wall_at(time.monotonic()))
+        return len(items)
 
     def _on_stream_end(self, conn, p):
         s = self._stream(p["task_id"])
@@ -2615,8 +2653,9 @@ class Hub:
             idx = len(s.oids)
             s.oids.append(err_oid)
             s.t_walls.append(None)
+            s.t_hubs.append(None)
             for wconn, req_id in s.next_waiters.pop(idx, []):
-                self._reply(wconn, req_id, items=[(err_oid, None, None)])
+                self._reply(wconn, req_id, items=self._stream_items(s, idx, 1))
         s.ended = True
         for idx, waiters in list(s.next_waiters.items()):
             if idx >= len(s.oids):
@@ -2639,10 +2678,11 @@ class Hub:
         s = self._stream(p["task_id"])
         idx = p["index"]
         if idx < len(s.oids):
-            items = self._stream_items(
-                s, idx, 1 if s.bounded else p.get("batch", 1))
-            s.consumed = max(s.consumed, idx + len(items))
-            self._reply(conn, p["req_id"], items=items)
+            self._bm_stream_found["value"] += 1
+            sent = self._reply_stream_items(
+                conn, p["req_id"], s, idx,
+                1 if s.bounded else p.get("batch", 1))
+            s.consumed = max(s.consumed, idx + sent)
             self._wake_credit_waiters(s)
         elif s.ended:
             self._reply(conn, p["req_id"], end=True)
@@ -2652,11 +2692,13 @@ class Hub:
             if s.oids:
                 s.oids = []
                 s.t_walls = []
+                s.t_hubs = []
                 self._ended_streams.append(p["task_id"])
                 while len(self._ended_streams) > 10000:
                     old = self._ended_streams.popleft()
                     self.streams.pop(old, None)
         else:
+            self._bm_stream_parked["value"] += 1
             s.next_waiters.setdefault(idx, []).append((conn, p["req_id"]))
 
     def _on_stream_credit(self, conn, p):

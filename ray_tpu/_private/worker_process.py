@@ -207,8 +207,9 @@ class WorkerRuntime:
         from .ids import ObjectID
 
         # when the generator handed the value over, as an anchored wall
-        # stamp: the hub keeps it beside the object id and the consumer
-        # reads its transit off it (serve.stream_transit)
+        # stamp: the hub keeps it beside the object id, with its own
+        # stamp of this message, and the consumer reads the item's
+        # transit off them (serve.stream_transit, serve.stream_to_hub)
         t_wall = _t.wall_at(time.monotonic())
         oid = ObjectID.generate()
         kind, payload, size = self.client.encode_value(oid, value)

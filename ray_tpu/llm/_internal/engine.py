@@ -174,6 +174,11 @@ re-designed for XLA instead of wrapped:
   ``decode_shards`` / ``decode_calls`` how many shards a decode call
   advanced (1.0: never a pair; 2.0: always), and a pair's
   ``llm.decode_dispatch`` span says ``shards=2`` beside its ``rows``.
+  Why a computed lane stood empty is counted where the lanes are:
+  ``decode_lanes_prefilling`` (its request's prompt is not all in) and
+  ``decode_lanes_free`` (no request could have used it) make up
+  ``decode_lanes_total`` with ``decode_lanes_active``, and the span says
+  ``prefilling=`` and ``free=`` for its call.
   The jitted programs carry a ``sample`` scope next to the model's own
   (``kv_write``, ``kv_slice``, ``attn_cached``, ...).
 """
@@ -276,10 +281,17 @@ class GenRequest:
     # written by the request's own thread in ``LLMServer.generate_stream``
     # alone: when it had the first token in hand, and the seconds and
     # count of its tokens' waits between the batching loop's read of
-    # their step and that thread's ``q.get`` returning
+    # their step and that thread's ``q.get`` returning; of those seconds,
+    # the ones a token lay in the queue before the thread came back to
+    # ask for it (``backlog_s``: the rest is the thread's wake); and the
+    # seconds and count of its ``yield``s, from handing a token to the
+    # runtime to being resumed for the next
     first_yielded: float = 0.0
     handoff_s: float = 0.0
     handoff_n: int = 0
+    backlog_s: float = 0.0
+    yield_s: float = 0.0
+    yield_n: int = 0
 
     def phases(self) -> Dict[str, float]:
         """Seconds in ingress (route entry to replica entry), accept
@@ -336,6 +348,15 @@ class EngineStats:
         # where the window is chosen (``_prefill`` / ``_decode``)
         "attn_rows_read", "attn_rows_full",
         "decode_calls", "decode_lanes_active", "decode_lanes_total",
+        # why a lane a decode call computed stood empty, counted with the
+        # two above so that active + prefilling + free = total on every
+        # call: the slots of requests still in ``shard.prefilling``
+        # (admitted, the prompt not all in: the lane waits for the
+        # engine's chunks), and the rest, which no request could have
+        # used at that call (slots nobody holds: nothing was there to
+        # admit; and, one call a request, the slot of a request whose
+        # last token is dispatched and not read yet)
+        "decode_lanes_prefilling", "decode_lanes_free",
         # shards the decode calls advanced: one a call, two where a call
         # ran over a pair, so over ``decode_calls`` how often it did
         "decode_shards",
@@ -878,6 +899,9 @@ class LlamaEngine:
         stats = self.stats
         shards = [shard for shard, _ in group]
         live = sum(len(lanes) for _, lanes in group)
+        total = len(group) * self.max_batch
+        prefilling = sum(len(shard.prefilling) for shard in shards)
+        free = total - live - prefilling
         self.peak_active = max(self.peak_active, live)
         lens, temps = zip(*(self._prepare_decode(*each) for each in group))
         # ``attended``, where a trace records the span: the rows its live
@@ -887,7 +911,8 @@ class LlamaEngine:
             of_shard[slot] for of_shard, (_, lanes) in zip(lens, group)
             for slot, _ in lanes))} if recording() else {}
         with phase("llm.decode_dispatch", stats.phases, shard=shards[0].index,
-                   shards=len(group), rows=live, **seen):
+                   shards=len(group), rows=live, prefilling=prefilling,
+                   free=free, **seen):
             if len(group) == 1:
                 shard, = shards
                 shard.tokens, shard.cache, self._rng = self._decode(
@@ -905,7 +930,9 @@ class LlamaEngine:
         stats.decode_shards += len(group)
         stats.decode_ahead += any(s.unread is not None for s in shards)
         stats.decode_lanes_active += live
-        stats.decode_lanes_total += len(group) * self.max_batch
+        stats.decode_lanes_prefilling += prefilling
+        stats.decode_lanes_free += free
+        stats.decode_lanes_total += total
 
     def _dispatch_decodes(self) -> List[Optional[tuple]]:
         """Every shard's decode, the shards with lanes to decode two to

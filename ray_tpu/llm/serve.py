@@ -8,9 +8,23 @@ vLLM; requests stream tokens through the serve streaming-response path
 
 What a request spends outside the engine is recorded beside the
 engine's own stamps (``GenRequest.routed`` / ``received`` /
-``first_yielded``; ``engine_stats()["loop_phases"]``: ``serve.ingress``,
-``llm.accept``, ``llm.first_token_handoff``, ``llm.token_handoff``),
-always on, at the cost of a ``time.monotonic()`` and a float.
+``first_yielded``; ``engine_stats()["loop_phases"]``), always on, at the
+cost of two ``time.monotonic()`` a token and a few floats a request. In
+a request's order: ``serve.ingress`` (the handle's route entry to the
+replica's method), ``llm.accept`` (to the pending queue),
+``llm.first_token_handoff`` (the loop's read of the first token to the
+request's thread holding it), then for every token
+``llm.token_handoff`` (the loop having the step's tokens to the
+thread's ``q.get`` returning), which is ``llm.token_backlog`` (the
+token lay in the queue before the thread came back to ask: the thread
+was inside an earlier token's ``yield``) plus ``llm.token_wake`` (the
+thread was waiting: the loop's put and the interpreter's switch), and
+``llm.token_yield`` (``yield tok`` to the generator being resumed: the
+worker's encode, its STREAM_YIELD send, a bounded stream's wait for
+credit). Behind the ``yield`` the stretches are the runtime's
+(``DeploymentHandle.stream_stats()``, the hub's
+``ray_tpu_stream_*_total`` counters; ``util/tracing.py`` lists the whole
+chain).
 
 HTTP: `serve.run(build_llm_app(cfg))` exposes POST /<name> with JSON
 {"prompt_ids": [...], "max_tokens": N, "temperature": t, "stream": bool}
@@ -76,7 +90,9 @@ class LLMServer:
         self._loop_phases = tracing.PhaseStats()
         # what the requests' own threads fold in as each ends, under
         # self._lock: serve.ingress, llm.accept, llm.first_token_handoff
-        # (a count a request), llm.token_handoff (a count a token)
+        # (a count a request), llm.token_handoff and its two parts
+        # llm.token_backlog and llm.token_wake, llm.token_yield (a count
+        # a token)
         self._request_phases = tracing.PhaseStats()
         self._running = True
         self._loop_thread = threading.Thread(
@@ -330,6 +346,8 @@ class LLMServer:
             req.received = received or req.submitted
             self._pending.put(req)
         try:
+            # when this thread last came to the queue to wait
+            t_wait = time.monotonic()
             # the wait for the first token, to just before its yield
             with tracing.phase("llm.first_yield", request_id=rid):
                 item = q.get(timeout=120)
@@ -344,8 +362,18 @@ class LLMServer:
                 now = time.monotonic()
                 req.handoff_s += now - t_read
                 req.handoff_n += 1
+                if t_wait > t_read:
+                    # the token was there before this thread asked: it
+                    # lay in the queue that long (the rest of its
+                    # hand-off is this thread's wake)
+                    req.backlog_s += t_wait - t_read
                 req.first_yielded = req.first_yielded or now
                 yield tok
+                # resumed: the runtime has encoded and sent the token
+                # and waited for credit where the stream is bounded
+                t_wait = time.monotonic()
+                req.yield_s += t_wait - now
+                req.yield_n += 1
                 item = q.get(timeout=120)
         finally:
             with self._lock:
@@ -362,6 +390,12 @@ class LLMServer:
             add("llm.first_token_handoff",
                 req.first_yielded - req.first_token)
             add("llm.token_handoff", req.handoff_s, req.handoff_n)
+            # the hand-off in two: in the queue before the thread asked,
+            # and the thread's wake once it was waiting
+            add("llm.token_backlog", req.backlog_s, req.handoff_n)
+            add("llm.token_wake", req.handoff_s - req.backlog_s,
+                req.handoff_n)
+            add("llm.token_yield", req.yield_s, req.yield_n)
 
     @staticmethod
     def _emit_request_span(trace_ctx, req) -> None:

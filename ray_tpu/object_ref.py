@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from concurrent.futures import Future
 from typing import Any, Optional
 
@@ -129,12 +130,22 @@ class ObjectRefGenerator:
         self._task_id = task_id
         self._idx = 0
         # handed over by the hub and not yet returned: (object id, the
-        # producer's yield stamp, the inline value or None)
+        # producer's yield stamp, the inline value or None, the hub's
+        # stamp of the item's STREAM_YIELD)
         self._ready: collections.deque = collections.deque()
         # the producer's stamp of when it yielded the ref last returned
         # (an anchored wall time, tracing.wall_at); None where it sent
         # none. It came with the STREAM_NEXT reply: no message of its own
         self.last_yield_wall: Optional[float] = None
+        # the hub's two stamps of that ref, the same way: when it
+        # handled the item's STREAM_YIELD, and when it sent the reply
+        # that carried the item here. None from a hub that stamps neither
+        self.last_hub_wall: Optional[float] = None
+        self.last_reply_wall: Optional[float] = None
+        # seconds the consumer spent in the STREAM_NEXT round trip that
+        # brought the ref last returned; None where the ref came with an
+        # earlier one's reply (so a count of these is a count of replies)
+        self.last_next_wait_s: Optional[float] = None
 
     def __iter__(self):
         return self
@@ -145,6 +156,7 @@ class ObjectRefGenerator:
 
         client = worker.get_client()
         if not self._ready:
+            t0 = time.monotonic()
             reply = client.request(
                 P.STREAM_NEXT, {"task_id": self._task_id, "index": self._idx,
                                 "batch": self._BATCH}
@@ -152,7 +164,13 @@ class ObjectRefGenerator:
             if reply.get("end"):
                 raise StopIteration
             self._ready.extend(reply["items"])
-        oid, self.last_yield_wall, inline = self._ready.popleft()
+            self.last_reply_wall = reply.get("t_reply")
+            self.last_next_wait_s = time.monotonic() - t0
+        else:
+            self.last_next_wait_s = None
+        # a hub from before it stamped sends three fields an item
+        oid, self.last_yield_wall, inline, *t_hub = self._ready.popleft()
+        self.last_hub_wall = t_hub[0] if t_hub else None
         self._idx += 1
         if inline is not None:
             # the value came with the reply: ``get`` finds it here

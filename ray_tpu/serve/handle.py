@@ -251,25 +251,43 @@ class DeploymentResponseGenerator:
 
     Each item, once its value is in hand, adds the time since the
     replica's worker yielded it (the stamp the STREAM_NEXT reply
-    carries) to the handle's ``stream_stats()``; the stream's end sends
-    the one latency observation (or error count) a unary call's
-    ``result()`` sends. A stream the consumer abandons sends neither."""
+    carries), and its stretches either side of the hub (the hub's two
+    stamps beside it), to this stream's own sums; they go into the
+    handle's ``stream_stats()`` once, when its iteration ends (closed
+    early too), under the handle's lock: no lock an item. The stream's
+    end also sends the one latency observation (or error count) a unary
+    call's ``result()`` sends. A stream the consumer abandons sends
+    neither of those."""
 
     def __init__(self, ref_gen, handle=None, t0: Optional[float] = None):
         self._ref_gen = ref_gen
         self._handle = handle
         self._t0 = time.monotonic() if t0 is None else t0
-        self._items = 0
+        # this stream's items so far; only the iterating thread adds
+        self._phases = _tracing.PhaseStats()
 
     def _note_item(self) -> None:
-        t_wall = self._ref_gen.last_yield_wall
+        gen = self._ref_gen
+        t_wall = gen.last_yield_wall
         if self._handle is None or t_wall is None:
             return
-        # both stamps as anchored wall times; a negative gap (another
-        # host's clock ahead of this one) reads 0
-        transit = max(0.0, _tracing.wall_at(time.monotonic()) - t_wall)
-        self._handle._note_transit(transit, first=not self._items)
-        self._items += 1
+        add = self._phases.add
+        # every stamp as an anchored wall time, each held at or behind
+        # the one before it: a negative gap (another host's clock ahead
+        # of this one) reads 0, and the parts sum to the whole
+        now = max(t_wall, _tracing.wall_at(time.monotonic()))
+        if not self._phases.counts:
+            add("serve.stream_first_transit", now - t_wall)
+        add("serve.stream_transit", now - t_wall)
+        t_hub, t_reply = gen.last_hub_wall, gen.last_reply_wall
+        if t_hub is not None and t_reply is not None:
+            t_hub = min(max(t_wall, t_hub), now)
+            t_reply = min(max(t_hub, t_reply), now)
+            add("serve.stream_to_hub", t_hub - t_wall)
+            add("serve.stream_in_hub", t_reply - t_hub)
+            add("serve.stream_from_hub", now - t_reply)
+        if gen.last_next_wait_s is not None:
+            add("serve.stream_next_wait", gen.last_next_wait_s)
 
     def _record_outcome(self, error: bool) -> None:
         if self._handle is None:
@@ -285,7 +303,8 @@ class DeploymentResponseGenerator:
     @contextlib.contextmanager
     def _recorded(self):
         """The stream's outcome, sent once when its iteration ends: not
-        at all where the consumer closes it early (GeneratorExit)."""
+        at all where the consumer closes it early (GeneratorExit). Its
+        items' times go to the handle either way."""
         try:
             yield
         except GeneratorExit:
@@ -293,6 +312,9 @@ class DeploymentResponseGenerator:
         except BaseException:
             self._record_outcome(error=True)
             raise
+        finally:
+            if self._handle is not None:
+                self._handle._fold_stream(self._phases)
         self._record_outcome(error=False)
 
     def __iter__(self):
@@ -338,10 +360,13 @@ class DeploymentHandle:
         self._fail_streaks: Dict[bytes, int] = {}
         self._ejected: Dict[bytes, Any] = {}
         self._prober: Optional[threading.Thread] = None
-        # streamed items' way back (serve.stream_transit, every item;
-        # serve.stream_first_transit, a stream's first): seconds and
-        # counts, added by whichever thread iterates a stream, under
-        # the lock beside them; options() views share both
+        # streamed items' way back (serve.stream_transit, every item,
+        # and its three stretches either side of the hub;
+        # serve.stream_first_transit, a stream's first;
+        # serve.stream_next_wait, every STREAM_NEXT reply): seconds and
+        # counts, folded in by whichever thread iterated a stream as the
+        # stream ends, under the lock beside them; options() views
+        # share both
         self._stream_phases = (_tracing.PhaseStats(), threading.Lock())
 
     def __reduce__(self):
@@ -388,25 +413,40 @@ class DeploymentHandle:
 
     def stream_stats(self) -> Dict[str, Dict[str, float]]:
         """{name: {"seconds", "count"}} of this process's streamed calls
-        through this handle and its ``options()`` views, cumulative (take
-        two and subtract): ``serve.stream_transit`` is the time from the
+        through this handle and its ``options()`` views that have ended
+        (a stream's items are folded in as its iteration ends),
+        cumulative (take two and subtract):
+        ``serve.stream_transit`` is the time from the
         replica's worker yielding an item to the consumer holding its
         value (encode, STREAM_YIELD, the hub, STREAM_NEXT's reply, which
         carries an inline value with it and every item that queued up
         behind a slow consumer; the get of a value that is not inline),
         ``serve.stream_first_transit`` the same for a stream's
-        first item alone. Exact on one host; from another host it holds
-        the two wall clocks' skew."""
+        first item alone. Where the hub stamps an item (when it handled
+        the item's STREAM_YIELD, when it sent the reply that carried it)
+        the transit is also kept in its three stretches, which sum to
+        it: ``serve.stream_to_hub`` (yielded to handled: encode, the
+        worker's socket, the hub's reader and state loop),
+        ``serve.stream_in_hub`` (handled to replied: the item waited for
+        its consumer to ask) and ``serve.stream_from_hub`` (replied to
+        the value in the consumer's hand: the client's reader thread,
+        the wake, the get; for an item that came behind others in one
+        reply, the consumer's own time on those too).
+        ``serve.stream_next_wait`` is the consumer's time inside
+        STREAM_NEXT round trips, a count a reply that carried items, so
+        ``serve.stream_transit``'s count over its count is the items a
+        reply. Exact on one host; from another host each holds the two
+        wall clocks' skew."""
         stats, lock = self._stream_phases
         with lock:
             return stats.snapshot()
 
-    def _note_transit(self, seconds: float, first: bool) -> None:
+    def _fold_stream(self, phases) -> None:
+        """A stream's own sums into the handle's, once, as it ends."""
         stats, lock = self._stream_phases
         with lock:
-            stats.add("serve.stream_transit", seconds)
-            if first:
-                stats.add("serve.stream_first_transit", seconds)
+            for name, seconds in phases.seconds.items():
+                stats.add(name, seconds, phases.counts[name])
 
     def __getattr__(self, name: str):
         if name.startswith("_"):

@@ -42,11 +42,37 @@ hub. A streamed request's time OUTSIDE the engine is kept the same way,
 as monotonic stamps and ``PhaseStats`` entries and two spans a request
 (``serve.accept``, ``llm.first_yield``, in the request's own thread):
 ``serve.ingress`` and ``llm.accept`` before the server's pending queue,
-``llm.first_token_handoff`` / ``llm.token_handoff`` from the batching
-loop to the request's thread (``LLMServer.engine_stats()``), and
-``serve.stream_transit`` from the replica's worker to the consumer
-(``DeploymentHandle.stream_stats()``). A stamp crosses a process as
-``wall_at`` renders it.
+and then a token's way back, each stretch stamped where it runs and
+read on the surface that was there:
+
+1. batching loop -> the request's thread, in
+   ``LLMServer.engine_stats()["loop_phases"]``:
+   ``llm.first_token_handoff`` (a count a request) and
+   ``llm.token_handoff`` (a count a token), the latter also in its two
+   parts: ``llm.token_backlog`` (the token lay in the queue because its
+   thread had not come back for it) and ``llm.token_wake`` (the thread
+   was waiting: the loop's put and the interpreter's switch);
+2. the request's thread inside ``yield``, same surface:
+   ``llm.token_yield`` (the worker's encode, its STREAM_YIELD send, a
+   bounded stream's wait for credit);
+3. worker -> hub -> consumer, in ``DeploymentHandle.stream_stats()``:
+   ``serve.stream_transit`` (and ``serve.stream_first_transit``) and its
+   three parts ``serve.stream_to_hub`` (yielded to the hub having
+   handled it), ``serve.stream_in_hub`` (handled to replied: the item
+   waited for its consumer to ask) and ``serve.stream_from_hub``
+   (replied to the value in the consumer's hand);
+   ``serve.stream_next_wait`` a STREAM_NEXT reply;
+4. in the hub, among its built-in metrics (``util.metrics.snapshot()``,
+   the Prometheus text) beside ``ray_tpu_stream_credit_stalls_total``:
+   ``ray_tpu_stream_items_total`` over
+   ``ray_tpu_stream_next_replies_total`` (items a reply) and
+   ``ray_tpu_stream_next_found_total`` against
+   ``ray_tpu_stream_next_parked_total`` (who waited for whom).
+
+Why a lane of a decode call stood empty is counted beside the lanes
+(``EngineStats``: ``decode_lanes_prefilling``, ``decode_lanes_free``; the
+``llm.decode_dispatch`` span's ``prefilling=`` and ``free=``). A stamp
+crosses a process as ``wall_at`` renders it.
 
 Clock discipline (graftlint GL008, which covers this file): span
 start/end are positioned in wall time for cross-process stitching, but

@@ -144,6 +144,10 @@ def test_engine_stats_equal_the_hand_count(tiny_params):
     # booked a shard
     assert (s["decode_calls"], s["decode_shards"]) == (6, 12)
     assert s["decode_lanes_total"] == s["decode_shards"] * eng.max_batch
+    # per shard: B's slot is mid-prefill during A's 3 decodes, and A's
+    # is nobody's during B's 3 (in the first of them A's last token is
+    # dispatched and not read: no request could have used the lane)
+    assert (s["decode_lanes_prefilling"], s["decode_lanes_free"]) == (6, 6)
     assert s["decode_ahead"] == 5       # every call but the first
     counts = {n: v["count"] for n, v in s["phases"].items()}
     assert counts == {
@@ -155,6 +159,37 @@ def test_engine_stats_equal_the_hand_count(tiny_params):
                 if n != "llm.step")
     assert inner <= s["phases"]["llm.step"]["seconds"]
     assert set(EngineStats.COUNTERS) <= set(s)
+
+
+def test_an_empty_lane_is_counted_as_prefilling_or_free(tiny_params):
+    """Four lanes in one shard: a short prompt that decodes from the
+    first step on, a prompt of four chunks behind it, two slots nobody
+    holds. Every decode call's lanes are the request's, the prompt's
+    that is not all in yet, or free, and the three make up the call."""
+    eng = make_engine(tiny_params, max_batch=4, max_slots=4)
+    short = GenRequest("short", list(range(1, 6)), max_tokens=12)
+    long = GenRequest("long", list(range(1, 61)), max_tokens=3)
+    assert eng.add_request(short) and eng.add_request(long)
+    by_call = []
+    while eng.num_active():
+        was = eng.stats.snapshot()
+        eng.step()
+        now = eng.stats.snapshot()
+        grew = [now[f"decode_lanes_{k}"] - was[f"decode_lanes_{k}"]
+                for k in ("active", "prefilling", "free", "total")]
+        if grew[3]:
+            by_call.append(tuple(grew[:3]))
+            assert sum(grew[:3]) == grew[3] == eng.max_batch
+    # the short prompt decodes from the first step on; 60 tokens are 4
+    # chunks, a step each behind it: the long prompt's lane waits through
+    # 4 decode calls, and is a live lane in the step that writes its
+    # last chunk
+    assert by_call[:5] == [(1, 1, 2)] * 4 + [(2, 0, 2)]
+    assert by_call[-1] == (1, 0, 3)                # the short one alone
+    s = eng.stats.snapshot()
+    assert s["decode_lanes_prefilling"] == 4
+    assert (s["decode_lanes_active"] + s["decode_lanes_prefilling"]
+            + s["decode_lanes_free"]) == s["decode_lanes_total"]
 
 
 def test_engine_programs_are_exposed_for_their_text(tiny_params):
@@ -250,6 +285,10 @@ def test_profiler_trace_holds_the_engine_spans_nested(tiny_params, tmp_path):
             if name.endswith("prefill_dispatch")] == [(0, 16), (16, 3), (0, 16)]
     assert [ids["attended"] for _, _, name, ids in events
             if name.endswith("decode_dispatch")] == [20, 21]
+    # and why its other lane stood empty: r1 holds it, its prompt not in
+    assert [(ids["rows"], ids["prefilling"], ids["free"])
+            for _, _, name, ids in events
+            if name.endswith("decode_dispatch")] == [(1, 1, 0)] * 2
     in_step = [[e[2].rsplit(".", 1)[1] for e in events
                 if step[0] <= e[0] and e[1] <= step[1]] for step in steps]
     # everything is dispatched before anything is read
@@ -459,6 +498,32 @@ def test_engine_stats_call_returns_the_snapshot(llm_server):
             >= loop["llm.first_token_handoff"]["seconds"] > 0.0)
 
 
+def test_a_slow_consumer_reads_as_backlog_not_as_wake(llm_server):
+    """A consumer that sleeps between tokens holds the request's thread
+    inside ``yield``: the next tokens lie in the queue until it comes
+    back (``llm.token_backlog``), and taking one that is there is no
+    wait (``llm.token_wake``). The two make up ``llm.token_handoff``."""
+    import time
+
+    tokens, nap = 6, 0.05
+    before = llm_server.engine_stats()["loop_phases"]
+    for _ in llm_server.generate_stream(list(range(1, 20)), tokens):
+        time.sleep(nap)
+    loop = llm_server.engine_stats()["loop_phases"]
+    assert "llm.token_backlog" not in before
+    names = ("llm.token_handoff", "llm.token_backlog", "llm.token_wake",
+             "llm.token_yield")
+    handoff, backlog, wake, yielded = (loop[n]["seconds"] for n in names)
+    assert [loop[n]["count"] for n in names] == [tokens] * 4
+    assert backlog >= 0.0 and wake > 0.0
+    assert backlog + wake == pytest.approx(handoff, rel=1e-9)
+    # the engine is a few naps ahead of the reader from the second
+    # token on: every nap behind that is a token's wait in the queue
+    assert backlog > 2 * nap and wake < backlog / 2
+    # the reader's naps pass inside the generator's yields
+    assert yielded >= tokens * nap * 0.9
+
+
 # ------------------------------------- the serve stack around the engine
 class _StampServer:
     """Mixed into ``LLMServer`` in the replica: keeps every request's
@@ -473,7 +538,9 @@ class _StampServer:
                        req.prefill_started, req.first_token,
                        req.first_yielded, req.finished],
             "phases": req.phases(), "tokens": len(req.generated),
-            "handoff": (req.handoff_s, req.handoff_n)})
+            "handoff": (req.handoff_s, req.handoff_n),
+            "backlog_s": req.backlog_s,
+            "yield": (req.yield_s, req.yield_n)})
 
     def folded_requests(self):
         return list(getattr(self, "folded", []))
@@ -515,6 +582,7 @@ def _wait_for(read, want, timeout_s=15.0):
 
 
 STREAMS, TOKENS = 3, 5
+SLOW_NAP_S = 0.2
 
 
 @pytest.fixture(scope="module")
@@ -564,15 +632,25 @@ def streamed():
             "generate_stream", (list(range(1, 20)), TOKENS), {}, "")
     out["old_style"] = ([ray_tpu.get(ref) for ref in old_style],
                         handle.folded_requests.remote().result()[-1])
+    # a consumer that sleeps between tokens
+    slow0 = (stream.stream_stats(), handle.engine_stats.remote().result())
+    for _ in stream.remote(list(range(1, 20)), TOKENS):
+        time.sleep(SLOW_NAP_S)
+    out["slow"] = (slow0, (stream.stream_stats(),
+                           handle.engine_stats.remote().result()))
     yield out
     serve.shutdown()
     ray_tpu.shutdown()
 
 
-def _loop_delta(streamed, name):
-    before, after = (e["loop_phases"] for e in streamed["engine"])
+def _stats_delta(before, after, name):
     was = before.get(name, {"seconds": 0.0, "count": 0})
     return {k: after[name][k] - was[k] for k in ("seconds", "count")}
+
+
+def _loop_delta(streamed, name):
+    before, after = (e["loop_phases"] for e in streamed["engine"])
+    return _stats_delta(before, after, name)
 
 
 def _stamps_in_order(streamed):
@@ -640,6 +718,61 @@ def _stream_stats_count_a_token(streamed):
     assert seconds < sum(streamed["durations"])
 
 
+HUB_STRETCHES = ("serve.stream_to_hub", "serve.stream_in_hub",
+                 "serve.stream_from_hub")
+
+
+def _the_hubs_stretches_sum_to_the_transit(streamed):
+    before, after = streamed["stream_stats"]
+    transit = _stats_delta(before, after, "serve.stream_transit")
+    parts = [_stats_delta(before, after, n) for n in HUB_STRETCHES]
+    assert [p["count"] for p in parts] == [transit["count"]] * 3
+    assert all(p["seconds"] >= 0.0 for p in parts)
+    assert sum(p["seconds"] for p in parts) == pytest.approx(
+        transit["seconds"], rel=1e-6)
+    # a reply carried an item or more, and every stream asked once
+    replies = _stats_delta(before, after, "serve.stream_next_wait")
+    assert STREAMS <= replies["count"] <= transit["count"]
+    assert replies["seconds"] > 0.0
+
+
+def _the_handoff_is_its_backlog_and_its_wake(streamed):
+    handoff, backlog, wake, yielded = (
+        _loop_delta(streamed, name) for name in (
+            "llm.token_handoff", "llm.token_backlog", "llm.token_wake",
+            "llm.token_yield"))
+    assert (backlog["count"], wake["count"], yielded["count"]) == (
+        handoff["count"],) * 3
+    assert backlog["seconds"] + wake["seconds"] == pytest.approx(
+        handoff["seconds"], rel=1e-9)
+    folded = streamed["folded"]
+    for req in folded:                    # request by request
+        assert 0.0 <= req["backlog_s"] <= req["handoff"][0]
+        assert req["yield"][1] == TOKENS and req["yield"][0] > 0.0
+    assert backlog["seconds"] == pytest.approx(
+        sum(r["backlog_s"] for r in folded))
+    assert yielded["seconds"] == pytest.approx(
+        sum(r["yield"][0] for r in folded))
+
+
+def _a_slow_consumer_shows_in_the_hub_not_in_the_wake(streamed):
+    """The stream is not bounded: the replica's worker hands every token
+    to the hub as it comes, and there they wait for the reader's next
+    ask. The request's thread never waits long for the interpreter."""
+    (stats0, engine0), (stats1, engine1) = streamed["slow"]
+    in_hub = _stats_delta(stats0, stats1, "serve.stream_in_hub")
+    to_hub = _stats_delta(stats0, stats1, "serve.stream_to_hub")
+    assert in_hub["count"] == TOKENS
+    # the tokens behind the first were made during the reader's first
+    # nap and waited out the rest of it
+    assert in_hub["seconds"] > SLOW_NAP_S
+    assert to_hub["seconds"] < in_hub["seconds"] / 2
+    wake = _stats_delta(engine0["loop_phases"], engine1["loop_phases"],
+                        "llm.token_wake")
+    assert wake["count"] == TOKENS
+    assert wake["seconds"] < in_hub["seconds"] / 2
+
+
 def _a_streamed_request_is_counted_and_timed(streamed):
     before, after = streamed["routes"]
     assert after["requests"] - before["requests"] == STREAMS
@@ -667,12 +800,56 @@ def _an_old_style_call_still_streams(streamed):
     _loop_phases_count_a_request_and_a_token,
     _ring_rows_keep_their_six_fields_and_gain_two,
     _stream_stats_count_a_token,
+    _the_hubs_stretches_sum_to_the_transit,
+    _the_handoff_is_its_backlog_and_its_wake,
+    _a_slow_consumer_shows_in_the_hub_not_in_the_wake,
     _a_streamed_request_is_counted_and_timed,
     _a_unary_call_is_stamped_too,
     _an_old_style_call_still_streams,
 ], ids=lambda f: f.__name__.strip("_"))
 def test_a_served_requests_time_outside_the_engine(streamed, check):
     check(streamed)
+
+
+@pytest.mark.parametrize("stamped", [True, False])
+def test_an_item_without_the_hubs_stamps_reads_as_before(stamped):
+    """An item whose reply came from a hub that stamps nothing adds its
+    transit and no stretch; one with the hub's two stamps adds all
+    three, which sum to the transit whatever the clocks' jitter."""
+    import time
+
+    from ray_tpu.serve.handle import (DeploymentHandle,
+                                      DeploymentResponseGenerator)
+
+    now = tracing.wall_at(time.monotonic())
+
+    class RefGen:
+        last_yield_wall = now - 0.5
+        # the hub's clock a hair behind the worker's, the reply stamped
+        # ahead of this process's own reading
+        last_hub_wall = now - 0.6 if stamped else None
+        last_reply_wall = now + 60.0 if stamped else None
+        last_next_wait_s = 0.25
+
+    handle = DeploymentHandle("nobody")
+    items = DeploymentResponseGenerator(RefGen(), handle)
+    items._note_item()
+    items._note_item()
+    assert handle.stream_stats() == {}         # folded as the stream ends
+    with items._recorded():
+        pass
+    stats = handle.stream_stats()
+    assert stats["serve.stream_transit"]["count"] == 2
+    assert stats["serve.stream_first_transit"]["count"] == 1
+    assert stats["serve.stream_next_wait"] == {"seconds": 0.5, "count": 2}
+    transit = stats["serve.stream_transit"]["seconds"]
+    assert 1.0 <= transit < 1.5
+    if not stamped:
+        assert not set(HUB_STRETCHES) & set(stats)
+        return
+    to_hub, in_hub, from_hub = (stats[n]["seconds"] for n in HUB_STRETCHES)
+    assert (to_hub, from_hub) == (0.0, 0.0)        # held, not negative
+    assert in_hub == pytest.approx(transit, rel=1e-9)
 
 
 @pytest.mark.parametrize("shards", [1, 2])

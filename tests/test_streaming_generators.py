@@ -23,21 +23,25 @@ def test_task_generator_streams(ray_start_regular):
 
 
 def test_incremental_delivery(ray_start_regular):
-    """First value is consumable before the generator finishes."""
+    """The first value is in hand before the generator has produced the
+    second: held to the generator's own stamp of its second yield (one
+    host, one clock), not to a wall budget that a worker's spawn on a
+    loaded box would eat."""
     import time
 
     @ray_tpu.remote(num_returns="streaming")
     def slow_gen():
         yield "first"
         time.sleep(2.0)
-        yield "second"
+        yield time.time()            # when the second value was made
 
     g = slow_gen.remote()
-    t0 = time.time()
-    first_ref = next(g)
-    assert ray_tpu.get(first_ref) == "first"
-    assert time.time() - t0 < 1.5  # did not wait for the full generator
-    assert ray_tpu.get(next(g)) == "second"
+    assert ray_tpu.get(next(g)) == "first"
+    in_hand = time.time()
+    second_made = ray_tpu.get(next(g))
+    assert in_hand < second_made     # did not wait for the full generator
+    with pytest.raises(StopIteration):
+        next(g)
 
 
 def test_generator_error_surfaces_as_final_ref(ray_start_regular):
@@ -299,3 +303,97 @@ def test_the_bound_holds_before_the_producers_first_wait_for_credit(
         P.STREAM_NEXT, {"task_id": task_id, "index": 0, "batch": 64})
     assert len(reply["items"]) == (1 if bound else 3)
     client.send(P.STREAM_END, {"task_id": task_id, "error": None})
+
+
+def _stream_counters():
+    """The hub's ``ray_tpu_stream_*_total`` counters, by their middles."""
+    from ray_tpu.util import metrics
+
+    return {m["name"][len("ray_tpu_stream_"):-len("_total")]: m["value"]
+            for m in metrics.snapshot()
+            if m["name"].startswith("ray_tpu_stream_")}
+
+
+def test_a_streamed_item_carries_the_hubs_two_stamps(ray_start_regular):
+    """Beside the producer's yield stamp an item comes with the hub's
+    stamp of its STREAM_YIELD, and its reply with the hub's stamp of the
+    send: in that order on one host's clock, whether the consumer waited
+    for the item (parked) or the item for the consumer (found, several
+    in one reply). The hub counts items, replies and who waited."""
+    import time
+
+    from ray_tpu.util import tracing
+
+    @ray_tpu.remote(num_returns="streaming")
+    def slow_then_fast():
+        time.sleep(0.3)              # the consumer is waiting by then
+        for i in range(4):
+            yield i
+
+    before = _stream_counters()
+    g = slow_then_fast.remote()
+    assert (g.last_hub_wall, g.last_reply_wall, g.last_next_wait_s) == (
+        None, None, None)
+    rows = []
+
+    def take(i):
+        ref = next(g)
+        rows.append((g.last_yield_wall, g.last_hub_wall, g.last_reply_wall,
+                     g.last_next_wait_s))
+        assert ray_tpu.get(ref) == i
+
+    take(0)
+    time.sleep(0.3)                  # items 1 to 3 are there before the ask
+    for i in (1, 2, 3):
+        take(i)
+    with pytest.raises(StopIteration):
+        next(g)
+    now = tracing.wall_at(time.monotonic())
+    jitter = 1e-3                    # two processes' anchors
+    for t_wall, t_hub, t_reply, _ in rows:
+        assert t_wall - jitter <= t_hub <= t_reply <= now + jitter
+    # item 0 was waited for: handled and replied in one pass of the hub
+    assert rows[0][2] - rows[0][1] < 0.1 and rows[0][3] >= 0.25
+    # items 1 to 3 waited in the hub for the consumer's sleep, and came
+    # in one reply: one stamp of the send, one round trip
+    assert all(r[2] - r[1] >= 0.25 for r in rows[1:])
+    assert len({r[2] for r in rows[1:]}) == 1
+    assert rows[1][3] is not None and (rows[2][3], rows[3][3]) == (None, None)
+    grew = {k: v - before.get(k, 0) for k, v in _stream_counters().items()}
+    assert grew["items"] == 4 and grew["next_replies"] == 2
+    # the first ask waited for its item, the second found three; the
+    # third found the end, or waited for it
+    assert grew["next_found"] == 1 and grew["next_parked"] in (1, 2)
+    assert "credit_stalls" in grew
+
+
+def test_a_reply_without_the_hubs_stamps_reads_as_before(monkeypatch):
+    """A hub from before the stamps sends three fields an item and no
+    ``t_reply``: the generator hands the refs on with the yield stamp
+    alone."""
+    from ray_tpu._private import worker
+    from ray_tpu._private.ids import ObjectID
+
+    oids = [ObjectID.generate().binary() for _ in range(2)]
+
+    class OldHubsClient:
+        held = []
+
+        def request(self, msg_type, payload):
+            if payload["index"] >= len(oids):
+                return {"end": True}
+            return {"items": [(oid, 100.0 + i, b"v")
+                              for i, oid in enumerate(oids)]}
+
+        def hold_inline(self, oid, payload):
+            self.held.append(oid)
+
+    client = OldHubsClient()
+    monkeypatch.setattr(worker, "get_client", lambda: client)
+    g = ObjectRefGenerator(b"t" * 16)
+    seen = []
+    for ref in g:
+        seen.append((ref.binary(), g.last_yield_wall, g.last_hub_wall,
+                     g.last_reply_wall))
+    assert seen == [(oids[0], 100.0, None, None), (oids[1], 101.0, None, None)]
+    assert client.held == oids
