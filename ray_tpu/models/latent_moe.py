@@ -60,7 +60,10 @@ other, the cache riding in both carries. The call around the layers
 ``models/decoder.py``'s.
 
 **Counters** (``COUNTERS``, in the cache's ``counts``): the ``moe_*`` three of
-``EngineStats`` (held experts only), ``moe_assignments_all`` (every live
+``EngineStats`` (held experts only), ``moe_held_slabs`` (the passes
+``ops/moe.py`` made over a slab of the held experts' assignments: one a
+layer a chunk call whose held share fits the slab, none in a decode
+call), ``moe_assignments_all`` (every live
 row's ``experts_per_token``, so that the held share of the routing is
 read and not assumed), and for the two attention forms
 ``attn_pairs_prefill`` (a chunk's live rows x the rows each attends to),
@@ -150,8 +153,8 @@ LATENT_MOE_TINY = LatentMoEConfig(
 
 SHARED_WEIGHTS = ("shared_gate", "shared_up", "shared_down")
 COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
-            "moe_assignments_all", "attn_pairs_prefill", "attn_rows_prefill",
-            "attn_rows_decode")
+            "moe_held_slabs", "moe_assignments_all", "attn_pairs_prefill",
+            "attn_rows_prefill", "attn_rows_decode")
 # (the most a call counts at once, 2048 rows x 16 384 x 5 layers, is
 # 2^27: under the carry of ``decoder``'s counter words)
 # What the ``jax.numpy`` loops take at a time: cache rows a block, and
@@ -506,7 +509,7 @@ def dense_mlp(c: LatentMoEConfig, x, layer):
 
 def moe_mlp(c: LatentMoEConfig, x, layer, experts, index, live=None):
     """The expert layer between its two norms + residual -> (x, counts
-    int32[4]: ``ops/moe.py``'s three and every live row's assignments,
+    int32[5]: ``ops/moe.py``'s four and every live row's assignments,
     held or not). ``layer``: this layer's norms, router and shared
     expert; ``experts``: every routed layer's held experts, stacked, of
     which this layer is ``index`` (``ops/moe.py`` ``expert_ffn`` says
@@ -649,7 +652,7 @@ def forward_with_cache(
         tuple((each["latent"], each["rope_key"]) for each in caches),
         params["dense"])
     x, shards, counted = decoder.scan_layers(
-        routed_step, x, shards, scanned, 4)
+        routed_step, x, shards, scanned, 5)
     with jax.named_scope("layers"):     # counted beside the scans
         counted = jnp.concatenate([counted, attended * c.n_layers])
     with jax.named_scope("head"):

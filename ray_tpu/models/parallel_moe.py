@@ -32,7 +32,10 @@ chunk's float32 scores against 8192 cache rows would be 4.3 GB a
 layer).
 
 **Counters** (``COUNTERS``, in the cache's ``counts``): the ``moe_*``
-three of ``EngineStats`` (held experts only), ``moe_assignments_all``
+three of ``EngineStats`` (held experts only), ``moe_held_slabs`` (the
+passes ``ops/moe.py`` made over a slab of the held experts'
+assignments: one a layer a chunk call whose held share fits the slab,
+none in a decode call), ``moe_assignments_all``
 (every live row's ``experts_per_token``, so the held share of the
 routing is read and not assumed), and ``window_moe.ATTN_COUNTERS``,
 which ``cached_periods`` counts of the sliding layers' attention:
@@ -111,8 +114,8 @@ PARALLEL_MOE_TINY = ParallelMoEConfig(
     expert_dim=32, held_experts=(4, 5, 6, 7), n_shared_experts=2,
 )
 
-COUNTERS = (*window_moe.MOE_COUNTERS, "moe_assignments_all",
-            *window_moe.ATTN_COUNTERS)
+COUNTERS = (*window_moe.MOE_COUNTERS, "moe_held_slabs",
+            "moe_assignments_all", *window_moe.ATTN_COUNTERS)
 
 
 def chunk_terms(config: ParallelMoEConfig, max_seq: int) -> Dict[str, float]:
@@ -172,7 +175,7 @@ def parallel_block(c: ParallelMoEConfig, pos, kind, x, layer, mixer, experts,
                    index, live):
     """One layer: the one norm, then attention and the experts on its
     output, both added to the ``x`` the layer began with -> (x, counts
-    int32[4]: ``ops/moe.py``'s three and every live row's assignments,
+    int32[5]: ``ops/moe.py``'s four and every live row's assignments,
     held or not)."""
     with jax.named_scope("block_norm"):
         h = layer_norm(x, layer["norm"], c.norm_eps)
@@ -218,7 +221,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
 
     x, _, _ = window_moe.scan_periods(
         c, params["blocks"], decoder.embed(params, tokens, c), pos, attend,
-        block=parallel_block, n_counted=4)
+        block=parallel_block, n_counted=5)
     return head(params, x, c)
 
 
@@ -248,6 +251,6 @@ def forward_with_cache(
                         shards=len(caches))
     x, shards, counted = window_moe.cached_periods(
         c, params["blocks"], decoder.embed(params, tokens, c), call, caches,
-        block=parallel_block, n_counted=4)
+        block=parallel_block, n_counted=5)
     return head(params, x, c, logits_at), back(
         window_moe.new_caches(caches, shards, counted))

@@ -222,11 +222,11 @@ def init_params(rng: jax.Array, config: WindowMoEConfig) -> Dict[str, Any]:
 # -- the sublayers -----------------------------------------------------
 def moe_mix(c: WindowMoEConfig, h, layer, experts, index, live=None):
     """The routed feed-forward over a normed input ``h`` -> (what the
-    sublayer adds, counts int32[3]). ``layer``: this layer's router (and
-    shared expert, where it has one); ``experts``: every layer's expert
-    weights, stacked, of which this layer is ``index`` (the grouped
-    matmul takes the stack whole: ``ops/moe.py`` ``expert_ffn`` says
-    why); ``live`` (B, T): the rows to count."""
+    sublayer adds, counts int32[4]: ``ops/moe.py``'s). ``layer``: this
+    layer's router (and shared expert, where it has one); ``experts``:
+    every layer's expert weights, stacked, of which this layer is
+    ``index`` (the grouped matmul takes the stack whole: ``ops/moe.py``
+    ``expert_ffn`` says why); ``live`` (B, T): the rows to count."""
     shared = {k: layer[k].astype(c.dtype) for k in SHARED_WEIGHTS
               if k in layer}
     return moe.moe_ffn_dropless(
@@ -241,7 +241,8 @@ def moe_sublayer(c: WindowMoEConfig, x, layer, experts, index, live=None):
     with jax.named_scope("moe"):
         h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
         out, counts = moe_mix(c, h, layer, experts, index, live)
-        return x + out, counts
+        # every expert is held here: no slab of a share to count passes of
+        return x + out, counts[:len(MOE_COUNTERS)]
 
 
 def pre_norm_block(c: WindowMoEConfig, pos, kind, x, layer, mixer, experts,

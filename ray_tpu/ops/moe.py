@@ -26,7 +26,13 @@ Pieces:
   (``EVERY_EXPERT_ROWS``). A chip that holds its share of a layer's
   experts (``MoEConfig.held``) routes over all of them and computes its
   own experts' part of the sum; what the absent experts would add is
-  left out, and nothing stands in for the chips that hold them. The
+  left out, and nothing stands in for the chips that hold them. Its
+  grouped passes are sized by what it holds: the assignments to held
+  experts sort first, and only they are gathered, multiplied, zeroed
+  behind and summed back, a slab of them a pass of a loop that runs as
+  many passes as the routing needs (``_held_slabs``; one where the
+  router spreads evenly), so the result is whole for every routing and
+  the rest of T x k goes through no pass at all. The
   scores are a softmax's or a sigmoid's (``scoring``), the chosen
   weights scaled by ``routed_scale``, and a shared expert, where the
   parameters bring one, is added once. No backward pass is written for
@@ -306,12 +312,121 @@ def expert_ffn_every(x, w_gate, w_up, w_down, combine, layer=None):
     return (ys * combine.T[:, :, None]).sum(0)
 
 
+# What a pass over a held share's assignments is sized by (``_slab``):
+# this many times what a router that spreads evenly sends to the experts
+# held here. The passes that cost by their rows (the gather, the zeroing,
+# the sum back) are paid at the slab's size in every call, a second pass
+# only by the calls whose routing sends more here than a slab takes, and
+# then over the few rows left: at twice the even share openPangu's
+# seeded router (2.6 to 2.9 % of its assignments held where 8 of 256 is
+# 3.1 %, but not evenly a layer) takes a second pass in a tenth of its
+# layer-calls and command-a-plus's in one of a thousand, and a 1024-row
+# call's expert layer went from 3.56 ms to 1.63 and from 4.37 to 3.48
+# (PERF.md section 6, PR 59); a wider slab would charge every call for
+# that tenth. Where every assignment lands here the passes are T * k /
+# slab and the layer 13 % slower than one pass over all of it was.
+HELD_SLAB_OVER_EVEN = 2
+
+
+def _slab(config: MoEConfig, assignments: int) -> int:
+    """The assignments one pass over a held share takes, of a call's
+    ``assignments`` (T * k): ``HELD_SLAB_OVER_EVEN`` times the held
+    experts' even share, a whole number of the grouped kernel's row
+    tiles (so that the slab's shapes stay on the kernel wherever the
+    call's did); all of them where every expert is held or the call is
+    too small to compact."""
+    if config.held is None or len(config.held) == config.n_experts:
+        return assignments
+    from .pallas_grouped_matmul import _ROW_TILE
+
+    even = assignments * len(config.held) / config.n_experts
+    tiles = math.ceil(HELD_SLAB_OVER_EVEN * even / _ROW_TILE)
+    return min(assignments, tiles * _ROW_TILE)
+
+
+def _add_rows(out, rows, ys):
+    """``out`` (T, D) float32 with ``ys`` (C, D) float32 added at the
+    rows ``rows`` (C,), which may name a row more than once: a (T, C)
+    one-hot of the rows times ``ys`` on the MXU, at the precision that
+    keeps float32. A scatter-add of the rows is serial on a v5e (a
+    layer of 1024 rows, 7680 wide, read 4.40 ms with it at a slab of
+    512 and 1.63 with this: 5.4 us a scattered row), the float32 rows
+    split by hand into three bf16 parts cost more in the splitting than
+    the three passes they save (1.83), and ``Precision.HIGH`` keeps 16
+    bits (PERF.md section 6, PR 59)."""
+    hot = (jnp.arange(out.shape[0])[:, None] == rows[None, :]).astype(
+        jnp.float32)
+    return out + jnp.dot(hot, ys, precision=jax.lax.Precision.HIGHEST)
+
+
+def _held_slabs(params: dict, x: jax.Array, weights, order, group_sizes,
+                slab: int, layer):
+    """The routed sum of a layer that holds a share of the experts, over
+    the assignments that reach them and no others -> (the sum (T, D)
+    float32, the passes run int32). ``order`` (T * k,): the call's
+    assignments sorted by expert, those to held experts first (``n`` of
+    them: ``group_sizes.sum()``); ``weights`` (T, k) float32.
+
+    A loop over slabs of ``slab`` assignments of the sorted order, as
+    many as hold ``n``: one where the router spreads evenly, T * k /
+    slab where every assignment lands here, none where none does. A
+    pass gathers its assignments' rows, multiplies them by their experts
+    (each expert's interval of the order clipped to the slab: one that
+    straddles two slabs is read in both), puts 0 behind the last held
+    assignment and adds the rows, weighed, into the sum at their
+    tokens'. Every held assignment is in exactly one pass, so the sum is
+    the whole layer's for every routing."""
+    T, D = x.shape
+    k = weights.shape[1]
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    n = ends[-1]
+    # whole slabs, so that the last one's slice starts where it says
+    order = jnp.pad(order, (0, -order.shape[0] % slab))
+    weights = weights.reshape(T * k)
+
+    def one(carry):
+        i, out = carry
+        first = i * slab
+        with jax.named_scope("moe_dispatch"):
+            took = jax.lax.dynamic_slice(order, (first,), (slab,))
+            rows = took // k
+            xs = x[rows]                              # (slab, D)
+            sizes = jnp.clip(jnp.minimum(ends, first + slab)
+                             - jnp.maximum(starts, first), 0)
+        with jax.named_scope("moe_experts"):
+            ys = expert_ffn(xs, params["w_gate"], params["w_up"],
+                            params["w_down"], sizes, layer)
+            # rows behind the last group are no expert's: whatever the
+            # grouped matmul leaves there is not a number to keep, and
+            # a weight of 0 would not make it one
+            ys = jnp.where((jnp.arange(slab) < n - first)[:, None], ys, 0.0)
+        with jax.named_scope("moe_combine"):
+            out = _add_rows(out, rows, ys * weights[took][:, None])
+        return i + 1, out
+
+    passes = (n + slab - 1) // slab
+    _, out = jax.lax.while_loop(
+        lambda carry: carry[0] < passes, one,
+        (jnp.int32(0), jnp.zeros((T, D), jnp.float32)))
+    return out, passes
+
+
 def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
                    config: MoEConfig, layer=None, sum_over=None):
-    """x (T, D), live (T,) bool -> (out (T, D), counts int32[3]: the
+    """x (T, D), live (T,) bool -> (out (T, D), counts int32[4]: the
     live rows' assignments to experts held here, experts held here with
-    a live row or more, experts held here); ``sum_over``: the mesh axes
-    the rows are split over, inside a shard_map."""
+    a live row or more, experts held here, passes over a held share's
+    slab); ``sum_over``: the mesh axes the rows are split over, inside a
+    shard_map.
+
+    Three ways, by the shapes and the share held alone: a few rows
+    through every expert (``EVERY_EXPERT_ROWS``); the assignments sorted
+    by expert and all of them gathered, multiplied and gathered back;
+    and, where a share of the experts is held and the call is past the
+    first, only the assignments to held experts, a slab at a time
+    (``_held_slabs``): the rest of T * k weighs 0 and goes through no
+    pass at all."""
     T, D = x.shape
     k = config.k
     E = config.n_experts if config.held is None else len(config.held)
@@ -328,6 +443,7 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
     # where experts are absent, the scatters below have their place too,
     # which is cut off again: no index is ever out of bounds
     places = E if config.held is None else E + 1
+    passes = jnp.int32(0)
     if E <= T * k and T <= EVERY_EXPERT_ROWS:
         with jax.named_scope("moe_dispatch"):
             combine = jnp.zeros((T, places), jnp.float32).at[
@@ -341,18 +457,27 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
             flat = experts.reshape(T * k)
             order = jnp.argsort(flat, stable=True)    # assignments by expert
             group_sizes = jnp.zeros(places, jnp.int32).at[flat].add(1)[:E]
-            xs = x[order // k]                        # (T*k, D)
-        with jax.named_scope("moe_experts"):
-            ys = expert_ffn(xs, params["w_gate"], params["w_up"],
-                            params["w_down"], group_sizes, layer)
-            if config.held is not None:
-                # rows behind the last group are no expert's: whatever
-                # the grouped matmul leaves there is not a number to keep
-                ys = jnp.where((flat[order] < E)[:, None], ys, 0.0)
-        with jax.named_scope("moe_combine"):
-            back = jnp.argsort(order)                 # each assignment's row
-            ys = ys[back].reshape(T, k, D)
-            out = (ys * weights[..., None]).sum(1).astype(x.dtype)
+        slab = _slab(config, T * k)
+        if slab < T * k:
+            out, passes = _held_slabs(params, x, weights, order, group_sizes,
+                                      slab, layer)
+            with jax.named_scope("moe_combine"):
+                out = out.astype(x.dtype)
+        else:
+            with jax.named_scope("moe_dispatch"):
+                xs = x[order // k]                    # (T*k, D)
+            with jax.named_scope("moe_experts"):
+                ys = expert_ffn(xs, params["w_gate"], params["w_up"],
+                                params["w_down"], group_sizes, layer)
+                if config.held is not None:
+                    # rows behind the last group are no expert's:
+                    # whatever the grouped matmul leaves there is not a
+                    # number to keep
+                    ys = jnp.where((flat[order] < E)[:, None], ys, 0.0)
+            with jax.named_scope("moe_combine"):
+                back = jnp.argsort(order)             # each assignment's row
+                ys = ys[back].reshape(T, k, D)
+                out = (ys * weights[..., None]).sum(1).astype(x.dtype)
     if "shared_gate" in params:
         with jax.named_scope("moe_shared"):
             # the expert every row goes through, added once: on every
@@ -372,7 +497,7 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
             live[:, None].astype(jnp.int32))[:E]
         counts = jnp.stack([asked.sum().astype(jnp.int32),
                             (asked > 0).sum().astype(jnp.int32),
-                            jnp.int32(E)])
+                            jnp.int32(E), passes])
         if sum_over:
             # every shard of the rows visits its own experts
             counts = jax.lax.psum(counts, sum_over)

@@ -13,8 +13,10 @@ Layout contract (what ``ops/moe.py`` holds):
     w_gate, w_up (E, D, F), w_down (E, F, D), or a model's stacks
     (L, E, D, F) / (L, E, F, D) with ``layer`` a traced index into them
     -> (N, D) float32. Rows behind the last group are not written: what
-    they hold is not a number to keep (``moe._dropless_rows`` puts 0
-    there where a share of the experts is held).
+    they hold is not a number to keep (where a share of the experts is
+    held ``ops/moe.py`` puts 0 there: ``_held_slabs`` behind each slab
+    of the held assignments it passes here, ``_dropless_rows`` behind a
+    call it passes whole).
 
 Two ``pallas_call``s a layer, the same kernel body twice: the first
 takes a tile of rows through ``w_gate`` and ``w_up`` and writes
@@ -34,7 +36,8 @@ ms of a layer's 2.
   prefetch, with the layer. At most ``N / tile + E - 1`` visits can be;
   the grid's extent is their count, a traced number, so what is not
   visited costs no step: openPangu's 8 held experts take about 245 of a
-  call's 8192 assignments, 9 or 10 visits of 71.
+  call's 8192 assignments, 9 or 10 visits (since PR 59 the call hands
+  over a slab of 512 of them, 11 visits at the most).
 - **An expert's weights are read once a call.** The weight block's
   index is (layer, expert of the visit, 0, column tile): consecutive
   visits of one expert keep it and the pipeline fetches nothing. The

@@ -312,32 +312,51 @@ def serving_cell(sds, name: str, model):
 def grouped_swiglu(check, sds, quick):
     """The grouped SwiGLU of a chunk's experts
     (``ops/pallas_grouped_matmul.py``) alone, at the published widths of
-    the two configurations that reach ``moe.expert_ffn`` and the
-    assignments of every chunk bucket of their cells (rows x top-8;
-    ``--quick``: the smallest bucket of each): 64 experts of 2304 x 896,
-    whose matrices go into VMEM whole, and 8 held experts of 7680 x
-    2048, whose columns are tiled; two layers' stacks, the layer a
-    traced index. Mosaic's answer on the tile shapes and on VMEM."""
-    from ray_tpu.models import latent_moe, window_moe
+    the three configurations that reach ``moe.expert_ffn`` and the
+    assignments one call of it takes in every chunk bucket of their
+    cells (``moe._slab`` of rows x top-8: all of them where every expert
+    is held, a slab where a share is; ``--quick``: the smallest bucket
+    of each): 64 experts of 2304 x 896, whose matrices go into VMEM
+    whole, and 8 held experts of 7680 x 2048 and 16 of 4096 x 4096,
+    whose columns are tiled; two layers' stacks, the layer a traced
+    index. Mosaic's answer on the tile shapes and on VMEM."""
+    from ray_tpu.models import latent_moe, parallel_moe, window_moe
     from ray_tpu.ops import moe
 
     for name, model in (("mellum2-12b-a2.5b.serve-ide-mix", window_moe),
                         ("openpangu-ultra-moe-718b.serve-longdoc",
-                         latent_moe)):
+                         latent_moe),
+                        ("command-a-plus-05-2026.serve-rag", parallel_moe)):
         cfg, _, _, chunk = cell_config(name, model)
         c = cfg.moe
         E = c.n_experts if c.held is None else len(c.held)
         up, down = (sds((2, E, *shape), cfg.dtype) for shape in (
             (c.d_model, c.d_ff), (c.d_ff, c.d_model)))
         for rows in (chunk // 4, chunk // 2, chunk)[:1 if quick else 3]:
-            check(f"grouped SwiGLU of a {rows}-row chunk's {rows * c.k} "
-                  f"assignments, {E} experts of {c.d_model} x {c.d_ff} "
-                  f"({name.rsplit('.', 1)[0]}), one device",
-                  lambda rows=rows: jax.jit(moe.expert_ffn).lower(
-                      sds((rows * c.k, c.d_model), cfg.dtype), up, up, down,
+            taken = moe._slab(c, rows * c.k)
+            check(f"grouped SwiGLU of {taken} of a {rows}-row chunk's "
+                  f"{rows * c.k} assignments, {E} experts of {c.d_model} x "
+                  f"{c.d_ff} ({name.rsplit('.', 1)[0]}), one device",
+                  lambda taken=taken: jax.jit(moe.expert_ffn).lower(
+                      sds((taken, c.d_model), cfg.dtype), up, up, down,
                       sds((E,), jnp.int32), sds((), jnp.int32)),
                   expect=("grouped_swiglu_gate_up", "grouped_swiglu_down"),
                   forbid=r"ragged-dot|ragged_dot")
+
+
+def every_assignment(cfg, rows: int) -> str:
+    """The pattern of a float32 tensor of every assignment of a
+    ``rows``-row chunk x the width (the product of the grouped matmul
+    over all of T x k, its zeroing, its gather back), which no program
+    of a configuration that holds a share of the experts has
+    (``moe._held_slabs``: a slab's at the most); one that matches
+    nothing where every expert is held."""
+    from ray_tpu.ops import moe
+
+    c = cfg.moe
+    if moe._slab(c, rows * c.k) == rows * c.k:
+        return r"(?!)"
+    return rf"f32\[{rows * c.k},{c.d_model}\]"
 
 
 def latent_chunks(check, sds):
@@ -365,7 +384,8 @@ def latent_chunks(check, sds):
               f"{lanes} x {max_seq}, published widths, one device",
               partial(lower_chunk, sds, latent_moe, cfg, params, cache, rows,
                       window),
-              expect=("latent_attention_prefill",), forbid=forbidden)
+              expect=("latent_attention_prefill", "grouped_swiglu_gate_up"),
+              forbid=rf"{forbidden}|{every_assignment(cfg, rows)}")
     for window in (max_seq // 2, max_seq):
         check(f"latent decode step of {lanes} lanes reading {window} of "
               f"{lanes} x {max_seq}, published widths, one device",
@@ -379,8 +399,10 @@ def tiled_chunks(check, sds, label, model, cfg, params, cache, lanes,
     ``window_moe.cached_periods`` (the two smaller buckets at the top
     read window, the whole chunk at both): each holds the kernels of
     ``ops/pallas_chunk_attention.py`` and of the grouped SwiGLU, no
-    float32 score of heads x chunk rows x cache rows, and no copy of a
-    whole stack of rows or of rings -> the pattern of such a copy."""
+    float32 score of heads x chunk rows x cache rows, no float32 product
+    of every assignment where a share of the experts is held, and no
+    copy of a whole stack of rows or of rings -> the pattern of such a
+    copy."""
     no_stack_copy = no_copy_of(cache["full"]["k"], cache["ring"]["k"])
     ring = cache["ring"]["k"].shape[3] - 8
     for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
@@ -393,7 +415,8 @@ def tiled_chunks(check, sds, label, model, cfg, params, cache, lanes,
               partial(lower_chunk, sds, model, cfg, params, cache, rows,
                       window),
               expect=("chunk_attention", "grouped_swiglu_gate_up"),
-              forbid=rf"{no_stack_copy}|ragged-dot|{score}")
+              forbid=rf"{no_stack_copy}|ragged-dot|{score}|"
+                     rf"{every_assignment(cfg, rows)}")
     return no_stack_copy
 
 

@@ -319,14 +319,15 @@ def test_device_programs_compile_for_a_v5e(aot_compile):
     and the decode step reading that and every row, of one shard and
     of a pair of shards in one call, none of which
     copies a whole cache leaf, the grouped SwiGLU of a chunk's experts
-    at the two routed configurations' published widths (their smallest
-    bucket: the kernel there and no ``ragged_dot``), and a one-layer
+    at the three routed configurations' published widths (what one call
+    takes of their smallest bucket, a slab where a share of the experts
+    is held: the kernel there and no ``ragged_dot``), and a one-layer
     train step at LLAMA_BENCH's widths, on one device and on four,
     through Mosaic and the TPU compiler (tests/aot_compile_check.py)."""
     out, _ = aot_compile.communicate(timeout=170)
     if aot_compile.returncode == 77:
         pytest.skip(out.strip())
     assert aot_compile.returncode == 0, out
-    assert out.count("\nok  ") + out.startswith("ok  ") == 13, out
+    assert out.count("\nok  ") + out.startswith("ok  ") == 14, out
     assert out.count("lanes reading") == 3 and out.count("a pair of") == 1
-    assert out.count("ok   grouped SwiGLU") == 2, out
+    assert out.count("ok   grouped SwiGLU") == 3, out

@@ -568,7 +568,7 @@ def test_taking_the_sublayers_apart_left_the_families_programs(family, T):
                 {"router": layer["router"],
                  **{k: w.astype(c.dtype) for k, w in experts.items()}},
                 h, c.moe, layer=index, live=live)
-            return x + out, counts
+            return x + out, counts[:3]
 
     patched = pytest.MonkeyPatch()
     try:

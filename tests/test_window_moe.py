@@ -65,7 +65,7 @@ def test_dropless_layer_equals_a_per_token_loop(seed, path):
         params, x)
     want, experts = per_token_loop(params, x, MOE)
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
-    assert list(counts) == [37 * 2, len(np.unique(experts)), 8]
+    assert list(counts) == [37 * 2, len(np.unique(experts)), 8, 0]
 
 
 def test_the_two_paths_are_chosen_by_the_rows_of_the_call():
@@ -104,12 +104,12 @@ def test_rows_that_are_nobodys_are_computed_and_not_counted(path):
     out, counts = moe.moe_ffn_dropless(params, x, MOE, live=jnp.asarray(live))
     want, experts = per_token_loop(params, x, MOE)
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
-    assert list(counts) == [5 * 2, len(np.unique(experts[:5])), 8]
+    assert list(counts) == [5 * 2, len(np.unique(experts[:5])), 8, 0]
     assert len(np.unique(experts[:5])) < len(np.unique(experts))
     none, counts = moe.moe_ffn_dropless(params, x, MOE,
                                         live=jnp.zeros(12, bool))
     np.testing.assert_allclose(np.asarray(none), want, atol=2e-5)
-    assert list(counts) == [0, 0, 8]
+    assert list(counts) == [0, 0, 8, 0]
 
 
 def test_weights_as_the_softmax_gives_them_where_not_renormalised(path):
@@ -139,7 +139,7 @@ def test_every_token_at_the_same_experts_drops_none(path):
     want, experts = per_token_loop(params, x, MOE)
     assert set(np.unique(experts)) == {2, 5}
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
-    assert list(counts) == [128, 2, 8]
+    assert list(counts) == [128, 2, 8, 0]
     assert float(jnp.abs(out).min(axis=-1).max()) > 0   # no row left at 0
 
 
@@ -156,7 +156,7 @@ def test_an_expert_with_no_row_and_a_single_row(path):
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
     one, counts = moe.moe_ffn_dropless(params, x[:1], MOE)     # one row
     np.testing.assert_allclose(np.asarray(one), want[:1], atol=2e-5)
-    assert list(counts) == [2, 2, 8]
+    assert list(counts) == [2, 2, 8, 0]
 
 
 def test_stacked_weights_take_the_layers_own_experts(path):
