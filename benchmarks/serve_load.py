@@ -100,6 +100,25 @@ def _closed_loop(stream, requests, prompts, seconds, clients, on_window):
     return records, origin, origin + seconds, pool, futures
 
 
+def tokens_in_window(records, t0: float, t1: float) -> int:
+    """What reached a client inside the window, over every record of the
+    run (an open loop's ramp too): one for each token that arrived in
+    ``[t0, t1]``, and a request's ``prompt_len`` where its FIRST token
+    did, the client's evidence that the prompt was read. A prompt is
+    credited once and whole, never pro rata: a count, not an estimate.
+    A request in flight at the close counts for what it had delivered,
+    so the number does not jump with the side of the close a request
+    ends on; a failed request counts for nothing."""
+    total = 0
+    for r in records:
+        if r.error or not r.token_times:
+            continue
+        total += sum(t0 <= t <= t1 for t in r.token_times)
+        if t0 <= r.token_times[0] <= t1:
+            total += r.request.prompt_len
+    return total
+
+
 # -- the reference comparison's decision ------------------------------
 # the lists of readings the comparison gives (reference_check: the
 # seeded probe through the engine's programs; served_check: a sample of
@@ -401,7 +420,7 @@ def run(cell: dict, args, per_layer: dict) -> dict:
     samples = {
         **at_end["samples"],
         "window_s": t1 - t0,
-        "tokens_in_window": done_tokens,
+        "tokens_in_window": tokens_in_window(records, t0, t1),
         "ttft_ms": ttft, "itl_ms": itl,
     }
     result = {
@@ -457,6 +476,10 @@ def run(cell: dict, args, per_layer: dict) -> dict:
 
     notes.update({
         "requests_in_window": len(in_window), "completed_in_window": done,
+        # the count until PR 60 (prompt + answer of the requests that
+        # ended inside), beside the one serve_tokens_per_s reads
+        "tokens_completed_in_window": done_tokens,
+        "tokens_in_window": samples["tokens_in_window"],
         "arrived_second_half": sum(r.due >= half for r in in_window),
         "completed_second_half": sum(
             bool(r.token_times) and half <= r.token_times[-1] <= t1

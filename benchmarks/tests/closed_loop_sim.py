@@ -2,8 +2,12 @@
 simulated before chip time is spent on it: the engine as two costs read
 off one traced run (a prefill chunk call and a decode call, seconds),
 the cell's own traffic (``traffic.closed_loop``: the pool, its order and
-where ``--seed`` enters it) and the window's rule (a request counts if it
-completes inside it).
+where ``--seed`` enters it) and the window's rule. Since PR 60 that rule
+is ``serve_load.tokens_in_window``'s: every token emitted inside the
+window counts, and a request's prompt whole with its first token,
+whether or not the request ends inside. Until then a request counted,
+prompt and answer, if it completed inside; that count is printed beside
+the new one (``old rule``), and the readings quoted below are of it.
 
     python benchmarks/tests/closed_loop_sim.py <cell> <chunk call s> <decode call s> [<prompt_len.max> ...]
 
@@ -13,7 +17,8 @@ clients send their next request when the last is answered. It prints,
 for the cell's traffic (or with ``prompt_len.max`` lowered to each value
 given), the median tokens/s, the requests completed, and the spread
 (quartiles over the median) of 32 sets of six seeds: its median, its
-worst, and the share of sets under 3.6 % and under 5 %. PR 46 sized
+worst, and the share of sets under 3.6 % and under 5 %; then the same
+under the old rule. PR 46 sized
 ``mellum2-12b-a2.5b.serve-ide-mix`` with it (PERF.md section 6): a chip
 run of the named traffic read 85 requests completed where this reads 87
 at 32 ms a chunk and 13.7 ms a decode, and twelve runs at prompts to
@@ -39,7 +44,8 @@ WINDOW_S = 50.0
 
 
 def run(tr: dict, cell: dict, seed: int, t_chunk: float, t_decode: float):
-    """-> (tokens/s, requests completed) of one simulated window."""
+    """-> (tokens/s, requests completed, tokens/s by the rule until
+    PR 60) of one simulated window."""
     sv = cell["serve"]
     lanes = sv["max_batch_size"]
     n_shards = sv.get("engine_kwargs", {}).get("max_slots", lanes) // lanes
@@ -47,7 +53,7 @@ def run(tr: dict, cell: dict, seed: int, t_chunk: float, t_decode: float):
     requests = traffic.closed_loop(tr, seed)
     shards = [{"prefilling": [], "active": [], "free": lanes}
               for _ in range(n_shards)]
-    sent = alive = done = done_tokens = 0
+    sent = alive = done = done_tokens = arrived = 0
     t = 0.0
 
     def admit():
@@ -61,6 +67,7 @@ def run(tr: dict, cell: dict, seed: int, t_chunk: float, t_decode: float):
             shard["free"] -= 1
             shard["prefilling"].append(
                 {"left": r.prompt_len, "out": r.max_tokens,
+                 "prompt": r.prompt_len,
                  "size": r.prompt_len + r.max_tokens})
 
     admit()
@@ -74,6 +81,7 @@ def run(tr: dict, cell: dict, seed: int, t_chunk: float, t_decode: float):
                 if p["left"] == 0:      # its first token comes with it
                     s["prefilling"].pop(0)
                     p["out"] -= 1
+                    p["first"] = True
                     s["active"].append(p)
             if s["active"]:
                 dt += t_decode
@@ -81,6 +89,9 @@ def run(tr: dict, cell: dict, seed: int, t_chunk: float, t_decode: float):
         for s in shards:
             still = []
             for p in s["active"]:
+                if t <= WINDOW_S:       # what this step delivered
+                    arrived += 1 + (
+                        p["prompt"] if p.pop("first", False) else 0)
                 if p["out"] <= 0:
                     if t <= WINDOW_S:
                         done, done_tokens = done + 1, done_tokens + p["size"]
@@ -91,7 +102,7 @@ def run(tr: dict, cell: dict, seed: int, t_chunk: float, t_decode: float):
                     still.append(p)
             s["active"] = still
         admit()
-    return done_tokens / WINDOW_S, done
+    return arrived / WINDOW_S, done, done_tokens / WINDOW_S
 
 
 def main(argv) -> int:
@@ -103,18 +114,21 @@ def main(argv) -> int:
             tr["prompt_len"]["max"] = most
         seeds = np.random.default_rng(1).integers(2**31, 2**31 + 10**6, 192)
         runs = [run(tr, cell, int(s), t_chunk, t_decode) for s in seeds]
-        rates = [r[0] for r in runs]
-        spreads = []
-        for i in range(0, len(rates), 6):
-            q = statistics.quantiles(rates[i:i + 6], n=4)
-            spreads.append((q[2] - q[0]) / statistics.median(rates[i:i + 6]))
-        print(f"prompt_len.max {tr['prompt_len']['max']}: tokens/s "
-              f"{statistics.median(rates):.0f}, completed "
-              f"{statistics.median(r[1] for r in runs):.0f}, spread of a set "
-              f"of six: median {100 * statistics.median(spreads):.1f} % worst "
-              f"{100 * max(spreads):.1f} %, sets under 3.6 %: "
-              f"{sum(s < 0.036 for s in spreads)} of {len(spreads)}, under "
-              f"5 %: {sum(s < 0.05 for s in spreads)} of {len(spreads)}")
+        for rule, column in (("", 0), (" (old rule)", 2)):
+            rates = [r[column] for r in runs]
+            spreads = []
+            for i in range(0, len(rates), 6):
+                q = statistics.quantiles(rates[i:i + 6], n=4)
+                spreads.append(
+                    (q[2] - q[0]) / statistics.median(rates[i:i + 6]))
+            print(f"prompt_len.max {tr['prompt_len']['max']}{rule}: tokens/s "
+                  f"{statistics.median(rates):.0f}, completed "
+                  f"{statistics.median(r[1] for r in runs):.0f}, spread of a "
+                  f"set of six: median {100 * statistics.median(spreads):.1f}"
+                  f" % worst {100 * max(spreads):.1f} %, sets under 3.6 %: "
+                  f"{sum(s < 0.036 for s in spreads)} of {len(spreads)}, "
+                  f"under 5 %: {sum(s < 0.05 for s in spreads)} of "
+                  f"{len(spreads)}")
     return 0
 
 

@@ -119,7 +119,7 @@ def main():
         {"cell": cell, "samples": samples, "trace": trace,
          "scopes": sidecar["scopes"],
          "peak": spec.peak_for(jax.devices()[0].device_kind, True)},
-        spec.load_json("metrics", "moe_experts_roofline.ide.json")["args"]))
+        spec.load_json("metrics", "moe_experts_roofline.json")["args"]))
     print(jax.devices(), "steps", steps, os.path.getsize(
         stem + ".xplane.pb.gz"), "bytes;", len(trace.chips),
         "device plane(s), busy", trace_reduce.busy_seconds(trace), "s;",

@@ -205,11 +205,11 @@ def ctx_for(cell_name, serving, tmp_path, **kw):
       "scope_unattributed_pct.train", "grad_norm_p50.toy"},
      {"flash_attention_roofline"}),
     ("toy-moe.serve",
-     {"engine_tokens_per_step.docs", "engine_host_ms.docs",
-      "decode_occupancy_pct.docs", "prefill_wait_p95_ms.docs",
-      "kv_cache_move_share_pct.docs", "scope_unattributed_pct.docs",
+     {"engine_tokens_per_step", "engine_host_ms",
+      "decode_occupancy_pct", "prefill_wait_p95_ms",
+      "kv_cache_move_share_pct", "scope_unattributed_pct",
       "decode_ahead_pct.toy", "sample_share_pct.toy"},
-     {"prefill_device_share_pct.docs"}),
+     {"prefill_device_share_pct"}),
 ])
 def test_a_second_family_runs_through_every_door(cell, read, not_read):
     """The toy family's cells (its training side a mixture of experts
@@ -441,10 +441,10 @@ def test_metrics_from_the_engines_stats_equal_the_hand_count(serving):
                          ("prefill_p95_ms.chat", 3)):
         want = np.percentile([1e3 * r[column] for r in rows], 95)
         assert out[name]["value"] == pytest.approx(want)
-    # the same readers under the other cells' names
+    # the same reader under the entry the other cells share
     docs, _ = spec.evaluate(spec.cell_metrics(DOCS, True),
                             serving_ctx(DOCS, serving))
-    assert docs["engine_host_ms.docs"]["value"] == pytest.approx(1e3 * host)
+    assert docs["engine_host_ms"]["value"] == pytest.approx(1e3 * host)
 
 
 def test_input_stage_ms_is_the_windows_seconds_a_batch(tmp_path):
@@ -601,7 +601,7 @@ def test_the_new_shares_by_scope_equal_the_hand_count(serving):
     total = tp.op_seconds(programs)
     which = tp.assign_maps(programs, found["scopes"])
     was, now = pair["before"]["engine"], pair["after"]["engine"]
-    for cell, suffix in ((CHAT, "chat"), (DOCS, "docs"), (OVER, "over")):
+    for cell, suffix in ((CHAT, ".chat"), (DOCS, ""), (OVER, "")):
         out, not_read = spec.evaluate(
             spec.cell_metrics(cell, True), serving_ctx(cell, serving))
         assert not_read == {}
@@ -614,13 +614,13 @@ def test_the_new_shares_by_scope_equal_the_hand_count(serving):
                         c.names[i], "")
                     if scope in path.split("/"):
                         by_hand += float(c.end[i] - c.start[i])
-            shares[scope] = out[f"{scope}_share_pct.{suffix}"]["value"]
+            shares[scope] = out[f"{scope}_share_pct{suffix}"]["value"]
             assert shares[scope] == pytest.approx(
                 100 * by_hand / len(programs.chips) / total)
         assert 1 < shares["attn_cached"] < 60 and 1 < shares["mlp"] < 60
-        moved = out.get(f"kv_cache_move_share_pct.{suffix}", {"value": 0.0})
+        moved = out.get(f"kv_cache_move_share_pct{suffix}", {"value": 0.0})
         assert sum(shares.values()) + moved["value"] < 100
-        assert out[f"prefill_tokens_per_chunk.{suffix}"]["value"] \
+        assert out[f"prefill_tokens_per_chunk{suffix}"]["value"] \
             == pytest.approx(
                 (now["prefill_tokens"] - was["prefill_tokens"])
                 / (now["prefill_chunks"] - was["prefill_chunks"]))

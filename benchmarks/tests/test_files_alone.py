@@ -25,8 +25,9 @@ there but ``BENCHMARK.json``:
     names than the stand-in's, so a test shared by every cell that
     turned on a key or a leaf of the fixture's would fail here,
 
-each with a handful of per-layer metrics (files copied from the
-``.docs`` ones, entries of their own) and a probe of its own (32
+each with a handful of per-layer metrics (its name added to the
+``workloads`` list of the entries the serving cells share, and no file:
+a reading is one entry since PR 60) and a probe of its own (32
 positions behind a whole chunk and 16 decodes: what
 ``test_serving_reference.py`` asks of a cell of BENCHMARK.json by
 property, and other sizes than any cell here has). Then it runs
@@ -48,7 +49,6 @@ declares the same in a checkout of any commit (at PR 36's the run
 fails in ``test_contract.py``, ``test_doors.py`` and
 ``test_serving_reference.py``: PERF.md section 6, PR 37)."""
 
-import copy
 import json
 import os
 import shutil
@@ -60,12 +60,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
-# (a), (b) and (c): the fixture cell each is a copy of, its new name, the
-# suffix of its metrics, the `.docs` metrics copied for it, and what its
+# (a), (b) and (c): the fixture cell each is a copy of, its new name,
+# the shared per-layer entries whose lists it joins, and what its
 # ``reference_check`` states otherwise than the fixture's
 SERVED = {
     "of": "toy-moe.serve", "config": "fourth-served", "traffic": "serve-mix",
-    "suffix": "fourth",
     "metrics": ["engine_tokens_per_step", "engine_host_ms",
                 "decode_occupancy_pct", "prefill_wait_p95_ms",
                 "kv_cache_move_share_pct", "scope_unattributed_pct"],
@@ -79,14 +78,14 @@ SERVED = {
 }
 ROUTED = {
     "of": "routed-standin.serve", "config": "fifth-routed",
-    "traffic": "serve", "suffix": "fifth",
+    "traffic": "serve",
     "metrics": ["engine_tokens_per_step", "engine_host_ms",
                 "decode_occupancy_pct"],
     "check": {"length": 96, "positions": 32, "decode_steps": 16},
 }
 HYBRID = {
     "of": "hybrid-standin.serve", "config": "sixth-hybrid",
-    "traffic": "serve", "suffix": "sixth",
+    "traffic": "serve",
     # a family of its own (files_alone/), and the keys of the fixture's
     # configuration that its schema states under another name
     "family": "sixth_kind", "renamed": {"pattern": "mixers"},
@@ -104,10 +103,11 @@ NEEDS_THE_PROGRAM = {
 
 def declare(root: str) -> list:
     """Adds ``SERVED``, ``ROUTED`` and ``HYBRID`` to the checkout at
-    ``root`` as a PR would: ``configs/<config>.json``,
-    ``workloads/<cell>.json`` and ``metrics/<metric>.<suffix>.json`` as
-    new files (for ``HYBRID`` its family's two modules too), and their
-    entries in ``BENCHMARK.json``. Returns the new cells' names."""
+    ``root`` as a PR would: ``configs/<config>.json`` and
+    ``workloads/<cell>.json`` as new files (for ``HYBRID`` its family's
+    two modules too), their entries in ``BENCHMARK.json``, and the
+    cell's name in the ``workloads`` list of each metric it reports.
+    Returns the new cells' names."""
     bench = os.path.join(root, "benchmarks")
     tree = os.path.join(bench, "tests", "tree")
 
@@ -122,7 +122,7 @@ def declare(root: str) -> list:
             json.dump(obj, f, indent=1)
 
     declared = load(root, "BENCHMARK.json")
-    docs = {e["name"]: e for e in declared["per_layer"]}
+    shared = {e["name"]: e for e in declared["per_layer"]}
     serve_rate = next(e for e in declared["end_to_end"]
                       if e["name"] == "serve_tokens_per_s")
     names = []
@@ -161,11 +161,7 @@ def declare(root: str) -> list:
                    "name, with a probe of its own"})
         serve_rate["workloads"].append(name)
         for metric in new["metrics"]:
-            entry = copy.deepcopy(docs[f"{metric}.docs"])
-            entry.update(name=f"{metric}.{new['suffix']}", workloads=[name])
-            declared["per_layer"].append(entry)
-            add(load(bench, "metrics", f"{metric}.docs.json"),
-                "metrics", f"{entry['name']}.json")
+            shared[metric]["workloads"].append(name)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(declared, f, indent=1)
     return names

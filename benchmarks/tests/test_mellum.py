@@ -39,7 +39,7 @@ def test_expert_work_counts_what_was_asked():
 
 def test_the_roofline_reader_reads_its_recording_with_the_chips_peaks():
     ctx = serving_ctx(CELL, None)
-    args = spec.load_json("metrics", "moe_experts_roofline.ide.json")["args"]
+    args = spec.load_json("metrics", "moe_experts_roofline.json")["args"]
     assert isinstance(moe_experts_roofline.read(ctx, args), spec.NotRead)
     ctx["peak"] = spec.load_json("peaks.json")["TPU v5 lite"]
     share = moe_experts_roofline.read(ctx, args)

@@ -1,11 +1,12 @@
 """The five metric files over a token's way back and a decode call's
 empty lanes (``token_backlog_ms``, ``token_wake_ms``, ``token_yield_ms``,
-``decode_lanes_prefilling_pct``, ``decode_lanes_free_pct``): files with
-no entry in BENCHMARK.json yet and no cell in their names, each read
-through ``spec.evaluate`` from two ``engine_stats()`` snapshots of a toy
-server as ``holder.engine_deltas`` flattens them, and made a second time
-by hand. On the snapshots of a program from before the counters every
-one of them says what it did not find, and raises nothing."""
+``decode_lanes_prefilling_pct``, ``decode_lanes_free_pct``): no cell in
+their names, since PR 60 one entry each in BENCHMARK.json with its cells
+in a list, each read through ``spec.evaluate`` from two
+``engine_stats()`` snapshots of a toy server as ``holder.engine_deltas``
+flattens them, and made a second time by hand. On the snapshots of a
+program from before the counters every one of them says what it did not
+find, and raises nothing."""
 
 import dataclasses
 import json
@@ -27,8 +28,7 @@ TOKENS, NAP_S = (12, 5, 5), 0.02
 
 
 def metrics():
-    """The five files as ``spec.cell_metrics`` would hand them over, the
-    unit from the entry each still waits for."""
+    """The five files as ``spec.cell_metrics`` hands them over."""
     return {name: {**spec.load_json("metrics", f"{name}.json"), "unit": unit}
             for name, unit in FILES.items()}
 
@@ -100,7 +100,7 @@ def test_the_files_read_what_the_snapshots_counted(snapshots):
     # with the occupancy the accepted files read, the lanes are whole
     assert (grew("decode_lanes_active") + grew("decode_lanes_prefilling")
             + grew("decode_lanes_free")) == total
-    occupancy = spec.load_json("metrics", "decode_occupancy_pct.reason.json")
+    occupancy = spec.load_json("metrics", "decode_occupancy_pct.json")
     read, _ = spec.evaluate({"occupancy": {**occupancy, "unit": "%"}},
                             {"samples": samples})
     assert (read["occupancy"]["value"]
@@ -122,12 +122,23 @@ def test_a_program_from_before_the_counters_reads_nothing():
 
 
 @pytest.mark.parametrize("name", sorted(FILES))
-def test_a_file_waits_for_one_entry_over_several_cells(name):
-    """No cell's suffix in the name, no entry yet: the ``benchmark`` PR
-    that makes room declares each once, with a ``workloads`` list."""
+def test_a_file_has_one_entry_over_several_cells(name):
+    """No cell's suffix in the name; one entry, with a ``workloads``
+    list, whose unit is the one the file is read in here. (The serving
+    cells of the llama family are not in the lists yet: the recording
+    they are tried on predates the counters. PERF.md section 7.)"""
     on_file = spec.load_json("metrics", f"{name}.json")
     assert on_file["name"] == name and "." not in name
     assert on_file["reader"] == "quotient"
     assert on_file["moves"] == "serve_tokens_per_s"
     assert on_file["layer"] in ("serve stack", "engine loop")
-    assert name not in [e["name"] for e in spec.declared("per_layer")]
+    entries = [e for e in spec.declared("per_layer") if e["name"] == name]
+    assert len(entries) == 1
+    entry = entries[0]
+    assert (entry["unit"], entry["layer"], entry["moves"]) == (
+        FILES[name], on_file["layer"], on_file["moves"])
+    assert len(entry["workloads"]) > 1
+    assert "ai21-jamba2-3b.serve-reason" in entry["workloads"]
+    for cell in entry["workloads"]:
+        assert spec.cell_metrics(cell, traced=True)[name]["unit"] \
+            == FILES[name]
