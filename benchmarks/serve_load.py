@@ -146,11 +146,32 @@ def served_sample(records, seed: int, count: int) -> list:
     return [done[longest]] + [done[rest[i]] for i in drawn]
 
 
+# A cell whose ``reference_check`` says ``"routing": "engine"`` has its
+# probe's rows and decodes compared under the engine's own routing
+# choices (``server.routed_rows``), and one list more: at every row of
+# the probe the largest ``route_margin`` over its routed layers, how far
+# the worst expert the engine chose lies under the reference's own k-th
+# best, in units of the router's logits (0 where the two would have
+# chosen the same), held to the cell's ``route_margin_tol``: a choice the
+# reference would not have made is allowed only where the reference was
+# itself near a tie.
+ROUTING = "engine"      # the one thing the key ``routing`` may say
+ROUTED_READINGS = {**READINGS, "route_margin": "route_margin_tol"}
+
+
+def routed(check: dict) -> bool:
+    return check.get("routing") == ROUTING
+
+
+def readings_of(check: dict) -> dict:
+    return ROUTED_READINGS if routed(check) else READINGS
+
+
 def summary(ref: dict, check: dict) -> dict:
     """Of each list of readings its median, its largest, and the share
     over the limit; its limit beside them."""
     out = {}
-    for name, limit in READINGS.items():
+    for name, limit in readings_of(check).items():
         xs, tol = ref[name], check[limit]
         out[name] = {
             "n": len(xs), "median": statistics.median(xs) if xs else None,
@@ -234,16 +255,38 @@ def counted(ref: dict, check: dict) -> dict:
     ``choice_gap_tol`` of the request that is furthest over (or least
     under) its own allowance: a lane's fault reads so at every decoded
     token of a request through it, a sound engine at a few of a hundred,
-    and pooled with a thousand tokens a short request would hide."""
+    and pooled with a thousand tokens a short request would hide.
+
+    A cell that says ``"routing": "engine"`` states no share of rows:
+    each of the probe's lists (its rows, its decodes' gaps, the margins)
+    is held at every reading, its largest beside its limit; no
+    shortened last chunk of the probe may have chosen otherwise than the
+    whole one at a row both compute (``server.probe_rows``); the served
+    tokens alone, which carry no choices and are read against
+    ``reference_logits``, are counted, all together and by request,
+    beside ``allowed`` at the ``choice_gap_over_share`` the cell may
+    state."""
     out = {"unread_or_not_finite": [
         (not ref["finite"]) + sum(
             (not ref[name]) + sum(not math.isfinite(x) for x in ref[name])
-            for name in READINGS), 0]}
+            for name in readings_of(check)), 0]}
 
     def over(xs, limit):
         return [sum(not x <= check[limit] for x in xs),
                 allowed(len(xs), check.get(OVER_SHARES[limit], 0.0))]
 
+    if routed(check):
+        out["shortened_calls_chose_otherwise"] = [
+            ref["shortened_calls_chose_otherwise"], 0]
+        for name, limit in ROUTED_READINGS.items():
+            if name != "served_choice_gap":
+                out[name] = [max(ref[name], default=math.inf), check[limit]]
+        out["served_choice_gap_over"] = over(ref["served_choice_gap"],
+                                             "choice_gap_tol")
+        out["request_choice_gap_over"] = max(
+            (over(xs, "choice_gap_tol") for xs in by_request(ref)[1:]),
+            key=lambda pair: (pair[0] - pair[1], pair[0]), default=[0, 0])
+        return out
     for limit in OVER_SHARES:
         out[limit[:-len("_tol")] + "_over"] = over(
             [x for name, of in READINGS.items() if of == limit
@@ -259,7 +302,9 @@ def matches_reference(ref: dict, check: dict) -> bool:
     request, no more readings over their limit than ``allowed`` at the
     share the cell states (``counted``). A cell that states none, as the
     three serving cells do, is held at every position and every served
-    token read: every reading at or under its limit."""
+    token read: every reading at or under its limit; so are the probe's
+    rows, gaps and margins of a cell compared under the engine's own
+    routing choices, whose pairs are then [largest, limit]."""
     return all(count <= most for count, most in counted(ref, check).values())
 
 
@@ -267,8 +312,10 @@ def compared(ref: dict, check: dict) -> dict:
     """Each number compared beside its limit, for the run's last line:
     each list's largest reading beside its limit where every reading
     decides, the counts beside their allowances where the cell states a
-    share."""
-    if states_a_share(check):
+    share; of a cell that says ``"routing": "engine"`` the largest row,
+    gap and margin beside their limits and the served counts beside
+    their allowances."""
+    if states_a_share(check) or routed(check):
         return counted(ref, check)
     return {name: [of["max"] if of["n"] else math.inf, of["limit"]]
             for name, of in summary(ref, check).items()}
@@ -280,6 +327,8 @@ def check_cell(cell: dict) -> None:
     compares no row before ``SHARES_FROM_ROW``."""
     check, tr = cell["serve"]["reference_check"], cell["traffic"]
     name = cell.get("name")
+    if "routing" in check:
+        check_routed(cell)
     for share, ceiling in SHARE_CEILINGS.items():
         stated = check.get(share, 0.0)
         if (isinstance(stated, bool) or not isinstance(stated, (int, float))
@@ -307,11 +356,47 @@ def check_cell(cell: dict) -> None:
                 "rows take a flip whole (serve_load.SHARES_FROM_ROW)")
 
 
+def check_routed(cell: dict) -> None:
+    """Of a cell whose ``reference_check`` has the key ``routing``: it
+    says ``"engine"``; it states no ``rel_rms_over_share``, since under
+    the engine's own choices every row decides; it states
+    ``route_margin_tol``, a number over 0; and its family gives
+    ``reference_routed``."""
+    check, name = cell["serve"]["reference_check"], cell.get("name")
+    if not routed(check):
+        raise ValueError(
+            f"cell {name}: reference_check.routing is {check['routing']!r}; "
+            f"the one thing it may say is \"{ROUTING}\" (the rows compared under "
+            "the engine's own routing choices); a cell compared under the "
+            "reference's own leaves the key out")
+    if check.get("rel_rms_over_share"):
+        raise ValueError(
+            f"cell {name} says \"routing\": \"engine\" and states "
+            f"rel_rms_over_share {check['rel_rms_over_share']!r}: under the "
+            "engine's own choices no row is expected over rel_rms_tol, so "
+            "every row decides and the cell states no share of them "
+            "(choice_gap_over_share, of the served tokens, it may)")
+    tol = check.get("route_margin_tol")
+    if (isinstance(tol, bool) or not isinstance(tol, (int, float))
+            or not 0 < tol < math.inf):
+        raise ValueError(
+            f"cell {name} says \"routing\": \"engine\" and its "
+            f"reference_check.route_margin_tol is {tol!r}: a number over 0, "
+            "in units of the router's logits, set between what the cell's "
+            "own sound engine and a router fault read")
+    if not hasattr(spec.family_of(cell["hp"]), "reference_routed"):
+        raise ValueError(
+            f"cell {name} says \"routing\": \"engine\" and the family of its "
+            f"configuration {cell['hp'].get('name')!r} gives no "
+            "reference_routed(params, tokens, hp, choices, last=0) -> "
+            "(logits, margin) (benchmarks/README.md, 'A served family')")
+
+
 def run(cell: dict, args, per_layer: dict) -> dict:
     from ray_tpu import serve
     from ray_tpu.llm import build_llm_app
 
-    from .server import BenchLLMServer, SeededLLMConfig
+    from .server import BenchLLMServer, SeededLLMConfig, no_door
 
     hp, tr, sv = cell["hp"], cell["traffic"], cell["serve"]
     phases = {"runner_ready": time.time()}  # where set-up goes, wall clock
@@ -348,6 +433,8 @@ def run(cell: dict, args, per_layer: dict) -> dict:
         warm = call("warm_up", sv["warm_up_prompt_lens"])
         phases["warmed_up"] = time.time()
         check = sv["reference_check"]
+        if routed(check) and not call("reads_choices"):
+            raise RuntimeError(no_door(f"cell {cell['name']}"))
         probe = traffic.prompt_tokens(
             args.seed, traffic.Request(10**6, 0.0, tr["probe_prompt_len"], 8),
             hp["vocab_size"])

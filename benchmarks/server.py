@@ -12,7 +12,7 @@ import time
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm.serve import LLMServer
 
-from . import holder, spec, traffic
+from . import holder, serve_load, spec, traffic
 
 
 @dataclasses.dataclass
@@ -75,13 +75,45 @@ def room_for_a_copy(cache) -> None:
                 "'A served family')")
 
 
+def no_door(cell: str) -> str:
+    """The sentence a cell (``cell``: "cell <name>") that says
+    ``"routing": "engine"`` fails by where the engine built for it
+    cannot say what it chose."""
+    return (f"{cell}: its reference_check says \"routing\": "
+            f"\"{serve_load.ROUTING}\", so its rows are compared under the "
+            "engine's own routing choices, and the engine built for it gives no "
+            "read_choices(cache): of the cache a _prefill or _decode call "
+            "returned, the experts every routed layer chose in that call, "
+            "int32 (routed layers, rows, k) (benchmarks/README.md, 'A served "
+            "family')")
+
+
+def choices_said(chunks: list, short: list, decodes: list, after: list,
+                 tail: int) -> dict:
+    """What ``probe_rows`` hands back of an engine's choices, from each
+    call's (routed layers, n, k): the real ``chunks`` in order (the last
+    of them the whole last chunk, of ``tail`` rows), the ``short`` last
+    chunks (shortened by 1, 2, ...), each decode's lane 0 and the
+    one-token call ``after`` each decode."""
+    import numpy as np
+
+    after = np.concatenate(after, 1)
+    return {
+        "rows": np.concatenate(chunks + decodes + [after[:, -1:]], 1),
+        "after": after, "shortened_calls_chose_otherwise": sum(
+            not np.array_equal(mine, chunks[-1][:, :tail - back])
+            for back, mine in enumerate(short, 1))}
+
+
 def probe_rows(eng, seq, positions: int, decode_steps: int):
     """The seeded probe ``seq`` through the engine's own jitted
     ``_prefill`` and ``_decode``, into slot 0 of its first cache shard;
     the engine must be idle. Returns the logits of the probe's last
     ``positions`` rows (the last first), the tokens the probe's last row
     and then ``decode_steps`` greedy decodes chose, and the logits of the
-    row behind each decode, all float32 on the host.
+    row behind each decode, all float32 on the host; and, of an engine
+    that can say what it chose, those choices (below; None of any other,
+    which is read as before there was such a door).
 
     The programs return logits only here, so most of these calls are
     made only to read a row, and a call that a request would not make
@@ -108,7 +140,30 @@ def probe_rows(eng, seq, positions: int, decode_steps: int):
     which returns the logits the next step chooses from and attends to
     every row the decodes wrote, runs on a copy of the cache the decode
     returned; the next decode feeds that token to the real cache, which
-    has not seen it."""
+    has not seen it.
+
+    *The choices.* An engine MAY give ``read_choices(cache)``: of the
+    cache a ``_prefill`` or ``_decode`` call returned, the experts every
+    routed layer chose in that call, int32 (routed layers, rows, k), the
+    bucket's rows of a ``_prefill`` (those at or behind ``length`` are
+    ignored) or the lanes of a ``_decode`` first; a leaf the timed
+    programs write, so nothing of what a cache holds is known here
+    either. Every call's are read before its cache is dropped or handed
+    on. Returned: ``rows`` (routed layers, ``length`` + ``decode_steps``
+    + 1, k), what the calls that advanced the real cache chose at each
+    row of the sequence (whole chunks, the last chunk, each decode's
+    lane 0), its last row the last scratch call's (no real call fed that
+    token); and ``after`` (routed layers, ``decode_steps``, k), what the
+    one-token scratch call behind each decode chose at its row, which is
+    the call that row's logits are read from and another program than
+    the decode that later writes the row, so near a tie it may choose
+    otherwise. A shortened last chunk computes rows the real call
+    computes too, by the same program from the same cache, so its
+    choices there are the real call's unless ``read_choices`` does not
+    return what a call chose or a row's choices depend on the rows
+    behind ``length`` (a scale taken over the whole bucket, say):
+    ``shortened_calls_chose_otherwise`` counts the calls where they are
+    not, and a cell compared under these choices holds it to 0."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -119,6 +174,12 @@ def probe_rows(eng, seq, positions: int, decode_steps: int):
     onehot = np.zeros(eng.max_batch, np.float32)
     onehot[0] = 1.0
     shard = eng.shards[0]
+    read = getattr(eng, "read_choices", None)
+    said = []       # a call's choices at its first n rows, call after call
+
+    def say(cache, n):
+        if read is not None:
+            said.append(np.asarray(read(cache)[:, :n]))
 
     def prefill(tokens, pos, real=None, scratch=False):
         """One call: on the real cache, which it advances (dispatched,
@@ -132,6 +193,7 @@ def probe_rows(eng, seq, positions: int, decode_steps: int):
             eng.params, jax.tree_util.tree_map(jnp.copy, shard.cache)
             if scratch else shard.cache, padded, onehot,
             np.asarray([pos], np.int32), real or len(tokens), bucket=bucket)
+        say(cache, real or len(tokens))
         if not scratch:
             shard.cache = cache
             return logits
@@ -161,11 +223,20 @@ def probe_rows(eng, seq, positions: int, decode_steps: int):
             tokens[0], lens[0] = chosen[-1], length + i
             toks, shard.cache, eng._rng = eng._decode(
                 eng.params, shard.cache, tokens, lens, temps, eng._rng)
+            say(shard.cache, 1)             # lane 0's row
             chosen.append(int(np.asarray(toks)[0]))
             got_after.append(prefill(chosen[-1:], length + i + 1,
                                      scratch=True))
         got_after = fetched(got_after)
-    return got_prefill, chosen, got_after
+    if read is None:
+        return got_prefill, chosen, got_after, None
+    # in the order made: the whole chunks, the shortened last chunks,
+    # the last chunk, then a decode and its one-token scratch call by turns
+    whole, rest = said[:len(starts) - 1], said[len(starts) - 1:]
+    short, last, rest = rest[:positions - 1], rest[positions - 1], rest[
+        positions:]
+    return got_prefill, chosen, got_after, choices_said(
+        whole + [last], short, rest[0::2], rest[1::2], tail)
 
 
 def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
@@ -216,27 +287,38 @@ def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
             f"reference_check: {length} tokens, {decode_steps} decode steps "
             f"and a bucket of {eng.buckets[0]} rows do not fit the cache's "
             f"{eng.max_seq} rows")
+    routed = serve_load.routed(check)
+    if routed and getattr(eng, "read_choices", None) is None:
+        raise RuntimeError(no_door(
+            f"a cell of the configuration {hp.get('name')!r}"))
     t0 = time.perf_counter()
-    got_prefill, chosen, got_after = probe_rows(eng, seq, positions,
-                                                decode_steps)
+    got_prefill, chosen, got_after, said = probe_rows(eng, seq, positions,
+                                                      decode_steps)
     engine_s = time.perf_counter() - t0
     engine_peak = holder.memory_peak_bytes()
 
     # the reference's rows: positions length - positions .. length +
     # decode_steps, the last of them the row behind the last decode
     full = np.concatenate([seq, np.asarray(chosen, np.int32)])
-    want = np.asarray(family.reference_logits(
-        eng.params, full, hp, last=positions + decode_steps + 1), np.float32)
+    rows = positions + decode_steps + 1
     at = positions - 1      # want[at] is the probe's last row, length - 1
+    if routed:
+        want, behind, routing = routed_rows(
+            family, eng.params, full, hp, said, rows, decode_steps)
+    else:
+        want = np.asarray(family.reference_logits(
+            eng.params, full, hp, last=rows), np.float32)
+        behind, routing = want[at + 2:], {}
 
     def rel_rms(got, ref):
         return float(np.sqrt(np.mean((got - ref) ** 2))
                      / np.sqrt(np.mean(ref ** 2)))
 
     return {
+        **routing,
         "prefill_rel_rms": [rel_rms(got_prefill[back], want[at - back])
                             for back in range(positions)],
-        "after_decode_rel_rms": [rel_rms(got_after[i], want[at + i + 2])
+        "after_decode_rel_rms": [rel_rms(got_after[i], behind[i])
                                  for i in range(decode_steps)],
         # how far under the reference's best logit each decode's token is
         "decode_choice_gap": [
@@ -251,6 +333,57 @@ def reference_readings(eng, family, seed: int, hp: dict, check: dict) -> dict:
         "engine_memory_peak_bytes": engine_peak,
         "total_s": time.perf_counter() - t0,
     }
+
+
+def routed_rows(family, params, full, hp: dict, said: dict, rows: int,
+                decode_steps: int):
+    """The reference's rows under the engine's own routing choices, for
+    a cell that says ``"routing": "engine"``: the family's
+    ``reference_routed(params, tokens, hp, choices, last=0) -> (logits,
+    margin)``, its float32 pass in which every routed layer takes the
+    experts in ``choices`` (routed layers, S, k) in place of its own
+    top-k, weighted by its own scores of them, and ``margin`` (routed
+    layers, S): how far the worst expert it was handed lies under its
+    own k-th best, in units of the router's logits, 0 where the two sets
+    are equal. One pass under ``said["rows"]`` (``probe_rows``: what the
+    real cache went through) gives the last ``rows`` rows' logits, which
+    the prefill's rows and the decodes' tokens are read against. The row
+    behind a decode is read from a one-token call, which may choose
+    otherwise than the decode that later writes that row: where it did
+    (as sets, in any layer), one more pass with that row's choices
+    replaced by the call's own gives that row (what lies behind it in
+    that pass is not read). Returns those logits, the row behind each
+    decode, and ``route_margin`` (the largest over the layers at every
+    row of the sequence, then at every row read again),
+    ``shortened_calls_chose_otherwise`` (``probe_rows``),
+    ``routing_differs_share`` (of the (layer, row) pairs of the first
+    pass, those whose sets differ) and ``rows_read_again``."""
+    import numpy as np
+
+    length = len(full) - decode_steps - 1
+    want, margin = family.reference_routed(params, full, hp, said["rows"],
+                                           last=rows)
+    want, margin = np.asarray(want, np.float32), np.asarray(margin)
+    behind = list(want[rows - decode_steps:])
+    margins, again = list(margin.max(0)), 0
+    for i in range(decode_steps - 1):   # the last row has the call's own
+        row, own = length + i + 1, said["after"][:, i]
+        if np.array_equal(np.sort(own, -1), np.sort(said["rows"][:, row], -1)):
+            continue
+        under = said["rows"].copy()
+        under[:, row] = own
+        logits, margin_again = family.reference_routed(params, full, hp,
+                                                       under, last=rows)
+        behind[i] = np.asarray(logits[rows - decode_steps + i], np.float32)
+        margins.append(np.asarray(margin_again)[:, row].max())
+        again += 1
+    return want, behind, {
+        "routing": serve_load.ROUTING,
+        "shortened_calls_chose_otherwise":
+            said["shortened_calls_chose_otherwise"],
+        "route_margin": [float(x) for x in margins],
+        "routing_differs_share": float((margin > 0).mean()),
+        "rows_read_again": again}
 
 
 # the reference's pass over a served request is padded (behind the
@@ -355,6 +488,11 @@ class BenchLLMServer(LLMServer):
         probe is and at how many positions it is read."""
         return reference_readings(self.engine, spec.family_of(hp), seed, hp,
                                   check)
+
+    def reads_choices(self) -> bool:
+        """Whether the engine built here can say what it chose: what a
+        cell that says ``"routing": "engine"`` asks before its window."""
+        return getattr(self.engine, "read_choices", None) is not None
 
     def served_check(self, hp: dict, served: list) -> dict:
         """``served_readings`` of requests the window finished, once it

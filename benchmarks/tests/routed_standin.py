@@ -33,6 +33,12 @@ experts and the layers, in units of the router's logits (PERF.md section
 6, PR 34: what a rule that spares near-tied positions would have had to
 mark them by). ``readings`` lays the sides beside each other position by
 position; ``reach`` makes a flip on purpose and reads the rows behind.
+``reference_routed`` is the float32 side with every routed layer's
+experts handed to it (``choices``: what an engine said it chose), and
+``_handed_margin`` how far the worst of them lies under its own k-th
+best: the reference of a cell compared under the engine's own routing
+choices (benchmarks/README.md, "A served family"; PERF.md section 6, PR
+61).
 
 ``Engine`` is what ``benchmarks/server.py`` ``reference_readings`` holds
 of an engine and nothing more of one (benchmarks/README.md, "A served
@@ -42,7 +48,11 @@ token a lane; attention in the absorbed form over the cached latent rows
 (another order of summation than ``forward``'s, as an engine's is), a
 state layer's state and convolution tail carried through the chunk's
 real rows and left alone by the rows at or behind ``length``, both
-zeroed by a call that starts a sequence. No scheduler and no shards:
+zeroed by a call that starts a sequence; every call leaves what its
+routed layers chose in the cache's ``choices`` leaf, which
+``read_choices`` reads; built with ``router_fault`` it takes, at one row
+in 32 of its first routed layer, the best held expert it had passed
+over (and says so). No scheduler and no shards:
 ``serve`` is a loop of decodes over the lanes, which gives served tokens
 for ``served_readings``.
 
@@ -104,15 +114,16 @@ CHIP_HYBRID = dict(width=2304, experts=256, expert_width=1024, top_k=8,
                    rope_dim=64, rotary=0, q_rank=0, kv_rank=512,
                    pattern="SSSL", state_heads=32, state_dim=128, seq=1024,
                    seqs=2, score="sigmoid", scale=2.446, std=0.02)
-# a test's: its three state layers are the dense leading ones, so that a
-# routed layer's flip is carried by latent attention alone and the rows
-# behind it read as a sound row does (through a state layer they do not:
-# PERF.md section 6, PR 44), and five routed layers behind them, so that
-# the control (their shared experts in fp8) reads over the sound rows'
-# noise, which eight layers spread wider than four
-TOY_HYBRID = dict(TOY, layers=8, dense_layers=3, dense_width=256, rope_dim=8,
-                  rotary=0, q_rank=0, pattern="SSSLLLLL", state_heads=2,
-                  state_dim=16, seq=128)
+# a test's: as ``CHIP_HYBRID``, state layers BEHIND routed layers (a
+# dense leading layer, then seven routed ones, SSSL twice) and half of
+# the experts held, so that a routed layer's flip is carried through the
+# states into the rows behind it: against the reference's own choices
+# most of a sound engine's compared rows read over any limit that the
+# fp8 control still reads over (the wall: PERF.md section 6, PR 44), and
+# under the engine's own choices every one reads under it (PR 61)
+TOY_HYBRID = dict(TOY, layers=8, dense_layers=1, dense_width=256, rope_dim=8,
+                  rotary=0, q_rank=0, pattern="SSSL", state_heads=2,
+                  state_dim=16, seq=128, held=16)
 SIZES = tuple(CHIP)     # the keys a set of sizes has
 CONV = 4                # rows a state layer's convolution spans
 KINDS = "LS"            # the mixers a pattern is written in
@@ -274,19 +285,22 @@ def init_params(key, *, sizes):
                            runs_of(s), ends, ends[1:])]}
 
 
-def _route(h, w, s, force):
+def _route(h, w, s, force, choices=None):
     """The router in float32 on every side, as such models run it:
     (chosen (S, k), the held experts' weights (S, held), margin (S,)).
     Where the experts come in groups, only those of the ``groups_kept``
     groups whose two best selection scores sum highest can be chosen.
     ``force`` (S,) moves the best held expert that was passed over into
     the choice: a flip made on purpose. The margin is the experts' alone:
-    it does not see a group's near-tie."""
+    it does not see a group's near-tie. ``choices`` (S, k) takes the
+    place of the top-k: the experts another side chose, weighted by this
+    side's own scores of them; the margin is then ``_handed_margin``'s,
+    how far the worst of them lies under this side's own k-th best."""
     k, held = s["top_k"], s["held"]
     z = jnp.matmul(h.astype(F32), w["router"].astype(F32), precision="highest")
     scores = jax.nn.sigmoid(z) if s["score"] == "sigmoid" \
         else jax.nn.softmax(z, -1)
-    select = scores + w["bias"]
+    select = every = scores + w["bias"]
     if s["groups"] > 1:
         by_group = select.reshape(select.shape[0], s["groups"], -1)
         best = jax.lax.top_k(by_group, 2)[0].sum(-1)            # (S, groups)
@@ -301,22 +315,65 @@ def _route(h, w, s, force):
             (force[:, None] > 0) & (jax.nn.one_hot(
                 passed.argmax(-1), s["experts"]) > 0), jnp.inf, select)
     ranked, order = jax.lax.top_k(select, k + 1)
-    chosen = order[:, :k]
+    chosen = order[:, :k] if choices is None else choices
     picked = jnp.take_along_axis(scores, chosen, -1)
     weights = s["scale"] * picked / picked.sum(-1, keepdims=True)
     of_held = jnp.where(chosen[:, :, None] == jnp.arange(held), weights[
         :, :, None], 0.0).sum(1)
+    slope = scores * (1.0 - scores)
+    if choices is not None:
+        return chosen, of_held, _handed_margin(every, slope, choices, s)
     # a held expert in the choice: over the best passed over; one
     # passed over: under the last chosen. In units of the router's
     # logits: the gap of the two selection scores over their slopes (a
     # saturated score moves little for the same noise in its logit)
-    slope = scores * (1.0 - scores)
     edge = jnp.take_along_axis(slope, order[:, k - 1:], -1)   # last in, out
     mine, inside = select[:, :held], select[:, :held] >= ranked[:, k - 1:k]
     gap = jnp.where(inside, mine - ranked[:, k:], ranked[:, k - 1:k] - mine)
     across = jnp.where(inside, edge[:, 1:], edge[:, :1])
     margin = (gap / jnp.sqrt(slope[:, :held] ** 2 + across ** 2)).min(-1)
     return chosen, of_held, margin
+
+
+def _handed_margin(select, slope, choices, s):
+    """(S,): how far the worst of the experts ``choices`` (S, k) lies
+    under this side's own k-th best selection score ``select`` (S, E,
+    before any group is masked), in units of the router's logits as
+    ``_route`` takes a margin (the gap of the two scores over their
+    slopes); 0 where the two sets are equal. Where the experts come in
+    groups a handed expert shows that its group was kept: such a group
+    this side would have dropped is judged the same way, its score (the
+    sum of its two best) under the last group this side kept, over the
+    slopes of the four experts that make the two sums; the experts are
+    then ranked among the groups this side keeps once the handed ones
+    are (all of the handed ones, should they be more than are kept),
+    and the margin is the larger of the two."""
+    k = s["top_k"]
+    of_groups = 0.0
+    if s["groups"] > 1:
+        n = select.shape[0]
+        by_group = select.reshape(n, s["groups"], -1)
+        two, at = jax.lax.top_k(by_group, 2)
+        best = two.sum(-1)                                      # (S, groups)
+        steep = (jnp.take_along_axis(slope.reshape(by_group.shape), at, -1)
+                 ** 2).sum(-1)
+        kept, kept_at = jax.lax.top_k(best, s["groups_kept"])
+        last, last_steep = kept[:, -1:], jnp.take_along_axis(
+            steep, kept_at[:, -1:], -1)
+        handed = (choices[:, :, None] // by_group.shape[-1]
+                  == jnp.arange(s["groups"])).any(1)            # (S, groups)
+        of_groups = jnp.where(handed, (last - best) / jnp.sqrt(
+            steep + last_steep), 0.0).max(-1)
+        forced = jnp.where(handed, jnp.inf, best)
+        edge = jax.lax.top_k(forced, s["groups_kept"])[0][:, -1:]
+        select = jnp.where((forced >= edge)[:, :, None], by_group,
+                           -jnp.inf).reshape(select.shape)
+    ranked, order = jax.lax.top_k(select, k)
+    across = jnp.take_along_axis(slope, order[:, k - 1:], -1)
+    gap = ranked[:, k - 1:] - jnp.take_along_axis(select, choices, -1)
+    mine = jnp.take_along_axis(slope, choices, -1)
+    of_experts = (gap / jnp.sqrt(mine ** 2 + across ** 2)).max(-1)
+    return jnp.maximum(jnp.maximum(of_experts, of_groups), 0.0)
 
 
 def _qkv(h, w, s, side, pos):
@@ -436,13 +493,13 @@ def _no_state(s, dtype):
             jnp.zeros((CONV - 1, 3 * hs * ds), dtype))
 
 
-def _mlp(x, w, s, side, force):
+def _mlp(x, w, s, side, force, choices=None):
     """The layer's second half on rows x (n, D): (x after it, chosen
     (n, k) or None in a dense layer, margin (n,) or None)."""
     h = _norm(x)
     if "dense" in w:
         return x + _swiglu(h, w["dense"], side), None, None
-    chosen, of_held, margin = _route(h, w, s, force)
+    chosen, of_held, margin = _route(h, w, s, force, choices)
     out = _swiglu(h, w["shared"], side, fp8 if side == "fp8" else lambda a: a)
 
     def add(total, expert):
@@ -454,15 +511,17 @@ def _mlp(x, w, s, side, force):
     return x + out, chosen, margin
 
 
-def _layers(x, params, s, side, attend, caches, force):
+def _layers(x, params, s, side, attend, caches, force, choices=None):
     """x (n, D) through the dense layers and then the routed ones (each
     run of them scanned over its stacked weights).
     ``attend(kind, h, w, cache)`` is a layer's mixer over its normed
     input and its share of ``caches`` ({kind: what the layers of that
     mixer keep, their leading axis}; None without a cache), returning
     what the mixer adds and the layer's cache as it leaves it. ``force``
-    (n,) flips a choice in the first routed layer. Returns x, the caches
-    stacked again, chosen (routed layers, n, k) and the least margin."""
+    (n,) flips a choice in the first routed layer; ``choices`` (routed
+    layers, n, k) are the experts every routed layer takes in place of
+    its own top-k. Returns x, the caches stacked again, chosen (routed
+    layers, n, k) and each routed layer's margin (routed layers, n)."""
     done = dict.fromkeys(KINDS, 0)          # layers of each mixer so far
     left = {kind: [] for kind in KINDS}     # and their caches, in order
 
@@ -478,27 +537,29 @@ def _layers(x, params, s, side, attend, caches, force):
         x, _, _ = _mlp(x + add, w, s, side, None)
         left[kind].append(jax.tree_util.tree_map(lambda a: a[None], cache))
 
-    chosen, margins = [], []
+    chosen, margins, before = [], [], 0
     for (kind, n), weights in zip(runs_of(s), params["routed"]):
         forces = None if force is None else jnp.zeros(
             (n,) + force.shape, F32).at[0].set(0.0 if chosen else force)
+        handed = None if choices is None else choices[before:before + n]
+        before += n
 
         def body(x, layer, kind=kind):
-            w, cache, f = layer
+            w, cache, f, take = layer
             add, cache = attend(kind, _norm(x), w, cache)
-            x, picked, margin = _mlp(x + add, w, s, side, f)
+            x, picked, margin = _mlp(x + add, w, s, side, f, take)
             return x, (cache, picked, margin)
 
         x, (cache, picked, margin) = jax.lax.scan(
-            body, x, (weights, share(kind, n), forces))
+            body, x, (weights, share(kind, n), forces, handed))
         left[kind].append(cache)
         chosen.append(picked)
-        margins.append(margin.min(0))
+        margins.append(margin)
     if caches is not None:
         caches = {kind: jax.tree_util.tree_map(
             lambda *parts: jnp.concatenate(parts), *left[kind])
             for kind in caches}
-    return x, caches, jnp.concatenate(chosen), jnp.stack(margins).min(0)
+    return x, caches, jnp.concatenate(chosen), jnp.concatenate(margins)
 
 
 def _whole(side, s):
@@ -515,32 +576,36 @@ def _whole(side, s):
 
 
 @partial(jax.jit, static_argnames=("sizes", "side", "last"))
-def _forward(params, tokens, force, *, sizes, side, last):
+def _forward(params, tokens, force, choices, *, sizes, side, last):
     s = dict(sizes)
 
     def one(row):
-        toks, f = row
+        toks, f, handed = row
         x = params["embed"][toks].astype(F32 if side == "f32" else BF16)
         x, _, chosen, margin = _layers(x, params, s, side, _whole(side, s),
-                                       None, f)
+                                       None, f, handed)
         x = x[-last:] if last else x
         return _mm(_norm(x), params["head"], side).astype(F32), chosen, margin
 
-    return jax.lax.map(one, (tokens, force))
+    return jax.lax.map(one, (tokens, force, choices))
 
 
 def forward(params, tokens, sizes: dict, side: str, force=None,
-            last: int = 0) -> dict:
+            last: int = 0, choices=None) -> dict:
     """The stack over whole sequences ``tokens`` (B, S): logits (B, S or
-    ``last``, V) float32, ``chosen`` (routed layers, B, S, k), and
-    ``margin`` (B, S), the least over the layers. ``force`` (B, S) flips
-    a choice in the first routed layer at the positions it marks."""
+    ``last``, V) float32, ``chosen`` (routed layers, B, S, k),
+    ``margins`` (B, routed layers, S) and ``margin`` (B, S), the least
+    over the layers. ``force`` (B, S) flips a choice in the first routed
+    layer at the positions it marks; ``choices`` (B, routed layers, S,
+    k) are taken in place of every routed layer's own top-k, and the
+    margins are then ``_handed_margin``'s."""
     tokens = jnp.asarray(tokens, jnp.int32)
-    logits, chosen, margin = _forward(
+    logits, chosen, margins = _forward(
         params, tokens, None if force is None else jnp.asarray(force, F32),
+        None if choices is None else jnp.asarray(choices, jnp.int32),
         sizes=frozen(sizes), side=side, last=last)
     return {"logits": logits, "chosen": jnp.moveaxis(chosen, 0, 1),
-            "margin": margin}
+            "margins": margins, "margin": margins.min(1)}
 
 
 def reference_logits(params, tokens, hp: dict, last: int = 0):
@@ -548,6 +613,19 @@ def reference_logits(params, tokens, hp: dict, last: int = 0):
     one sequence, (S or ``last``, V)."""
     return forward(params, np.asarray(tokens)[None], hp, "f32",
                    last=last)["logits"][0]
+
+
+def reference_routed(params, tokens, hp: dict, choices, last: int = 0):
+    """What a family whose cell says ``"routing": "engine"`` gives
+    beside ``reference_logits``: the same float32 pass in which every
+    routed layer takes the experts ``choices`` (routed layers, S, k)
+    that the engine says it chose, weighted by this pass's own scores of
+    them, and ``margin`` (routed layers, S): how far the worst of them
+    lies under this pass's own k-th best, in units of the router's
+    logits, 0 where it would have chosen the same set."""
+    out = forward(params, np.asarray(tokens)[None], hp, "f32", last=last,
+                  choices=np.asarray(choices)[None])
+    return out["logits"][0], out["margins"][0]
 
 
 @jax.jit
@@ -623,11 +701,30 @@ def reach(seed: int, sizes: dict, at: int) -> dict:
 
 
 # ------------------------------------------------ the engine's two programs
+# the router fault: the rows (by their position in the sequence) at which
+# an engine built with ``router_fault`` takes, in its first routed layer,
+# the best held expert it had passed over
+FAULT_EVERY, FAULT_AT = 32, 7
+
+
+def _faulted(pos):
+    return (pos % FAULT_EVERY == FAULT_AT).astype(F32)
+
+
+def _said(cache: dict, chosen):
+    """The call's choices (routed layers, rows, k) written at the start
+    of the cache's ``choices`` leaf, which holds as many rows as the
+    largest bucket or the lanes, whichever is more: what
+    ``Engine.read_choices`` reads."""
+    return jax.lax.dynamic_update_slice(cache["choices"], chosen, (0, 0, 0))
+
+
 def _by_kind(cache: dict) -> dict:
     """An engine's cache, ``latent`` (layers of L, B, T, c + r) and,
     where the pattern has state layers, ``state`` (layers of S, B, H,
     dk, dv) float32 and ``conv`` (layers of S, B, CONV - 1, 3 H d), as
-    ``_layers`` takes it: what the layers of each mixer keep."""
+    ``_layers`` takes it: what the layers of each mixer keep (its
+    ``choices`` leaf is no layer's: ``_said``)."""
     out = {"L": cache["latent"]} if "latent" in cache else {}
     if "state" in cache:
         out["S"] = (cache["state"], cache["conv"])
@@ -660,16 +757,18 @@ def _cached(side, s, slot_rows, write, pos, through):
     return attend
 
 
-@partial(jax.jit, static_argnames=("sizes", "side", "bucket"),
+@partial(jax.jit, static_argnames=("sizes", "side", "bucket", "fault"),
          donate_argnums=(1,))
 def _prefill(params, cache, tokens, slot_onehot, start, length, *, sizes,
-             side, bucket):
+             side, bucket, fault=False):
     """One chunk ``tokens`` (1, bucket) of one sequence into the slot
     ``slot_onehot`` marks, at rows ``start`` (1,) on: all ``bucket``
     latent rows are written (those at or behind ``length`` are never
     read), a state layer's state and tail are carried through the first
     ``length`` rows, from nothing where ``start`` is 0, and the logits
-    (V,) of row ``length`` - 1 of the chunk returned."""
+    (V,) of row ``length`` - 1 of the chunk returned; every routed
+    layer's choices at the chunk's rows are left in the cache's
+    ``choices`` leaf. ``fault``: the router fault at ``_faulted`` rows."""
     s = dict(sizes)
     slot = slot_onehot.argmax()
     pos = start[0] + jnp.arange(bucket)
@@ -691,21 +790,25 @@ def _prefill(params, cache, tokens, slot_onehot, start, length, *, sizes,
             for mine, new in zip(cache, held))
 
     x = params["embed"][tokens[0]]
-    x, cache, _, _ = _layers(x, params, s, side,
-                             _cached(side, s, over, write, pos, through),
-                             _by_kind(cache), None)
+    x, kept, chosen, _ = _layers(x, params, s, side,
+                                 _cached(side, s, over, write, pos, through),
+                                 _by_kind(cache),
+                                 _faulted(pos) if fault else None)
     row = jax.lax.dynamic_index_in_dim(x, length - 1, 0, keepdims=True)
     logits = _mm(_norm(row), params["head"], side).astype(F32)[0]
-    return logits, _by_name(cache)
+    return logits, dict(_by_name(kept), choices=_said(cache, chosen))
 
 
-@partial(jax.jit, static_argnames=("sizes", "side"), donate_argnums=(1,))
-def _decode(params, cache, last_tokens, lengths, temps, rng, *, sizes, side):
+@partial(jax.jit, static_argnames=("sizes", "side", "fault"),
+         donate_argnums=(1,))
+def _decode(params, cache, last_tokens, lengths, temps, rng, *, sizes, side,
+            fault=False):
     """One greedy token a lane: lane b's ``last_tokens[b]`` written at
     row ``lengths[b]`` of its slot and attended from there (an idle lane
     writes the scratch row ``max_seq`` - 1), every lane's state and tail
     moved by its token (an idle lane's too: the call that next starts a
-    sequence there begins from nothing)."""
+    sequence there begins from nothing); every routed layer's choices, a
+    row a lane, are left in the cache's ``choices`` leaf."""
     s = dict(sizes)
     lanes = jnp.arange(lengths.shape[0])
 
@@ -724,11 +827,12 @@ def _decode(params, cache, last_tokens, lengths, temps, rng, *, sizes, side):
         return out[:, 0], tuple(held)
 
     x = params["embed"][last_tokens]
-    x, cache, _, _ = _layers(x, params, s, side,
-                             _cached(side, s, over, write, lengths, through),
-                             _by_kind(cache), None)
+    x, kept, chosen, _ = _layers(
+        x, params, s, side, _cached(side, s, over, write, lengths, through),
+        _by_kind(cache), _faulted(lengths) if fault else None)
     logits = _mm(_norm(x), params["head"], side).astype(F32)
-    return logits.argmax(-1).astype(jnp.int32), _by_name(cache), rng
+    return (logits.argmax(-1).astype(jnp.int32),
+            dict(_by_name(kept), choices=_said(cache, chosen)), rng)
 
 
 class Engine:
@@ -741,8 +845,9 @@ class Engine:
 
     def __init__(self, sizes: dict, params, side: str = "bf16",
                  max_batch: int = 8, max_seq: int = 2048,
-                 buckets=(16, 256)):
+                 buckets=(16, 256), router_fault: bool = False):
         self.sizes, self.side, self.params = frozen(sizes), side, params
+        self.router_fault = router_fault
         self.buckets, self.prefill_chunk = list(buckets), buckets[-1]
         self.max_batch, self.max_seq = max_batch, max_seq
         s = dict(self.sizes)
@@ -754,6 +859,11 @@ class Engine:
             cache["state"] = jnp.zeros((of["S"], max_batch, hs, ds, ds), F32)
             cache["conv"] = jnp.zeros(
                 (of["S"], max_batch, CONV - 1, 3 * hs * ds), BF16)
+        # what every call chose (``read_choices``): 4 B an expert, 0.6 MB
+        # at 7 routed layers, a chunk of 256 rows and 8 of them a row
+        cache["choices"] = jnp.zeros(
+            (s["layers"] - s["dense_layers"], max(max_batch, buckets[-1]),
+             s["top_k"]), jnp.int32)
         self.shards = [types.SimpleNamespace(cache=cache)]
         self._lock, self._rng = threading.Lock(), jax.random.PRNGKey(0)
 
@@ -764,12 +874,20 @@ class Engine:
                  bucket):
         return _prefill(params, cache, jnp.asarray(tokens),
                         jnp.asarray(slot_onehot), jnp.asarray(start), length,
-                        sizes=self.sizes, side=self.side, bucket=bucket)
+                        sizes=self.sizes, side=self.side, bucket=bucket,
+                        fault=self.router_fault)
 
     def _decode(self, params, cache, last_tokens, lengths, temps, rng):
         return _decode(params, cache, jnp.asarray(last_tokens),
                        jnp.asarray(lengths), temps, rng, sizes=self.sizes,
-                       side=self.side)
+                       side=self.side, fault=self.router_fault)
+
+    def read_choices(self, cache):
+        """Of the cache a ``_prefill`` or ``_decode`` returned: the
+        experts every routed layer chose in that call, int32 (routed
+        layers, rows, k), the bucket's rows or the lanes first (what
+        lies behind them is an earlier call's)."""
+        return cache["choices"]
 
     def serve(self, prompts: list, max_tokens: list) -> list:
         """Greedy answers of ``max_tokens[i]`` tokens to ``prompts[i]``,

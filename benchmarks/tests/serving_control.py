@@ -29,7 +29,12 @@ engine's readings over the limits (benchmarks/README.md, "A served
 family") each seed's line ends in its counts beside their allowances,
 and the program's seeds end in the two shares as read over all of them
 (each seed's own: mean, least and most): the numbers a family's PR
-writes into its cell's file and its ``tolerance_why``.
+writes into its cell's file and its ``tolerance_why``. For a cell
+compared under the engine's own routing choices (``"routing":
+"engine"``) the lists read include ``route_margin``, and where the
+family's control file gives ``router_fault()`` a fourth side, the engine
+built under it, is read on the control's seeds: ``route_margin_tol`` is
+set between the program's largest margin and that side's least run.
 
     python benchmarks/tests/serving_control.py standin <seeds> <reach seeds>
 
@@ -43,9 +48,13 @@ the sound side once more through the probe as it stood until PR 44,
 on every seed ``server.reference_readings`` through its ``_prefill`` and
 ``_decode`` at the probe a routed cell would bring, ``served_readings``
 over a request a lane, and ``serve_load.matches_reference`` itself under
-the two shares such a cell would state, for the sound side, the control
-(the shared expert's operands in fp8) and the fault (one lane's token
-moved to the next of the vocabulary); then how far a flip made on
+both decisions such a cell may ask for (``standin_checks``: the two
+shares stated, against the reference's own routing choices; and
+``"routing": "engine"``, every row and margin held, under the engine's
+own), for the sound side and the control (the shared expert's operands
+in fp8) under both, and under the engine's for the fault (one lane's
+token moved to the next of the vocabulary) and the router fault (an
+expert the reference would not have chosen, taken and said); then how far a flip made on
 purpose reaches the rows behind it, all of them and by how far behind,
 and what the arithmetic alone reads with no engine, no cache and no
 probe: ``routed_standin.readings``, bf16 against float32 over whole
@@ -209,13 +218,22 @@ def parents_probe_rows(eng, seq, positions: int, decode_steps: int):
     on the real cache, the last chunk ``positions`` times at the same
     start (a state then holds the chunk before all but the first), and
     the token behind each decode goes in twice, once by the one-token
-    prefill that reads its row and once by the next decode."""
+    prefill that reads its row and once by the next decode. Of an engine
+    that can say what it chose the choices are handed back as
+    ``server.probe_rows`` hands them (the door is PR 61's; a cell
+    compared under them is read under this protocol too)."""
     import numpy as np
 
     length, shard = len(seq), eng.shards[0]
     starts = range(0, length, eng.prefill_chunk)
+    tail = length - starts[-1]
     onehot = np.zeros(eng.max_batch, np.float32)
     onehot[0] = 1.0
+    read, said = getattr(eng, "read_choices", None), []
+
+    def say(n):
+        if read is not None:
+            said.append(np.asarray(read(shard.cache)[:, :n]))
 
     def prefill(tokens, pos, real=None):
         bucket = next(b for b in eng.buckets if b >= len(tokens))
@@ -224,6 +242,7 @@ def parents_probe_rows(eng, seq, positions: int, decode_steps: int):
         logits, shard.cache = eng._prefill(
             eng.params, shard.cache, padded, onehot,
             np.asarray([pos], np.int32), real or len(tokens), bucket=bucket)
+        say(real or len(tokens))
         return np.asarray(logits, np.float32)
 
     for pos in starts:
@@ -239,9 +258,15 @@ def parents_probe_rows(eng, seq, positions: int, decode_steps: int):
         toks, shard.cache, eng._rng = eng._decode(
             eng.params, shard.cache, tokens, lens,
             np.zeros(eng.max_batch, np.float32), eng._rng)
+        say(1)
         chosen.append(int(np.asarray(toks)[0]))
         got_after.append(prefill(chosen[-1:], length + i + 1))
-    return got_prefill, chosen, np.stack(got_after)
+    if read is None:
+        return got_prefill, chosen, np.stack(got_after), None
+    real, short = said[:len(starts)], said[len(starts):][:positions - 1]
+    rest = said[len(starts) + positions - 1:]
+    return got_prefill, chosen, np.stack(got_after), server.choices_said(
+        real, short, rest[0::2], rest[1::2], tail)
 
 
 @contextlib.contextmanager
@@ -293,6 +318,43 @@ STANDIN_SHARES = {"CHIP": (0.028, 0.009), "CHIP_GROUPED": (0.06, 0.014),
                   # held the sound side read 89 % of its rows over
                   # (PERF.md section 6, PR 44)
                   "CHIP_HYBRID": (0.1, 0.03), "TOY_HYBRID": (0.03, 0.01)}
+# The same probe under the engine's own routing choices (``"routing":
+# "engine"``): no share of rows, every row held to ``rel_rms_tol`` and
+# every margin to ``route_margin_tol``, each set between two readings of
+# the chip runs of PR 61 (PERF.md section 6, PR 61, has them).
+# ``CHIP_HYBRID`` over 8 seeds: rows, the sound engine's largest 0.01502,
+# the fp8 control's least 0.0460, the limit 1.8 x over the one and 1.7 x
+# under the other; margins, the sound engine's largest 0.0421, the
+# router fault's least run (a run's largest margin) 0.2545, the limit
+# 2.6 x over the one and 2.3 x under the other. ``CHIP`` over 6 seeds:
+# rows 0.01498 and 0.1177; margins 0.0602 and 0.902. ``CHIP_GROUPED``
+# over 6: rows 0.01449 and 0.0836; margins 0.1619 (a group's near-tie
+# is judged by four slopes) and 0.887. A test's sizes take the fixture
+# cell's.
+STANDIN_ROUTED = {
+    "CHIP": {"rel_rms_tol": 0.03, "route_margin_tol": 0.2},
+    "CHIP_GROUPED": {"rel_rms_tol": 0.03, "route_margin_tol": 0.35},
+    "CHIP_HYBRID": {"rel_rms_tol": 0.027, "route_margin_tol": 0.11},
+    "TOY": {"rel_rms_tol": 0.015, "route_margin_tol": 0.25},
+    "TOY_GROUPED": {"rel_rms_tol": 0.015, "route_margin_tol": 0.25},
+    "TOY_HYBRID": {"rel_rms_tol": 0.03, "route_margin_tol": 0.08},
+}
+
+
+def standin_checks(name: str, rehearse: bool) -> dict:
+    """The two decisions a routed cell may ask for, at one set of the
+    stand-in's sizes: ``own`` (the reference's own choices, the two
+    shares stated: until PR 61 the only one) and ``engine`` (the
+    engine's)."""
+    check = dict(STANDIN_CHECK, **dict(zip(
+        serve_load.SHARE_CEILINGS, STANDIN_SHARES[name])))
+    if rehearse:
+        check.update(length=96, positions=32, decode_steps=8)
+    engine = {k: v for k, v in check.items() if k != "rel_rms_over_share"}
+    return {"own": check, "engine": {**engine, "routing": "engine",
+                                     **STANDIN_ROUTED[name]}}
+
+
 # its served requests, one a lane: prompts and answers of so many
 # tokens, the shortest and the longest answer in every seed
 STANDIN_PROMPTS, STANDIN_ANSWERS = (32, 480), (48, 256)
@@ -314,19 +376,22 @@ def standin_requests(seed: int, lanes: int, vocab: int, prompts, answers):
 
 def standin_sides(sizes: dict, lanes: int, max_seq: int, buckets,
                   parents: bool = False):
-    """The stand-in's engine three times over one set of weights: sound,
-    the control (the shared expert's operands in fp8) and the fault (the
+    """The stand-in's engine four times over one set of weights: sound,
+    the control (the shared expert's operands in fp8), the fault (the
     decode's token of the lane before the last moved to the next of the
-    vocabulary); with ``parents`` a fourth, sound, which ``standin``
-    reads under ``parents_protocol``."""
+    vocabulary) and the router fault (at one row in 32 the first routed
+    layer takes the best held expert it had passed over, and says so);
+    with ``parents`` a fifth, sound, which ``standin`` reads under
+    ``parents_protocol``."""
     import routed_standin
 
-    def engine(side):
+    def engine(side, **more):
         return routed_standin.Engine(sizes, None, side, max_batch=lanes,
-                                     max_seq=max_seq, buckets=buckets)
+                                     max_seq=max_seq, buckets=buckets, **more)
 
     sides = {"sound": engine("bf16"), "control": engine("fp8"),
-             "fault": engine("bf16")}
+             "fault": engine("bf16"),
+             "router": engine("bf16", router_fault=True)}
     sides["fault"]._decode = broken(
         sides["fault"]._decode, next_token(sizes["vocab"], lanes - 2))
     if parents:
@@ -334,22 +399,27 @@ def standin_sides(sizes: dict, lanes: int, max_seq: int, buckets,
     return sides
 
 
-def standin_read(eng, family, seed: int, hp: dict, check: dict,
+def standin_read(eng, family, seed: int, hp: dict, checks: dict,
                  requests) -> dict:
-    """The comparison as a run makes it, of one engine on one seed:
-    ``reference_readings`` (the probe), ``served_readings`` over what
-    ``eng.serve`` answered to ``requests`` ((prompt ids, answer length)
-    each), the decision and what it counted."""
-    got = server.reference_readings(eng, family, seed, hp, check)
+    """The comparison as a run makes it, of one engine on one seed,
+    under each of ``checks`` ({name: a ``reference_check`` block}):
+    ``reference_readings`` (the probe, once a check),
+    ``served_readings`` over what ``eng.serve`` answered to ``requests``
+    ((prompt ids, answer length) each; read once), the decision and what
+    it counted."""
+    probes = {name: server.reference_readings(eng, family, seed, hp, check)
+              for name, check in checks.items()}
     answers, lanes = eng.serve([p for p, _ in requests],
                                [n for _, n in requests])
-    got.update(server.served_readings(
+    served = server.served_readings(
         eng.params, family, hp,
-        [(p, a) for (p, _), a in zip(requests, answers)]))
-    got["lanes"] = lanes
-    got["counted"] = serve_load.counted(got, check)
-    got["correct"] = serve_load.matches_reference(got, check)
-    return got
+        [(p, a) for (p, _), a in zip(requests, answers)])
+    out = {}
+    for name, check in checks.items():
+        got = out[name] = {**probes[name], **served, "lanes": lanes}
+        got["counted"] = serve_load.counted(got, check)
+        got["correct"] = serve_load.matches_reference(got, check)
+    return out
 
 
 # rows behind a forced flip, from each of these to the next: how far
@@ -386,8 +456,11 @@ def arithmetic(seed: int, sizes: dict, tol: float, gap_tol: float) -> dict:
 def standin(seeds: list, reach_seeds: list, rehearse: bool,
             variant: str = "", parents: bool = False) -> None:
     """The stand-in's engine through the comparison a routed cell would
-    bring, on every seed the sound side, the control and the fault; then
-    ``routed_standin.reach`` over ``reach_seeds``. Every reading goes to
+    bring, under both decisions (``standin_checks``): on every seed the
+    sound side and the control under the reference's own choices and
+    under the engine's, the fault and the router fault under the
+    engine's; then ``routed_standin.reach`` over ``reach_seeds``. Every
+    reading goes to
     ``chiprun_out/serving_control.standin.<sizes>.<first seed>.json``,
     written anew after every seed."""
     import numpy as np
@@ -395,19 +468,18 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
 
     name = ("TOY" if rehearse else "CHIP") + variant
     sizes = getattr(routed_standin, name)
-    check = dict(STANDIN_CHECK, **dict(zip(
-        serve_load.SHARE_CEILINGS, STANDIN_SHARES[name])))
+    checks = standin_checks(name, rehearse)
+    check = checks["own"]
     lanes, max_seq, buckets = 8, 2048, (16, 256)
     prompts, answers = STANDIN_PROMPTS, STANDIN_ANSWERS
     if rehearse:
-        check.update(length=96, positions=32, decode_steps=8)
         lanes, max_seq, buckets = 4, 192, (8, 32)
         prompts, answers = (32, 80), (8, 40)
-    hp = {**sizes, "vocab_size": sizes["vocab"]}
+    hp = {**sizes, "vocab_size": sizes["vocab"], "name": name}
     sides = standin_sides(sizes, lanes, max_seq, buckets, parents)
-    out = {"sizes": sizes, "name": name, "check": check, "risk":
-           serve_load.RISK, "seeds": seeds, "by_seed": {}, "reach": {},
-           "arithmetic": {}}
+    out = {"sizes": sizes, "name": name, "check": check, "checks": checks,
+           "risk": serve_load.RISK, "seeds": seeds, "by_seed": {},
+           "engine": {}, "reach": {}, "arithmetic": {}}
     os.makedirs("chiprun_out", exist_ok=True)
     path = f"chiprun_out/serving_control.standin.{name}.{seeds[0]}.json"
 
@@ -424,12 +496,31 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
         requests = standin_requests(seed, lanes, sizes["vocab"], prompts,
                                     answers)
         row = out["by_seed"][seed] = {}
+        routed = out["engine"][seed] = {}
         for side, eng in sides.items():
             eng.params = params
+            # the faults are the new decision's to see or the served
+            # tokens', which both decisions read alike
+            under = {k: v for k, v in checks.items()
+                     if side in ("sound", "control")
+                     or k == ("own" if side == "parents" else "engine")}
             with parents_protocol() if side == "parents" \
                     else contextlib.nullcontext():
-                got = row[side] = standin_read(eng, routed_standin, seed, hp,
-                                               check, requests)
+                read = standin_read(eng, routed_standin, seed, hp, under,
+                                    requests)
+            if "engine" in read:
+                got = routed[side] = read["engine"]
+                rel = got["prefill_rel_rms"] + got["after_decode_rel_rms"]
+                print("standin", name, seed, side, "under the engine's "
+                      "choices: correct", got["correct"], got["counted"],
+                      "rows least, median, largest",
+                      [round(x, 5) for x in (min(rel), statistics.median(
+                          rel), max(rel))], "differs", round(
+                          got["routing_differs_share"], 4), "read again",
+                      got["rows_read_again"], flush=True)
+            if "own" not in read:
+                continue
+            got = row[side] = read["own"]
             rel = got["prefill_rel_rms"] + got["after_decode_rel_rms"]
             over = sorted(x for x in rel if x > check["rel_rms_tol"])
             tol = check["choice_gap_tol"]
@@ -483,7 +574,45 @@ def standin(seeds: list, reach_seeds: list, rehearse: bool,
     out["summary"] = standin_summary(out["by_seed"], check, lanes - 2)
     print("standin", name, "over", len(seeds), "seeds:",
           json.dumps(out["summary"]), flush=True)
+    out["engine_summary"] = routed_summary(out["engine"])
+    print("standin", name, "under the engine's choices, over", len(seeds),
+          "seeds:", json.dumps(out["engine_summary"]), flush=True)
     keep()
+
+
+def routed_summary(by_seed: dict) -> dict:
+    """Of each side under the engine's own choices, over the seeds: how
+    many read correct; ``spread`` of the compared rows, of a seed's
+    largest row, of the margins over 0 and of a seed's largest margin
+    (the readings a limit is set between: the sound side's largest, the
+    control's least row, the router fault's least run); the share of
+    (layer, row) pairs whose sets differ; the rows read again; the
+    largest decode gap; and the served tokens' counts."""
+    out = {}
+    for side in next(iter(by_seed.values())):
+        rows = [r[side] for r in by_seed.values()]
+        rel = [r["prefill_rel_rms"] + r["after_decode_rel_rms"] for r in rows]
+        margins = [r["route_margin"] for r in rows]
+        out[side] = {
+            "correct": sum(r["correct"] for r in rows), "seeds": len(rows),
+            "rows": spread([x for xs in rel for x in xs]),
+            "a_seeds_largest_row": spread([max(xs) for xs in rel]),
+            "a_seeds_least_row": spread([min(xs) for xs in rel]),
+            "margins_over_0": spread([x for xs in margins for x in xs
+                                      if x > 0]),
+            "a_seeds_largest_margin": spread([max(xs) for xs in margins]),
+            "routing_differs_share": spread(
+                [r["routing_differs_share"] for r in rows]),
+            "rows_read_again": [r["rows_read_again"] for r in rows],
+            "decode_gap_largest": max(
+                max(r["decode_choice_gap"]) for r in rows),
+            "served_over": [r["counted"]["served_choice_gap_over"]
+                            for r in rows],
+            "request_over": [r["counted"]["request_choice_gap_over"]
+                             for r in rows],
+            "seconds": [min(r["total_s"] for r in rows),
+                        max(r["total_s"] for r in rows)]}
+    return out
 
 
 def standin_summary(by_seed: dict, check: dict, faulty: int) -> dict:
@@ -588,15 +717,22 @@ def main(argv) -> int:
     ensure_compilation_cache_dir()
     cell = spec.load_cell(cell_name, rehearse)
     check = cell["serve"]["reference_check"]
-    stating = serve_load.states_a_share(check)
+    routed = serve_load.routed(check)
+    stating = serve_load.states_a_share(check) or routed
     # the family's control, found by its name: a family that brings none
     # is an error here, before anything is read
     control = controls.of(cell["hp"]).fp8
     out = {"cell": cell_name, "program": {}, "control": {}, "altered": {}}
-    for side, under, wanted in (
-            ("program", contextlib.nullcontext, seeds_of(seeds)),
-            ("control", control, seeds_of(control_seeds)),
-            ("altered", contextlib.nullcontext, seeds_of(control_seeds))):
+    sides = [("program", contextlib.nullcontext, seeds_of(seeds)),
+             ("control", control, seeds_of(control_seeds)),
+             ("altered", contextlib.nullcontext, seeds_of(control_seeds))]
+    # a cell compared under the engine's own routing choices: the fault
+    # its route_margin_tol is set under, where the family's file plants one
+    router_fault = getattr(controls.of(cell["hp"]), "router_fault", None)
+    if routed and router_fault is not None:
+        out["router"] = {}
+        sides.append(("router", router_fault, seeds_of(control_seeds)))
+    for side, under, wanted in sides:
         with under():
             # its programs are traced when its first seed is read; the
             # fault sits in the lane the engine fills second
@@ -639,9 +775,9 @@ def main(argv) -> int:
                                       for r in rows.values()),
                     min(r["summary"][k]["max"] for r in rows.values()),
                     max(r["summary"][k]["max"] for r in rows.values())]
-                for k in serve_load.READINGS},
+                for k in serve_load.readings_of(check)},
                 "(least, median, a seed's largest at the least, largest)")
-    if stating and out["program"]:
+    if serve_load.states_a_share(check) and out["program"]:
         out["shares_read"] = shares_read(
             [r["readings"] for r in out["program"].values()], check)
         print("program over", len(out["program"]), "seeds, shares of the "

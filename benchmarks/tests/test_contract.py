@@ -189,15 +189,23 @@ def stated_shares(cell: dict) -> dict:
     """The shares of readings over the limits that a serve cell states,
     held as ``serve_load.check_cell`` holds them: each under its
     ceiling, both or neither, and with them no compared row before row
-    32 (the probe's and the traffic's least prompt)."""
+    32 (the probe's and the traffic's least prompt). A cell compared
+    under the engine's own routing choices (``"routing": "engine"``)
+    states none of its rows, a ``route_margin_tol``, and may state the
+    served tokens' alone; its family gives ``reference_routed``."""
     from benchmarks import serve_load
 
     check = cell["serve"]["reference_check"]
     stated = {k: check[k] for k in serve_load.SHARE_CEILINGS if k in check}
     for share, value in stated.items():
         assert 0 < value <= serve_load.SHARE_CEILINGS[share]
-    if stated:
+    if serve_load.routed(check):
+        assert set(stated) <= {"choice_gap_over_share"}
+        assert 0 < check["route_margin_tol"]
+        assert callable(spec.family_of(cell["hp"]).reference_routed)
+    elif stated:
         assert set(stated) == set(serve_load.SHARE_CEILINGS)
+    if stated:
         assert check["length"] - check["positions"] \
             >= serve_load.SHARES_FROM_ROW
         assert cell["traffic"]["prompt_len"]["min"] \

@@ -550,13 +550,16 @@ def test_a_serve_cell_of_a_second_family_is_tried_on_its_own_recording(
                                   "hybrid-standin.serve"])
 def test_a_routed_serve_cell_states_its_shares_by_files_alone(name):
     """``routed-standin.serve`` and ``hybrid-standin.serve`` (the same
-    stand-in with state layers before its latent attention: a cache
-    that is not addressed by position), their configurations and their
-    family ``routed`` exist under tests/tree alone: the cell is found by
-    its kind, the family by the configuration's name, the two shares it
-    states are held by ``spec.load_cell`` through the kind's own
-    ``check_cell``, and no harness file knows the family, the cell or a
-    share's value. It brings no recording, so the tests parametrised
+    stand-in with state layers behind its routed layers: a cache that
+    is not addressed by position, compared under the engine's own
+    routing choices since PR 61, so that one kept cell goes each way),
+    their configurations and their family ``routed`` exist under
+    tests/tree alone: the cell is found by its kind, the family by the
+    configuration's name, the shares it states (the routed one both,
+    the hybrid one the served tokens' alone beside its
+    ``route_margin_tol``) are held by ``spec.load_cell`` through the
+    kind's own ``check_cell``, and no harness file knows the family,
+    the cell or a share's value. It brings no recording, so the tests parametrised
     over cells try it on the llama family's. Of the tree's own cells
     these are the ones that state them; what a cell of BENCHMARK.json
     that states them is held to is ``test_contract.py``'s and
@@ -576,8 +579,13 @@ def test_a_routed_serve_cell_states_its_shares_by_files_alone(name):
         os.path.realpath(spec.FIXTURE_TREE))
     assert spec.kind_of(cell).check_cell is serve_load.check_cell
     check = cell["serve"]["reference_check"]
+    assert serve_load.routed(check) is (name == "hybrid-standin.serve")
     for share, ceiling in serve_load.SHARE_CEILINGS.items():
-        assert 0 < check[share] <= ceiling
+        if serve_load.routed(check) and share == "rel_rms_over_share":
+            assert share not in check and check["route_margin_tol"] > 0
+            assert family.reference_routed
+        else:
+            assert 0 < check[share] <= ceiling
     assert len(check["tolerance_why"]) > 100
     for path in harness_files():
         with open(path) as f:

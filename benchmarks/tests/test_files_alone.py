@@ -11,18 +11,25 @@ there but ``BENCHMARK.json``:
     sound engine's readings over the limits (the tree's
     ``routed-standin.serve`` and its configuration under other names),
     and
-(c) one of a hybrid, whose engine keeps a recurrent state and a
-    convolution's tail beside its latent rows, a cache that is not
-    addressed by position (the tree's ``hybrid-standin.serve``), and
-    with it a family of its own, as such a PR brings one:
+(c) one of a hybrid routed family, whose engine keeps a recurrent state
+    and a convolution's tail beside its latent rows, a cache that is
+    not addressed by position, with state layers behind its routed
+    layers, so that its rows are compared under the engine's own
+    routing choices: its ``reference_check`` says ``"routing":
+    "engine"``, states ``route_margin_tol`` and of the two shares the
+    served tokens' alone, its engine gives ``read_choices`` and its
+    family ``reference_routed`` (the tree's ``hybrid-standin.serve``;
+    the next ``model_config`` PR's way in since PR 61), and with it a
+    family of its own, as such a PR brings one:
     ``files_alone/family.py`` and ``files_alone/control.py`` as
     ``tree/families/<family>.py`` and ``tree/control_<family>.py`` (the
     tests' own families live in their tree; the name lookups are the
     ones ``benchmarks/families/`` and ``tests/`` go through). It is the
     tree's ``routed`` under another schema: the configuration states its
     layers' mixers under ``mixers`` where the fixture's says
-    ``pattern``, and the engine keeps what a slot holds under other
-    names than the stand-in's, so a test shared by every cell that
+    ``pattern``, and the engine keeps what a slot holds, and the leaf
+    its calls write their choices into, under other names than the
+    stand-in's, so a test (or a harness) shared by every cell that
     turned on a key or a leaf of the fixture's would fail here,
 
 each with a handful of per-layer metrics (its name added to the
@@ -34,11 +41,18 @@ property, and other sizes than any cell here has). Then it runs
 ``python -m pytest benchmarks/tests`` there, this file left out, and
 holds the outcome: every case passes but those listed under
 ``NEEDS_THE_PROGRAM``, each of which drives (b)'s or (c)'s cell through
-``run.py``, where ``LLMServer`` builds the engine of ``models/llama.py``
-and cannot serve a routed family yet (PERF.md section 7, items 1-3: the
-``model_config`` PR's to bring). Everything the tests themselves hold of
-such a cell (the control found by the family's name, the decision by
-its counts, the probe's sizes by property) passes.
+``run.py``, where ``LLMServer`` builds an engine of the program's and
+the program has none for the fixture family: since PR 46 it serves
+routed families of its own (``config.model_module``), but a family is
+served by a module of ``ray_tpu/models/`` that its ``model_config``
+names, which the tests' stand-in is not, and for (c) no engine of the
+program gives ``read_choices`` yet (``ops/moe.py`` ``route_top_k``
+returns its experts to ``_dropless_rows`` and no further: PERF.md
+section 7, "What PR 61 leaves"; the ``model_config`` PR's to bring in
+its own module). Everything the tests themselves hold of such a cell
+(the control and the router fault found by the family's name, the
+decision by its counts or under the engine's choices, the probe's sizes
+by property) passes.
 
 Slow (it is the whole of ``benchmarks/tests`` once more, with three more
 cells); ``benchmarks/tests`` is not tier-1.

@@ -16,14 +16,18 @@ the faults a serving cell can have planted under the timed path where
 the decode program's tokens are produced.
 
 No test here knows how many serve cells there are, what family they are
-of or a probe's sizes. What is asserted of a cell follows from two
+of or a probe's sizes. What is asserted of a cell follows from three
 things: whether its ``reference_check`` states the two shares of its
 sound engine's readings over the limits (benchmarks/README.md, "A served
-family"), and what its family gives. A cell that states none is held at
-every reading (each row at or under 0.6 x ``rel_rms_tol``, each gap
-under half of ``choice_gap_tol``, the control over at every row); a cell
-that states them by its counts beside their allowances, with every row
-that is not over the limit under it with room. ``serving_control.py``
+family"), whether it asks for its rows to be compared under the
+engine's own routing choices (``"routing": "engine"``), and what its
+family gives. A cell that states none is held at every reading (each row
+at or under 0.6 x ``rel_rms_tol``, each gap under half of
+``choice_gap_tol``, the control over at every row); a cell that states
+them by its counts beside their allowances, with every row that is not
+over the limit under it with room; a cell under the engine's choices at
+every row, gap and margin of its probe, and by the counts of its served
+tokens alone. ``serving_control.py``
 beside this file reads the same on the chip at the cells' own sizes
 (PERF.md sections 4 and 6 have the chip's readings)."""
 
@@ -54,6 +58,27 @@ def states_shares(cell: str) -> bool:
     that the assertions below turn on."""
     return serve_load.states_a_share(
         spec.load_cell(cell, True)["serve"]["reference_check"])
+
+
+@functools.lru_cache(maxsize=None)
+def routed(cell: str) -> bool:
+    """Whether the cell's rows are compared under the engine's own
+    routing choices: it then states no share of its rows, whatever it
+    states of its served tokens, and every row decides."""
+    return serve_load.routed(
+        spec.load_cell(cell, True)["serve"]["reference_check"])
+
+
+def rows_by_count(cell: str) -> bool:
+    """Whether a count of rows over the limit decides the cell."""
+    return states_shares(cell) and not routed(cell)
+
+
+def served_counts(cell: str) -> tuple:
+    """The names ``serve_load.counted`` gives the served tokens' counts,
+    all together and by request."""
+    return ("served_choice_gap_over" if routed(cell) else "choice_gap_over",
+            "request_choice_gap_over")
 
 
 def readings(rel_rms, gaps=(0.0,) * 16, finite=True, served=(0.0,) * 300):
@@ -514,7 +539,8 @@ def test_a_cell_of_the_benchmark_reads_enough_for_its_decision(cell):
     what its limits were set from. One that states a share states both,
     under the ceilings, compares no row before row 32
     (``test_contract.stated_shares``) and brings a probe in which a count
-    can bite."""
+    can bite; one compared under the engine's own routing choices states
+    no share of its rows, so no count of them has to."""
     loaded = spec.load_cell(cell, False)
     check = loaded["serve"]["reference_check"]
     assert "quantile" not in check
@@ -524,7 +550,10 @@ def test_a_cell_of_the_benchmark_reads_enough_for_its_decision(cell):
     assert check.get("served_requests", serve_load.SERVED_REQUESTS) >= 8
     assert len(check["tolerance_why"]) > 100
     assert check["length"] - positions >= whole_chunk(loaded)
-    if stated_shares(loaded):
+    if serve_load.routed(check):
+        assert "rel_rms_over_share" not in check
+        assert "route_margin" in check["tolerance_why"]
+    elif stated_shares(loaded):
         assert a_count_can_bite(check), (
             f"{cell}: a probe of {positions} positions and "
             f"{check['decode_steps']} decodes is too short for the share "
@@ -596,7 +625,21 @@ def test_the_sound_program_is_correct_on_every_seed(cell, seed, probes):
     rows, tol = rows_of(got), check["rel_rms_tol"]
     under = [x for x in rows if x <= tol]
     assert 0 < min(rows) and max(under) <= ROOM[stating] * tol, got
-    if stating:
+    if routed(cell):
+        # every row, gap and margin of the probe decides; the reference
+        # was handed a choice of the engine's at a few pairs in a
+        # hundred, each where it was itself near a tie
+        assert under == rows and within(counted)
+        assert serve_load.compared(got, check) == counted
+        assert counted["route_margin"] == [max(got["route_margin"]),
+                                           check["route_margin_tol"]]
+        assert max(got["route_margin"]) <= 0.6 * check["route_margin_tol"]
+        assert len(got["route_margin"]) == check["length"] \
+            + check["decode_steps"] + 1 + got["rows_read_again"]
+        assert 0 < got["routing_differs_share"] < 0.1
+        assert counted["shortened_calls_chose_otherwise"] == [0, 0]
+        assert max(got["decode_choice_gap"]) <= 0.5 * check["choice_gap_tol"]
+    elif stating:
         assert within(counted)
         assert counted["rel_rms_over"] == [
             len(rows) - len(under),
@@ -609,7 +652,7 @@ def test_the_sound_program_is_correct_on_every_seed(cell, seed, probes):
     assert check["length"] - check["positions"] >= probe.eng.prefill_chunk
 
 
-@pytest.mark.parametrize("cell", [c for c in TREE_SERVE if states_shares(c)])
+@pytest.mark.parametrize("cell", [c for c in TREE_SERVE if rows_by_count(c)])
 def test_some_toy_seed_flips_and_none_is_correct_without_the_share(
         cell, probes):
     """The allowance is used: on some of the fixture cell's seeds a
@@ -649,7 +692,7 @@ def test_what_the_timed_path_served_is_held_as_the_cell_states(cell, probes):
                 for p, _, _ in by_request}) > 1
     if states_shares(cell):
         assert within(serve_load.counted(got, probe.check),
-                      "choice_gap_over", "request_choice_gap_over")
+                      *served_counts(cell))
     else:
         assert max(got["served_choice_gap"]) \
             <= 0.5 * probe.check["choice_gap_tol"]
@@ -716,7 +759,11 @@ def test_the_control_moves_every_position_over_the_limit(cell, seed):
     of = serve_load.summary(got, probe.check)
     assert of["prefill_rel_rms"]["outlier_share"] == 1.0
     assert of["after_decode_rel_rms"]["outlier_share"] == 1.0
-    if states_shares(cell):
+    if routed(cell):
+        # its own choices handed over, so its flips go too: what is left
+        # is the lower precision, at every row
+        assert serve_load.counted(got, probe.check)["prefill_rel_rms"][0] > tol
+    elif states_shares(cell):
         count, most = serve_load.counted(got, probe.check)["rel_rms_over"]
         assert count == len(rows_of(got)) > 4 * most
 
@@ -735,7 +782,7 @@ def test_a_token_altered_where_it_is_produced_fails_the_choice_gap(cell):
     check = probe.check
     assert not serve_load.matches_reference(got, check)
     assert min(got["decode_choice_gap"]) > check["choice_gap_tol"], got
-    if states_shares(cell):
+    if rows_by_count(cell):
         assert within(serve_load.counted(got, check), "rel_rms_over")
     else:
         assert max(rows_of(got)) <= check["rel_rms_tol"]
@@ -776,7 +823,11 @@ def test_a_fault_outside_the_probes_lane_fails_the_served_tokens(
     if stating:
         counted = serve_load.counted(got, check)
         assert not within(counted, "request_choice_gap_over")
-        assert within(counted, "rel_rms_over")
+        if routed(cell):
+            assert max(rows_of(got)) <= check["rel_rms_tol"]
+            assert within(counted, "route_margin")
+        else:
+            assert within(counted, "rel_rms_over")
     else:
         assert max(got["served_choice_gap"]) > 4 * tol, got
         assert max(rows_of(got)) <= check["rel_rms_tol"]
@@ -871,6 +922,197 @@ def test_the_trees_own_cells_keep_both_sides_of_that_door(
     altered = [cell for cell in TREE_SERVE if differ(cell)]
     assert altered == ["hybrid-standin.serve"]
     assert len(TREE_SERVE) > len(altered)
+
+
+# ------------------------------ under the engine's own routing choices
+ROUTED = [c for c in SERVE if routed(c)]
+ROUTED_LIMITS = {**LIMITS, "routing": "engine", "route_margin_tol": 0.05,
+                 "choice_gap_over_share": 0.01}
+
+
+def routed_readings(**lists):
+    return {**readings([0.011] * 32), "route_margin": [0.0] * 40 + [0.02],
+            "shortened_calls_chose_otherwise": 0,
+            "served_by_request": [[40, 100, 0.0], [33, 200, 0.0]], **lists}
+
+
+@pytest.mark.parametrize("lists,passes", [
+    ({}, True),
+    # no row is spared: the cell states no share of them
+    ({"prefill_rel_rms": [0.011] * 31 + [0.0301]}, False),
+    ({"after_decode_rel_rms": [0.011] * 15 + [0.13]}, False),
+    ({"prefill_rel_rms": [0.03] * 32}, True),
+    # a choice the reference would not have made, far from a tie
+    ({"route_margin": [0.0] * 40 + [0.051]}, False),
+    ({"route_margin": [0.05] * 41}, True),
+    ({"route_margin": []}, False),
+    ({"route_margin": [0.0, math.nan]}, False),
+    ({"route_margin": [0.0, math.inf]}, False),
+    ({"shortened_calls_chose_otherwise": 1}, False),
+    # the probe's decodes are read under the engine's choices: every gap
+    # decides, and none is pooled with the served tokens
+    ({"decode_choice_gap": [0.0] * 15 + [0.11]}, False),
+    # the served tokens carry no choices: counted at the stated share,
+    # all together and request by request
+    ({"served_choice_gap": [0.0] * 50 + [0.9] + [0.0] * 249}, True),
+    ({"served_choice_gap": [0.0] * 100 + [5.0] * 20 + [0.0] * 180,
+      "served_by_request": [[40, 100, 0.0], [33, 20, 5.0], [9, 180, 0.0]]},
+     False),
+    ({"served_choice_gap": []}, False),
+])
+def test_under_the_engines_choices_every_probe_reading_decides(lists, passes):
+    """The rule of a cell that says ``"routing": "engine"``, on made-up
+    readings: the probe's rows, gaps and margins each at or under their
+    limit at every reading, no shortened call that chose otherwise, and
+    the served tokens by their counts; ``compared`` is what was held,
+    the largest readings beside their limits and the counts beside
+    their allowances, and passes the last line's contract."""
+    from benchmarks import contract
+
+    ref = routed_readings(**lists)
+    assert serve_load.matches_reference(ref, ROUTED_LIMITS) is passes
+    got = serve_load.compared(ref, ROUTED_LIMITS)
+    assert got == serve_load.counted(ref, ROUTED_LIMITS)
+    assert list(got) == [
+        "unread_or_not_finite", "shortened_calls_chose_otherwise",
+        "prefill_rel_rms", "after_decode_rel_rms", "decode_choice_gap",
+        "route_margin", "served_choice_gap_over", "request_choice_gap_over"]
+    assert got["route_margin"][1] == 0.05
+    assert got["served_choice_gap_over"][1] == serve_load.allowed(
+        len(ref["served_choice_gap"]), 0.01)
+    assert "route_margin" in serve_load.summary(ref, ROUTED_LIMITS)
+    assert "route_margin" not in serve_load.summary(ref, LIMITS)
+    # without a stated share of tokens every served token decides too
+    none = {k: v for k, v in ROUTED_LIMITS.items()
+            if k != "choice_gap_over_share"}
+    assert serve_load.matches_reference(ref, none) is (
+        passes and max(ref["served_choice_gap"], default=1.0) <= 0.1)
+    line = {"correct": passes, "attempted": 1, "failed": 0, "metrics": {},
+            "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                       "memory_peak_bytes": 1},
+            "compared": {k: [x if math.isfinite(x) else None for x in v]
+                         for k, v in got.items()}}
+    assert contract.check_last_line(line, {}, traced=False, chips=1) == []
+
+
+HYBRID_CELL = "hybrid-standin.serve"
+
+
+@pytest.mark.parametrize("change,why", [
+    ({"routing": "reference"}, "the one thing it may say is"),
+    ({"routing": True}, "the one thing it may say is"),
+    ({"rel_rms_over_share": 0.03}, "states no share of them"),
+    ({"route_margin_tol": None}, "route_margin_tol is None"),
+    ({"route_margin_tol": 0}, "a number over 0"),
+    ({"route_margin_tol": "0.08"}, "a number over 0"),
+    ({"route_margin_tol": True}, "a number over 0"),
+    ({"choice_gap_over_share": 0.031}, "ceiling 0.03"),
+    ({"length": 90}, "first compared row .* is 26"),
+    ({"family": "toy_moe"}, "gives no reference_routed"),
+])
+def test_a_cell_under_the_engines_choices_is_held_when_it_is_loaded(
+        change, why, monkeypatch):
+    """``serve_load.check_cell`` of a cell whose ``reference_check`` has
+    the key ``routing``: it says ``"engine"``, states no share of its
+    rows, states its ``route_margin_tol``, and its family gives
+    ``reference_routed``; what it states of its served tokens is held as
+    any cell's share is."""
+    real = spec.load_json
+    assert serve_load.routed(spec.load_cell(HYBRID_CELL, False)["serve"][
+        "reference_check"])
+
+    def fake(*parts):
+        out = real(*parts)
+        if parts[0] == "configs" and "family" in change:
+            out["family"] = change["family"]
+        if parts == ("workloads", f"{HYBRID_CELL}.json"):
+            for key, value in change.items():
+                if key == "family":
+                    continue
+                if value is None:
+                    del out["serve"]["reference_check"][key]
+                else:
+                    out["serve"]["reference_check"][key] = value
+        return out
+
+    monkeypatch.setattr(spec, "load_json", fake)
+    with pytest.raises(ValueError, match=why):
+        spec.load_cell(HYBRID_CELL, False)
+
+
+def by_router_fault() -> list:
+    return [pytest.param(cell, seed, id=f"{cell}-{seed}") for cell in ROUTED
+            if hasattr(controls.of(spec.load_cell(cell, True)["hp"]),
+                       "router_fault")
+            for seed in SEEDS[True]["control"]]
+
+
+@pytest.mark.parametrize("cell,seed", by_router_fault())
+def test_a_router_fault_fails_by_the_margin_alone(cell, seed):
+    """The fault only such a cell can have: the engine takes, at one row
+    in 32 of its first routed layer, the best held expert it had passed
+    over, and says so. The reference follows, so every compared row
+    reads as a sound engine's; what it was handed lies far under its own
+    k-th best at some faulted row, and the margin alone fails the run."""
+    with controls.of(spec.load_cell(cell, True)["hp"]).router_fault():
+        probe = probe_of(cell)
+        got = probe.read(seed)
+    check = probe.check
+    counted = serve_load.counted(got, check)
+    assert not serve_load.matches_reference(got, check)
+    assert counted["route_margin"][0] > 1.5 * check["route_margin_tol"]
+    assert max(rows_of(got)) <= ROOM[True] * check["rel_rms_tol"]
+    assert within({k: v for k, v in counted.items() if k != "route_margin"})
+    assert serve_load.matches_reference(dict(got, route_margin=[0.0]), check)
+
+
+@pytest.mark.parametrize("cell", [c for c in TREE_SERVE if routed(c)])
+def test_without_the_key_the_same_cell_stands_before_the_wall(cell, probes):
+    """The same engine, weights and probe against the reference's OWN
+    choices, as the cell was compared until PR 61: a flip is carried
+    through the state layers behind it into the rows that follow, so on
+    every seed more rows read over the limit than any share a cell may
+    state allows, and most of them over all the seeds; the control reads
+    over at every row either way, so no limit and no count stands
+    between the two (PERF.md section 6, PR 44)."""
+    probe = probes[cell]
+    own = {k: v for k, v in probe.check.items()
+           if k not in ("routing", "route_margin_tol")}
+    tol, over, rows = own["rel_rms_tol"], 0, 0
+    for seed in SEEDS[True]["sound"][:3]:
+        got = server.reference_readings(
+            probe._engine(seed), probe.family, seed, probe.hp, own)
+        assert "route_margin" not in got
+        mine = sum(x > tol for x in rows_of(got))
+        assert mine > serve_load.allowed(
+            len(rows_of(got)), serve_load.SHARE_CEILINGS["rel_rms_over_share"])
+        over, rows = over + mine, rows + len(rows_of(got))
+    assert over > rows / 2
+
+
+@pytest.mark.parametrize("cell", TREE_SERVE)
+def test_an_engine_that_cannot_say_what_it_chose(cell):
+    """Under a cell that asks for the engine's choices it fails by the
+    sentence, which names ``read_choices`` (``serve_load.run`` raises the
+    same before its window, with the cell's name); under any other cell
+    it is read as an engine that can, to the last digit: the door is
+    asked for by the cell, not taken because it is there."""
+    probe, seed = probe_of(cell), SEEDS[states_shares(cell)]["sound"][0]
+    eng = probe._engine(seed)
+    if getattr(eng, "read_choices", None) is None:
+        assert not routed(cell)
+        return
+    with_door = None if routed(cell) else probe.read(seed, served=False)
+    eng.read_choices = None
+    if routed(cell):
+        with pytest.raises(RuntimeError, match=r"gives no\s+read_choices"):
+            probe.read(seed, served=False)
+        assert f"cell {cell}: " in server.no_door(f"cell {cell}")
+        return
+    without = probe.read(seed, served=False)
+    for name in PROBE_LISTS:
+        assert without[name] == with_door[name]
+    assert "route_margin" not in without
 
 
 # ---------------------------------------------------- a probe that fits

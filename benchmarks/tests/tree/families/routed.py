@@ -29,8 +29,11 @@ def init_params(key, cfg):
     return routed_standin.init_params(key, sizes=cfg)
 
 
-# the float32 side at ``highest`` of the same seeded weights
+# the float32 side at ``highest`` of the same seeded weights, and the
+# same under the experts an engine says it chose (a cell that says
+# ``"routing": "engine"``)
 reference_logits = routed_standin.reference_logits
+reference_routed = routed_standin.reference_routed
 
 
 # its programs carry no named scope
