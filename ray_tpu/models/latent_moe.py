@@ -46,7 +46,17 @@ one runs follows from the call alone:
   Wuk) = (q_nope Wuk^T) . c`` and ``sum_s p_s (c_s Wuv) = (sum_s p_s
   c_s) Wuv``, so every head attends to the latent rows themselves, one
   576-wide key and one 512-wide value for all of them
-  (``attn_latent_decode``), block by block as well.
+  (``attn_latent_decode``), block by block as well. Where the widths
+  tile (kv_rank in 128s, the rotary part and the heads in 16s, the
+  cache's rows in 128s: the published ones do) that is one Pallas kernel
+  a layer between the two foldings, ``ops/pallas_latent_attention.py``
+  ``latent_decode_attention``: a lane's heads attend together to a
+  block of its rows, fetched once out of the stacks where they lie,
+  each lane through the blocks up to its own last row and an idle lane
+  through none (its result zeros, which the engine drops); elsewhere a
+  ``jax.numpy`` loop with the same arithmetic
+  (``attend_absorbed_blockwise``, every lane through the longest live
+  lane's blocks), which is also the kernel's reference.
 
 Above some 170 rows a call the expanded form is the cheaper (it pays
 ``2 x kv_rank x heads x (nope + v)`` FLOPs once a latent row; the
@@ -68,8 +78,12 @@ row's ``experts_per_token``, so that the held share of the routing is
 read and not assumed), and for the two attention forms
 ``attn_pairs_prefill`` (a chunk's live rows x the rows each attends to),
 ``attn_rows_prefill`` (the latent rows a chunk call attends to, each
-expanded once) and ``attn_rows_decode`` (the rows a live lane attends
-to), each summed over layers and calls.
+expanded once), ``attn_rows_decode`` (the rows a live lane attends to)
+and ``attn_blocks_decode`` (the rows of the blocks the decode form took
+the call's lanes through, ``absorbed_blocks``: on the kernel's path
+every live lane's own blocks, on the loop's the longest's for every
+lane; ``attn_rows_decode`` over it is the share of the fetched rows that
+some lane asked for), each summed over layers and calls.
 """
 
 from __future__ import annotations
@@ -154,13 +168,14 @@ LATENT_MOE_TINY = LatentMoEConfig(
 SHARED_WEIGHTS = ("shared_gate", "shared_up", "shared_down")
 COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
             "moe_held_slabs", "moe_assignments_all", "attn_pairs_prefill",
-            "attn_rows_prefill", "attn_rows_decode")
+            "attn_rows_prefill", "attn_rows_decode", "attn_blocks_decode")
 # (the most a call counts at once, 2048 rows x 16 384 x 5 layers, is
 # 2^27: under the carry of ``decoder``'s counter words)
-# What the ``jax.numpy`` loops take at a time: cache rows a block, and
-# (the prefill form's loop, ``attend_expanded_blockwise``, which since
-# PR 50 is the form of the widths the kernel cannot tile and the tests'
-# reference) the rows of a chunk that attend to them at a time: its
+# What the ``jax.numpy`` loops take at a time (since PR 50 and PR 62
+# they are the forms of the widths the kernels cannot tile, and the
+# tests' reference): cache rows a block, and (the prefill form's loop,
+# ``attend_expanded_blockwise``) the rows of a chunk that attend to them
+# at a time: its
 # score of one tile and block is heads x PREFILL_TILE x PREFILL_BLOCK
 # float32 (34 MB at 128 heads), a decode's lanes x heads x DECODE_BLOCK.
 # Read on a v5e at the published widths, when the loop was the cell's
@@ -168,8 +183,8 @@ COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
 # 2.6 at 128, 3.3 at 512, 4.8 at 1024; a 1024-row call 11 us in tiles of
 # 256, 14 in tiles of 128, 16 in tiles of 512 and 21 as one tile; a
 # decode call of 32 lanes the same at 512, 1024 and 2048 (PERF.md
-# section 6, PR 48 and PR 49). The kernel (``ops/
-# pallas_latent_attention.py``) sizes itself: there 1024 rows at row
+# section 6, PR 48 and PR 49). The kernels (``ops/
+# pallas_latent_attention.py``) size themselves: there 1024 rows at row
 # 3072, a layer, read 8.1 ms at blocks of 256 rows and 2 heads a step,
 # 4.2 at 512 rows and 4 heads in tiles of 512, 4.1 at 1024 rows and at
 # 8 heads, 4.5 in tiles of 256 (the row maxima's lane reductions are
@@ -445,50 +460,93 @@ def attend_expanded_blockwise(c: LatentMoEConfig, q_nope, q_rope, read,
              for _, l, acc in done], axis=1)
 
 
-def attend_absorbed(c: LatentMoEConfig, q_nope, q_rope, read, S: int, pos,
-                    layer, last: jax.Array):
-    """The decode form, one query row a sequence. q_nope (B, 1, H,
-    nope), q_rope (B, 1, H, rope) at ``pos`` (B, 1); ``read`` and ``S``
-    as ``attend_expanded`` takes them -> (B, 1, H, v). ``Wuk`` is folded
-    into the query and ``Wuv`` into the output, so the heads attend to
-    the latent rows themselves, ``DECODE_BLOCK`` at a time; ``last`` is
-    the furthest position a live sequence attends to, and the loop over
-    blocks of rows ends with its block."""
-    B, _, H, _ = q_nope.shape
+def absorbed_blocks(c: LatentMoEConfig, stack, S: int, seen):
+    """What the decode form fetches for a call whose lane b attends to
+    its ``seen[b]`` leading rows (B,; 0: an idle lane) at the read window
+    ``S`` -> (the rows of a block, the blocks each lane is taken
+    through (B,)). On the kernel's path a lane's own count, none for a
+    lane that attends to nothing; on the loop's the count of the
+    longest, for every lane."""
+    from ray_tpu.ops import pallas_latent_attention as kernel
+
+    if kernel.decode_untileable(c.n_heads, c.rope_dim, *stack) is None:
+        block = kernel.decode_block(stack[0])
+        return block, jnp.minimum(-(-seen // block),
+                                  stack[0].shape[2] // block)
     block = _blocks(S, DECODE_BLOCK)
-    scale = 1.0 / math.sqrt(c.nope_dim + c.rope_dim)
+    return block, jnp.broadcast_to(
+        jnp.clip(-(-seen.max() // block), 1, S // block), seen.shape)
+
+
+def attend_absorbed(c: LatentMoEConfig, q_nope, q_rope, stack, index, first,
+                    S: int, pos, layer, blocks):
+    """The decode form, one query row a sequence. q_nope (B, 1, H,
+    nope), q_rope (B, 1, H, rope) at ``pos`` (B, 1); ``stack``, ``index``,
+    ``first`` and ``S`` as ``attend_expanded`` takes them; ``blocks``
+    (B,) of ``absorbed_blocks``: the blocks each lane is taken through
+    -> (B, 1, H, v). ``Wuk`` is folded into the query and ``Wuv`` into
+    the output, so the heads attend to the latent rows themselves, a
+    block at a time. Which of the two implementations runs follows from
+    the shapes alone: ``ops/pallas_latent_attention.py``'s decode
+    kernel where it can tile them (each lane through its own blocks, a
+    lane of none given zeros), ``attend_absorbed_blockwise`` elsewhere
+    (every lane through the longest's)."""
+    from ray_tpu.ops import pallas_latent_attention as kernel
+
     with jax.named_scope("attn_latent_decode"):
         q = jnp.einsum("bhk,chk->bhc", q_nope[:, 0],
                        layer["wuk"].astype(c.dtype))
-
-        def step(i, carry):
-            m, l, acc = carry
-            with jax.named_scope("kv_slice"):
-                rows, k_rope = read(i * block, block)
-            s = (jnp.einsum("bhc,bsc->bhs", q, rows,
-                            preferred_element_type=jnp.float32)
-                 + jnp.einsum("bhr,brs->bhs", q_rope[:, 0], k_rope,
-                              preferred_element_type=jnp.float32)) * scale
-            at = i * block + jnp.arange(block)
-            s = jnp.where((at[None, :] <= pos)[:, None], s, -1e30)
-            m_new = jnp.maximum(m, s.max(-1))
-            p = jnp.exp(s - m_new[..., None])
-            fade = jnp.exp(m - m_new)
-            l = l * fade + p.sum(-1)
-            acc = acc * fade[..., None] + jnp.einsum(
-                "bhs,bsc->bhc", p.astype(c.dtype), rows,
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        blocks = jnp.minimum(last // block + 1, S // block)
-        _, l, acc = jax.lax.fori_loop(
-            0, blocks, step,
-            (jnp.full((B, H), -1e30, jnp.float32),
-             jnp.zeros((B, H), jnp.float32),
-             jnp.zeros((B, H, c.kv_rank), jnp.float32)))
-        mixed = (acc / l[..., None]).astype(c.dtype)
+        if kernel.decode_untileable(c.n_heads, c.rope_dim, *stack) is None:
+            mixed = kernel.latent_decode_attention(
+                q, q_rope[:, 0], *stack, layer=index, slot=first,
+                pos=pos[:, 0], blocks=blocks,
+                scale=1.0 / math.sqrt(c.nope_dim + c.rope_dim))
+        else:
+            mixed = attend_absorbed_blockwise(
+                c, q, q_rope[:, 0], _stack_reader(stack, index, first,
+                                                  q.shape[0]),
+                S, pos, blocks.max())
         return jnp.einsum("bhc,chk->bhk", mixed,
                           layer["wuv"].astype(c.dtype))[:, None]
+
+
+def attend_absorbed_blockwise(c: LatentMoEConfig, q, q_rope, read, S: int,
+                              pos, blocks):
+    """The decode form's attention in ``jax.numpy``, for the shapes the
+    kernel cannot tile and as its numerical reference. q (B, H, kv_rank)
+    the folded queries, q_rope (B, H, rope), at ``pos`` (B, 1); ``read``
+    and ``S`` as ``attend_expanded_blockwise`` takes them -> the mixed
+    latent rows (B, H, kv_rank). ``DECODE_BLOCK`` rows at a time, all
+    lanes through the ``blocks`` leading blocks."""
+    B, H, _ = q.shape
+    block = _blocks(S, DECODE_BLOCK)
+    scale = 1.0 / math.sqrt(c.nope_dim + c.rope_dim)
+
+    def step(i, carry):
+        m, l, acc = carry
+        with jax.named_scope("kv_slice"):
+            rows, k_rope = read(i * block, block)
+        s = (jnp.einsum("bhc,bsc->bhs", q, rows,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhr,brs->bhs", q_rope, k_rope,
+                          preferred_element_type=jnp.float32)) * scale
+        at = i * block + jnp.arange(block)
+        s = jnp.where((at[None, :] <= pos)[:, None], s, -1e30)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        l = l * fade + p.sum(-1)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bhs,bsc->bhc", p.astype(c.dtype), rows,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, blocks, step,
+        (jnp.full((B, H), -1e30, jnp.float32),
+         jnp.zeros((B, H), jnp.float32),
+         jnp.zeros((B, H, c.kv_rank), jnp.float32)))
+    return (acc / l[..., None]).astype(c.dtype)
 
 
 def attn_out(c: LatentMoEConfig, x, attn, layer):
@@ -545,9 +603,9 @@ def init_cache(config: LatentMoEConfig, batch: int, max_seq: int,
 
 def attn_rows_read(config: LatentMoEConfig, cache, rows: int) -> int:
     """Cache rows a sequence one call may read for attention at the read
-    window ``rows``: every layer's bound is the window (the loops stop
-    at the block of the call's last position, which the device counters
-    see and this host-side count does not)."""
+    window ``rows``: every layer's bound is the window (both forms stop
+    at the block of a sequence's last position, which the device
+    counters see and this host-side count does not)."""
     del config, cache
     return rows
 
@@ -606,24 +664,27 @@ def forward_with_cache(
     seen_by_live = jnp.where(live, pos + 1, 0)       # rows a live row sees
     zero = jnp.int32(0)
     if T == 1:
-        # the furthest row a live lane attends to bounds the loop
-        last = jnp.minimum(jnp.where(live, pos, 0).max(), call.window - 1)
-        attended = jnp.stack([zero, zero, seen_by_live.sum()])
+        # the blocks of rows each lane is taken through, a live lane's
+        # own or the longest's for all, as the shapes decide
+        block, blocks = absorbed_blocks(
+            c, (caches[0]["latent"], caches[0]["rope_key"]), call.window,
+            seen_by_live[:, 0])
+        attended = jnp.stack([zero, zero, seen_by_live.sum(),
+                              blocks.sum() * block])
     else:
+        blocks = None
         attended = jnp.stack([seen_by_live.sum(),
-                              seen_by_live.max(axis=1).sum(), zero])
+                              seen_by_live.max(axis=1).sum(), zero, zero])
     attended = attended.astype(jnp.int32)
 
     def attention(x, shards, layer, i):
         """-> (x, the shards' stacks with layer i's new rows)."""
-        def attend(part, latents, q_nope, q_rope, new):
+        def attend(part, latents, q_nope, q_rope, new, blocks):
             with jax.named_scope("kv_write"):
                 latents = _write_rows(latents, new, i, first, part.start_pos)
             if T == 1:
-                attn = attend_absorbed(
-                    c, q_nope, q_rope,
-                    _stack_reader(latents, i, first, part.B), call.window,
-                    part.pos, layer, last)
+                attn = attend_absorbed(c, q_nope, q_rope, latents, i, first,
+                                       call.window, part.pos, layer, blocks)
             else:
                 attn = attend_expanded(c, q_nope, q_rope, latents, i, first,
                                        call.window, part.start_pos, layer)
@@ -633,7 +694,8 @@ def forward_with_cache(
             h = rms_norm(x, layer["attn_norm"], c.norm_eps)
             q_nope, q_rope = latent_q(c, h, layer, cos, sin)
             new = latent_kv(c, h, layer, cos, sin)
-            attn, shards = call.by_shard(attend, shards, q_nope, q_rope, new)
+            attn, shards = call.by_shard(attend, shards, q_nope, q_rope, new,
+                                         blocks)
             return attn_out(c, x, attn, layer), shards
 
     def dense_step(x, shards, layer, i):
@@ -671,11 +733,11 @@ def _import_kernel():
     from ray_tpu.ops import pallas_latent_attention  # noqa: F401
 
 
-# Pallas takes 1.2 s to import on a replica's host, a chunk program's
-# first trace needs it, and ``setup_s`` is a metric with a bound. A
-# process that imports this module to serve goes on to open its chip,
-# nine seconds in which Python has nothing to do: the import runs beside
-# that, and ``attend_expanded``'s own import finds it done (or waits on
+# Pallas takes 1.2 s to import on a replica's host, a chunk or decode
+# program's first trace needs it, and ``setup_s`` is a metric with a
+# bound. A process that imports this module to serve goes on to open its
+# chip, nine seconds in which Python has nothing to do: the import runs
+# beside that, and the two forms' own imports find it done (or wait on
 # the module's lock for the rest). PERF.md section 6, PR 50.
 threading.Thread(target=_import_kernel, name="import-latent-kernel",
                  daemon=True).start()

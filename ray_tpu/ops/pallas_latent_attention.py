@@ -1,9 +1,11 @@
-"""Pallas TPU kernel for the prefill (expanded) form of latent attention.
+"""Pallas TPU kernels for the two forms of latent attention: the prefill
+(expanded) form, and since PR 62 the decode (absorbed) form (the second
+half of this note and of the file).
 
 ``models/latent_moe.py`` keeps one latent row and one turned rotary key
 a token a layer; a prefill chunk makes the heads' keys and values from
-the latent rows it attends to and scores its rows against them. This
-kernel is that form with the score, the running softmax and the
+the latent rows it attends to and scores its rows against them. The
+first kernel is that form with the score, the running softmax and the
 accumulator kept in VMEM; ``latent_moe.attend_expanded`` dispatches to
 it wherever ``untileable`` finds nothing against the shapes, and its
 ``jax.numpy`` block loop (the arithmetic and every rounding point of
@@ -88,6 +90,76 @@ layer: 4.1 ms where the block loop took 8):
 - ``_interpret`` is ``pallas_attention``'s: on the CPU the kernel's own
   code runs interpreted, copies and semaphores too, so tier-1 tests it
   at small tileable shapes.
+
+**The decode form** (``latent_decode_attention``, kernel
+``latent_attention_decode``; ``latent_moe.attend_absorbed`` dispatches
+to it between its two foldings wherever ``decode_untileable`` finds
+nothing against the shapes, and ``latent_moe.attend_absorbed_blockwise``
+is the form of the other shapes and the reference). One query row a
+lane, ``Wuk`` folded into it, so all H heads of a lane attend to the
+latent rows themselves:
+    q (B, H, kv_rank), q_rope (B, H, rope); the cache's two stacks as
+    above, of which lane b reads layer ``layer``, cache row ``slot +
+    b`` and its ``blocks[b]`` leading blocks of ``decode_block`` rows;
+    pos (B,): the lane's row, which masks its last block
+    -> the mixed latent rows (B, H, kv_rank), which the caller folds
+    through ``Wuv``; zeros for a lane of no blocks.
+Design notes (PERF.md section 6, PR 62, has the chip readings; all on a
+v5e at the published widths, 16 lanes of 128 heads over latent rows of
+512 + 64, a layer-call with the query's folding (23 us), all lanes at
+6144 rows unless said, where the reader's count allows 139 us: 1152
+bytes and 2 x 128 x 1088 FLOPs a row, the two bounds equal; the
+``jax.numpy`` loop took 369 us there and 540 on the cell's mix of
+lengths, 15 lanes of 2048 to 8960 rows and one idle):
+- Grid (lane,); a lane's queries and result ride the pipeline's
+  BlockSpecs, its running maximum (H, 1), its sums a lane apart (H,
+  128) and its float32 accumulator (H, kv_rank) live in scratch. A
+  block of ``_DECODE_BLOCK`` rows is fetched **once**, a (block,
+  kv_rank) tile of latent rows and a (rope, block) tile of rotary keys
+  by the kernel's own copies out of the stacks where they lie, and
+  serves the score of all heads (two products, 512 and 64 deep, summed)
+  and then, the same tile, the value. Rounding points are the loop's:
+  score and softmax in float32, ``p`` rounded to the compute type
+  before the value's product, ``acc / l`` rounded once.
+- **Each lane through its own blocks**: the per-lane counts arrive by
+  scalar prefetch (``latent_moe.absorbed_blocks``: from the rows a live
+  lane sees, none for an idle lane, whose position is the scratch row
+  far up the cache), so nothing behind a lane's last block is fetched
+  or scored. The call's blocks are one sequence, lane after lane (the
+  lane and block of each by scalar prefetch too), and every block's
+  step starts the fetch of the next one **whichever lane's it is**: a
+  lane's first block is in flight while the lane before it finishes.
+- What bounds it, as read: the fetches alone, nothing computed, take
+  165 us (686 GB/s); a third buffer read the same as two (239.7 against
+  242.7 us at blocks of 1024, 287.3 against 288.0 at 512), so the
+  fetch is hidden and the rest is the block's own chain (score, row
+  maximum, exp, value, the accumulator's update): 197 us + 0.47 us a
+  block-step over blocks of 256 / 512 / 1024 / 2048 rows (381 / 288 /
+  243 / 220 us).
+- ``_DECODE_BLOCK`` = 1024 in tiles of ``_DECODE_TILE`` = 512, each
+  tile with the running update of its own and **every tile's score
+  before the first tile's softmax** in program order: a tile's score
+  needs nothing of the tile before it, and the scheduler runs its
+  matmuls beside that one's exp: 223.5 us (224.4 in tiles of 256)
+  where the block as one tile read 242.8; score and softmax tile by
+  tile in turn 266.8; one maximum a block and the tiles after it 240.5.
+  Blocks of 2048 in tiles of 512 read 208.6 at equal lanes and no
+  better than 1024 on the mix (a lane's count is rounded up to a block:
+  a lane of 6100 rows wastes 512 on average at 1024, 1024 at 2048);
+  512 in tiles of 256 280.6. On the mix: 235 us.
+- Which operand stands still in the MXU made no difference: the score
+  as ``rows @ q^T`` (the 128 x 512 query the stationary one, 1024 rows
+  streamed) transposed back read 241.6 us against 243.2 for ``q @
+  rows^T``. The value's product has the cache rows stationary either
+  way (they are what is contracted over).
+- Masking by position in every block (``-1e30``; row 0 is seen by every
+  lane): masking the lane's last block alone through ``lax.cond`` read
+  265 us against 243, the branch dearer than the compare and select.
+- Left out: a last block fetched and scored in tiles (would take half
+  the mix's rounding waste, 4 % of the rows); the step's chain
+  overlapped across blocks by hand (the 0.3 us a step that is left);
+  the value's folding inside the kernel (``Wuv``'s einsum outside is 50
+  us a layer-call, twice what its 17 MB cost to read).
 """
 
 from __future__ import annotations
@@ -320,3 +392,183 @@ def _call(q_nope, q_rope, latents, keys, wuk, wuv, layer, slot, start_pos, *,
       q_nope, q_rope, latents, keys, wuk.transpose(1, 0, 2),
       wuv.transpose(1, 0, 2))
     return out.transpose(0, 2, 1, 3)
+
+
+# -- the decode (absorbed) form -----------------------------------------
+# Cache rows a block of the decode form (what is fetched at a time, and
+# what a lane's count is rounded up to) and the rows of a block that
+# are scored and weighed at a time
+_DECODE_BLOCK = 1024
+_DECODE_TILE = 512
+
+
+def decode_block(latents) -> int:
+    """Cache rows a block of the decode kernel over these stacks."""
+    return _divisor(latents.shape[2], _DECODE_BLOCK)
+
+
+def decode_untileable(H: int, rope: int, latents, keys):
+    """Why the decode kernel cannot take ``H`` heads with rotary parts
+    of ``rope`` over the cache's two stacks, or None when it can."""
+    kv_rank = latents.shape[3]
+    if kv_rank % _LANES:
+        return f"kv_rank={kv_rank} not a multiple of {_LANES} lanes"
+    if rope % 16 or H % 16:
+        return f"rope={rope} or heads={H} not a multiple of 16 sublanes"
+    if latents.shape[2] % _LANES:
+        return f"cache rows {latents.shape[2]} not a multiple of {_LANES}"
+    if keys.shape[2:] != (rope, latents.shape[2]):
+        return "the rotary keys do not fit the queries or the latent rows"
+    return None
+
+
+def _decode_kernel(meta_ref, blocks_ref, pos_ref, base_ref, lane_ref, block_ref,
+                   q_ref, qr_ref, lat_hbm, key_hbm, o_ref,
+                   lat_buf, key_buf, sems, m_scr, l_scr, acc_scr,
+                   *, scale, H, block, tile):
+    b = pl.program_id(0)
+    layer, first, total = meta_ref[0], meta_ref[1], meta_ref[2]
+    blocks, base, pos = blocks_ref[b], base_ref[b], pos_ref[b]
+
+    def fetch(g):
+        """The copies of the call's g-th block, all lanes' blocks in
+        one sequence, into the buffer of its turn."""
+        slot = g % 2
+        lane = first + lane_ref[g]
+        at = pl.ds(pl.multiple_of(block_ref[g] * block, block), block)
+        return (pltpu.make_async_copy(lat_hbm.at[layer, lane, at, :],
+                                      lat_buf.at[slot], sems.at[0, slot]),
+                pltpu.make_async_copy(key_hbm.at[layer, lane, :, at],
+                                      key_buf.at[slot], sems.at[1, slot]))
+
+    def start(g):
+        @pl.when(g < total)
+        def _():
+            for copy in fetch(g):
+                copy.start()
+
+    # the first grid step starts the call's first fetch; from there
+    # every block's step starts the next one's, whichever lane's it is
+    @pl.when(b == 0)
+    def _first():
+        start(0)
+
+    @pl.when(blocks == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(blocks > 0)
+    def _live():
+        _init(m_scr, l_scr, acc_scr)
+        q, qr = q_ref[0], qr_ref[0]
+        column = jax.lax.broadcasted_iota(jnp.int32, (H, tile), 1)
+
+        def step(j, carry):
+            g = base + j
+            slot = g % 2
+            start(g + 1)
+            for copy in fetch(g):
+                copy.wait()
+            # every tile's score first, written out: none needs a tile
+            # before it, so its matmuls run beside that one's softmax
+            scores = [
+                (jax.lax.dot_general(q, lat_buf[slot, k:k + tile, :], _NT,
+                                     preferred_element_type=jnp.float32)
+                 + jax.lax.dot_general(qr, key_buf[slot, :, k:k + tile], _NN,
+                                       preferred_element_type=jnp.float32)
+                 ) * scale for k in range(0, block, tile)]
+            for i, s in enumerate(scores):
+                rows = lat_buf[slot, i * tile:(i + 1) * tile, :]
+                s = jnp.where(column <= pos - (j * block + i * tile), s,
+                              _MASKED)
+                m = m_scr[...]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                fade = jnp.exp(m - m_new)
+                m_scr[...] = m_new
+                l_scr[...] = l_scr[...] * fade + sum(
+                    p[:, k:k + _LANES] for k in range(0, tile, _LANES))
+                acc_scr[...] = acc_scr[...] * fade + jax.lax.dot_general(
+                    p.astype(rows.dtype), rows, _NN,
+                    preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, blocks, step, 0)
+        o_ref[0] = (acc_scr[...] / jnp.sum(l_scr[...], axis=1, keepdims=True)
+                    ).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, q_rope, latents, keys, *, layer, slot, pos,
+                            blocks, scale: float):
+    """The absorbed form over the cache's stacks: q (B, H, kv_rank) the
+    queries folded through ``wuk``, q_rope (B, H, rope), lane b's one
+    row at ``pos[b]`` attending to the ``blocks[b]`` leading blocks
+    (``decode_block`` rows each) of layer ``layer``, cache row ``slot +
+    b`` -> the mixed latent rows (B, H, kv_rank); zeros for a lane of no
+    blocks. Raises NotImplementedError for shapes the kernel does not
+    tile (``decode_untileable``)."""
+    reason = decode_untileable(*q_rope.shape[1:], latents, keys)
+    if reason is None and q.shape[2] != latents.shape[3]:
+        reason = "the folded queries are not as wide as the latent rows"
+    if reason is not None:
+        raise NotImplementedError(reason)
+    block = decode_block(latents)
+    return _call_decode(q, q_rope, latents, keys, layer, slot, pos, blocks,
+                        scale=scale, block=block,
+                        tile=_divisor(block, _DECODE_TILE),
+                        interpret=_flash._interpret())
+
+
+# jitted for the reason ``_call`` is; the read window is no argument, so
+# the engine's decode variants trace and lower one kernel between them
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block", "tile", "interpret"))
+def _call_decode(q, q_rope, latents, keys, layer, slot, pos, blocks, *,
+                 scale, block, tile, interpret):
+    B, H, kv_rank = q.shape
+    rope, S = keys.shape[2:]
+    dtype = q.dtype
+    blocks = jnp.minimum(blocks, S // block).astype(jnp.int32)
+    # the call's blocks as one sequence, lane after lane: the lane and
+    # the block of each, and where each lane's begin
+    ends = jnp.cumsum(blocks)
+    base = ends - blocks
+    turn = jnp.arange(B * (S // block), dtype=jnp.int32)
+    # (the lanes that end at or before a turn, counted: a search by
+    # halves is a loop of its own in every layer of the scan)
+    lane_of = jnp.minimum((ends[None, :] <= turn[:, None]).sum(1), B - 1)
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.asarray(slot, jnp.int32), ends[-1]])
+    lane = lambda width: pl.BlockSpec((1, H, width), lambda b, *_: (b, 0, 0))
+    scratch = [pltpu.VMEM((2, block, kv_rank), dtype),
+               pltpu.VMEM((2, rope, block), dtype),
+               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.VMEM((H, 1), jnp.float32),
+               pltpu.VMEM((H, _LANES), jnp.float32),
+               pltpu.VMEM((H, kv_rank), jnp.float32)]
+    # half the cache attended, as a lane holds on average
+    attended = B * H * S // 2
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, H=H, block=block,
+                          tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(B,),
+            in_specs=[lane(kv_rank), lane(rope),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=lane(kv_rank), scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B, H, kv_rank), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * attended * (2 * kv_rank + rope)),
+            bytes_accessed=int((2 * q.size + q_rope.size
+                                + B * (S // 2) * (kv_rank + rope))
+                               * dtype.itemsize),
+            transcendentals=int(attended)),
+        interpret=interpret,
+        name="latent_attention_decode",
+    )(meta, blocks, pos.astype(jnp.int32), base.astype(jnp.int32),
+      lane_of.astype(jnp.int32), turn - base[lane_of].astype(jnp.int32),
+      q, q_rope, latents, keys)

@@ -390,7 +390,8 @@ def latent_chunks(check, sds):
         check(f"latent decode step of {lanes} lanes reading {window} of "
               f"{lanes} x {max_seq}, published widths, one device",
               partial(lower_decode, sds, latent_moe, cfg, params, cache,
-                      lanes, window), forbid=no_leaf_copy)
+                      lanes, window), expect=("latent_attention_decode",),
+              forbid=rf"{no_leaf_copy}|f32\[{lanes},{cfg.n_heads},\d{{3,}}\]")
 
 
 def tiled_chunks(check, sds, label, model, cfg, params, cache, lanes,

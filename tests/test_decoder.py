@@ -14,14 +14,14 @@ import pytest
 from ray_tpu.llm import GenRequest, LlamaEngine
 from ray_tpu.models import decoder, hybrid_ssm, latent_moe, llama, window_moe
 
-NAMES = {5: window_moe.COUNTERS, 8: latent_moe.COUNTERS}
+NAMES = {5: window_moe.COUNTERS, 9: latent_moe.COUNTERS}
 
 
 @pytest.mark.parametrize("n", sorted(NAMES))
 def test_counters_carry_into_the_high_word_and_read_back_the_sum(n):
     """Calls that count just under 2^30 each: the low words wrap, the
     high ones take the carry, ``read_counters`` returns the sum under
-    each family's names (two of the layouts in use, 5 and 8 counters)."""
+    each family's names (two of the layouts in use, 5 and 9 counters)."""
     fold = jax.jit(decoder.fold_counts)
     words = decoder.counter_words(n)
     assert words.shape == (n, 2) and words.dtype == jnp.int32

@@ -187,12 +187,22 @@ def test_blockwise_prefill_attention_equals_the_whole_matrix(
     np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
 
 
+def stack_of(c, rows):
+    """Cache rows given as one array (B, S, kv_rank + rope) as the two
+    stacks of a one-layer cache: latent (1, B, S, kv_rank), rope_key
+    (1, B, rope, S)."""
+    return (rows[None, ..., :c.kv_rank],
+            rows[None, ..., c.kv_rank:].swapaxes(2, 3))
+
+
 @pytest.mark.parametrize("block", [8, 64])
 def test_the_absorbed_form_equals_the_expanded_form_on_the_same_cache(
         block, monkeypatch):
     """One query row a lane at its own length, lane 2 idle at the
     scratch row: the decode form, which never makes a key or a value,
-    against the prefill form's whole-matrix arithmetic."""
+    against the prefill form's whole-matrix arithmetic. At widths the
+    decode kernel cannot tile, so in the ``jax.numpy`` loop, every lane
+    through the longest live lane's blocks."""
     c = F32
     monkeypatch.setattr(lm, "DECODE_BLOCK", block)
     start = jnp.asarray([40, 7, 63, 22])
@@ -200,8 +210,13 @@ def test_the_absorbed_form_equals_the_expanded_form_on_the_same_cache(
                                                           start)
     want = attend_whole(c, q_nope, q_rope, latent, pos, layer)
     live = np.asarray([0, 1, 3])
-    got = lm.attend_absorbed(c, q_nope, q_rope, rows_of(c, latent), 64, pos,
-                             layer, last=jnp.int32(40))
+    stack = stack_of(c, latent)
+    rows, blocks = lm.absorbed_blocks(c, stack, 64,
+                                      jnp.asarray([41, 8, 0, 23]))
+    assert rows == block
+    assert blocks.tolist() == [-(-41 // block)] * 4
+    got = lm.attend_absorbed(c, q_nope, q_rope, stack, 0, 0, 64, pos, layer,
+                             blocks)
     assert rel_rms(got[live], want[live]) < 1e-5
 
 
@@ -289,6 +304,61 @@ def test_the_control_reaches_the_kernel_through_the_layers_weights():
         rounded = lm.attend_expanded(*args)
     assert lm.attend_expanded(*args) is not None      # the patch is gone
     assert 0.01 < rel_rms(rounded, sound) < 0.2
+
+
+# widths and a cache the decode kernel can tile as well (16 heads: a
+# lane's heads are the rows of its matmuls); ``test_pallas_latent_
+# attention.py`` has the kernel's own cases
+DECODABLE = dataclasses.replace(TILEABLE, n_heads=16)
+
+
+def prefilled(c, params, tokens, max_seq):
+    """A cache of ``max_seq`` rows a lane with lane b's leading
+    ``len(tokens[b])`` tokens in it, a lane a call."""
+    cache = lm.init_cache(c, len(tokens), max_seq)
+    for b, row in enumerate(tokens):
+        _, cache = lm.forward_with_cache(
+            params, jnp.asarray(row)[None], cache, jnp.zeros(1, jnp.int32),
+            c, slot=jnp.int32(b))
+    return cache
+
+
+def test_a_decode_call_through_the_kernel_gives_the_loops_logits(monkeypatch):
+    """``forward_with_cache`` at T = 1 on caches of 256 rows (which the
+    decode kernel tiles, in blocks of 128) and of 192 (which it does
+    not: the ``jax.numpy`` loop), the same parameters and tokens, lane 1
+    idle: the live lanes' logits agree to the prefill kernel's
+    tolerance, ``attn_rows_decode`` is the same count on both paths, and
+    ``attn_blocks_decode`` is what each path's bounds say."""
+    from ray_tpu.ops import pallas_latent_attention as kernel
+
+    monkeypatch.setattr(kernel, "_DECODE_BLOCK", 128)
+    c = DECODABLE
+    params = lm.init_params(jax.random.PRNGKey(5), c)
+    lengths = (150, 3, 128)
+    rng = np.random.default_rng(5)
+    tokens = [rng.integers(1, c.vocab_size, n) for n in lengths]
+    step = jnp.asarray([[7], [0], [9]])
+    logits, counts = {}, {}
+    for max_seq in (256, 192):
+        shapes = jax.eval_shape(lambda: lm.init_cache(c, 3, max_seq))
+        assert (kernel.decode_untileable(
+            c.n_heads, c.rope_dim, shapes["latent"], shapes["rope_key"])
+            is None) == (max_seq == 256)
+        cache = prefilled(c, params, tokens, max_seq)
+        before = lm.read_counters(cache)
+        logits[max_seq], cache = lm.forward_with_cache(
+            params, step, cache, jnp.asarray([150, max_seq - 1, 128]), c)
+        after = lm.read_counters(cache)
+        counts[max_seq] = {k: after[k] - before[k] for k in after}
+    live = np.asarray([0, 2])
+    assert rel_rms(logits[256][live], logits[192][live]) < 1e-5
+    for max_seq in (256, 192):
+        assert counts[max_seq]["attn_rows_decode"] == 3 * (151 + 129)
+    # the kernel: lanes of 151 and 129 rows two blocks of 128 each, the
+    # idle lane none; the loop: all three lanes through 192 rows
+    assert counts[256]["attn_blocks_decode"] == 3 * (2 + 0 + 2) * 128
+    assert counts[192]["attn_blocks_decode"] == 3 * 3 * 192
 
 
 # ------------------------------------------------------ the held share
@@ -396,6 +466,9 @@ def test_the_counters_equal_a_count_made_by_hand(path):
     assert got["attn_pairs_prefill"] == 3 * (20 * 21 // 2)
     assert got["attn_rows_prefill"] == 3 * 20
     assert got["attn_rows_decode"] == 3 * 21
+    # the loop's path (widths no kernel tiles): both lanes through the
+    # one block of 64 rows that holds the live lane's last row
+    assert got["attn_blocks_decode"] == 3 * 2 * 64
 
 
 # -------------------------------------- the shared code's other family

@@ -3,7 +3,10 @@ interpret mode at small shapes it can tile (2 heads of 128 + 64 / 128,
 latent rows of 128 or 512, float32 and bfloat16), against the
 whole-matrix arithmetic (``attend_whole`` of ``test_latent_moe.py``) and
 against ``latent_moe``'s ``jax.numpy`` block loop, through
-``latent_moe.attend_expanded`` as the model calls it."""
+``latent_moe.attend_expanded`` as the model calls it; and the decode
+form's kernel (16 heads, blocks of 128 rows) against ``latent_moe``'s
+``jax.numpy`` loop over the same cache, through
+``latent_moe.attend_absorbed``."""
 
 import dataclasses
 import os
@@ -18,6 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from test_latent_moe import attend_whole, rel_rms  # noqa: E402
+from ray_tpu.models import decoder  # noqa: E402
 from ray_tpu.models import latent_moe as lm  # noqa: E402
 from ray_tpu.ops import pallas_latent_attention as kernel  # noqa: E402
 
@@ -33,6 +37,8 @@ def small_blocks(monkeypatch):
     blocks and a chunk of 256 two tiles."""
     monkeypatch.setattr(kernel, "_BLOCK", 128)
     monkeypatch.setattr(kernel, "_TILE", 128)
+    monkeypatch.setattr(kernel, "_DECODE_BLOCK", 128)
+    monkeypatch.setattr(kernel, "_DECODE_TILE", 128)
 
 
 def inputs(c, seed, T, start):
@@ -183,3 +189,185 @@ def test_what_the_kernel_cannot_tile_goes_to_the_block_loop(change, why):
                              layer)
     want = whole(c, q_nope, q_rope, stack, 1, 1, start, layer)
     assert rel_rms(got, want) < 1e-5
+
+
+# ------------------------------------------------------ the decode form
+# a lane's heads are the rows of the decode kernel's matmuls: 16 of them
+DECODABLE = dataclasses.replace(TILEABLE, n_heads=16)
+IDLE = decoder.idle_position(CACHE_ROWS)
+
+# (each lane's position, IDLE for a lane that is nobody's; layer; first
+# lane; read window; the blocks of 128 rows each lane is taken through)
+DECODE_CASES = {
+    "lanes_of_unequal_length": ([300, 40, 510], 0, 0, 512, [3, 1, 4]),
+    "last_rows_at_a_blocks_last_and_first_row": (
+        [127, 128, 255], 1, 0, 512, [1, 2, 2]),
+    "an_idle_lane_among_live_ones": ([200, IDLE, 77], 1, 0, 512, [2, 0, 1]),
+    "every_lane_idle": ([IDLE, IDLE], 0, 1, 512, [0, 0]),
+    "a_first_lane_that_is_not_0": ([130, 383], 1, 1, 512, [2, 3]),
+    "one_lane_the_last_of_the_cache": ([256], 0, 2, 512, [3]),
+    "the_shorter_read_window": ([100, 255, IDLE], 1, 0, 256, [1, 2, 0]),
+}
+
+
+def decode_inputs(c, seed, pos):
+    stack, layer, q_nope, q_rope, pos = inputs(c, seed, 1, pos)
+    return stack, layer, q_nope, q_rope, pos[:, None]
+
+
+def absorbed(c, q_nope, q_rope, stack, index, first, rows, pos, layer):
+    """Through the model's own door, which has to take the kernel ->
+    (the lanes' results, the blocks it took each lane through)."""
+    assert kernel.decode_untileable(c.n_heads, c.rope_dim, *stack) is None
+    seen = jnp.where(pos[:, 0] == IDLE, 0, pos[:, 0] + 1)
+    block, blocks = lm.absorbed_blocks(c, stack, rows, seen)
+    assert block == 128
+    return lm.attend_absorbed(c, q_nope, q_rope, stack, index, first, rows,
+                              pos, layer, blocks), blocks.tolist()
+
+
+def absorbed_loop(c, q_nope, q_rope, stack, index, first, rows, pos, layer):
+    """``latent_moe``'s ``jax.numpy`` loop between the same two
+    foldings, a lane at a time (the CPU has no batched bfloat16 matmul
+    into float32), each through the blocks of its own last row."""
+    out = []
+    for b in range(len(pos)):
+        q = jnp.einsum("bhk,chk->bhc", q_nope[b:b + 1, 0], layer["wuk"])
+        mixed = lm.attend_absorbed_blockwise(
+            c, q, q_rope[b:b + 1, 0],
+            lm._stack_reader(stack, index, first + b, 1), rows, pos[b:b + 1],
+            jnp.minimum(pos[b, 0], rows - 1) // lm._blocks(
+                rows, lm.DECODE_BLOCK) + 1)
+        out.append(jnp.einsum("bhc,chk->bhk", mixed, layer["wuv"])[:, None])
+    return jnp.concatenate(out)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_the_decode_kernel_equals_the_block_loop(case, dtype):
+    """Every live lane's result is the loop's (whose rounding points are
+    the kernel's; its blocks are the whole window here, the kernel's 128
+    rows); each lane is taken through the blocks of its own last row and
+    an idle lane through none, whatever its rows hold (NaN here, as are
+    the rows behind every lane's last block, the other layer and the
+    lanes outside the call): its result is zeros and the live lanes'
+    are the same to the last bit."""
+    c = dataclasses.replace(DECODABLE, dtype=dtype)
+    pos, index, first, rows, want_blocks = DECODE_CASES[case]
+    stack, layer, q_nope, q_rope, pos = decode_inputs(c, 11, pos)
+    args = (index, first, rows, pos, layer)
+    got, blocks = absorbed(c, q_nope, q_rope, stack, *args)
+    assert blocks == want_blocks
+    assert got.shape == (len(pos), 1, c.n_heads, c.v_dim)
+    assert got.dtype == dtype
+    live = np.flatnonzero(np.asarray(pos[:, 0]) != IDLE)
+    got32 = np.asarray(got.astype(jnp.float32))
+    assert not got32[np.asarray(pos[:, 0]) == IDLE].any()
+    if len(live):
+        want = absorbed_loop(c, q_nope, q_rope, stack, *args)
+        assert rel_rms(got32[live], want.astype(jnp.float32)[live]) < (
+            1e-5 if dtype == jnp.float32 else 6e-3)
+    # what no lane was taken through may hold anything
+    fetched = np.zeros((LAYERS, LANES, CACHE_ROWS), bool)
+    for b, n in enumerate(want_blocks):
+        fetched[index, first + b, :n * 128] = True
+    latents = jnp.where(fetched[..., None], stack[0], jnp.nan)
+    keys = jnp.where(fetched[:, :, None, :], stack[1], jnp.nan)
+    again, _ = absorbed(c, q_nope, q_rope, (latents, keys), *args)
+    np.testing.assert_array_equal(got32, np.asarray(again, np.float32))
+
+
+@pytest.mark.parametrize("block, tile", [(128, 128), (256, 128), (512, 128),
+                                         (256, 256), (512, 1024)])
+def test_a_decode_block_of_one_two_and_four_tiles(block, tile, monkeypatch):
+    """A block is scored and weighed in tiles, each with the running
+    maximum's update of its own: blocks of one tile, of two and the
+    whole cache as one block of four (and a tile larger than the block,
+    which is then the block), lanes ending in a block's first tile, in
+    its last and at the cache's last row but one."""
+    monkeypatch.setattr(kernel, "_DECODE_BLOCK", block)
+    monkeypatch.setattr(kernel, "_DECODE_TILE", tile)
+    c = DECODABLE
+    stack, layer, q_nope, q_rope, pos = decode_inputs(c, 16, [260, 127, 510])
+    seen = pos[:, 0] + 1
+    rows, blocks = lm.absorbed_blocks(c, stack, 512, seen)
+    assert rows == block
+    assert blocks.tolist() == [-(-int(n) // block) for n in seen]
+    got = lm.attend_absorbed(c, q_nope, q_rope, stack, 1, 0, 512, pos, layer,
+                             blocks)
+    want = absorbed_loop(c, q_nope, q_rope, stack, 1, 0, 512, pos, layer)
+    assert rel_rms(got, want) < 1e-5
+
+
+def test_both_read_windows_give_the_decode_kernels_result_to_the_last_bit():
+    """The kernel takes no read window: lanes that fit the cache's
+    first half give the same bits at either, and the engine's two
+    decode variants hold one trace of it."""
+    c = DECODABLE
+    stack, layer, q_nope, q_rope, pos = decode_inputs(c, 12, [10, 255, IDLE])
+    half, _ = absorbed(c, q_nope, q_rope, stack, 1, 0, 256, pos, layer)
+    whole_window, _ = absorbed(c, q_nope, q_rope, stack, 1, 0, 512, pos, layer)
+    np.testing.assert_array_equal(np.asarray(half), np.asarray(whole_window))
+
+
+def test_two_shards_in_one_decode_call_are_each_their_own_call():
+    """``decoder.Call.by_shard`` as ``forward_with_cache`` uses it: four
+    lanes over a pair of caches, the per-lane blocks cut like the
+    queries; each shard's lanes get what a call of that shard alone
+    gives, to the last bit."""
+    c = DECODABLE
+    shards = [decode_inputs(c, seed, at) for seed, at in (
+        (13, [300, IDLE]), (14, [5, 140]))]
+    layer = shards[0][1]
+    q_nope, q_rope, pos = (jnp.concatenate([s[i] for s in shards])
+                           for i in (2, 3, 4))
+    # two lanes a cache
+    stacks = tuple(tuple(leaf[:, :2] for leaf in s[0]) for s in shards)
+    call = decoder.Call(jnp.zeros((4, 1), jnp.int32), pos[:, 0], CACHE_ROWS,
+                        shards=2)
+    seen = jnp.where(call.live(), call.pos + 1, 0)[:, 0]
+    _, blocks = lm.absorbed_blocks(c, stacks[0], CACHE_ROWS, seen)
+    assert blocks.tolist() == [3, 0, 1, 2]
+
+    def attend(part, stack, q_nope, q_rope, blocks):
+        return lm.attend_absorbed(c, q_nope, q_rope, stack, 1, 0, CACHE_ROWS,
+                                  part.pos, layer, blocks), stack
+
+    got, _ = call.by_shard(attend, stacks, q_nope, q_rope, blocks)
+    for s, stack in enumerate(stacks):
+        at = slice(2 * s, 2 * s + 2)
+        alone, _ = absorbed(c, q_nope[at], q_rope[at], stack, 1, 0,
+                            CACHE_ROWS, pos[at], layer)
+        np.testing.assert_array_equal(np.asarray(got[at]), np.asarray(alone))
+
+
+@pytest.mark.parametrize("change, why", [
+    (dict(kv_rank=32), "kv_rank"), (dict(rope_dim=8), "rope"),
+    (dict(n_heads=2), "heads"), (dict(), "cache rows")])
+def test_what_the_decode_kernel_cannot_tile_goes_to_the_block_loop(
+        change, why):
+    """A latent row that is no whole number of lanes, a rotary part or
+    a count of heads that is no whole number of sublanes, or a cache of
+    192 rows: ``decode_untileable`` says which, the kernel itself
+    refuses, and ``attend_absorbed`` takes every lane through the
+    longest live lane's blocks in the ``jax.numpy`` form."""
+    c = dataclasses.replace(DECODABLE, **change)
+    stack, layer, q_nope, q_rope, pos = decode_inputs(c, 15, [40, 150, 191])
+    if not change:
+        stack = (stack[0][:, :, :192], stack[1][..., :192])
+    rows = stack[0].shape[2]
+    assert why in kernel.decode_untileable(c.n_heads, c.rope_dim, *stack)
+    seen = jnp.asarray([41, 151, 0])
+    block, blocks = lm.absorbed_blocks(c, stack, rows, seen)
+    assert block == rows and blocks.tolist() == [1, 1, 1]
+    with pytest.raises(NotImplementedError):
+        kernel.latent_decode_attention(
+            jnp.zeros((3, c.n_heads, c.kv_rank)), q_rope[:, 0], *stack,
+            layer=1, slot=0, pos=pos[:, 0], blocks=blocks, scale=1.0)
+    got = lm.attend_absorbed(c, q_nope, q_rope, stack, 1, 0, rows, pos, layer,
+                             blocks)
+    rows_as_one = jnp.concatenate(
+        [stack[0][1], stack[1][1].swapaxes(1, 2)], axis=-1)
+    want = attend_whole(c, q_nope, q_rope, rows_as_one, pos, layer)
+    assert rel_rms(got[:2], want[:2]) < 1e-5
