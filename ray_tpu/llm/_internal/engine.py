@@ -96,7 +96,10 @@ re-designed for XLA instead of wrapped:
   ``init_params``, ``init_cache``, ``forward_with_cache`` and
   ``attn_rows_read``; ``read_counters`` where its programs count on the
   device; ``chunk_terms`` where a call pays otherwise than by every row
-  meeting every weight. Nothing below knows what a cache holds: it is a
+  meeting every weight; ``read_choices`` where the configuration's
+  programs leave in the cache what a call chose (experts, selected rows:
+  ``config.says_choices``), which the engine hands on under the same
+  name (None elsewhere). Nothing below knows what a cache holds: it is a
   pytree the programs take and return. Beside the signature the engine
   and the programs share one thing, ``decoder.idle_position``: the
   length a lane that is nobody's is dispatched at. A family whose cache
@@ -468,6 +471,8 @@ class LlamaEngine:
         # what caches that a failed call took along had counted when
         # last read (``abort_all``): the totals only ever rise
         self._counted_before: Dict[str, int] = {}
+        self.read_choices = (model.read_choices if getattr(
+            config, "says_choices", False) else None)
         if hasattr(model, "read_counters"):
             # through a weak reference: the engine, its weights and its
             # caches go when their last holder lets go, with no cycle

@@ -1,15 +1,26 @@
-"""Decoders with latent attention, sandwich norms and a shared expert
-beside routed ones of which this chip holds its share (openPangu-Ultra-
-MoE, and by its key names the DeepSeek-V3 convention). Served through
+"""Decoders with latent attention and a shared expert beside routed
+ones of which this chip holds its share: the DeepSeek-V3 key convention,
+in the two configurations that run through here. **openPangu-Ultra-MoE**
+(``LatentMoEConfig``'s defaults) has sandwich norms and attends to every
+row at or before a row's own; **DeepSeek-V3.2-Exp** (``sandwich_norm``
+off, ``index_*``, ``n_groups`` / ``groups_kept``, ``selection_bias``,
+``yarn`` / ``score_mscale``) is pre-norm, has an indexer beside every
+layer's attention and attends to the ``index_topk`` rows it scores
+highest, chooses its experts by score + bias among its best groups, and
+turns its rotary part by a YaRN table. The fields are architecture, not
+switches: absent or zero, the programs are openPangu's. Served through
 ``llm/_internal/engine.py`` as the other families are; not trained.
 
-**A layer** (``N`` an RMS norm with its own gain): ``x = x + N2(attn(
-N1(x)))``, then ``x = x + N4(mlp(N3(x)))``: four gains a layer. ``mlp``
+**A layer** (``N`` an RMS norm with its own gain). With sandwich norms
+(openPangu): ``x = x + N2(attn(N1(x)))``, then ``x = x + N4(mlp(
+N3(x)))``: four gains a layer. Without (DeepSeek-V3.2-Exp): ``x = x +
+attn(N1(x))``, ``x = x + mlp(N2(x))``: two. ``mlp``
 is a dense SwiGLU in the ``n_dense_layers`` leading layers and
 ``ops/moe.py``'s dropless expert layer after them (sigmoid scores over
-all ``n_experts``, the ``experts_per_token`` largest renormalised and
-scaled, the experts in ``held_experts`` computed and the others' part
-left out, one shared expert added once).
+all ``n_experts``, the ``experts_per_token`` largest, or where the
+configuration has them the largest score + bias among the kept groups,
+renormalised and scaled, the experts in ``held_experts`` computed and
+the others' part left out, one shared expert added once).
 
 **Latent attention.** ``cq = Nq(h Wdq)``; a head's query is ``[q_nope |
 q_rope] = cq Wuq``; ``[ckv | k_rope] = h Wdkv``, ``c = Nkv(ckv)``;
@@ -62,6 +73,33 @@ Above some 170 rows a call the expanded form is the cheaper (it pays
 ``2 x kv_rank x heads x (nope + v)`` FLOPs once a latent row; the
 absorbed form pays the wider key and value at every pair).
 
+**The indexer** (``index_topk`` > 0; ``_forward_indexed``). Beside the
+latent row the cache keeps every row's **index key** ``kI = LN(h WkI)``
+(``index_key`` (L, B, max_seq, index_dim)). A query row's index queries
+``qI = cq WqI`` (``index_heads`` of them) and weights ``w = h Ww /
+sqrt(index_heads x index_dim)`` score every row at or before its own,
+``I(t, s) = sum_j w(t, j) relu(qI(t, j) . kI(s))`` (the first
+``rope_dim`` dimensions of ``qI`` and ``kI`` turned by the layer's
+table), and the row attends to the ``index_topk`` rows of the largest
+score (all of them while there are no more; of equal scores the lower
+index first) and to no other: ``ops/index_select.py`` has the score (a
+Pallas kernel for a chunk, ``ops/pallas_index_score.py``) and the exact
+selection. The two forms stay two. A chunk's rows each have their own
+set, so the selection is a mask that the expanded form's kernel takes
+beside the cache (``attend_expanded``'s ``allowed``): it still expands
+every row up to the chunk's last once for all the chunk's rows and
+masks what a row did not select. A decode lane's set is a list of rows:
+they are gathered, ``index_topk`` x (kv_rank + rope) values a lane, and
+attended to in the absorbed form (``attend_rows``), so the lane reads
+its index keys and those rows and not every latent row up to its last.
+Scopes: ``attn_index`` > ``index_q``, ``index_k``, ``index_score``,
+``index_select``; the selected attention under ``attn_latent_prefill``
+/ ``attn_latent_decode``; the index keys' write under ``kv_write``.
+Such a model's programs also say what they chose (``read_choices``:
+each layer's set at each of the call's rows as bits, each routed
+layer's experts), so that a comparison can hold a float32 reference to
+the choices bf16 made and judge the choices apart.
+
 **Parameters are stacked by kind of layer**: ``dense`` and ``routed``
 each hold their layers' attention and norms, and the MLP of their own
 shape; ``decoder.scan_layers`` runs over the one and then over the
@@ -83,7 +121,14 @@ and ``attn_blocks_decode`` (the rows of the blocks the decode form took
 the call's lanes through, ``absorbed_blocks``: on the kernel's path
 every live lane's own blocks, on the loop's the longest's for every
 lane; ``attn_rows_decode`` over it is the share of the fetched rows that
-some lane asked for), each summed over layers and calls.
+some lane asked for), each summed over layers and calls. In an indexed
+model the four count what was attended after the selection
+(``attn_pairs_prefill`` and ``attn_rows_decode`` the selected pairs and
+rows, ``attn_blocks_decode`` the ``index_topk`` places a live lane's
+gather fetches), and two more words (``INDEX_COUNTERS``) count the
+selection itself: ``attn_rows_indexed`` (the rows scored: a live row x
+the rows at or before it) and ``attn_rows_selected`` (the rows kept for
+them: the bits each row said, counted).
 """
 
 from __future__ import annotations
@@ -97,13 +142,15 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import moe
+from ray_tpu.ops import index_select, moe
 
 from . import decoder
-from .decoder import rms_norm
+from .decoder import layer_norm, rms_norm
 from .llama import LlamaConfig, apply_rope, make_dense_init
+from .rope import YarnRope, yarn_inv_freq
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,6 +173,28 @@ class LatentMoEConfig(LlamaConfig):
     shared_dim: int = 2048      # the one shared expert's width
     norm_topk_prob: bool = True
     routed_scale: float = 2.5
+    # What follows is architecture, and absent or zero is openPangu's.
+    # ``sandwich_norm``: a norm behind each sublayer as well as before
+    # it (four gains a layer); without, two
+    sandwich_norm: bool = True
+    # the indexer beside every layer's attention: ``index_heads`` index
+    # queries of ``index_dim`` a row, one index key a row, and a row
+    # attends to the ``index_topk`` rows of largest index score; 0: every
+    # row at or before its own
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    # the router's experts in groups of which a token keeps some
+    # (``ops/moe.py`` ``MoEConfig``), and a bias a routed layer adds to
+    # the scores for the choice alone (``router_bias``)
+    n_groups: int = 1
+    groups_kept: int = 1
+    selection_bias: bool = False
+    # the rotary part's table where it is YaRN's (its own
+    # ``attention_factor`` on cos and sin), and the factor ``m`` whose
+    # square multiplies the attention scores' scale
+    yarn: Optional[YarnRope] = None
+    score_mscale: float = 1.0
 
     model_module = "ray_tpu.models.latent_moe"
 
@@ -135,6 +204,11 @@ class LatentMoEConfig(LlamaConfig):
                 f"{self.n_dense_layers} dense layers of {self.n_layers}")
         if self.rope_dim % 2:
             raise ValueError("the rotary part turns pairs of dimensions")
+        if self.index_topk and not (
+                self.index_heads and self.index_dim >= self.rope_dim):
+            raise ValueError(
+                f"an indexer of {self.index_heads} heads of "
+                f"{self.index_dim}, turned in its first {self.rope_dim}")
 
     @property
     def n_routed_layers(self) -> int:
@@ -149,12 +223,25 @@ class LatentMoEConfig(LlamaConfig):
         return self.kv_rank + self.rope_dim
 
     @property
+    def says_choices(self) -> bool:
+        """Whether the programs leave in the cache what a call chose
+        (``read_choices``): a model whose every row selects does."""
+        return bool(self.index_topk)
+
+    @property
+    def score_scale(self) -> float:
+        """What a score of row t on row s is multiplied by."""
+        return self.score_mscale ** 2 / math.sqrt(self.nope_dim
+                                                  + self.rope_dim)
+
+    @property
     def moe(self) -> moe.MoEConfig:
         return moe.MoEConfig(
             d_model=self.dim, d_ff=self.expert_dim, n_experts=self.n_experts,
             k=self.experts_per_token, norm_topk_prob=self.norm_topk_prob,
             scoring="sigmoid", routed_scale=self.routed_scale,
-            held=self.held_experts)
+            held=self.held_experts, n_groups=self.n_groups,
+            groups_kept=self.groups_kept)
 
 
 LATENT_MOE_TINY = LatentMoEConfig(
@@ -164,11 +251,22 @@ LATENT_MOE_TINY = LatentMoEConfig(
     n_dense_layers=1, n_experts=16, experts_per_token=4, expert_dim=32,
     held_experts=(4, 5, 6, 7), shared_dim=32,
 )
+# the same with what an indexed model adds: two norms a layer, an indexer
+# of 4 heads of 16 that keeps 16 rows, 4 groups of which 2 are kept, a
+# selection bias, a YaRN table
+LATENT_MOE_INDEXED_TINY = dataclasses.replace(
+    LATENT_MOE_TINY, sandwich_norm=False, index_heads=4, index_dim=16,
+    index_topk=16, n_groups=4, groups_kept=2, selection_bias=True,
+    yarn=YarnRope(theta=10000.0, factor=4.0, original_max_position=64,
+                  attention_factor=1.0),
+    score_mscale=0.1 * math.log(4.0) + 1.0)
 
 SHARED_WEIGHTS = ("shared_gate", "shared_up", "shared_down")
 COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
             "moe_held_slabs", "moe_assignments_all", "attn_pairs_prefill",
             "attn_rows_prefill", "attn_rows_decode", "attn_blocks_decode")
+# and, behind them in an indexed model's words, of the selection
+INDEX_COUNTERS = ("attn_rows_indexed", "attn_rows_selected")
 # (the most a call counts at once, 2048 rows x 16 384 x 5 layers, is
 # 2^27: under the carry of ``decoder``'s counter words)
 # What the ``jax.numpy`` loops take at a time (since PR 50 and PR 62
@@ -208,6 +306,18 @@ def _attn_shapes(c: LatentMoEConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]:
     }
 
 
+def _indexer_shapes(c: LatentMoEConfig):
+    """name -> (a layer's shape, fan-in) of the indexer's projections;
+    none where the model has no indexer."""
+    if not c.index_topk:
+        return {}
+    return {
+        "wq_index": ((c.q_rank, c.index_heads, c.index_dim), c.q_rank),
+        "wk_index": ((c.dim, c.index_dim), c.dim),
+        "w_index": ((c.dim, c.index_heads), c.dim),
+    }
+
+
 def chunk_terms(config: LatentMoEConfig, max_seq: int) -> Dict[str, float]:
     """What ``engine.derived_prefill_chunk`` is told beside the chip,
     from the configuration and the cache's length alone. Every row
@@ -236,7 +346,8 @@ def chunk_terms(config: LatentMoEConfig, max_seq: int) -> Dict[str, float]:
     meets are past their own ridge by then and the expansion is small
     beside rows so many."""
     c = config
-    attention = sum(math.prod(shape) for shape, _ in _attn_shapes(c).values())
+    attention = sum(math.prod(shape) for shape, _ in (
+        *_attn_shapes(c).values(), *_indexer_shapes(c).values()))
     every_row = (c.n_layers * attention
                  + c.n_dense_layers * 3 * c.dim * c.ffn_dim
                  + c.n_routed_layers * 3 * c.dim * c.shared_dim
@@ -251,8 +362,10 @@ def chunk_terms(config: LatentMoEConfig, max_seq: int) -> Dict[str, float]:
 
 
 # -- parameters --------------------------------------------------------
+BIAS_STD = 0.02     # a seeded selection bias's (sigmoid scores lie in 0..1)
 NORMS = {"attn_norm": "dim", "attn_post_norm": "dim", "mlp_norm": "dim",
          "mlp_post_norm": "dim", "q_norm": "q_rank", "kv_norm": "kv_rank"}
+POST_NORMS = ("attn_post_norm", "mlp_post_norm")    # a sandwich's alone
 
 
 def param_specs(config: LatentMoEConfig) -> Dict[str, Any]:
@@ -264,16 +377,30 @@ def param_specs(config: LatentMoEConfig) -> Dict[str, Any]:
 
 def init_params(rng: jax.Array, config: LatentMoEConfig) -> Dict[str, Any]:
     """``dense`` and ``routed``: each kind's layers stacked, in
-    ``param_dtype``; the router float32; every norm's gain 1."""
+    ``param_dtype``; the router float32; every norm's gain 1. An
+    indexer's three projections and its key's LayerNorm (gain 1, bias
+    0) beside each layer's attention; a selection bias (``router_bias``,
+    float32, drawn at ``BIAS_STD``: small, and not 0, so that a choice
+    by score + bias differs from one by score at some rows) beside each
+    router."""
     c = config
     dense = make_dense_init(c)
     keys = iter(jax.random.split(rng, 32))
+    # what openPangu has not is drawn from keys of its own, so that its
+    # parameters are what they were
+    more = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
 
     def common(L):
         out = {name: jnp.ones((L, getattr(c, width)), c.param_dtype)
-               for name, width in NORMS.items()}
+               for name, width in NORMS.items()
+               if c.sandwich_norm or name not in POST_NORMS}
         for name, (shape, fan_in) in _attn_shapes(c).items():
             out[name] = dense(next(keys), (L, *shape), fan_in)
+        for name, (shape, fan_in) in _indexer_shapes(c).items():
+            out[name] = dense(next(more), (L, *shape), fan_in)
+        if c.index_topk:
+            out["k_index_norm"] = jnp.ones((L, c.index_dim), c.param_dtype)
+            out["k_index_bias"] = jnp.zeros((L, c.index_dim), c.param_dtype)
         return out
 
     Ld, Lr, D, E = c.n_dense_layers, c.n_routed_layers, c.dim, c.n_held
@@ -301,25 +428,46 @@ def init_params(rng: jax.Array, config: LatentMoEConfig) -> Dict[str, Any]:
         shared_gate=dense(next(keys), (Lr, D, c.shared_dim), D),
         shared_up=dense(next(keys), (Lr, D, c.shared_dim), D),
         shared_down=dense(next(keys), (Lr, c.shared_dim, D), c.shared_dim))
+    if c.selection_bias:
+        params["routed"]["router_bias"] = BIAS_STD * jax.random.normal(
+            next(more), (Lr, c.n_experts), jnp.float32)
     return params
 
 
 # -- the sublayers -----------------------------------------------------
 def rope_cos_sin(c: LatentMoEConfig, pos: jax.Array):
-    """cos and sin (..., rope_dim / 2) float32 at the positions ``pos``."""
-    inv_freq = c.rope_theta ** -(
-        np.arange(0, c.rope_dim, 2, dtype=np.float64) / c.rope_dim)
+    """cos and sin (..., rope_dim / 2) float32 at the positions ``pos``:
+    the default table at ``rope_theta``, or the configuration's YaRN
+    table, scaled by its attention factor."""
+    if c.yarn is None:
+        inv_freq = c.rope_theta ** -(
+            np.arange(0, c.rope_dim, 2, dtype=np.float64) / c.rope_dim)
+        scale = 1.0
+    else:
+        inv_freq = yarn_inv_freq(c.yarn, c.rope_dim)
+        scale = c.yarn.cos_sin_scale
     freqs = pos[..., None].astype(jnp.float32) * jnp.asarray(
         inv_freq, jnp.float32)
-    return jnp.cos(freqs), jnp.sin(freqs)
+    if scale == 1.0:
+        return jnp.cos(freqs), jnp.sin(freqs)
+    return jnp.cos(freqs) * scale, jnp.sin(freqs) * scale
 
 
-def latent_q(c: LatentMoEConfig, h, layer, cos, sin):
-    """h (B, T, D) -> the heads' queries (q_nope (B, T, H, nope), q_rope
-    (B, T, H, rope) turned)."""
+def query_latent(c: LatentMoEConfig, h, layer):
+    """h (B, T, D) -> the normed query latent (B, T, q_rank): what the
+    heads' queries, and an indexer's, are made from."""
     with jax.named_scope("latent_q"):
-        cq = rms_norm(h @ layer["wdq"].astype(c.dtype), layer["q_norm"],
-                      c.norm_eps)
+        return rms_norm(h @ layer["wdq"].astype(c.dtype), layer["q_norm"],
+                        c.norm_eps)
+
+
+def latent_q(c: LatentMoEConfig, h, layer, cos, sin, cq=None):
+    """h (B, T, D) -> the heads' queries (q_nope (B, T, H, nope), q_rope
+    (B, T, H, rope) turned); ``cq``: ``query_latent``'s, where the
+    caller has made it already."""
+    if cq is None:
+        cq = query_latent(c, h, layer)
+    with jax.named_scope("latent_q"):
         q = jnp.einsum("btr,rhk->bthk", cq, layer["wuq"].astype(c.dtype))
         return q[..., :c.nope_dim], apply_rope(q[..., c.nope_dim:], cos, sin)
 
@@ -361,16 +509,19 @@ def _stack_reader(stack, layer, first, B: int):
 
 
 def attend_expanded(c: LatentMoEConfig, q_nope, q_rope, stack, index, first,
-                    S: int, start_pos, layer):
+                    S: int, start_pos, layer, allowed=None):
     """The prefill form. q_nope (B, T, H, nope), q_rope (B, T, H, rope),
     sequence b's T rows at the positions ``start_pos[b] ..``; ``stack``
     the cache's two stacks (latent (L, B', max_seq, kv_rank), rope_key
     (L, B', rope, max_seq)) of which layer ``index``, sequences ``first
     .. first + B`` and the rows ``[0, S)`` are read, the call's own among
     them; ``layer`` holds ``wuk`` and ``wuv`` -> (B, T, H, v) in the
-    compute type. Which of the two implementations runs follows from
-    the shapes alone: ``ops/pallas_latent_attention.py``'s kernel where
-    it can tile them, ``attend_expanded_blockwise`` elsewhere."""
+    compute type. ``allowed`` (B, T, S) bool, where the rows were
+    selected: row t attends to the rows it marks among those at or
+    before its own position, one at least, and to no other. Which of
+    the two implementations runs follows from the shapes alone:
+    ``ops/pallas_latent_attention.py``'s kernel where it can tile them,
+    ``attend_expanded_blockwise`` elsewhere."""
     # imported beside whatever followed this module's own import
     # (``_import_kernel``); waits here for what is left of it
     from ray_tpu.ops import pallas_latent_attention as kernel
@@ -383,18 +534,19 @@ def attend_expanded(c: LatentMoEConfig, q_nope, q_rope, stack, index, first,
         by_head = q_nope.transpose(0, 2, 1, 3), q_rope.transpose(0, 2, 1, 3)
     if kernel.untileable(*by_head, *stack, wuk, wuv, S) is None:
         with jax.named_scope("attn_latent_prefill"):
+            selected = {} if allowed is None else {"allowed": allowed}
             return kernel.latent_prefill_attention(
                 *by_head, *stack, wuk, wuv, layer=index, slot=first,
                 start_pos=start_pos, rows=S,
-                scale=1.0 / math.sqrt(c.nope_dim + c.rope_dim))
+                scale=c.score_scale, **selected)
     pos = start_pos[:, None] + jnp.arange(T)[None, :]
     return attend_expanded_blockwise(
         c, q_nope, q_rope, _stack_reader(stack, index, first, B), S, pos,
-        layer)
+        layer, allowed)
 
 
 def attend_expanded_blockwise(c: LatentMoEConfig, q_nope, q_rope, read,
-                              S: int, pos, layer):
+                              S: int, pos, layer, allowed=None):
     """The prefill form in ``jax.numpy``, for the shapes the kernel
     cannot tile and as its numerical reference. q_nope (B, T, H, nope),
     q_rope (B, T, H, rope) at the positions ``pos`` (B, T); ``read(
@@ -405,15 +557,18 @@ def attend_expanded_blockwise(c: LatentMoEConfig, q_nope, q_rope, read,
     their keys and values are made from the latent rows once, the
     chunk's rows attend to them ``PREFILL_TILE`` at a time, each tile
     with a running maximum and sum of its own that carry its softmax;
-    the loop ends with the block that holds the call's last position."""
+    the loop ends with the block that holds the call's last position.
+    ``allowed`` (B, T, S) bool as ``attend_expanded`` takes it."""
     B, T, H, _ = q_nope.shape
     block = _blocks(S, PREFILL_BLOCK)
     tile = _blocks(T, PREFILL_TILE)
-    scale = 1.0 / math.sqrt(c.nope_dim + c.rope_dim)
+    scale = c.score_scale
     wuk, wuv = layer["wuk"].astype(c.dtype), layer["wuv"].astype(c.dtype)
     # a tile's queries and positions, cut out here and not once a block
     queries = [(q_nope[:, t:t + tile], q_rope[:, t:t + tile],
-                pos[:, t:t + tile, None]) for t in range(0, T, tile)]
+                pos[:, t:t + tile, None],
+                None if allowed is None else allowed[:, t:t + tile])
+               for t in range(0, T, tile)]
 
     def step(i, carry):
         with jax.named_scope("attn_latent_prefill"), \
@@ -430,13 +585,17 @@ def attend_expanded_blockwise(c: LatentMoEConfig, q_nope, q_rope, read,
             v = jnp.einsum("bsc,chk->bshk", rows, wuv)
         at = i * block + jnp.arange(block)
         out = []
-        for (q_nope_t, q_rope_t, pos_t), (m, l, acc) in zip(queries, carry):
+        for (q_nope_t, q_rope_t, pos_t, allowed_t), (m, l, acc) in zip(
+                queries, carry):
             with jax.named_scope("attn_latent_prefill"):
                 s = (jnp.einsum("bthk,bshk->bhts", q_nope_t, k_nope,
                                 preferred_element_type=jnp.float32)
                      + jnp.einsum("bthr,brs->bhts", q_rope_t, k_rope,
                                   preferred_element_type=jnp.float32)) * scale
                 seen = at[None, None, :] <= pos_t              # (B, tile, blk)
+                if allowed_t is not None:
+                    seen &= jax.lax.dynamic_slice_in_dim(
+                        allowed_t, i * block, block, axis=2)
                 s = jnp.where(seen[:, None], s, -1e30)
                 m_new = jnp.maximum(m, s.max(-1))
                 p = jnp.exp(s - m_new[..., None])
@@ -449,6 +608,8 @@ def attend_expanded_blockwise(c: LatentMoEConfig, q_nope, q_rope, read,
 
     # row 0 is seen by every query, so the first block sets every
     # maximum and a masked score weighs exp(-1e30 - m) = 0 exactly
+    # (under ``allowed``, what a row gathered before its first marked
+    # row fades by exp(-1e30 - m) = 0 when that row's score arrives)
     blocks = jnp.minimum(pos.max() // block + 1, S // block)
     first = (jnp.full((B, H, tile), -1e30, jnp.float32),
              jnp.zeros((B, H, tile), jnp.float32),
@@ -500,7 +661,7 @@ def attend_absorbed(c: LatentMoEConfig, q_nope, q_rope, stack, index, first,
             mixed = kernel.latent_decode_attention(
                 q, q_rope[:, 0], *stack, layer=index, slot=first,
                 pos=pos[:, 0], blocks=blocks,
-                scale=1.0 / math.sqrt(c.nope_dim + c.rope_dim))
+                scale=c.score_scale)
         else:
             mixed = attend_absorbed_blockwise(
                 c, q, q_rope[:, 0], _stack_reader(stack, index, first,
@@ -520,7 +681,7 @@ def attend_absorbed_blockwise(c: LatentMoEConfig, q, q_rope, read, S: int,
     lanes through the ``blocks`` leading blocks."""
     B, H, _ = q.shape
     block = _blocks(S, DECODE_BLOCK)
-    scale = 1.0 / math.sqrt(c.nope_dim + c.rope_dim)
+    scale = c.score_scale
 
     def step(i, carry):
         m, l, acc = carry
@@ -549,10 +710,60 @@ def attend_absorbed_blockwise(c: LatentMoEConfig, q, q_rope, read, S: int,
     return (acc / l[..., None]).astype(c.dtype)
 
 
+def index_qkw(c: LatentMoEConfig, h, cq, layer, cos, sin):
+    """The indexer over the normed input ``h`` (B, T, D) and the query
+    latent ``cq`` (B, T, q_rank) -> (the index queries (B, T, Hi, di),
+    the row's index key (B, T, di), the first ``rope_dim`` dimensions of
+    both turned by the layer's rotary table; the heads' weights (B, T,
+    Hi) float32)."""
+    rope = c.rope_dim
+
+    def turned(x):          # (B, T, heads, di)
+        return jnp.concatenate(
+            [apply_rope(x[..., :rope], cos, sin), x[..., rope:]], axis=-1)
+
+    with jax.named_scope("index_q"):
+        q = turned(jnp.einsum("btr,rhd->bthd", cq,
+                              layer["wq_index"].astype(c.dtype)))
+        w = (h @ layer["w_index"].astype(c.dtype)).astype(jnp.float32) * (
+            1.0 / math.sqrt(c.index_heads * c.index_dim))
+    with jax.named_scope("index_k"):
+        k = layer_norm(h @ layer["wk_index"].astype(c.dtype),
+                       layer["k_index_norm"], c.norm_eps
+                       ) + layer["k_index_bias"].astype(c.dtype)
+        k = turned(k[:, :, None])[:, :, 0]
+    return q, k, w
+
+
+def attend_rows(c: LatentMoEConfig, q_nope, q_rope, rows, k_rope, allowed,
+                layer):
+    """The absorbed form over rows handed in (a decode lane's selected
+    ones): one query row a lane, q_nope (B, 1, H, nope), q_rope (B, 1,
+    H, rope); ``rows`` (B, K, kv_rank) latent rows with their turned
+    rotary keys ``k_rope`` (B, K, rope), of which lane b attends to
+    those ``allowed`` (B, K) marks -> (B, 1, H, v). ``Wuk`` is folded
+    into the query and ``Wuv`` into the output, as ``attend_absorbed``
+    folds them; K is ``index_topk``, so the rows are scored at once."""
+    q = jnp.einsum("bhk,chk->bhc", q_nope[:, 0], layer["wuk"].astype(c.dtype))
+    score = (jnp.einsum("bhc,bkc->bhk", q, rows,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhr,bkr->bhk", q_rope[:, 0], k_rope,
+                          preferred_element_type=jnp.float32)
+             ) * c.score_scale
+    p = jax.nn.softmax(jnp.where(allowed[:, None], score, -1e30), axis=-1)
+    mixed = jnp.einsum("bhk,bkc->bhc", p.astype(c.dtype), rows,
+                       preferred_element_type=jnp.float32).astype(c.dtype)
+    return jnp.einsum("bhc,chk->bhk", mixed,
+                      layer["wuv"].astype(c.dtype))[:, None]
+
+
 def attn_out(c: LatentMoEConfig, x, attn, layer):
-    """The heads' results through ``Wo``, the post-norm, the residual."""
+    """The heads' results through ``Wo``, the post-norm where the model
+    has one, the residual."""
     with jax.named_scope("attn_out"):
         out = jnp.einsum("bthk,hkd->btd", attn, layer["wo"].astype(c.dtype))
+    if not c.sandwich_norm:
+        return x + out
     return x + rms_norm(out, layer["attn_post_norm"], c.norm_eps)
 
 
@@ -562,28 +773,37 @@ def dense_mlp(c: LatentMoEConfig, x, layer):
         gate = h @ layer["w_gate"].astype(c.dtype)
         up = h @ layer["w_up"].astype(c.dtype)
         out = (jax.nn.silu(gate) * up) @ layer["w_down"].astype(c.dtype)
+        if not c.sandwich_norm:
+            return x + out
         return x + rms_norm(out, layer["mlp_post_norm"], c.norm_eps)
 
 
 def moe_mlp(c: LatentMoEConfig, x, layer, experts, index, live=None):
-    """The expert layer between its two norms + residual -> (x, counts
+    """The expert layer between its norms + residual -> (x, counts
     int32[5]: ``ops/moe.py``'s four and every live row's assignments,
-    held or not). ``layer``: this layer's norms, router and shared
-    expert; ``experts``: every routed layer's held experts, stacked, of
-    which this layer is ``index`` (``ops/moe.py`` ``expert_ffn`` says
-    why the stack goes whole)."""
+    held or not; and, of a model that says what it chose (an indexed
+    one), the experts the router chose at every row, int32 (B, T, k),
+    else None). ``layer``: this layer's norms, router (and selection
+    bias) and shared expert; ``experts``: every routed layer's held
+    experts, stacked, of which this layer is ``index`` (``ops/moe.py``
+    ``expert_ffn`` says why the stack goes whole)."""
     with jax.named_scope("moe"):
         h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
-        out, counts = moe.moe_ffn_dropless(
-            {"router": layer["router"],
+        router = {"router": layer["router"]}
+        if c.selection_bias:
+            router["router_bias"] = layer["router_bias"]
+        out, counts, *chose = moe.moe_ffn_dropless(
+            {**router,
              **{k: layer[k].astype(c.dtype) for k in SHARED_WEIGHTS},
              **{k: w.astype(c.dtype) for k, w in experts.items()}},
-            h, c.moe, layer=index, live=live)
+            h, c.moe, layer=index, live=live, say_experts=bool(c.index_topk))
         rows = (math.prod(x.shape[:-1]) if live is None
                 else jnp.broadcast_to(live, x.shape[:-1]).sum())
         counts = jnp.concatenate([counts, jnp.reshape(
             rows * c.experts_per_token, (1,)).astype(jnp.int32)])
-        return x + rms_norm(out, layer["mlp_post_norm"], c.norm_eps), counts
+        if c.sandwich_norm:
+            out = rms_norm(out, layer["mlp_post_norm"], c.norm_eps)
+        return x + out, counts, (chose[0] if chose else None)
 
 
 # -- the cache ---------------------------------------------------------
@@ -591,27 +811,126 @@ def init_cache(config: LatentMoEConfig, batch: int, max_seq: int,
                chunk: Optional[int] = None):
     """``latent`` (L, B, max_seq, kv_rank) and ``rope_key`` (L, B,
     rope_dim, max_seq) in the compute type, a row a position;
-    ``counts``: the device words of ``COUNTERS``."""
-    del chunk
+    ``counts``: the device words of ``COUNTERS``. Of an indexed model
+    also ``index_key`` (L, B, max_seq, index_dim), every row's index
+    key; ``INDEX_COUNTERS``' words behind the others; and what the last
+    call chose at each of its rows (``read_choices``): ``said_rows`` (L,
+    R, words) uint32, a layer's selected set as bits
+    (``index_select.mask_as_bits``), ``said_experts`` (routed layers, R,
+    k) int32 and ``said_count``, how many rows that call had; R the most
+    rows a call has (``chunk``: the most a call will write)."""
     c = config
-    return {"latent": jnp.zeros((c.n_layers, batch, max_seq, c.kv_rank),
-                                c.dtype),
-            "rope_key": jnp.zeros((c.n_layers, batch, c.rope_dim, max_seq),
-                                  c.dtype),
-            "counts": decoder.counter_words(len(COUNTERS))}
+    cache = {"latent": jnp.zeros((c.n_layers, batch, max_seq, c.kv_rank),
+                                 c.dtype),
+             "rope_key": jnp.zeros((c.n_layers, batch, c.rope_dim, max_seq),
+                                   c.dtype),
+             "counts": decoder.counter_words(len(COUNTERS))}
+    if not c.index_topk:
+        return cache
+    said = max(chunk or max_seq, batch)
+    return {
+        **cache,
+        "index_key": jnp.zeros((c.n_layers, batch, max_seq, c.index_dim),
+                               c.dtype),
+        "said_rows": jnp.zeros(
+            (c.n_layers, said, index_select.said_words(max_seq)), jnp.uint32),
+        "said_experts": jnp.zeros(
+            (c.n_routed_layers, said, c.experts_per_token), jnp.int32),
+        "said_count": jnp.int32(0),
+        "counts": decoder.counter_words(len(COUNTERS) + len(INDEX_COUNTERS))}
 
 
 def attn_rows_read(config: LatentMoEConfig, cache, rows: int) -> int:
     """Cache rows a sequence one call may read for attention at the read
     window ``rows``: every layer's bound is the window (both forms stop
     at the block of a sequence's last position, which the device
-    counters see and this host-side count does not)."""
+    counters see and this host-side count does not; an indexed model's
+    indexer scores every row of the window, and a decode then attends
+    to ``index_topk`` of them, which the device counters see too)."""
     del config, cache
     return rows
 
 
-# what one cache shard's programs have counted
-read_counters = partial(decoder.read_counters, names=COUNTERS)
+# what one cache shard's programs have counted (an indexed model's words
+# are two more)
+read_counters = partial(decoder.read_counters,
+                        names=COUNTERS + INDEX_COUNTERS)
+
+
+def read_choices(cache):
+    """What the call that returned an indexed model's ``cache`` chose at
+    each of its rows (a chunk's rows, or a decode's lanes), on the host:
+    int32 (layers + 1, the call's rows, W), every entry of a row a
+    number no other entry of the row can be, so that two rows hold the
+    same numbers where the same was chosen and there alone. Entry l is
+    layer l's selected set: the words ``index_select.mask_as_bits`` lays
+    its bits into, each in two halves, ``position << 16 | sixteen bits``
+    (half j of word w at position 2 w + j). The last entry holds every
+    routed layer's experts, ``routed layer << 16 | expert``, layer after
+    layer, and -1 behind them."""
+    n = int(cache["said_count"])
+    words = np.asarray(cache["said_rows"][:, :n])
+    halves = np.stack([words & 0xFFFF, words >> 16], axis=-1).reshape(
+        *words.shape[:2], -1)
+    tag = np.arange(halves.shape[2], dtype=np.uint32) << 16
+    experts = np.asarray(cache["said_experts"][:, :n])
+    experts = (np.arange(len(experts))[:, None, None] << 16 | experts
+               ).transpose(1, 0, 2).reshape(1, n, -1)
+    if experts.shape[2] > halves.shape[2]:
+        raise ValueError("the experts of a row are more than a set's words")
+    return np.concatenate([(halves | tag).view(np.int32), np.pad(
+        experts, ((0, 0), (0, 0), (0, halves.shape[2] - experts.shape[2])),
+        constant_values=-1).astype(np.int32)])
+
+
+def _say(said, new, layer):
+    """``new`` (B, T, ...) into ``said`` (layers, R, ...) at ``layer``,
+    from row 0: the call's rows, a sequence after the other (the first R
+    of them, should there be more)."""
+    new = new.reshape(1, -1, *new.shape[2:])[:, :said.shape[1]]
+    return jax.lax.dynamic_update_slice(
+        said, new, (layer,) + (0,) * (said.ndim - 1))
+
+
+def _rows_first(x):
+    """``x`` as it is, its last axis the one that runs fastest in
+    memory. What is cut out of a cache's stack is held to that, so that
+    the stack the layer scan carries keeps the layout it came in with:
+    left to itself the compiler lays a whole stack out the way one
+    reader of a slice would like it (the rotary keys' gather) and
+    brackets every call with two transposing copies of the leaf (read
+    off the compiled programs; ``tests/aot_compile_check.py`` holds them
+    to none)."""
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def _write_index_keys(stack, new, layer, first, start_pos):
+    """``new`` (B, T, index_dim) into the stack (L, B', S, index_dim) at
+    layer ``layer``: sequence b's T rows from row ``start_pos[b]`` of
+    cache row ``first + b``, and nothing else."""
+    new = _rows_first(new.astype(stack.dtype))
+    for b in range(new.shape[0]):
+        stack = jax.lax.dynamic_update_slice(
+            stack, new[None, b:b + 1], (layer, first + b, start_pos[b], 0))
+    return stack
+
+
+def _gather_rows(stack, layer, first, rows, window: int):
+    """The rows ``rows`` (B, K) of layer ``layer``, sequences ``first ..
+    first + B`` out of the two stacks -> (latent rows (B, K, kv_rank),
+    rotary keys (B, K, rope)): the latent rows straight out of the
+    stack, the rotary keys (the rows last, as the expanded form's kernel
+    reads them) out of the lanes' read window."""
+    latents, keys = stack
+    _, lanes, S, rank = latents.shape
+    B = rows.shape[0]
+    at = ((layer * lanes + first + jnp.arange(B)) * S)[:, None] + rows
+    picked = jnp.take(latents.reshape(-1, rank), at, axis=0)
+    turned = _rows_first(jax.lax.dynamic_slice(
+        keys, (layer, first, 0, 0), (1, B, keys.shape[2], window))[0])
+    return picked, jnp.take_along_axis(
+        turned, rows[:, None, :], axis=2).swapaxes(1, 2)
 
 
 def _write_rows(stack, new, layer, first, start_pos):
@@ -629,6 +948,17 @@ def _write_rows(stack, new, layer, first, start_pos):
         keys = jax.lax.dynamic_update_slice(
             keys, turned[None, b:b + 1], (layer, first + b, 0, start_pos[b]))
     return latents, keys
+
+
+def _head(params, x, c: LatentMoEConfig, logits_at):
+    """The final norm and the head over the rows whose logits are kept
+    -> float32 logits."""
+    with jax.named_scope("head"):
+        # accumulated in float32, where the other families round the
+        # product to the compute type and widen (ROADMAP Queue 3 item 1)
+        x = decoder.final_rows(params, x, c, logits_at)
+        return jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(c.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def forward_with_cache(
@@ -653,6 +983,9 @@ def forward_with_cache(
     (``models/decoder.py``); what the call counts goes into the first
     one's words."""
     c = config
+    if c.index_topk:
+        return _forward_indexed(params, tokens, cache, start_pos, c,
+                                slot=slot, logits_at=logits_at, rows=rows)
     caches, back = decoder.caches_of(cache)
     call = decoder.Call(tokens, start_pos, caches[0]["latent"].shape[2],
                         slot=slot, logits_at=logits_at, rows=rows,
@@ -706,7 +1039,7 @@ def forward_with_cache(
 
     def routed_step(x, shards, layer, i):
         x, shards = attention(x, shards, layer, c.n_dense_layers + i)
-        x, counted = moe_mlp(c, x, layer, experts, i, live)
+        x, counted, _ = moe_mlp(c, x, layer, experts, i, live)
         return x, shards, counted
 
     x, shards, _ = decoder.scan_layers(
@@ -717,20 +1050,145 @@ def forward_with_cache(
         routed_step, x, shards, scanned, 5)
     with jax.named_scope("layers"):     # counted beside the scans
         counted = jnp.concatenate([counted, attended * c.n_layers])
-    with jax.named_scope("head"):
-        # accumulated in float32, where the other families round the
-        # product to the compute type and widen (ROADMAP Queue 3 item 1)
-        x = decoder.final_rows(params, x, c, logits_at)
-        logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(c.dtype),
-                            preferred_element_type=jnp.float32)
+    logits = _head(params, x, c, logits_at)
     return logits, back(tuple(
         {"latent": latents, "rope_key": keys, "counts": words}
         for (latents, keys), words in zip(
             shards, decoder.folded(caches, counted))))
 
 
+def _forward_indexed(params, tokens, cache, start_pos, c: LatentMoEConfig,
+                     *, slot, logits_at, rows):
+    """``forward_with_cache`` of a model with an indexer. Every layer
+    scores the read window's index keys for each of the call's rows
+    (``attn_index`` > ``index_q``, ``index_k``, ``index_score``), selects
+    each row's ``index_topk`` (``index_select``) and attends to those
+    alone: a chunk in the expanded form under the selection's mask
+    (``attn_latent_prefill``), a decode lane in the absorbed form over
+    its rows gathered (``attn_latent_decode``). What each layer selected
+    and each routed layer's router chose is left in the cache
+    (``read_choices``)."""
+    caches, back = decoder.caches_of(cache)
+    call = decoder.Call(tokens, start_pos, caches[0]["latent"].shape[2],
+                        slot=slot, logits_at=logits_at, rows=rows,
+                        shards=len(caches))
+    T, pos, first, window = call.T, call.pos, call.first, call.window
+    x = decoder.embed(params, tokens, c)
+    cos, sin = rope_cos_sin(c, pos)
+    live = call.live()
+
+    def attend(part, state, layer, i, q_nope, q_rope, new, q_index, k_index,
+               w_index):
+        """-> ((attn, each sequence's live rows' (rows handed to the
+        selection, rows it kept) int32 (B, 2)), the shard's stacks with
+        layer i's new rows and what the layer selected)."""
+        latents, index_keys, said = state[:2], state[2], state[3]
+        B = q_nope.shape[0]
+        with jax.named_scope("kv_write"):
+            latents = _write_rows(latents, new, i, first, part.start_pos)
+            index_keys = _write_index_keys(index_keys, k_index, i, first,
+                                           part.start_pos)
+        with jax.named_scope("attn_index"), jax.named_scope("index_score"):
+            scores = index_select.index_scores(
+                q_index, w_index, _rows_first(jax.lax.dynamic_slice(
+                    index_keys, (i, first, 0, 0),
+                    (1, B, window, c.index_dim))[0]), part.start_pos)
+        before = jnp.arange(window)[None, None, :] <= part.pos[:, :, None]
+        if T > 1:
+            with jax.named_scope("attn_index"), \
+                    jax.named_scope("index_select"):
+                allowed = index_select.select_mask(scores, before,
+                                                   c.index_topk)
+                bits = index_select.mask_as_bits(allowed, said.shape[2])
+            attn = attend_expanded(c, q_nope, q_rope, latents, i, first,
+                                   window, part.start_pos, layer, allowed)
+        else:
+            with jax.named_scope("attn_index"), \
+                    jax.named_scope("index_select"):
+                chosen_rows, chosen = index_select.select_rows(
+                    scores[:, 0], before[:, 0], c.index_topk)
+                bits = index_select.rows_as_bits(chosen_rows, chosen,
+                                                 said.shape[2])[:, None]
+            with jax.named_scope("attn_latent_decode"):
+                with jax.named_scope("kv_slice"):
+                    picked = _gather_rows(latents, i, first, chosen_rows,
+                                          window)
+                attn = attend_rows(c, q_nope, q_rope, *picked, chosen, layer)
+        with jax.named_scope("attn_index"), jax.named_scope("index_select"):
+            mine = part.live()
+            counted = jnp.stack([
+                jnp.where(mine[..., None], before, False).sum((1, 2)),
+                jnp.where(mine, jax.lax.population_count(bits).sum(-1), 0
+                          ).sum(1)], axis=1).astype(jnp.int32)
+            said = _say(said, bits, i)
+        return (attn, counted), (*latents, index_keys, said, state[4])
+
+    def attention(x, shards, layer, i):
+        """-> (x, the shards' states with layer i's new rows, the call's
+        live rows' (rows handed to a selection, rows it kept) int32
+        (2,))."""
+        with jax.named_scope("attn"):
+            h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+            cq = query_latent(c, h, layer)
+            q_nope, q_rope = latent_q(c, h, layer, cos, sin, cq)
+            new = latent_kv(c, h, layer, cos, sin)
+            with jax.named_scope("attn_index"):
+                index = index_qkw(c, h, cq, layer, cos, sin)
+            (attn, counted), shards = call.by_shard(
+                lambda part, state, *rows: attend(part, state, layer, i,
+                                                  *rows),
+                shards, q_nope, q_rope, new, *index)
+            return attn_out(c, x, attn, layer), shards, counted.sum(0)
+
+    def dense_step(x, shards, layer, i):
+        x, shards, selected = attention(x, shards, layer, i)
+        return dense_mlp(c, x, layer), shards, selected
+
+    scanned, experts = moe.split_experts(params["routed"])
+
+    def routed_step(x, shards, layer, i):
+        x, shards, selected = attention(x, shards, layer,
+                                        c.n_dense_layers + i)
+        x, counted, chose = moe_mlp(c, x, layer, experts, i, live)
+        with jax.named_scope("moe"), jax.named_scope("moe_router"):
+            _, shards = call.by_shard(
+                lambda part, state, mine: (
+                    mine[:, :0], (*state[:4], _say(state[4], mine, i))),
+                shards, chose)
+        return x, shards, jnp.concatenate([counted, selected])
+
+    x, shards, selected = decoder.scan_layers(
+        dense_step, x,
+        tuple((each["latent"], each["rope_key"], each["index_key"],
+               each["said_rows"], each["said_experts"]) for each in caches),
+        params["dense"], 2)
+    x, shards, counted = decoder.scan_layers(
+        routed_step, x, shards, scanned, 7)
+    with jax.named_scope("layers"):     # counted beside the scans
+        counted, selected = counted[:5], selected + counted[5:]
+        zero = jnp.int32(0)
+        if T == 1:
+            # the rows a lane's gather fetches: index_topk places, of
+            # which a lane of fewer rows fills its own
+            fetched = live.sum() * min(c.index_topk, window) * c.n_layers
+            attended = [zero, zero, selected[1], fetched]
+        else:
+            expanded = jnp.where(live, pos + 1, 0).max(axis=1).sum()
+            attended = [selected[1], expanded * c.n_layers, zero, zero]
+        counted = jnp.concatenate([
+            counted, jnp.stack([*attended, *selected]).astype(jnp.int32)])
+    logits = _head(params, x, c, logits_at)
+    n_said = min(call.B // len(caches) * T, caches[0]["said_rows"].shape[1])
+    return logits, back(tuple(
+        {"latent": state[0], "rope_key": state[1], "index_key": state[2],
+         "said_rows": state[3], "said_experts": state[4],
+         "said_count": jnp.int32(n_said), "counts": words}
+        for state, words in zip(shards, decoder.folded(caches, counted))))
+
+
 def _import_kernel():
     from ray_tpu.ops import pallas_latent_attention  # noqa: F401
+    from ray_tpu.ops import pallas_index_score  # noqa: F401
 
 
 # Pallas takes 1.2 s to import on a replica's host, a chunk or decode

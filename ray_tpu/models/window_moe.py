@@ -74,23 +74,11 @@ from .llama import (
     write_rows,
 )
 from .llama import param_specs as dense_param_specs
+from .rope import YarnRope, yarn_inv_freq  # noqa: F401  (this family's names for them)
 
 SLIDING, FULL = "sliding", "full"
 # a layer's shared expert (or several side by side), where it has one
 SHARED_WEIGHTS = ("shared_gate", "shared_up", "shared_down")
-
-
-@dataclasses.dataclass(frozen=True)
-class YarnRope:
-    """A YaRN rotary table from the six numbers a configuration gives.
-    ``attention_factor`` None is 0.1 ln(factor) + 1."""
-
-    theta: float
-    factor: float
-    original_max_position: int
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,29 +147,6 @@ def chunk_terms(config: WindowMoEConfig, max_seq: int) -> Dict[str, float]:
 
 
 # -- rotary tables -----------------------------------------------------
-def yarn_inv_freq(rope: YarnRope, head_dim: int) -> np.ndarray:
-    """The blended inverse frequencies: ``theta^(-2i/d)`` where a
-    dimension turns more than ``beta_fast`` times over the original
-    context, the same over ``factor`` where it turns fewer than
-    ``beta_slow`` times, a linear ramp over the dimensions between the
-    two correction dims (rounded down and up to whole dimensions)."""
-    half = head_dim // 2
-    extrapolation = rope.theta ** -(np.arange(half, dtype=np.float64) / half)
-    interpolation = extrapolation / rope.factor
-
-    def correction_dim(rotations):
-        return (head_dim * math.log(rope.original_max_position
-                                    / (rotations * 2 * math.pi))
-                / (2 * math.log(rope.theta)))
-
-    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
-    high = min(math.ceil(correction_dim(rope.beta_slow)), head_dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
-    return interpolation * ramp + extrapolation * (1.0 - ramp)
-
-
 def rope_cos_sin(config: WindowMoEConfig, kind: str, pos: jax.Array):
     """cos and sin (..., hd/2) float32 at the positions ``pos`` for a
     layer of ``kind``; a YaRN table's are scaled by its attention
@@ -194,9 +159,7 @@ def rope_cos_sin(config: WindowMoEConfig, kind: str, pos: jax.Array):
         scale = 1.0
     else:
         inv_freq = yarn_inv_freq(rope, hd)
-        scale = rope.attention_factor
-        if scale is None:
-            scale = 0.1 * math.log(rope.factor) + 1.0
+        scale = rope.cos_sin_scale
     freqs = pos[..., None].astype(jnp.float32) * jnp.asarray(
         inv_freq, jnp.float32)
     return jnp.cos(freqs) * scale, jnp.sin(freqs) * scale

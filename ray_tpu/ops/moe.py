@@ -145,10 +145,25 @@ class MoEConfig:
     # added: 1 / n where the parameters stack n shared experts along the
     # width and the layer adds their mean
     shared_scale: float = 1.0
+    # the dropless layer's alone, and architecture: the router's experts
+    # stand in ``n_groups`` equal groups, a group's score is the sum of
+    # its two largest selection scores, and a token's experts are chosen
+    # among its ``groups_kept`` best groups. 1 and 1: no groups
+    n_groups: int = 1
+    groups_kept: int = 1
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {self.scoring!r}")
+        if (self.n_experts % self.n_groups
+                or not 1 <= self.groups_kept <= self.n_groups
+                or (self.n_groups > 1 and (
+                    self.n_experts // self.n_groups < 2
+                    or self.groups_kept * (self.n_experts // self.n_groups)
+                    < self.k))):
+            raise ValueError(
+                f"{self.n_experts} experts in {self.n_groups} groups of "
+                f"which {self.groups_kept} are kept, {self.k} a token")
         if self.held is not None and (
                 len(set(self.held)) != len(self.held)
                 or not all(0 <= e < self.n_experts for e in self.held)):
@@ -209,15 +224,35 @@ def moe_ffn(
 # moe_dispatch, moe_experts, moe_combine.
 # ---------------------------------------------------------------------
 
-def route_top_k(x: jax.Array, router: jax.Array, config: MoEConfig):
+def route_top_k(x: jax.Array, router: jax.Array, config: MoEConfig,
+                bias: Optional[jax.Array] = None):
     """x (T, D), router (D, E) -> (weights (T, k) float32, experts
     (T, k) int32): the scores of all experts in float32 (a softmax over
     them, or each one's sigmoid), the k largest, renormalised to sum to
-    1 where the configuration says so, then scaled."""
+    1 where the configuration says so, then scaled. ``bias`` (E,)
+    float32, where the layer has a selection bias: the choice is made on
+    ``score + bias`` and the weights are the scores alone. Where the
+    configuration has groups, a group is scored by the sum of its two
+    largest selection scores, the ``groups_kept`` best groups are kept
+    (of equal groups the lower first) and the k experts are the largest
+    among them."""
     logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
     scores = (jax.nn.sigmoid(logits) if config.scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    weights, experts = jax.lax.top_k(scores, config.k)
+    if bias is None and config.n_groups == 1:
+        weights, experts = jax.lax.top_k(scores, config.k)
+    else:
+        select = scores if bias is None else scores + bias.astype(jnp.float32)
+        if config.n_groups > 1:
+            by_group = select.reshape(-1, config.n_groups,
+                                      config.n_experts // config.n_groups)
+            of_group = jax.lax.top_k(by_group, 2)[0].sum(-1)
+            _, kept = jax.lax.top_k(of_group, config.groups_kept)
+            is_kept = (kept[:, :, None] == jnp.arange(config.n_groups)).any(1)
+            select = jnp.where(is_kept[:, :, None], by_group,
+                               -jnp.inf).reshape(select.shape)
+        _, experts = jax.lax.top_k(select, config.k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if config.norm_topk_prob:
         weights = weights / weights.sum(-1, keepdims=True)
     if config.routed_scale != 1.0:
@@ -413,7 +448,8 @@ def _held_slabs(params: dict, x: jax.Array, weights, order, group_sizes,
 
 
 def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
-                   config: MoEConfig, layer=None, sum_over=None):
+                   config: MoEConfig, layer=None, sum_over=None,
+                   say_experts: bool = False):
     """x (T, D), live (T,) bool -> (out (T, D), counts int32[4]: the
     live rows' assignments to experts held here, experts held here with
     a live row or more, experts held here, passes over a held share's
@@ -426,12 +462,15 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
     and, where a share of the experts is held and the call is past the
     first, only the assignments to held experts, a slab at a time
     (``_held_slabs``): the rest of T * k weighs 0 and goes through no
-    pass at all."""
+    pass at all. ``say_experts``: a third result, the experts the router
+    chose for every row, held or not: its own ids, int32 (T, k)."""
     T, D = x.shape
     k = config.k
     E = config.n_experts if config.held is None else len(config.held)
     with jax.named_scope("moe_router"):
-        weights, experts = route_top_k(x, params["router"], config)
+        weights, experts = route_top_k(x, params["router"], config,
+                                       params.get("router_bias"))
+        chose = experts
         if config.held is not None:
             # an assignment's place among the experts held here; one to
             # an absent expert gets the place past them, weighs 0, sorts
@@ -501,17 +540,18 @@ def _dropless_rows(params: dict, x: jax.Array, live: jax.Array,
         if sum_over:
             # every shard of the rows visits its own experts
             counts = jax.lax.psum(counts, sum_over)
-    return out, counts
+    return (out, counts, chose) if say_experts else (out, counts)
 
 
 def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
-                     layer=None, live=None):
+                     layer=None, live=None, say_experts: bool = False):
     """Routed SwiGLU expert FFN with no capacity: every token goes to
     its ``config.k`` experts. x (..., D) -> (out (..., D), counts): the
     int32 vector (assignments, experts touched, experts held) of this
     call, for the engine's ``moe_*`` counters. ``params``: ``router``
-    (D, ``config.n_experts``), ``w_gate`` / ``w_up`` (E, D, F) and
-    ``w_down`` (E, F, D) of the E experts held (``config.held``; all of
+    (D, ``config.n_experts``) (and ``router_bias`` (``config.n_experts``,),
+    where the layer chooses its experts by score + bias), ``w_gate`` /
+    ``w_up`` (E, D, F) and ``w_down`` (E, F, D) of the E experts held (``config.held``; all of
     them where it is None: an assignment to an expert that is not held
     adds nothing and is not counted), and, where the layer has a shared
     expert, ``shared_gate`` / ``shared_up`` (D, Fs) and ``shared_down``
@@ -519,7 +559,10 @@ def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
     weights are a model's stacked ones and this is the layer among them
     (``expert_ffn``); the router is the layer's own. ``live`` (...) bool:
     the rows that are somebody's tokens (default: all); the others are
-    computed like them and left out of the counts.
+    computed like them and left out of the counts. ``say_experts``: a
+    third result, the experts the router chose for every row, held or
+    not, int32 (..., ``config.k``) (a program that says what it chose,
+    to be compared under its own choices; one device's rows only).
 
     The grouped matmul is a Mosaic custom call whichever of
     ``expert_ffn``'s two implementations the shapes choose (the
@@ -535,7 +578,10 @@ def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
             else jnp.broadcast_to(live, lead).reshape(-1))
     part = ambient_partition()
     if part is None or part.batch is None:
-        out, counts = _dropless_rows(params, rows, live, config, layer)
+        out, counts, *chose = _dropless_rows(params, rows, live, config,
+                                             layer, say_experts=say_experts)
+    elif say_experts:
+        raise NotImplementedError("say_experts with the rows split by hand")
     else:
         out, counts = jax.shard_map(
             lambda p, r, a, i: _dropless_rows(p, r, a, config, i,
@@ -544,4 +590,6 @@ def moe_ffn_dropless(params: dict, x: jax.Array, config: MoEConfig,
             out_specs=(P(part.batch, None), P()),
             axis_names=part.axes, check_vma=False,
         )(params, rows, live, layer)
+    if say_experts:
+        return out.reshape(*lead, D), counts, chose[0].reshape(*lead, config.k)
     return out.reshape(*lead, D), counts

@@ -221,10 +221,12 @@ def _init(m_scr, l_scr, acc_scr):
 
 def _attend(first_row, start, lat_ref, key_ref, qn_ref, qr_ref, wuk_ref,
             wuv_ref, k_scr, v_scr, m_scr, l_scr, acc_scr,
-            *, scale, G, T, tile, block):
+            *, scale, G, T, tile, block, allowed_ref=None):
     """All T rows of the chunk, the G heads of the group, on the block
     of cache rows from ``first_row``: ``lat_ref`` (block, kv_rank) its
-    latent rows, ``key_ref`` (rope, block) its rotary keys. The loops
+    latent rows, ``key_ref`` (rope, block) its rotary keys,
+    ``allowed_ref`` (T, block) int8, where the call brings a selection:
+    which of the block's rows each of the chunk's may attend to. The loops
     over heads are traced once and unrolled when the kernel is lowered:
     written out in Python they read the same on the chip and took four
     times as long to trace, a second of a replica's start."""
@@ -257,6 +259,8 @@ def _attend(first_row, start, lat_ref, key_ref, qn_ref, qr_ref, wuk_ref,
                 seen = (jax.lax.broadcasted_iota(jnp.int32, (tile, block), 1)
                         - jax.lax.broadcasted_iota(jnp.int32, (tile, block), 0)
                         <= start + r0 - first_row)
+                if allowed_ref is not None:
+                    seen &= allowed_ref[at, :].astype(jnp.int32) != 0
                 s = jnp.where(seen, s, _MASKED)
                 m = m_scr[g, at]
                 m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -284,19 +288,28 @@ def _finish(o_ref, l_scr, acc_scr, G):
 
 
 def _kernel(meta_ref, start_ref, blocks_ref,
-            qn_ref, qr_ref, lat_hbm, key_hbm, wuk_ref, wuv_ref, o_ref,
-            lat_buf, key_buf, sems, k_scr, v_scr, m_scr, l_scr, acc_scr,
-            *, scale, G, T, tile, block):
+            qn_ref, qr_ref, lat_hbm, key_hbm, wuk_ref, wuv_ref, *rest,
+            scale, G, T, tile, block, selected):
+    # a call with a selection brings it behind the weights, (B, T, rows)
+    # int8 in HBM, and a buffer for a block's columns of it
+    allowed_hbm, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    (o_ref, lat_buf, key_buf, sems, k_scr, v_scr, m_scr, l_scr, acc_scr,
+     *allowed_buf) = rest
     b = pl.program_id(0)
     layer, lane = meta_ref[0], meta_ref[1] + b
     blocks = blocks_ref[b]
 
     def fetch(j, slot):
         at = pl.ds(pl.multiple_of(j * block, block), block)
-        return (pltpu.make_async_copy(lat_hbm.at[layer, lane, at, :],
-                                      lat_buf.at[slot], sems.at[0, slot]),
-                pltpu.make_async_copy(key_hbm.at[layer, lane, :, at],
-                                      key_buf.at[slot], sems.at[1, slot]))
+        copies = (pltpu.make_async_copy(lat_hbm.at[layer, lane, at, :],
+                                        lat_buf.at[slot], sems.at[0, slot]),
+                  pltpu.make_async_copy(key_hbm.at[layer, lane, :, at],
+                                        key_buf.at[slot], sems.at[1, slot]))
+        if selected:
+            copies += (pltpu.make_async_copy(
+                allowed_hbm.at[b, :, at], allowed_buf[0].at[slot],
+                sems.at[2, slot]),)
+        return copies
 
     for copy in fetch(0, 0):
         copy.start()
@@ -314,7 +327,8 @@ def _kernel(meta_ref, start_ref, blocks_ref,
             copy.wait()
         _attend(j * block, start_ref[b], lat_buf.at[slot], key_buf.at[slot],
                 qn_ref, qr_ref, wuk_ref, wuv_ref, k_scr, v_scr, m_scr, l_scr,
-                acc_scr, scale=scale, G=G, T=T, tile=tile, block=block)
+                acc_scr, scale=scale, G=G, T=T, tile=tile, block=block,
+                allowed_ref=allowed_buf[0].at[slot] if selected else None)
         return carry
 
     jax.lax.fori_loop(0, blocks, step, 0)
@@ -322,10 +336,17 @@ def _kernel(meta_ref, start_ref, blocks_ref,
 
 
 def latent_prefill_attention(q_nope, q_rope, latents, keys, wuk, wuv, *,
-                             layer, slot, start_pos, rows: int, scale: float):
+                             layer, slot, start_pos, rows: int, scale: float,
+                             allowed=None):
     """The expanded form over the cache's stacks (the module docstring
-    has the layout contract) -> (B, T, H, v). Raises NotImplementedError
-    for shapes the kernel does not tile (see ``untileable``)."""
+    has the layout contract) -> (B, T, H, v). ``allowed`` (B, T, rows)
+    bool, where the caller has selected rows: row t of sequence b
+    attends to the cache rows at or before its own that it marks, and
+    to no other (one of them at least, for every row whose result is
+    kept: the first block no longer sets every maximum, and what a row
+    gathered before its first marked row fades to 0 exactly when that
+    row's score arrives). Raises NotImplementedError for shapes the
+    kernel does not tile (see ``untileable``)."""
     reason = untileable(q_nope, q_rope, latents, keys, wuk, wuv, rows)
     if reason is not None:
         raise NotImplementedError(reason)
@@ -334,7 +355,8 @@ def latent_prefill_attention(q_nope, q_rope, latents, keys, wuk, wuv, *,
         q_nope, q_rope, latents, keys, wuk, wuv, layer, slot, start_pos,
         rows=rows, scale=scale, block=_divisor(rows, _BLOCK),
         tile=_divisor(T, _TILE), G=math.gcd(H, _HEADS),
-        interpret=_flash._interpret())
+        interpret=_flash._interpret(),
+        **({} if allowed is None else {"allowed": allowed.astype(jnp.int8)}))
 
 
 # jitted, so that a program whose two layer scans each call it with the
@@ -343,7 +365,7 @@ def latent_prefill_attention(q_nope, q_rope, latents, keys, wuk, wuv, *,
 @functools.partial(jax.jit, static_argnames=(
     "rows", "scale", "block", "tile", "G", "interpret"))
 def _call(q_nope, q_rope, latents, keys, wuk, wuv, layer, slot, start_pos, *,
-          rows, scale, block, tile, G, interpret):
+          rows, scale, block, tile, G, interpret, allowed=None):
     B, H, T, nope = q_nope.shape
     rope, kv_rank, v = q_rope.shape[3], latents.shape[3], wuv.shape[2]
     dtype = q_nope.dtype
@@ -353,7 +375,7 @@ def _call(q_nope, q_rope, latents, keys, wuk, wuv, layer, slot, start_pos, *,
                       jnp.asarray(slot, jnp.int32)])
     scratch = [pltpu.VMEM((2, block, kv_rank), dtype),
                pltpu.VMEM((2, rope, block), dtype),
-               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SemaphoreType.DMA((2 if allowed is None else 3, 2)),
                pltpu.VMEM((G, block, nope), dtype),
                pltpu.VMEM((G, block, v), dtype),
                pltpu.VMEM((G, T, 1), jnp.float32),
@@ -365,11 +387,16 @@ def _call(q_nope, q_rope, latents, keys, wuk, wuv, layer, slot, start_pos, *,
                                          lambda b, h, *_: (h, 0, 0))
     in_specs = [heads(nope), heads(rope), pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY), weights(nope), weights(v)]
+    selection = ()
+    if allowed is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch.append(pltpu.VMEM((2, T, block), jnp.int8))
+        selection = (allowed,)
     # half the window attended, as a prompt's chunks see on average
     pairs, attended = B * H * T * rows // 2, B * H * rows // 2
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, G=G, T=T, tile=tile,
-                          block=block),
+                          block=block, selected=allowed is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, H // G), in_specs=in_specs,
             out_specs=heads(v),
@@ -390,7 +417,7 @@ def _call(q_nope, q_rope, latents, keys, wuk, wuv, layer, slot, start_pos, *,
         name="latent_attention_prefill",
     )(meta, start_pos.astype(jnp.int32), blocks.astype(jnp.int32),
       q_nope, q_rope, latents, keys, wuk.transpose(1, 0, 2),
-      wuv.transpose(1, 0, 2))
+      wuv.transpose(1, 0, 2), *selection)
     return out.transpose(0, 2, 1, 3)
 
 

@@ -233,6 +233,7 @@ def main() -> int:
     grouped_swiglu(check, sds, quick)
     if not quick:
         latent_chunks(check, sds)
+        indexed_latent_chunks(check, sds)
         window_pair(check, sds)
         state_pair(check, sds)
         parallel_chunks(check, sds)
@@ -392,6 +393,40 @@ def latent_chunks(check, sds):
               partial(lower_decode, sds, latent_moe, cfg, params, cache,
                       lanes, window), expect=("latent_attention_decode",),
               forbid=rf"{no_leaf_copy}|f32\[{lanes},{cfg.n_heads},\d{{3,}}\]")
+
+
+def indexed_latent_chunks(check, sds):
+    """The programs of ``deepseek-v3.2-exp.serve-longctx`` at its
+    published widths and the cell's 8 x 25 600 cache: the three buckets
+    of the chunk a v5e's engine derives (1024 rows) reading the whole
+    slot, the whole chunk at the other read window, and the decode step
+    at both. None may copy a leaf of the shard; each chunk holds the
+    indexer's score kernel (``ops/pallas_index_score.py``), the expanded
+    form's kernel (under the selection's mask) and the grouped SwiGLU,
+    and no float32 score of index heads x chunk rows x cache rows; a
+    decode holds no float32 score of every latent row a head."""
+    from ray_tpu.models import latent_moe
+
+    cfg, params, cache, lanes, max_seq, chunk = serving_cell(
+        sds, "deepseek-v3.2-exp.serve-longctx", latent_moe)
+    no_leaf_copy = no_copy_of(cache["latent"], cache["rope_key"],
+                              cache["index_key"])
+    for rows, window in ((chunk // 4, max_seq), (chunk // 2, max_seq),
+                         (chunk, max_seq), (chunk, max_seq // 2)):
+        check(f"indexed latent prefill chunk of {rows} rows reading {window} "
+              f"of {lanes} x {max_seq}, published widths, one device",
+              partial(lower_chunk, sds, latent_moe, cfg, params, cache, rows,
+                      window),
+              expect=("index_score", "latent_attention_prefill",
+                      "grouped_swiglu_gate_up"),
+              forbid=rf"{no_leaf_copy}|f32\[\d*,?{cfg.index_heads},{rows},\d+\]"
+                     rf"|{every_assignment(cfg, rows)}")
+    for window in (max_seq // 2, max_seq):
+        check(f"indexed latent decode step of {lanes} lanes reading {window} "
+              f"of {lanes} x {max_seq}, published widths, one device",
+              partial(lower_decode, sds, latent_moe, cfg, params, cache,
+                      lanes, window),
+              forbid=rf"{no_leaf_copy}|f32\[{lanes},{cfg.n_heads},{window}\]")
 
 
 def tiled_chunks(check, sds, label, model, cfg, params, cache, lanes,
